@@ -94,7 +94,7 @@ def _walk_jaxpr(jaxpr, allowed_axes: Tuple[str, ...], sink):
     """Recurse through all nested jaxprs; ``sink(eqn, allowed_axes)``
     sees every equation with the axis names of its enclosing
     shard_map."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for eqn in jaxpr.eqns:
         inner_allowed = allowed_axes

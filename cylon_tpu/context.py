@@ -18,6 +18,7 @@ execution model:
 """
 from __future__ import annotations
 
+import os
 import threading
 from typing import Dict, List, Optional
 
@@ -41,6 +42,30 @@ def _distributed_initialized() -> bool:
         return getattr(_jd.global_state, "client", None) is not None
     except Exception:  # pragma: no cover - jax internals moved  # cylint: disable=errors/broad-swallow — jax internals moved: treat as uninitialized
         return False
+
+
+# fixed, because the directory is part of the cache key: a path that
+# moves (temp name, pid, time) never hits
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def _use_compile_cache(devices) -> None:
+    """Persistent compile cache for TPU contexts — THE one place the
+    program sets it (the stream join plan alone compiles for ~2 min).
+    Where JAX_COMPILATION_CACHE_DIR is set JAX already reads it; on the
+    CPU nothing is set, so a test run writes no cache into the tree."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+            or devices[0].platform != "tpu" \
+            or jax.config.jax_compilation_cache_dir == COMPILE_CACHE_DIR:
+        return
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.set_cache_dir(COMPILE_CACHE_DIR)
+    # if something compiled before the first context, JAX latched "no
+    # directory" then, and only a reset makes it look again
+    compilation_cache.reset_cache()
 
 
 class CylonContext:
@@ -92,6 +117,7 @@ class CylonContext:
 
         self.devices: List = devices
         self.mesh = jax.sharding.Mesh(np.array(devices), (_AXIS,))
+        _use_compile_cache(devices)
 
         from .memory import MemoryPool
         from . import telemetry as _telemetry

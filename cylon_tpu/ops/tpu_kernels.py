@@ -42,14 +42,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # pre-0.5 jax naming
-    def _compiler_params_compat(has_side_effects: bool = False):
-        # TPUCompilerParams grew has_side_effects later; the dict form
-        # ({"mosaic": {...}}) is the spelling old pallas_call accepts
-        return {"mosaic": {"has_side_effects": bool(has_side_effects)}}
-
-    pltpu.CompilerParams = _compiler_params_compat
-
 LANES = 128
 _I32MAX = jnp.iinfo(jnp.int32).max
 
@@ -60,11 +52,7 @@ def _x32_trace():
     Mosaic's int64 convert_element_type rule recurses forever; every
     kernel here is 32-bit by construction, so the promotion is never
     wanted."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import enable_x64 as _e64  # pre-0.5 jax home
-
-    return _e64(False)
+    return jax.enable_x64(False)
 
 
 def _roll(x, k, axis, interpret=False):
@@ -1033,21 +1021,23 @@ def partition_hist(t_s: jnp.ndarray, nbuckets: int, block_rows: int = 32,
         for w in range(nbuckets):
             c = jnp.sum((tv == w).astype(jnp.int32))
             row = jnp.where(lane == w, c, row)
-        hist_ref[:] = row
+        # Mosaic wants output blocks of (8k, 128): each grid step owns
+        # one full (8, 128) tile holding its histogram row 8 times
+        hist_ref[:] = jnp.broadcast_to(row, (8, LANES))
 
     res = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((blocks, LANES), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((blocks * 8, LANES), jnp.int32),
         grid=(blocks,),
         in_specs=[pl.BlockSpec((BR, LANES), lambda b: (b, 0),
                                memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((1, LANES), lambda b: (b, 0),
+        out_specs=pl.BlockSpec((8, LANES), lambda b: (b, 0),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
     )
     with _x32_trace():
         res = res(t2)
-    return res[:, :nbuckets]
+    return res[::8, :nbuckets]
 
 
 def partition_scatter(t_s: jnp.ndarray, streams: Sequence[jnp.ndarray],

@@ -155,10 +155,11 @@ def render_table() -> str:
 # ---------------------------------------------------------------------------
 
 # memory.py
-declare("CYLON_HBM_BYTES", 16 * (1 << 30), "int",
-        "per-chip HBM fallback when the runtime hides memory_stats "
-        "(tunneled backends); sizes the >HBM routing guards and the "
-        "shuffle comm budget", lo=1)
+declare("CYLON_HBM_BYTES", None, "int",
+        "per-chip HBM limit for a TPU whose runtime hides memory_stats "
+        "(unset: the published size of its device_kind, memory."
+        "TPU_HBM_BYTES); sizes the >HBM routing guards and the shuffle "
+        "comm budget", lo=1)
 
 # telemetry/
 declare("CYLON_TRACE_SAMPLE_RATE", 1.0, "float",

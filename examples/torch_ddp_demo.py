@@ -59,31 +59,15 @@ def make_local_tables(ctx, n_per_shard=4096):
 
 
 def worker(pid: int, nproc: int, jax_port: str, torch_port: str) -> None:
-    # 2 virtual CPU devices per process. jax 0.4.x lacks the
-    # jax_num_cpu_devices config option and only honors the XLA_FLAGS
-    # spelling, which must be in place before backend init; a launching
-    # pytest parent's 8-device flag is inherited through the env and
-    # must be REPLACED, not appended to. Same guarded fallback as
-    # tests/conftest.py, applied to this fresh interpreter.
-    os.environ["XLA_FLAGS"] = " ".join(
-        [f for f in os.environ.get("XLA_FLAGS", "").split()
-         if not f.startswith("--xla_force_host_platform_device_count")]
-        + ["--xla_force_host_platform_device_count=2"])
+    # 2 virtual CPU devices per process: the config option overrides a
+    # launching pytest parent's 8-device XLA_FLAGS
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        pass  # jax 0.4.x: the XLA_FLAGS form above is the only spelling
-    try:
-        # cross-process collectives on the CPU backend need gloo;
-        # without this jax 0.4.x raises "Multiprocess computations
-        # aren't implemented on the CPU backend" at the first collective
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError:
-        pass  # newer jax enables gloo CPU collectives by default
+    jax.config.update("jax_num_cpu_devices", 2)
+    # cross-process collectives on the CPU backend need gloo
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     import cylon_tpu as ct
 
     ctx = ct.CylonContext.InitDistributed(ct.MultiHostConfig(

@@ -24,13 +24,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:  # older jax spells it as an XLA boot flag
-    if "--xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
-            " --xla_force_host_platform_device_count=8"
+jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_enable_x64", True)
 
 import pandas as pd  # noqa: E402

@@ -1,23 +1,7 @@
 #!/bin/sh
-# Real-TPU correctness pass (VERDICT r03 #7): compiled Pallas kernels on
-# the attached chip, recorded as TPU_TESTS.json for the driver/judge.
-set -e
-cd "$(dirname "$0")/.."
-CYLON_TPU_TESTS=1 python -m pytest tests/test_tpu_golden.py -m tpu \
-    -q --tb=short --junitxml=/tmp/tpu_tests.xml || true
-python - <<'EOF'
-import json
-import xml.etree.ElementTree as ET
-
-root = ET.parse("/tmp/tpu_tests.xml").getroot()
-suite = root if root.tag == "testsuite" else root.find("testsuite")
-out = {"passed": int(suite.get("tests", 0))
-       - int(suite.get("failures", 0)) - int(suite.get("errors", 0))
-       - int(suite.get("skipped", 0)),
-       "failed": int(suite.get("failures", 0)) + int(suite.get("errors", 0)),
-       "skipped": int(suite.get("skipped", 0)),
-       "backend": "tpu"}
-with open("TPU_TESTS.json", "w") as fh:
-    json.dump(out, fh, indent=1)
-print(json.dumps(out))
-EOF
+# Real-TPU correctness pass: the golden relational ops with COMPILED
+# (non-interpreted) Pallas kernels on the attached chip. One process;
+# the exit code is pytest's.
+cd "$(dirname "$0")/.." || exit 1
+CYLON_TPU_TESTS=1 exec python -m pytest tests/test_tpu_golden.py -m tpu \
+    -q --tb=short "$@"

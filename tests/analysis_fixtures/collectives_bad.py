@@ -7,10 +7,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from cylon_tpu.analysis.collectives import EntryPoint, _sds
 

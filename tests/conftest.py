@@ -31,12 +31,7 @@ if not TPU_MODE:
     # runs, in which case the env vars above were read too late — set via
     # config too.
     jax.config.update("jax_platforms", "cpu")
-    try:
-        # newer jax spells the virtual-device count as a config option;
-        # older releases only honor the XLA_FLAGS form set above
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", 8)
     # x64 stays OFF in TPU mode (Mosaic rejects 64-bit converts)
     jax.config.update("jax_enable_x64", True)
 

@@ -1,6 +1,5 @@
 """bench.py is the driver-facing artifact producer — its code paths are
-gated here so a refactor can't silently sink a round's evidence again
-(round-4 postmortem: BENCH_r04 was rc=1/parsed=null)."""
+gated here so a refactor can't silently sink a round's evidence."""
 import json
 
 import numpy as np
@@ -9,14 +8,13 @@ import pytest
 import bench
 
 
-def test_last_json_line_parses_noise():
-    noisy = ("WARNING: platform experimental\n"
-             "{\"not\": \"last\"}\n"
-             "progress 50%\n"
-             '{"metric": "x", "value": 1.5}\n')
-    assert bench._last_json_line(noisy) == {"metric": "x", "value": 1.5}
-    assert bench._last_json_line("no json here") is None
-    assert bench._last_json_line("{broken\n") is None
+def test_main_refuses_a_platform_other_than_tpu():
+    """`python bench.py` measures the chip: on the CPU it is an error
+    (no fallback artifact, no child, no retry); run() below stays
+    callable anywhere."""
+    with pytest.raises(SystemExit) as e:
+        bench.main(["--rows", "1024", "--join-only"])
+    assert "'cpu'" in str(e.value.code)  # a message: exit status 1
 
 
 def test_run_join_only_small(local_ctx):

@@ -273,11 +273,15 @@ def test_empty_directory_check_passes(tmp_path):
                    "note": "no baseline yet"}
 
 
-def test_cli_over_committed_artifacts():
-    """The repo's own BENCH_r01–r05 trajectory renders and passes the
-    gate (r05 is a cpu-fallback round with no same-backend reference)."""
+def test_cli_over_a_five_round_trajectory(tmp_path):
+    """A trajectory shaped like the repo's first five rounds — three
+    TPU rounds, one lost (rc=1, nothing parsed), one on another backend
+    with no same-backend reference — renders and passes the gate."""
+    d = _write_rounds(tmp_path, {
+        1: _artifact(1.0e7), 2: _artifact(1.2e7), 3: _artifact(1.3e7),
+        4: None, 5: _artifact(2.0e5, backend="cpu-fallback")})
     r = subprocess.run(
-        [sys.executable, SCRIPT, "--dir", REPO, "--check"],
+        [sys.executable, SCRIPT, "--dir", d, "--check"],
         capture_output=True, text=True, timeout=60)
     assert r.returncode == 0, r.stdout + r.stderr
     for rnd in ("r01", "r02", "r03", "r04", "r05"):

@@ -277,3 +277,23 @@ def test_distributed_join_identical_under_overlap(dist_ctx, monkeypatch,
 
     pd.testing.assert_frame_equal(canon(got), canon(want),
                                   check_dtype=False, atol=1e-6)
+
+
+def test_padded_route_block_cap_comes_from_a_known_budget():
+    """16M rows a chip on 4 chips: every (src, dst) pair moves ~4.2M
+    rows, so the padded block is 8M — past MAX_BLOCK, which caps the
+    block only where the budget is unknown. With live HBM numbers the
+    budget alone decides (chip run, PR 22: capped at MAX_BLOCK the main
+    path fell to the blockwise sort rounds and never met the partition
+    kernel)."""
+    counts = np.full((4, 4), 4_200_000)
+    payload = {"k": np.zeros(8, np.int32), "w": np.zeros(8, np.float32)}
+    assert _shuffle._padded_route(counts, payload, 4, None) \
+        == (False, 1 << 23, _shuffle.MAX_BLOCK)
+    ok, block, mb = _shuffle._padded_route(counts, payload, 4, 4 << 30)
+    assert ok and block == 1 << 23 and mb >= block
+    # a budget that cannot hold 4 * world * block * 8 B still refuses
+    assert not _shuffle._padded_route(counts, payload, 4, 512 << 20)[0]
+    # an explicit max_block binds whatever the budget
+    assert not _shuffle._padded_route(counts, payload, 4, 4 << 30,
+                                     max_block=1 << 20)[0]

@@ -25,30 +25,14 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _CHILD = r"""
 import os, sys
 pid, nproc, port, tmp = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3], sys.argv[4]
-# 2 virtual CPU devices per process. jax 0.4.x lacks the
-# jax_num_cpu_devices config option and only honors the XLA_FLAGS
-# spelling, which must be in place before backend init; the parent
-# pytest process's 8-device flag is inherited through the env and must
-# be REPLACED, not appended to. Same guarded fallback as
-# tests/conftest.py, applied to this fresh interpreter.
-os.environ["XLA_FLAGS"] = " ".join(
-    [f for f in os.environ.get("XLA_FLAGS", "").split()
-     if not f.startswith("--xla_force_host_platform_device_count")]
-    + ["--xla_force_host_platform_device_count=2"])
+# 2 virtual CPU devices per process: the config option overrides the
+# 8-device XLA_FLAGS inherited from the parent pytest process
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    pass  # jax 0.4.x: the XLA_FLAGS form above is the only spelling
-try:
-    # cross-process collectives on the CPU backend need gloo; without
-    # this jax 0.4.x raises "Multiprocess computations aren't
-    # implemented on the CPU backend" at the first allgather
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except AttributeError:
-    pass  # newer jax enables gloo CPU collectives by default
+jax.config.update("jax_num_cpu_devices", 2)
+# cross-process collectives on the CPU backend need gloo
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 import numpy as np
 import cylon_tpu as ct
 

@@ -1,0 +1,130 @@
+"""The main path's Pallas kernels, compiled by the chip's own compiler for
+a DESCRIBED (not attached) TPU v5e at the real 16M-row shapes: what
+Mosaic refuses — a block shape off the (8, 128) tiling, too much VMEM,
+a program past HBM — fails here, at no chip time, where the interpreter
+tests pass. Nothing runs: results, donation and collectives are
+`chip_smoke.py`'s to prove.
+
+Only one process at a time may load libtpu, and it keeps it until it
+exits: the topology is described inside a module-scoped fixture (never
+at import), the compiles run in this process, and all of them live in
+this ONE file so that one xdist worker owns the library.
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from cylon_tpu.ops import join as _join
+from cylon_tpu.ops import tpu_kernels as tk
+
+N = 1 << 24  # rows a side per chip: the size every record uses
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described chip, with the persistent compile cache off around
+    the module: a described-chip entry cannot be read back and warns."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(chip, n, dtype):
+    return jax.ShapeDtypeStruct((n,), dtype, sharding=chip)
+
+
+def _compiled_text(fn, *args, **kw):
+    """Lower + compile for the described chip with x64 OFF, as on the
+    chip (tier-1 runs with it on, conftest.py)."""
+    with jax.enable_x64(False):
+        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+        return jitted.lower(*args, **kw).compile().as_text()
+
+
+def test_partition_kernels_compile(chip):
+    """The exchange's fused partitioner at world 4 -> 5 buckets (4 live
+    targets + the dead-row tail), two payload legs."""
+    def part(t, a, b):
+        return (tk.partition_hist(t, 5),
+                tk.partition_scatter(t, [a, b], 5))
+
+    text = _compiled_text(part, _sds(chip, N, jnp.int32),
+                          _sds(chip, N, jnp.uint32),
+                          _sds(chip, N, jnp.uint32))
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_stream_compact_compiles(chip):
+    def compact(mask, a, b):
+        return tk.stream_compact(mask, [a, b])
+
+    text = _compiled_text(compact, _sds(chip, N, jnp.bool_),
+                          _sds(chip, N, jnp.uint32),
+                          _sds(chip, N, jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_setop_stream_compiles(chip):
+    """Distinct union over two 8M-row tables of one payload lane."""
+    def setop(bits, bits2, tag, lane):
+        return tk.setop_stream(bits, bits2, tag, [lane], op=0)
+
+    u32 = _sds(chip, N, jnp.uint32)
+    assert "tpu_custom_call" in _compiled_text(setop, u32, u32, u32, u32)
+
+
+# the smoke's pruned join inputs: left (k,), right (k, w), all rows valid
+def _join_shapes(chip):
+    k, w = _sds(chip, N, jnp.int32), _sds(chip, N, jnp.float32)
+    cols = ((k,), (None,), (k, w), (None, None))
+    a_desc, b_desc = _join.plan_lane_descs(*cols, _join.JoinType.INNER)
+    keys = ((k,), (None,), None, (k,), (None,), None)
+    kw = dict(join_type=_join.JoinType.INNER, a_desc=a_desc, b_desc=b_desc,
+              block_rows=_join.stream_block_rows(N, N), interpret=False)
+    return keys, cols, kw
+
+
+def test_join_plan_stream_compiles(chip):
+    """The 16M x 16M stream join plan at block_rows 64 — the ~2 minute
+    compile of the main path."""
+    keys, cols, kw = _join_shapes(chip)
+    assert kw["block_rows"] == 64
+    text = _compiled_text(_join._plan_program_stream_jit, *keys, *cols,
+                          str_flags=(False,), hash_mode=False, **kw)
+    assert "tpu_custom_call" in text
+
+
+def test_join_expand_compiles(chip):
+    """The expand (materialize) program on that plan's outputs, at the
+    output capacity of the smoke's 64M joined rows."""
+    keys, cols, kw = _join_shapes(chip)
+    with jax.enable_x64(False):
+        plan_out = jax.eval_shape(
+            lambda *a: _join._plan_program_stream_impl(
+                *a, str_flags=(False,), hash_mode=False, **kw),
+            *keys, *cols)
+    counts, a_streams, b_streams = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        plan_out)
+    cap_e = _join.stream_expand_capacity(4 * N, kw["block_rows"])
+    text = _compiled_text(_join._materialize_program_stream_jit, counts,
+                          a_streams, b_streams, *cols, cap_e=cap_e, **kw)
+    assert "tpu_custom_call" in text

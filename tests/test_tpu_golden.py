@@ -5,7 +5,7 @@ leaves open (tests/conftest.py pins JAX_PLATFORMS=cpu and runs kernels
 under the Pallas interpreter).
 
 Run: CYLON_TPU_TESTS=1 python -m pytest tests/test_tpu_golden.py -m tpu
-(scripts/run_tpu_tests.sh wraps this and records TPU_TESTS.json).
+(scripts/run_tpu_tests.sh wraps this; its exit code is pytest's).
 Reference bar: the reference's tests run the real transport
 (cpp/test/CMakeLists.txt:36-76).
 """
@@ -44,7 +44,7 @@ def _cmp(got, exp, name):
 
 
 N = 60_000  # big enough to engage the stream (Pallas) paths, small
-            # enough that remote compiles stay in seconds
+            # enough that compiles stay in seconds
 
 
 def _pair(seed, nkeys=997):
