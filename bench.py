@@ -727,7 +727,7 @@ def bench_service_pipeline(ctx, n_rows: int, iters: int = 3) -> dict:
     cache bypassed, so every run pays host-side optimization) vs
     submitted through the :class:`QueryService` with a warm plan/
     fingerprint cache. The artifact records the cache hit count, the
-    total ``cylon_kernel_compile_seconds`` (the compile cost the warm
+    total ``cylon_jit_seconds_total`` (the compile cost the warm
     cache amortizes — zero NEW factory builds across the whole warmed
     service phase), and the
     mean submit→dispatch wait, so scripts/benchtrend.py tracks the
@@ -758,10 +758,8 @@ def bench_service_pipeline(ctx, n_rows: int, iters: int = 3) -> dict:
 
     def compile_seconds():
         return sum(
-            v.get("sum", 0.0)
-            for k, v in telemetry.metrics_snapshot().items()
-            if k.startswith("cylon_kernel_compile_seconds")
-            and isinstance(v, dict))
+            v for k, v in telemetry.metrics_snapshot().items()
+            if k.startswith("cylon_jit_seconds_total"))
 
     N = 8
     # warm the kernel memos once so BOTH sides measure steady state
@@ -896,13 +894,8 @@ def bench_pandas_reference(n_rows: int, iters: int = 1) -> dict:
 def run(n_rows: int = 1 << 24, iters: int = 3, full: bool = True) -> dict:
     import jax
 
-    # compile-cost capture for every kernel factory the run builds:
-    # enabled BEFORE the context (and so before any counted_cache memo
-    # fills) — the artifact then carries per-factory compile seconds +
-    # XLA cost analysis beside the wall-clock numbers
     from cylon_tpu.telemetry import profiler as _profiler
 
-    _profiler.enable()
     ctx = _mk_ctx()
     dist_res = bench_dist_join(ctx, n_rows, iters)
     local_res = bench_local_join(ctx, n_rows, iters)
@@ -958,12 +951,13 @@ def run(n_rows: int = 1 << 24, iters: int = 3, full: bool = True) -> dict:
     # ledger's end-of-run leak count — a growing leak count across
     # rounds is a regression even when throughput holds
     _hbm_used, _hbm_peak, _hbm_limit = ctx.memory_pool.snapshot()
-    # recompile-cardinality trajectory: every distinct (factory, input
-    # signature) the profiler measured is one compiled XLA program.
-    # Capacity bucketing (benchutils.bucket_cap, enforced statically by
-    # the specialization analysis family) bounds this per factory by
-    # the BUCKET count, not the distinct-value count — benchtrend
-    # tracks it lower-is-better across rounds
+    # recompile-cardinality trajectory: every backend-compile event of
+    # the process (the always-on jax.monitoring listener,
+    # cylon_jit_events_total{stage="compile"}) is one compiled XLA
+    # program. Capacity bucketing (benchutils.bucket_cap, enforced
+    # statically by the specialization analysis family) bounds this
+    # per factory by the BUCKET count, not the distinct-value count —
+    # benchtrend tracks it lower-is-better across rounds
     _compile_profile = _profiler.summary()
     return {
         "metric": "dist_inner_join_rows_per_sec_per_chip",
@@ -985,8 +979,8 @@ def run(n_rows: int = 1 << 24, iters: int = 3, full: bool = True) -> dict:
             "shuffle_gbps": shuffle_res["gbps_per_chip"],
             "shuffle": shuffle_res,
             "compile_profile": _compile_profile,
-            "distinct_kernel_signatures": sum(
-                v["programs"] for v in _compile_profile.values()),
+            "distinct_kernel_signatures":
+                _compile_profile["compile"]["events"],
             "suite": {k: {kk: (_sig(vv) if isinstance(vv, float) else vv)
                           for kk, vv in v.items()}
                       for k, v in suite.items()},

@@ -139,8 +139,12 @@ DECLARED_ENTRIES: Tuple[Tuple[str, str, str], ...] = (
 # hook registrars: a function-valued argument to one of these becomes
 # hook-domain code (runs on whichever thread triggers the hook)
 HOOK_REGISTRARS = ("add_root_hook", "add_sink", "add_dump_section",
-                   "set_factory_fault_hook", "set_factory_build_hook",
-                   "set_plan_memo", "set_plan_evict_hook")
+                   "set_factory_fault_hook",
+                   "set_plan_memo", "set_plan_evict_hook",
+                   # jax.monitoring (telemetry/profiler.py): the
+                   # listener runs on whichever thread compiles
+                   "register_event_time_span_listener",
+                   "register_event_duration_secs_listener")
 
 _LOCK_CTORS = {
     ("threading", "Lock"): False,      # reentrant? no

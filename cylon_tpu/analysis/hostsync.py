@@ -32,6 +32,17 @@ never flagged: they live outside any traced closure. Each finding
 reports the trace chain (root → callee) so a false positive is cheap
 to triage; a justified one takes a per-line ``# cylint:
 disable=hostsync/...`` with a comment.
+
+4. *The choke point.* On the host side, a fetch whose value decides
+   what is dispatched next (a count, a capacity, a flag, splitters)
+   goes through ``telemetry.host_fetch(site, x)``, which spans and
+   counts it. In the files that hold the operators' count→capacity
+   steps (``FETCH_SCOPES``) a bare ``jax.device_get`` is therefore a
+   ``hostsync/bare-fetch`` finding, except in the functions declared
+   as bulk movers of whole columns (``BULK_EXPORTS``: ``to_numpy``'s
+   live-row read, the process-local assembly), which decide nothing
+   and keep their plain ``device_get``. ``host_fetch`` itself is a
+   sync call like ``device_get`` inside traced code.
 """
 from __future__ import annotations
 
@@ -57,7 +68,23 @@ _SYNC_CALLS = {
     ("np", "asarray"), ("np", "array"), ("np", "ascontiguousarray"),
     ("numpy", "asarray"), ("numpy", "array"),
     ("jax", "device_get"),
+    # the spanned, counted choke point and the repo's aliases of it
+    ("host_fetch",), ("_host_fetch",), ("telemetry", "host_fetch"),
+    ("_telemetry", "host_fetch"),
 }
+
+# package-relative files in which every host-side device_get is either
+# the choke point or inside a declared bulk export
+# (options["fetch_scopes"] / ["bulk_exports"] override, for fixtures)
+FETCH_SCOPES = ("data/table.py", "parallel/shuffle.py",
+                "parallel/dist_ops.py", "parallel/shard.py")
+
+# (file, function): whole column buffers copied to the host, to be
+# exported or placed again — no value the next dispatch waits on
+BULK_EXPORTS = frozenset({
+    ("data/table.py", "_compact_indices"),       # to_numpy / to_pandas
+    ("parallel/shard.py", "assemble_process_local"),
+})
 
 _SYNC_METHODS = {"item", "tolist"}
 
@@ -232,6 +259,28 @@ def check_hostsync(ctx: AnalysisContext) -> List[Finding]:
         fn = mod.lookup(fname)
         if fn is not None:
             findings.extend(_scan_body(fn, mod, desc))
+
+    scopes = set(ctx.options.get("fetch_scopes", FETCH_SCOPES))
+    bulk = ctx.options.get("bulk_exports", BULK_EXPORTS)
+    for sf in ctx.files():
+        if sf.rel not in scopes:
+            continue
+        exempt = {id(node) for fn in ast.walk(sf.tree)
+                  if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef))
+                  and (sf.rel, fn.name) in bulk
+                  for node in ast.walk(fn)}
+        for node in ast.walk(sf.tree):
+            if isinstance(node, ast.Call) and id(node) not in exempt \
+                    and _attr_chain(node.func) == ("jax", "device_get"):
+                findings.append(Finding(
+                    rule="hostsync/bare-fetch", path=sf.rel,
+                    line=node.lineno,
+                    message="jax.device_get() outside the choke point: "
+                            "a fetch that decides the next dispatch "
+                            "goes through telemetry.host_fetch(site, x) "
+                            "(spanned and counted); a bulk export of "
+                            "whole columns is declared in "
+                            "analysis/hostsync.BULK_EXPORTS"))
 
     # classification summary: every host-transfer call site in the tree
     # is either inside a traced closure (flagged above) or host-side

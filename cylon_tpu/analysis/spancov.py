@@ -21,6 +21,12 @@ force spans around no-op paths the label-honesty discipline
 the real failure mode — a NEW operator or lowering added with no
 telemetry at all.
 
+The host-fetch choke point (``telemetry.host_fetch(site, x)``) opens a
+``sync.<site>`` span per call: its ``site`` must be a string literal
+(``span-coverage/dynamic-sync-site``), so that the span names and the
+``cylon_host_syncs_total{site=}`` labels stay the fixed set of choke
+points that docs/telemetry.md catalogs, never data.
+
 Fixture trees exercise it through the same scope table via
 ``options["span_scopes"]``.
 """
@@ -43,20 +49,22 @@ DEFAULT_SCOPES: Tuple[Tuple[str, str, str], ...] = (
 # (telemetry.span(...))
 _SPAN_CALL_NAMES = frozenset({"span", "_span", "phase", "_phase"})
 
+_FETCH_CALL_NAMES = frozenset({"host_fetch", "_host_fetch"})
+
+
+def _call_name(node: ast.AST):
+    """``f`` of a call ``f(...)`` / ``mod.f(...)``, else None."""
+    if not isinstance(node, ast.Call):
+        return None
+    fn = node.func
+    return fn.id if isinstance(fn, ast.Name) else \
+        fn.attr if isinstance(fn, ast.Attribute) else None
+
 
 def _is_span_with(stmt: ast.stmt) -> bool:
-    if not isinstance(stmt, (ast.With, ast.AsyncWith)):
-        return False
-    for item in stmt.items:
-        call = item.context_expr
-        if not isinstance(call, ast.Call):
-            continue
-        fn = call.func
-        name = fn.id if isinstance(fn, ast.Name) else \
-            fn.attr if isinstance(fn, ast.Attribute) else None
-        if name in _SPAN_CALL_NAMES:
-            return True
-    return False
+    return isinstance(stmt, (ast.With, ast.AsyncWith)) and any(
+        _call_name(item.context_expr) in _SPAN_CALL_NAMES
+        for item in stmt.items)
 
 
 def _has_span(fn_node: ast.FunctionDef) -> bool:
@@ -100,4 +108,18 @@ def check_span_coverage(ctx: AnalysisContext) -> List[Finding]:
                             f"phase log, collect_phases counting and "
                             f"EXPLAIN ANALYZE — wrap the operative "
                             f"path in telemetry.span/phase"))
+    for f in ctx.files():
+        for node in ast.walk(f.tree):
+            if _call_name(node) not in _FETCH_CALL_NAMES or not node.args:
+                continue
+            site = node.args[0]
+            if not (isinstance(site, ast.Constant)
+                    and isinstance(site.value, str)):
+                findings.append(Finding(
+                    rule="span-coverage/dynamic-sync-site", path=f.rel,
+                    line=node.lineno,
+                    message="host_fetch() site is not a string literal: "
+                            "the sync.<site> span name and the "
+                            "cylon_host_syncs_total{site=} label must "
+                            "be a static choke-point name, never data"))
     return findings

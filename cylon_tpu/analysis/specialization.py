@@ -3,7 +3,7 @@
 Every distributed op is *local kernel + shuffle + local kernel*
 (PAPER.md), and every local kernel comes from a ``counted_cache``
 factory whose arguments ARE the jit cache key: each distinct key tuple
-bakes a brand-new XLA program, and ``cylon_kernel_compile_seconds``
+bakes a brand-new XLA program, and ``cylon_jit_seconds_total``
 (docs/telemetry.md) bills the build. Whether that is fine or a
 recompile storm depends on each key parameter's *cardinality class*:
 

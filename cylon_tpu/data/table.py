@@ -96,7 +96,8 @@ class Table:
         if self.row_mask is None:
             return len(self._columns[0])
         if self._row_count_cache is None:
-            self._row_count_cache = int(self.row_mask.sum())
+            self._row_count_cache = int(_telemetry.host_fetch(
+                "row_count", self.row_mask.sum()))
         return self._row_count_cache
 
     def columns(self) -> List[Column]:
@@ -979,7 +980,8 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
             refs = lrefs + rrefs \
                 + tuple(x for x in (lemit, remit) if x is not None)
             host_counts = _count_cached(
-                ck, refs, lambda: jax.device_get(counts))
+                ck, refs,
+                lambda: _telemetry.host_fetch("join.count", counts))
             n_primary = int(host_counts[0])
         if hash_mode and int(host_counts[3]) > 0:
             return None  # hash collision — caller recomputes exactly
@@ -1020,7 +1022,8 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
             refs = lrefs + rrefs \
                 + tuple(x for x in (lemit, remit) if x is not None)
             n_primary, n_un = (int(v) for v in _count_cached(
-                ck, refs, lambda: jax.device_get(counts2)))
+                ck, refs,
+                lambda: _telemetry.host_fetch("join.count", counts2)))
         cap_p = _capacity(n_primary)
         cap_u = _capacity(n_un) \
             if config.type == _join.JoinType.FULL_OUTER else 0
@@ -1098,7 +1101,8 @@ def _exact_verify_keys(config, lcols, rcols, lidx, ridx, emit):
         if config.type == _join.JoinType.INNER:
             emit = emit & (~matched | eq)
             continue
-        if bool(jax.device_get((emit & matched & ~eq).any())):
+        if bool(_telemetry.host_fetch(
+                "join.exact_verify", (emit & matched & ~eq).any())):
             return emit, True
     return emit, False
 
@@ -1378,7 +1382,7 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
     # scatter-back path; the old gid scatter cost ~15-30 ns/element)
     values_s, valids_s, emit_s, iota_s, gid_s, ng = \
         _groupby.presort_groups_jit(tuple(keys), emit, values, valids)
-    num_groups = max(int(jax.device_get(ng)), 1)
+    num_groups = max(int(_telemetry.host_fetch("groupby.groups", ng)), 1)
     cap = _pow2(num_groups)
 
     rep, group_valid, results = _groupby.sorted_segment_aggregate_jit(

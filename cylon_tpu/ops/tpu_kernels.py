@@ -277,6 +277,7 @@ def stream_compact(mask: jnp.ndarray, streams: Sequence[jnp.ndarray],
 
     res = pl.pallas_call(
         kernel,
+        name="stream_compact",
         out_shape=out_shapes,
         grid=(blocks,),
         in_specs=([pl.BlockSpec((BR, LANES), lambda i: (i, 0),
@@ -509,6 +510,7 @@ def join_plan_stream(bits_s: jnp.ndarray, tag_s: jnp.ndarray, na: int,
     extra_in = ([b2b] if has_b2 else []) + v2 + l2
     res = pl.pallas_call(
         kernel,
+        name="join_stream_plan",
         out_shape=out_shapes,
         grid=(blocks,),
         in_specs=[pl.BlockSpec((BR, LANES), lambda i: (i, 0),
@@ -671,6 +673,7 @@ def setop_stream(bits_s: jnp.ndarray, bits2_s: jnp.ndarray,
 
     res = pl.pallas_call(
         kernel,
+        name="setop_stream",
         out_shape=out_shapes,
         grid=(blocks,),
         in_specs=[pl.BlockSpec((BR, LANES), lambda i: (i, 0),
@@ -831,6 +834,7 @@ def join_expand_stream(counts: jnp.ndarray,
 
     res = pl.pallas_call(
         kernel,
+        name="join_stream_expand",
         out_shape=out_shapes,
         grid=(nblocks,),
         in_specs=([pl.BlockSpec(memory_space=pltpu.SMEM)]
@@ -1027,6 +1031,7 @@ def partition_hist(t_s: jnp.ndarray, nbuckets: int, block_rows: int = 32,
 
     res = pl.pallas_call(
         kernel,
+        name="partition_hist",
         out_shape=jax.ShapeDtypeStruct((blocks * 8, LANES), jnp.int32),
         grid=(blocks,),
         in_specs=[pl.BlockSpec((BR, LANES), lambda b: (b, 0),
@@ -1114,6 +1119,7 @@ def partition_scatter(t_s: jnp.ndarray, streams: Sequence[jnp.ndarray],
 
     res = pl.pallas_call(
         kernel,
+        name="partition_scatter",
         out_shape=out_shapes,
         grid=(nbuckets, blocks),
         in_specs=[pl.BlockSpec((BR, LANES), lambda w, b: (b, 0),

@@ -44,8 +44,8 @@ from ..ops import order as _order
 from ..ops import setops as _setops
 from ..status import Code, CylonPlanError
 from ..telemetry import annotate as _annotate, counted_cache, \
-    counter as _counter, ledger as _ledger, phase as _phase, \
-    record_host_sync as _host_sync, span as _span
+    counter as _counter, host_fetch as _host_fetch, ledger as _ledger, \
+    phase as _phase, span as _span
 from . import shard
 from ..benchutils import bucket_cap as _bucket_cap
 from ..util import capacity as _capacity, pow2_floor as _pow2_floor
@@ -455,9 +455,8 @@ def _varlen_take_sharded(ctx: CylonContext, vb, idx) -> "object":
     starts = shard.pin(vb.starts, ctx)
     lengths = shard.pin(vb.lengths, ctx)
     idx = shard.pin(idx, ctx)
-    counts = np.asarray(jax.device_get(
-        _varlen_count_fn(ctx.mesh)(lengths, idx)))
-    _host_sync("varlen.count")
+    counts = np.asarray(_host_fetch(
+        "varlen.count", _varlen_count_fn(ctx.mesh)(lengths, idx)))
     cap_w = _bucket_cap(int(counts.max()))
     w, s, ln = _varlen_take_fn(ctx.mesh, cap_w)(words, starts, lengths, idx)
     world = ctx.get_world_size()
@@ -476,10 +475,9 @@ def _dist_as_varbytes(ctx: CylonContext, col: Column) -> Column:
     vocab_vb = VarBytes.from_host(col.dictionary)
     max_words = vocab_vb.max_words
     codes = shard.pin(col.data, ctx)
-    counts = np.asarray(jax.device_get(
-        _varlen_count_fn(ctx.mesh, replicated=True)(
+    counts = np.asarray(_host_fetch(
+        "varlen.count", _varlen_count_fn(ctx.mesh, replicated=True)(
             jax.device_put(vocab_vb.lengths), codes)))
-    _host_sync("varlen.count")
     cap_w = _bucket_cap(int(counts.max()))
     w, s, ln = _varlen_take_fn(ctx.mesh, cap_w, replicated=True)(
         vocab_vb.words, vocab_vb.starts, vocab_vb.lengths, codes)
@@ -854,10 +852,10 @@ def hash_partition(table: Table, hash_columns: Sequence,
     res = jax.lax.sort((tkey,) + tuple(leaves), num_keys=1,
                        is_stable=True)
     sorted_leaves = list(res[1:])
-    counts = np.asarray(jax.device_get(jax.ops.segment_sum(
-        jnp.ones(tkey.shape[0], jnp.int32), tkey,
-        num_segments=num_partitions + 1)))[:num_partitions]
-    _host_sync("hash_partition.counts")
+    counts = np.asarray(_host_fetch(
+        "hash_partition.counts", jax.ops.segment_sum(
+            jnp.ones(tkey.shape[0], jnp.int32), tkey,
+            num_segments=num_partitions + 1)))[:num_partitions]
     offs = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
 
     out = {}
@@ -1054,8 +1052,8 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
             # collective dispatch — counted, so the adaptive bench's
             # launch comparison is honest on both algorithms
             _counter("cylon_collective_launches_total").inc()
-            cm = np.asarray(jax.device_get(rep_counts)).reshape(world, -1)
-            _host_sync("join.plan")
+            cm = np.asarray(
+                _host_fetch("join.plan", rep_counts)).reshape(world, -1)
         if not (hash_mode and int(cm[:, 3].sum()) > 0):
             cap_e = _join.stream_expand_capacity(int(cm[:, 0].max()), br)
             with _phase("distributed_join.materialize", seq):
@@ -1078,8 +1076,8 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
             # counts2 is the replicated [world, 2] matrix of per-shard
             # [n_primary, n_unmatched_b]; capacity = worst shard (all
             # shards share one program)
-            counts = np.asarray(jax.device_get(counts2)).reshape(world, 2)
-            _host_sync("join.plan")
+            counts = np.asarray(
+                _host_fetch("join.plan", counts2)).reshape(world, 2)
             _annotate(rows_out=int(counts[:, 0].sum()))
         # bucket_cap, not util.capacity: these caps are cache-key
         # parameters of _join_mat_fn — 1 bucket per octave bounds the
@@ -1160,8 +1158,7 @@ def _exact_post_verify(res: Table, nl: int, pairs, config):
         bad = bad | (emit & both & ~a.varbytes.equals_rows(b.varbytes))
     if config.type == _join.JoinType.INNER:
         return Table(res._columns, res._ctx, emit & ~bad), False
-    collided = bool(jax.device_get(bad.any()))
-    _host_sync("join.exact_verify")
+    collided = bool(_host_fetch("join.exact_verify", bad.any()))
     return res, collided
 
 
@@ -1452,10 +1449,9 @@ def distributed_join_ring(left: Table, right: Table,
 
     seq = ctx.get_next_sequence()
     with _phase("ring_join.count", seq):
-        counts = np.asarray(jax.device_get(_ring_count_fn(
+        counts = np.asarray(_host_fetch("ring.count", _ring_count_fn(
             ctx.mesh, emit_un_a, len(abits))(
             abits, akv, aemit, bbits, bkv, bemit)))
-        _host_sync("ring.count")
     pairs, extra = counts[:, :world], counts[:, world]
     cap_step = _bucket_cap(int(pairs.max())) if pairs.size else 1
     cap_extra = _bucket_cap(int(extra.max())) if emit_un_a else 0
@@ -1677,8 +1673,8 @@ def broadcast_hash_join(left: Table, right: Table,
             ctx.mesh, jt_local)(abits, akv, aemit, bbits, bkv, bemit)
         # the gather program is this join's only collective transport
         _counter("cylon_collective_launches_total").inc()
-        cm = np.asarray(jax.device_get(rep_counts)).reshape(world, 2)
-        _host_sync("join.plan")
+        cm = np.asarray(
+            _host_fetch("join.plan", rep_counts)).reshape(world, 2)
         _annotate(rows_out=int(cm[:, 0].sum()))
     cap_p = _bucket_cap(int(cm[:, 0].max()))
 
@@ -1799,9 +1795,9 @@ def distributed_set_op(left: Table, right: Table, op: _setops.SetOp,
     rval = tuple(shard.pin(c.valid_mask(), ctx) for c in rcols_s)
 
     with _phase("distributed_set_op.count", seq):
-        counts = np.asarray(jax.device_get(_setop_count_fn(ctx.mesh)(
-            lkb, lemit, rkb, remit))).reshape(world, 3)
-        _host_sync("setop.count")
+        counts = np.asarray(_host_fetch(
+            "setop.count", _setop_count_fn(ctx.mesh)(
+                lkb, lemit, rkb, remit))).reshape(world, 3)
     total = counts[:, int(op)]
     cap = _bucket_cap(int(total.max()))
 
@@ -1815,11 +1811,10 @@ def distributed_set_op(left: Table, right: Table, op: _setops.SetOp,
     for ci, (d, v, a) in enumerate(zip(od, ov, lcols_s)):
         if a.is_varbytes:
             bvb = rcols_s[ci].varbytes
-            wcounts = np.asarray(jax.device_get(
-                _varlen_take_concat_count_fn(ctx.mesh)(
+            wcounts = np.asarray(_host_fetch(
+                "varlen.count", _varlen_take_concat_count_fn(ctx.mesh)(
                     shard.pin(a.varbytes.lengths, ctx),
                     shard.pin(bvb.lengths, ctx), idx)))
-            _host_sync("varlen.count")
             cap_w = _bucket_cap(int(wcounts.max()))
             w, s, ln = _varlen_take_concat_fn(ctx.mesh, cap_w)(
                 shard.pin(a.varbytes.words, ctx),
@@ -2117,8 +2112,7 @@ def _range_splitters(ctx: CylonContext, lanes, emit):
     packed = jnp.stack(
         [jnp.take(l, pos).astype(wide) for l in lanes]
         + [jnp.take(emit, pos).astype(wide)])
-    host = np.asarray(jax.device_get(packed))
-    _host_sync("sort.splitters")
+    host = np.asarray(_host_fetch("sort.splitters", packed))
     live = host[-1].astype(bool)
     samples = [host[i].astype(l.dtype)[live]
                for i, l in enumerate(lanes)]

@@ -27,7 +27,7 @@ from ..context import CylonContext
 from ..data.column import Column
 from ..data.table import Table
 from ..status import Code, CylonPlanError
-from ..telemetry import record_host_sync as _host_sync
+from ..telemetry import host_fetch as _host_fetch
 
 # Per-shard capacities are rounded to a multiple of 8 (TPU sublane quantum)
 _ROW_QUANTUM = 8
@@ -127,10 +127,8 @@ def _distribute_varbytes(c: Column, n: int, cap: int, world: int,
     # one device_get + numpy slicing + one device_put: each shard's rows
     # are a CONTIGUOUS row range, so its words are a contiguous slice of
     # the source buffer (monotone starts) — no per-shard device gathers
-    words_h = np.asarray(jax.device_get(vb.words))
-    starts_h = np.asarray(jax.device_get(vb.eff_starts()))
-    lens_h = np.asarray(jax.device_get(vb.lengths))
-    _host_sync("distribute.varbytes", 3)
+    words_h, starts_h, lens_h = (np.asarray(a) for a in _host_fetch(
+        "distribute.varbytes", (vb.words, vb.eff_starts(), vb.lengths)))
     nw_h = (lens_h.astype(np.int64) + 3) // 4
     slices = []
     for s in range(world):
@@ -201,18 +199,14 @@ def host_partition_arrays(t: Table, idxs, world: int):
     from .. import native as _native
     from ..dtypes import Type
 
-    host = []
-    for c in t._columns:
-        if c.is_varbytes:
-            host.append(c.varbytes.to_host(
-                as_str=c.dtype.type != Type.BINARY))
-        else:
-            host.append(np.asarray(jax.device_get(c.data)))
-    valids = [None if c.validity is None
-              else np.asarray(jax.device_get(c.valid_mask()))
-              for c in t._columns]
-    _host_sync("ingest.host_partition",
-               len(host) + sum(v is not None for v in valids))
+    data_h, valids = _host_fetch("ingest.host_partition", (
+        [None if c.is_varbytes else c.data for c in t._columns],
+        [None if c.validity is None else c.valid_mask()
+         for c in t._columns]))
+    host = [c.varbytes.to_host(as_str=c.dtype.type != Type.BINARY)
+            if c.is_varbytes else np.asarray(d)
+            for c, d in zip(t._columns, data_h)]
+    valids = [None if v is None else np.asarray(v) for v in valids]
     keys = []
     pre = []
     for i in idxs:

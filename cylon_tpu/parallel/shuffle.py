@@ -50,7 +50,7 @@ from ..resilience import inject as _inject
 from ..resilience import retry as _retry
 from ..telemetry import REGISTRY as _REGISTRY
 from ..telemetry import counted_cache, counter as _counter, \
-    phase as _phase, record_host_sync as _host_sync, span as _span
+    host_fetch as _host_fetch, phase as _phase, span as _span
 from ..telemetry import knobs as _knobs
 from ..telemetry import skew as _skew
 from ..util import pow2 as _pow2, pow2_floor as _pow2_floor
@@ -943,8 +943,7 @@ def salted_exchange_targets(targets, emit, ctx: CylonContext,
     def compute():
         t2, both = _salted_targets_fn(ctx.mesh, salt)(
             targets, emit, jnp.float32(warn_factor))
-        host = np.asarray(jax.device_get(both))
-        _host_sync("shuffle.salt")
+        host = np.asarray(_host_fetch("shuffle.salt", both))
         _counter("cylon_collective_launches_total").inc()
         return t2, host[0], host[1]
 
@@ -989,9 +988,9 @@ def count_pair(targets1, emit1, targets2, emit2, ctx: CylonContext):
         # result is [src, 2, dst] (replicated_gather stacks per source)
         with _span("shuffle.count", ctx.get_next_sequence(),
                    world=ctx.get_world_size(), tables=2):
-            both = np.asarray(jax.device_get(
+            both = np.asarray(_host_fetch(
+                "shuffle.count_pair",
                 _count2_fn(ctx.mesh)(targets1, emit1, targets2, emit2)))
-        _host_sync("shuffle.count_pair")
         _counter("cylon_collective_launches_total").inc()
         return both[:, 0, :], both[:, 1, :]
 
@@ -1104,9 +1103,8 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
     if counts is None:
         def compute():
             with _span("shuffle.count", seq, world=world, tables=1):
-                res = np.asarray(jax.device_get(
-                    _count_fn(ctx.mesh)(targets, emit)))
-            _host_sync("shuffle.count")
+                res = np.asarray(_host_fetch(
+                    "shuffle.count", _count_fn(ctx.mesh)(targets, emit)))
             _counter("cylon_collective_launches_total").inc()
             return res
 

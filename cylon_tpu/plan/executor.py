@@ -44,6 +44,8 @@ from __future__ import annotations
 import time
 from typing import Optional, Tuple
 
+import jax
+
 from .. import table_api, telemetry
 from ..data import table as table_mod
 from ..data.table import Table
@@ -251,6 +253,10 @@ class _Recorder:
         rec.i0 = len(self._labels)
         t0 = time.perf_counter()
         out = fn(node)
+        # the node's time ends when the DEVICE has finished its output
+        # (dispatch alone reads 2 ms for a 317 ms join); analyze mode
+        # waits for the node at row_count below anyway
+        jax.block_until_ready(out.buffers())
         rec.ms = (time.perf_counter() - t0) * 1e3
         rec.i1 = len(self._labels)
         # row_count syncs ONE scalar per node — the analyze-mode cost

@@ -49,9 +49,8 @@ supports.
 
 Metrics: ``cylon_plan_cache_{hits,misses,evictions}_total``. Because a
 hit re-fires the same lowerings, the same ``counted_cache`` kernel
-factories re-hit their memo — the PR-4 profiler's
-``cylon_kernel_compile_seconds`` shows exactly which compilations the
-cache amortizes.
+factories re-hit their memo — ``cylon_jit_seconds_total`` stays flat
+across the compilations the cache amortizes.
 
 Library-mode wiring: :func:`install` registers :func:`memo_optimize`
 as ``plan.lazy``'s late-bound optimize hook (the same leaf-hook

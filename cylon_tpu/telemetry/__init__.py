@@ -22,9 +22,10 @@ and grows it into a measurement layer:
   count matrices the host already fetches (zero extra syncs): per-shard
   send/recv rows+bytes histograms, imbalance factor, EXPLAIN ANALYZE
   warning threshold.
-* ``profiler`` — opt-in kernel compile-cost capture hooked into
-  ``counted_cache``: compile wall time + XLA cost analysis per factory
-  program (``cylon_kernel_compile_seconds{factory=...}``).
+* ``profiler`` — always-on ``jax.monitoring`` listener: trace, lower,
+  compile and cache-load seconds of every jitted program, by stage and
+  by the span they were spent under
+  (``cylon_jit_seconds_total{stage=,phase=}``).
 * ``ledger``  — buffer lifetime ledger: materializing ops register
   alloc/free events with owner labels
   (``cylon_live_table_bytes{owner=...}``), per-span HBM deltas ride
@@ -66,13 +67,13 @@ its own submodules, and its underscore names are module-private
 from __future__ import annotations
 
 from .spans import (Span, annotate, collect_phases, current_span,
-                    log_to_stderr, logger, phase, root_attrs, span,
-                    add_sink, remove_sink, add_root_hook,
+                    host_fetch, log_to_stderr, logger, phase, root_attrs,
+                    span, add_sink, remove_sink, add_root_hook,
                     remove_root_hook)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       REGISTRY, counted_cache, counter, gauge, histogram,
-                      metrics_snapshot, record_host_sync, reset_metrics,
-                      sample_memory, set_memory_pool, get_memory_pool)
+                      metrics_snapshot, reset_metrics, sample_memory,
+                      set_memory_pool, get_memory_pool)
 from .export import JsonlSpanSink, prometheus_text, span_to_json
 from . import knobs, ledger, profiler, sampling, skew
 from . import flight
@@ -82,14 +83,13 @@ from .skew import SkewStats
 
 __all__ = [
     # spans
-    "Span", "annotate", "collect_phases", "current_span", "log_to_stderr",
-    "logger", "phase", "root_attrs", "span", "add_sink", "remove_sink",
-    "add_root_hook", "remove_root_hook",
+    "Span", "annotate", "collect_phases", "current_span", "host_fetch",
+    "log_to_stderr", "logger", "phase", "root_attrs", "span", "add_sink",
+    "remove_sink", "add_root_hook", "remove_root_hook",
     # metrics
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "counted_cache", "counter", "gauge", "histogram", "metrics_snapshot",
-    "record_host_sync", "reset_metrics", "sample_memory",
-    "set_memory_pool", "get_memory_pool",
+    "reset_metrics", "sample_memory", "set_memory_pool", "get_memory_pool",
     # exporters
     "JsonlSpanSink", "prometheus_text", "span_to_json",
     # skew + compile-cost + memory-lifetime + failure observability

@@ -258,12 +258,15 @@ def _on_root_close(root) -> None:
         # dump BEFORE ring insertion so recent_queries lists the
         # queries that PRECEDED the failure
         write_crash_dump(root)
-    if root.name in ("plan.preflight", "plan.admission"):
+    if root.name in ("plan.preflight", "plan.admission") \
+            or root.name.startswith("sync."):
         # the default execute() path emits these warning/decision
-        # markers as parentless spans; they are not query trees —
-        # letting them into the ring would evict the real query
-        # history the forensics depend on (admission decisions have
-        # their own ring: record_admission)
+        # markers as parentless spans, and a host fetch outside any
+        # operator (``Table.row_count`` on a result) is a parentless
+        # ``sync.*`` span; they are not query trees — letting them
+        # into the ring would evict the real query history the
+        # forensics depend on (admission decisions have their own
+        # ring: record_admission)
         return
     _ring.append(root)
 

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import threading
+import types
 from typing import Callable, Dict, Optional, Tuple
 
 LabelKey = Tuple[Tuple[str, str], ...]
@@ -268,18 +269,6 @@ def observe_phase(name: str, elapsed_ms: float, error: bool = False
                          {"phase": name}).inc()
 
 
-def record_host_sync(site: str, n: int = 1) -> None:
-    """One device→host round trip at a named choke point — feeds
-    ``cylon_host_syncs_total{site=...}``. The sites are the
-    ``jax.device_get`` calls the hostsync analysis already classifies
-    as host-side-legal (count fetches, splitter samples, plan-capacity
-    reads); this counter makes the round trips per query VISIBLE (each
-    one stalls the dispatch pipeline). ``site`` labels must be
-    static strings at the call site — label cardinality is the fixed
-    set of choke points, never data."""
-    REGISTRY.counter("cylon_host_syncs_total", {"site": site}).inc(n)
-
-
 # Process-global MemoryPool handle (duck-typed — telemetry never
 # imports memory.py): CylonContext registers its pool here so the span
 # layer can sample per-span HBM deltas and the flight recorder can dump
@@ -297,30 +286,39 @@ def get_memory_pool():
     return _memory_pool
 
 
-# Build hook for the compile-cost profiler (telemetry/profiler.py):
-# when installed, every counted_cache factory build passes its result
-# through ``hook(factory_name, built)`` so the profiler can wrap the
-# jitted program with compile-time capture. Kept as a late-bound module
-# attribute so metrics (a leaf of the leaf) never imports profiler.
-_factory_build_hook: Optional[Callable] = None
-
 # Fault hook for the chaos injector (resilience/inject.py): when
 # installed, ``hook(factory_name)`` runs BEFORE each counted_cache
 # build and may raise a typed error — the deterministic stand-in for a
 # compile OOM. lru_cache never caches exceptions, so a faulted build
-# rebuilds cleanly on retry. Duck-typed like the build hook: telemetry
-# stays a base-layer leaf and never imports resilience.
+# rebuilds cleanly on retry. Duck-typed: telemetry stays a base-layer
+# leaf and never imports resilience.
 _factory_fault_hook: Optional[Callable] = None
-
-
-def set_factory_build_hook(hook: Optional[Callable]) -> None:
-    global _factory_build_hook
-    _factory_build_hook = hook
 
 
 def set_factory_fault_hook(hook: Optional[Callable]) -> None:
     global _factory_fault_hook
     _factory_fault_hook = hook
+
+
+def program_name(factory: str) -> str:
+    """``_join_plan_stream_fn`` -> ``join_plan_stream``: what the
+    program of a ``counted_cache`` factory is called, so a profiler
+    trace reads ``jit_join_plan_stream(<hash>)`` on ``XLA Modules``."""
+    name = factory.lstrip("_")
+    return name[:-3] if name.endswith("_fn") else name
+
+
+def _name_program(built, name: str) -> None:
+    """Name a freshly built jit program: ``jax.jit`` reads the wrapped
+    function's ``__name__`` at its first trace, and every factory jits
+    a local closure called ``kernel`` (``jit_kernel`` on all of them,
+    ``shard_map.<n>`` for what runs inside). Only a function created
+    by this build (``<locals>`` in its qualname) is renamed — a shared
+    module-level function keeps its own name."""
+    inner = getattr(built, "__wrapped__", None)
+    if isinstance(inner, types.FunctionType) \
+            and "<locals>" in inner.__qualname__:
+        inner.__name__ = inner.__qualname__ = name
 
 
 def counted_cache(fn: Callable) -> Callable:
@@ -329,9 +327,12 @@ def counted_cache(fn: Callable) -> Callable:
     builds (and on first call compiles) a new XLA program, so
     ``cylon_kernel_factory_builds_total{factory=...}`` IS the
     jit-recompile counter: a hot loop that grows it is paying
-    compilation, not compute."""
+    compilation, not compute. The program a factory returns is named
+    after the factory (``program_name``) — here, once, not at each of
+    the factories."""
     c = REGISTRY.counter("cylon_kernel_factory_builds_total",
                          {"factory": fn.__name__})
+    name = program_name(fn.__name__)
 
     def _build(*args, **kwargs):
         fault = _factory_fault_hook
@@ -339,9 +340,7 @@ def counted_cache(fn: Callable) -> Callable:
             fault(fn.__name__)  # chaos: may raise an injected error
         c.inc()
         out = fn(*args, **kwargs)
-        hook = _factory_build_hook
-        if hook is not None:
-            out = hook(fn.__name__, out)
+        _name_program(out, name)
         return out
 
     cached = functools.lru_cache(maxsize=None)(_build)

@@ -160,7 +160,7 @@ class Span:
 
 def current_span() -> Optional[Span]:
     """The innermost open span of this context, or None."""
-    return _current.get()
+    return _current.get()  # cylint: disable=concurrency/unstamped-contextvar — None on a thread with no open span IS the answer (the jit listener bills such seconds to phase="none")
 
 
 def annotate(**attrs) -> None:
@@ -362,6 +362,26 @@ def span(name: str, seq: Optional[int] = None, **attrs) -> Iterator[Span]:
         if logger.isEnabledFor(logging.INFO):
             logger.info("%s %.3f ms%s", label, s.elapsed_ms,
                         " error=True" if s.error else "")
+
+
+def host_fetch(site: str, x):
+    """``jax.device_get(x)`` at a named choke point — THE way the host
+    reads a value that decides what it dispatches next (a count, a
+    capacity, a flag, splitters; ``x`` may be a pytree). The blocking
+    call runs inside a ``sync.<site>`` span, so a profiler trace shows
+    ``cylon:sync.<site>`` nested in the operator's span on the device
+    planes' clock: the chip's idle time INSIDE it is what the round
+    trip costs. Each call adds 1 to ``cylon_host_syncs_total{site=}``
+    (before it blocks: what comes after the fetch returns is on the
+    critical path of the next dispatch).
+    ``site`` is a static string at the call site (label cardinality is
+    the fixed set of choke points, never data). Bulk export of a
+    finished result (``to_numpy``, csv, output gathers) decides nothing
+    and stays a plain ``device_get``."""
+    with span("sync." + site):
+        _metrics.REGISTRY.counter("cylon_host_syncs_total",
+                                  {"site": site}).inc()
+        return jax.device_get(x)
 
 
 def phase(name: str, seq: Optional[int] = None):

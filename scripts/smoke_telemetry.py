@@ -14,8 +14,8 @@ verifies the observability layer end to end:
   ``cylon_shuffle_bytes_total`` (the exchange counters are wired, not
   decorative), the per-shard shuffle histograms
   (``cylon_shuffle_shard_rows`` / ``_shard_bytes``), host-sync
-  counters, and ``cylon_kernel_compile_seconds`` from the enabled
-  compile-cost profiler;
+  counters, and ``cylon_jit_seconds_total`` from the always-on
+  ``jax.monitoring`` listener (telemetry/profiler.py);
 * ``explain(analyze=True)`` renders per-node measured rows, its
   reported shuffle count equals ``collect_phases.count("plan.shuffle")``,
   its exchange-bearing nodes render ``skew(...)`` columns, and every
@@ -61,9 +61,6 @@ def main() -> None:
     from cylon_tpu import plan, telemetry
     from cylon_tpu.telemetry import profiler
 
-    # compile-cost capture must be on BEFORE the first kernel factory
-    # builds (the lru memo would otherwise keep unwrapped programs)
-    profiler.enable()
     ctx = ct.CylonContext.InitDistributed(ct.TPUConfig(world_size=4))
     rng = np.random.default_rng(0)
     n = 4096
@@ -160,14 +157,14 @@ def main() -> None:
     for series in ("cylon_shuffle_shard_rows_bucket",
                    "cylon_shuffle_shard_bytes_bucket",
                    "cylon_shuffle_imbalance_factor_bucket",
-                   "cylon_kernel_compile_seconds_bucket",
+                   "cylon_jit_seconds_total",
                    "cylon_host_syncs_total",
                    "cylon_live_table_bytes"):
         if series not in prom:
             fail(f"{series} missing from Prometheus dump")
-    n_compiles = len(profiler.records())
+    n_compiles = profiler.summary()["compile"]["events"]
     if n_compiles == 0:
-        fail("compile-cost profiler recorded no programs")
+        fail("the jax.monitoring listener counted no compile")
 
     # -- flight recorder: a failing query leaves a crash dump ---------
     dump = crash_dump_smoke(ct, plan, left)

@@ -47,3 +47,24 @@ def _partition_rogue_fn(mesh, block, part):  # SEEDED: collectives/uncataloged-f
 
 def _bcast_rogue_fn(mesh, join_type):  # SEEDED: collectives/uncataloged-factory (broadcast-path control)
     return mesh
+
+
+def _count_bare(x):
+    import jax
+
+    def fetch():
+        return jax.device_get(x)   # SEEDED: hostsync/bare-fetch (once)
+    return fetch()
+
+
+def _gather_for_output(x):  # declared in the test's bulk_exports: clean
+    import jax
+
+    return jax.device_get(x)
+
+
+def _count_choked(x, site):
+    from ..telemetry import host_fetch as _host_fetch
+
+    n = _host_fetch("fixture.count", x)    # literal site: clean
+    return n + _host_fetch(site, x)  # SEEDED: span-coverage/dynamic-sync-site

@@ -81,6 +81,9 @@ def test_spancov_fixture_reports_exactly_seeded():
     assert got == {
         ("parallel/dist_ops.py", 14, "span-coverage/missing-span"),
         ("plan/executor.py", 12, "span-coverage/missing-span"),
+        # host_fetch(site, x) with a computed site; the literal site
+        # one line above it stays clean
+        ("parallel/dist_ops.py", 70, "span-coverage/dynamic-sync-site"),
     }, res.format_text()
     # private helpers / non-distributed_* / non-_do_* stay out of scope
     msgs = " ".join(f.message for f in res.findings)
@@ -185,10 +188,25 @@ def test_hostsync_fixture_reports_exactly_seeded():
         ("ops/bad_kernel.py", 12, "hostsync/transfer"),
         ("ops/bad_kernel.py", 20, "hostsync/transfer"),
         ("ops/bad_kernel.py", 25, "hostsync/transfer"),
+        # host-side device_get outside the choke point, in a file of
+        # the fetch scope (the nested def reports once)
+        ("parallel/dist_ops.py", 56, "hostsync/bare-fetch"),
+        ("parallel/dist_ops.py", 63, "hostsync/bare-fetch"),
     }, res.format_text()
     # host_side_ok's transfers are OUTSIDE any traced closure: none of
     # its lines (29+) may appear
-    assert not any(f.line >= 28 for f in res.findings)
+    assert not any(f.path == "ops/bad_kernel.py" and f.line >= 28
+                   for f in res.findings)
+
+
+def test_hostsync_bare_fetch_honours_declared_bulk_exports():
+    """A function declared as a bulk mover of whole columns keeps its
+    plain device_get; the undeclared one beside it stays flagged."""
+    ctx = AnalysisContext(PKG_BAD, options={"bulk_exports": {
+        ("parallel/dist_ops.py", "_gather_for_output")}})
+    res = run_checkers(ctx, families=["hostsync"])
+    got = {f.line for f in res.findings if f.rule == "hostsync/bare-fetch"}
+    assert got == {56}, res.format_text()
 
 
 def test_hostsync_real_tree_clean():

@@ -1,7 +1,8 @@
 """Performance-observatory tests: shuffle skew metrics (span attrs,
-registry histograms, EXPLAIN ANALYZE columns), the kernel compile-cost
-profiler (incl. graceful degradation when the backend hides
-cost_analysis), the host-sync counter, and bench timer precision."""
+registry histograms, EXPLAIN ANALYZE columns), the host-fetch choke point
+(one counted, spanned sync per decision fetch), the jax.monitoring
+listener (trace/lower/compile/cache-load seconds by stage and span), and
+bench timer precision."""
 import numpy as np
 import pytest
 
@@ -220,138 +221,264 @@ def test_host_sync_counter_pair_and_plan(dist_ctx):
 
 
 # ---------------------------------------------------------------------------
-# compile-cost profiler
+# the host-fetch choke point (telemetry.host_fetch): every fetch that
+# decides the next dispatch is one sync.<site> span and one count
 # ---------------------------------------------------------------------------
 
 
-def test_profiler_measures_counted_cache_builds(local_ctx):
+def _syncs():
+    from cylon_tpu import telemetry
+
+    return sum(v for k, v in telemetry.metrics_snapshot().items()
+               if k.startswith("cylon_host_syncs_total"))
+
+
+def _fresh_pair(ctx, seed, n=2048):
+    import cylon_tpu as ct
+
+    rng = np.random.default_rng(seed)
+    left = ct.Table.from_pydict(ctx, {
+        "k": rng.permutation(n).astype(np.int32),
+        "v": rng.normal(size=n).astype(np.float32)})
+    right = ct.Table.from_pydict(ctx, {
+        "k": rng.integers(0, n, n).astype(np.int32),
+        "w": rng.normal(size=n).astype(np.float32)})
+    return left, right
+
+
+def _planned(kind, left, right):
+    from cylon_tpu import plan
+
+    if kind == "join":
+        return plan.scan(left).join(plan.scan(right), on="k")
+    return plan.scan(right).groupby("k", ["w"], ["sum"])
+
+
+@pytest.mark.parametrize("kind,site,operator", [
+    ("join", "sync.join.count", "join.plan"),
+    ("groupby", "sync.groupby.groups", "plan.groupby"),
+])
+def test_planned_local_operator_is_one_counted_spanned_sync(
+        local_ctx, kind, site, operator):
+    """A planned local join / groupby on fresh inputs: the counter grows
+    by exactly 1 and exactly one ``sync.*`` span opens, as a child of
+    the operator's span (what the benchmark's ``host_syncs_per_query``
+    and ``sync_idle_ms_per_query`` read in a trace)."""
+    from cylon_tpu import telemetry
+
+    pipe = _planned(kind, *_fresh_pair(local_ctx, 31))
+    s0 = _syncs()
+    with telemetry.collect_phases() as cp:
+        out = pipe.execute()
+    assert _syncs() == s0 + 1
+    sync_spans = [sp for sp in cp.spans if sp.name.startswith("sync.")]
+    assert [sp.name for sp in sync_spans] == [site]
+    parent = [sp for sp in cp.spans
+              if sp.span_id == sync_spans[0].parent_id]
+    assert [sp.name for sp in parent] == [operator]
+    assert sync_spans[0].elapsed_ms is not None
+    assert out.row_count > 0
+
+
+def test_repeat_join_on_the_same_buffers_counts_no_sync(local_ctx):
+    """The count memo answers a repeat join of the same arrays: no
+    fetch, so no count and no span (the fetch sits INSIDE the memo's
+    compute)."""
+    from cylon_tpu import telemetry
+
+    left, right = _fresh_pair(local_ctx, 32)
+    _planned("join", left, right).execute()
+    s0 = _syncs()
+    with telemetry.collect_phases() as cp:
+        _planned("join", left, right).execute()
+    assert _syncs() == s0
+    assert cp.count("sync.") == 0
+
+
+def test_row_count_of_a_masked_table_is_one_counted_sync(local_ctx):
+    from cylon_tpu import telemetry
+    from cylon_tpu.telemetry import flight
+
+    def site():
+        return telemetry.metrics_snapshot().get(
+            'cylon_host_syncs_total{site="row_count"}', 0)
+
+    left, _right = _fresh_pair(local_ctx, 33)
+    t = left.filter_mask(left.get_column(0).data % 2 == 0)
+    ring0 = [sp.span_id for sp in flight.recent()]
+    s0 = site()
+    assert t.row_count == 1024
+    assert site() == s0 + 1
+    assert t.row_count == 1024       # cached: no second fetch
+    assert site() == s0 + 1
+    # a parentless sync span is no query tree: the flight ring keeps
+    # its query history
+    assert [sp.span_id for sp in flight.recent()] == ring0
+
+
+def test_host_fetch_spans_the_blocking_call_and_counts_once():
+    import jax.numpy as jnp
+
+    from cylon_tpu import telemetry
+
+    key = 'cylon_host_syncs_total{site="probe.fetch"}'
+    c0 = telemetry.metrics_snapshot().get(key, 0)
+    with telemetry.collect_phases() as cp, \
+            telemetry.span("probe.operator") as op:
+        got = telemetry.host_fetch(
+            "probe.fetch", (jnp.arange(4), {"n": jnp.int32(7)}, None))
+    # a pytree comes back as host values, in one round trip
+    assert isinstance(got[0], np.ndarray) and got[0].tolist() == [0, 1, 2, 3]
+    assert int(got[1]["n"]) == 7 and got[2] is None
+    assert telemetry.metrics_snapshot()[key] == c0 + 1
+    assert cp.labels == ["probe.operator", "sync.probe.fetch"]
+    assert cp.spans[1].parent_id == op.span_id
+    assert op.children == [cp.spans[1]]
+
+
+# ---------------------------------------------------------------------------
+# trace / lower / compile / cache-load seconds (telemetry/profiler.py:
+# the always-on jax.monitoring listener)
+# ---------------------------------------------------------------------------
+
+
+def _jit_seconds(phase):
+    from cylon_tpu import telemetry
+
+    snap = telemetry.metrics_snapshot()
+    return {st: snap.get(
+        f'cylon_jit_seconds_total{{phase="{phase}",stage="{st}"}}', 0.0)
+        for st in ("trace", "lower", "compile", "cache_load")}
+
+
+def _jit_events():
+    from cylon_tpu import telemetry
+
+    snap = telemetry.metrics_snapshot()
+    return {st: snap.get(f'cylon_jit_events_total{{stage="{st}"}}', 0)
+            for st in ("trace", "lower", "compile", "cache_load")}
+
+
+def test_listener_bills_a_first_call_to_the_open_span_and_no_second():
     import jax
     import jax.numpy as jnp
 
     from cylon_tpu import telemetry
-    from cylon_tpu.telemetry import counted_cache, profiler
 
-    profiler.enable()
-    try:
-        @counted_cache
-        def _observatory_probe_fn(scale):
-            return jax.jit(lambda x: x * scale)
+    @jax.jit
+    def _listener_probe(x):
+        return jnp.cumsum(x * 3) + 1
 
-        f = _observatory_probe_fn(3)
-        x = jnp.arange(8.0)
-        np.testing.assert_allclose(np.asarray(f(x)), np.arange(8.0) * 3)
-        f(x)  # repeat signature: cached executable, no re-measure
-        recs = [r for r in profiler.records()
-                if r["factory"] == "_observatory_probe_fn"]
-        assert len(recs) == 1
-        assert recs[0]["compile_s"] > 0
-        snap = telemetry.metrics_snapshot()
-        key = 'cylon_kernel_compile_seconds{factory="_observatory_probe_fn"}'
-        assert snap[key]["count"] == 1
-        # a NEW signature compiles (and measures) a second program
-        np.testing.assert_allclose(np.asarray(f(jnp.arange(16.0))),
-                                   np.arange(16.0) * 3)
-        assert telemetry.metrics_snapshot()[key]["count"] == 2
-        s = profiler.summary()["_observatory_probe_fn"]
-        assert s["programs"] == 2 and s["compile_s"] > 0
-    finally:
-        profiler.disable()
+    x = jnp.arange(32.0)
+    e0 = _jit_events()
+    with telemetry.span("probe.first_call"):
+        _listener_probe(x).block_until_ready()
+    first = _jit_seconds("probe.first_call")
+    assert first["trace"] > 0 and first["lower"] > 0
+    assert first["compile"] > 0
+    e1 = _jit_events()
+    for st in ("trace", "lower", "compile"):
+        assert e1[st] > e0[st]
+    # the same shapes again: no trace, no lowering, no compile
+    with telemetry.span("probe.second_call"):
+        _listener_probe(x).block_until_ready()
+    assert _jit_seconds("probe.second_call") == {
+        "trace": 0.0, "lower": 0.0, "compile": 0.0, "cache_load": 0.0}
+    assert _jit_events() == e1
+    # outside any span the seconds go to phase="none"
+    n0 = _jit_seconds("none")
+    jax.jit(lambda v: v - 2)(x).block_until_ready()
+    assert _jit_seconds("none")["compile"] > n0["compile"]
 
 
-def test_profiler_graceful_when_cost_analysis_unavailable():
-    """The CPU-degradation contract: a backend whose Compiled raises
-    from (or garbles) cost_analysis still yields compile seconds, with
-    flops/bytes None — never an error."""
+def test_listener_counts_self_time_once():
+    """Nested events (a helper traced inside an outer trace) and the
+    cache retrieval inside a backend compile are taken out of the
+    enclosing event's seconds: the stages add up to the wall time."""
+    from cylon_tpu import telemetry
     from cylon_tpu.telemetry import profiler
 
-    class _Raises:
-        def cost_analysis(self):
-            raise NotImplementedError("no cost analysis on this backend")
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    compile_ = "/jax/core/compile/backend_compile_duration"
+    t = 4e9   # later than any real event of this process
+    with telemetry.span("probe.nesting"):
+        profiler._on_time_span(trace, t + 1, t + 2, fun_name="inner")
+        profiler._on_time_span(trace, t + 3, t + 5, fun_name="inner2")
+        profiler._on_time_span(trace, t, t + 10, fun_name="outer")
+        # a sibling after it absorbs nothing
+        profiler._on_time_span(trace, t + 10, t + 11, fun_name="next")
+        profiler._on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        profiler._on_time_span(compile_, t + 11, t + 12, fun_name="outer")
+        # an event that is none of the stages is ignored
+        profiler._on_time_span("/jax/other", 0.0, 50.0)
+        profiler._on_duration("/jax/other_duration", 50.0)
+    del profiler._local.done[-3:]   # outer, next, compile: not real
+    got = _jit_seconds("probe.nesting")
+    assert got["trace"] == pytest.approx(1 + 2 + 7 + 1)
+    assert got["cache_load"] == pytest.approx(0.25)
+    assert got["compile"] == pytest.approx(0.75)
+    assert got["lower"] == 0.0
 
-    class _NotADict:
-        def cost_analysis(self):
-            return "unparseable"
 
-    class _ListForm:
-        def cost_analysis(self):
-            return [{"flops": 5.0, "bytes accessed": 12.0}]
+def test_profiler_summary_reads_the_counters_back():
+    import jax
+    import jax.numpy as jnp
 
-    class _Partial:
-        def cost_analysis(self):
-            return {"flops": 3.0}
-
-    assert profiler._cost_analysis(_Raises()) == (None, None)
-    assert profiler._cost_analysis(_NotADict()) == (None, None)
-    assert profiler._cost_analysis(_ListForm()) == (5.0, 12.0)
-    assert profiler._cost_analysis(_Partial()) == (3.0, None)
-
-
-def test_profiler_full_path_without_cost_analysis():
+    from cylon_tpu import telemetry
     from cylon_tpu.telemetry import profiler
 
-    class FakeCompiled:
-        def cost_analysis(self):
-            raise NotImplementedError
-
-        def __call__(self, x):
-            return x + 1
-
-    class FakeLowered:
-        def compile(self):
-            return FakeCompiled()
-
-    class FakeJit:
-        def __call__(self, x):  # pragma: no cover - fallback only
-            return x + 1
-
-        def lower(self, x):
-            return FakeLowered()
-
-    profiler.enable()
-    try:
-        p = profiler._ProfiledProgram("_fake_nocost_fn", FakeJit())
-        assert p(np.int32(1)) == 2
-        rec = [r for r in profiler.records()
-               if r["factory"] == "_fake_nocost_fn"][0]
-        assert rec["compile_s"] >= 0
-        assert rec["flops"] is None and rec["bytes_accessed"] is None
-    finally:
-        profiler.disable()
+    with telemetry.span("probe.summary"):
+        jax.jit(lambda v: v * 5 - 1)(jnp.arange(8.0)).block_until_ready()
+    s = profiler.summary()
+    assert set(s) == set(profiler.STAGES)
+    assert s["compile"]["events"] >= 1
+    assert s["compile"]["by_phase"]["probe.summary"] > 0
+    assert s["trace"]["seconds"] == pytest.approx(
+        sum(s["trace"]["by_phase"].values()))
 
 
-def test_profiler_falls_back_on_non_lowerable():
-    """Factories returning plain host callables (no .lower) pass
-    through untouched — profiling is additive, never a crash."""
-    from cylon_tpu.telemetry import profiler
-
-    profiler.enable()
-    try:
-        p = profiler._ProfiledProgram("_plain_fn", lambda x: x * 2)
-        assert p(np.float32(3.0)) == 6.0
-        # kwargs route straight to the wrapped callable too
-        pk = profiler._ProfiledProgram("_kw_fn", lambda **kw: kw["k"])
-        assert pk(k=41) == 41
-        assert not [r for r in profiler.records()
-                    if r["factory"] in ("_plain_fn", "_kw_fn")]
-    finally:
-        profiler.disable()
-
-
-def test_profiler_disabled_is_passthrough():
-    from cylon_tpu.telemetry import metrics as _metrics
-    from cylon_tpu.telemetry import profiler
-
-    profiler.disable()
-    assert _metrics._factory_build_hook is None
-    # hook uninstalled: counted_cache returns the bare build result
+def test_counted_cache_returns_the_bare_build_result():
+    """No proxy any more: a factory hands back what it built."""
     from cylon_tpu.telemetry import counted_cache
+
+    built = []
 
     @counted_cache
     def _bare_probe_fn():
-        return lambda: 41
+        built.append(lambda: 41)
+        return built[-1]
 
-    assert _bare_probe_fn()() == 41
-    assert not isinstance(_bare_probe_fn(), profiler._ProfiledProgram)
+    assert _bare_probe_fn() is built[0]
+    assert _bare_probe_fn()() == 41 and len(built) == 1
+
+
+def test_explain_analyze_node_time_waits_for_the_device(local_ctx,
+                                                        monkeypatch):
+    """A node's ``actual time`` is taken after block_until_ready on the
+    node's buffers, not at dispatch."""
+    import jax
+
+    from cylon_tpu.plan import executor
+
+    order = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(executor.jax, "block_until_ready",
+                        lambda x: (order.append("ready"), real(x))[1])
+    real_clock = executor.time.perf_counter
+
+    def clock():
+        order.append("clock")
+        return real_clock()
+
+    monkeypatch.setattr(executor.time, "perf_counter", clock)
+    pipe = _planned("join", *_fresh_pair(local_ctx, 34))
+    pipe.explain(analyze=True)
+    # for every recorded node: clock, ... , ready, clock
+    assert "ready" in order
+    for i, what in enumerate(order):
+        if what == "ready":
+            assert order[i + 1] == "clock"
 
 
 # ---------------------------------------------------------------------------
