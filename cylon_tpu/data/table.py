@@ -1376,17 +1376,22 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
     # None for all-valid columns: the mask never rides the sort
     valids = tuple(table._columns[i].validity for i in val_cols)
     # ONE fused sort groups rows contiguously (dead rows last); the
-    # n_groups fetch below is the op's single host sync, and every
-    # segment reduction then runs on SORTED ids — see
-    # ops/groupby.presort_groups (round-5 rework of the dense-rank +
-    # scatter-back path; the old gid scatter cost ~15-30 ns/element)
-    values_s, valids_s, emit_s, iota_s, gid_s, ng = \
+    # n_groups fetch below is the op's single host sync, and the reduce
+    # step then works on the sorted RUNS — see ops/groupby.presort_groups
+    # (round-5 rework of the dense-rank + scatter-back path; the old gid
+    # scatter cost ~15-30 ns/element)
+    values_s, valids_s, emit_s, iota_s, new_grp, ng = \
         _groupby.presort_groups_jit(tuple(keys), emit, values, valids)
     num_groups = max(int(_telemetry.host_fetch("groupby.groups", ng)), 1)
     cap = _pow2(num_groups)
 
+    # the path sorted_segment_aggregate will take, counted where the
+    # host can see it (the same pure function of backend and widths)
+    _telemetry.counter("cylon_groupby_reduce_path_total", {
+        "path": _groupby.reduce_path([v.dtype for v in values], ops,
+                                     emit.shape[0])}).inc()
     rep, group_valid, results = _groupby.sorted_segment_aggregate_jit(
-        gid_s, emit_s, iota_s, values_s, valids_s, cap, tuple(ops),
+        new_grp, emit_s, iota_s, values_s, valids_s, cap, tuple(ops),
         tuple(val_cols),
         tuple(table._columns[i].validity is None for i in val_cols))
 

@@ -19,6 +19,9 @@ that touch HBM sequentially and resolve indirection on-chip:
   into dense prefixes, writing element-exact output via row-aligned DMA
   with a write pointer and partial-row tail carried in SMEM/VMEM across
   the (sequential) TPU grid.
+- ``groupby_run_reduce`` — the groupby's reduce step over presorted
+  rows: running reductions that restart at every run start, the value
+  at each run's end compacted to the run's ordinal; no scatter.
 
 Storage convention: 1-D streams are reshaped (n/128, 128) so windows can
 be DMA'd at dynamic *row* offsets (Mosaic rejects arbitrary-offset 1-D
@@ -1135,10 +1138,239 @@ def partition_scatter(t_s: jnp.ndarray, streams: Sequence[jnp.ndarray],
 
 
 # ---------------------------------------------------------------------------
-# groupby_stream — streaming grouped aggregation
+# groupby_run_reduce — the groupby's reduce step over sorted runs
 # ---------------------------------------------------------------------------
-# A groupby_stream kernel (segmented-scan grouped aggregation) lived
-# here through rounds 2-3; it measured 10-11M rows/s vs the XLA segment
-# path's 13-19M on v5e and was removed per the round-3 review rather
-# than shipped as a slower parallel implementation (see git history).
+# After ops/groupby.presort_groups the rows are sorted by group, dead
+# rows last, and group g is the g-th run. A group's aggregate is then a
+# reduction over a CONTIGUOUS run and its slot is the run's ordinal —
+# what a stream compaction writes — so the reduce step needs no scatter:
+# one sequential pass keeps, per stream, a running reduction that
+# restarts at every run start, and compacts the value standing at each
+# run's last live row to slot g. (A segmented-scan groupby kernel lived
+# here through rounds 2-3 and was removed at 10-11M rows/s on a retired
+# set-up; this one is measured on the benchmark: PERF.md section 6,
+# PR 26.)
 # ---------------------------------------------------------------------------
+
+_RUN_OPS = {
+    "add": jnp.add,
+    "min": jnp.minimum,
+    "max": jnp.maximum,
+    "first": lambda head, later: head,
+}
+
+
+def _shift_down_one(x, head, interpret):
+    """(R,128) block shifted DOWN by one in flat order; element 0 takes
+    ``head`` (an (R,128) block: the value carried from the block
+    before)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    ra = _roll(x, 1, 1, interpret)
+    out = jnp.where(lane >= 1, ra, _roll(ra, 1, 0, interpret))
+    return jnp.where(flat_iota(x.shape) == 0, head, out)
+
+
+def _run_scan(start, vals, kinds, carries, interpret):
+    """Inclusive running reductions of (R,128) blocks in flat order that
+    RESTART where ``start`` (int32 0/1) holds: out[j] = vals[s..j]
+    reduced, s the last run start at or before j. Where the block holds
+    no start at or before j the run is still open from the block
+    before and its accumulator ``carries[k]`` (an (R,128) broadcast)
+    joins in.
+
+    Every combination is SELECTED, never masked by arithmetic: an
+    element only ever meets values and partials of its own run, so a
+    float sum is formed from the run's own float32 additions alone
+    (no prefix difference — the bound on a group's error is relative
+    to ITS Σ|x|), and no identity element is needed. Log-shift
+    structure of block_cumsum: lanes, then row totals."""
+    R = start.shape[0]
+    ops = [_RUN_OPS[k] for k in kinds]
+    zero = np.int32(0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, start.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, start.shape, 0)
+    g = start   # a run start lies in the window already reduced
+    vs = list(vals)
+    k = 1
+    while k < LANES:
+        ext = (lane >= k) & (g == zero)
+        vs = [jnp.where(ext, op(_roll(v, k, 1, interpret), v), v)
+              for op, v in zip(ops, vs)]
+        g = g | jnp.where(lane >= k, _roll(g, k, 1, interpret), zero)
+        k <<= 1
+    # row totals (the open tail of each row), scanned the same way
+    tg = jnp.broadcast_to(g[:, LANES - 1:LANES], (R, LANES))
+    ts = [jnp.broadcast_to(v[:, LANES - 1:LANES], (R, LANES)) for v in vs]
+    k = 1
+    while k < R:
+        ext = (row >= k) & (tg == zero)
+        ts = [jnp.where(ext, op(_roll(t, k, 0, interpret), t), t)
+              for op, t in zip(ops, ts)]
+        tg = tg | jnp.where(row >= k, _roll(tg, k, 0, interpret), zero)
+        k <<= 1
+    # what enters each row: the rows before it back to their last
+    # start, or — no start yet in this block — the carry joined on
+    has_prev = row >= 1
+    started = jnp.where(has_prev, _roll(tg, 1, 0, interpret), zero) != zero
+    outs = []
+    for op, v, t, c in zip(ops, vs, ts, carries):
+        pt = _roll(t, 1, 0, interpret)
+        cin = jnp.where(started, pt, jnp.where(has_prev, op(c, pt), c))
+        outs.append(jnp.where(g != zero, v, op(cin, v)))
+    return outs
+
+
+def groupby_run_reduce(new_grp: jnp.ndarray, emit: jnp.ndarray,
+                       streams: Sequence[jnp.ndarray],
+                       kinds: Sequence[str], num_segments: int,
+                       block_rows: int = 128, interpret: bool = False
+                       ) -> Tuple[Tuple[jnp.ndarray, ...], jnp.ndarray]:
+    """Reduce every run of the presorted rows into its slot — the
+    scatter-free replacement of the O(n) ``segment_*`` scatters of
+    ops/groupby.sorted_segment_aggregate (8.8 ns a row EACH on v5e
+    whatever the locality).
+
+    new_grp, emit: (n,) bool, rows sorted by group with live rows
+    (``emit``) a PREFIX and ``new_grp`` marking each live run's first
+    row. streams: (n,) int32/float32 arrays; kinds[k] in "add" / "min"
+    / "max" / "first" (the run's first element). What dead rows hold
+    is never read into a result.
+
+    ONE sequential pass: the running reductions restart at each run
+    start (_run_scan; a run longer than a block carries its open
+    accumulator to the next grid step), and the value standing at a
+    run's LAST live row is emitted one element later — at the next
+    run's start, or at the first dead row — which needs no look-ahead
+    across a block edge. The emitted elements are compacted onto slot
+    g = the run's ordinal (_compact_write). Elements at and past n
+    (the ragged last block; n itself is padded only to a multiple of
+    128, so a 128-multiple n is read in place) are made dead in the
+    kernel; a run still open at the very last element of the grid is
+    handed back as it stands and placed by one select here.
+
+    block_rows 128: at 1e8 rows in 1e6 runs, 4 streams, a v5e takes
+    73.1 / ~44 / 37.6 / 35.8 ms at 32 / 64 / 128 / 256 rows a block
+    (16.3 ms when no block ends a run: the scans and the reads), and
+    Mosaic compiles the body in 1.0 / 1.6 / 2.8 s at 64 / 128 / 256
+    (PERF.md section 6, PR 26).
+
+    Returns (outs, count): outs[k] is (num_segments,) in streams[k]'s
+    dtype, slot g < count holding run g's reduction; slots at and past
+    ``count`` (the number of runs, int32) are NOT defined — callers
+    mask by count. More runs than ``num_segments`` is a caller error:
+    the excess is dropped, never written out of bounds.
+    """
+    n = new_grp.shape[0]
+    K = len(streams)
+    BR = block_rows
+    assert BR % 8 == 0 and BR >= 8
+    assert K >= 1 and len(kinds) == K
+    for s in streams:
+        assert s.shape == (n,) and s.dtype in (jnp.int32, jnp.float32), \
+            f"groupby_run_reduce takes int32/float32 streams, got {s.dtype}"
+    span = BR * LANES
+    rows = rows_for(n)
+    blocks = -(-rows // BR)   # the last block may be ragged
+    fl2 = pad_rows(new_grp.astype(jnp.int32)
+                   | (emit.astype(jnp.int32) << np.int32(1)), rows)
+    s2 = [pad_rows(s, rows) for s in streams]
+    dtypes = [s.dtype for s in streams]
+
+    out_rows = rows_for(num_segments) + BR + 8  # the last write window
+    # tails rows: [0,K) the compaction's partial-row carries, [K,2K) each
+    # stream's open accumulator (last row of its scan), 2K the live flags
+    t_acc, t_live = K, 2 * K
+    fin_rows = -(-(K + 1) // 8) * 8
+    out_shapes = ([jax.ShapeDtypeStruct((out_rows, LANES), jnp.uint32)] * K
+                  + [jax.ShapeDtypeStruct((1,), jnp.int32),
+                     jax.ShapeDtypeStruct((fin_rows, LANES), jnp.uint32)])
+    scratch = ([pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((2 * K + 1, LANES), jnp.uint32)]
+               + [pltpu.VMEM((BR + 8, LANES), jnp.uint32) for _ in range(K)]
+               + [pltpu.SemaphoreType.DMA((K,))])
+
+    def kernel(fl_ref, *rest):
+        srefs = rest[:K]
+        outs = list(rest[K:2 * K])
+        cnt_ref, fin_ref = rest[2 * K], rest[2 * K + 1]
+        wptr = rest[2 * K + 2]
+        tails = rest[2 * K + 3]
+        bufs = list(rest[2 * K + 4:3 * K + 4])
+        sems = rest[3 * K + 4]
+        i = pl.program_id(0)
+
+        @pl.when(i == 0)
+        def _():
+            wptr[0] = jnp.int32(0)  # strong: see partition_scatter
+            tails[:] = jnp.zeros((2 * K + 1, LANES), jnp.uint32)
+
+        def carried(trow, dtype):
+            """Last element of the block before, broadcast (zeros at
+            i == 0, where nothing reads it: row 0 is a run start or
+            dead)."""
+            c = jnp.full((BR, LANES), tails[trow, LANES - 1], jnp.uint32)
+            return jax.lax.bitcast_convert_type(c, dtype)
+
+        one = np.int32(1)
+        inside = i * np.int32(span) + flat_iota((BR, LANES)) < np.int32(n)
+        fl = jnp.where(inside, fl_ref[:], np.int32(0))
+        start = fl & one
+        live = (fl >> one) & one
+        carries = [carried(t_acc + k, dtypes[k]) for k in range(K)]
+        acc = _run_scan(start, [r[:] for r in srefs], kinds, carries,
+                        interpret)
+        prev_live = _shift_down_one(live, carried(t_live, jnp.int32),
+                                    interpret)
+        # a run ended one element back: a new run starts here, or the
+        # live prefix is over
+        m = (start | (live ^ one)) & prev_live
+        vals = [jax.lax.bitcast_convert_type(
+            _shift_down_one(a, c, interpret), jnp.uint32)
+            for a, c in zip(acc, carries)]
+        for k in range(K):
+            tails[t_acc + k:t_acc + k + 1, :] = \
+                jax.lax.bitcast_convert_type(acc[k][BR - 1:BR, :],
+                                             jnp.uint32)
+        tails[t_live:t_live + 1, :] = jax.lax.bitcast_convert_type(
+            live[BR - 1:BR, :], jnp.uint32)
+
+        # a block inside one long run ends no run: no compaction, no DMA.
+        # The bound keeps every write window inside the outputs.
+        @pl.when((jnp.sum(m) > 0) & (wptr[0] <= np.int32(num_segments)))
+        def _():
+            _compact_write(BR, m, vals, outs, wptr, 0, tails, 0, bufs,
+                           sems, 0, interpret)
+
+        @pl.when(i == pl.num_programs(0) - 1)
+        def _():
+            cnt_ref[0] = wptr[0]
+            fin_ref[0:K + 1, :] = tails[t_acc:t_acc + K + 1, :]
+
+    res = pl.pallas_call(
+        kernel,
+        name="groupby_run_reduce",
+        out_shape=out_shapes,
+        grid=(blocks,),
+        in_specs=[pl.BlockSpec((BR, LANES), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM)] * (1 + K),
+        out_specs=([pl.BlockSpec(memory_space=pl.ANY)] * K
+                   + [pl.BlockSpec(memory_space=pltpu.SMEM),
+                      pl.BlockSpec((fin_rows, LANES), lambda i: (0, 0),
+                                   memory_space=pltpu.VMEM)]),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        interpret=interpret,
+    )
+    with _x32_trace():
+        res = res(fl2, *s2)
+    # the run still open at the grid's very last element (n fills the
+    # blocks exactly and row n-1 is live) was not flushed: it is run
+    # `count`, and its reductions are the accumulators as they stand
+    count, fin = res[K][0], res[K + 1][:, LANES - 1]
+    open_end = fin[K] != 0
+    slot = jnp.arange(num_segments, dtype=jnp.int32)
+    outs = tuple(
+        jnp.where(open_end & (slot == count), fin[k].view(s.dtype),
+                  o.reshape(-1)[:num_segments].view(s.dtype))
+        for k, (o, s) in enumerate(zip(res[:K], streams)))
+    return outs, count + open_end.astype(jnp.int32)

@@ -724,10 +724,10 @@ def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
     def kernel(kbits, kdat, kval, emit, vdat, vval):
         n = emit.shape[0]
         keys = tuple(kbits) + tuple(v.astype(jnp.uint8) for v in kval)
-        vdat_s, vval_s, emit_s, iota_s, gid_s, _ng = \
+        vdat_s, vval_s, emit_s, iota_s, new_grp, _ng = \
             _groupby.presort_groups(keys, emit, vdat, vval)
         rep, gvalid, results = _groupby.sorted_segment_aggregate(
-            gid_s, emit_s, iota_s, vdat_s, vval_s, n, ops, col_ids,
+            new_grp, emit_s, iota_s, vdat_s, vval_s, n, ops, col_ids,
             all_valid)
         safe = jnp.minimum(rep, n - 1)
         kout = tuple(jnp.take(d, safe, axis=0) for d in kdat)
@@ -737,6 +737,20 @@ def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
 
     return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 6,
                              out_specs=spec))
+
+
+def _aggregate_shards(ctx: CylonContext, ops, col_ids, all_valid, kbits,
+                      kdat, kval, emit, vdat, vval):
+    """One per-shard sort + reduce step (``_groupby_fn``), its reduce
+    path counted here, where the host can see it: the same pure function
+    of backend and accumulator widths that sorted_segment_aggregate
+    evaluates inside the program."""
+    _counter("cylon_groupby_reduce_path_total", {
+        "path": _groupby.reduce_path(
+            [v.dtype for v in vdat], ops,
+            emit.shape[0] // ctx.get_world_size())}).inc()
+    return _groupby_fn(ctx.mesh, ops, col_ids, all_valid)(
+        kbits, kdat, kval, emit, vdat, vval)
 
 
 # ---------------------------------------------------------------------------
@@ -1889,9 +1903,9 @@ def _groupby_shuffle_agg(ctx: CylonContext, key_columns, value_columns,
         if col_ids is None:
             col_ids = tuple(range(len(vcols_s)))
         all_valid = tuple(c.validity is None for c in vcols_s)
-        kout, kvout, gvalid, agg, safe = _groupby_fn(
-            ctx.mesh, ops, col_ids, all_valid)(
-            kbits, kdat, kval, emit_s, vdat, vval)
+        kout, kvout, gvalid, agg, safe = _aggregate_shards(
+            ctx, ops, col_ids, all_valid, kbits, kdat, kval, emit_s, vdat,
+            vval)
 
     key_out = []
     for d, v, kc in zip(kout, kvout, kcols_s):
@@ -1999,9 +2013,9 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
         cidsA = tuple((val_cols[j], cast) for j, _opA, cast in a_entries)
         avA = tuple(t._columns[val_cols[j]].validity is None
                     for j, _opA, _c in a_entries)
-        koutA, kvoutA, gvalidA, aggA, safeA = _groupby_fn(
-            ctx.mesh, opsA, cidsA, avA)(kbitsA, kdatA, kvalA, emit,
-                                        tuple(vdatA), tuple(vvalA))
+        koutA, kvoutA, gvalidA, aggA, safeA = _aggregate_shards(
+            ctx, opsA, cidsA, avA, kbitsA, kdatA, kvalA, emit,
+            tuple(vdatA), tuple(vvalA))
 
     pkey_cols = []
     for d, v, kc in zip(koutA, kvoutA, key_columns):

@@ -64,7 +64,7 @@ def main(log2n: int = 24, log2g: int = 20) -> dict:
 
     # phase 1b: the n_groups scalar fetch (the op's single host sync)
     state = _groupby.presort_groups_jit(keys, emit, values, valids)
-    vs, vm, emit_s, iota_s, gid_s, ng = state
+    vs, vm, emit_s, iota_s, new_grp, ng = state
 
     def ngroups_fetch():
         int(jax.device_get(ng))
@@ -74,7 +74,7 @@ def main(log2n: int = 24, log2g: int = 20) -> dict:
     # phase 2: the sorted segment reductions alone
     def aggregate():
         rep, gv, results = _groupby.sorted_segment_aggregate_jit(
-            gid_s, emit_s, iota_s, vs, vm, cap, ops, (1, 2, 1),
+            new_grp, emit_s, iota_s, vs, vm, cap, ops, (1, 2, 1),
             (True, True, True))
         sync(results[0][0])
     res["segment_agg_s"] = best_of(aggregate)

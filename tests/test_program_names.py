@@ -109,6 +109,10 @@ def _u32(n):
 
 
 KERNELS = {
+    "groupby_run_reduce": lambda: jax.make_jaxpr(
+        lambda g, s: tk.groupby_run_reduce(g, g, [s], ["add"], 64,
+                                           interpret=True))(
+            jnp.ones(4096, bool), jnp.arange(4096, dtype=jnp.int32)),
     "stream_compact": lambda: jax.make_jaxpr(
         lambda m, s: tk.stream_compact(m, [s], interpret=True))(
             jnp.ones(4096, bool), _u32(4096)),
@@ -128,6 +132,26 @@ KERNELS = {
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_pallas_call_carries_its_name(name):
     assert _pallas_names(KERNELS[name]().jaxpr, []) == [name]
+
+
+def test_groupby_reduce_keeps_its_program_and_names_its_kernel():
+    """What a traced ``groupby-q5`` reads: the reduce step's program is
+    still ``jit_sorted_segment_aggregate`` on ``XLA Modules`` (the
+    benchmark's groupby metrics match it) and its one kernel reads
+    ``groupby_run_reduce`` on ``XLA Ops``."""
+    from cylon_tpu.ops import groupby as _groupby
+
+    n = 4096
+    args = (_sds(jnp.bool_, n), _sds(jnp.bool_, n), _sds(jnp.int32, n),
+            (_sds(jnp.int32, n), _sds(jnp.float32, n)), (None, None))
+    kw = dict(num_segments=64, ops=(_groupby.AggregationOp.SUM,) * 2,
+              col_ids=(0, 1), all_valid=(True, True))
+    head = _groupby.sorted_segment_aggregate_jit.lower(
+        *args, **kw).as_text().split("\n", 1)[0]
+    assert head.startswith("module @jit_sorted_segment_aggregate "), head
+    closed = jax.make_jaxpr(lambda *a: _groupby.sorted_segment_aggregate(
+        *a, interpret=True, **kw))(*args)
+    assert _pallas_names(closed.jaxpr, []) == ["groupby_run_reduce"]
 
 
 def test_join_kernels_carry_their_names():

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from cylon_tpu.ops import groupby as _groupby
 from cylon_tpu.ops import join as _join
 from cylon_tpu.ops import tpu_kernels as tk
 
@@ -80,6 +81,27 @@ def test_stream_compact_compiles(chip):
                           _sds(chip, N, jnp.uint32),
                           _sds(chip, N, jnp.float32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n,num_segments", [
+    (100_000_000, 1 << 20),   # groupby-q5: 1e8 rows, 2^20 group slots
+    (N, N),                   # the distributed caller: num_segments = n
+], ids=["q5", "segments_eq_rows"])
+def test_groupby_stream_reduce_compiles(chip, monkeypatch, n, num_segments):
+    """The groupby's reduce step on its streaming path, as a TPU backend
+    chooses it (this process's backend is the CPU: the choice is steered
+    here), q5's three sums: int32, int32, float32."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    SUM = _groupby.AggregationOp.SUM
+    vals = (_sds(chip, n, jnp.int32), _sds(chip, n, jnp.int32),
+            _sds(chip, n, jnp.float32))
+    text = _compiled_text(
+        _groupby.sorted_segment_aggregate_jit, _sds(chip, n, jnp.bool_),
+        _sds(chip, n, jnp.bool_), _sds(chip, n, jnp.int32), vals,
+        (None,) * 3, num_segments=num_segments, ops=(SUM,) * 3,
+        col_ids=(0, 1, 2), all_valid=(True,) * 3)
+    assert text.count("tpu_custom_call") == 1   # ONE pass for all streams
+    assert "scatter" not in text
 
 
 def test_setop_stream_compiles(chip):
