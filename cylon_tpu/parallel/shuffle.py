@@ -735,7 +735,8 @@ def exchange_pair(payload1, targets1, emit1, counts1,
             rows = int(targets1.shape[0]) + int(targets2.shape[0])
             nbytes = _payload_nbytes(payload1) + _payload_nbytes(payload2)
             with _span("shuffle.exchange_pair", seq, world=1,
-                       mode="padded", rows=rows, bytes_moved=nbytes):
+                       mode="padded", rows=rows, bytes_moved=nbytes,
+                       block=max(b1, b2)):
                 res = _launch_exchange(
                     lambda: _exchange_padded_pair_fn(ctx.mesh, b1, b2)(
                         payload1, targets1, emit1, payload2, targets2,
@@ -771,15 +772,18 @@ def exchange_pair(payload1, targets1, emit1, counts1,
         # per-side histograms carry each table's own row width; the
         # span attributes carry the COMBINED per-destination totals
         # (what each shard actually absorbs from the fused program)
-        _skew.observe_exchange(counts1, _payload_row_bytes(payload1))
-        _skew.observe_exchange(counts2, _payload_row_bytes(payload2))
+        _skew.observe_exchange(counts1, _payload_row_bytes(payload1),
+                               world * world * b1)
+        _skew.observe_exchange(counts2, _payload_row_bytes(payload2),
+                               world * world * b2)
         pair_stats = _skew.SkewStats.from_counts(
             np.asarray(counts1) + np.asarray(counts2)) \
             if counts1 is not None and counts2 is not None else None
         part1 = _partition_path(ctx.mesh, world, payload1)
         part2 = _partition_path(ctx.mesh, world, payload2)
         with _span("shuffle.exchange_pair", seq, world=world,
-                   mode="padded", rows=rows, bytes_moved=nbytes) as sp:
+                   mode="padded", rows=rows, bytes_moved=nbytes,
+                   block=max(b1, b2)) as sp:
             if pair_stats is not None:
                 sp.set(**pair_stats.span_attrs())
             # one decision per side; the fused program partitions both
@@ -1093,7 +1097,7 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
             rows = int(targets.shape[0])
             nbytes = _payload_nbytes(payload)
             with _span("shuffle.exchange", seq, world=1, mode="padded",
-                       rows=rows, bytes_moved=nbytes):
+                       rows=rows, bytes_moved=nbytes, block=block1):
                 out, new_emit, counts_in = _launch_exchange(
                     lambda: _exchange_padded_fn(
                         ctx.mesh, block1)(payload, targets, emit))
@@ -1125,13 +1129,16 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
     row_bytes = _payload_row_bytes(payload)
     # skew observability rides the ALREADY-FETCHED count matrix: zero
     # extra device→host transfers (None on a 1-wide mesh)
-    skew_stats = _skew.observe_exchange(counts, row_bytes)
+    skew_stats = _skew.observe_exchange(
+        counts, row_bytes,
+        world * (cap_padded if padded_ok else cap_compact))
     with _span("shuffle.exchange", seq, world=world,
                mode="padded" if padded_ok else "compact",
                rows=rows_live, bytes_moved=nbytes) as sp:
         if skew_stats is not None:
             sp.set(**skew_stats.span_attrs())
         if padded_ok:
+            sp.set(block=block_p)
             part = _partition_path(ctx.mesh, world, payload)
             _record_partition(sp, part)
             cb, chunks = _chunk_plan(block_p, world, row_bytes)

@@ -151,10 +151,18 @@ class SkewStats:
                 attrs["shard_recv_bytes"] = list(self.recv_bytes)
         return attrs
 
-    def record(self, registry: Optional["_metrics.MetricsRegistry"] = None
+    def record(self, slots: int,
+               registry: Optional["_metrics.MetricsRegistry"] = None
                ) -> None:
-        """Feed the registry histograms — one imbalance observation per
-        exchange, one rows/bytes observation per destination shard."""
+        """Feed the registry: histograms — one imbalance observation per
+        exchange, one rows/bytes observation per destination shard — and
+        monotonic counters. A histogram's buckets give no per-query
+        reading; counters do, as ratios of their growth over a query:
+        the worst destination's received rows over the mean (receive
+        imbalance, summed over the query's exchanges), and 1 - live
+        rows / ``slots`` (padding share). ``slots`` is what the exchange
+        allocates for received rows over the whole mesh: world * the
+        per-shard capacity of its route."""
         r = registry or _metrics.REGISTRY
         r.histogram("cylon_shuffle_imbalance_factor",
                     buckets=IMBALANCE_BUCKETS).observe(self.imbalance)
@@ -166,13 +174,20 @@ class SkewStats:
             rows_h.observe(rows)
             if self.bytes_per_row:
                 bytes_h.observe(nbytes)
+        r.counter("cylon_exchange_recv_rows_total",
+                  {"stat": "max"}).inc(self.rows_max)
+        r.counter("cylon_exchange_recv_rows_total",
+                  {"stat": "mean"}).inc(self.total_rows / self.world)
+        r.counter("cylon_exchange_live_rows_total").inc(self.total_rows)
+        r.counter("cylon_exchange_slots_total").inc(int(slots))
 
 
-def observe_exchange(counts, bytes_per_row: int = 0,
+def observe_exchange(counts, bytes_per_row: int, slots: int,
                      registry=None) -> Optional[SkewStats]:
     """One-call form for the exchange sites: reduce + record; returns
-    the stats (for span attachment) or None on a 1-wide mesh."""
+    the stats (for span attachment) or None on a 1-wide mesh. ``slots``:
+    the received-row slots the exchange allocates, mesh-wide."""
     stats = SkewStats.from_counts(counts, bytes_per_row)
     if stats is not None:
-        stats.record(registry)
+        stats.record(slots, registry)
     return stats

@@ -67,7 +67,8 @@ def test_skew_record_feeds_histograms():
 
     reg = MetricsRegistry()
     counts = np.full((4, 4), 10)
-    stats = skew.observe_exchange(counts, bytes_per_row=16, registry=reg)
+    stats = skew.observe_exchange(counts, bytes_per_row=16, slots=4 * 64,
+                                  registry=reg)
     assert stats is not None
     snap = reg.snapshot()
     assert snap["cylon_shuffle_imbalance_factor"]["count"] == 1
