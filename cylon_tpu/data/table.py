@@ -1371,40 +1371,61 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
             keys.extend(_order.sort_keys([c]))
         if c.validity is not None:
             keys.append(c.valid_mask().astype(jnp.uint8))
-    emit = table.emit_mask()
+    # None (no row mask): every row is live, and no dead flag rides
+    emit = table.row_mask
     values = tuple(table._columns[i].data for i in val_cols)
     # None for all-valid columns: the mask never rides the sort
     valids = tuple(table._columns[i].validity for i in val_cols)
+    vdtypes = [v.dtype for v in values]
+    # how to read each output key column back off its sorted lanes; a
+    # varbytes key (hash lanes) has no way back
+    key_spec = None if any(c.is_varbytes for c in key_columns) else tuple(
+        (np.dtype(c.data.dtype), c.is_string, c.validity is not None)
+        for c in key_columns)
+    index = _groupby.sort_carries_index(keys, key_spec, vdtypes, ops,
+                                        table.capacity)
     # ONE fused sort groups rows contiguously (dead rows last); the
     # n_groups fetch below is the op's single host sync, and the reduce
     # step then works on the sorted RUNS — see ops/groupby.presort_groups
     # (round-5 rework of the dense-rank + scatter-back path; the old gid
-    # scatter cost ~15-30 ns/element)
-    values_s, valids_s, emit_s, iota_s, new_grp, ng = \
-        _groupby.presort_groups_jit(tuple(keys), emit, values, valids)
+    # scatter cost ~15-30 ns/element). Its operands are counted here,
+    # where the host can see them (the same pure function of mask, lanes,
+    # columns and index that presort_groups builds its list by)
+    _telemetry.counter("cylon_groupby_sort_operands_total").inc(
+        _groupby.sort_operand_count(keys, emit, values, valids, index))
+    values_s, valids_s, emit_s, first_s, new_grp, ng = \
+        _groupby.presort_groups_jit(tuple(keys), emit, values, valids,
+                                    index=index)
     num_groups = max(int(_telemetry.host_fetch("groupby.groups", ng)), 1)
     cap = _pow2(num_groups)
 
     # the path sorted_segment_aggregate will take, counted where the
     # host can see it (the same pure function of backend and widths)
     _telemetry.counter("cylon_groupby_reduce_path_total", {
-        "path": _groupby.reduce_path([v.dtype for v in values], ops,
-                                     emit.shape[0])}).inc()
-    rep, group_valid, results = _groupby.sorted_segment_aggregate_jit(
-        new_grp, emit_s, iota_s, values_s, valids_s, cap, tuple(ops),
+        "path": _groupby.reduce_path(vdtypes, ops, table.capacity)}).inc()
+    firsts, group_valid, results = _groupby.sorted_segment_aggregate_jit(
+        new_grp, emit_s, first_s, values_s, valids_s, cap, tuple(ops),
         tuple(val_cols),
-        tuple(table._columns[i].validity is None for i in val_cols))
+        tuple(table._columns[i].validity is None for i in val_cols),
+        key_spec=None if index else key_spec)
 
     # materialize at pow2 group capacity: dead slots (gid-space holes from
     # masked rows, pow2 padding) stay on device masked via row_mask —
     # num_groups above was the only host sync in this op
-    safe = jnp.minimum(rep, max(table.capacity - 1, 0))
-    out_cols = []
-    for i in idx_cols:
-        g = table._columns[i].take(safe)
-        validity = None if g.validity is None else g.validity & group_valid
-        out_cols.append(Column(g.data, g.dtype, validity, g.dictionary,
-                               g.name, varbytes=g.varbytes))
+    if index:
+        # firsts = rep: each group's first original row, to gather its key
+        safe = jnp.minimum(firsts, max(table.capacity - 1, 0))
+        out_cols = []
+        for c in key_columns:
+            g = c.take(safe)
+            validity = None if g.validity is None \
+                else g.validity & group_valid
+            out_cols.append(Column(g.data, g.dtype, validity, g.dictionary,
+                                   g.name, varbytes=g.varbytes))
+    else:
+        # the key columns came out of the reduce program itself
+        out_cols = [Column(data, c.dtype, validity, c.dictionary, c.name)
+                    for c, (data, validity) in zip(key_columns, firsts)]
     for (arr, avalid), vi, op in zip(results, val_cols, aggregate_ops):
         src = table._columns[vi]
         out_cols.append(Column(

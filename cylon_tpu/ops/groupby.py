@@ -4,7 +4,7 @@ Replaces the reference's hash-map group-by (reference:
 cpp/src/cylon/groupby/groupby_hash.hpp:28-359 — `unordered_map` with
 compile-time `AggregateKernel<T,Op>{Init,Update,Finalize}`, and the
 sorted-run pipeline variant groupby_pipeline.hpp:28-257) with the TPU
-formulation: ONE fused stable sort groups the rows contiguously
+formulation: ONE fused sort groups the rows contiguously
 (`presort_groups`), then group g is the g-th run and every aggregation
 is a reduction over contiguous runs (`sorted_segment_aggregate`). On a
 TPU with 4-byte accumulators that is ONE streaming Pallas pass with no
@@ -14,6 +14,18 @@ the CPU, 8-byte accumulators under x64 — the portable path, which is
 also the tests' oracle: `jax.ops.segment_*` over sorted ids, an XLA
 scatter a stream that costs 8.8 ns a row on a v5e whatever the locality
 (3,515 ms of groupby-q5's 4,467 before PR 26; PERF.md section 6).
+
+What rides the sort (it is most of the query, and its time goes with its
+operands: PERF.md section 6, PR 29): only what cannot be derived after
+it. Always the key lanes (the sort keys), the value columns and the
+validity masks that exist. A dead flag, as the primary key, only when the
+table has a row mask (`emit` is read back off it, never carried). The
+row index only when a caller needs the first ORIGINAL row of a group
+(`sort_carries_index`): the `segment_*` path, an 8-byte or a varbytes
+key, the distributed kernel. Otherwise the sorted key lanes themselves
+are compacted to slot g by the reduce pass and the output key columns
+are read off them (`order.from_ordered_bits_raw`), with no index, no
+gather of the key column and no need for a stable sort.
 
 Distributed semantics (fixing the reference's re-aggregation subtlety noted
 in SURVEY §3.2): partial aggregates are combined with the correct SECOND-
@@ -30,6 +42,8 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import order as _order
 
 
 class AggregationOp(enum.IntEnum):
@@ -77,43 +91,64 @@ def _min_of(dtype):
     return np.iinfo(d).min
 
 
-def presort_groups(keys: Tuple[jnp.ndarray, ...], emit: jnp.ndarray,
+def sort_operand_count(keys, emit, values, valids, index: bool) -> int:
+    """How many operands presort_groups hands the sort for these
+    arguments: a pure function of what the host sees before it
+    dispatches (counted there as ``cylon_groupby_sort_operands_total``)."""
+    return ((emit is not None) + len(keys) + len(values)
+            + sum(v is not None for v in valids) + bool(index))
+
+
+def presort_groups(keys: Tuple[jnp.ndarray, ...], emit,
                    values: Tuple[jnp.ndarray, ...],
-                   valids: Tuple[jnp.ndarray, ...]):
-    """ONE fused stable sort carries the key bits, every value column,
-    every validity mask, emit and iota as operands (dead rows last via a
-    dead-flag primary key — the join/sort kernels' trick). Output rows
-    are grouped contiguously: group g is the g-th run of live rows, so
-    the reduce step (sorted_segment_aggregate) works on runs and the
-    dense-rank scatter-back the old path paid (a ~15-30 ns/element
-    .at[perm].set at full row count) disappears entirely.
+                   valids: Tuple[jnp.ndarray, ...], index: bool = True):
+    """ONE fused sort by the key lanes that carries every value column
+    and every validity mask as operands, and nothing that can be derived
+    after it. Output rows are grouped contiguously: group g is the g-th
+    run of live rows, so the reduce step (sorted_segment_aggregate)
+    works on runs.
 
-    ``valids`` entries may be None (all-valid column): None masks don't
-    ride the sort — the aggregate reads them as "live row = valid".
+    ``emit`` None says every row is live (a table with no row mask): no
+    dead flag rides. With a mask the dead flag is the primary sort key
+    (dead rows last; the join/sort kernels' trick) and the sorted emit
+    is read back off it. ``valids`` entries may be None (all-valid
+    column): None masks don't ride either; the aggregate reads them as
+    "live row = valid". ``index`` (static; `sort_carries_index` decides)
+    adds the row index as the last operand of a STABLE sort, so that a
+    run's first sorted row is its first original row; without it the
+    sorted key lanes go out in its place and the sort need not be
+    stable (a group's rows arrive in any order).
 
-    Returns (values_s, valids_s, emit_s, iota_s, new_grp, n_groups):
-    new_grp marks each live run's first SORTED row, n_groups is a device
-    scalar (the caller's single host sync)."""
-    n = emit.shape[0]
-    dead = (~emit).astype(jnp.uint8)
-    iota = jnp.arange(n, dtype=jnp.int32)
+    Returns (values_s, valids_s, emit_s, first_s, new_grp, n_groups):
+    emit_s is None when emit was; first_s is the sorted row index, or
+    the tuple of sorted key lanes when ``index`` is off; new_grp marks
+    each live run's first SORTED row; n_groups is a device scalar (the
+    caller's single host sync)."""
+    n = keys[0].shape[0]
     nk, nv = len(keys), len(values)
     real_v = [v for v in valids if v is not None]
-    ops_in = (dead,) + tuple(keys) + tuple(values) + tuple(real_v) \
-        + (emit, iota)
-    res = jax.lax.sort(ops_in, num_keys=1 + nk, is_stable=True)
-    ks = res[1:1 + nk]
-    values_s = tuple(res[1 + nk:1 + nk + nv])
-    it = iter(res[1 + nk + nv:1 + nk + nv + len(real_v)])
+    head = () if emit is None else ((~emit).astype(jnp.uint8),)
+    tail = (jnp.arange(n, dtype=jnp.int32),) if index else ()
+    ops_in = head + tuple(keys) + tuple(values) + tuple(real_v) + tail
+    assert len(ops_in) == sort_operand_count(keys, emit, values, valids,
+                                             index)
+    h = len(head)
+    res = jax.lax.sort(ops_in, num_keys=h + nk, is_stable=index)
+    ks = res[h:h + nk]
+    values_s = tuple(res[h + nk:h + nk + nv])
+    it = iter(res[h + nk + nv:h + nk + nv + len(real_v)])
     valids_s = tuple(None if v is None else next(it) for v in valids)
-    emit_s, iota_s = res[-2], res[-1]
     # row differs from its predecessor on any key lane (row 0 = True);
     # dead rows are all last, so live rows form a prefix
-    neq = jnp.zeros(n, dtype=bool).at[0].set(True)
+    new_grp = jnp.zeros(n, dtype=bool).at[0].set(True)
     for k in ks:
-        neq = neq | jnp.concatenate([jnp.ones(1, bool), k[1:] != k[:-1]])
-    new_grp = neq & emit_s
-    return (values_s, valids_s, emit_s, iota_s, new_grp,
+        new_grp = new_grp | jnp.concatenate(
+            [jnp.ones(1, bool), k[1:] != k[:-1]])
+    emit_s = None
+    if emit is not None:
+        emit_s = res[0] == 0
+        new_grp = new_grp & emit_s
+    return (values_s, valids_s, emit_s, res[-1] if index else ks, new_grp,
             new_grp.sum(dtype=jnp.int32))
 
 
@@ -151,55 +186,102 @@ def reduce_path(value_dtypes, ops, n: int, interpret: bool = False) -> str:
     return "stream"
 
 
+def sort_carries_index(key_lanes, key_spec, value_dtypes, ops, n: int,
+                       interpret: bool = False) -> bool:
+    """Whether presort_groups must carry the row index, from what the
+    code can observe (no knob). False — the sorted key lanes ride out
+    in the index's place — when the reduce step streams (``reduce_path``)
+    and the output key columns can be read back off the lanes:
+    ``key_spec`` names them (None: some key is varbytes, whose lanes are
+    content hashes with no inverse) and no lane is wider than the
+    kernel's 32 bits (an 8-byte key under x64)."""
+    if key_spec is None or any(
+            np.dtype(k.dtype).itemsize > 4 for k in key_lanes):
+        return True
+    return reduce_path(value_dtypes, ops, n, interpret) != "stream"
+
+
 def _reduce_segments(new_grp, emit_s, iota_s, subs, num_segments: int):
     """The portable reduce step: dense ids by a cumsum over the run
-    starts, then one ``indices_are_sorted`` XLA scatter a stream."""
+    starts, then one ``indices_are_sorted`` XLA scatter a stream.
+    Returns ((rep,), group_valid, reductions)."""
     n = new_grp.shape[0]
-    gid_s = jnp.cumsum(new_grp.astype(jnp.int32)) - 1
-    seg = jnp.where(emit_s, gid_s, num_segments)  # masked -> overflow slot
+    seg = jnp.cumsum(new_grp.astype(jnp.int32)) - 1
+    first = iota_s
+    if emit_s is not None:   # masked -> overflow slot
+        seg = jnp.where(emit_s, seg, num_segments)
+        first = jnp.where(emit_s, iota_s, n)
     rep = jnp.full(num_segments + 1, n, jnp.int32).at[seg].min(
-        jnp.where(emit_s, iota_s, n), indices_are_sorted=True)
+        first, indices_are_sorted=True)[:num_segments]
     reducers = {"add": jax.ops.segment_sum, "min": jax.ops.segment_min,
                 "max": jax.ops.segment_max}
-    return rep[:num_segments], {
+    return (rep,), rep < n, {
         key: reducers[kind](x, seg, num_segments=num_segments + 1,
                             indices_are_sorted=True)[:num_segments]
         for key, (kind, x) in subs.items()}
 
 
-def _reduce_runs(new_grp, emit_s, iota_s, subs, num_segments: int,
-                 interpret: bool):
+def _reduce_runs(new_grp, emit_s, firsts, first_fill, subs,
+                 num_segments: int, interpret: bool):
     """The streaming reduce step: every stream in ONE pass of
     tpu_kernels.groupby_run_reduce (no gather or scatter of n or of
-    num_segments elements); slots at and past the run count are filled
-    as the segment path leaves them (rep = n, each op's identity)."""
+    num_segments elements): each of ``firsts`` (int32) gives its run's
+    first element, each of ``subs`` its reduction. Slots at and past the
+    run count hold ``first_fill`` and each op's identity, as the segment
+    path leaves them. Returns (firsts, group_valid, reductions)."""
     from . import tpu_kernels as tk
 
     n = new_grp.shape[0]
     keys = list(subs)
-    # the sort is stable: a run's first sorted row is its first original
+    nf = len(firsts)
     outs, count = tk.groupby_run_reduce(
-        new_grp, emit_s, [iota_s] + [subs[k][1] for k in keys],
-        ["first"] + [subs[k][0] for k in keys], num_segments,
+        new_grp, jnp.ones(n, bool) if emit_s is None else emit_s,
+        list(firsts) + [subs[k][1] for k in keys],
+        ["first"] * nf + [subs[k][0] for k in keys], num_segments,
         interpret=interpret)
     live = jnp.arange(num_segments, dtype=jnp.int32) < count
     empty = {"add": lambda d: 0, "min": _max_of, "max": _min_of}
-    return jnp.where(live, outs[0], jnp.int32(n)), {
+    return (tuple(jnp.where(live, o, jnp.int32(first_fill))
+                  for o in outs[:nf]), live, {
         k: jnp.where(live, o, jnp.asarray(empty[subs[k][0]](o.dtype),
                                           o.dtype))
-        for k, o in zip(keys, outs[1:])}
+        for k, o in zip(keys, outs[nf:])})
 
 
-def sorted_segment_aggregate(new_grp, emit_s, iota_s,
+def _lane_to_stream(lane):
+    """A sorted key lane (unsigned, at most 32 bits) as the int32 stream
+    the reduce kernel takes; `_stream_to_lane` undoes it."""
+    return jax.lax.bitcast_convert_type(lane.astype(jnp.uint32), jnp.int32)
+
+
+def _stream_to_lane(stream, dtype):
+    return jax.lax.bitcast_convert_type(stream, jnp.uint32).astype(dtype)
+
+
+def _key_columns(firsts, lane_dtypes, key_spec, group_valid):
+    """The output key columns, off the compacted key lanes: per key
+    column of ``key_spec`` one ordered-bits lane, inverted to the
+    column's dtype, then its validity lane if the column is nullable."""
+    it = (_stream_to_lane(f, d) for f, d in zip(firsts, lane_dtypes))
+    return tuple(
+        (_order.from_ordered_bits_raw(next(it), dtype, is_string),
+         (next(it) != 0) & group_valid if nullable else None)
+        for dtype, is_string, nullable in key_spec)
+
+
+def sorted_segment_aggregate(new_grp, emit_s, first_s,
                              values_s: Tuple[jnp.ndarray, ...],
                              valids_s: Tuple[jnp.ndarray, ...],
                              num_segments: int,
                              ops: Tuple[AggregationOp, ...],
                              col_ids: Tuple[int, ...],
                              all_valid: Tuple[bool, ...],
+                             key_spec=None,
                              interpret: bool = False):
     """Aggregate presorted value columns into per-group slots: group g
-    (the g-th run that ``new_grp`` starts) lands in slot g.
+    (the g-th run that ``new_grp`` starts) lands in slot g. The first
+    three arguments are presort_groups' outputs (``emit_s`` None: every
+    row is live).
 
     Duplicate sub-reductions dedup across the op list (static
     ``col_ids`` name each value's source column — the same traced array
@@ -214,14 +296,22 @@ def sorted_segment_aggregate(new_grp, emit_s, iota_s,
     only (each path adds a group's own values and partials, nothing
     else).
 
-    Returns (rep_idx, group_valid, list_of_(agg_array, agg_valid)):
+    ``key_spec`` None: ``first_s`` is the sorted row index, and the
+    return is (rep_idx, group_valid, list_of_(agg_array, agg_valid)):
       rep_idx[g] = first ORIGINAL row index holding group g (n past
       the group count), agg arrays have shape [num_segments].
+    ``key_spec`` a tuple of (numpy dtype, is_string, nullable), one a
+    key column: the sort carried no index (`sort_carries_index`),
+    ``first_s`` is the tuple of sorted key lanes, the reduce pass
+    compacts them beside the reductions, and in rep_idx's place comes a
+    tuple of (key data, key validity or None), one a key column, the
+    data read off the ordered bits (a float key's -0.0 is +0.0).
     MEAN returns a float64 array; COUNT returns int64 of non-null values
     (Arrow count semantics) — float32 / int32 with x64 off."""
     n = new_grp.shape[0]
     stream = reduce_path([v.dtype for v in values_s], ops, n,
                          interpret) == "stream"
+    assert stream or key_spec is None, "key lanes ride the stream path only"
     subs = {}   # key -> (kind, masked stream): each DISTINCT reduction
 
     def sub(key, kind, make):
@@ -237,7 +327,7 @@ def sorted_segment_aggregate(new_grp, emit_s, iota_s,
         # the rows that count; None = every row. The streaming pass
         # never reads a dead row into a result, so only a validity mask
         # costs it a pass (an all-valid column goes in as it is)
-        if stream:
+        if stream or emit_s is None:
             use = vmask
         else:
             use = emit_s if vmask is None else (emit_s & vmask)
@@ -263,13 +353,16 @@ def sorted_segment_aggregate(new_grp, emit_s, iota_s,
                                    lambda: tally(jnp.int32))
         plan.append((op, out, anyv))
 
-    if stream:
-        rep, red = _reduce_runs(new_grp, emit_s, iota_s, subs,
-                                num_segments, interpret)
+    if not stream:
+        firsts, group_valid, red = _reduce_segments(
+            new_grp, emit_s, first_s, subs, num_segments)
     else:
-        rep, red = _reduce_segments(new_grp, emit_s, iota_s, subs,
-                                    num_segments)
-    group_valid = rep < n
+        # the index of a STABLE sort (a run's first sorted row is its
+        # first original row), or the key lanes
+        lanes, fill = ((first_s,), n) if key_spec is None else (
+            [_lane_to_stream(k) for k in first_s], 0)
+        firsts, group_valid, red = _reduce_runs(
+            new_grp, emit_s, lanes, fill, subs, num_segments, interpret)
 
     results = []
     for op, a, b in plan:
@@ -282,12 +375,16 @@ def sorted_segment_aggregate(new_grp, emit_s, iota_s,
         else:
             results.append((red[a], group_valid if b is None
                             else group_valid & (red[b] > 0)))
-    return rep, group_valid, results
+    if key_spec is None:
+        return firsts[0], group_valid, results
+    return (_key_columns(firsts, [k.dtype for k in first_s], key_spec,
+                         group_valid), group_valid, results)
 
 
-presort_groups_jit = jax.jit(presort_groups)
+presort_groups_jit = partial(jax.jit, static_argnames=("index",))(
+    presort_groups)
 
 sorted_segment_aggregate_jit = partial(
     jax.jit, static_argnames=("num_segments", "ops", "col_ids",
-                              "all_valid", "interpret"))(
+                              "all_valid", "key_spec", "interpret"))(
                                   sorted_segment_aggregate)

@@ -84,6 +84,31 @@ def ordered_bits_raw(x: jnp.ndarray, is_string: bool = False,
     return out
 
 
+def from_ordered_bits_raw(bits: jnp.ndarray, dtype,
+                          is_string: bool = False) -> jnp.ndarray:
+    """The inverse of `ordered_bits_raw` (ascending): the ``dtype``
+    values whose ordered bits are ``bits`` (traceable). Exact but for a
+    float's -0.0, which `ordered_bits_raw` folded into +0.0 (one key);
+    NaNs keep their payload bits. A group-by whose sort carries the key
+    as ordered bits reads its output key column off them with this
+    (ops/groupby.sorted_segment_aggregate)."""
+    dt = np.dtype(dtype)
+    if is_string or dt == np.bool_:
+        return bits.astype(dt)
+    if jnp.issubdtype(dt, jnp.unsignedinteger):
+        return bits
+    w = dt.itemsize
+    u = _WIDTH_UINT[w]
+    signbit = jnp.asarray(np.uint64(1) << (8 * w - 1), u)
+    if jnp.issubdtype(dt, jnp.signedinteger):
+        return (bits ^ signbit).view(dt)
+    if jnp.issubdtype(dt, jnp.floating):
+        # a set top bit marks a value that was not negative
+        return jnp.where((bits & signbit) != 0, bits ^ signbit,
+                         ~bits).view(dt)
+    raise CylonError(Code.TypeError, f"unorderable dtype {dt}")
+
+
 def sort_keys(cols: Sequence[Column],
               ascending: Optional[Sequence[bool]] = None,
               nulls_last: bool = True) -> List[jnp.ndarray]:

@@ -741,10 +741,15 @@ def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
 
 def _aggregate_shards(ctx: CylonContext, ops, col_ids, all_valid, kbits,
                       kdat, kval, emit, vdat, vval):
-    """One per-shard sort + reduce step (``_groupby_fn``), its reduce
-    path counted here, where the host can see it: the same pure function
-    of backend and accumulator widths that sorted_segment_aggregate
-    evaluates inside the program."""
+    """One per-shard sort + reduce step (``_groupby_fn``), its sort's
+    operands and its reduce path counted here, where the host can see
+    them: the same pure functions of masks, lanes and accumulator widths
+    that presort_groups and sorted_segment_aggregate evaluate inside the
+    program. After an exchange there is always a row mask, and the
+    varlen key gather needs each group's first row: the dead flag and
+    the index both ride."""
+    _counter("cylon_groupby_sort_operands_total").inc(
+        _groupby.sort_operand_count(kbits + kval, emit, vdat, vval, True))
     _counter("cylon_groupby_reduce_path_total", {
         "path": _groupby.reduce_path(
             [v.dtype for v in vdat], ops,

@@ -3,11 +3,17 @@
 Parity model: cpp/test/groupby_test.cpp, aggregate_test.cpp,
 python/test/test_table_compute (world=1).
 """
+import functools
+
+import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 import pytest
 
 import cylon_tpu as ct
+from cylon_tpu import dtypes, telemetry
+from cylon_tpu.ops import groupby as G
+from cylon_tpu.ops import order
 
 
 def df(seed=0, n=80, keys=9):
@@ -57,6 +63,154 @@ def test_groupby_enum_ops(local_ctx):
     got = t.groupby(0, [2], [ct.AggregationOp.MIN])
     exp = d.groupby("k")["b"].min()
     assert got.row_count == len(exp)
+
+
+def _col(arr, name, validity=None):
+    """A column as it is: a NaN stays a value (from_numpy reads a host
+    NaN as a null), a string array is dictionary-encoded."""
+    arr = np.asarray(arr)
+    if arr.dtype.kind in "UO":
+        return ct.Column.from_numpy(arr, name, validity)
+    return ct.Column(jnp.asarray(arr), dtypes.from_np_dtype(arr.dtype),
+                     None if validity is None else jnp.asarray(validity),
+                     None, name)
+
+
+N_KEYED = 400
+
+
+def _key_columns(kind):
+    """(key columns, key lanes) of a named kind of key over N_KEYED
+    rows, a dozen-odd groups each."""
+    rng = np.random.default_rng(len(kind))
+    pick = lambda vals, dt: np.asarray(vals, dt)[
+        rng.integers(0, len(vals), N_KEYED)]
+    i32 = pick([-2 ** 31, -7, -1, 0, 1, 5, 99, 2 ** 31 - 1], np.int32)
+    f32 = pick([-np.inf, -1.5, -0.0, 0.0, 1e-30, 2.5, np.inf, np.nan],
+               np.float32)
+    some = rng.random(N_KEYED) < 0.8
+    made = {
+        "int32": ([_col(i32, "k")], 1),
+        "int32+row_mask": ([_col(i32, "k")], 1),
+        "uint32": ([_col(pick([0, 3, 2 ** 31, 2 ** 32 - 1], np.uint32),
+                         "k")], 1),
+        "int16": ([_col(pick([-2 ** 15, -3, 0, 2 ** 15 - 1], np.int16),
+                        "k")], 1),
+        "float32": ([_col(f32, "k")], 1),
+        "bool": ([_col(rng.random(N_KEYED) < 0.5, "k")], 1),
+        "dictionary": ([_col(pick(["pear", "apple", "fig", "kiwi"], object),
+                             "k")], 1),
+        "nullable": ([_col(i32, "k", some)], 2),     # + its validity lane
+        "two_columns": ([_col(i32, "k"), _col(f32, "k2", some)], 3),
+        # no way back from the lanes to the key: the index rides
+        "varbytes": ([_col(np.array([f"row{i % 300:03d}" for i in
+                                     range(N_KEYED)], object), "k")], None),
+        "int64": ([_col(i32.astype(np.int64) * 3, "k")], 1),
+    }
+    return made[kind]
+
+
+KEY_KINDS = ["int32", "int32+row_mask", "uint32", "int16", "float32", "bool",
+             "dictionary", "nullable", "two_columns", "varbytes", "int64"]
+
+
+@pytest.mark.parametrize("kind", KEY_KINDS)
+def test_groupby_on_the_stream_path_reads_its_keys_off_the_sort(
+        local_ctx, monkeypatch, kind):
+    """groupby_local as a TPU backend runs it (the stream path, here
+    under the Pallas interpreter) against the same call as the CPU runs
+    it (``segment_*``, the index rides, the keys gathered): same groups
+    in the same order, equal keys, validity and integer sums; and the
+    sort's operands are counted as the host sees them."""
+    keys, lanes = _key_columns(kind)
+    rng = np.random.default_rng(7)
+    cols = keys + [_col(rng.integers(-50, 50, N_KEYED).astype(np.int32), "a"),
+                   _col(rng.normal(size=N_KEYED).astype(np.float32), "b")]
+    mask = jnp.asarray(rng.random(N_KEYED) < 0.7) \
+        if kind.endswith("row_mask") else None
+    table = ct.Table(cols, local_ctx, mask)
+    by, vals = list(range(len(keys))), [len(keys), len(keys) + 1]
+    rides_index = kind in ("varbytes", "int64")
+    assert keys[0].is_varbytes == (kind == "varbytes")
+    assert keys[0].is_string == (kind in ("dictionary", "varbytes"))
+
+    def run():
+        name = "cylon_groupby_sort_operands_total"
+        before = telemetry.metrics_snapshot().get(name, 0)
+        out = table.groupby(by, vals, ["sum", "sum"])
+        return out, telemetry.metrics_snapshot()[name] - before
+
+    want, carried = run()
+    if lanes is not None:   # the CPU's path: the index always rides
+        assert carried == (mask is not None) + lanes + 2 + 1
+    real_path, real_agg = G.reduce_path, G.sorted_segment_aggregate
+    monkeypatch.setattr(G, "reduce_path", lambda dts, ops, n, interpret=False:
+                        real_path(dts, ops, n, True))
+    monkeypatch.setattr(G, "sorted_segment_aggregate_jit",
+                        functools.partial(real_agg, interpret=True))
+    got, carried_on_tpu = run()
+    assert carried_on_tpu == carried - (not rides_index)
+    assert got.row_count == want.row_count >= 2
+    live = np.asarray(want.row_mask)
+    np.testing.assert_array_equal(np.asarray(got.row_mask), live)
+    for g, w in zip(got._columns, want._columns):
+        assert (g.name, g.dtype, g.data.dtype, g.is_varbytes) == \
+            (w.name, w.dtype, w.data.dtype, w.is_varbytes)
+        # no validity at all where the gathered one holds no null
+        ok = live if w.validity is None else np.asarray(w.validity) & live
+        np.testing.assert_array_equal(
+            live if g.validity is None else np.asarray(g.validity) & live,
+            ok)
+    got, want = got.compact(), want.compact()
+    for g, w in zip(got._columns, want._columns):
+        gv, wv = g.to_numpy(), w.to_numpy()
+        if w.name == "b":   # float sums differ by association only
+            np.testing.assert_allclose(gv, wv, rtol=1e-4, atol=1e-4)
+        else:               # NaN == NaN, -0.0 == +0.0, None == None
+            np.testing.assert_array_equal(gv, wv)
+    if kind in ("float32", "two_columns"):
+        k = got._columns[len(keys) - 1].to_numpy()
+        assert not np.signbit(k[k == 0]).any()   # -0.0 comes back as +0.0
+        assert np.isnan(np.asarray(got._columns[len(keys) - 1].data)).any()
+
+
+ORDERABLE = ["bool", "uint8", "uint16", "uint32", "int8", "int16", "int32",
+             "float16", "float32", "uint64", "int64", "float64"]
+
+
+@pytest.mark.parametrize("dtype", ORDERABLE)
+def test_ordered_bits_round_trip(dtype):
+    """inverse(ordered_bits_raw(x)) == x bit for bit over each dtype's
+    edges (but -0.0, which the ordered bits fold into +0.0), and the bits
+    order as the values do."""
+    dt = np.dtype(dtype)
+    if dt.kind == "b":
+        x = np.array([False, True, True, False])
+    elif dt.kind in "iu":
+        info = np.iinfo(dt)
+        x = np.array([info.min, info.min + 1, info.min // 2, 0, 1, 7,
+                      info.max - 1, info.max], dt)
+    else:
+        info = np.finfo(dt)
+        x = np.array([-np.inf, info.min, -1.5, -info.tiny, -0.0, 0.0,
+                      info.tiny, 2.5, info.max,
+                      np.inf, np.nan], dt)
+        x = np.concatenate([x, -x[-1:]])       # a NaN with its sign set
+    bits = order.ordered_bits_raw(jnp.asarray(x))
+    back = np.asarray(order.from_ordered_bits_raw(bits, dt))
+    assert back.dtype == dt and bits.dtype.itemsize == dt.itemsize
+    want = np.where(x == 0, np.zeros((), dt), x) if dt.kind == "f" else x
+    np.testing.assert_array_equal(back.view(f"u{dt.itemsize}"),
+                                  want.view(f"u{dt.itemsize}"))
+    finite = x[x == x]
+    np.testing.assert_array_equal(
+        np.argsort(np.asarray(bits)[x == x], kind="stable"),
+        np.argsort(finite, kind="stable"))
+    # dictionary codes are their own bits
+    codes = jnp.asarray(np.array([3, 0, 2], np.int32))
+    np.testing.assert_array_equal(np.asarray(order.from_ordered_bits_raw(
+        order.ordered_bits_raw(codes, is_string=True), np.int32, True)),
+        np.asarray(codes))
 
 
 def test_groupby_null_values_skipped(local_ctx):

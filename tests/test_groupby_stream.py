@@ -9,7 +9,10 @@ the parametrised cases read their (op, column) out of it: the whole
 op x column matrix on one general shape; an int32 and a float32 sum (each
 carried across blocks as bits) on every shape that stresses the scan, the
 carry or the compaction; a masked min with its any-valid tally besides,
-where rows are dead or null.
+where rows are dead or null. Every shape also runs without a row mask
+(no dead flag rides the sort) and without the index (the sorted key lane
+rides out in its place and the keys are read back off it), on the lighter
+of its case lists.
 """
 import contextlib
 import functools
@@ -23,6 +26,7 @@ import pytest
 import cylon_tpu as ct
 from cylon_tpu import telemetry
 from cylon_tpu.ops import groupby as G
+from cylon_tpu.ops import order
 from cylon_tpu.ops import tpu_kernels as tk
 
 Op = G.AggregationOp
@@ -77,8 +81,19 @@ MASKED = SUMS + [(Op.MIN, "masked")]
 SHAPES = {"mixed": FULL, "mixed_x64": MASKED, "one_run": SUMS,
           "own_runs": SUMS, "block_edges": SUMS, "dead_rows": MASKED,
           "all_dead": SUMS, "nulls": MASKED, "n1": SUMS}
-CASES = [(shape, op, col) for shape, pairs in SHAPES.items()
-         for op, col in pairs]
+# what the sort carries: (a row mask and its dead flag, the row index);
+# without the mask every row of the shape is live
+CARRIES = {"mask+index": (True, True), "index": (False, True),
+           "mask+keys": (True, False), "keys": (False, False)}
+
+
+def _pairs(shape, carries):
+    full = SHAPES[shape]
+    return full if carries == "mask+index" or full is not FULL else MASKED
+
+
+CASES = [(shape, carries, op, col) for shape in SHAPES
+         for carries in CARRIES for op, col in _pairs(shape, carries)]
 
 
 @contextlib.contextmanager
@@ -93,13 +108,18 @@ def small_blocks():
 
 
 @functools.lru_cache(maxsize=None)
-def _reduced(shape):
+def _reduced(shape, carries):
     """Both paths over one shape: (inputs, segment result, stream
-    result), results as host arrays. x64 off as on the chip (COUNT and
-    MEAN accumulate in 32 bits there), but for ``mixed_x64``, which runs
-    as tier-1 does."""
+    result), results as host arrays. The segment path's sort always
+    carries the index (it is the oracle); the stream path's sort what
+    ``carries`` says. x64 off as on the chip (COUNT and MEAN accumulate
+    in 32 bits there), but for ``mixed_x64``, which runs as tier-1
+    does."""
+    masked, index = CARRIES[carries]
     keys, emit, num_segments = _shape(shape)
     n = len(keys)
+    if not masked:
+        emit = np.ones(n, bool)
     rng = np.random.default_rng(n)
     cols = {"int32": rng.integers(-1000, 1000, n).astype(np.int32),
             "float32": (rng.normal(size=n) * 100).astype(np.float32),
@@ -107,22 +127,31 @@ def _reduced(shape):
     mask = rng.random(n) < 0.5
     if shape == "nulls":
         mask &= keys >= 10
-    pairs = SHAPES[shape]
+    pairs = _pairs(shape, carries)
     ops = tuple(op for op, _ in pairs)
     cids = tuple(COLUMNS.index(c) for _, c in pairs)
     av = tuple(c != "masked" for _, c in pairs)
     with jax.enable_x64(shape == "mixed_x64"), small_blocks():
-        state = G.presort_groups(
-            (jnp.asarray(keys),), jnp.asarray(emit),
+        presort = functools.partial(
+            G.presort_groups, (order.ordered_bits_raw(jnp.asarray(keys)),),
+            jnp.asarray(emit) if masked else None,
             tuple(jnp.asarray(cols[c]) for _, c in pairs),
             tuple(jnp.asarray(mask) if c == "masked" else None
                   for _, c in pairs))
-        vs, vm, emit_s, iota_s, new_grp, _ng = state
-        args = (new_grp, emit_s, iota_s, vs, vm, num_segments, ops, cids, av)
-        assert G.reduce_path([v.dtype for v in vs], ops, n, True) == "stream"
-        seg = jax.tree.map(np.asarray, G.sorted_segment_aggregate(*args))
-        run = jax.tree.map(np.asarray, G.sorted_segment_aggregate(
-            *args, interpret=True))
+
+        def reduce(state, **kw):
+            vs, vm, emit_s, first_s, new_grp, _ng = state
+            assert (emit_s is None) == (not masked)
+            assert G.reduce_path([v.dtype for v in vs], ops, n,
+                                 True) == "stream"
+            return jax.tree.map(np.asarray, G.sorted_segment_aggregate(
+                new_grp, emit_s, first_s, vs, vm, num_segments, ops, cids,
+                av, **kw))
+
+        seg = reduce(presort())
+        run = reduce(presort(index=index), interpret=True,
+                     key_spec=None if index else (
+                         (np.dtype(np.int32), False, False),))
     return (keys, emit, cols, mask), seg, run
 
 
@@ -135,16 +164,23 @@ def _f64_groups(keys, use, x):
             np.bincount(pos, minlength=len(ks)))
 
 
-@pytest.mark.parametrize("shape,op,col", CASES,
-                         ids=[f"{s}-{o.name}-{c}" for s, o, c in CASES])
-def test_stream_path_matches_segment_path(shape, op, col):
-    (keys, emit, cols, mask), seg, run = _reduced(shape)
-    i = SHAPES[shape].index((op, col))
-    # the slots: rep (n past the group count) and group_valid, bit-equal
-    np.testing.assert_array_equal(run[0], seg[0])
-    np.testing.assert_array_equal(run[1], seg[1])
+@pytest.mark.parametrize("shape,carries,op,col", CASES,
+                         ids=[f"{s}-{w}-{o.name}-{c}" for s, w, o, c in CASES])
+def test_stream_path_matches_segment_path(shape, carries, op, col):
+    (keys, emit, cols, mask), seg, run = _reduced(shape, carries)
+    i = _pairs(shape, carries).index((op, col))
     groups = len(np.unique(keys[emit]))
-    assert run[1].sum() == groups and (run[0][groups:] == len(keys)).all()
+    # the slots: group_valid, bit-equal, and rep (n past the group
+    # count) or, where the index did not ride, the keys themselves
+    np.testing.assert_array_equal(run[1], seg[1])
+    assert run[1].sum() == groups and (seg[0][groups:] == len(keys)).all()
+    if CARRIES[carries][1]:
+        np.testing.assert_array_equal(run[0], seg[0])
+    else:
+        [(got_keys, key_validity)] = run[0]
+        assert key_validity is None and got_keys.dtype == np.int32
+        np.testing.assert_array_equal(got_keys[:groups],
+                                      keys[seg[0][:groups]])
     (got, got_valid), (want, want_valid) = run[2][i], seg[2][i]
     assert got.dtype == want.dtype and got.shape == want.shape
     np.testing.assert_array_equal(got_valid, want_valid)
@@ -217,8 +253,10 @@ def test_path_is_chosen_from_backend_and_widths(monkeypatch):
 
 def _paths():
     snap = telemetry.metrics_snapshot()
-    return {p: snap.get('cylon_groupby_reduce_path_total{path="%s"}' % p, 0)
-            for p in ("stream", "segment")}
+    counted = {p: snap.get('cylon_groupby_reduce_path_total{path="%s"}' % p, 0)
+               for p in ("stream", "segment")}
+    counted["operands"] = snap.get("cylon_groupby_sort_operands_total", 0)
+    return counted
 
 
 def test_every_reduce_step_is_counted_by_its_path(local_ctx, dist_ctx):
@@ -230,8 +268,15 @@ def test_every_reduce_step_is_counted_by_its_path(local_ctx, dist_ctx):
     after = _paths()
     assert after["segment"] == before["segment"] + 1     # the CPU's path
     assert after["stream"] == before["stream"]
+    # its sort carried the key, the value and, for the CPU's path, the
+    # index; no row mask, so no dead flag
+    assert after["operands"] == before["operands"] + 3
     # distributed: the partial aggregation and the merge are a reduce
-    # step each
+    # step each, and each one's sort carries the dead flag (an exchanged
+    # table always has a mask), the key's bits and validity lane, the
+    # value and the index; the merge's value, a partial sum, brings its
+    # validity mask besides
     t = ct.Table.from_pydict(dist_ctx, data)
     assert t.groupby(0, [1], ["sum"]).row_count == 5
     assert _paths()["segment"] == after["segment"] + 2
+    assert _paths()["operands"] == after["operands"] + 5 + 6

@@ -10,6 +10,7 @@ exits: the topology is described inside a module-scoped fixture (never
 at import), the compiles run in this process, and all of them live in
 this ONE file so that one xdist worker owns the library.
 """
+import numpy as np
 import pytest
 
 import jax
@@ -83,25 +84,34 @@ def test_stream_compact_compiles(chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("n,num_segments", [
-    (100_000_000, 1 << 20),   # groupby-q5: 1e8 rows, 2^20 group slots
-    (N, N),                   # the distributed caller: num_segments = n
-], ids=["q5", "segments_eq_rows"])
-def test_groupby_stream_reduce_compiles(chip, monkeypatch, n, num_segments):
+@pytest.mark.parametrize("n,num_segments,keys_ride", [
+    (100_000_000, 1 << 20, True),    # groupby-q5: 1e8 rows, 2^20 slots
+    (100_000_000, 1 << 20, False),   # the same with a row mask and the index
+    (N, N, False),             # the distributed caller: num_segments = n
+], ids=["q5", "q5_masked_index", "segments_eq_rows"])
+def test_groupby_stream_reduce_compiles(chip, monkeypatch, n, num_segments,
+                                        keys_ride):
     """The groupby's reduce step on its streaming path, as a TPU backend
     chooses it (this process's backend is the CPU: the choice is steered
-    here), q5's three sums: int32, int32, float32."""
+    here), q5's three sums: int32, int32, float32. ``keys_ride``: as
+    groupby-q5 runs it, no row mask and the sorted key lane as the
+    "first" stream in the index's place, the int32 key column read back
+    off it inside the program."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     SUM = _groupby.AggregationOp.SUM
     vals = (_sds(chip, n, jnp.int32), _sds(chip, n, jnp.int32),
             _sds(chip, n, jnp.float32))
+    emit, first, key_spec = (
+        (None, (_sds(chip, n, jnp.uint32),),
+         ((np.dtype(np.int32), False, False),)) if keys_ride else
+        (_sds(chip, n, jnp.bool_), _sds(chip, n, jnp.int32), None))
     text = _compiled_text(
         _groupby.sorted_segment_aggregate_jit, _sds(chip, n, jnp.bool_),
-        _sds(chip, n, jnp.bool_), _sds(chip, n, jnp.int32), vals,
-        (None,) * 3, num_segments=num_segments, ops=(SUM,) * 3,
-        col_ids=(0, 1, 2), all_valid=(True,) * 3)
+        emit, first, vals, (None,) * 3, num_segments=num_segments,
+        ops=(SUM,) * 3, col_ids=(0, 1, 2), all_valid=(True,) * 3,
+        key_spec=key_spec)
     assert text.count("tpu_custom_call") == 1   # ONE pass for all streams
-    assert "scatter" not in text
+    assert "scatter" not in text and "gather" not in text
 
 
 def test_setop_stream_compiles(chip):
