@@ -13,7 +13,7 @@ recompile storm depends on each key parameter's *cardinality class*:
   ``max_words``: bounded by schema diversity. Fine, but noted — this is
   the axis along which compile time scales with schema variety.
 * **bucketed capacity** — a runtime count routed through a recognized
-  bucketing helper (``benchutils.bucket_cap``, ``util.pow2``,
+  bucketing helper (``util.bucket_cap``, ``util.pow2``,
   ``util.pow2_floor``, ``ops.join.stream_expand_capacity``): bounded to
   ~1 bucket per octave of data size. Fine.
 * **data-dependent** — a runtime count (``device_get`` fetch,
@@ -56,8 +56,7 @@ CLASS_NAMES = ("structural", "schema-bound", "bucketed-capacity",
 # recognized bucketing helpers, by package-relative (module, name) and —
 # for single-file fixture trees where imports do not resolve — bare name
 BUCKET_HELPERS_QUAL = {
-    ("benchutils", "bucket_cap"),
-    ("util", "pow2"), ("util", "pow2_floor"),
+    ("util", "bucket_cap"), ("util", "pow2"), ("util", "pow2_floor"),
     ("ops.join", "stream_expand_capacity"),
 }
 BUCKET_HELPER_NAMES = {"bucket_cap", "_bucket_cap", "pow2", "_pow2",
@@ -785,7 +784,7 @@ def check_specialization(ctx: AnalysisContext) -> List[Finding]:
                         message=f"cache-key parameter '{p.arg}' of "
                                 f"{fname} is data-dependent and not "
                                 f"routed through a recognized bucketing "
-                                f"helper (benchutils.bucket_cap / "
+                                f"helper (util.bucket_cap / "
                                 f"util.pow2) — one compiled program per "
                                 f"distinct value; derivation: "
                                 f"{res.why}"))

@@ -9,55 +9,12 @@ trusting the wall clock around a dispatch.
 """
 from __future__ import annotations
 
-import math
 import time
 from typing import Sequence
 
 import numpy as np
 
 _DIV = {"ns": 1.0, "us": 1e3, "ms": 1e6, "s": 1e9}
-
-# bucket_cap's small-value floor: every capacity below it shares ONE
-# bucket (and one compiled program). 512 rows/words is well under a
-# single shard's working set at bench scale, so the extra padding on
-# tiny shapes costs noise while the merged buckets cut a long tail of
-# small-capacity recompiles.
-BUCKET_FLOOR = 512
-
-
-def bucket_cap(n: int, floor: int = BUCKET_FLOOR) -> int:
-    """Next-power-of-two capacity with a small-value floor — the ONE
-    bucketing policy for data-dependent kernel-factory cache keys.
-
-    Every ``counted_cache`` factory keyed on a runtime count (join
-    materialize cap, set-op cap, varlen word cap, ring slab steps)
-    routes the count through this helper, so the key's cardinality is
-    bounded by OCTAVES of the data size (1 bucket per octave above the
-    floor, 1 below) instead of one compiled XLA program per distinct
-    value. Padding rows/words past the true count are masked by the
-    kernels' emit discipline, so results are bit-identical to an exact
-    capacity — only compile cardinality changes. The ``specialization``
-    analysis family (docs/analysis.md) statically enforces that
-    capacity-keyed call sites use this helper (or ``util.pow2`` /
-    ``util.pow2_floor`` for exchange blocks)."""
-    from .util import pow2
-
-    return max(pow2(max(int(n), 1)), int(floor))
-
-
-def round_sig(x: float, sig: int = 6) -> float:
-    """Round to ``sig`` SIGNIFICANT digits (not decimal places).
-
-    Fixed-decimal rounding destroyed sub-millisecond bench walls —
-    BENCH_r05 reported ``local_inner_join.wall_s_best: 0.0`` beside a
-    2.8M rows/s rate because a 23 ms wall was rounded to 1 decimal.
-    Significant-digit rounding keeps any nonzero measurement nonzero
-    and self-consistent with the rates computed from the unrounded
-    value, at any scale."""
-    if not isinstance(x, float) or x == 0.0 or not math.isfinite(x):
-        return x
-    return round(x, sig - 1 - int(math.floor(math.log10(abs(x)))))
-
 
 def _force(value) -> None:
     """Force async JAX results: device_get one element of every array

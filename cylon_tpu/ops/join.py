@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..util import pow2 as _pow2
+from ..util import bucket_cap as _bucket_cap, pow2 as _pow2
 
 
 class JoinType(enum.IntEnum):
@@ -542,12 +542,10 @@ def stream_expand_capacity(n: int, block_rows: int):
     """cap_e for join_expand_stream: the pow2-bucketed capacity lifted
     to a whole number of expansion blocks. cap_e is a jit cache-key
     parameter on both the local and the distributed stream path, so it
-    routes through benchutils.bucket_cap (1 bucket per octave) rather
+    routes through util.bucket_cap (1 bucket per octave) rather
     than the 16-per-octave mantissa rounding — the specialization
     analysis recognizes this helper as bucketing."""
     blk = block_rows * 128
-    from ..benchutils import bucket_cap as _bucket_cap
-
     return -(-_bucket_cap(n) // blk) * blk
 
 

@@ -47,8 +47,8 @@ from ..telemetry import annotate as _annotate, counted_cache, \
     counter as _counter, host_fetch as _host_fetch, ledger as _ledger, \
     phase as _phase, span as _span
 from . import shard
-from ..benchutils import bucket_cap as _bucket_cap
-from ..util import capacity as _capacity, pow2_floor as _pow2_floor
+from ..util import bucket_cap as _bucket_cap, capacity as _capacity, \
+    pow2_floor as _pow2_floor
 from .shuffle import count_pair, exchange, exchange_pair, \
     replicated_gather
 
@@ -379,27 +379,24 @@ def _finish_exchange_table(t: Table, ctx: CylonContext, targets, emit,
 
 
 def _exchange_table(t: Table, targets, emit, ctx: CylonContext,
-                    extra: Optional[dict] = None, counts=None,
-                    dense: bool = False):
+                    extra: Optional[dict] = None, counts=None):
     """Shuffle a whole table's columns (fixed-width AND varbytes) plus
     optional extra per-row arrays. Returns (columns, new_emit,
-    extra_out). ``dense``: caller asserts ``emit`` is all-live (enables
-    the count-free fused world-1 route)."""
+    extra_out)."""
     payload, lane_cols = _build_exchange_payload(t, ctx, extra)
     out, new_emit, _cap, meta = exchange(payload, targets, emit, ctx,
-                                         counts=counts, dense=dense)
+                                         counts=counts)
     return _finish_exchange_table(t, ctx, targets, emit, out, new_emit,
                                   meta, lane_cols, extra)
 
 
 def _exchange_table_pair(t1: Table, tg1, e1, c1, t2: Table, tg2, e2, c2,
-                         ctx: CylonContext, dense: bool = False):
+                         ctx: CylonContext):
     """Two-table shuffle in ONE compiled program when both sides route
     padded (exchange_pair) — the distributed join/set-op composition."""
     p1, lc1 = _build_exchange_payload(t1, ctx, None)
     p2, lc2 = _build_exchange_payload(t2, ctx, None)
-    r1, r2 = exchange_pair(p1, tg1, e1, c1, p2, tg2, e2, c2, ctx,
-                           dense=dense)
+    r1, r2 = exchange_pair(p1, tg1, e1, c1, p2, tg2, e2, c2, ctx)
     out1, ne1, _cap1, m1 = r1
     out2, ne2, _cap2, m2 = r2
     return (_finish_exchange_table(t1, ctx, tg1, e1, out1, ne1, m1, lc1,
@@ -820,8 +817,7 @@ def shuffle(table: Table, hash_columns: Sequence,
         # NO witness: hot keys are spread positionally across shards
         table._free_if_unretained()
         return _ledger.track(result, "shuffle")
-    cols, new_emit, _x = _exchange_table(t, targets, emit, ctx,
-                                         dense=t.row_mask is None)
+    cols, new_emit, _x = _exchange_table(t, targets, emit, ctx)
     result = Table(cols, ctx, new_emit)
     result._hash_partitioned = sig
     # reference parity: Shuffle frees non-retained inputs (table.cpp:207)
@@ -928,12 +924,13 @@ def repartition(table: Table, ctx: CylonContext) -> Table:
     """Round-robin balance rows across shards (no key)."""
     t = shard.distribute(table, ctx)
     world = ctx.get_world_size()
+    if world == 1:
+        return t
     n = t.capacity
     targets = shard.pin(
         jnp.arange(n, dtype=jnp.int32) % world, ctx)
     cols, new_emit, _x = _exchange_table(
-        t, targets, shard.pin(t.emit_mask(), ctx), ctx,
-        dense=t.row_mask is None)
+        t, targets, shard.pin(t.emit_mask(), ctx), ctx)
     return _ledger.track(Table(cols, ctx, new_emit), "repartition")
 
 
@@ -941,14 +938,11 @@ def repartition(table: Table, ctx: CylonContext) -> Table:
 # distributed join (reference: DistributedJoin, table.cpp:656-696)
 # ---------------------------------------------------------------------------
 
-def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
-                     force_exchange: bool = False) -> Table:
-    """``force_exchange``: run the full shuffle+join composition even on
-    a 1-wide mesh / co-partitioned inputs (the all_to_all still executes)
-    — used by bench.py to time the honest distributed path on one chip."""
+def distributed_join(left: Table, right: Table,
+                     config: _join.JoinConfig) -> Table:
     ctx = left._ctx
     world = ctx.get_world_size()
-    if world == 1 and not (force_exchange and ctx.is_distributed()):
+    if world == 1:
         # reference parity: world==1 short-circuits to the local join
         # (table.cpp:662-669)
         _counter("cylon_join_algorithm_total", {"algo": "local"}).inc()
@@ -969,7 +963,6 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
                 # byte-verifies AFTER the exchange (both key columns are
                 # row-aligned in the output) — INNER filters false
                 # matches, outer joins redo on dictionary codes
-                # (round-5: VERDICT r04 #8 closed the old rejection)
                 exact_pairs.append((li, rj))
 
     left_d = shard.distribute(left, ctx)
@@ -985,8 +978,7 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
         for t, kcols, kidx, other in ((left_d, lcols, lidx, rcols),
                                       (right_d, rcols, ridx, lcols)):
             sig = shard.partition_signature(kcols, kidx, world)
-            if sig is not None and t._hash_partitioned == sig \
-                    and not force_exchange:
+            if sig is not None and t._hash_partitioned == sig:
                 # co-partitioned (prior shuffle or distribute_by_key host
                 # ingest): rows are already hash-placed — skip the exchange
                 plan.append(("skip", t, None, None))
@@ -1008,17 +1000,11 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
         _sp.set(sides_exchanged=len(ex), sides_skipped=2 - len(ex))
         results = {}
         if len(ex) == 2:
-            # 1-wide mesh + dense emits: skip the count sync entirely —
-            # the fused padded body computes counts in-program (round-5)
-            dense = (ex[0][1].row_mask is None
-                     and ex[1][1].row_mask is None)
-            cl = cr = None
-            if world > 1 or not dense:
-                cl, cr = count_pair(ex[0][2], ex[0][3], ex[1][2],
-                                    ex[1][3], ctx)
+            cl, cr = count_pair(ex[0][2], ex[0][3], ex[1][2],
+                                ex[1][3], ctx)
             r1, r2 = _exchange_table_pair(
                 ex[0][1], ex[0][2], ex[0][3], cl,
-                ex[1][1], ex[1][2], ex[1][3], cr, ctx, dense=dense)
+                ex[1][1], ex[1][2], ex[1][3], cr, ctx)
             results[id(ex[0])] = r1
             results[id(ex[1])] = r2
         for p in plan:
@@ -1030,8 +1016,7 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
             if id(p) in results:
                 cols, emit_s, _x = results[id(p)]
             else:
-                cols, emit_s, _x = _exchange_table(
-                    t, targets, emit, ctx, dense=t.row_mask is None)
+                cols, emit_s, _x = _exchange_table(t, targets, emit, ctx)
             shuffled.append((cols, emit_s, emit_s))
 
     # rebuild key bits from the SHUFFLED columns (word lanes reshape out
@@ -1068,8 +1053,8 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
                     lkb, lkv, lemit, rkb, rkv, remit,
                     ldat, lval, rdat, rval)
             # the plan program's replicated counts-gather is a real
-            # collective dispatch — counted, so the adaptive bench's
-            # launch comparison is honest on both algorithms
+            # collective dispatch — counted, so a launch comparison of
+            # the shuffle and broadcast joins is honest on both
             _counter("cylon_collective_launches_total").inc()
             cm = np.asarray(
                 _host_fetch("join.plan", rep_counts)).reshape(world, -1)
@@ -1135,8 +1120,7 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
         if collided:
             # rare path (an actual 96-bit collision): skip the frees —
             # the encoded tables share payload columns with the inputs
-            return _exact_dict_redo(left, right, config, exact_pairs,
-                                    force_exchange)
+            return _exact_dict_redo(left, right, config, exact_pairs)
     # co-partitioning witness on the OUTPUT: every emitted row sits on
     # the shard its join-key hash routed it to, so a later shuffle /
     # pre-partitioned groupby on the same keys can skip its exchange
@@ -1158,8 +1142,7 @@ def distributed_join(left: Table, right: Table, config: _join.JoinConfig,
 
 
 def _exact_post_verify(res: Table, nl: int, pairs, config):
-    """Post-exchange byte verification for exact=True long varbytes keys
-    (round-5, VERDICT r04 #8 — the old path rejected these outright).
+    """Post-exchange byte verification for exact=True long varbytes keys.
     Both key columns sit row-aligned in the join output, so verification
     is one ``VarBytes.equals_rows`` per key pair: INNER joins filter the
     false matches out of the row mask; outer joins report any collision
@@ -1182,7 +1165,7 @@ def _exact_post_verify(res: Table, nl: int, pairs, config):
 
 
 def _exact_dict_redo(left: Table, right: Table, config: _join.JoinConfig,
-                     pairs, force_exchange: bool) -> Table:
+                     pairs) -> Table:
     """Collision recovery for exact outer joins on long varbytes keys:
     re-encode each colliding key pair over ONE shared sorted vocabulary
     (host round trip — paid only when a collision was actually detected,
@@ -1191,8 +1174,7 @@ def _exact_dict_redo(left: Table, right: Table, config: _join.JoinConfig,
     The redo's dictionary-coded key columns are re-materialized as
     varbytes so the recovery path's output schema matches the normal
     path, and the unretained originals are freed once the redo no
-    longer shares their buffers (ADVICE r5 low — this path used to
-    leak retain=False inputs and leak the storage change)."""
+    longer shares their buffers."""
     from ..data.table import _dict_encode_pair
 
     ctx = left._ctx
@@ -1206,7 +1188,7 @@ def _exact_dict_redo(left: Table, right: Table, config: _join.JoinConfig,
                            exact=False)
     res = distributed_join(Table(lcols2, left._ctx, left.row_mask),
                            Table(rcols2, right._ctx, right.row_mask),
-                           cfg, force_exchange=force_exchange)
+                           cfg)
     # decode the redone key columns back through the shared vocab so the
     # output carries varbytes storage exactly like the collision-free path
     from ..data.column import as_varbytes
@@ -1738,13 +1720,11 @@ def broadcast_hash_join(left: Table, right: Table,
 # table.cpp:948-1010 — ShuffleTwoTables on ALL columns + local set op)
 # ---------------------------------------------------------------------------
 
-def distributed_set_op(left: Table, right: Table, op: _setops.SetOp,
-                       force_exchange: bool = False) -> Table:
-    """``force_exchange``: run the full shuffle+set-op composition even
-    on a 1-wide mesh (bench contract, same as distributed_join)."""
+def distributed_set_op(left: Table, right: Table,
+                       op: _setops.SetOp) -> Table:
     ctx = left._ctx
     world = ctx.get_world_size()
-    if world == 1 and not (force_exchange and ctx.is_distributed()):
+    if world == 1:
         return _ledger.track(table_mod.set_op(left, right, op),
                              "distributed_set_op")
     if left.column_count != right.column_count:
@@ -1767,7 +1747,7 @@ def distributed_set_op(left: Table, right: Table, op: _setops.SetOp,
         # exchange ONLY the aligned columns; key bits (word lanes /
         # hash quads / ordered bits) and validity key lanes are
         # recomputed per shard from the shuffled columns — the exchange
-        # stops double-shipping the lanes (round-4 review finding).
+        # does not ship the lanes twice.
         # Both counts fuse into one program + one host sync.
         sides = []
         for cols, t, other in ((lcols, left_d, rcols),
@@ -1777,17 +1757,11 @@ def distributed_set_op(left: Table, right: Table, op: _setops.SetOp,
                 _partition_targets_dist(ctx, cols, other), ctx)
             emit = shard.pin(t.emit_mask(), ctx)
             sides.append((view, targets, emit))
-        # 1-wide mesh + dense emits: count-free fused route (round-5)
-        dense = (world == 1 and left_d.row_mask is None
-                 and right_d.row_mask is None)
-        cl = cr = None
-        if not dense:
-            cl, cr = count_pair(sides[0][1], sides[0][2],
-                                sides[1][1], sides[1][2], ctx)
+        cl, cr = count_pair(sides[0][1], sides[0][2],
+                            sides[1][1], sides[1][2], ctx)
         for (view, targets, emit), cnt in zip(sides, (cl, cr)):
             out_cols, emit_s, _x = _exchange_table(view, targets, emit,
-                                                   ctx, counts=cnt,
-                                                   dense=dense)
+                                                   ctx, counts=cnt)
             shuffled.append((emit_s, out_cols))
 
     (lemit, lcols_s), (remit, rcols_s) = shuffled
@@ -1863,7 +1837,7 @@ def distributed_set_op(left: Table, right: Table, op: _setops.SetOp,
 
 def _groupby_shuffle_agg(ctx: CylonContext, key_columns, value_columns,
                          ops: Tuple, emit, seq, col_ids: Tuple = None,
-                         dense: bool = False, skip_exchange: bool = False):
+                         skip_exchange: bool = False):
     """Shuffle rows by key hash, then aggregate per shard. Returns
     (key_out_cols, agg list of (arr, valid), gvalid). ``col_ids``: static
     source-column names for the aggregate's sub-reduction dedup (repeated
@@ -1886,7 +1860,7 @@ def _groupby_shuffle_agg(ctx: CylonContext, key_columns, value_columns,
             targets = shard.pin(
                 _partition_targets_dist(ctx, key_columns), ctx)
             out_cols, emit_s, _x = _exchange_table(view, targets, emit,
-                                                   ctx, dense=dense)
+                                                   ctx)
 
     nk = len(key_columns)
     kcols_s = out_cols[:nk]
@@ -1964,8 +1938,7 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
         value_columns = [t._columns[vi] for vi in val_cols]
         key_out, agg, gvalid = _groupby_shuffle_agg(
             ctx, key_columns, value_columns, tuple(ops), emit, seq,
-            col_ids=tuple(val_cols), dense=t.row_mask is None,
-            skip_exchange=pre_partitioned)
+            col_ids=tuple(val_cols), skip_exchange=pre_partitioned)
         cols = list(key_out)
         for (arr, av), vi, op in zip(agg, val_cols, ops):
             src = t._columns[vi]
@@ -2182,19 +2155,14 @@ def _dist_order_lanes(ctx: CylonContext, c: Column, a: bool):
     return list(_order.sort_keys([c], [a]))
 
 
-def distributed_sort(table: Table, order_by, ascending=True,
-                     force_exchange: bool = False) -> Table:
+def distributed_sort(table: Table, order_by, ascending=True) -> Table:
     """Splitter-based distributed sort over ANY key combination: sample
     composite key-lane tuples, agree range splitters, range-partition
     through the same exchange the joins use, per-shard fused sort. No
     global gather for multi-key or (short) varbytes ORDER columns; rows
     beyond the device prefix bound (> SORT_PREFIX_WORDS*4-byte strings)
     take the host path. Reference: Sort + sort kernels incl. strings
-    (table.hpp:365, arrow_kernels.cpp:136-317).
-
-    ``force_exchange``: run the full sample+partition+exchange+sort
-    composition even on a 1-wide mesh (bench.py times the honest
-    distributed path on one chip — same contract as distributed_join)."""
+    (table.hpp:365, arrow_kernels.cpp:136-317)."""
     ctx = table._ctx
     t = shard.distribute(table, ctx) if ctx.is_distributed() else table
     by = order_by if isinstance(order_by, (list, tuple)) else [order_by]
@@ -2204,7 +2172,7 @@ def distributed_sort(table: Table, order_by, ascending=True,
     world = ctx.get_world_size()
     order_cols = [t._columns[i] for i in idxs]
 
-    if not (ctx.is_distributed() and (world > 1 or force_exchange)):
+    if not (ctx.is_distributed() and world > 1):
         return t.sort(by, ascending)
 
     per_col = [_dist_order_lanes(ctx, c, a)
@@ -2227,9 +2195,9 @@ def distributed_sort(table: Table, order_by, ascending=True,
         # derived arrays every call, so the key is the source data
         from .shuffle import _count_cached
 
-        # memo key/refs span data + validity + varbytes buffers (ADVICE
-        # r5 low — data ids alone could alias columns differing only in
-        # validity or string content), same discipline as the join memos
+        # memo key/refs span data + validity + varbytes buffers (data
+        # ids alone could alias columns differing only in validity or
+        # string content), same discipline as the join memos
         src_ids, src_refs = table_mod._memo_refs(order_cols)
         if t.row_mask is not None:
             src_ids = src_ids + (id(t.row_mask),)
@@ -2239,8 +2207,7 @@ def distributed_sort(table: Table, order_by, ascending=True,
             src_refs, lambda: _range_splitters(ctx, lanes, emit))
         targets = _splitter_targets(lanes, splitters)
         cols_s, emit_s, _x = _exchange_table(
-            t, shard.pin(targets, ctx), emit, ctx,
-            dense=t.row_mask is None)
+            t, shard.pin(targets, ctx), emit, ctx)
 
     with _phase("distributed_sort.local", seq):
         # key lanes recompute per shard from the shuffled columns —

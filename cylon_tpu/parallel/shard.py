@@ -106,7 +106,7 @@ def distribute(table: Table, ctx: CylonContext) -> Table:
     if table.row_mask is None and total == n:
         # no padding, all rows live: preserve mask-None — downstream
         # routing reads "row_mask is None" as the dense invariant (the
-        # count-free fused world-1 exchange keys on it)
+        # groupby's fused sort carries no dead flag without a mask)
         mask = None
     else:
         mask = jax.device_put(_pad_to(table.emit_mask(), total, False),

@@ -238,44 +238,6 @@ def _send_block(xs, start, o, block, world):
     return jnp.stack(outs)
 
 
-def _padded_body_w1(axis, block, payload, targets, emit):
-    """1-wide-mesh padded body: there is exactly one target, so the
-    all_to_all is the identity and the bucket sort's only job is
-    pushing dead rows to the tail. A device-side cond skips even that
-    when every row is live (stable sort by a constant key IS the
-    identity) — the common all-live case costs one pad memcpy, the way
-    the reference's world-1 MPI path degenerates to memcpy
-    (mpi_channel.cpp:30-247 moves bytes at wire speed). Fused count:
-    counts_in computes in-program, so the caller never needs the host
-    count round trip on a 1-wide mesh."""
-    leaves, treedef = jax.tree.flatten(payload)
-    n = targets.shape[0]
-
-    def pad(x):
-        if block <= x.shape[0]:
-            return x[:block]
-        return jnp.concatenate(
-            [x, jnp.zeros((block - x.shape[0],) + x.shape[1:], x.dtype)])
-
-    _to_varying = _to_varying_fn(axis)
-
-    def live_path(ls):
-        # constants must be cast varying to type-match the sort branch
-        # under shard_map's varying-mesh-axes check
-        return (tuple(pad(x) for x in ls),
-                _to_varying(jnp.full((1,), n, jnp.int32)))
-
-    def sort_path(ls):
-        sorted_ls, counts_out, _start = _bucket_sort(
-            list(ls), targets, emit, 1)
-        return tuple(pad(x) for x in sorted_ls), counts_out
-
-    outs, counts_in = jax.lax.cond(emit.all(), live_path, sort_path,
-                                   tuple(leaves))
-    new_emit = jnp.arange(block, dtype=jnp.int32) < counts_in[0]
-    return jax.tree.unflatten(treedef, list(outs)), new_emit, counts_in
-
-
 # ---------------------------------------------------------------------------
 # the fused partition kernel (ROADMAP item 2 close-out, SURVEY §7): the
 # padded-mode partition — a stable bucket sort by target — is the one
@@ -432,10 +394,7 @@ def _padded_body(axis, world, block, payload, targets, emit,
                  part: str = "sort"):
     """The padded-mode exchange as a pure function of per-shard values —
     shared by the single and the PAIR program builders. ``part`` picks
-    the partition path (world-1 keeps the cond-gated sort: a 1-bucket
-    counting sort buys nothing over the identity fast path)."""
-    if world == 1:
-        return _padded_body_w1(axis, block, payload, targets, emit)
+    the partition path."""
     cap_out = world * block
     sorted_leaves, counts_in, start, new_emit = _padded_partition(
         axis, world, block, payload, targets, emit, part)
@@ -560,11 +519,10 @@ def _partition_body(axis, world, block, cb, payload, targets, emit,
 @counted_cache
 def _exchange_partition_fn(mesh, block: int, chunk_block: int,
                            part: str = "sort"):
-    """UNFUSED partition program of the chunked exchange (no chunk 0):
-    kept as a real dispatchable program so the profiler and the
-    shuffle_pipeline bench can measure the fusion win of
-    `_exchange_chunk_first_fn` against it — with fusion a C-chunk
-    exchange costs C program launches, without it C+1."""
+    """The partition program WITHOUT chunk 0: what `_dispatch_chunked`
+    rebuilds the pipeline state with after a faulted dispatch consumed
+    the donated accumulator. It leaves the accumulators zeroed, so the
+    rebuild replays every landed chunk through the one chunk program."""
     axis = mesh.axis_names[0]
     world = mesh.devices.size
     spec = P(axis)
@@ -629,30 +587,23 @@ def _exchange_chunk_fn(mesh, block: int, chunk_block: int):
 
 
 def _dispatch_chunked(ctx: CylonContext, block: int, cb: int,
-                      chunks: int, payload, targets, emit, fuse: bool,
+                      chunks: int, payload, targets, emit,
                       part: str = "sort"):
-    """Launch the chunked pipeline: one partition program (with chunk 0
-    folded in when ``fuse``), then one chunk program per remaining
-    chunk — dispatched back to back WITHOUT waiting, so chunk N+1's
-    all_to_all runs while chunk N's received rows are compacted (and
-    while the consumer's local kernels on already-landed rows queue
-    behind them). Every dispatch runs under the per-chunk retry policy;
-    re-dispatch is idempotent because the chaos injector fires BEFORE
-    the program consumes its (donated) buffers. Returns (outs,
-    new_emit, counts_in, programs_launched)."""
+    """Launch the chunked pipeline: one partition program with chunk 0
+    folded in, then one chunk program per remaining chunk — dispatched
+    back to back WITHOUT waiting, so chunk N+1's all_to_all runs while
+    chunk N's received rows are compacted (and while the consumer's
+    local kernels on already-landed rows queue behind them). Every
+    dispatch runs under the per-chunk retry policy; re-dispatch is
+    idempotent because the chaos injector fires BEFORE the program
+    consumes its (donated) buffers. Returns (outs, new_emit,
+    counts_in); ``chunks`` programs were launched."""
     mesh = ctx.mesh
-    if fuse:
-        padded, start, counts_in, new_emit, outs = _launch_exchange(
-            lambda: _exchange_chunk_first_fn(mesh, block, cb, part)(
-                payload, targets, emit))
-        k0, programs = 1, chunks
-    else:
-        padded, start, counts_in, new_emit, outs = _launch_exchange(
-            lambda: _exchange_partition_fn(mesh, block, cb, part)(
-                payload, targets, emit))
-        k0, programs = 0, chunks + 1
+    padded, start, counts_in, new_emit, outs = _launch_exchange(
+        lambda: _exchange_chunk_first_fn(mesh, block, cb, part)(
+            payload, targets, emit))
     step = _exchange_chunk_fn(mesh, block, cb)
-    for k in range(k0, chunks):
+    for k in range(1, chunks):
         karr = np.int32(k)
 
         def attempt(karr=karr, k=k):
@@ -675,15 +626,15 @@ def _dispatch_chunked(ctx: CylonContext, block: int, cb: int,
             return step(padded, start, outs, karr)
 
         outs = _launch_exchange(attempt)
-    return outs, new_emit, counts_in, programs
+    return outs, new_emit, counts_in
 
 
-def _record_chunked(sp, chunks: int, cb: int, programs: int) -> None:
+def _record_chunked(sp, chunks: int, cb: int) -> None:
     """Chunk-pipeline observability: per-exchange span attrs plus the
     cylon_exchange_chunks_total counter and the overlap-ratio histogram
-    ((programs-1)/programs — the fraction of the pipeline's programs
-    issued while earlier chunk work was still in flight)."""
-    ratio = (programs - 1) / programs if programs else 0.0
+    ((chunks-1)/chunks — the fraction of the pipeline's programs, one a
+    chunk, issued while earlier chunk work was still in flight)."""
+    ratio = (chunks - 1) / chunks
     sp.set(chunks=chunks, chunk_block=cb,
            overlap_ratio=round(ratio, 4))
     _counter("cylon_exchange_chunks_total").inc(chunks)
@@ -714,41 +665,13 @@ def _exchange_padded_pair_fn(mesh, block1: int, block2: int,
 
 
 def exchange_pair(payload1, targets1, emit1, counts1,
-                  payload2, targets2, emit2, counts2, ctx: CylonContext,
-                  dense: bool = False):
+                  payload2, targets2, emit2, counts2, ctx: CylonContext):
     """Two shuffles in one program when both route to padded mode
     (the uniform-hash common case); otherwise two sequential
     exchanges. Returns (result1, result2) where each result is the
-    exchange() 4-tuple. ``counts1``/``counts2`` may be None on a 1-wide
-    mesh when ``dense`` (both emits all-live): the fused world-1 padded
-    body computes counts in-program (no host count sync at all for the
-    whole two-table shuffle)."""
+    exchange() 4-tuple."""
     world = ctx.get_world_size()
     budget = ctx.memory_pool.comm_budget_bytes()
-    if world == 1 and counts1 is None and counts2 is None and dense:
-        b1 = _pow2(int(targets1.shape[0]))
-        b2 = _pow2(int(targets2.shape[0]))
-        mb1 = _budget_block_cap(payload1, 1, budget, b1, 8)
-        mb2 = _budget_block_cap(payload2, 1, budget, b2, 8)
-        if b1 <= mb1 and b2 <= mb2:
-            seq = ctx.get_next_sequence()
-            rows = int(targets1.shape[0]) + int(targets2.shape[0])
-            nbytes = _payload_nbytes(payload1) + _payload_nbytes(payload2)
-            with _span("shuffle.exchange_pair", seq, world=1,
-                       mode="padded", rows=rows, bytes_moved=nbytes,
-                       block=max(b1, b2)):
-                res = _launch_exchange(
-                    lambda: _exchange_padded_pair_fn(ctx.mesh, b1, b2)(
-                        payload1, targets1, emit1, payload2, targets2,
-                        emit2))
-            _record_exchange(rows, nbytes)
-            out1, emit1_o, ci1, out2, emit2_o, ci2 = res
-            return ((out1, emit1_o, b1,
-                     {"mode": "padded", "block": b1, "counts_in": ci1}),
-                    (out2, emit2_o, b2,
-                     {"mode": "padded", "block": b2, "counts_in": ci2}))
-        return (exchange(payload1, targets1, emit1, ctx, dense=dense),
-                exchange(payload2, targets2, emit2, ctx, dense=dense))
     # buffer_factor=8: the pair program holds BOTH tables' comm buffers
     ok1, b1, _mb1 = _padded_route(counts1, payload1, world, budget,
                                   buffer_factor=8)
@@ -954,10 +877,10 @@ def salted_exchange_targets(targets, emit, ctx: CylonContext,
     return _retry.run_retryable("exchange.count", compute)
 
 
-# Repeat-shuffle count cache (round-5, VERDICT r04 #4a): jax Arrays are
-# immutable, so identical (targets, emit) OBJECTS imply identical counts
-# — iterative pipelines that re-shuffle the same key column (and bench
-# timing loops) skip the ~100 ms count round trip on every repeat.
+# Repeat-shuffle count cache: jax Arrays are immutable, so identical
+# (targets, emit) OBJECTS imply identical counts — iterative pipelines
+# that re-shuffle the same key column skip the count round trip on
+# every repeat.
 # WEAK refs only: entries die with their arrays (no HBM pinned beyond
 # the caller's own lifetime), and a hit additionally verifies object
 # identity so a recycled id can never alias a dead entry.
@@ -1048,8 +971,7 @@ def _padded_route(counts, payload, world: int, budget,
 def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
              emit: jnp.ndarray, ctx: CylonContext,
              max_block: Optional[int] = None,
-             counts: Optional[np.ndarray] = None,
-             dense: bool = False, fuse: bool = True
+             counts: Optional[np.ndarray] = None
              ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, int, dict]:
     """Shuffle a pytree of row-sharded per-row arrays to their target shards.
 
@@ -1073,37 +995,11 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
     exchanges whose payload exceeds CYLON_EXCHANGE_CHUNK_BYTES run as
     the chunked, double-buffered pipeline (meta gains ``chunks``;
     ``CYLON_EXCHANGE_OVERLAP=0`` restores the single-shot program, and
-    the two paths are bit-identical on every live row). ``fuse`` folds
-    the partition program into chunk 0 (on by default; the bench's
-    launch-count comparison is the only caller that turns it off).
+    the two paths are bit-identical on every live row).
     ``max_block`` caps the per-round blockwise block size.
     """
     world = ctx.get_world_size()
     seq = ctx.get_next_sequence()
-    budget0 = ctx.memory_pool.comm_budget_bytes()
-    if world == 1 and counts is None and dense:
-        # fused count+exchange (round-5, VERDICT r04 #4b): on a 1-wide
-        # mesh the padded route with block = pow2(n) is always exact, so
-        # the host count round trip is pure overhead — counts_in
-        # computes inside the exchange program itself. Gated on the
-        # caller asserting a dense emit (``dense``): for sparse-emit
-        # tables the counted route's pow2(live) capacity beats saving
-        # one sync. MAX_BLOCK (a per-ROUND comm-buffer cap) does not
-        # bind here: there are no rounds, only the memory budget
-        block1 = _pow2(int(targets.shape[0]))
-        mb1 = _budget_block_cap(payload, 1, budget0, block1
-                                if max_block is None else max_block, 4)
-        if block1 <= mb1:
-            rows = int(targets.shape[0])
-            nbytes = _payload_nbytes(payload)
-            with _span("shuffle.exchange", seq, world=1, mode="padded",
-                       rows=rows, bytes_moved=nbytes, block=block1):
-                out, new_emit, counts_in = _launch_exchange(
-                    lambda: _exchange_padded_fn(
-                        ctx.mesh, block1)(payload, targets, emit))
-            _record_exchange(rows, nbytes)
-            return out, new_emit, block1, {
-                "mode": "padded", "block": block1, "counts_in": counts_in}
     if counts is None:
         def compute():
             with _span("shuffle.count", seq, world=world, tables=1):
@@ -1143,11 +1039,11 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
             _record_partition(sp, part)
             cb, chunks = _chunk_plan(block_p, world, row_bytes)
             if chunks > 1:
-                out, new_emit, counts_in, programs = _dispatch_chunked(
+                out, new_emit, counts_in = _dispatch_chunked(
                     ctx, block_p, cb, chunks, payload, targets, emit,
-                    fuse, part)
-                _record_chunked(sp, chunks, cb, programs)
-                _record_exchange(rows_live, nbytes, programs)
+                    part)
+                _record_chunked(sp, chunks, cb)
+                _record_exchange(rows_live, nbytes, chunks)
                 return out, new_emit, cap_padded, {
                     "mode": "padded", "block": block_p,
                     "counts_in": counts_in, "chunks": chunks}

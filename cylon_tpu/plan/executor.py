@@ -198,8 +198,8 @@ def execute_analyzed(plan: ir.PlanNode, ctx=None, stats=None,
 
     The whole run nests under one ``plan.query`` span (the report's
     span tree); HBM gauges are sampled from the context's MemoryPool
-    after the run, the registry snapshot rides along so a BENCH
-    artifact is one ``report.to_dict()`` away, and the ledger's
+    after the run, the registry snapshot rides along in
+    ``report.to_dict()``, and the ledger's
     end-of-query leak report (allocated under this root span, never
     freed, query result excluded) lands on ``report.leaks``. Deadline
     expiry and admission sheds raise INSIDE the ``plan.query`` span,

@@ -11,9 +11,8 @@ shapes those measurements into a `PlanReport`:
   measured totals as trailing ``--`` lines. Shuffle markers folded
   into a join's fused exchange render as ``(folded into parent
   exchange)`` — they never execute standalone (executor docstring).
-* ``to_dict()`` — the machine-comparable form bench.py embeds in
-  BENCH_*.json artifacts (nested node records + global counters), so
-  the perf trajectory across rounds is diffable without parsing text.
+* ``to_dict()`` — the machine-comparable form (nested node records +
+  global counters), diffable without parsing text.
 * ``span`` — the raw span TREE of the whole query (a telemetry.Span),
   for JSONL export or programmatic walks.
 

@@ -223,8 +223,7 @@ def record(decision: Decision, tenant: Optional[str] = None
     _metrics.REGISTRY.counter("cylon_admission_total",
                               {"decision": decision.action}).inc()
     # which estimator is steering admission — the closed-loop health
-    # signal (bench surfaces the measured-admit count as
-    # service_pipeline.stats_informed_admits)
+    # signal
     _metrics.REGISTRY.counter(
         "cylon_admission_est_source_total",
         {"source": decision.est_source}).inc()

@@ -10,7 +10,7 @@ Two wire formats, both deliberately boring:
 * **Prometheus text exposition** — the v0.0.4 text format rendered
   from a MetricsRegistry: counters/gauges as single samples,
   histograms as cumulative ``_bucket{le=...}`` + ``_sum`` + ``_count``.
-  Scrape-ready, and diff-able across BENCH rounds.
+  Scrape-ready, and diff-able across runs.
 
 The ``jax.profiler.TraceAnnotation`` carrier is NOT here — it lives
 inside spans.span itself, so Perfetto labels keep working with no

@@ -22,7 +22,7 @@ explicit lifetime events:
 What this buys:
 
 * ``cylon_live_table_bytes{owner=...}`` gauges — live tracked bytes per
-  owner label, in every Prometheus dump and BENCH artifact;
+  owner label, in every Prometheus dump;
 * ``live_bytes()`` — the pool's fallback live-HBM source on backends
   that hide ``memory_stats`` (memory.MemoryPool.set_external_source),
   so span ``hbm_delta``/``hbm_peak`` attrs and crash-dump watermarks
@@ -246,8 +246,8 @@ def leak_report(root_id: int, exclude: Optional[set] = None
 
 
 def leak_count() -> int:
-    """Live non-borrowed entries, any root — the BENCH artifact's
-    whole-run leak signal."""
+    """Live non-borrowed entries, any root — the whole-run leak
+    signal."""
     with _lock:
         return sum(1 for e in _entries.values() if not e.borrowed)
 

@@ -5,7 +5,7 @@ The quantitative half of the observability layer — where spans answer
 reference logs and then drops (rows exchanged, shuffle bytes, HBM
 watermarks, program builds). Everything is process-local, cheap
 (plain attribute adds under the GIL), and exported either as a plain
-dict (``snapshot()`` — the BENCH artifact form) or Prometheus text
+dict (``snapshot()``) or Prometheus text
 (export.prometheus_text).
 
 Well-known series (full catalog: docs/telemetry.md):
@@ -214,7 +214,7 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         """Plain JSON-able dict keyed by the rendered series name —
         counters/gauges map to their value, histograms to
-        {count, sum, min, max}. The BENCH artifact form."""
+        {count, sum, min, max}."""
         out = {}
         for name, labels, m in self.series():
             key = format_series(name, labels)

@@ -614,8 +614,8 @@ class StatsStore:
 
 def qerror_quantiles() -> Dict[str, dict]:
     """Per-node-kind q-error p50/p95 + observation count, read back
-    from the registry histograms — the observatory summary the /stats
-    route and the bench artifact share."""
+    from the registry histograms — the observatory summary of the
+    /stats route."""
     out: Dict[str, dict] = {}
     for name, labels, m in _metrics.REGISTRY.series():
         if name != "cylon_estimate_qerror" or m.kind != "histogram":

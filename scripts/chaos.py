@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Chaos drill: seeded fault plans swept through the bench pipeline.
+"""Chaos drill: seeded fault plans swept through a join→groupby pipeline.
 
 Proves the resilience layer END TO END, deterministically (wired into
 scripts/check.sh after the telemetry smoke gate):

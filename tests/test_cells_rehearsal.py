@@ -1,0 +1,119 @@
+"""Every cell of BENCHMARK.json, rehearsed on the CPU at a test size.
+
+The benchmark under ``benchmarks/`` is the one yardstick a number may
+come from, and the driver runs it only after a PR is handed in. So that a
+PR whose program no longer runs a cell, or no longer emits a counter a
+metric file reads, learns it from tier-1 and not as a ``null`` in the
+ledger, each cell runs here through every phase of ``benchmarks/run.py``
+(``--scale``: off a TPU the run ends with exit 1 and no result line),
+sound and as the control, and every metric file that names a counter of
+the program finds that counter's family after the run.
+
+Each run is a child process, as on the chip: x64 off and four virtual CPU
+devices instead of this suite's x64 on and eight, its counters its own,
+and a time limit of its own.
+"""
+import functools
+import glob
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+SCALE = 0.0005        # 8,000 rows a side a chip; 50,000 for the groupby
+LIMIT_S = 120         # a run takes 4-8 s
+
+_CHILD = """
+import argparse, importlib, json, os, sys
+script, cell, scale, control = sys.argv[1:5]
+sys.path.insert(0, os.path.dirname(os.path.abspath(script)))
+run = importlib.import_module(os.path.splitext(os.path.basename(script))[0])
+result, code = run.run(argparse.Namespace(
+    workload=cell, seed=2147483659, seconds=0.5, trace=0,
+    scale=float(scale), control=int(control)))
+from cylon_tpu import telemetry
+print("REHEARSED " + json.dumps({
+    "code": code, "correct": result["correct"],
+    "attempted": result["attempted"], "failed": result["failed"],
+    "series": sorted(k for k, v in telemetry.metrics_snapshot().items()
+                     if isinstance(v, (int, float)))}))
+"""
+
+
+@functools.lru_cache(maxsize=None)
+def rehearse(cell, control):
+    """(what the run said of itself, its whole output)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_ENABLE_X64="0",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env.pop("CYLON_TPU_VERIFY_PLANS", None)   # the chip's run has none
+    proc = subprocess.run(
+        [sys.executable, "-c", _CHILD, BENCH["command"][-1], cell,
+         str(SCALE), str(control)],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=LIMIT_S)
+    said = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("REHEARSED ")]
+    assert said, (f"{cell}: the run did not reach its end (exit "
+                  f"{proc.returncode})\n{proc.stdout[-3000:]}\n"
+                  f"{proc.stderr[-3000:]}")
+    return json.loads(said[-1][len("REHEARSED "):]), proc.stdout
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_rehearses_correct(cell):
+    said, out = rehearse(cell, 0)
+    assert said["code"] == 1 and "every phase ran" in out   # not a TPU
+    assert said["correct"] is True, out[-3000:]
+    assert said["attempted"] >= 1 and said["failed"] == 0
+    window = re.search(r"window: \d+ queries .* (\d+) compile\(s\)", out)
+    assert window and int(window.group(1)) == 0, out[-3000:]
+    assert "MISMATCH" not in out
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_control_is_not_correct(cell):
+    """The reference in the next lower precision, put in the program's
+    place, has to come out wrong: the comparison can tell."""
+    said, out = rehearse(cell, 1)
+    assert said["correct"] is False
+    assert said["failed"] == 0 and "MISMATCH" in out
+
+
+def _counter_metrics():
+    """(metric, the counter families its file names) for every metric file
+    whose reducer reads the program's counters."""
+    found = []
+    for path in sorted(glob.glob(os.path.join(
+            ROOT, BENCH["paths"][0], "metrics", "*.json"))):
+        with open(path) as f:
+            spec = json.load(f)
+        names = [spec[k] for k in ("prefix", "numerator", "denominator")
+                 if k in spec]
+        if names:
+            found.append((os.path.splitext(os.path.basename(path))[0],
+                          sorted({n.split("{")[0] for n in names})))
+    return found
+
+
+COUNTER_METRICS = _counter_metrics()
+
+
+@pytest.mark.parametrize("metric,families", COUNTER_METRICS,
+                         ids=[m for m, _ in COUNTER_METRICS])
+def test_metric_finds_its_counter(metric, families):
+    """After the rehearsal of one cell that lists the metric, the program
+    has emitted every counter family the metric's file names (the label a
+    reducer then picks, ``path="stream"``, may belong to the chip)."""
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    cell = entry.get("workloads", CELLS)[0]
+    said, _out = rehearse(cell, 0)
+    for family in families:
+        assert any(s.split("{")[0] == family for s in said["series"]), \
+            f"{metric}: no series of {family} after {cell}"

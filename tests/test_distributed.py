@@ -241,12 +241,49 @@ def test_dist_join_result_feeds_next_op(dist_ctx):
     assert g.row_count > 0
 
 
-def test_world1_distributed_falls_back_to_local():
+def _world1_join(a, b):
+    return (a.distributed_join(b, "inner", on="k"),
+            a.join(b, "inner", on="k"))
+
+
+def _world1_set_op(a, b):
+    return a.distributed_union(a), a.union(a)
+
+
+def _world1_groupby(a, b):
+    return (dist_ops.distributed_groupby(a, "k", ["v"],
+                                         [ct.AggregationOp.SUM]),
+            a.groupby("k", ["v"], ["sum"]))
+
+
+def _world1_sort(a, b):
+    return dist_ops.distributed_sort(a, "v", False), a.sort("v", False)
+
+
+def _world1_shuffle(a, b):
+    return dist_ops.shuffle(a, ["k"]), a
+
+
+@pytest.mark.parametrize("op", [_world1_join, _world1_set_op,
+                                _world1_groupby, _world1_sort,
+                                _world1_shuffle],
+                         ids=["join", "set_op", "groupby", "sort",
+                              "shuffle"])
+def test_world1_distributed_falls_back_to_local(op, monkeypatch):
+    """Reference parity (table.cpp:662-669): on a 1-wide mesh every
+    distributed operator IS its local twin, and nothing enters the
+    exchange."""
+    def no_exchange(*a, **kw):
+        raise AssertionError("a 1-wide operator entered the exchange")
+
+    monkeypatch.setattr(dist_ops, "exchange", no_exchange)
+    monkeypatch.setattr(dist_ops, "exchange_pair", no_exchange)
     ctx = ct.CylonContext.InitDistributed(ct.TPUConfig(world_size=1))
     a = ct.Table.from_pydict(ctx, {"k": [1, 2, 2], "v": [1., 2., 3.]})
     b = ct.Table.from_pydict(ctx, {"k": [2, 3], "u": [10, 20]})
-    j = a.distributed_join(b, "inner", on="k")
-    assert j.row_count == 2
+    got, want = op(a, b)
+    assert got.row_count == want.row_count
+    assert_rows_equal(got.to_pandas(), want.to_pandas())
 
 
 # ---------------------------------------------------------------------------
@@ -559,90 +596,6 @@ def test_hash_partition_device_resident_with_strings(local_ctx, monkeypatch):
     assert sorted(all_rows) == sorted(zip(keys, range(n)))
 
 
-# ---------------------------------------------------------------------------
-# round-5: fused world-1 exchange (count-free, device-side identity when
-# dense) + the dense routing gate
-# ---------------------------------------------------------------------------
-
-def test_world1_fused_exchange_skips_count(monkeypatch):
-    """Dense 1-wide-mesh shuffles must never pay the host count sync:
-    counts compute in-program (VERDICT r04 #4b). Masked tables keep the
-    counted route (pow2(live) capacity beats saving one sync)."""
-    import jax
-
-    from cylon_tpu.ops.join import JoinConfig
-    from cylon_tpu.parallel import shuffle as _shuffle
-
-    counted = {"n": 0}
-    orig1, orig2 = _shuffle._count_fn, _shuffle._count2_fn
-
-    def spy1(mesh):
-        counted["n"] += 1
-        return orig1(mesh)
-
-    def spy2(mesh):
-        counted["n"] += 1
-        return orig2(mesh)
-
-    monkeypatch.setattr(_shuffle, "_count_fn", spy1)
-    monkeypatch.setattr(_shuffle, "_count2_fn", spy2)
-    ctx1 = ct.CylonContext.InitDistributed(
-        ct.TPUConfig(devices=(jax.devices()[0],)))
-    rng = np.random.default_rng(0)
-    n = 2048  # pow2: distribute adds no padding, row_mask stays None
-    left = ct.Table.from_pydict(ctx1, {
-        "k": rng.integers(0, 500, n).astype(np.int32),
-        "v": rng.normal(size=n).astype(np.float32)})
-    right = ct.Table.from_pydict(ctx1, {
-        "k": rng.integers(0, 500, n).astype(np.int32),
-        "w": rng.normal(size=n).astype(np.float32)})
-    assert left.row_mask is None and right.row_mask is None
-
-    dj = dist_ops.distributed_join(left, right,
-                                   JoinConfig.InnerJoin([0], [0]),
-                                   force_exchange=True)
-    assert dj.row_count == left.join(right, "inner", on="k").row_count
-    assert counted["n"] == 0, "dense w1 join must not run a count program"
-
-    s = dist_ops.distributed_sort(left, "k", force_exchange=True)
-    assert np.array_equal(np.asarray(s.to_pydict()["k"]),
-                          np.sort(np.asarray(left.to_pydict()["k"])))
-    assert counted["n"] == 0, "dense w1 sort must not run a count program"
-
-    # masked input: counted route engages (dense gate)
-    fm = left.filter_mask(left._columns[0].data < 100)
-    dj2 = dist_ops.distributed_join(fm, right,
-                                    JoinConfig.InnerJoin([0], [0]),
-                                    force_exchange=True)
-    assert dj2.row_count == fm.join(right, "inner", on="k").row_count
-    assert counted["n"] >= 1
-
-
-def test_world1_fused_exchange_dead_rows(monkeypatch):
-    """The fused body's device-side cond: dead rows route through the
-    compaction sort branch and come out dropped, in stable order."""
-    import jax
-    import jax.numpy as jnp
-
-    from cylon_tpu.parallel import shard as _shard
-    from cylon_tpu.parallel.shuffle import exchange
-
-    ctx1 = ct.CylonContext.InitDistributed(
-        ct.TPUConfig(devices=(jax.devices()[0],)))
-    n = 512
-    rng = np.random.default_rng(1)
-    a = rng.integers(0, 1 << 30, n).astype(np.int32)
-    emit = np.ones(n, bool)
-    emit[::3] = False
-    out, ne, cap, meta = exchange(
-        {"a": _shard.pin(jnp.asarray(a), ctx1)},
-        _shard.pin(jnp.zeros(n, np.int32), ctx1),
-        _shard.pin(jnp.asarray(emit), ctx1), ctx1, dense=True)
-    got = np.asarray(out["a"])[np.asarray(ne)]
-    assert np.array_equal(got, a[emit]), "stable live-prefix compaction"
-    assert meta["mode"] == "padded" and cap == 512
-
-
 def test_to_pydict_local_roundtrip(dist_ctx):
     """extract_process_local: single-controller processes own every
     shard, so the local extract must equal the global content — incl.
@@ -749,8 +702,7 @@ def test_exact_redo_schema_and_free(dist_ctx):
     left2.retain_memory(False)
     cfg = JoinConfig(JoinType.LEFT, [0], [0], JoinAlgorithm.SORT,
                      exact=True)
-    res = _exact_dict_redo(left2, right2, cfg, [(0, 0)],
-                           force_exchange=False)
+    res = _exact_dict_redo(left2, right2, cfg, [(0, 0)])
     nl = 2
     assert res.get_column(0).is_varbytes, "left key not varbytes"
     assert res.get_column(nl).is_varbytes, "right key not varbytes"
@@ -791,8 +743,7 @@ def test_exact_redo_ledger_zero_outstanding_unretained(dist_ctx):
     ledger.track(right, "redo_input_retained")
     cfg = JoinConfig(JoinType.LEFT, [0], [0], JoinAlgorithm.SORT,
                      exact=True)
-    res = _exact_dict_redo(left, right, cfg, [(0, 0)],
-                           force_exchange=False)
+    res = _exact_dict_redo(left, right, cfg, [(0, 0)])
     assert res.row_count > 0
     owners = [e["owner"] for e in ledger.outstanding()]
     assert "redo_input_unretained" not in owners, \

@@ -4,8 +4,9 @@ Core contract: the chunked path is BIT-IDENTICAL to the single-shot
 padded program on every live row — same emit mask, same counts_in, same
 capacity — across chunk counts (1, 2, deep, odd remainder, chunk >
 payload), under per-chunk transient faults, and end to end through the
-distributed-op compositions. The fused partition+chunk-0 program must
-launch strictly fewer collective programs than the unfused form.
+distributed-op compositions. A retry that finds its donated accumulator
+consumed rebuilds the pipeline through the partition program without
+chunk 0 and lands on the same bits.
 """
 import os
 
@@ -107,22 +108,6 @@ def test_chunked_bit_identical_odd_remainder(dist_ctx, monkeypatch):
     assert out[3]["chunks"] == -(-base[3]["block"] // 3)
 
 
-def test_chunked_world1_counted_route(monkeypatch):
-    """The counted padded route chunks even on a 1-wide mesh (the
-    1-chip bench shape): all_to_all is the identity, the pipeline
-    still bounds comm-buffer peaks."""
-    ctx = ct.CylonContext.InitDistributed(ct.TPUConfig(world_size=1))
-    payload, targets, emit = _mk_exchange_inputs(ctx, 2048, seed=5)
-    counts = _counts(ctx, targets, emit)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
-    base = _run(ctx, payload, targets, emit, counts)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
-    out = _run(ctx, payload, targets, emit, counts)
-    _assert_bit_identical(base, out)
-    assert out[3]["chunks"] > 1
-
-
 def test_chunked_skew_attrs_match_single_shot(dist_ctx, monkeypatch):
     """Skew span attributes ride the ONE host count matrix, so a
     chunked exchange reports exactly the single-shot combined matrix —
@@ -183,10 +168,25 @@ def test_chunked_per_chunk_retry_bit_identical(dist_ctx, monkeypatch):
     _assert_bit_identical(base, out)
 
 
-def test_fused_partition_launches_strictly_fewer(dist_ctx, monkeypatch):
-    """The fused partition+chunk-0 program: a C-chunk exchange costs C
-    collective launches; the unfused form costs C+1."""
-    payload, targets, emit = _mk_exchange_inputs(dist_ctx, 4096, seed=11)
+class _Consumed:
+    """What a donated accumulator looks like after the dispatch that
+    took it (donation is a no-op on the CPU, so a real one never is)."""
+
+    def is_deleted(self):
+        return True
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_rebuild_after_consumed_donation_is_bit_identical(dist_ctx,
+                                                          monkeypatch, k):
+    """A retried chunk ``k`` that finds its accumulator consumed (after
+    the fused first program for k = 1, after a chunk program for k = 2)
+    rebuilds the pipeline from the never-donated payload: the partition
+    program WITHOUT chunk 0 runs once, chunks 0..k-1 replay, and the
+    result is bit-identical to the undisturbed run (C launches counted)."""
+    import jax
+
+    payload, targets, emit = _mk_exchange_inputs(dist_ctx, 16384, seed=11)
     counts = _counts(dist_ctx, targets, emit)
     monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
     monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
@@ -196,15 +196,81 @@ def test_fused_partition_launches_strictly_fewer(dist_ctx, monkeypatch):
             "cylon_collective_launches_total", 0)
 
     l0 = launches()
-    fused = _run(dist_ctx, payload, targets, emit, counts, fuse=True)
-    l1 = launches()
-    unfused = _run(dist_ctx, payload, targets, emit, counts, fuse=False)
-    l2 = launches()
-    chunks = fused[3]["chunks"]
-    assert chunks > 1
-    assert l1 - l0 == chunks          # fused: C programs
-    assert l2 - l1 == chunks + 1      # unfused: partition + C
-    _assert_bit_identical(fused, unfused)
+    base = _run(dist_ctx, payload, targets, emit, counts)
+    chunks = base[3]["chunks"]
+    assert chunks > 2
+    assert launches() - l0 == chunks
+
+    real = {n: getattr(_shuffle, n) for n in (
+        "_exchange_chunk_first_fn", "_exchange_chunk_fn",
+        "_exchange_partition_fn")}
+    steps, rebuilds = [], []
+
+    def consumed(tree):
+        return jax.tree.map(lambda _: _Consumed(), tree)
+
+    def first_fn(*key):
+        def first(*args):
+            *state, outs = real["_exchange_chunk_first_fn"](*key)(*args)
+            return (*state, consumed(outs) if k == 1 else outs)
+        return first
+
+    def step_fn(*key):
+        def step(padded, start, outs, i):
+            steps.append(int(i))
+            out = real["_exchange_chunk_fn"](*key)(padded, start, outs, i)
+            # chunk k-1 lands, then its buffers go
+            return consumed(out) if len(steps) == k - 1 else out
+        return step
+
+    def part_fn(*key):
+        def part(*args):
+            rebuilds.append(key)
+            return real["_exchange_partition_fn"](*key)(*args)
+        return part
+
+    monkeypatch.setattr(_shuffle, "_exchange_chunk_first_fn", first_fn)
+    monkeypatch.setattr(_shuffle, "_exchange_chunk_fn", step_fn)
+    monkeypatch.setattr(_shuffle, "_exchange_partition_fn", part_fn)
+    out = _run(dist_ctx, payload, targets, emit, counts)
+    assert len(rebuilds) == 1
+    # chunks up to k-1, then before chunk k the replay of 0..k-1, the rest
+    assert steps == list(range(1, k)) + list(range(k)) \
+        + list(range(k, chunks))
+    _assert_bit_identical(base, out)
+
+
+def _one_wide_repartition(t):
+    from cylon_tpu.parallel import dist_ops
+
+    return dist_ops.repartition(t, t.context)
+
+
+def _one_wide_task_exchange(t):
+    from cylon_tpu.plan import LogicalTaskPlan, task_exchange
+
+    ids = np.arange(t.capacity) % 3
+    return task_exchange(t, ids, LogicalTaskPlan({0: 0, 1: 0, 2: 0}, 1))
+
+
+@pytest.mark.parametrize("op", [_one_wide_repartition,
+                                _one_wide_task_exchange],
+                         ids=["repartition", "task_exchange"])
+def test_one_wide_context_keeps_live_rows_in_order(op):
+    """On a 1-wide distributed context there is one target: what comes
+    back is the input's live rows, in their order (task_exchange takes
+    the general counted route, repartition returns its input)."""
+    ctx = ct.CylonContext.InitDistributed(ct.TPUConfig(world_size=1))
+    rng = np.random.default_rng(5)
+    n = 1000
+    k = rng.integers(0, 1 << 30, n).astype(np.int32)
+    v = rng.normal(size=n).astype(np.float32)
+    t = ct.Table.from_pydict(ctx, {"k": k, "v": v})
+    t = t.filter_mask(t.get_column(0).data % 3 != 0)
+    live = k % 3 != 0
+    got = op(t).to_pandas()
+    assert np.array_equal(got["k"].to_numpy(), k[live])
+    assert np.array_equal(got["v"].to_numpy(), v[live])
 
 
 def test_exchange_pair_routes_through_chunked(dist_ctx, monkeypatch):

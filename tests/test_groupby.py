@@ -292,10 +292,8 @@ def test_distributed_groupby_preagg_reduces_shuffle_rows(dist_ctx):
     seen = []
     orig = _shuffle.exchange
 
-    def spy(payload, targets, emit, ctx, max_block=None, counts=None,
-            dense=False):
-        out = orig(payload, targets, emit, ctx, max_block, counts=counts,
-                   dense=dense)
+    def spy(payload, targets, emit, ctx, max_block=None, counts=None):
+        out = orig(payload, targets, emit, ctx, max_block, counts=counts)
         import jax
         seen.append(int(np.asarray(jax.device_get(emit)).sum()))
         return out

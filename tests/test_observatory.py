@@ -1,8 +1,7 @@
 """Performance-observatory tests: shuffle skew metrics (span attrs,
 registry histograms, EXPLAIN ANALYZE columns), the host-fetch choke point
-(one counted, spanned sync per decision fetch), the jax.monitoring
-listener (trace/lower/compile/cache-load seconds by stage and span), and
-bench timer precision."""
+(one counted, spanned sync per decision fetch) and the jax.monitoring
+listener (trace/lower/compile/cache-load seconds by stage and span)."""
 import numpy as np
 import pytest
 
@@ -482,38 +481,3 @@ def test_explain_analyze_node_time_waits_for_the_device(local_ctx,
             assert order[i + 1] == "clock"
 
 
-# ---------------------------------------------------------------------------
-# bench timer precision (satellite: BENCH_r05 wall_s_best 0.0)
-# ---------------------------------------------------------------------------
-
-
-def test_round_sig_keeps_submillisecond_walls():
-    from cylon_tpu.benchutils import round_sig
-
-    assert round_sig(0.0000234567891) == 0.0000234568
-    assert round_sig(0.023456789) == 0.0234568
-    assert round_sig(1234567.891) == 1234570.0
-    assert round_sig(0.0) == 0.0
-    assert round_sig(float("inf")) == float("inf")
-    assert round_sig(7) == 7  # non-floats pass through
-
-
-def test_bench_sig_matches_benchutils():
-    import bench
-    from cylon_tpu.benchutils import round_sig
-
-    for v in (0.00012345678, 0.9876543, 123456.789):
-        assert bench._sig(v) == round_sig(v)
-
-
-def test_bench_walls_nonzero_and_consistent(local_ctx):
-    """A sub-millisecond config must report a nonzero wall that is
-    self-consistent with its rate (rate * wall ≈ rows)."""
-    import bench
-
-    ctx = bench._mk_ctx()
-    res = bench.bench_local_join(ctx, 1 << 8, iters=1)
-    wall = res["wall_s_best"]
-    assert wall > 0.0
-    rows = res["rows_per_s_per_chip"] * wall
-    assert rows == pytest.approx(2 * (1 << 8), rel=1e-3)

@@ -6,7 +6,7 @@ histogram+scatter kernel (interpreter off-TPU), and the two paths are
 BIT-IDENTICAL on every live row — leaves, counts, start offsets, emit
 mask — across dtypes (varbytes word legs included), chunk geometry
 (single-shot / deep / odd remainder), empty buckets, all-dead emit
-masks, world-1, and end to end through distributed_join /
+masks, and end to end through distributed_join /
 distributed_groupby. `CYLON_PARTITION_KERNEL=sort` restores the exact
 pre-kernel program (the path string keys every factory cache).
 
@@ -245,20 +245,6 @@ def test_exchange_bit_identical_all_dead(dist_ctx, monkeypatch):
                             monkeypatch)
     assert not np.asarray(base[1]).any()
     _assert_bit_identical(base, out)
-
-
-def test_world1_counted_route_stays_on_sort(monkeypatch):
-    """A 1-wide mesh has one bucket — the kernel buys nothing, so
-    routing pins world-1 to the sort path even under a forced knob,
-    and the counted route stays correct."""
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
-    ctx = ct.CylonContext.InitDistributed(ct.TPUConfig(world_size=1))
-    payload, targets, emit = _mk_inputs(ctx, 1024, seed=10)
-    assert _shuffle._partition_path(ctx.mesh, 1, payload) == "sort"
-    base, out = _both_paths(ctx, payload, targets, emit, monkeypatch)
-    _assert_bit_identical(base, out)
-    snap = telemetry.metrics_snapshot()
-    assert snap.get('cylon_partition_path_total{path="sort"}', 0) >= 2
 
 
 # ---------------------------------------------------------------------------

@@ -438,8 +438,8 @@ def test_explain_mentions_elision(dist_ctx):
 # ---------------------------------------------------------------------------
 
 
-def test_explain_analyze_bench_pipeline_shuffle_counts(dist_ctx):
-    """The acceptance pin: on the plan_pipeline bench query shape
+def test_explain_analyze_pipeline_shuffle_counts(dist_ctx):
+    """The acceptance pin: on the join→groupby pipeline
     (join on k → groupby on k), explain(analyze=True) shows per-node
     measured rows/bytes/ms, and its reported shuffle count equals
     collect_phases.count("plan.shuffle") — 1 optimized vs 2 eager."""

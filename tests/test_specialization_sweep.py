@@ -19,7 +19,7 @@ import pytest
 
 import cylon_tpu as ct
 from cylon_tpu import telemetry, util
-from cylon_tpu.benchutils import bucket_cap
+from cylon_tpu.util import bucket_cap
 from cylon_tpu.parallel import dist_ops, distribute
 
 # per-side row counts chosen so the union's per-shard materialize

@@ -599,12 +599,12 @@ def test_exact_join_survives_forced_hash_collision(ctx, monkeypatch):
         assert gset == eset, jt
 
 
-def test_exact_distributed_join_long_keys(dist_ctx, monkeypatch):
-    """Round-5 (VERDICT r04 #8): exact=True on DISTRIBUTED long-key
-    joins byte-verifies after the exchange instead of rejecting. With
-    every content hash forced to collide, INNER filters the false
-    matches on device and LEFT redoes the join on shared-vocabulary
-    dictionary codes."""
+@pytest.mark.parametrize("how", ["inner", "left", "right", "outer"])
+def test_exact_distributed_join_long_keys(how, dist_ctx, monkeypatch):
+    """exact=True on DISTRIBUTED long-key joins byte-verifies after the
+    exchange instead of rejecting. With every content hash forced to
+    collide, INNER filters the false matches on device and the outer
+    joins redo on shared-vocabulary dictionary codes."""
     from cylon_tpu.ops.join import JoinConfig, JoinType
     from cylon_tpu.parallel import dist_ops
 
@@ -627,37 +627,22 @@ def test_exact_distributed_join_long_keys(dist_ctx, monkeypatch):
 
     ldf = pd.DataFrame({"k": lk, "v": np.arange(40)})
     rdf = pd.DataFrame({"k": rk, "w": np.arange(40)})
-    exp = ldf.merge(rdf, on="k")
-    cfg = JoinConfig(JoinType.INNER, [0], [0], exact=True)
-    j = dist_ops.distributed_join(lt, rt, cfg,
-                                  force_exchange=True).to_pandas()
-    assert len(j) == len(exp) == 20
-    assert sorted(j.iloc[:, 0]) == sorted(exp["k"])
-
-    cfg = JoinConfig(JoinType.LEFT, [0], [0], exact=True)
-    j = dist_ops.distributed_join(lt, rt, cfg,
-                                  force_exchange=True).to_pandas()
-    assert len(j) == 40
-    gm = j.dropna(subset=[j.columns[-1]])
-    assert len(gm) == 20
-    assert sorted(gm.iloc[:, 0]) == sorted(exp["k"])
-
-    for jt, how in ((JoinType.RIGHT, "right"),
-                    (JoinType.FULL_OUTER, "outer")):
-        cfg = JoinConfig(jt, [0], [0], exact=True)
-        j = dist_ops.distributed_join(lt, rt, cfg,
-                                      force_exchange=True).to_pandas()
-        e = ldf.merge(rdf, on="k", how=how)
-        assert len(j) == len(e), (how, len(j), len(e))
-        gm = j.dropna(subset=[j.columns[1], j.columns[-1]])
-        em = e.dropna()
-        assert len(gm) == len(em), how
-        # matched rows byte-correct, not just counted: (k, v, w) triples
-        gset = sorted(zip(gm.iloc[:, 0], gm.iloc[:, 1].astype(int),
-                          gm.iloc[:, -1].astype(int)))
-        eset = sorted(zip(em["k"], em["v"].astype(int),
-                          em["w"].astype(int)))
-        assert gset == eset, how
+    jt = {"inner": JoinType.INNER, "left": JoinType.LEFT,
+          "right": JoinType.RIGHT, "outer": JoinType.FULL_OUTER}[how]
+    cfg = JoinConfig(jt, [0], [0], exact=True)
+    j = dist_ops.distributed_join(lt, rt, cfg).to_pandas()
+    e = ldf.merge(rdf, on="k", how=how)
+    assert len(j) == len(e) == {"inner": 20, "left": 40, "right": 40,
+                                "outer": 60}[how]
+    gm = j.dropna(subset=[j.columns[1], j.columns[-1]])
+    em = e.dropna()
+    assert len(gm) == len(em) == 20
+    # matched rows byte-correct, not just counted: (k, v, w) triples
+    gset = sorted(zip(gm.iloc[:, 0], gm.iloc[:, 1].astype(int),
+                      gm.iloc[:, -1].astype(int)))
+    eset = sorted(zip(em["k"], em["v"].astype(int),
+                      em["w"].astype(int)))
+    assert gset == eset
 
 
 def test_lane_paths_edge_shapes(ctx, monkeypatch):
