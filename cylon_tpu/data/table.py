@@ -763,6 +763,22 @@ def align_key_columns(left: Table, right: Table, lidx: List[int],
     return lcols, rcols
 
 
+def sole_key_index(aligned: Sequence[Column], source: Sequence[Column],
+                   idx: Sequence[int]) -> Optional[int]:
+    """The column of ``source`` that is the join's ONE key AND the very
+    array the plan's key bits are made from, else None: what
+    `ops/join.plan_lane_descs` needs to let the key ride its sort once.
+    An aligned key column that is a promoted copy, a re-coded dictionary
+    or a varbytes lift is another array than its source, and a string's
+    bits are codes, not values."""
+    if len(idx) != 1:
+        return None
+    a, c = aligned[0], source[idx[0]]
+    if a.is_string or a.data is not c.data:
+        return None
+    return idx[0]
+
+
 def _all_valid(cols: Sequence[Column]) -> jnp.ndarray:
     v = cols[0].valid_mask()
     for c in cols[1:]:
@@ -958,8 +974,15 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
         from ..parallel.shuffle import _count_cached
 
         interp = jax.default_backend() != "tpu"
+        # the sort path's key bits are the key column's own: it rides once
+        lkey, rkey = (None, None) if hash_mode else (
+            sole_key_index(lcols, left._columns, config.left_column_idx),
+            sole_key_index(rcols, right._columns, config.right_column_idx))
         a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval,
-                                               config.type)
+                                               config.type, lkey, rkey)
+        _telemetry.counter("cylon_join_sort_operands_total").inc(
+            _join.plan_sort_operand_count(lkeys, str_flags, a_desc, b_desc,
+                                          hash_mode))
         br = _join.stream_block_rows(lkeys[0].shape[0], rkeys[0].shape[0])
         with _telemetry.phase("join.plan", seq):
             counts, a_streams, b_streams = _join.plan_program_stream(
@@ -1007,6 +1030,8 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
     else:
         from ..parallel.shuffle import _count_cached
 
+        _telemetry.counter("cylon_join_sort_operands_total").inc(
+            _join.plan_sort_operand_count(lkeys, str_flags))
         with _telemetry.phase("join.plan", seq):
             counts2, lo, m, bperm, un_mask = _join.plan_program(
                 lkeys, lkvalid, lemit, rkeys, rkvalid, remit, str_flags,

@@ -494,29 +494,57 @@ def hash_stream_applicable(lkeys, rkeys, str_flags,
 
 
 # Shared sort-payload slot budget: each slot adds one u32 operand to the
-# fused plan sort (measured on v5e at 33M rows: +2 operands free, +5 ≈
-# +100 ms). Columns beyond the budget fall back to aidx/bidx gathers.
+# fused plan sort, and the sort's time goes with the NUMBER of operands
+# (PERF.md section 6: ~136 ms a 4-byte operand at 1e8 rows on v5e).
+# Columns beyond the budget fall back to aidx/bidx gathers. The join key
+# takes no slot where it rides as "k" (plan_lane_descs).
 MAX_SHARED_LANES = 8
 
 
-def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType):
+def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType,
+                    lkey: Optional[int] = None, rkey: Optional[int] = None):
     """Static lane packing for the stream path: which columns ride the
     plan sort as u32 payload lanes. Slot s carries the probe side's lane
     s at probe rows and the build side's lane s at build rows, so the
     operand count is max(a, b) lanes, not the sum.
 
     Returns hashable (a_desc, b_desc): tuples of (col_idx, kind) with
-    kind "d" (data, bit-exact u32 reinterpret) or "v" (validity widened
-    to u32). 4-byte 1-D non-bool columns qualify; the rest (8-byte,
-    bool) use the index-gather fallback in materialize."""
-    if join_type == JoinType.RIGHT:
-        adat, aval, bdat, bval = rdat, rval, ldat, lval
-    else:
-        adat, aval, bdat, bval = ldat, lval, rdat, rval
+    kind "d" (data, bit-exact u32 reinterpret), "v" (validity widened to
+    u32) or "k" (THE join key: no operand of its own; the sorted key bits
+    stand in its slot and the column is read back off them with
+    order.from_ordered_bits_raw). 4-byte 1-D non-bool columns qualify;
+    the rest (8-byte, bool) use the index-gather fallback in materialize.
+    A column whose validity is None adds no "v" lane: the output's
+    validity is then the side's hit mask.
 
-    def side(dat, val):
+    ``lkey`` / ``rkey``: the column of each side that is the join's ONE
+    key and whose values the sort path's key bits were made from (the
+    caller sees that: not a promoted copy, not dictionary codes, not the
+    hash path). The key rides once only where the input allows it, on
+    BOTH sides (slot 0 is shared, so it is the sorted bits for both or
+    for neither): a signed or unsigned 4-byte integer (a float keeps its
+    lane: its ordered bits fold -0.0 into +0.0 and the output must not)
+    with validity None (then no emitted row had its bits forced to
+    all-ones as a dead row). Otherwise the key rides as "d", as any
+    other column."""
+    if join_type == JoinType.RIGHT:
+        adat, aval, akey, bdat, bval, bkey = rdat, rval, rkey, ldat, lval, lkey
+    else:
+        adat, aval, akey, bdat, bval, bkey = ldat, lval, lkey, rdat, rval, rkey
+
+    def key_once(dat, val, key):
+        return (key is not None and val[key] is None and dat[key].ndim == 1
+                and dat[key].dtype.itemsize == 4
+                and jnp.issubdtype(dat[key].dtype, jnp.integer))
+
+    once = key_once(adat, aval, akey) and key_once(bdat, bval, bkey)
+
+    def side(dat, val, key):
+        head = ((key, "k"),) if once else ()
         desc = []
         for ci, (d, v) in enumerate(zip(dat, val)):
+            if once and ci == key:
+                continue
             need = 1 + (1 if v is not None else 0)
             if (d.ndim == 1 and d.dtype.itemsize == 4
                     and d.dtype != jnp.bool_
@@ -524,9 +552,32 @@ def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType):
                 desc.append((ci, "d"))
                 if v is not None:
                     desc.append((ci, "v"))
-        return tuple(desc)
+        return head + tuple(desc)
 
-    return side(adat, aval), side(bdat, bval)
+    return side(adat, aval, akey), side(bdat, bval, bkey)
+
+
+def plan_sort_operand_count(keys, str_flags, a_desc=None, b_desc=None,
+                            hash_mode: bool = False) -> int:
+    """How many operands the join's plan sort is handed for these
+    arguments: a pure function of what the host sees before it
+    dispatches (counted there as ``cylon_join_sort_operands_total``).
+    ``keys``: one side's key arrays, raw or as ordered bits. With the
+    lane descriptors it is the stream path's sort: key bits + tag + one
+    operand a payload slot (the hash path: two hashes + tag + the key's
+    verify lanes + the slots); without them the XLA plan's
+    (`join_plan_keys`: the key bit arrays + tag). It counts what the
+    program hands over: the stream path's sorts are not stable, so the
+    compiler adds no index to them."""
+    if a_desc is None:
+        return len(keys) + 1
+    # a payload slot is the wider side's lane; the key as "k" takes none
+    slots = max(sum(kind != "k" for _, kind in desc)
+                for desc in (a_desc, b_desc))
+    if not hash_mode:
+        return 2 + slots
+    return 3 + sum(_key_lane_count(x, s)
+                   for x, s in zip(keys, str_flags)) + slots
 
 
 def stream_block_rows(na: int, nb: int) -> int:
@@ -552,6 +603,8 @@ def stream_expand_capacity(n: int, block_rows: int):
 def _side_lanes(dat, val, desc):
     lanes = []
     for ci, kind in desc:
+        if kind == "k":
+            continue  # rides as the sort's key bits, not as a lane
         if kind == "d":
             d = dat[ci]
             lanes.append(d if d.dtype == jnp.uint32 else d.view(jnp.uint32))
@@ -601,6 +654,11 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
            | (emit.astype(jnp.uint32) << 30)
            | (live.astype(jnp.uint32) << 29) | iota)
 
+    # the join key rides once (plan_lane_descs): no lane of its own here,
+    # the sorted key bits stand in slot 0 for both sides after the sort
+    key_once = bool(a_desc) and a_desc[0][1] == "k"
+    assert key_once == (bool(b_desc) and b_desc[0][1] == "k")
+    assert not (key_once and hash_mode), "the hash path sorts by hashes"
     a_lanes = _side_lanes(adat, aval, a_desc)
     b_lanes = _side_lanes(bdat, bval, b_desc)
     lanes = []
@@ -613,11 +671,12 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
     if hash_mode:
         # flatten every key column into u32 lanes (8-byte bits split
         # hi/lo) and hash them into two independent 32-bit streams.
-        # KNOWN trade-off: 4-byte key columns ride the sort twice (verify
-        # lane here + payload lane from plan_lane_descs, ~+30 ms/lane at
-        # 33M rows) — deduplicating needs static key→column maps and a
-        # bits→value inverse at unpack, deferred until the hash path
-        # shows up in a profile again
+        # KNOWN trade-off, here only (the sort path's key rides once, as
+        # the key bits: plan_lane_descs' "k"): a 4-byte key column rides
+        # this sort twice, as a verify lane and as a payload lane. The
+        # way back exists (order.from_ordered_bits_raw); what is missing
+        # is the key→column map for several key columns, left until the
+        # hash path shows up in a profile
         kb_lanes = []
         for a, b in zip(abits, bbits):
             cat = jnp.concatenate([a, b])
@@ -627,8 +686,10 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
             else:
                 kb_lanes.append(cat.astype(jnp.uint32))
         h1, h2 = hash2_streams(kb_lanes, live)
+        # not stable, as the sort path's below: tag ends the keys and
+        # holds the row's iota
         res = jax.lax.sort((h1, h2, tag) + tuple(kb_lanes) + tuple(lanes),
-                           num_keys=3)
+                           num_keys=3, is_stable=False)
         nk = len(kb_lanes)
         return tk.join_plan_stream(
             res[0], res[2], na, nb,
@@ -640,12 +701,19 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
 
     bits = jnp.concatenate([abits[0], bbits[0]])
     bits = jnp.where(live, bits, allones)
-    res = jax.lax.sort((bits, tag) + tuple(lanes), num_keys=2)
+    # (bits, tag) is a total order (tag's low 29 bits are the row's iota),
+    # so a sort that is not stable gives the same arrays bit for bit, and
+    # XLA does not append an index operand of its own as it does for a
+    # stable sort (PERF.md section 6: one 4-byte operand's time)
+    res = jax.lax.sort((bits, tag) + tuple(lanes), num_keys=2,
+                       is_stable=False)
     bits_s, tag_s, lanes_s = res[0], res[1], res[2:]
+    if key_once:
+        lanes_s = (bits_s,) + tuple(lanes_s)
     return tk.join_plan_stream(bits_s, tag_s, na, nb,
                                emit_unmatched_a=join_type != JoinType.INNER,
-                               lanes=lanes_s, n_a_lanes=len(a_lanes),
-                               n_b_lanes=len(b_lanes),
+                               lanes=lanes_s, n_a_lanes=len(a_desc),
+                               n_b_lanes=len(b_desc),
                                block_rows=block_rows, interpret=interpret)
 
 
@@ -677,6 +745,7 @@ def _materialize_program_stream_impl(counts, a_streams, b_streams,
     the kernel's lane outputs (zero output-sized XLA gathers); the rest
     gather by the materialized aidx/bidx."""
     from . import tpu_kernels as tk
+    from .order import from_ordered_bits_raw
 
     aidx, bidx, a_lane_outs, b_lane_outs = tk.join_expand_stream(
         counts, a_streams, b_streams, cap_e, block_rows=block_rows,
@@ -694,7 +763,14 @@ def _materialize_program_stream_impl(counts, a_streams, b_streams,
         od: list = [None] * len(dat)
         ov: list = [None] * len(dat)
         for (ci, kind), lane in zip(desc, lane_outs):
-            if kind == "d":
+            if kind == "k":
+                # the lane is the key's ordered bits; rows without a hit
+                # read 0 as every lane does (the kernel zeroes them)
+                od[ci] = jnp.where(
+                    hit, from_ordered_bits_raw(lane, dat[ci].dtype),
+                    jnp.zeros((), dat[ci].dtype))
+                ov[ci] = hit
+            elif kind == "d":
                 od[ci] = lane if dat[ci].dtype == jnp.uint32 \
                     else lane.view(dat[ci].dtype)
                 if val[ci] is None:

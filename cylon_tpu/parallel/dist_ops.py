@@ -329,7 +329,9 @@ def _build_exchange_payload(t: Table, ctx: CylonContext,
     (≤ LANE_WORDS_MAX words) ride the ROW exchange as fixed word lanes —
     no second word-level exchange, no extra count sync, no starts
     reconcile. All-valid columns skip the mask leaf entirely (validity
-    None round-trips as None — one less sort operand per column)."""
+    None round-trips as None — one less sort operand per column, here
+    and in the per-shard join's plan sort: distributed_join hands the
+    None on as it is)."""
     from ..data.strings import LANE_WORDS_MAX
 
     payload = dict(extra or {})
@@ -1035,17 +1037,32 @@ def distributed_join(left: Table, right: Table,
     lcols_s, rcols_s = lcols_all, rcols_all
     lvb = [i for i, c in enumerate(lcols_s) if c.is_varbytes]
     rvb = [i for i, c in enumerate(rcols_s) if c.is_varbytes]
+    # a validity that is None stays None, as _build_exchange_payload
+    # round-trips it: an all-ones mask would ride the plan sort as a "v"
+    # lane that says nothing, and the XLA materialize's gather reads None
+    # as all-valid too
     ldat = tuple(shard.pin(c.data, ctx) for c in lcols_s)
-    lval = tuple(shard.pin(c.valid_mask(), ctx) for c in lcols_s)
+    lval = tuple(None if c.validity is None else shard.pin(c.validity, ctx)
+                 for c in lcols_s)
     rdat = tuple(shard.pin(c.data, ctx) for c in rcols_s)
-    rval = tuple(shard.pin(c.valid_mask(), ctx) for c in rcols_s)
+    rval = tuple(None if c.validity is None else shard.pin(c.validity, ctx)
+                 for c in rcols_s)
 
     jt = config.type
     res = None
     mode = _dist_stream_mode(lkb, rkb, jt, world)
     if mode is not None:
         hash_mode, br = mode
-        a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval, jt)
+        # the sort path's key bits are the shuffled key column's own
+        # ordered bits: it rides once
+        lkey, rkey = (None, None) if hash_mode else (
+            table_mod.sole_key_index(lcols2, lcols_s, lidx),
+            table_mod.sole_key_index(rcols2, rcols_s, ridx))
+        a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval, jt,
+                                               lkey, rkey)
+        _counter("cylon_join_sort_operands_total").inc(
+            _join.plan_sort_operand_count(lkb, (False,) * len(lkb), a_desc,
+                                          b_desc, hash_mode))
         with _phase("distributed_join.plan", seq):
             rep_counts, counts_dev, a_streams, b_streams = \
                 _join_plan_stream_fn(ctx.mesh, jt, len(lkb), a_desc,
@@ -1070,6 +1087,8 @@ def distributed_join(left: Table, right: Table,
     if res is not None:
         lod, lov, rod, rov, emit, lidx_o, ridx_o = res
     else:
+        _counter("cylon_join_sort_operands_total").inc(
+            _join.plan_sort_operand_count(lkb, (False,) * len(lkb)))
         with _phase("distributed_join.plan", seq):
             counts2, lo, m, bperm, un_mask = _join_plan_fn(ctx.mesh, jt)(
                 lkb, lkv, lemit, rkb, rkv, remit)

@@ -226,6 +226,54 @@ def test_dist_scalar_aggregates(dist_ctx):
     assert abs(float(t.max("v").to_pydict()["v"][0]) - v.max()) < 1e-12
 
 
+@pytest.mark.parametrize("jt", ["inner", "left", "right", "outer"])
+def test_dist_join_none_masks_give_the_bytes_of_all_ones_masks(
+        dist_ctx, monkeypatch, jt):
+    """`distributed_join` hands the per-shard join a shuffled column's
+    validity as it is (None for all-valid), where it used to hand an
+    all-ones mask: every output column's data AND validity, and the row
+    mask, are byte for byte those of the run before (stood in for by
+    widening the None masks at the materialize program's door)."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(17)
+    n = 600
+    w = rng.normal(size=n)
+    w[rng.random(n) < 0.1] = np.nan          # one column with a real mask
+    a = ct.Table.from_pydict(dist_ctx, {
+        "k": rng.integers(0, 90, n).astype(np.int32),
+        "v": rng.normal(size=n).astype(np.float32)})
+    b = ct.Table.from_pydict(dist_ctx, {
+        "k": rng.integers(30, 120, n).astype(np.int32), "w": w})
+    got = a.distributed_join(b, jt, on="k")
+
+    real, widened = dist_ops._join_mat_fn, []
+
+    def before(mesh, join_type, cap_p, cap_u):
+        fn = real(mesh, join_type, cap_p, cap_u)
+
+        def run(lo, m, bperm, un_mask, aemit, ldat, lval, rdat, rval):
+            ones = lambda d, v: jnp.ones(d.shape[0], bool) if v is None else v
+            widened.append(sum(v is None for v in lval + rval))
+            return fn(lo, m, bperm, un_mask, aemit, ldat,
+                      tuple(map(ones, ldat, lval)), rdat,
+                      tuple(map(ones, rdat, rval)))
+        return run
+
+    monkeypatch.setattr(dist_ops, "_join_mat_fn", before)
+    want = a.distributed_join(b, jt, on="k")
+    assert widened == [3]                    # k, v, k rode as None; w did not
+    np.testing.assert_array_equal(np.asarray(got.row_mask),
+                                  np.asarray(want.row_mask))
+    assert got.row_count == want.row_count > n
+    for g, x in zip(got._columns, want._columns):
+        assert g.data.dtype == x.data.dtype
+        assert np.asarray(g.data).tobytes() == np.asarray(x.data).tobytes()
+        np.testing.assert_array_equal(np.asarray(g.valid_mask()),
+                                      np.asarray(x.valid_mask()))
+        assert (g.validity is None) == (x.validity is None)
+
+
 def test_dist_join_result_feeds_next_op(dist_ctx):
     """Outputs of dist ops are themselves sharded tables usable downstream
     (op pipelining without host round-trips)."""

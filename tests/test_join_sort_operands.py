@@ -1,0 +1,261 @@
+"""What the stream join's fused plan sort carries, and that the answer
+does not depend on it.
+
+The sort is handed the key bits, the tag and one operand a payload slot
+(``ops/join.plan_lane_descs``): no validity lane for a column without a
+mask, no lane for THE join key where its bits are the column's own (kind
+"k": the column is read back off the sorted bits), and it is not stable
+(``(bits, tag)`` is a total order). The operand list is read off a
+jaxpr; the answers are compared cell by cell, bit patterns included,
+with a nested-loop reference over the host arrays, on the Pallas
+interpreter (the same kernels compile to Mosaic on a TPU).
+"""
+from collections import Counter
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import cylon_tpu as ct
+from cylon_tpu import dtypes, telemetry
+from cylon_tpu.ops import join as _join
+from cylon_tpu.parallel import dist_ops
+
+COUNTER = "cylon_join_sort_operands_total"
+INNER = _join.JoinType.INNER
+
+
+def _sorts(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            out.append((len(eqn.invars), eqn.params["is_stable"],
+                        eqn.params["num_keys"]))
+        for v in eqn.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    _sorts(inner, out)
+    return out
+
+
+def _sds(dtype, n=4096):
+    return jax.ShapeDtypeStruct((n,), dtype)
+
+
+def _plan_sorts(keys, cols, a_desc, b_desc, hash_mode=False):
+    kw = dict(str_flags=(False,), join_type=INNER, a_desc=a_desc,
+              b_desc=b_desc, block_rows=8, hash_mode=hash_mode,
+              interpret=False)
+    with jax.enable_x64(False):
+        closed = jax.make_jaxpr(
+            lambda *a: _join._plan_program_stream_impl(*a, **kw))(
+                *keys, *cols)
+    return _sorts(closed.jaxpr, [])
+
+
+# workload B's schema: a 4-byte key and a 4-byte payload a side
+K, W, M = _sds(jnp.int32), _sds(jnp.float32), _sds(jnp.bool_)
+
+
+@pytest.mark.parametrize("case,cols,keycols,hash_mode,a_want,operands", [
+    # the local join's arguments (data/table._join_once)
+    ("local", ((K, W), (None, None), (K, W), (None, None)), (0, 0), False,
+     ((0, "k"), (1, "d")), 3),
+    # a payload column with a real mask still carries its validity lane
+    ("masked_payload", ((K, W), (None, None), (K, W), (None, M)), (0, 0),
+     False, ((0, "k"), (1, "d")), 4),
+    # a key with a mask keeps its lanes, on both sides: slot 0 is shared
+    ("masked_key", ((K, W), (M, None), (K, W), (None, None)), (0, 0), False,
+     ((0, "d"), (0, "v"), (1, "d")), 5),
+    # a key the caller does not vouch for (promoted, dictionary codes)
+    ("no_key_map", ((K, W), (None, None), (K, W), (None, None)),
+     (None, None), False, ((0, "d"), (1, "d")), 4),
+    ("float_key", ((W, K), (None, None), (W, K), (None, None)), (0, 0),
+     False, ((0, "d"), (1, "d")), 4),
+    # the hash path: two hashes, tag, one verify lane, two payload slots
+    ("hash", ((K, W), (None, None), (K, W), (None, None)), (None, None),
+     True, ((0, "d"), (1, "d")), 6),
+])
+def test_plan_sort_operands_read_off_the_jaxpr(case, cols, keycols,
+                                               hash_mode, a_want, operands):
+    ldat, lval, rdat, rval = cols
+    a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval, INNER,
+                                           *keycols)
+    assert a_desc == a_want
+    keys = ((ldat[0],), (lval[0],), None, (rdat[0],), (rval[0],), None)
+    sorts = _plan_sorts(keys, cols, a_desc, b_desc, hash_mode)
+    assert sorts == [(operands, False, 3 if hash_mode else 2)]
+    assert _join.plan_sort_operand_count(
+        keys[0], (False,), a_desc, b_desc, hash_mode) == operands
+    # the XLA plan (join_plan_keys): the key bit arrays and the tag
+    assert _join.plan_sort_operand_count(keys[0], (False,)) == 2
+
+
+def test_right_join_key_map_follows_the_swap():
+    """RIGHT joins swap sides before the lanes are built: the key's
+    column index goes with its side."""
+    cols = ((W, K), (None, None), (K, W), (None, None))
+    a_desc, b_desc = _join.plan_lane_descs(*cols, _join.JoinType.RIGHT, 1, 0)
+    assert a_desc == ((0, "k"), (1, "d"))     # the right table probes
+    assert b_desc == ((1, "k"), (0, "d"))
+
+
+class _Recorded(Exception):
+    pass
+
+
+def test_distributed_join_hands_the_plan_no_mask_that_says_nothing(
+        dist_ctx, monkeypatch):
+    """What `distributed_join` builds for all-valid shuffled columns, as
+    a TPU backend would run it (the Pallas factory is stood in for: it
+    does not lower off a TPU): validity None stays None, the key rides
+    once, and the per-shard plan sort has 3 operands, not stable."""
+    rng = np.random.default_rng(1)
+    n = 512
+    tables = [ct.Table.from_pydict(dist_ctx, {
+        "k": rng.integers(0, 64, n).astype(np.int32),
+        name: rng.random(n).astype(np.float32)}) for name in ("v", "w")]
+    seen = {}
+
+    def factory(mesh, jt, nk, a_desc, b_desc, br, hash_mode):
+        def run(lkb, lkv, lemit, rkb, rkv, remit, ldat, lval, rdat, rval):
+            seen.update(a_desc=a_desc, b_desc=b_desc, hash_mode=hash_mode,
+                        args=(lkb, lkv, lemit, rkb, rkv, remit,
+                              ldat, lval, rdat, rval))
+            raise _Recorded()
+        return run
+
+    monkeypatch.setattr(dist_ops, "_dist_stream_mode",
+                        lambda lkb, rkb, jt, world: (False, 8))
+    monkeypatch.setattr(dist_ops, "_join_plan_stream_fn", factory)
+    before = telemetry.metrics_snapshot().get(COUNTER, 0)
+    with pytest.raises(_Recorded):
+        tables[0].distributed_join(tables[1], "inner", on="k")
+    assert telemetry.metrics_snapshot()[COUNTER] - before == 3
+    assert seen["a_desc"] == seen["b_desc"] == ((0, "k"), (1, "d"))
+    lkb, lkv, lemit, rkb, rkv, remit, ldat, lval, rdat, rval = seen["args"]
+    assert lval == (None, None) and rval == (None, None)
+    world = dist_ctx.get_world_size()
+    shard = lambda x: None if x is None else _sds(x.dtype,
+                                                  x.shape[0] // world)
+    keys = jax.tree.map(shard, ((lkb, (lkv,), lemit, rkb, (rkv,), remit)))
+    cols = (tuple(map(shard, ldat)), lval, tuple(map(shard, rdat)), rval)
+    assert _plan_sorts(keys, cols, seen["a_desc"], seen["b_desc"]) == \
+        [(3, False, 2)]
+
+
+# ---------------------------------------------------------------------------
+# the answer, key columns included, bit for bit
+# ---------------------------------------------------------------------------
+
+def _col(arr, name, validity=None):
+    arr = np.asarray(arr)
+    if arr.dtype.kind in "UO":
+        return ct.Column.from_numpy(arr, name, validity)
+    return ct.Column(jnp.asarray(arr), dtypes.from_np_dtype(arr.dtype),
+                     None if validity is None else jnp.asarray(validity),
+                     None, name)
+
+
+def _cells(arr, valid):
+    """One hashable value a row: None for a null, a string, or the bit
+    pattern of a number (so -0.0 and +0.0 are different answers)."""
+    arr = np.asarray(arr)
+    if arr.dtype.kind in "UO":
+        vals = [None if v is None else str(v) for v in arr]
+    else:
+        vals = [int(v) for v in arr.view(f"u{arr.dtype.itemsize}")]
+    if valid is None:
+        return vals
+    return [v if ok else None for v, ok in zip(vals, np.asarray(valid))]
+
+
+def _reference(lk, lkv, lv, rk, rkv, rw, how):
+    """Nested-loop equi-join over host arrays: rows (lk, lv, rk, rw);
+    keys compare by value (-0.0 == +0.0), a null key matches nothing."""
+    ok_l = np.ones(len(lk), bool) if lkv is None else lkv
+    ok_r = np.ones(len(rk), bool) if rkv is None else rkv
+    cl = list(zip(_cells(lk, lkv), _cells(lv, None)))
+    cr = list(zip(_cells(rk, rkv), _cells(rw, None)))
+    rows, hit_r = [], np.zeros(len(rk), bool)
+    for i in range(len(lk)):
+        m = ok_r & (rk == lk[i]) if ok_l[i] else np.zeros(len(rk), bool)
+        hit_r |= m
+        rows += [cl[i] + cr[j] for j in np.flatnonzero(m)]
+        if not m.any() and how in ("left", "outer"):
+            rows.append(cl[i] + (None, None))
+    if how in ("right", "outer"):
+        rows += [(None, None) + cr[j] for j in np.flatnonzero(~hit_r)]
+    return Counter(rows)
+
+
+def _table_rows(t):
+    live = np.ones(t.capacity, bool) if t.row_mask is None \
+        else np.asarray(t.row_mask)
+    cols = []
+    for c in t._columns:
+        host = c.to_numpy() if c.is_string else np.asarray(c.data)
+        valid = None if c.validity is None else np.asarray(c.validity)
+        cols.append(_cells(host, None if c.is_string else valid))
+    return Counter(row for row, ok in zip(zip(*cols), live) if ok)
+
+
+NL, NR = 90, 70
+
+
+def _inputs(kind):
+    """(left key, its validity, right key, its validity, algorithm,
+    operands the plan sort carries)."""
+    rng = np.random.default_rng(len(kind))
+    pick = lambda vals, dt, n: np.asarray(vals, dt)[
+        rng.integers(0, len(vals), n)]
+    i32 = [-2 ** 31, -7, -1, 0, 1, 5, 99, 2 ** 31 - 1]
+    if kind == "int32":
+        return pick(i32, np.int32, NL), None, pick(i32, np.int32, NR), \
+            None, "sort", 3
+    if kind == "uint32":
+        u32 = [0, 3, 2 ** 31, 2 ** 32 - 1, 17]
+        return pick(u32, np.uint32, NL), None, pick(u32, np.uint32, NR), \
+            None, "sort", 3
+    if kind == "float32_negzero":     # a float key keeps its lane
+        f32 = [-np.inf, -1.5, -0.0, 0.0, 1e-30, 2.5, np.inf]
+        return pick(f32, np.float32, NL), None, pick(f32, np.float32, NR), \
+            None, "sort", 4
+    if kind == "int32_nulls":         # key d + v, payload d
+        return pick(i32, np.int32, NL), rng.random(NL) < 0.8, \
+            pick(i32, np.int32, NR), rng.random(NR) < 0.8, "sort", 5
+    if kind == "promoted":            # int16 joins int32 as int32 copies:
+        # no key map; the int16 column itself comes back by the index
+        return pick([-7, -1, 0, 1, 5, 99], np.int16, NL), None, \
+            pick(i32, np.int32, NR), None, "sort", 4
+    if kind == "dictionary":
+        fruit = ["pear", "apple", "fig", "kiwi", "plum"]
+        return pick(fruit[:4], object, NL), None, \
+            pick(fruit[1:], object, NR), None, "sort", 4
+    assert kind == "hash"             # h1, h2, tag, verify, two slots
+    return pick(i32, np.int32, NL), None, pick(i32, np.int32, NR), None, \
+        "hash", 6
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "right", "outer"])
+@pytest.mark.parametrize("kind", ["int32", "uint32", "float32_negzero",
+                                  "int32_nulls", "promoted", "dictionary",
+                                  "hash"])
+def test_stream_join_answer_bit_for_bit(local_ctx, monkeypatch, kind, how):
+    lk, lkv, rk, rkv, algo, operands = _inputs(kind)
+    rng = np.random.default_rng(5)
+    lv = rng.normal(size=NL).astype(np.float32)
+    rw = rng.normal(size=NR).astype(np.float32)
+    left = ct.Table([_col(lk, "k", lkv), _col(lv, "v")], local_ctx)
+    right = ct.Table([_col(rk, "k", rkv), _col(rw, "w")], local_ctx)
+    monkeypatch.setattr(_join, "STREAM_PLAN", True)
+    before = telemetry.metrics_snapshot().get(COUNTER, 0)
+    got = left.join(right, how, algo, on=["k"])
+    assert telemetry.metrics_snapshot()[COUNTER] - before == operands
+    for c, src in zip(got._columns, left._columns + right._columns):
+        assert (c.dtype, c.data.dtype, c.is_string) == \
+            (src.dtype, src.data.dtype, src.is_string)
+    want = _reference(lk, lkv, lv, rk, rkv, rw, how)
+    assert _table_rows(got) == want
+    assert sum(want.values()) > max(NL, NR)   # duplicates on both sides

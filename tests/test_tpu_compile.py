@@ -10,6 +10,8 @@ exits: the topology is described inside a module-scoped fixture (never
 at import), the compiles run in this process, and all of them live in
 this ONE file so that one xdist worker owns the library.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -123,11 +125,12 @@ def test_setop_stream_compiles(chip):
     assert "tpu_custom_call" in _compiled_text(setop, u32, u32, u32, u32)
 
 
-# the smoke's pruned join inputs: left (k,), right (k, w), all rows valid
+# the smoke's pruned join inputs: left (k,), right (k, w), all rows valid,
+# the key map as the local join passes it (both keys are column 0)
 def _join_shapes(chip):
     k, w = _sds(chip, N, jnp.int32), _sds(chip, N, jnp.float32)
     cols = ((k,), (None,), (k, w), (None, None))
-    a_desc, b_desc = _join.plan_lane_descs(*cols, _join.JoinType.INNER)
+    a_desc, b_desc = _join.plan_lane_descs(*cols, _join.JoinType.INNER, 0, 0)
     keys = ((k,), (None,), None, (k,), (None,), None)
     kw = dict(join_type=_join.JoinType.INNER, a_desc=a_desc, b_desc=b_desc,
               block_rows=_join.stream_block_rows(N, N), interpret=False)
@@ -142,6 +145,15 @@ def test_join_plan_stream_compiles(chip):
     text = _compiled_text(_join._plan_program_stream_jit, *keys, *cols,
                           str_flags=(False,), hash_mode=False, **kw)
     assert "tpu_custom_call" in text
+    # the fused sort as COMPILED: key bits, tag and one payload slot (w);
+    # the key rides once and, the sort not being stable, the compiler
+    # appends no index of its own (PERF.md section 6)
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    assert len(sorts) == 1, sorts
+    operands = re.search(r"\bsort\((.*?)\), dimensions=", sorts[0]).group(1)
+    assert operands.count("%") == 3, sorts[0]
+    assert _join.plan_sort_operand_count(
+        keys[0], (False,), kw["a_desc"], kw["b_desc"]) == 3
 
 
 def test_join_expand_compiles(chip):
