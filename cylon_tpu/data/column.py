@@ -14,6 +14,24 @@ id + DataType + arrow::ChunkedArray) with a TPU-native representation:
   group-by on strings are integer ops on the MXU-friendly codes. Cross-table
   ops unify vocabularies host-side and re-map codes with one device gather
   (`unify_dictionaries`).
+* a 64-bit column (int64, uint64, float64, the 8-byte temporal types) on a
+  backend without x64 (the chip) is held EXACTLY as two 32-bit word planes,
+  ONE ``uint32[2, n]`` array (plane 0 the high words, plane 1 the low) under
+  its logical 64-bit dtype: `Column.is_planes`. With x64 on it is the native
+  array. Which form a column takes follows from ``jax_enable_x64`` alone; it
+  is never narrowed. The local join takes plane-held columns; an operator
+  that cannot yet raises (`refuse_planes`).
+
+THE AXIS CONVENTION. Rows are the LAST axis of ``Column.data`` in both forms
+(``[n]`` and ``[2, n]``): a minor dimension of 2 would be padded to a whole
+tile on the chip. parallel/shuffle.py's 2-D leaves are the other way round,
+rows FIRST (``x[:, j]``, ``shape[1:]`` is a row's trailing shape), so a
+plane-held column never goes to the exchange as it is: it goes as its two
+1-D planes (`split_planes`), two leaves, and is put together after
+(`join_planes`); until that is written `parallel/shard.distribute` refuses
+(ROADMAP.md 2a-a). What a word-plane array IS lives here and nowhere else:
+`is_word_planes`, `split_planes`, `join_planes`, and on the host
+`dtypes.to_word_planes` / `from_word_planes`.
 """
 from __future__ import annotations
 
@@ -50,18 +68,18 @@ class Column:
         if arr.dtype.kind == "M":  # datetime64
             unit = np.datetime_data(arr.dtype)[0]
             dt = dtypes.Timestamp(_np_unit(unit))
-            data = jnp.asarray(arr.astype("int64"))
+            data = _to_device(arr.astype("int64"))
             return Column(data, dt, _dev_mask(validity), None, name)
         if arr.dtype.kind == "m":
             unit = np.datetime_data(arr.dtype)[0]
             dt = dtypes.Duration(_np_unit(unit))
-            return Column(jnp.asarray(arr.astype("int64")), dt,
+            return Column(_to_device(arr.astype("int64")), dt,
                           _dev_mask(validity), None, name)
         if arr.dtype.kind == "f" and validity is None and np.isnan(arr).any():
             # pandas-style: NaN means null for float columns coming from host
             validity = ~np.isnan(arr)
         dt = dtypes.from_np_dtype(arr.dtype)
-        return Column(jnp.asarray(arr), dt, _dev_mask(validity), None, name)
+        return Column(_to_device(arr), dt, _dev_mask(validity), None, name)
 
     @staticmethod
     def _encode_strings(arr: np.ndarray, name: str,
@@ -185,7 +203,20 @@ class Column:
     # -- properties --
 
     def __len__(self) -> int:
-        return int(self.data.shape[0])
+        return int(self.data.shape[-1])  # rows are the last axis
+
+    @property
+    def is_planes(self) -> bool:
+        """A 64-bit column held as two 32-bit word planes, ``uint32[2,
+        n]`` (module docstring): x64 is off."""
+        return is_word_planes(self.data)
+
+    @property
+    def host_dtype(self) -> np.dtype:
+        """The dtype of `_host_data`: a plane-held column's logical
+        64-bit dtype, else the device array's own."""
+        return np.dtype(self.dtype.np_dtype if self.is_planes
+                        else self.data.dtype)
 
     @property
     def is_string(self) -> bool:
@@ -202,7 +233,7 @@ class Column:
 
     def valid_mask(self) -> jnp.ndarray:
         if self.validity is None:
-            return jnp.ones(self.data.shape[0], dtype=bool)
+            return jnp.ones(len(self), dtype=bool)
         return self.validity
 
     # -- transforms --
@@ -210,6 +241,7 @@ class Column:
     def astype(self, dtype: DataType) -> "Column":
         if self.is_string:
             raise CylonError(Code.TypeError, "cannot cast string column")
+        refuse_planes([self], "astype")
         return Column(self.data.astype(dtype.np_dtype), dtype, self.validity,
                       None, self.name)
 
@@ -217,8 +249,9 @@ class Column:
         """Gather rows; negative indices produce NULL rows (the reference's
         −1→null gather, util/copy_arrray.cpp:16-287)."""
         idx = jnp.asarray(indices)
-        if self.data.shape[0] == 0 and not self.is_varbytes:
-            data = jnp.zeros(idx.shape, self.data.dtype)
+        if len(self) == 0 and not self.is_varbytes:
+            data = jnp.zeros(self.data.shape[:-1] + idx.shape,
+                             self.data.dtype)
             return Column(data, self.dtype, jnp.zeros(idx.shape, bool),
                           self.dictionary, self.name)
         neg = idx < 0
@@ -228,7 +261,7 @@ class Column:
             # NOTE: an all-True mask is NOT collapsed to None here — that
             # would force a device→host sync on every gather. Export
             # paths collapse it instead.
-            if self.data.shape[0] == 0:
+            if len(self) == 0:
                 validity = jnp.zeros(idx.shape, bool)
             else:
                 validity = jnp.take(self.valid_mask(), safe, axis=0) & ~neg
@@ -236,7 +269,7 @@ class Column:
             vb = self.varbytes.take(idx)  # negatives → empty rows
             return Column(vb.lengths, self.dtype, validity, None, self.name,
                           varbytes=vb)
-        data = jnp.take(self.data, safe, axis=0)
+        data = jnp.take(self.data, safe, axis=-1)
         return Column(data, self.dtype, validity, self.dictionary, self.name)
 
     def slice(self, start: int, stop: int) -> "Column":
@@ -245,8 +278,8 @@ class Column:
             vb = self.varbytes.slice(start, stop)
             return Column(vb.lengths, self.dtype, v, None, self.name,
                           varbytes=vb)
-        return Column(self.data[start:stop], self.dtype, v, self.dictionary,
-                      self.name)
+        return Column(self.data[..., start:stop], self.dtype, v,
+                      self.dictionary, self.name)
 
     def rename(self, name: str) -> "Column":
         return Column(self.data, self.dtype, self.validity, self.dictionary,
@@ -261,6 +294,14 @@ class Column:
         mask = np.asarray(jax.device_get(self.validity))
         return None if mask.all() else mask
 
+    def _host_data(self) -> np.ndarray:
+        """``data`` on the host, one value a row: the word planes of a
+        plane-held column put back together as its 64-bit dtype."""
+        data = np.asarray(jax.device_get(self.data))
+        if self.is_planes:
+            return dtypes.from_word_planes(data, self.dtype.np_dtype)
+        return data
+
     def to_numpy(self) -> np.ndarray:
         if self.is_varbytes:
             out = self.varbytes.to_host(
@@ -269,7 +310,7 @@ class Column:
             if mask is not None:
                 out[~mask] = None
             return out
-        data = np.asarray(jax.device_get(self.data))
+        data = self._host_data()
         mask = self._host_mask()
         if self.is_string:
             out = self.dictionary[data].astype(object)
@@ -304,7 +345,7 @@ class Column:
                                 type=pa.binary(), mask=mask)
             return pa.array(self.varbytes.to_host(), type=pa.string(),
                             mask=mask)
-        data = np.asarray(jax.device_get(self.data))
+        data = self._host_data()
         if self.is_string:
             vals = self.dictionary[data]
             return pa.array(vals, type=pa.string(),
@@ -381,6 +422,44 @@ def unify_dictionaries(a: Column, b: Column) -> Tuple[Column, Column]:
     na = Column(jnp.take(map_a, a.data), a.dtype, a.validity, union, a.name)
     nb = Column(jnp.take(map_b, b.data), b.dtype, b.validity, union, b.name)
     return na, nb
+
+
+def _to_device(arr: np.ndarray):
+    """The host array on the device, exactly: an 8-byte dtype on a backend
+    without x64 as its two word planes (``jnp.asarray`` would narrow it
+    to 32 bits and say nothing)."""
+    if arr.dtype.itemsize == 8 and not jax.config.jax_enable_x64:
+        return jnp.asarray(dtypes.to_word_planes(arr))
+    return jnp.asarray(arr)
+
+
+def is_word_planes(x) -> bool:
+    """Whether the array is a 64-bit column's two word planes,
+    ``uint32[2, n]`` (module docstring), and not one lane ``[n]``."""
+    return x.ndim == 2 and x.shape[0] == 2 and x.dtype == np.uint32
+
+
+def split_planes(x):
+    """``(hi, lo)``: the two ``uint32[n]`` planes of a word-plane array."""
+    return x[0], x[1]
+
+
+def join_planes(hi, lo):
+    """`split_planes`' inverse: ``uint32[2, n]`` on the device."""
+    return jnp.stack([hi, lo])
+
+
+def refuse_planes(cols, operator: str) -> None:
+    """Raise for the first plane-held column among ``cols``: ``operator``
+    cannot take a 64-bit column held as word planes yet."""
+    for c in cols:
+        if c.is_planes:
+            raise CylonError(
+                Code.NotImplemented,
+                f"{operator}: column {c.name!r} is {c.dtype.type.name} held "
+                f"as two 32-bit word planes (jax_enable_x64 is off) and "
+                f"{operator} cannot take such a column yet (the local inner, "
+                f"left and right joins can)")
 
 
 def _dev_mask(validity: Optional[np.ndarray]):

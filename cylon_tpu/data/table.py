@@ -22,6 +22,7 @@ distributed API (table.hpp:262-336).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -33,7 +34,7 @@ from ..config import CSVWriteOptions
 from ..context import CylonContext
 from ..status import Code, CylonError
 from .column import (Column, align_string_columns, as_varbytes,
-                     string_key_arrays, unify_dictionaries)
+                     refuse_planes, string_key_arrays, unify_dictionaries)
 from .strings import concat_varbytes, pair_k_words
 from .. import telemetry as _telemetry
 from ..ops import aggregates as _aggregates
@@ -394,6 +395,7 @@ class Table:
              ascending: Union[bool, Sequence[bool]] = True) -> "Table":
         """Local sort (reference: Sort, table.cpp / util/arrow_utils.cpp:144-184
         — argsort the key column then gather every column)."""
+        refuse_planes(self._columns, "sort")
         t = self.compact()
         cols_idx = [t._col_index(c) for c in
                     (order_by if isinstance(order_by, (list, tuple)) else [order_by])]
@@ -503,6 +505,7 @@ class Table:
     def _agg(self, column, op: str):
         i = self._col_index(column) if not isinstance(column, Column) else None
         col = self._columns[i] if i is not None else column
+        refuse_planes([col], op)
         if self.row_mask is not None:
             valid = col.valid_mask() & self.emit_mask()
             col = Column(col.data, col.dtype, valid, col.dictionary, col.name,
@@ -567,6 +570,7 @@ class Table:
         # keep the padded capacity + row_mask (join/dist results are padded;
         # compacting here would break t[t["c"] > x] shape alignment)
         t = self
+        refuse_planes(t._columns, "compare")
         out_cols = []
         for c in t._columns:
             if c.is_varbytes:
@@ -754,6 +758,15 @@ def align_key_columns(left: Table, right: Table, lidx: List[int],
                              f"join key type mismatch: {a.name} vs {b.name}")
         if a.is_string:
             a, b = align_string_columns(a, b)
+        elif a.is_planes or b.is_planes:
+            # word planes compare as they are: no promotion between them
+            if a.dtype.np_dtype != b.dtype.np_dtype:
+                raise CylonError(
+                    Code.NotImplemented,
+                    f"join key type mismatch: {a.name} is "
+                    f"{a.dtype.type.name}, {b.name} is {b.dtype.type.name}, "
+                    f"and a 64-bit column held as word planes (x64 is off) "
+                    f"is not promoted; cast on the host")
         elif a.data.dtype != b.data.dtype:
             common = jnp.promote_types(a.data.dtype, b.data.dtype)
             a = Column(a.data.astype(common), a.dtype, a.validity, None, a.name)
@@ -819,8 +832,10 @@ def _expanded_keys(cols: Sequence[Column], paired: Sequence[Column] = None):
     varbytes columns expand to raw word lanes (short rows, byte-exact)
     or (h1, h2, h3, len) content hashes (long rows) — data/strings.py.
     ``paired``: the other side's aligned key columns, so both sides
-    emit the same lane count (max of the two max_words)."""
-    keys, valids, flags = [], [], []
+    emit the same lane count (max of the two max_words). The fourth
+    tuple names, for a key held as word planes, its logical 64-bit dtype
+    (else None): ops/join.plan_program's ``key_wide``."""
+    keys, valids, flags, wide = [], [], [], []
     for j, c in enumerate(cols):
         if c.is_varbytes:
             kw = pair_k_words(c, paired[j]) if paired is not None else None
@@ -828,11 +843,13 @@ def _expanded_keys(cols: Sequence[Column], paired: Sequence[Column] = None):
             keys.extend(ks)
             valids.extend(vs)
             flags.extend(fs)
+            wide.extend([None] * len(ks))
         else:
             keys.append(c.data)
             valids.append(c.validity)
             flags.append(c.is_string)
-    return tuple(keys), tuple(valids), tuple(flags)
+            wide.append(c.host_dtype.name if c.is_planes else None)
+    return tuple(keys), tuple(valids), tuple(flags), tuple(wide)
 
 
 def _memo_refs(cols: Sequence[Column]) -> Tuple[Tuple, Tuple]:
@@ -884,12 +901,30 @@ def _join_plan_bytes_estimate(left: Table, right: Table) -> int:
     gathers, ~6 u32-equivalents per row per column-ish; varbytes columns
     add their word-buffer bytes (the content dominates large strings)."""
     n = left.capacity + right.capacity
-    width = sum(max(np.dtype(c.data.dtype).itemsize, 4) + 1
+    width = sum((8 if c.is_planes
+                 else max(np.dtype(c.data.dtype).itemsize, 4)) + 1
                 for c in left._columns + right._columns)
     vb_bytes = sum(4 * int(c.varbytes.words.shape[0])
                    for c in left._columns + right._columns
                    if c.is_varbytes)
     return int(n) * (width + 24) + 2 * vb_bytes
+
+
+def count_plan_sort(keys, str_flags, n_cols: int, a_desc=None, b_desc=None,
+                    hash_mode: bool = False) -> None:
+    """Count what the host hands a join's plan sort and what the join
+    will gather, once a join, where the operand list is built (the local
+    join here, `parallel/dist_ops.distributed_join`): the three
+    ``cylon_join_*`` counters of docs/telemetry.md. ``keys``: one side's
+    key arrays; ``n_cols``: the input columns of both sides; the lane
+    descriptors only on the stream path."""
+    _telemetry.counter("cylon_join_sort_operands_total").inc(
+        _join.plan_sort_operand_count(keys, str_flags, a_desc, b_desc,
+                                      hash_mode))
+    _telemetry.counter("cylon_join_key_lanes_total").inc(
+        _join.plan_key_lane_count(keys, str_flags))
+    _telemetry.counter("cylon_join_gathered_columns_total").inc(
+        _join.plan_gathered_column_count(n_cols, a_desc, b_desc))
 
 
 def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
@@ -899,9 +934,13 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
                                      config.right_column_idx)
     # varbytes alignment may have lifted a dictionary key column: joins
     # read keys from the ALIGNED columns, payload from the originals
-    lkeys, lkvalid, str_flags = _expanded_keys(lcols, rcols)
-    rkeys, rkvalid, _ = _expanded_keys(rcols, lcols)
+    lkeys, lkvalid, str_flags, key_wide = _expanded_keys(lcols, rcols)
+    rkeys, rkvalid, _, _ = _expanded_keys(rcols, lcols)
     lemit, remit = left.row_mask, right.row_mask
+    planes = any(c.is_planes for c in left._columns + right._columns)
+    if planes and config.type == _join.JoinType.FULL_OUTER:
+        # its unmatched-build tail goes through set membership and concat
+        refuse_planes(left._columns + right._columns, "full outer join")
 
     lvb = [i for i, c in enumerate(left._columns) if c.is_varbytes]
     rvb = [i for i, c in enumerate(right._columns) if c.is_varbytes]
@@ -974,22 +1013,25 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
         from ..parallel.shuffle import _count_cached
 
         interp = jax.default_backend() != "tpu"
+        # the sort path admits ONE key held as word planes (the hash path
+        # none): its logical dtype, by name
+        wide_key = None if hash_mode else key_wide[0]
         # the sort path's key bits are the key column's own: it rides once
         lkey, rkey = (None, None) if hash_mode else (
             sole_key_index(lcols, left._columns, config.left_column_idx),
             sole_key_index(rcols, right._columns, config.right_column_idx))
         a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval,
-                                               config.type, lkey, rkey)
-        _telemetry.counter("cylon_join_sort_operands_total").inc(
-            _join.plan_sort_operand_count(lkeys, str_flags, a_desc, b_desc,
-                                          hash_mode))
-        br = _join.stream_block_rows(lkeys[0].shape[0], rkeys[0].shape[0])
+                                               config.type, lkey, rkey,
+                                               wide_key)
+        count_plan_sort(lkeys, str_flags, len(ldat) + len(rdat), a_desc,
+                        b_desc, hash_mode)
+        br = _join.stream_block_rows(lkeys[0].shape[-1], rkeys[0].shape[-1])
         with _telemetry.phase("join.plan", seq):
             counts, a_streams, b_streams = _join.plan_program_stream(
                 lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
                 ldat, lval, rdat, rval, str_flags, config.type,
                 a_desc=a_desc, b_desc=b_desc, block_rows=br,
-                hash_mode=hash_mode, interpret=interp)
+                hash_mode=hash_mode, interpret=interp, wide_key=wide_key)
             # the COUNT FETCH memoizes on the source buffers (weakref
             # identity — jax arrays are immutable): repeat joins of the
             # same tables skip this ~100 ms host sync; the device
@@ -1018,7 +1060,7 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
                 counts, a_streams, b_streams,
                 ldat, lval, rdat, rval, config.type, cap_e,
                 a_desc=a_desc, b_desc=b_desc, block_rows=br,
-                interpret=interp)
+                interpret=interp, wide_key=wide_key), n_primary
 
     res = None
     if use_stream:
@@ -1026,16 +1068,15 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
     elif use_hash:
         res = _stream_join(hash_mode=True)
     if res is not None:
-        lod, lov, rod, rov, emit, lidx, ridx = res
+        (lod, lov, rod, rov, emit, lidx, ridx), n_primary = res
     else:
         from ..parallel.shuffle import _count_cached
 
-        _telemetry.counter("cylon_join_sort_operands_total").inc(
-            _join.plan_sort_operand_count(lkeys, str_flags))
+        count_plan_sort(lkeys, str_flags, len(ldat) + len(rdat))
         with _telemetry.phase("join.plan", seq):
             counts2, lo, m, bperm, un_mask = _join.plan_program(
                 lkeys, lkvalid, lemit, rkeys, rkvalid, remit, str_flags,
-                config.type)
+                config.type, key_wide=key_wide)
             # same memoization as the stream path: repeat joins of the
             # same tables skip the count host sync
             lids, lrefs = _memo_refs(lcols)
@@ -1101,7 +1142,24 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
             # unmatched (and FULL_OUTER would need appended rows) —
             # redo the join on exact shared-vocabulary dictionary codes
             return _exact_dict_fallback_join(left, right, config)
+    if planes and not (lvb or rvb or config.exact):
+        # INNER/LEFT/RIGHT: the live rows are the dense prefix [0, n) of
+        # the capacity. Cut a result that holds word planes to it, so
+        # that its row mask is None: a consumer that indexes a column's
+        # array by the row mask (boolean indexing on the host) cannot do
+        # that to a [2, n] array. One small program a distinct n.
+        dat, val = _join_prefix_program(
+            [c.data for c in cols], [c.validity for c in cols],
+            n=n_primary)
+        return Table([Column(d, c.dtype, v, c.dictionary, c.name)
+                      for d, v, c in zip(dat, val, cols)], left._ctx)
     return Table(cols, left._ctx, emit)
+
+
+@partial(jax.jit, static_argnames=("n",))
+def _join_prefix_program(dat, val, n: int):
+    """The first ``n`` rows of every array (rows are the last axis)."""
+    return jax.tree.map(lambda a: a[..., :n], (dat, val))
 
 
 def _exact_verify_keys(config, lcols, rcols, lidx, ridx, emit):
@@ -1237,8 +1295,8 @@ def _append_unmatched_right(left: Table, right: Table,
         left, right, config.left_column_idx, config.right_column_idx)
     # pairing is load-bearing: both sides must emit the same lane count
     # per varbytes key column or dense_ranks_two zips misaligned arrays
-    lkeys, _lv_, _f = _expanded_keys(lcols, rcols)
-    rkeys, _rv_, _f2 = _expanded_keys(rcols, lcols)
+    lkeys, _lv_, _f, _w = _expanded_keys(lcols, rcols)
+    rkeys, _rv_, _f2, _w2 = _expanded_keys(rcols, lcols)
     lv = _all_valid(lcols) & left.emit_mask()
     rv = _all_valid(rcols) & right.emit_mask()
     gl, gr = _order.dense_ranks_two(
@@ -1300,6 +1358,8 @@ def set_op(left: Table, right: Table, op) -> Table:
     (and collision) fallback."""
     if left.column_count != right.column_count:
         raise CylonError(Code.Invalid, "set ops need equal schemas")
+    refuse_planes(left._columns + right._columns,
+                  _setops.SetOp(op).name.lower())
     lcols, rcols = _aligned_setop_columns(left, right)
     out = _setops.setop_stream_table(left, right, lcols, rcols, op)
     if out is not None:
@@ -1329,6 +1389,7 @@ def concat_tables(tables: Sequence[Table], ctx: CylonContext) -> Table:
     out_cols = []
     for ci in range(first.column_count):
         cs = [t._columns[ci] for t in tables]
+        refuse_planes(cs, "merge")
         if any(c.is_varbytes for c in cs):
             cs = [as_varbytes(c) for c in cs]
             vb = concat_varbytes([c.varbytes for c in cs])
@@ -1373,6 +1434,8 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
     idx_cols = index_col if isinstance(index_col, (list, tuple)) else [index_col]
     idx_cols = [table._col_index(c) for c in idx_cols]
     val_cols = [table._col_index(c) for c in aggregate_cols]
+    refuse_planes([table._columns[i] for i in idx_cols + val_cols],
+                  "groupby")
     ops = [(_groupby.second_phase_op(o) if second_phase else o)
            for o in aggregate_ops]
 

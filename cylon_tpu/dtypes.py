@@ -8,7 +8,9 @@ factory functions `Int64()`, `Double()`, ...), mapped onto device dtypes:
 * STRING/BINARY are VARIABLE layout and are dictionary-encoded on device
   (int32 codes in HBM + host-side sorted vocabulary) because XLA has no
   variable-length array type — see data/column.py;
-* temporal types carry their unit and are stored as int32/int64 lanes.
+* temporal types carry their unit and are stored as int32/int64 lanes;
+* an 8-byte lane on a backend without x64 is two 32-bit word planes
+  (`to_word_planes` / `from_word_planes`; data/column.py says when).
 """
 from __future__ import annotations
 
@@ -183,3 +185,18 @@ def from_np_dtype(dt) -> DataType:
     if dt.kind == "m":
         return Duration(TimeUnit.NANO)
     raise TypeError(f"unsupported numpy dtype {dt}")
+
+
+def to_word_planes(arr: np.ndarray) -> np.ndarray:
+    """An 8-byte host array as ``uint32[2, n]``: plane 0 the high words,
+    plane 1 the low, bit for bit (rows last: a minor dimension of 2 would
+    be padded to a tile on the chip)."""
+    words = np.ascontiguousarray(arr).view("<u4").reshape(-1, 2)
+    return np.ascontiguousarray(words.T[::-1])
+
+
+def from_word_planes(planes: np.ndarray, dt) -> np.ndarray:
+    """The inverse of `to_word_planes`: ``n`` values of the 8-byte ``dt``."""
+    planes = np.asarray(planes, dtype="<u4")
+    words = np.stack([planes[1], planes[0]], axis=1)
+    return words.view("<u8").reshape(-1).view(np.dtype(dt))

@@ -139,9 +139,6 @@ def write_csv(table: Table, path: str,
     1091-1142 — native C++ row stringify there, native C++ here: all-
     numeric tables go through the multithreaded writer in
     native/cylon_host.cpp; strings/temporal/bool fall back to pandas)."""
-    import jax
-    import numpy as np
-
     options = options or CSVWriteOptions()
     names = options.GetColumnNames()
     t = table.compact() if table.row_mask is not None else table
@@ -149,11 +146,13 @@ def write_csv(table: Table, path: str,
 
     native_ok = (
         all(not c.is_string and not c.dtype.is_temporal()
-            and np.dtype(c.data.dtype) in _native.SUPPORTED_CSV_DTYPES
+            and c.host_dtype in _native.SUPPORTED_CSV_DTYPES
             for c in t._columns)
         and (names is None or len(names) == t.column_count))
     if native_ok:
-        cols = [np.asarray(jax.device_get(c.data)) for c in t._columns]
+        # one value a row: a 64-bit column held as word planes goes to
+        # the writer as int64 / uint64 / float64, never as its planes
+        cols = [c._host_data() for c in t._columns]
         valids = [c._host_mask() for c in t._columns]
         out_names = list(names) if names is not None else \
             [c.name or f"c{i}" for i, c in enumerate(t._columns)]

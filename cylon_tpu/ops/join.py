@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..data.column import is_word_planes, join_planes, split_planes
 from ..util import bucket_cap as _bucket_cap, pow2 as _pow2
 
 
@@ -436,20 +437,25 @@ STREAM_PLAN: Optional[bool] = None
 def stream_plan_applicable(lkeys, rkeys, str_flags,
                            join_type: JoinType) -> bool:
     """Host-side check over key arrays (pre-ordered-bits): single 4-byte
-    (or dictionary-string) key, INNER/LEFT/RIGHT, both sides non-empty."""
+    (or dictionary-string) key, or a single 8-byte key held as word
+    planes on both sides (data/column.py: two exact key lanes, no hash);
+    INNER/LEFT/RIGHT, both sides non-empty."""
     if STREAM_PLAN is False or join_type == JoinType.FULL_OUTER:
         return False
     if len(lkeys) != 1:
         return False
 
     def width(x, is_str):
+        if is_word_planes(x):
+            return 8
         return 4 if is_str else np.dtype(x.dtype).itemsize
 
-    if width(lkeys[0], str_flags[0]) != 4 \
-            or width(rkeys[0], str_flags[0]) != 4 \
+    wl, wr = width(lkeys[0], str_flags[0]), width(rkeys[0], str_flags[0])
+    planes = is_word_planes(lkeys[0]) and is_word_planes(rkeys[0])
+    if not (planes or (wl == 4 and wr == 4)) \
             or (not str_flags[0] and lkeys[0].dtype == jnp.bool_):
         return False
-    na, nb = lkeys[0].shape[0], rkeys[0].shape[0]
+    na, nb = lkeys[0].shape[-1], rkeys[0].shape[-1]
     if na == 0 or nb == 0 or na + nb >= (1 << 29):
         return False
     if STREAM_PLAN:
@@ -467,7 +473,7 @@ def _key_lane_count(x, is_str) -> int:
         return 1
     if x.dtype == jnp.bool_:
         return 1
-    return 2 if np.dtype(x.dtype).itemsize == 8 else 1
+    return 2 if is_word_planes(x) or np.dtype(x.dtype).itemsize == 8 else 1
 
 
 def hash_stream_applicable(lkeys, rkeys, str_flags,
@@ -482,6 +488,8 @@ def hash_stream_applicable(lkeys, rkeys, str_flags,
     """
     if STREAM_PLAN is False or join_type == JoinType.FULL_OUTER:
         return False
+    if any(is_word_planes(x) for x in tuple(lkeys) + tuple(rkeys)):
+        return False  # word planes: the sort path (one key) or the XLA plan
     na, nb = lkeys[0].shape[0], rkeys[0].shape[0]
     if na == 0 or nb == 0 or na + nb >= (1 << 29):
         return False
@@ -502,7 +510,8 @@ MAX_SHARED_LANES = 8
 
 
 def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType,
-                    lkey: Optional[int] = None, rkey: Optional[int] = None):
+                    lkey: Optional[int] = None, rkey: Optional[int] = None,
+                    wide_key: Optional[str] = None):
     """Static lane packing for the stream path: which columns ride the
     plan sort as u32 payload lanes. Slot s carries the probe side's lane
     s at probe rows and the build side's lane s at build rows, so the
@@ -512,18 +521,23 @@ def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType,
     kind "d" (data, bit-exact u32 reinterpret), "v" (validity widened to
     u32) or "k" (THE join key: no operand of its own; the sorted key bits
     stand in its slot and the column is read back off them with
-    order.from_ordered_bits_raw). 4-byte 1-D non-bool columns qualify;
-    the rest (8-byte, bool) use the index-gather fallback in materialize.
-    A column whose validity is None adds no "v" lane: the output's
-    validity is then the side's hit mask.
+    order.from_ordered_bits_raw). A 64-bit column held as word planes
+    (``uint32[2, n]``, data/column.py) rides as its two planes, "dh" and
+    "dl", and as the key "kh" and "kl": the two sorted key lanes.
+    4-byte 1-D non-bool columns and word planes qualify; the rest (a
+    native 8-byte array under x64, bool) use the index-gather fallback in
+    materialize. A column whose validity is None adds no "v" lane: the
+    output's validity is then the side's hit mask.
 
     ``lkey`` / ``rkey``: the column of each side that is the join's ONE
     key and whose values the sort path's key bits were made from (the
     caller sees that: not a promoted copy, not dictionary codes, not the
     hash path). The key rides once only where the input allows it, on
     BOTH sides (slot 0 is shared, so it is the sorted bits for both or
-    for neither): a signed or unsigned 4-byte integer (a float keeps its
-    lane: its ordered bits fold -0.0 into +0.0 and the output must not)
+    for neither): a signed or unsigned integer, 4 bytes wide or held as
+    word planes of the logical dtype ``wide_key`` (its numpy name, else
+    None; a float keeps its lane: its ordered bits fold -0.0 into +0.0 and
+    the output must not)
     with validity None (then no emitted row had its bits forced to
     all-ones as a dead row). Otherwise the key rides as "d", as any
     other column."""
@@ -533,28 +547,43 @@ def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType,
         adat, aval, akey, bdat, bval, bkey = ldat, lval, lkey, rdat, rval, rkey
 
     def key_once(dat, val, key):
-        return (key is not None and val[key] is None and dat[key].ndim == 1
-                and dat[key].dtype.itemsize == 4
-                and jnp.issubdtype(dat[key].dtype, jnp.integer))
+        if key is None or val[key] is not None:
+            return False
+        k = dat[key]
+        if is_word_planes(k):
+            return np.dtype(wide_key).kind in "iu"
+        return k.dtype.itemsize == 4 and jnp.issubdtype(k.dtype, jnp.integer)
 
     once = key_once(adat, aval, akey) and key_once(bdat, bval, bkey)
 
     def side(dat, val, key):
-        head = ((key, "k"),) if once else ()
+        head = ()
+        if once:
+            head = ((key, "kh"), (key, "kl")) if is_word_planes(dat[key]) \
+                else ((key, "k"),)
         desc = []
         for ci, (d, v) in enumerate(zip(dat, val)):
             if once and ci == key:
                 continue
-            need = 1 + (1 if v is not None else 0)
-            if (d.ndim == 1 and d.dtype.itemsize == 4
+            wide = is_word_planes(d)
+            kinds = ("dh", "dl") if wide else ("d",)
+            need = len(kinds) + (1 if v is not None else 0)
+            if ((wide or (d.ndim == 1 and d.dtype.itemsize == 4))
                     and d.dtype != jnp.bool_
                     and len(desc) + need <= MAX_SHARED_LANES):
-                desc.append((ci, "d"))
+                desc.extend((ci, kind) for kind in kinds)
                 if v is not None:
                     desc.append((ci, "v"))
         return head + tuple(desc)
 
     return side(adat, aval, akey), side(bdat, bval, bkey)
+
+
+def plan_key_lane_count(keys, str_flags) -> int:
+    """32-bit key lanes the join's plan sort compares for one side's key
+    arrays (counted as ``cylon_join_key_lanes_total``): one for a 4-byte
+    key, two for an 8-byte one, native or held as word planes."""
+    return sum(_key_lane_count(x, s) for x, s in zip(keys, str_flags))
 
 
 def plan_sort_operand_count(keys, str_flags, a_desc=None, b_desc=None,
@@ -563,21 +592,33 @@ def plan_sort_operand_count(keys, str_flags, a_desc=None, b_desc=None,
     arguments: a pure function of what the host sees before it
     dispatches (counted there as ``cylon_join_sort_operands_total``).
     ``keys``: one side's key arrays, raw or as ordered bits. With the
-    lane descriptors it is the stream path's sort: key bits + tag + one
-    operand a payload slot (the hash path: two hashes + tag + the key's
-    verify lanes + the slots); without them the XLA plan's
-    (`join_plan_keys`: the key bit arrays + tag). It counts what the
-    program hands over: the stream path's sorts are not stable, so the
-    compiler adds no index to them."""
+    lane descriptors it is the stream path's sort: key bits (two lanes
+    for a key held as word planes) + tag + one operand a payload slot
+    (the hash path: two hashes + tag + the key's verify lanes + the
+    slots); without them the XLA plan's (`join_plan_keys`: the key bit
+    arrays + tag). It counts what the program hands over: the stream
+    path's sorts are not stable, so the compiler adds no index to them."""
+    planes = sum(map(is_word_planes, keys))  # each is two bit arrays
     if a_desc is None:
-        return len(keys) + 1
+        return len(keys) + planes + 1
     # a payload slot is the wider side's lane; the key as "k" takes none
-    slots = max(sum(kind != "k" for _, kind in desc)
+    slots = max(sum(kind[0] != "k" for _, kind in desc)
                 for desc in (a_desc, b_desc))
     if not hash_mode:
-        return 2 + slots
-    return 3 + sum(_key_lane_count(x, s)
-                   for x, s in zip(keys, str_flags)) + slots
+        return 2 + planes + slots
+    return 3 + plan_key_lane_count(keys, str_flags) + slots
+
+
+def plan_gathered_column_count(n_cols: int, a_desc=None, b_desc=None) -> int:
+    """Output columns of a join over ``n_cols`` input columns (both
+    sides) that are materialised through the index-gather fallback and
+    not off a sort lane (counted as
+    ``cylon_join_gathered_columns_total``): on the stream path those no
+    lane descriptor names, on the XLA plan all of them."""
+    if a_desc is None:
+        return n_cols
+    return n_cols - sum(len({ci for ci, _ in desc})
+                        for desc in (a_desc, b_desc))
 
 
 def stream_block_rows(na: int, nb: int) -> int:
@@ -603,13 +644,16 @@ def stream_expand_capacity(n: int, block_rows: int):
 def _side_lanes(dat, val, desc):
     lanes = []
     for ci, kind in desc:
-        if kind == "k":
+        if kind[0] == "k":
             continue  # rides as the sort's key bits, not as a lane
         if kind == "d":
             d = dat[ci]
             lanes.append(d if d.dtype == jnp.uint32 else d.view(jnp.uint32))
-        else:
+        elif kind == "v":
             lanes.append(val[ci].astype(jnp.uint32))
+        else:  # "dh" / "dl": a plane of a 64-bit column
+            hi, lo = split_planes(dat[ci])
+            lanes.append(hi if kind == "dh" else lo)
     return lanes
 
 
@@ -618,10 +662,16 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
                               str_flags, join_type: JoinType,
                               a_desc=(), b_desc=(), block_rows: int = 64,
                               hash_mode: bool = False,
-                              interpret: bool = False):
+                              interpret: bool = False,
+                              wide_key: Optional[str] = None):
     """Phase 1 (stream path): raw key columns → sorted stream (payload
     lanes riding along) → Pallas plan pass that compacts the plan AND the
     payload into groups A/B. Only counts[4] crosses to the host.
+
+    ``wide_key``: the logical 64-bit dtype name of THE key where it is
+    held as word planes (`stream_plan_applicable` admits one such key),
+    else None. It sorts as its two exact ordered lanes ``(hi, lo, tag)``,
+    and the plan kernel finds its runs over both.
 
     hash_mode (the honest JoinAlgorithm.HASH): rows sort by a 2x32-bit
     row hash instead of raw key bits, so ANY key shape costs two sort
@@ -632,7 +682,8 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
     from .hash import hash2_streams
 
     lbits, lkv, rbits, rkv = _keys_to_bits(lkeys, lkvalid, rkeys, rkvalid,
-                                           str_flags)
+                                           str_flags,
+                                           (wide_key,) if wide_key else ())
     lemit = _vm(lemit, lkv.shape[0])
     remit = _vm(remit, rkv.shape[0])
     if join_type == JoinType.RIGHT:
@@ -656,8 +707,8 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
 
     # the join key rides once (plan_lane_descs): no lane of its own here,
     # the sorted key bits stand in slot 0 for both sides after the sort
-    key_once = bool(a_desc) and a_desc[0][1] == "k"
-    assert key_once == (bool(b_desc) and b_desc[0][1] == "k")
+    key_once = bool(a_desc) and a_desc[0][1][0] == "k"
+    assert key_once == (bool(b_desc) and b_desc[0][1][0] == "k")
     assert not (key_once and hash_mode), "the hash path sorts by hashes"
     a_lanes = _side_lanes(adat, aval, a_desc)
     b_lanes = _side_lanes(bdat, bval, b_desc)
@@ -699,28 +750,32 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
             verify_lanes=res[3:3 + nk],
             block_rows=block_rows, interpret=interpret)
 
-    bits = jnp.concatenate([abits[0], bbits[0]])
-    bits = jnp.where(live, bits, allones)
+    # one bit array for a 4-byte key, (hi, lo) for one held as word planes
+    assert len(abits) in (1, 2)
+    bits = tuple(jnp.where(live, jnp.concatenate([a, b]), allones)
+                 for a, b in zip(abits, bbits))
+    nk = len(bits)
     # (bits, tag) is a total order (tag's low 29 bits are the row's iota),
     # so a sort that is not stable gives the same arrays bit for bit, and
     # XLA does not append an index operand of its own as it does for a
     # stable sort (PERF.md section 6: one 4-byte operand's time)
-    res = jax.lax.sort((bits, tag) + tuple(lanes), num_keys=2,
+    res = jax.lax.sort(bits + (tag,) + tuple(lanes), num_keys=nk + 1,
                        is_stable=False)
-    bits_s, tag_s, lanes_s = res[0], res[1], res[2:]
+    bits_s, tag_s, lanes_s = res[:nk], res[nk], res[nk + 1:]
     if key_once:
-        lanes_s = (bits_s,) + tuple(lanes_s)
-    return tk.join_plan_stream(bits_s, tag_s, na, nb,
+        lanes_s = tuple(bits_s) + tuple(lanes_s)
+    return tk.join_plan_stream(bits_s[0], tag_s, na, nb,
                                emit_unmatched_a=join_type != JoinType.INNER,
                                lanes=lanes_s, n_a_lanes=len(a_desc),
                                n_b_lanes=len(b_desc),
+                               bits2_s=bits_s[1] if nk == 2 else None,
                                block_rows=block_rows, interpret=interpret)
 
 
 _plan_program_stream_jit = partial(
     jax.jit, static_argnames=("str_flags", "join_type", "a_desc", "b_desc",
-                              "block_rows", "hash_mode",
-                              "interpret"))(_plan_program_stream_impl)
+                              "block_rows", "hash_mode", "interpret",
+                              "wide_key"))(_plan_program_stream_impl)
 
 
 def plan_program_stream(*args, interpret: bool = False, **kw):
@@ -738,14 +793,17 @@ def _materialize_program_stream_impl(counts, a_streams, b_streams,
                                      join_type: JoinType, cap_e: int,
                                      a_desc=(), b_desc=(),
                                      block_rows: int = 64,
-                                     interpret: bool = False):
+                                     interpret: bool = False,
+                                     wide_key: Optional[str] = None):
     """Phase 2 (stream path): compacted plan + payload lanes → output
     rows via the streaming expansion kernel. Returns (ldat', lval',
     rdat', rval', emit). Columns that rode sort lanes are unpacked from
-    the kernel's lane outputs (zero output-sized XLA gathers); the rest
-    gather by the materialized aidx/bidx."""
+    the kernel's lane outputs (zero output-sized XLA gathers; a 64-bit
+    column's two lanes go back to ``uint32[2, cap_e]`` word planes, the
+    key's through ``wide_key``, its logical dtype); the rest gather
+    by the materialized aidx/bidx."""
     from . import tpu_kernels as tk
-    from .order import from_ordered_bits_raw
+    from .order import from_ordered_bits_planes, from_ordered_bits_raw
 
     aidx, bidx, a_lane_outs, b_lane_outs = tk.join_expand_stream(
         counts, a_streams, b_streams, cap_e, block_rows=block_rows,
@@ -762,8 +820,11 @@ def _materialize_program_stream_impl(counts, a_streams, b_streams,
     def unpack(dat, val, desc, lane_outs, hit, idx):
         od: list = [None] * len(dat)
         ov: list = [None] * len(dat)
+        planes: dict = {}  # column -> its "kh"/"kl" or "dh"/"dl" lanes
         for (ci, kind), lane in zip(desc, lane_outs):
-            if kind == "k":
+            if kind in ("kh", "kl", "dh", "dl"):
+                planes.setdefault(ci, {})[kind] = lane
+            elif kind == "k":
                 # the lane is the key's ordered bits; rows without a hit
                 # read 0 as every lane does (the kernel zeroes them)
                 od[ci] = jnp.where(
@@ -777,6 +838,17 @@ def _materialize_program_stream_impl(counts, a_streams, b_streams,
                     ov[ci] = hit
             else:
                 ov[ci] = (lane != 0) & hit
+        for ci, got in planes.items():
+            if "kh" in got:  # the key's ordered lanes; see "k" above
+                zero = jnp.uint32(0)
+                od[ci] = join_planes(*(jnp.where(hit, w, zero) for w in
+                                       from_ordered_bits_planes(
+                                           got["kh"], got["kl"], wide_key)))
+                ov[ci] = hit
+            else:
+                od[ci] = join_planes(got["dh"], got["dl"])
+                if val[ci] is None:
+                    ov[ci] = hit
         fb = [ci for ci in range(len(dat)) if od[ci] is None]
         if fb:
             fbd, fbv = gather_columns(
@@ -797,8 +869,8 @@ def _materialize_program_stream_impl(counts, a_streams, b_streams,
 
 _materialize_program_stream_jit = partial(
     jax.jit, static_argnames=("join_type", "cap_e", "a_desc", "b_desc",
-                              "block_rows",
-                              "interpret"))(_materialize_program_stream_impl)
+                              "block_rows", "interpret",
+                              "wide_key"))(_materialize_program_stream_impl)
 
 
 def materialize_program_stream(*args, interpret: bool = False, **kw):
@@ -814,12 +886,26 @@ def _vm(v, n):
     return jnp.ones(n, dtype=bool) if v is None else v
 
 
-def _keys_to_bits(lkeys, lkvalid, rkeys, rkvalid, str_flags):
-    from .order import ordered_bits_raw
+def _keys_to_bits(lkeys, lkvalid, rkeys, rkvalid, str_flags, key_wide=()):
+    """Ordered bit arrays of both sides' keys. ``key_wide[i]`` names the
+    logical 64-bit dtype of key array i where it is held as word planes
+    (else None; () for none at all): such a key gives TWO bit arrays,
+    its ordered high and low lanes."""
+    from .order import ordered_bits_planes, ordered_bits_raw
 
-    n_l, n_r = lkeys[0].shape[0], rkeys[0].shape[0]
-    lbits = tuple(ordered_bits_raw(x, s) for x, s in zip(lkeys, str_flags))
-    rbits = tuple(ordered_bits_raw(x, s) for x, s in zip(rkeys, str_flags))
+    wide = tuple(key_wide) or (None,) * len(str_flags)
+
+    def bits(keys):
+        out = []
+        for x, s, w in zip(keys, str_flags, wide):
+            if w is None:
+                out.append(ordered_bits_raw(x, s))
+            else:
+                out.extend(ordered_bits_planes(x, w))
+        return tuple(out)
+
+    n_l, n_r = lkeys[0].shape[-1], rkeys[0].shape[-1]
+    lbits, rbits = bits(lkeys), bits(rkeys)
     lkv = jnp.ones(n_l, bool)
     for v in lkvalid:
         if v is not None:
@@ -831,14 +917,14 @@ def _keys_to_bits(lkeys, lkvalid, rkeys, rkvalid, str_flags):
     return lbits, lkv, rbits, rkv
 
 
-@partial(jax.jit, static_argnames=("str_flags", "join_type"))
+@partial(jax.jit, static_argnames=("str_flags", "join_type", "key_wide"))
 def plan_program(lkeys, lkvalid, lemit, rkeys, rkvalid, remit, str_flags,
-                 join_type: JoinType):
+                 join_type: JoinType, key_wide=()):
     """Phase 1: raw key columns → plan (counts + match arrays), one
     compiled program. Only counts2 crosses to the host; the match arrays
     stay on device for phase 2."""
     lbits, lkv, rbits, rkv = _keys_to_bits(lkeys, lkvalid, rkeys, rkvalid,
-                                           str_flags)
+                                           str_flags, key_wide)
     return join_plan_keys(lbits, lkv, _vm(lemit, lkv.shape[0]),
                           rbits, rkv, _vm(remit, rkv.shape[0]), join_type)
 
@@ -870,14 +956,11 @@ def gather_columns(dat, val, idx):
     hit = idx >= 0
     out_d, out_v = [], []
     for d, v in zip(dat, val):
-        if d.shape[0] == 0:
-            out_d.append(jnp.zeros(idx.shape + d.shape[1:], d.dtype))
+        if d.shape[-1] == 0:  # rows are the last axis (word planes: [2, n])
+            out_d.append(jnp.zeros(d.shape[:-1] + idx.shape, d.dtype))
             out_v.append(jnp.zeros(idx.shape, bool))
         else:
-            out_d.append(jnp.take(d, safe, axis=0))
+            out_d.append(jnp.take(d, safe, axis=-1))
             out_v.append(hit if v is None else (jnp.take(v, safe) & hit))
     return tuple(out_d), tuple(out_v)
-
-
-
 

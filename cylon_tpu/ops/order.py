@@ -22,7 +22,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..data.column import Column
+from ..data.column import Column, refuse_planes, split_planes
 from ..status import Code, CylonError
 
 _WIDTH_UINT = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
@@ -36,6 +36,7 @@ def ordered_bits(col: Column, descending: bool = False) -> jnp.ndarray:
         raise CylonError(Code.TypeError,
                          "varbytes columns need sort_prefix_keys/hash_keys, "
                          "not ordered_bits")
+    refuse_planes([col], "ordered_bits")  # two lanes: ordered_bits_planes
     return ordered_bits_raw(col.data, col.is_string, descending)
 
 
@@ -106,6 +107,45 @@ def from_ordered_bits_raw(bits: jnp.ndarray, dtype,
         # a set top bit marks a value that was not negative
         return jnp.where((bits & signbit) != 0, bits ^ signbit,
                          ~bits).view(dt)
+    raise CylonError(Code.TypeError, f"unorderable dtype {dt}")
+
+
+def ordered_bits_planes(planes: jnp.ndarray, dtype
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`ordered_bits_raw`'s twin for a 64-bit column held as word planes
+    (data/column.py): the two ``uint32`` lanes ``(hi, lo)`` whose
+    lexicographic order is the order of the ``dtype`` values, with no
+    ``uint64`` (the chip has none). Signed: the sign bit of the high word
+    flipped; float64: the IEEE total-order trick over both words, -0.0
+    folded into +0.0 first; unsigned: the planes themselves."""
+    dt = np.dtype(dtype)
+    hi, lo = split_planes(planes)
+    signbit = jnp.uint32(1 << 31)
+    if jnp.issubdtype(dt, jnp.unsignedinteger):
+        return hi, lo
+    if jnp.issubdtype(dt, jnp.signedinteger):
+        return hi ^ signbit, lo
+    if jnp.issubdtype(dt, jnp.floating):
+        hi = jnp.where((hi == signbit) & (lo == 0), jnp.uint32(0), hi)
+        neg = (hi & signbit) != 0
+        return (jnp.where(neg, ~hi, hi ^ signbit), jnp.where(neg, ~lo, lo))
+    raise CylonError(Code.TypeError, f"unorderable dtype {dt}")
+
+
+def from_ordered_bits_planes(hi: jnp.ndarray, lo: jnp.ndarray, dtype
+                             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The inverse of `ordered_bits_planes`: the word planes ``(hi, lo)``
+    of the ``dtype`` values whose ordered bits are ``(hi, lo)``; exact but
+    for a float's -0.0, as `from_ordered_bits_raw`."""
+    dt = np.dtype(dtype)
+    signbit = jnp.uint32(1 << 31)
+    if jnp.issubdtype(dt, jnp.unsignedinteger):
+        return hi, lo
+    if jnp.issubdtype(dt, jnp.signedinteger):
+        return hi ^ signbit, lo
+    if jnp.issubdtype(dt, jnp.floating):
+        pos = (hi & signbit) != 0   # a set top bit: the value was not negative
+        return (jnp.where(pos, hi ^ signbit, ~hi), jnp.where(pos, lo, ~lo))
     raise CylonError(Code.TypeError, f"unorderable dtype {dt}")
 
 

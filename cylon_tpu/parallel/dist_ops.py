@@ -837,9 +837,10 @@ def hash_partition(table: Table, hash_columns: Sequence,
     (round-3 verdict: the old host-numpy round trip was wrong for a
     device table mid-pipeline). Long varbytes columns (> LANE_WORDS_MAX
     words) fall back to the native host partitioner."""
-    from ..data.column import Column
+    from ..data.column import Column, refuse_planes
     from ..data.strings import LANE_WORDS_MAX, VarBytes
 
+    refuse_planes(table._columns, "hash_partition")
     idxs = [table._col_index(c) for c in hash_columns]
     if any(c.is_varbytes and c.varbytes.max_words > LANE_WORDS_MAX
            for c in table._columns):
@@ -1060,9 +1061,9 @@ def distributed_join(left: Table, right: Table,
             table_mod.sole_key_index(rcols2, rcols_s, ridx))
         a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval, jt,
                                                lkey, rkey)
-        _counter("cylon_join_sort_operands_total").inc(
-            _join.plan_sort_operand_count(lkb, (False,) * len(lkb), a_desc,
-                                          b_desc, hash_mode))
+        table_mod.count_plan_sort(lkb, (False,) * len(lkb),
+                                  len(ldat) + len(rdat), a_desc, b_desc,
+                                  hash_mode)
         with _phase("distributed_join.plan", seq):
             rep_counts, counts_dev, a_streams, b_streams = \
                 _join_plan_stream_fn(ctx.mesh, jt, len(lkb), a_desc,
@@ -1087,8 +1088,8 @@ def distributed_join(left: Table, right: Table,
     if res is not None:
         lod, lov, rod, rov, emit, lidx_o, ridx_o = res
     else:
-        _counter("cylon_join_sort_operands_total").inc(
-            _join.plan_sort_operand_count(lkb, (False,) * len(lkb)))
+        table_mod.count_plan_sort(lkb, (False,) * len(lkb),
+                                  len(ldat) + len(rdat))
         with _phase("distributed_join.plan", seq):
             counts2, lo, m, bperm, un_mask = _join_plan_fn(ctx.mesh, jt)(
                 lkb, lkv, lemit, rkb, rkv, remit)

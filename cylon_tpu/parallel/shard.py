@@ -24,7 +24,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..context import CylonContext
-from ..data.column import Column
+from ..data.column import Column, refuse_planes
 from ..data.table import Table
 from ..status import Code, CylonPlanError
 from ..telemetry import host_fetch as _host_fetch
@@ -88,6 +88,7 @@ def distribute(table: Table, ctx: CylonContext) -> Table:
     if is_distributed_table(table, ctx):
         return table
     world = ctx.get_world_size()
+    refuse_planes(table._columns, f"distribute over {world} chips")
     n = table.capacity
     cap = shard_capacity(n, world)
     total = world * cap
@@ -237,6 +238,7 @@ def distribute_by_key(table: Table, ctx: CylonContext, key_columns) -> Table:
     DistributedJoin (table.cpp:656-696), moved off the device entirely.
     """
     world = ctx.get_world_size()
+    refuse_planes(table._columns, "distribute_by_key")
     idxs = [table._col_index(c) for c in key_columns]
     t = table.compact()
     key_cols = [t._columns[i] for i in idxs]
@@ -327,6 +329,8 @@ def assemble_process_local(tables, ctx: CylonContext) -> Table:
         raise CylonPlanError(
             f"need one table per local shard ({len(local)}), "
             f"got {len(tables)}")
+    for t in tables:
+        refuse_planes(t._columns, "assemble_process_local")
     tables = [t.compact() for t in tables]
 
     first = tables[0]
@@ -432,7 +436,7 @@ def extract_process_local(table: Table, ctx: CylonContext) -> dict:
     Varbytes columns decode per shard: their starts are SHARD-RELATIVE
     by invariant (strings.py shard_geom), so each addressable word block
     pairs with its row block with no global gather."""
-    from ..dtypes import Type
+    from ..dtypes import Type, from_word_planes
 
     t = table
     n_local = None
@@ -456,7 +460,9 @@ def extract_process_local(table: Table, ctx: CylonContext) -> dict:
                 vals[~m] = None
             out[name] = vals
             continue
-        d = np.concatenate(_local_blocks(c.data))
+        d = np.concatenate(_local_blocks(c.data), axis=-1)  # rows last
+        if c.is_planes:  # only a table on one chip holds such a column
+            d = from_word_planes(d, c.dtype.np_dtype)
         n_local = d.shape[0]
         vals = c.dictionary[d].astype(object) if c.is_string else d
         if c.validity is not None:

@@ -310,7 +310,12 @@ def _leg_split(x):
     word legs (the varbytes trick applied to every column), narrower
     ones widen value-exactly, 2-D leaves split per column. Round trips
     are bit-exact: bitcasts for 4/8-byte, value casts for 1/2-byte
-    (lossless by range)."""
+    (lossless by range).
+
+    A 2-D leaf here has its rows FIRST (``[n, k]``), as everywhere in
+    this module. A 64-bit column's word planes are ``[2, n]``, rows LAST
+    (data/column.py, THE AXIS CONVENTION): they come here as two 1-D
+    leaves, never as that array."""
     if x.ndim == 2:
         subs = [_leg_split(x[:, j]) for j in range(x.shape[1])]
         legs = [leg for sub_legs, _ in subs for leg in sub_legs]

@@ -53,7 +53,8 @@ def _optimize_root(root, world):
 
 
 def _snapshot(table: Table, table_id=None, inline=None) -> ir.Scan:
-    types = [ir.STR_TYPE if c.is_string else str(c.data.dtype)
+    # a 64-bit column held as word planes goes by its logical dtype
+    types = [ir.STR_TYPE if c.is_string else str(c.host_dtype)
              for c in table._columns]
     return ir.Scan(table_id, list(table.column_names), types,
                    witness_sig=table._hash_partitioned, table=inline)

@@ -43,10 +43,10 @@ def _sds(dtype, n=4096):
     return jax.ShapeDtypeStruct((n,), dtype)
 
 
-def _plan_sorts(keys, cols, a_desc, b_desc, hash_mode=False):
+def _plan_sorts(keys, cols, a_desc, b_desc, hash_mode=False, wide=None):
     kw = dict(str_flags=(False,), join_type=INNER, a_desc=a_desc,
               b_desc=b_desc, block_rows=8, hash_mode=hash_mode,
-              interpret=False)
+              interpret=False, wide_key=wide)
     with jax.enable_x64(False):
         closed = jax.make_jaxpr(
             lambda *a: _join._plan_program_stream_impl(*a, **kw))(
@@ -56,40 +56,59 @@ def _plan_sorts(keys, cols, a_desc, b_desc, hash_mode=False):
 
 # workload B's schema: a 4-byte key and a 4-byte payload a side
 K, W, M = _sds(jnp.int32), _sds(jnp.float32), _sds(jnp.bool_)
+# upstream Cylon's: an int64 key and a float64 payload, on a backend
+# without x64 each ONE array of two 32-bit word planes (data/column.py)
+P = jax.ShapeDtypeStruct((2, 4096), jnp.uint32)
 
 
-@pytest.mark.parametrize("case,cols,keycols,hash_mode,a_want,operands", [
+@pytest.mark.parametrize("case,cols,keycols,hash_mode,a_want,operands,wide", [
     # the local join's arguments (data/table._join_once)
     ("local", ((K, W), (None, None), (K, W), (None, None)), (0, 0), False,
-     ((0, "k"), (1, "d")), 3),
+     ((0, "k"), (1, "d")), 3, None),
     # a payload column with a real mask still carries its validity lane
     ("masked_payload", ((K, W), (None, None), (K, W), (None, M)), (0, 0),
-     False, ((0, "k"), (1, "d")), 4),
+     False, ((0, "k"), (1, "d")), 4, None),
     # a key with a mask keeps its lanes, on both sides: slot 0 is shared
     ("masked_key", ((K, W), (M, None), (K, W), (None, None)), (0, 0), False,
-     ((0, "d"), (0, "v"), (1, "d")), 5),
+     ((0, "d"), (0, "v"), (1, "d")), 5, None),
     # a key the caller does not vouch for (promoted, dictionary codes)
     ("no_key_map", ((K, W), (None, None), (K, W), (None, None)),
-     (None, None), False, ((0, "d"), (1, "d")), 4),
+     (None, None), False, ((0, "d"), (1, "d")), 4, None),
     ("float_key", ((W, K), (None, None), (W, K), (None, None)), (0, 0),
-     False, ((0, "d"), (1, "d")), 4),
+     False, ((0, "d"), (1, "d")), 4, None),
     # the hash path: two hashes, tag, one verify lane, two payload slots
     ("hash", ((K, W), (None, None), (K, W), (None, None)), (None, None),
-     True, ((0, "d"), (1, "d")), 6),
+     True, ((0, "d"), (1, "d")), 6, None),
+    # (int64 key, float64 value) as word planes: the key rides once, as
+    # its two ordered lanes (hi, lo, tag are the sort's three keys), the
+    # payload as its two planes: no gather
+    ("i64_f64", ((P, P), (None, None), (P, P), (None, None)), (0, 0), False,
+     ((0, "kh"), (0, "kl"), (1, "dh"), (1, "dl")), 5, "int64"),
+    ("u64_masked_payload", ((P, P), (None, None), (P, P), (None, M)), (0, 0),
+     False, ((0, "kh"), (0, "kl"), (1, "dh"), (1, "dl")), 6, "uint64"),
+    # a float64 key keeps its planes as lanes (-0.0 must come back)
+    ("f64_key", ((P, P), (None, None), (P, P), (None, None)), (0, 0), False,
+     ((0, "dh"), (0, "dl"), (1, "dh"), (1, "dl")), 7, "float64"),
 ])
 def test_plan_sort_operands_read_off_the_jaxpr(case, cols, keycols,
-                                               hash_mode, a_want, operands):
+                                               hash_mode, a_want, operands,
+                                               wide):
     ldat, lval, rdat, rval = cols
     a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval, INNER,
-                                           *keycols)
+                                           *keycols, wide)
     assert a_desc == a_want
     keys = ((ldat[0],), (lval[0],), None, (rdat[0],), (rval[0],), None)
-    sorts = _plan_sorts(keys, cols, a_desc, b_desc, hash_mode)
-    assert sorts == [(operands, False, 3 if hash_mode else 2)]
+    sorts = _plan_sorts(keys, cols, a_desc, b_desc, hash_mode, wide)
+    lanes = 2 if wide else 1     # 32-bit key lanes the sort compares
+    assert _join.plan_key_lane_count(keys[0], (False,)) == lanes
+    assert sorts == [(operands, False, 3 if hash_mode else lanes + 1)]
     assert _join.plan_sort_operand_count(
         keys[0], (False,), a_desc, b_desc, hash_mode) == operands
     # the XLA plan (join_plan_keys): the key bit arrays and the tag
-    assert _join.plan_sort_operand_count(keys[0], (False,)) == 2
+    assert _join.plan_sort_operand_count(keys[0], (False,)) == lanes + 1
+    # every column rides a lane: none is gathered by index
+    assert _join.plan_gathered_column_count(4, a_desc, b_desc) == 0
+    assert _join.plan_gathered_column_count(4) == 4   # the XLA plan: all
 
 
 def test_right_join_key_map_follows_the_swap():

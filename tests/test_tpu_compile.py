@@ -172,3 +172,50 @@ def test_join_expand_compiles(chip):
     text = _compiled_text(_join._materialize_program_stream_jit, counts,
                           a_streams, b_streams, *cols, cap_e=cap_e, **kw)
     assert "tpu_custom_call" in text
+
+
+# upstream Cylon's join schema (PR 32): int64 key, float64 value, each ONE
+# uint32[2, n] array of word planes, the key riding once as its two
+# ordered lanes. 1M rows a side: block_rows is 64 as at 31.25M, and what
+# Mosaic may refuse (the second run stream, 12 compacted streams, 8 expand
+# lanes in VMEM) does not depend on the rows; the sort at full size was
+# compiled by hand (PERF.md section 6, PR 32).
+N64 = 1 << 20
+
+
+def _join64_shapes(chip):
+    p = jax.ShapeDtypeStruct((2, N64), jnp.uint32, sharding=chip)
+    cols = ((p, p), (None, None), (p, p), (None, None))
+    a_desc, b_desc = _join.plan_lane_descs(*cols, _join.JoinType.INNER, 0, 0,
+                                           "int64")
+    keys = ((p,), (None,), None, (p,), (None,), None)
+    kw = dict(join_type=_join.JoinType.INNER, a_desc=a_desc, b_desc=b_desc,
+              block_rows=_join.stream_block_rows(N64, N64), interpret=False)
+    return keys, cols, kw
+
+
+def test_join64_plan_and_expand_compile(chip):
+    keys, cols, kw = _join64_shapes(chip)
+    assert kw["block_rows"] == 64
+    plan_kw = dict(str_flags=(False,), hash_mode=False,
+                   wide_key="int64", **kw)
+    text = _compiled_text(_join._plan_program_stream_jit, *keys, *cols,
+                          **plan_kw)
+    assert "tpu_custom_call" in text
+    # (hi, lo, tag) and the payload's two planes: 5 operands as COMPILED
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    assert len(sorts) == 1, sorts
+    operands = re.search(r"\bsort\((.*?)\), dimensions=", sorts[0]).group(1)
+    assert operands.count("%") == 5, sorts[0]
+    with jax.enable_x64(False):
+        plan_out = jax.eval_shape(
+            lambda *a: _join._plan_program_stream_impl(*a, **plan_kw),
+            *keys, *cols)
+    counts, a_streams, b_streams = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        plan_out)
+    cap_e = _join.stream_expand_capacity(N64, kw["block_rows"])
+    text = _compiled_text(_join._materialize_program_stream_jit, counts,
+                          a_streams, b_streams, *cols, cap_e=cap_e,
+                          wide_key="int64", **kw)
+    assert "tpu_custom_call" in text
