@@ -911,13 +911,15 @@ def _join_plan_bytes_estimate(left: Table, right: Table) -> int:
 
 
 def count_plan_sort(keys, str_flags, n_cols: int, a_desc=None, b_desc=None,
-                    hash_mode: bool = False) -> None:
-    """Count what the host hands a join's plan sort and what the join
-    will gather, once a join, where the operand list is built (the local
-    join here, `parallel/dist_ops.distributed_join`): the three
-    ``cylon_join_*`` counters of docs/telemetry.md. ``keys``: one side's
-    key arrays; ``n_cols``: the input columns of both sides; the lane
-    descriptors only on the stream path."""
+                    hash_mode: bool = False,
+                    block_rows: Optional[int] = None) -> None:
+    """Count what the host hands a join's plan sort, what the join will
+    gather and what its expand kernel sweeps, once a join, where the
+    operand list is built (the local join here,
+    `parallel/dist_ops.distributed_join`): the four ``cylon_join_*``
+    counters of docs/telemetry.md. ``keys``: one side's key arrays;
+    ``n_cols``: the input columns of both sides; the lane descriptors
+    and the kernels' ``block_rows`` only on the stream path."""
     _telemetry.counter("cylon_join_sort_operands_total").inc(
         _join.plan_sort_operand_count(keys, str_flags, a_desc, b_desc,
                                       hash_mode))
@@ -925,6 +927,8 @@ def count_plan_sort(keys, str_flags, n_cols: int, a_desc=None, b_desc=None,
         _join.plan_key_lane_count(keys, str_flags))
     _telemetry.counter("cylon_join_gathered_columns_total").inc(
         _join.plan_gathered_column_count(n_cols, a_desc, b_desc))
+    _telemetry.counter("cylon_join_expand_sweep_rows_total").inc(
+        _join.expand_sweep_rows(block_rows))
 
 
 def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
@@ -1023,9 +1027,9 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
         a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval,
                                                config.type, lkey, rkey,
                                                wide_key)
-        count_plan_sort(lkeys, str_flags, len(ldat) + len(rdat), a_desc,
-                        b_desc, hash_mode)
         br = _join.stream_block_rows(lkeys[0].shape[-1], rkeys[0].shape[-1])
+        count_plan_sort(lkeys, str_flags, len(ldat) + len(rdat), a_desc,
+                        b_desc, hash_mode, br)
         with _telemetry.phase("join.plan", seq):
             counts, a_streams, b_streams = _join.plan_program_stream(
                 lkeys, lkvalid, lemit, rkeys, rkvalid, remit,

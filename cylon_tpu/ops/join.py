@@ -621,6 +621,20 @@ def plan_gathered_column_count(n_cols: int, a_desc=None, b_desc=None) -> int:
                         for desc in (a_desc, b_desc))
 
 
+def expand_sweep_rows(block_rows: Optional[int] = None) -> int:
+    """Window rows ONE output vreg's a-side sweep visits in the expand
+    kernel, on the path taken (counted as
+    ``cylon_join_expand_sweep_rows_total``): the slab's sub-window on
+    the stream path, whatever the block (before PR 33 the block's whole
+    ``block_rows + 8`` window: 72 at 64); 0 on the XLA plan
+    (``block_rows`` None), which materialises by gathers."""
+    if block_rows is None:
+        return 0
+    from . import tpu_kernels as tk
+
+    return tk.EXPAND_SWEEP_ROWS  # every block's window holds them
+
+
 def stream_block_rows(na: int, nb: int) -> int:
     """ONE Pallas block-rows choice for plan AND expand (the expansion
     window slack requires expand block_rows <= plan block_rows): small

@@ -10,11 +10,17 @@ that touch HBM sequentially and resolve indirection on-chip:
 - ``sweep_gather``  — in-kernel VMEM window gather out[i] = win[o[i]]:
   sublane sweep of native (rows,128) lane gathers (`take_along_axis`
   along lanes is a Mosaic primitive; wider windows sweep row-by-row
-  with compare+select).
+  with compare+select). One gather a (window row, output vreg) pair:
+  hand it the rows the outputs can reach and no more
+  (`join_expand_stream` DMAs a block's window and sweeps, a vreg of
+  outputs at a time, the 16 rows of it that vreg reaches).
 - ``block_cumsum``  — in-kernel flat inclusive scan of a (R,128) block
   (`jnp.cumsum` has no Mosaic lowering).
 - ``inverse_monotone`` — o[q] = #{j : P[j] <= q} for a non-decreasing
-  block P: binary search over sweep_gather probes.
+  block P: a count of the rows wholly <= q off their last elements,
+  then a binary search over the one row left, 7 sweep_gather probes,
+  each a sweep of all of P for all of q (so it is called a slab at a
+  time on 16 rows, not on a block's 72).
 - ``stream_compact`` — compact masked elements of K parallel u32 streams
   into dense prefixes, writing element-exact output via row-aligned DMA
   with a write pointer and partial-row tail carried in SMEM/VMEM across
@@ -47,6 +53,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 _I32MAX = jnp.iinfo(jnp.int32).max
+# window rows ONE (8, 128) vreg of join_expand_stream's outputs can reach:
+# 1,024 outputs span at most 1,025 runs, 9 rows, 16 from an 8-aligned row
+EXPAND_SWEEP_ROWS = 16
 
 
 def _x32_trace():
@@ -207,14 +216,22 @@ def sweep_gather(win: jnp.ndarray, o: jnp.ndarray, fill=0) -> jnp.ndarray:
 
 
 def inverse_monotone(P: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
-    """o[·] = #{j : P.flat[j] <= q[·]} for non-decreasing (R,128) P.
-    Binary search; q any int32 block shape."""
-    span = P.shape[0] * LANES
-    width = 1
-    while width < span:
-        width <<= 1
-    lo = jnp.zeros(q.shape, jnp.int32)
-    step = width
+    """o[·] = #{j : P.flat[j] <= q[·]} for non-decreasing (R,128) P; q
+    any int32 block shape. Two levels: the rows wholly <= q are counted
+    off each row's LAST element (one lane gather and one compare a row,
+    where a binary search's probes at steps of 128 and up would each
+    sweep all R rows), then a binary search over the 127 lane positions
+    of the one row left: 7 `sweep_gather` probes whatever R."""
+    R = P.shape[0]
+    span = R * LANES
+    last = jnp.full(q.shape, LANES - 1, jnp.int32)
+    rows = jnp.zeros(q.shape, jnp.int32)
+    for r in range(R):
+        bc = jnp.broadcast_to(P[r:r + 1, :], q.shape)
+        rows = rows + (jnp.take_along_axis(bc, last, axis=1)
+                       <= q).astype(jnp.int32)
+    lo = rows * _L32
+    step = LANES // 2
     while step:
         mid = lo + step
         pv = sweep_gather(P, jnp.minimum(mid, span) - 1, fill=_I32MAX)
@@ -707,16 +724,30 @@ def join_expand_stream(counts: jnp.ndarray,
     the join at ~30 ns/row (profiled: ordx 228 ms + two output-sized row
     gathers ~1.1 s at 17M output rows on v5e).
 
+    DMA goes by the BLOCK (BR rows of outputs, chosen large to amortise
+    the round trips), vector work by the SLAB (one (8, 128) vreg of
+    outputs): a sweep costs (window rows) x (output vregs), so each slab
+    searches and sweeps only the rows its own 1,024 outputs can reach.
+
     The key structural facts the kernel exploits:
       * group A's output starts are STRICTLY increasing over emitting
-        runs, so the covering-run ordinal of output j is monotone — each
-        output block needs only a (BR+8)-row window of group A at the
-        carried run pointer, searched with `inverse_monotone`;
+        runs, so the covering-run ordinal of output j is monotone: a
+        block needs only a (BR+8)-row window of group A at the carried
+        run pointer (ONE DMA a stream a block), and a slab only the
+        8-aligned 16 rows of it at the pointer carried from the slab
+        before (1,024 outputs span at most 1,025 runs: 9 rows, 16 when
+        aligned), searched with `inverse_monotone` and swept for the
+        plan and the lanes;
       * within a run, b positions are CONSECUTIVE (bpos = j + delta), and
         run lo's are non-decreasing, so each block's b reads live in a
         short span walked with a windowed loop whose TOTAL work across
         blocks is bounded by one streaming pass over group B (plus one
-        window per duplicate-key reset).
+        window per duplicate-key reset); inside a DMA'd window a slab
+        sweeps the 16-row sub-windows its own min..max bpos spans (one,
+        mostly), so its cost goes with its span;
+      * a slab whose first output is past n_out reaches no row: it is
+        filled (-1 / 0) without a search, and a block of such slabs
+        without a DMA.
 
     counts: i32[4] from join_plan_stream. a_streams: (elist, delc,
     startsc, a_lane…); b_streams: (blist, b_lane…) — padded (rows, LANES)
@@ -734,6 +765,9 @@ def join_expand_stream(counts: jnp.ndarray,
     La, Lb = nA - 3, nB - 1
     nblocks = cap_e // (BR * LANES)
     W = BR + 8  # window rows; DMA row counts must be multiples of 8
+    SR = 8  # slab rows: one vreg of outputs, the tile sub-windows align to
+    SW = EXPAND_SWEEP_ROWS  # sub-window rows a slab sweeps
+    nslabs = BR // SR
     tot_a = a_streams[0].shape[0]
     tot_b = b_streams[0].shape[0]
     assert tot_a >= W and tot_b >= W, "plan streams carry BR+8 slack rows"
@@ -742,10 +776,15 @@ def join_expand_stream(counts: jnp.ndarray,
                   + [jax.ShapeDtypeStruct((nblocks * BR, LANES), jnp.uint32)]
                   * (La + Lb))
 
-    scratch = ([pltpu.SMEM((2,), jnp.int32)]
+    # carr: [0] run pointer, then (min, max) of bpos a slab
+    scratch = ([pltpu.SMEM((1 + 2 * nslabs,), jnp.int32),
+                pltpu.VMEM((BR, LANES), jnp.int32)]
                + [pltpu.VMEM((W, LANES), jnp.uint32)
                   for _ in range(nA + nB)]
                + [pltpu.SemaphoreType.DMA((nA + nB,))])
+
+    def as_i32(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
 
     def kernel(cnt_ref, *rest):
         a_refs = rest[:nA]
@@ -755,61 +794,100 @@ def join_expand_stream(counts: jnp.ndarray,
         o_alane = rest[nA + nB + 2:nA + nB + 2 + La]
         o_blane = rest[nA + nB + 2 + La:nA + nB + 2 + La + Lb]
         carr = rest[nA + nB + 2 + La + Lb]
-        bufsA = list(rest[nA + nB + 3 + La + Lb:
-                          nA + nB + 3 + La + Lb + nA])
-        bufsB = list(rest[nA + nB + 3 + La + Lb + nA:
-                          nA + nB + 3 + La + Lb + nA + nB])
-        sems = rest[nA + nB + 3 + La + Lb + nA + nB]
+        bpos_buf = rest[nA + nB + 3 + La + Lb]
+        bufsA = list(rest[nA + nB + 4 + La + Lb:
+                          nA + nB + 4 + La + Lb + nA])
+        bufsB = list(rest[nA + nB + 4 + La + Lb + nA:
+                          nA + nB + 4 + La + Lb + nA + nB])
+        sems = rest[nA + nB + 4 + La + Lb + nA + nB]
         i = pl.program_id(0)
 
         @pl.when(i == 0)
         def _():
-            carr[0] = 0  # run pointer: ordinal of prev block's last output
+            carr[0] = 0  # run pointer: ordinal of prev slab's last output
 
         n_out = cnt_ref[0]
         n_emit = cnt_ref[1]
+        j0 = i * (BR * LANES)
+        # slabs with an output under n_out; the rest reach no row
+        nlive = jnp.clip((n_out - j0 + (SR * LANES - 1)) // (SR * LANES),
+                         0, nslabs)
+
+        def slab(ref, s):
+            return ref.at[pl.ds(pl.multiple_of(s * SR, SR), SR), :]
+
+        def sub(ref, r):
+            return ref[pl.ds(pl.multiple_of(r, SR), SW), :]
+
+        # the fills: every b output (a slab's hits are merged in below,
+        # one sub-window at a time) and the a outputs of dead slabs
+        o_bidx[:] = jnp.full((BR, LANES), -1, jnp.int32)
+        for k in range(Lb):
+            o_blane[k][:] = jnp.zeros((BR, LANES), jnp.uint32)
+
+        @pl.when(nlive < nslabs)
+        def _():
+            o_aidx[:] = jnp.full((BR, LANES), -1, jnp.int32)
+            for k in range(La):
+                o_alane[k][:] = jnp.zeros((BR, LANES), jnp.uint32)
 
         # --- group A window at the carried run pointer ---
         arow0 = jnp.minimum(carr[0] // LANES, tot_a - W)
-        for k in range(nA):
-            pltpu.make_async_copy(a_refs[k].at[pl.ds(arow0, W)], bufsA[k],
-                                  sems.at[k]).start()
-        for k in range(nA):
-            pltpu.make_async_copy(a_refs[k].at[pl.ds(arow0, W)], bufsA[k],
-                                  sems.at[k]).wait()
-        base_e = arow0 * LANES
-        ge = base_e + flat_iota((W, LANES))
-        s_raw = jax.lax.bitcast_convert_type(bufsA[2][:], jnp.int32)
-        s_win = jnp.where(ge < n_emit, s_raw, _I32MAX)
-        j = i * (BR * LANES) + flat_iota((BR, LANES))
-        # ordinal = #{r global : start[r] <= j} − 1; every pre-window run
-        # starts at/before the carried pointer's covered output, so the
-        # window count + base_e is the global count
-        cnt_le = inverse_monotone(s_win, j)
-        ordinal = base_e + cnt_le - 1
-        woff = jnp.maximum(cnt_le - 1, 0)
-        d2 = sweep_gather(
-            jax.lax.bitcast_convert_type(bufsA[1][:], jnp.int32), woff)
-        aidx = sweep_gather(
-            jax.lax.bitcast_convert_type(bufsA[0][:], jnp.int32), woff)
-        alanes = [sweep_gather(bufsA[3 + k][:], woff) for k in range(La)]
-        valid = j < n_out
-        has = ((d2 & 1) == 1) & valid
-        bpos = j + (d2 >> 1)  # arithmetic shift: delta may be negative
-        carr[0] = jnp.maximum(ordinal[BR - 1, LANES - 1], 0)
+
+        @pl.when(nlive > 0)
+        def _():
+            for k in range(nA):
+                pltpu.make_async_copy(a_refs[k].at[pl.ds(arow0, W)],
+                                      bufsA[k], sems.at[k]).start()
+            for k in range(nA):
+                pltpu.make_async_copy(a_refs[k].at[pl.ds(arow0, W)],
+                                      bufsA[k], sems.at[k]).wait()
+
+        def a_slab(s, carry):
+            ptr, minb, maxb = carry
+            # the 16 window rows this slab's outputs can reach: every
+            # run before them starts at/before the pointer's covered
+            # output, every run after them past the slab's last
+            r0 = jnp.minimum((ptr // LANES - arow0) // SR * SR, W - SW)
+            base_e = (arow0 + r0) * LANES
+            ge = base_e + flat_iota((SW, LANES))
+            s_win = jnp.where(ge < n_emit, as_i32(sub(bufsA[2], r0)),
+                              _I32MAX)
+            j = j0 + s * (SR * LANES) + flat_iota((SR, LANES))
+            # ordinal = #{r global : start[r] <= j} − 1
+            cnt_le = inverse_monotone(s_win, j)
+            ordinal = base_e + cnt_le - 1
+            woff = jnp.maximum(cnt_le - 1, 0)
+            d2 = sweep_gather(as_i32(sub(bufsA[1], r0)), woff)
+            aidx = sweep_gather(as_i32(sub(bufsA[0], r0)), woff)
+            valid = j < n_out
+            slab(o_aidx, s)[:] = jnp.where(valid, aidx, -1)
+            for k in range(La):
+                slab(o_alane[k], s)[:] = jnp.where(
+                    valid, sweep_gather(sub(bufsA[3 + k], r0), woff),
+                    jnp.uint32(0))
+            has = ((d2 & 1) == 1) & valid
+            bpos = j + (d2 >> 1)  # arithmetic shift: delta may be negative
+            slab(bpos_buf, s)[:] = jnp.where(has, bpos, -1)
+            lo = jnp.min(jnp.where(has, bpos, _I32MAX))
+            hi = jnp.max(jnp.where(has, bpos, -1))
+            carr[1 + 2 * s] = lo
+            carr[2 + 2 * s] = hi
+            return (jnp.maximum(ordinal[SR - 1, LANES - 1], 0),
+                    jnp.minimum(minb, lo), jnp.maximum(maxb, hi))
+
+        carr[0], minb, maxb = jax.lax.fori_loop(
+            0, nlive, a_slab,
+            (carr[0], jnp.int32(_I32MAX), jnp.int32(-1)))
 
         # --- group B windowed walk over the block's bpos span ---
-        bposv = jnp.where(has, bpos, _I32MAX)
-        minb = jnp.min(bposv)
-        maxb = jnp.max(jnp.where(has, bpos, -1))
         brow0 = jnp.clip(minb // LANES, 0, tot_b - W)
         nw = jnp.where(maxb >= 0,
                        (jnp.minimum(maxb // LANES, tot_b - 1) - brow0) // W
                        + 1, 0)
-        outs0 = tuple(jnp.zeros((BR, LANES), jnp.uint32)
-                      for _ in range(nB))
+        b_outs = [o_bidx] + list(o_blane)
 
-        def body(w, outs):
+        def b_window(w, _):
             brow = jnp.minimum(brow0 + w * W, tot_b - W)
             for k in range(nB):
                 pltpu.make_async_copy(b_refs[k].at[pl.ds(brow, W)],
@@ -817,23 +895,33 @@ def join_expand_stream(counts: jnp.ndarray,
             for k in range(nB):
                 pltpu.make_async_copy(b_refs[k].at[pl.ds(brow, W)],
                                       bufsB[k], sems.at[nA + k]).wait()
-            off = bpos - brow * LANES
-            inwin = has & (off >= 0) & (off < W * LANES)
-            return tuple(
-                jnp.where(inwin, sweep_gather(bufsB[k][:],
-                                              jnp.where(inwin, off, -1)),
-                          outs[k])
-                for k in range(nB))
 
-        outs = jax.lax.fori_loop(0, nw, body, outs0)
+            def b_slab(s, _):
+                # the slab's own span, cut to this window: its rows
+                lo = jnp.maximum(carr[1 + 2 * s] // LANES - brow, 0)
+                hi = jnp.minimum(carr[2 + 2 * s] // LANES - brow, W - 1)
+                r0 = jnp.minimum(lo // SR * SR, W - SW)
+                nsub = jnp.where(hi >= lo, (hi - r0) // SW + 1, 0)
+                off = slab(bpos_buf, s)[:] - brow * LANES
 
-        o_aidx[:] = jnp.where(valid, aidx, -1)
-        o_bidx[:] = jnp.where(
-            has, jax.lax.bitcast_convert_type(outs[0], jnp.int32), -1)
-        for k in range(La):
-            o_alane[k][:] = jnp.where(valid, alanes[k], jnp.uint32(0))
-        for k in range(Lb):
-            o_blane[k][:] = jnp.where(has, outs[1 + k], jnp.uint32(0))
+                def b_sub(t, _):
+                    r = jnp.minimum(r0 + t * SW, W - SW)
+                    o = off - r * LANES
+                    hit = (o >= 0) & (o < SW * LANES)  # a miss's off is < 0
+                    o = jnp.where(hit, o, -1)
+                    for k in range(nB):
+                        out = slab(b_outs[k], s)
+                        g = sweep_gather(sub(bufsB[k], r), o)
+                        if k == 0:
+                            g = as_i32(g)
+                        out[:] = jnp.where(hit, g, out[:])
+                    return _
+
+                return jax.lax.fori_loop(0, nsub, b_sub, _)
+
+            return jax.lax.fori_loop(0, nlive, b_slab, _)
+
+        jax.lax.fori_loop(0, nw, b_window, jnp.int32(0))
 
     res = pl.pallas_call(
         kernel,

@@ -1063,7 +1063,7 @@ def distributed_join(left: Table, right: Table,
                                                lkey, rkey)
         table_mod.count_plan_sort(lkb, (False,) * len(lkb),
                                   len(ldat) + len(rdat), a_desc, b_desc,
-                                  hash_mode)
+                                  hash_mode, br)
         with _phase("distributed_join.plan", seq):
             rep_counts, counts_dev, a_streams, b_streams = \
                 _join_plan_stream_fn(ctx.mesh, jt, len(lkb), a_desc,

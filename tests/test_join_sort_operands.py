@@ -23,6 +23,7 @@ from cylon_tpu.ops import join as _join
 from cylon_tpu.parallel import dist_ops
 
 COUNTER = "cylon_join_sort_operands_total"
+SWEEP = "cylon_join_expand_sweep_rows_total"
 INNER = _join.JoinType.INNER
 
 
@@ -148,10 +149,12 @@ def test_distributed_join_hands_the_plan_no_mask_that_says_nothing(
     monkeypatch.setattr(dist_ops, "_dist_stream_mode",
                         lambda lkb, rkb, jt, world: (False, 8))
     monkeypatch.setattr(dist_ops, "_join_plan_stream_fn", factory)
-    before = telemetry.metrics_snapshot().get(COUNTER, 0)
+    before = telemetry.metrics_snapshot()
     with pytest.raises(_Recorded):
         tables[0].distributed_join(tables[1], "inner", on="k")
-    assert telemetry.metrics_snapshot()[COUNTER] - before == 3
+    after = telemetry.metrics_snapshot()
+    assert after[COUNTER] - before.get(COUNTER, 0) == 3
+    assert after[SWEEP] - before.get(SWEEP, 0) == 16
     assert seen["a_desc"] == seen["b_desc"] == ((0, "k"), (1, "d"))
     lkb, lkv, lemit, rkb, rkv, remit, ldat, lval, rdat, rval = seen["args"]
     assert lval == (None, None) and rval == (None, None)
@@ -162,6 +165,33 @@ def test_distributed_join_hands_the_plan_no_mask_that_says_nothing(
     cols = (tuple(map(shard, ldat)), lval, tuple(map(shard, rdat)), rval)
     assert _plan_sorts(keys, cols, seen["a_desc"], seen["b_desc"]) == \
         [(3, False, 2)]
+
+
+@pytest.mark.parametrize("block_rows,rows", [
+    (None, 0),   # the XLA plan: no expand kernel, it gathers
+    (8, 16),     # the block's window IS the slab's 16 rows
+    (16, 16),
+    (64, 16),    # 72 before PR 33: every output vreg swept the block's
+])
+def test_expand_sweep_rows_are_the_slabs_not_the_blocks(block_rows, rows):
+    assert _join.expand_sweep_rows(block_rows) == rows
+
+
+@pytest.mark.parametrize("stream,rows", [(True, 16), (False, 0)])
+def test_expand_sweep_rows_counted_once_a_join(local_ctx, monkeypatch,
+                                               stream, rows):
+    """16 a join on the stream path, 0 (but counted: the family is
+    there) on the XLA plan, which every CPU run takes."""
+    rng = np.random.default_rng(3)
+    left, right = (ct.Table.from_pydict(local_ctx, {
+        "k": rng.integers(0, 40, n).astype(np.int32),
+        name: rng.random(n).astype(np.float32)})
+        for name, n in (("v", 300), ("w", 200)))
+    monkeypatch.setattr(_join, "STREAM_PLAN", stream)
+    before = telemetry.metrics_snapshot().get(SWEEP, 0)
+    got = left.join(right, "inner", on=["k"])
+    assert telemetry.metrics_snapshot()[SWEEP] - before == rows
+    assert got.row_count > 300
 
 
 # ---------------------------------------------------------------------------
