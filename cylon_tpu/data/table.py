@@ -1452,6 +1452,16 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
                 "a total order the content-hash identity does not carry; "
                 "dictionary-encode the column for string MIN/MAX)")
     key_columns = [table._columns[i] for i in idx_cols]
+    # None (no row mask): every row is live, and no dead flag rides
+    emit = table.row_mask
+    values = tuple(table._columns[i].data for i in val_cols)
+    # None for all-valid columns: the mask never rides the sort
+    valids = tuple(table._columns[i].validity for i in val_cols)
+    vdtypes = [v.dtype for v in values]
+    dense = _groupby_dense(table, key_columns, val_cols, values, valids,
+                           ops, aggregate_ops)
+    if dense is not None:
+        return dense
     keys = []
     for c in key_columns:
         if c.is_varbytes:
@@ -1463,12 +1473,6 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
             keys.extend(_order.sort_keys([c]))
         if c.validity is not None:
             keys.append(c.valid_mask().astype(jnp.uint8))
-    # None (no row mask): every row is live, and no dead flag rides
-    emit = table.row_mask
-    values = tuple(table._columns[i].data for i in val_cols)
-    # None for all-valid columns: the mask never rides the sort
-    valids = tuple(table._columns[i].validity for i in val_cols)
-    vdtypes = [v.dtype for v in values]
     # how to read each output key column back off its sorted lanes; a
     # varbytes key (hash lanes) has no way back
     key_spec = None if any(c.is_varbytes for c in key_columns) else tuple(
@@ -1526,6 +1530,77 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
                                      _groupby.AggregationOp.MAX)
             and src.is_string else None,
             src.name))
+    return Table(out_cols, table._ctx, group_valid)
+
+
+@_telemetry.counted_cache
+def _groupby_key_range_fn():
+    """The probe of the groupby's path choice: (lo, hi) of the key over
+    its live rows, one small program (`jit_groupby_key_range`)."""
+    def kernel(key, emit, key_valid):
+        return _groupby.key_range_probe(key, emit, key_valid)
+
+    return jax.jit(kernel)
+
+
+@_telemetry.counted_cache
+def _groupby_dense_fn(slots: int, ops: tuple, col_ids: tuple,
+                      interpret: bool):
+    """The no-sort groupby over ``slots`` slots (`jit_groupby_dense`:
+    ops/groupby.dense_aggregate, the Pallas pass inside)."""
+    def kernel(key, key_valid, emit, lohi, values, valids):
+        return _groupby.dense_aggregate(key, key_valid, emit, lohi, values,
+                                        valids, slots, ops, col_ids,
+                                        interpret=interpret)
+
+    return jax.jit(kernel)
+
+
+def _groupby_dense(table: Table, key_columns, val_cols, values, valids, ops,
+                   aggregate_ops) -> Optional[Table]:
+    """The groupby over few groups, with no sort, or None where
+    `ops/groupby.group_path` says "sort". The static conditions cost
+    nothing; only a table that meets them (and lives on ONE device: the
+    kernel is no sharded program) pays the probe, a fused min / max over
+    the key and the fetch of the pair (`sync.groupby.keyrange`). With
+    the observed range inside DENSE_MAX_SLOTS the dense program runs and
+    nothing more is fetched: `_pow2(range)` slots, slot s the key
+    ``lo + s``, ``row_mask`` the slots a live row reached."""
+    kdtypes = [None if c.is_varbytes else np.dtype(c.data.dtype)
+               for c in key_columns]
+    knull = [c.validity is not None for c in key_columns]
+    vdtypes = [None if table._columns[i].is_varbytes else np.dtype(v.dtype)
+               for i, v in zip(val_cols, values)]
+    n = table.capacity
+    if _groupby.group_path(kdtypes, knull, vdtypes, ops, n) != "dense" \
+            or len(key_columns[0].data.sharding.device_set) != 1:
+        return None
+    kc = key_columns[0]
+    emit = table.row_mask
+    lohi = _groupby_key_range_fn()(kc.data, emit, kc.validity)
+    lo, hi = (int(x) for x in _telemetry.host_fetch("groupby.keyrange",
+                                                    lohi))
+    # no live row with a key: one empty slot
+    key_range = hi - lo + 1 if lo <= hi else 1
+    if _groupby.group_path(kdtypes, knull, vdtypes, ops, n,
+                           key_range) != "dense":
+        return None
+    slots = _pow2(key_range + knull[0])
+    # counted where the host evaluates the decision (the sort path
+    # counts its reduce step, `stream` or `segment`, below)
+    _telemetry.counter("cylon_groupby_reduce_path_total",
+                       {"path": "dense"}).inc()
+    _telemetry.counter("cylon_groupby_dense_slots_total").inc(slots)
+    key_data, key_validity, group_valid, results = _groupby_dense_fn(
+        slots, tuple(ops), tuple(val_cols),
+        jax.default_backend() != "tpu")(
+            kc.data, kc.validity, emit, lohi, values, valids)
+    out_cols = [Column(key_data, kc.dtype, key_validity, kc.dictionary,
+                       kc.name)]
+    for (arr, avalid), vi, op in zip(results, val_cols, aggregate_ops):
+        src = table._columns[vi]
+        out_cols.append(Column(arr, _agg_dtype(src, op), avalid, None,
+                               src.name))
     return Table(out_cols, table._ctx, group_valid)
 
 

@@ -201,6 +201,56 @@ def sort_carries_index(key_lanes, key_spec, value_dtypes, ops, n: int,
     return reduce_path(value_dtypes, ops, n, interpret) != "stream"
 
 
+# the most slots the dense table may have: past it the no-sort pass
+# (whose time goes with slots x rows) is handed back to the sort path.
+# From ONE sweep on a v5e at 1e8 rows (PERF.md section 6, PR 34): 0.164
+# ms a slot, level with the sort path's ~630 ms near 3,800 slots; half
+# of that, and the power of two under it.
+DENSE_MAX_SLOTS = 1024
+
+_DENSE_OPS = (AggregationOp.SUM, AggregationOp.COUNT, AggregationOp.MEAN)
+
+
+def group_path(key_dtypes, key_nullable, value_dtypes, ops, n: int,
+               key_range=None) -> str:
+    """HOW a local groupby groups its rows, decided before any sort from
+    what the code can observe (no knob): ``"dense"`` — no sort, every
+    row goes straight to slot ``key - lo`` of a small table
+    (`dense_aggregate`) — or ``"sort"``, the fused sort and the reduce
+    step over its runs.
+
+    Static conditions first: ONE key column of an integer kind at most
+    32 bits wide (bool and dictionary codes are; ``key_dtypes`` holds
+    None for a varbytes key), every op a SUM, COUNT or MEAN, every
+    summed column and every accumulator an int32 or a float32 (the
+    kernel's lanes: under x64 a COUNT or a MEAN accumulates 8 bytes and
+    sorts), 1 <= n < 2^30. Only when they hold is the key's range worth
+    observing: called with ``key_range`` None the answer is ``"dense"``
+    for "probe it". With the OBSERVED ``key_range`` (hi - lo + 1 over
+    the live rows; a nullable key takes one slot more) it is ``"dense"``
+    while the slots fit DENSE_MAX_SLOTS."""
+    if len(key_dtypes) != 1 or key_dtypes[0] is None:
+        return "sort"
+    kd = np.dtype(key_dtypes[0])
+    if kd.kind not in "iub" or kd.itemsize > 4:
+        return "sort"
+    if not 1 <= n < (1 << 30):
+        return "sort"
+    for dtype, op in zip(value_dtypes, ops):
+        if op not in _DENSE_OPS:
+            return "sort"
+        if op != AggregationOp.COUNT and (dtype is None
+                                          or dtype not in _STREAM_DTYPES):
+            return "sort"
+        if any(a not in _STREAM_DTYPES
+               for a in _accumulator_dtypes(dtype, op)):
+            return "sort"
+    if key_range is not None and \
+            key_range + bool(key_nullable[0]) > DENSE_MAX_SLOTS:
+        return "sort"
+    return "dense"
+
+
 def _reduce_segments(new_grp, emit_s, iota_s, subs, num_segments: int):
     """The portable reduce step: dense ids by a cumsum over the run
     starts, then one ``indices_are_sorted`` XLA scatter a stream.
@@ -267,6 +317,14 @@ def _key_columns(firsts, lane_dtypes, key_spec, group_valid):
         (_order.from_ordered_bits_raw(next(it), dtype, is_string),
          (next(it) != 0) & group_valid if nullable else None)
         for dtype, is_string, nullable in key_spec)
+
+
+def _mean(total, count, group_valid):
+    """MEAN's finalisation, shared by the sort and the dense path: the
+    group's sum over its non-null count, both in the op's accumulator
+    dtypes; (mean, valid)."""
+    c = count.astype(jnp.float64)
+    return total / jnp.maximum(c, 1), group_valid & (c > 0)
 
 
 def sorted_segment_aggregate(new_grp, emit_s, first_s,
@@ -369,9 +427,7 @@ def sorted_segment_aggregate(new_grp, emit_s, first_s,
         if op == AggregationOp.COUNT:
             results.append((red[b], group_valid))
         elif op == AggregationOp.MEAN:
-            c = red[b].astype(jnp.float64)
-            results.append((red[a] / jnp.maximum(c, 1),
-                            group_valid & (c > 0)))
+            results.append(_mean(red[a], red[b], group_valid))
         else:
             results.append((red[a], group_valid if b is None
                             else group_valid & (red[b] > 0)))
@@ -379,6 +435,122 @@ def sorted_segment_aggregate(new_grp, emit_s, first_s,
         return firsts[0], group_valid, results
     return (_key_columns(firsts, [k.dtype for k in first_s], key_spec,
                          group_valid), group_valid, results)
+
+
+def key_range_probe(key, emit, key_valid):
+    """[lo, hi] of ``key`` over the rows that are live and whose key is
+    not null (either mask may be None), in the key's own order (a bool
+    key as 0 / 1); lo > hi when there is no such row. One fused pass
+    over the key column: what `group_path` needs to see. ONE array of
+    two: the host fetches it in one transfer, and `dense_aggregate`
+    takes it as it is, on the device (two scalars sent back up cost a
+    dense query 0.4 ms: PERF.md section 6, PR 34)."""
+    k = key.astype(jnp.int32) if key.dtype == jnp.bool_ else key
+    live = emit
+    if key_valid is not None:
+        live = key_valid if live is None else live & key_valid
+    if live is None:
+        return jnp.stack([k.min(), k.max()])
+    top, bottom = _max_of(k.dtype), _min_of(k.dtype)
+    return jnp.stack([
+        jnp.where(live, k, jnp.asarray(top, k.dtype)).min(),
+        jnp.where(live, k, jnp.asarray(bottom, k.dtype)).max()])
+
+
+def _key_as_int32(key):
+    """A key of an integer kind at most 32 bits wide as the int32 the
+    dense kernel subtracts ``lo`` from (wrapping: a uint32 key keeps
+    its bits, and differences of in-range keys are exact)."""
+    if key.dtype == jnp.uint32:
+        return jax.lax.bitcast_convert_type(key, jnp.int32)
+    return key.astype(jnp.int32)
+
+
+def dense_aggregate(key, key_valid, emit, lohi,
+                    values: Tuple[jnp.ndarray, ...],
+                    valids: Tuple[jnp.ndarray, ...], slots: int,
+                    ops: Tuple[AggregationOp, ...],
+                    col_ids: Tuple[int, ...], interpret: bool = False):
+    """The groupby WITHOUT a sort (`group_path` says when): slot s of a
+    table of ``slots`` holds the group whose key is ``lo + s``, for s in
+    [0, key_range); a nullable key's null group sits in slot key_range.
+    ``lohi`` is `key_range_probe`'s array as it left the device (lo > hi:
+    no live row, one empty slot), so one program serves every range of
+    a slot count. ONE pass of tpu_kernels.groupby_dense_reduce over the
+    key and the value columns; a dead row (``emit``) reaches no slot, a
+    null value no accumulator of its column (Arrow's count semantics,
+    as `sorted_segment_aggregate`'s). Distinct reductions run once
+    (``col_ids`` name the source columns, as there).
+
+    Integer sums and counts are exact; a float sum (and MEAN's, which
+    sums an int32 column as float32 too) is compensated: within about
+    2^-24 of the exact sum of its float32 inputs, whatever the rows.
+
+    Returns (key_data, key_validity or None, group_valid, results):
+    key_data (slots,) in the key's dtype, group_valid marks the slots
+    that hold a live row, results one (array, valid) an op."""
+    from . import tpu_kernels as tk
+
+    empty = lohi[0] > lohi[1]
+    lo32 = _key_as_int32(lohi)
+    lo = jnp.where(empty, jnp.int32(0), lo32[0])
+    key_range = jnp.where(empty, jnp.int32(1),
+                          lo32[1] - lo32[0] + jnp.int32(1))
+    k32 = _key_as_int32(key)
+    live_slots = key_range
+    if key_valid is not None:
+        k32 = jnp.where(key_valid, k32, lo + key_range)
+        live_slots = key_range + jnp.int32(1)
+    if emit is not None:
+        k32 = jnp.where(emit, k32, lo - jnp.int32(1))
+
+    streams, kinds, where_ = [], [], {}
+
+    def stream(name, kind, make):
+        if name not in where_:
+            where_[name] = len(streams)
+            streams.append(make())
+            kinds.append(kind)
+        return where_[name]
+
+    plan = []
+    for arr, vmask, op, cid in zip(values, valids, ops, col_ids):
+        # rows a slot for an all-valid column: the kernel's own count
+        count = None if vmask is None else stream(
+            ("count", cid), "int", lambda: vmask.astype(jnp.int32))
+        total = None
+        if op != AggregationOp.COUNT:
+            is_sum_int = op == AggregationOp.SUM and arr.dtype == jnp.int32
+            total = stream(
+                ("int" if is_sum_int else "float", cid),
+                "int" if is_sum_int else "float",
+                lambda: arr if vmask is None
+                else jnp.where(vmask, arr, jnp.zeros((), arr.dtype)))
+        plan.append((op, total, count))
+    rows, sums = tk.groupby_dense_reduce(k32, lo, live_slots, streams, kinds,
+                                         slots, interpret=interpret)
+    group_valid = rows > 0
+    canon = jax.dtypes.canonicalize_dtype
+    results = []
+    for op, total, count in plan:
+        c = rows if count is None else sums[count]
+        if op == AggregationOp.COUNT:
+            results.append((c.astype(canon(jnp.int64)), group_valid))
+        elif op == AggregationOp.MEAN:
+            results.append(_mean(sums[total].astype(canon(jnp.float64)),
+                                 c, group_valid))
+        else:
+            results.append((sums[total], group_valid & (c > 0)))
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    # the null group's slot holds a key of the column's own (a
+    # dictionary code has to stay inside its dictionary)
+    kd = lo + jnp.where(slot == key_range, jnp.int32(0), slot)
+    key_data = kd != 0 if key.dtype == jnp.bool_ else (
+        jax.lax.bitcast_convert_type(kd, jnp.uint32)
+        if key.dtype == jnp.uint32 else kd.astype(key.dtype))
+    key_validity = None if key_valid is None else \
+        group_valid & (slot != key_range)
+    return key_data, key_validity, group_valid, results
 
 
 presort_groups_jit = partial(jax.jit, static_argnames=("index",))(

@@ -1462,3 +1462,226 @@ def groupby_run_reduce(new_grp: jnp.ndarray, emit: jnp.ndarray,
                   o.reshape(-1)[:num_segments].view(s.dtype))
         for k, (o, s) in enumerate(zip(res[:K], streams)))
     return outs, count + open_end.astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# groupby_dense_reduce — a groupby over few groups, with no sort
+# ---------------------------------------------------------------------------
+# When the key's observed range is small (ops/groupby.group_path) a row's
+# slot is ``key - lo`` and nothing has to be sorted: ONE pass over the key
+# and the value columns keeps, per slot, a count and one accumulator a
+# stream in VMEM and writes the table once at the end. A row reaches its
+# slot by compare-and-select on the VPU: the block's rows are swept once
+# a CHUNK of 8 slots (whose accumulators, a vreg a slot a stream, stay in
+# vector registers), so the time goes with slots x rows / 1,024, not with
+# bytes.
+#
+# The table holds one (1, 128) row a slot a stream: 128 independent
+# partial sums by lane. At the end of a block each slot's accumulator
+# vreg is folded over its 8 sublanes and the chunk's 8 rows join the
+# table as ONE aligned (8, 128) tile. An int32 stream adds exactly
+# (wrapping, as XLA's own int32 sum). A float stream's block partial is
+# formed by plain float32 additions (at most block_rows / 8 + 3 a lane)
+# and joins a float32 (hi, lo) pair by a two-sum, so that across blocks
+# nothing is lost: the table holds each lane's sum to about 2^-48
+# relative, and the wrapper folds the 128 pairs pairwise with two-sums
+# and rounds ONCE. (Adding the blocks' partials one after another into a
+# plain float32 slot is a random walk of half-ulps: ~120 units of 2^-24
+# at 1e5 blocks.)
+# ---------------------------------------------------------------------------
+
+DENSE_CHUNK = 8   # slots swept together: the rows of one table tile
+# vregs of rows a step of a chunk's sweep: an inner loop unrolled whole
+# (Mosaic takes a fori_loop's `unroll` only whole or not at all). In
+# groupby-q4's shape the pass takes 35.9 ms at 1, 20.5 at 8, 19.5 at 16,
+# 18.8 at 32, 18.2 with the block's 128 (PERF.md section 6, PR 34)
+DENSE_UNROLL = 16
+
+
+def _two_sum(a, b):
+    """(s, e): s = fl(a + b) and a + b = s + e exactly (Knuth)."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def _fold_pairs(hi, lo):
+    """(..., W) float32 (hi, lo) pairs -> (...,) float32: the W pairs
+    added pairwise, every addition of two ``hi`` a two-sum whose error
+    joins ``lo``; one rounding at the end."""
+    w = hi.shape[-1]
+    assert w & (w - 1) == 0
+    while w > 1:
+        w //= 2
+        hi, e = _two_sum(hi[..., :w], hi[..., w:2 * w])
+        lo = lo[..., :w] + lo[..., w:2 * w] + e
+    return hi[..., 0] + lo[..., 0]
+
+
+def groupby_dense_reduce(keys: jnp.ndarray, lo, live_slots,
+                         streams: Sequence[jnp.ndarray],
+                         kinds: Sequence[str], slots: int,
+                         block_rows: int = 1024, interpret: bool = False
+                         ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
+    """Per-slot count and per-slot sums of unsorted rows: row r belongs
+    to slot ``keys[r] - lo`` (int32, wrapping) and to none when that lies
+    outside [0, live_slots) — how a caller keeps a dead row out is to
+    hand it the key ``lo - 1``.
+
+    keys: (n,) int32. lo, live_slots: int32 scalars (traced: neither is
+    in the program's shape; live_slots <= slots, and only that many
+    slots are swept). streams: (n,) int32 /
+    float32; kinds[k] is ``"int"`` (an int32 stream, its exact wrapping
+    int32 sum) or ``"float"`` (a float32 sum, compensated as described
+    above; an int32 stream is converted to float32 element by element
+    first, once a block). What a row outside every slot holds is never
+    read into a result.
+
+    Returns (count, sums): count (slots,) int32 rows a slot, sums[k]
+    (slots,) int32 or float32; slots at and past live_slots read 0.
+    """
+    n = keys.shape[0]
+    K = len(streams)
+    U = DENSE_CHUNK
+    assert keys.dtype == jnp.int32 and len(kinds) == K
+    assert 1 <= n < (1 << 30) and slots >= 1
+    for s, kind in zip(streams, kinds):
+        assert s.shape == (n,) and kind in ("int", "float")
+        assert s.dtype == jnp.int32 or (kind == "float"
+                                        and s.dtype == jnp.float32), \
+            f"groupby_dense_reduce: a {kind} stream of {s.dtype}"
+    step_rows = 8 * DENSE_UNROLL
+    BR = min(block_rows, -(-rows_for(n) // step_rows) * step_rows)
+    assert BR % step_rows == 0
+    rows = max(rows_for(n), BR)      # a small input: one whole block
+    span = BR * LANES
+    blocks = -(-rows // BR)          # the last block may be ragged
+    SP = -(-slots // U) * U          # table rows: whole chunks
+    k2 = pad_rows(keys, rows)
+    s2 = [pad_rows(s, rows) for s in streams]
+    # streams converted in the kernel (an int32 column summed as float)
+    conv = [k for k in range(K)
+            if kinds[k] == "float" and streams[k].dtype == jnp.int32]
+    # table arrays: the count, then one an "int" stream and (hi, lo) a
+    # "float" one
+    tab_dtypes = [jnp.int32]
+    tab_of = []
+    for kind in kinds:
+        tab_of.append(len(tab_dtypes))
+        tab_dtypes += [jnp.int32] if kind == "int" else [jnp.float32] * 2
+    T = len(tab_dtypes)
+    acc_dtypes = [jnp.int32] + [jnp.int32 if kind == "int" else jnp.float32
+                                for kind in kinds]
+
+    def kernel(par_ref, k_ref, *rest):
+        srefs = list(rest[:K])
+        tabs = rest[K:K + T]
+        rel_ref = rest[K + T]
+        conv_refs = dict(zip(conv, rest[K + T + 1:]))
+        i = pl.program_id(0)
+
+        @pl.when(i == 0)
+        def _():
+            for t, d in zip(tabs, tab_dtypes):
+                t[...] = jnp.zeros((SP, LANES), d)
+
+        # the block's slot ids, once: key - lo, -1 past the live slots
+        # (the last chunk is swept whole) and past the last row
+        rel = k_ref[:] - par_ref[0]
+        rel = jnp.where(rel < par_ref[1], rel, np.int32(-1))
+        if blocks * span != n:
+            inside = (i * np.int32(span) + flat_iota((BR, LANES))
+                      < np.int32(n))
+            rel = jnp.where(inside, rel, np.int32(-1))
+        rel_ref[:] = rel
+        for k in conv:
+            conv_refs[k][:] = srefs[k][:].astype(jnp.float32)
+        vrefs = [conv_refs.get(k, srefs[k]) for k in range(K)]
+        sub = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0)
+
+        def sweep(s0):
+            """One step of a chunk's pass over the block: DENSE_UNROLL
+            vregs of rows against the 8 slots from ``s0``."""
+            def vreg(r0, accs):
+                r0 = pl.multiple_of(r0, 8)
+                d = rel_ref[pl.ds(r0, 8), :] - s0
+                vals = [v[pl.ds(r0, 8), :] for v in vrefs]
+                out = []
+                for u in range(U):
+                    m = d == np.int32(u)
+                    a = accs[u]
+                    out.append(tuple(
+                        [a[0] + jnp.where(m, np.int32(1), np.int32(0))]
+                        + [ak + jnp.where(m, v, jnp.zeros_like(v))
+                           for ak, v in zip(a[1:], vals)]))
+                return tuple(out)
+
+            def step(g, accs):
+                return jax.lax.fori_loop(
+                    np.int32(0), np.int32(DENSE_UNROLL),
+                    lambda h, a: vreg(g * np.int32(step_rows)
+                                      + h * np.int32(8), a),
+                    accs, unroll=True)
+            return step
+
+        def tile(accs, j):
+            """Row u: slot u's accumulator j folded over its sublanes."""
+            out = jnp.zeros((8, LANES), acc_dtypes[j])
+            for u in range(U):
+                r = jnp.sum(accs[u][j], axis=0, keepdims=True)
+                out = jnp.where(sub == np.int32(u),
+                                jnp.broadcast_to(r, (8, LANES)), out)
+            return out
+
+        def chunk(c, carry):
+            s0 = c * np.int32(U)
+            zero = tuple(tuple(jnp.zeros((8, LANES), d) for d in acc_dtypes)
+                         for _ in range(U))
+            accs = jax.lax.fori_loop(np.int32(0), np.int32(BR // step_rows),
+                                     sweep(s0), zero)
+            at = pl.ds(pl.multiple_of(s0, 8), 8)
+            tabs[0][at, :] = tabs[0][at, :] + tile(accs, 0)
+            for k in range(K):
+                t, p = tab_of[k], tile(accs, 1 + k)
+                if kinds[k] == "int":
+                    tabs[t][at, :] = tabs[t][at, :] + p
+                else:
+                    hi, e = _two_sum(tabs[t][at, :], p)
+                    tabs[t][at, :] = hi
+                    tabs[t + 1][at, :] = tabs[t + 1][at, :] + e
+            return carry
+
+        chunks = (par_ref[1] + np.int32(U - 1)) // np.int32(U)
+        jax.lax.fori_loop(np.int32(0), chunks, chunk, np.int32(0))
+
+    stream_spec = pl.BlockSpec((BR, LANES), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM)
+    tab_spec = pl.BlockSpec((SP, LANES), lambda i: (0, 0),
+                            memory_space=pltpu.VMEM)
+    # the table and every input block are held twice (the pipeline's
+    # buffers), the scratch once
+    vmem = (2 * T * SP * LANES * 4
+            + (2 * (1 + K) + 1 + len(conv)) * span * 4 + (16 << 20))
+    res = pl.pallas_call(
+        kernel,
+        name="groupby_dense_reduce",
+        out_shape=[jax.ShapeDtypeStruct((SP, LANES), d) for d in tab_dtypes],
+        grid=(blocks,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [stream_spec] * (1 + K),
+        out_specs=[tab_spec] * T,
+        scratch_shapes=[pltpu.VMEM((BR, LANES), jnp.int32)]
+        + [pltpu.VMEM((BR, LANES), jnp.float32) for _ in conv],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        interpret=interpret,
+    )
+    with _x32_trace():
+        par = jnp.stack([jnp.asarray(lo, jnp.int32),
+                         jnp.asarray(live_slots, jnp.int32)])
+        tabs = [t[:slots] for t in res(par, k2, *s2)]
+        count = tabs[0].sum(axis=1, dtype=jnp.int32)
+        sums = tuple(
+            tabs[t].sum(axis=1, dtype=jnp.int32) if kind == "int"
+            else _fold_pairs(tabs[t], tabs[t + 1])
+            for t, kind in zip(tab_of, kinds))
+    return count, sums

@@ -29,6 +29,11 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
 CELLS = [w["name"] for w in BENCH["workloads"]]
 
 SCALE = 0.0005        # 8,000 rows a side a chip; 50,000 for the groupby
+# groupby-q5's key has N/K = rows/100 values: at 50,000 rows its range
+# (500) would fit the dense table (ops/groupby.DENSE_MAX_SLOTS, 1,024),
+# which the cell at 1e8 rows never takes; 250,000 rows keep it on the
+# sort path its metrics read
+SCALES = {"groupby-q5": 0.0025}
 LIMIT_S = 120         # a run takes 4-8 s
 
 _CHILD = """
@@ -56,7 +61,7 @@ def rehearse(cell, control):
     env.pop("CYLON_TPU_VERIFY_PLANS", None)   # the chip's run has none
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD, BENCH["command"][-1], cell,
-         str(SCALE), str(control)],
+         str(SCALES.get(cell, SCALE)), str(control)],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=LIMIT_S)
     said = [ln for ln in proc.stdout.splitlines()
             if ln.startswith("REHEARSED ")]

@@ -123,6 +123,10 @@ def test_groupby_on_the_stream_path_reads_its_keys_off_the_sort(
     in the same order, equal keys, validity and integer sums; and the
     sort's operands are counted as the host sees them."""
     keys, lanes = _key_columns(kind)
+    # the SORT path is what this test is about: a bool or a dictionary
+    # key's few values would go to the dense table (tests/
+    # test_groupby_dense.py) before any sort
+    monkeypatch.setattr(G, "group_path", lambda *a, **k: "sort")
     rng = np.random.default_rng(7)
     cols = keys + [_col(rng.integers(-50, 50, N_KEYED).astype(np.int32), "a"),
                    _col(rng.normal(size=N_KEYED).astype(np.float32), "b")]

@@ -260,7 +260,10 @@ def _paths():
 
 
 def test_every_reduce_step_is_counted_by_its_path(local_ctx, dist_ctx):
-    data = {"k": np.arange(64, dtype=np.int32) % 5,
+    # keys 5,000 apart: a range past DENSE_MAX_SLOTS, so the rows are
+    # sorted (five keys side by side fill a dense table, which counts
+    # `dense` and no sort operand: tests/test_groupby_dense.py)
+    data = {"k": np.arange(64, dtype=np.int32) % 5 * 5000,
             "v": np.arange(64, dtype=np.int32)}
     before = _paths()
     out = ct.Table.from_pydict(local_ctx, data).groupby(0, [1], ["sum"])

@@ -251,32 +251,42 @@ def _planned(kind, left, right):
 
     if kind == "join":
         return plan.scan(left).join(plan.scan(right), on="k")
+    if kind == "groupby-dense":     # 16 groups: the key's range is small
+        return plan.scan(right).filter(
+            plan.col("k") < 16).groupby("k", ["w"], ["sum"])
     return plan.scan(right).groupby("k", ["w"], ["sum"])
 
 
-@pytest.mark.parametrize("kind,site,operator", [
-    ("join", "sync.join.count", "join.plan"),
-    ("groupby", "sync.groupby.groups", "plan.groupby"),
+@pytest.mark.parametrize("kind,sites,operator", [
+    ("join", ["sync.join.count"], "join.plan"),
+    # the key's range (2,048 values: past DENSE_MAX_SLOTS) is probed,
+    # then the rows are sorted and the group count fetched
+    ("groupby", ["sync.groupby.keyrange", "sync.groupby.groups"],
+     "plan.groupby"),
+    # few groups: the probe's fetch is the only one
+    ("groupby-dense", ["sync.groupby.keyrange"], "plan.groupby"),
 ])
 def test_planned_local_operator_is_one_counted_spanned_sync(
-        local_ctx, kind, site, operator):
+        local_ctx, kind, sites, operator):
     """A planned local join / groupby on fresh inputs: the counter grows
-    by exactly 1 and exactly one ``sync.*`` span opens, as a child of
-    the operator's span (what the benchmark's ``host_syncs_per_query``
-    and ``sync_idle_ms_per_query`` read in a trace)."""
+    by exactly 1 a fetch and exactly that many ``sync.*`` spans open, as
+    children of the operator's span (what the benchmark's
+    ``host_syncs_per_query`` and ``sync_idle_ms_per_query`` read in a
+    trace): one for the join and for a groupby over few groups, two for
+    a groupby that sorts."""
     from cylon_tpu import telemetry
 
     pipe = _planned(kind, *_fresh_pair(local_ctx, 31))
     s0 = _syncs()
     with telemetry.collect_phases() as cp:
         out = pipe.execute()
-    assert _syncs() == s0 + 1
+    assert _syncs() == s0 + len(sites)
     sync_spans = [sp for sp in cp.spans if sp.name.startswith("sync.")]
-    assert [sp.name for sp in sync_spans] == [site]
-    parent = [sp for sp in cp.spans
-              if sp.span_id == sync_spans[0].parent_id]
-    assert [sp.name for sp in parent] == [operator]
-    assert sync_spans[0].elapsed_ms is not None
+    assert [sp.name for sp in sync_spans] == sites
+    for sync in sync_spans:
+        parent = [sp for sp in cp.spans if sp.span_id == sync.parent_id]
+        assert [sp.name for sp in parent] == [operator]
+        assert sync.elapsed_ms is not None
     assert out.row_count > 0
 
 

@@ -113,6 +113,11 @@ KERNELS = {
         lambda g, s: tk.groupby_run_reduce(g, g, [s], ["add"], 64,
                                            interpret=True))(
             jnp.ones(4096, bool), jnp.arange(4096, dtype=jnp.int32)),
+    "groupby_dense_reduce": lambda: jax.make_jaxpr(
+        lambda k, s: tk.groupby_dense_reduce(k, np.int32(0), np.int32(8),
+                                             [s], ["int"], 8,
+                                             interpret=True))(
+            jnp.zeros(4096, jnp.int32), jnp.arange(4096, dtype=jnp.int32)),
     "stream_compact": lambda: jax.make_jaxpr(
         lambda m, s: tk.stream_compact(m, [s], interpret=True))(
             jnp.ones(4096, bool), _u32(4096)),
@@ -152,6 +157,28 @@ def test_groupby_reduce_keeps_its_program_and_names_its_kernel():
     closed = jax.make_jaxpr(lambda *a: _groupby.sorted_segment_aggregate(
         *a, interpret=True, **kw))(*args)
     assert _pallas_names(closed.jaxpr, []) == ["groupby_run_reduce"]
+
+
+def test_dense_groupby_names_its_probe_its_program_and_its_kernel():
+    """What a traced ``groupby-q4`` reads: the probe and the dense program
+    on ``XLA Modules`` (both match the benchmark's pattern ``groupby``),
+    the kernel ``groupby_dense_reduce`` on ``XLA Ops``."""
+    from cylon_tpu.data import table as table_mod
+    from cylon_tpu.ops import groupby as _groupby
+
+    n = 4096
+    probe = table_mod._groupby_key_range_fn()
+    head = probe.lower(_sds(jnp.int32, n), None, None).as_text()
+    assert head.startswith("module @jit_groupby_key_range "), head[:80]
+    MEAN = _groupby.AggregationOp.MEAN
+    with jax.enable_x64(False):
+        dense = table_mod._groupby_dense_fn(128, (MEAN, MEAN), (1, 2), True)
+        args = (_sds(jnp.int32, n), None, None, _sds(jnp.int32, 2),
+                (_sds(jnp.int32, n), _sds(jnp.float32, n)), (None, None))
+        head = dense.lower(*args).as_text()
+        closed = jax.make_jaxpr(dense)(*args)
+    assert head.startswith("module @jit_groupby_dense "), head[:80]
+    assert _pallas_names(closed.jaxpr, []) == ["groupby_dense_reduce"]
 
 
 def test_join_kernels_carry_their_names():

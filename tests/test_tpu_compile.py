@@ -116,6 +116,39 @@ def test_groupby_stream_reduce_compiles(chip, monkeypatch, n, num_segments,
     assert "scatter" not in text and "gather" not in text
 
 
+@pytest.mark.parametrize("n,slots", [
+    (100_000_000, 128), (100_000_000, _groupby.DENSE_MAX_SLOTS), (1000, 8)],
+    ids=["q4", "max_slots", "small"])
+def test_groupby_dense_compiles(chip, n, slots):
+    """The no-sort groupby as groupby-q4 runs it: 1e8 rows, an int32 key,
+    three means (two int32 columns converted in the kernel, one float32),
+    no row mask, no nulls; the same at the most slots `group_path`
+    admits, where the table is largest; and a table smaller than one
+    block of the kernel. ONE pass: one kernel, no sort, no scatter."""
+    MEAN = _groupby.AggregationOp.MEAN
+
+    def dense(key, lohi, a, b, c):
+        return _groupby.dense_aggregate(
+            key, None, None, lohi, (a, b, c), (None,) * 3, slots,
+            (MEAN,) * 3, (1, 2, 3))
+
+    text = _compiled_text(dense, _sds(chip, n, jnp.int32),
+                          _sds(chip, 2, jnp.int32),
+                          _sds(chip, n, jnp.int32), _sds(chip, n, jnp.int32),
+                          _sds(chip, n, jnp.float32))
+    assert text.count("tpu_custom_call") == 1
+    assert "scatter" not in text and " sort(" not in text
+
+
+def test_groupby_key_range_probe_compiles(chip):
+    """The probe that every candidate groupby pays (groupby-q5 too): min
+    and max of 1e8 keys, no temporary of the key's size."""
+    fn = jax.jit(lambda k: _groupby.key_range_probe(k, None, None))
+    with jax.enable_x64(False):
+        compiled = fn.lower(_sds(chip, 100_000_000, jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 def test_setop_stream_compiles(chip):
     """Distinct union over two 8M-row tables of one payload lane."""
     def setop(bits, bits2, tag, lane):
