@@ -1458,8 +1458,8 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
     # None for all-valid columns: the mask never rides the sort
     valids = tuple(table._columns[i].validity for i in val_cols)
     vdtypes = [v.dtype for v in values]
-    dense = _groupby_dense(table, key_columns, val_cols, values, valids,
-                           ops, aggregate_ops)
+    dense, key_range = _groupby_dense(table, key_columns, val_cols, values,
+                                      valids, ops, aggregate_ops)
     if dense is not None:
         return dense
     keys = []
@@ -1486,12 +1486,16 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
     # (round-5 rework of the dense-rank + scatter-back path; the old gid
     # scatter cost ~15-30 ns/element). Its operands are counted here,
     # where the host can see them (the same pure function of mask, lanes,
-    # columns and index that presort_groups builds its list by)
+    # columns, index and packing that presort_groups builds its list by)
+    plan, params = _groupby_sort_packing(table, key_columns[0], key_range,
+                                         values)
     _telemetry.counter("cylon_groupby_sort_operands_total").inc(
-        _groupby.sort_operand_count(keys, emit, values, valids, index))
+        _groupby.sort_operand_count(keys, emit, values, valids, index, plan))
+    _telemetry.counter("cylon_groupby_sort_packed_columns_total").inc(
+        _groupby.packed_members(plan))
     values_s, valids_s, emit_s, first_s, new_grp, ng = \
         _groupby.presort_groups_jit(tuple(keys), emit, values, valids,
-                                    index=index)
+                                    index=index, plan=plan, params=params)
     num_groups = max(int(_telemetry.host_fetch("groupby.groups", ng)), 1)
     cap = _pow2(num_groups)
 
@@ -1556,10 +1560,60 @@ def _groupby_dense_fn(slots: int, ops: tuple, col_ids: tuple,
     return jax.jit(kernel)
 
 
+@_telemetry.counted_cache
+def _groupby_value_range_fn():
+    """The probe of the sort's packing: (lo, hi) of each integer value
+    column over every row, one small program (`jit_groupby_value_range`)."""
+    def kernel(values):
+        return _groupby.value_range_probe(values)
+
+    return jax.jit(kernel)
+
+
+def _groupby_sort_packing(table: Table, key_column: Column, key_range,
+                          values):
+    """(plan, params) for `ops/groupby.presort_groups`: which integer
+    value columns ride inside another operand's word, by what the host
+    observed, or (None, None) where every column rides alone.
+    ``key_range`` is `_groupby_dense`'s observed (lo, hi) of the ONE key
+    column, None where it probed nothing. The value columns' ranges cost
+    a probe and a fetch (`sync.groupby.valuerange`), paid only where the
+    static side says a word could be saved (an integer value column at
+    most 32 bits wide, and the observed key's spare bits or a second such
+    column to share a word with), for a table of SORT_PACK_MIN_ROWS rows
+    or more that lives on ONE device."""
+    key_bits = key_lo = None
+    if key_range is not None:
+        lo, hi = key_range
+        # no live row: a key of one bit, every row is dead
+        key_bits = max(_groupby.range_bits(lo, hi), 1) if lo <= hi else 1
+        key_lo = _groupby.key_lane_lo(lo, key_column.data.dtype,
+                                      key_column.is_string)
+    # the static side: a bit a column that could pack, the least it takes
+    hoped = [1 if _groupby.packs(v.dtype) else None for v in values]
+    if table.capacity < _groupby.SORT_PACK_MIN_ROWS \
+            or _groupby.sort_pack_plan(key_bits, hoped) is None \
+            or len(key_column.data.sharding.device_set) != 1:
+        return None, None
+    probed = [j for j, bit in enumerate(hoped) if bit is not None]
+    ranges = _telemetry.host_fetch(
+        "groupby.valuerange",
+        _groupby_value_range_fn()(tuple(values[j] for j in probed)))
+    value_lo, value_bits = [None] * len(values), [None] * len(values)
+    for j, (lo, hi) in zip(probed, ranges.tolist()):
+        value_lo[j], value_bits[j] = lo, _groupby.range_bits(lo, hi)
+    plan = _groupby.sort_pack_plan(key_bits, value_bits)
+    if plan is None:
+        return None, None
+    return plan, jnp.asarray(_groupby.sort_pack_params(
+        plan, key_lo, key_bits, value_lo, value_bits))
+
+
 def _groupby_dense(table: Table, key_columns, val_cols, values, valids, ops,
-                   aggregate_ops) -> Optional[Table]:
-    """The groupby over few groups, with no sort, or None where
-    `ops/groupby.group_path` says "sort". The static conditions cost
+                   aggregate_ops):
+    """(the groupby over few groups, with no sort, or None where
+    `ops/groupby.group_path` says "sort"; the key's observed (lo, hi), or
+    None where nothing was probed). The static conditions cost
     nothing; only a table that meets them (and lives on ONE device: the
     kernel is no sharded program) pays the probe, a fused min / max over
     the key and the fetch of the pair (`sync.groupby.keyrange`). With
@@ -1574,7 +1628,7 @@ def _groupby_dense(table: Table, key_columns, val_cols, values, valids, ops,
     n = table.capacity
     if _groupby.group_path(kdtypes, knull, vdtypes, ops, n) != "dense" \
             or len(key_columns[0].data.sharding.device_set) != 1:
-        return None
+        return None, None
     kc = key_columns[0]
     emit = table.row_mask
     lohi = _groupby_key_range_fn()(kc.data, emit, kc.validity)
@@ -1584,7 +1638,8 @@ def _groupby_dense(table: Table, key_columns, val_cols, values, valids, ops,
     key_range = hi - lo + 1 if lo <= hi else 1
     if _groupby.group_path(kdtypes, knull, vdtypes, ops, n,
                            key_range) != "dense":
-        return None
+        # a key that holds no null keeps its range for the sort's packing
+        return None, None if knull[0] else (lo, hi)
     slots = _pow2(key_range + knull[0])
     # counted where the host evaluates the decision (the sort path
     # counts its reduce step, `stream` or `segment`, below)
@@ -1601,7 +1656,7 @@ def _groupby_dense(table: Table, key_columns, val_cols, values, valids, ops,
         src = table._columns[vi]
         out_cols.append(Column(arr, _agg_dtype(src, op), avalid, None,
                                src.name))
-    return Table(out_cols, table._ctx, group_valid)
+    return Table(out_cols, table._ctx, group_valid), None
 
 
 def _agg_dtype(src: Column, op) -> dtypes.DataType:

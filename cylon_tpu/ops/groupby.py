@@ -25,7 +25,11 @@ row index only when a caller needs the first ORIGINAL row of a group
 key, the distributed kernel. Otherwise the sorted key lanes themselves
 are compacted to slot g by the reduce pass and the output key columns
 are read off them (`order.from_ordered_bits_raw`), with no index, no
-gather of the key column and no need for a stable sort.
+gather of the key column and no need for a stable sort. And what rides
+takes as few operands as its OBSERVED ranges allow (`sort_pack_plan`,
+PR 35): integer value columns narrow enough ride in the spare low bits
+of the key's word, or several to a word, and are unpacked exactly after
+the sort, inside the same program.
 
 Distributed semantics (fixing the reference's re-aggregation subtlety noted
 in SURVEY §3.2): partial aggregates are combined with the correct SECOND-
@@ -91,17 +95,184 @@ def _min_of(dtype):
     return np.iinfo(d).min
 
 
-def sort_operand_count(keys, emit, values, valids, index: bool) -> int:
+# under this many rows a sort's packing is not looked for: the value
+# probe's dispatch and fetch cost more than the operands they could save.
+# From ONE sweep on a v5e (PERF.md section 6, PR 35): a packed groupby is
+# 0.3-0.9 ms slower than a plain one at 2^20 rows and 0.4-1.3 ms faster
+# at 2^21, whether one operand is saved or two; twice the level, and the
+# power of two over it.
+SORT_PACK_MIN_ROWS = 1 << 22
+
+_WORD_BITS = 32
+_KEY = -1      # the key's member id in a plan's word; value column i is i
+
+
+def range_bits(lo: int, hi: int) -> int:
+    """Bits that hold every ``x - lo`` for x in [lo, hi] (0 for lo = hi)."""
+    return (hi - lo).bit_length()
+
+
+def packs(dtype) -> bool:
+    """Whether a column whose array is of ``dtype`` may ride inside
+    another operand's word: an integer kind, bool and dictionary codes
+    too, at most 32 bits wide. A float never does."""
+    d = np.dtype(dtype)
+    return d.kind in "iub" and d.itemsize * 8 <= _WORD_BITS
+
+
+def sort_pack_plan(key_bits, value_bits):
+    """WHICH operands the fused sort carries, from what the code observed
+    (no knob): a tuple of words, each a tuple of the members that ride in
+    it (``-1`` the key, ``i`` value column i), or None where no column
+    shares a word, which is the sort as it always was.
+
+    ``key_bits``: bits of the key's observed range, None when it was not
+    observed (several key columns, a nullable, varbytes or 8-byte key, a
+    sharded table). ``value_bits[i]``: bits of value column i's observed
+    physical range, None for a column that cannot pack (`packs`) or was
+    not probed. First fit in column order: the key's word first (the key
+    keeps the HIGH bits, so the word sorts as the key does and the rows
+    of one group fall in an order nobody reads), then the words opened by
+    earlier columns; a column that fits nowhere opens a word of its own,
+    and a word of one member rides as the column itself, untouched. A
+    range that does not fit is never truncated: its column rides alone.
+
+    The plan is the compiled program's only static part: offsets, shifts
+    and masks go in as an array (`sort_pack_params`)."""
+    words = []      # [members, bits used or None for a closed word]
+    if key_bits is not None:
+        words.append([[_KEY], key_bits])
+    for i, bits in enumerate(value_bits):
+        if bits is not None:
+            home = next((w for w in words if w[1] is not None
+                         and w[1] + bits <= _WORD_BITS), None)
+            if home is not None:
+                home[0].append(i)
+                home[1] += bits
+                continue
+        words.append([[i], bits])
+    if all(len(members) == 1 for members, _ in words):
+        return None
+    return tuple(tuple(members) for members, _ in words)
+
+
+def packed_members(plan) -> int:
+    """Value columns that ride inside another operand's word (counted as
+    ``cylon_groupby_sort_packed_columns_total``): a shared word's members
+    but its first, which is the operand they ride in."""
+    return sum(len(word) - 1 for word in plan or ())
+
+
+def key_lane_lo(lo: int, dtype, is_string: bool) -> int:
+    """The ordered lane (`order.ordered_bits_raw`) of the key value
+    ``lo``, on the host: a signed key's sign bit flipped; an unsigned or
+    bool key and dictionary codes as they are."""
+    d = np.dtype(dtype)
+    signed = d.kind == "i" and not is_string
+    return lo + (1 << (8 * d.itemsize - 1)) if signed else lo
+
+
+def sort_pack_params(plan, key_lo: int, key_bits, value_lo, value_bits):
+    """The numbers of ``plan`` as ONE uint32 array (one transfer; two
+    tables of one plan share one program): a row (offset, shift, mask) a
+    member of a shared word, in the plan's order. Offsets are in the
+    ordered-lane domain (`_lane32`: ``key_lo`` is the key LANE's lowest
+    observed value). The key sits at the top of its word, every other
+    member is stacked from bit 0 up in plan order."""
+    rows = []
+    for word in plan:
+        if len(word) == 1:
+            continue
+        at = 0
+        for m in word:
+            if m == _KEY:
+                rows.append((key_lo, _WORD_BITS - key_bits,
+                             (1 << key_bits) - 1))
+                continue
+            bits = value_bits[m]
+            # a member of no bits (lo = hi) holds nothing: any shift
+            rows.append((value_lo[m], min(at, _WORD_BITS - 1),
+                         (1 << bits) - 1))
+            at += bits
+        assert at + (key_bits if word[0] == _KEY else 0) <= _WORD_BITS, word
+    return np.asarray(rows, np.uint32)
+
+
+def _lane32(x):
+    """An integer column of at most 32 bits as the uint32 lane that
+    orders as it does (a key lane is one already)."""
+    return _order.ordered_bits_raw(x).astype(jnp.uint32)
+
+
+def _from_lane32(lane, dtype):
+    """`_lane32`'s inverse, to a column of ``dtype``."""
+    dt = np.dtype(dtype)
+    return _order.from_ordered_bits_raw(
+        lane.astype(_order._WIDTH_UINT[dt.itemsize]), dt)
+
+
+def value_range_probe(values):
+    """uint32[len(values), 2]: (lo, hi) of each integer column's PHYSICAL
+    array as an ordered lane, over every row, masks ignored: a packing
+    by these ranges holds dead and null rows' slots too, whatever they
+    hold. One fused pass, ONE array to fetch."""
+    return jnp.stack([jnp.stack([lane.min(), lane.max()])
+                      for lane in map(_lane32, values)])
+
+
+def sort_operand_count(keys, emit, values, valids, index: bool,
+                       plan=None) -> int:
     """How many operands presort_groups hands the sort for these
     arguments: a pure function of what the host sees before it
-    dispatches (counted there as ``cylon_groupby_sort_operands_total``)."""
-    return ((emit is not None) + len(keys) + len(values)
+    dispatches (counted there as ``cylon_groupby_sort_operands_total``).
+    With a ``plan`` (`sort_pack_plan`) the value columns and a packed key
+    count by its words."""
+    carried = len(keys) + len(values) if plan is None else \
+        len(plan) + len(keys) - (plan[0][0] == _KEY)
+    return ((emit is not None) + carried
             + sum(v is not None for v in valids) + bool(index))
+
+
+def _pack(plan, params, cols):
+    """One operand a word of ``plan`` (``cols``: member id -> array): the
+    column itself where it rides alone, else its members' ordered lanes,
+    each less its offset, shifted into place."""
+    words, row = [], 0
+    for word in plan:
+        if len(word) == 1:
+            words.append(cols[word[0]])
+            continue
+        packed = 0
+        for m in word:
+            # wraps only for a dead row's key, outside the live rows'
+            # range: its high bits fall off the top, the riders' stay
+            packed = packed | ((_lane32(cols[m]) - params[row, 0])
+                               << params[row, 1])
+            row += 1
+        words.append(packed)
+    return words
+
+
+def _unpack(plan, params, words_s, cols):
+    """member id -> sorted column, off `_pack`'s sorted words: what an
+    unpacked sort hands on, bit for bit."""
+    cols_s, row = {}, 0
+    for word, packed in zip(plan, words_s):
+        if len(word) == 1:
+            cols_s[word[0]] = packed
+            continue
+        for m in word:
+            lane = ((packed >> params[row, 1]) & params[row, 2]) \
+                + params[row, 0]
+            cols_s[m] = _from_lane32(lane, cols[m].dtype)
+            row += 1
+    return cols_s
 
 
 def presort_groups(keys: Tuple[jnp.ndarray, ...], emit,
                    values: Tuple[jnp.ndarray, ...],
-                   valids: Tuple[jnp.ndarray, ...], index: bool = True):
+                   valids: Tuple[jnp.ndarray, ...], index: bool = True,
+                   plan=None, params=None):
     """ONE fused sort by the key lanes that carries every value column
     and every validity mask as operands, and nothing that can be derived
     after it. Output rows are grouped contiguously: group g is the g-th
@@ -117,7 +288,13 @@ def presort_groups(keys: Tuple[jnp.ndarray, ...], emit,
     adds the row index as the last operand of a STABLE sort, so that a
     run's first sorted row is its first original row; without it the
     sorted key lanes go out in its place and the sort need not be
-    stable (a group's rows arrive in any order).
+    stable (a group's rows arrive in any order). ``plan`` (static;
+    `sort_pack_plan`, None: every column rides alone, as under
+    `shard_map`, where no host observes a range) packs integer columns
+    into shared words by the offsets, shifts and masks of ``params``
+    (`sort_pack_params`) and unpacks them after the sort: what is handed
+    on is what the unpacked sort hands on, only a group's rows in
+    another order.
 
     Returns (values_s, valids_s, emit_s, first_s, new_grp, n_groups):
     emit_s is None when emit was; first_s is the sorted row index, or
@@ -126,17 +303,31 @@ def presort_groups(keys: Tuple[jnp.ndarray, ...], emit,
     caller's single host sync)."""
     n = keys[0].shape[0]
     nk, nv = len(keys), len(values)
-    real_v = [v for v in valids if v is not None]
+    real_v = tuple(v for v in valids if v is not None)
     head = () if emit is None else ((~emit).astype(jnp.uint8),)
     tail = (jnp.arange(n, dtype=jnp.int32),) if index else ()
-    ops_in = head + tuple(keys) + tuple(values) + tuple(real_v) + tail
+    if plan is None:
+        carried = tuple(keys) + tuple(values)
+    else:
+        cols = dict(enumerate(values))
+        if plan[0][0] == _KEY:      # an observed key is ONE lane
+            cols[_KEY], = keys
+        # the key's word, where it has one, is the first: the sort's key
+        lanes = () if _KEY in cols else tuple(keys)
+        carried = lanes + tuple(_pack(plan, params, cols))
+    ops_in = head + carried + real_v + tail
     assert len(ops_in) == sort_operand_count(keys, emit, values, valids,
-                                             index)
+                                             index, plan)
     h = len(head)
     res = jax.lax.sort(ops_in, num_keys=h + nk, is_stable=index)
-    ks = res[h:h + nk]
-    values_s = tuple(res[h + nk:h + nk + nv])
-    it = iter(res[h + nk + nv:h + nk + nv + len(real_v)])
+    carried_s = res[h:h + len(carried)]
+    if plan is None:
+        ks, values_s = carried_s[:nk], tuple(carried_s[nk:])
+    else:
+        cols_s = _unpack(plan, params, carried_s[len(lanes):], cols)
+        ks = carried_s[:nk] if lanes else (cols_s[_KEY],)
+        values_s = tuple(cols_s[i] for i in range(nv))
+    it = iter(res[h + len(carried):h + len(carried) + len(real_v)])
     valids_s = tuple(None if v is None else next(it) for v in valids)
     # row differs from its predecessor on any key lane (row 0 = True);
     # dead rows are all last, so live rows form a prefix
@@ -148,8 +339,8 @@ def presort_groups(keys: Tuple[jnp.ndarray, ...], emit,
     if emit is not None:
         emit_s = res[0] == 0
         new_grp = new_grp & emit_s
-    return (values_s, valids_s, emit_s, res[-1] if index else ks, new_grp,
-            new_grp.sum(dtype=jnp.int32))
+    return (values_s, valids_s, emit_s, res[-1] if index else tuple(ks),
+            new_grp, new_grp.sum(dtype=jnp.int32))
 
 
 _STREAM_DTYPES = (jnp.int32, jnp.float32)
@@ -553,7 +744,7 @@ def dense_aggregate(key, key_valid, emit, lohi,
     return key_data, key_validity, group_valid, results
 
 
-presort_groups_jit = partial(jax.jit, static_argnames=("index",))(
+presort_groups_jit = partial(jax.jit, static_argnames=("index", "plan"))(
     presort_groups)
 
 sorted_segment_aggregate_jit = partial(

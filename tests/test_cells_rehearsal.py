@@ -41,15 +41,22 @@ import argparse, importlib, json, os, sys
 script, cell, scale, control = sys.argv[1:5]
 sys.path.insert(0, os.path.dirname(os.path.abspath(script)))
 run = importlib.import_module(os.path.splitext(os.path.basename(script))[0])
+# the sort's packing (PR 35) is looked for from SORT_PACK_MIN_ROWS rows
+# on, which no rehearsal has: groupby-q5 packs at 1e8 rows, so here too
+from cylon_tpu.ops import groupby
+groupby.SORT_PACK_MIN_ROWS = 0
 result, code = run.run(argparse.Namespace(
     workload=cell, seed=2147483659, seconds=0.5, trace=0,
     scale=float(scale), control=int(control)))
 from cylon_tpu import telemetry
+snap = telemetry.metrics_snapshot()
 print("REHEARSED " + json.dumps({
     "code": code, "correct": result["correct"],
     "attempted": result["attempted"], "failed": result["failed"],
-    "series": sorted(k for k, v in telemetry.metrics_snapshot().items()
-                     if isinstance(v, (int, float)))}))
+    "series": sorted(k for k, v in snap.items()
+                     if isinstance(v, (int, float))),
+    "packed": snap.get("cylon_groupby_sort_packed_columns_total", 0),
+    "sorted": snap.get("cylon_groupby_sort_operands_total", 0)}))
 """
 
 
@@ -89,6 +96,19 @@ def test_cell_control_is_not_correct(cell):
     said, out = rehearse(cell, 1)
     assert said["correct"] is False
     assert said["failed"] == 0 and "MISMATCH" in out
+
+
+def test_groupby_q5_rehearses_packed():
+    """The cell's sort carries v1 and v2 inside the key's word in every
+    query, as at 1e8 rows: two operands a query (the word and v3; off a
+    TPU the index besides), and `groupby-q4`, which never sorts, packs
+    nothing."""
+    said, _out = rehearse("groupby-q5", 0)
+    queries = said["packed"] // 2
+    assert queries >= 3 and said["packed"] == 2 * queries
+    assert said["sorted"] == 3 * queries
+    said, _out = rehearse("groupby-q4", 0)
+    assert said["packed"] == 0 and said["sorted"] == 0
 
 
 def _counter_metrics():

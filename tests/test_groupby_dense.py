@@ -36,7 +36,8 @@ def _counted():
            for p in ("dense", "stream", "segment")}
     out["slots"] = snap.get("cylon_groupby_dense_slots_total", 0)
     out["operands"] = snap.get("cylon_groupby_sort_operands_total", 0)
-    for site in ("groupby.keyrange", "groupby.groups"):
+    out["packed"] = snap.get("cylon_groupby_sort_packed_columns_total", 0)
+    for site in ("groupby.keyrange", "groupby.valuerange", "groupby.groups"):
         out[site] = snap.get('cylon_host_syncs_total{site="%s"}' % site, 0)
     out["probes"] = snap.get(
         'cylon_kernel_factory_builds_total{factory="_groupby_key_range_fn"}',
@@ -183,9 +184,13 @@ def test_dense_groupby_matches_numpy(local_ctx, kind, shape):
     (G.DENSE_MAX_SLOTS, "dense"), (G.DENSE_MAX_SLOTS + 1, "sort")])
 def test_both_sides_of_dense_max_slots(local_ctx, monkeypatch, key_range,
                                        path):
-    """Range exactly DENSE_MAX_SLOTS: dense, ONE fetch. One more: the
-    sort path, untouched, after the probe: TWO fetches. Integer results
-    are bit-equal to the sort path's on the same table either way."""
+    """Range exactly DENSE_MAX_SLOTS: dense, ONE fetch, and nothing of
+    the sort's packing (PR 35) is dispatched. One more: the sort path
+    after the probe, which keeps the probe's range: the integer column
+    rides in the key's spare bits (a third fetch, its range's; one
+    operand fewer). Integer results are bit-equal to the plain sort's on
+    the same table either way."""
+    monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 0)
     rng = np.random.default_rng(key_range)
     key = rng.integers(0, key_range, N).astype(np.int32) - 17
     key[:2] = (-17, key_range - 18)              # the range is exact
@@ -200,8 +205,11 @@ def test_both_sides_of_dense_max_slots(local_ctx, monkeypatch, key_range,
         assert moved == {"dense": 1, "slots": G.DENSE_MAX_SLOTS,
                          "groupby.keyrange": 1}
     else:       # tier-1's CPU: the segment reduce step, the index rides
-        assert moved == {"segment": 1, "operands": 4, "groupby.keyrange": 1,
+        assert moved == {"segment": 1, "operands": 3, "packed": 1,
+                         "groupby.keyrange": 1, "groupby.valuerange": 1,
                          "groupby.groups": 1}
+    # steered to the sort with no probe: no key range and ONE integer
+    # column, so no word to save and no value probe either
     monkeypatch.setattr(G, "group_path", lambda *a, **k: "sort")
     before = _counted()
     sort = t.groupby(0, [1, 2], ["sum", "sum"])

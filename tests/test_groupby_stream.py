@@ -256,30 +256,38 @@ def _paths():
     counted = {p: snap.get('cylon_groupby_reduce_path_total{path="%s"}' % p, 0)
                for p in ("stream", "segment")}
     counted["operands"] = snap.get("cylon_groupby_sort_operands_total", 0)
+    counted["packed"] = snap.get("cylon_groupby_sort_packed_columns_total", 0)
     return counted
 
 
-def test_every_reduce_step_is_counted_by_its_path(local_ctx, dist_ctx):
+def test_every_reduce_step_is_counted_by_its_path(local_ctx, dist_ctx,
+                                                  monkeypatch):
     # keys 5,000 apart: a range past DENSE_MAX_SLOTS, so the rows are
     # sorted (five keys side by side fill a dense table, which counts
     # `dense` and no sort operand: tests/test_groupby_dense.py)
     data = {"k": np.arange(64, dtype=np.int32) % 5 * 5000,
             "v": np.arange(64, dtype=np.int32)}
+    # the sort's packing as a table of SORT_PACK_MIN_ROWS rows meets it
+    monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 0)
     before = _paths()
     out = ct.Table.from_pydict(local_ctx, data).groupby(0, [1], ["sum"])
     assert out.row_count == 5
     after = _paths()
     assert after["segment"] == before["segment"] + 1     # the CPU's path
     assert after["stream"] == before["stream"]
-    # its sort carried the key, the value and, for the CPU's path, the
-    # index; no row mask, so no dead flag
-    assert after["operands"] == before["operands"] + 3
+    # its sort carried the key's word, the value inside it (the key's
+    # observed range takes 15 bits, the value's 6), and, for the CPU's
+    # path, the index; no row mask, so no dead flag
+    assert after["operands"] == before["operands"] + 2
+    assert after["packed"] == before["packed"] + 1
     # distributed: the partial aggregation and the merge are a reduce
     # step each, and each one's sort carries the dead flag (an exchanged
     # table always has a mask), the key's bits and validity lane, the
     # value and the index; the merge's value, a partial sum, brings its
-    # validity mask besides
+    # validity mask besides; under `shard_map` no host observes a range,
+    # and nothing is packed
     t = ct.Table.from_pydict(dist_ctx, data)
     assert t.groupby(0, [1], ["sum"]).row_count == 5
     assert _paths()["segment"] == after["segment"] + 2
     assert _paths()["operands"] == after["operands"] + 5 + 6
+    assert _paths()["packed"] == after["packed"]

@@ -254,6 +254,8 @@ def _planned(kind, left, right):
     if kind == "groupby-dense":     # 16 groups: the key's range is small
         return plan.scan(right).filter(
             plan.col("k") < 16).groupby("k", ["w"], ["sum"])
+    if kind == "groupby-packed":    # an integer column beside the float
+        return plan.scan(right).groupby("k", ["k", "w"], ["sum", "sum"])
     return plan.scan(right).groupby("k", ["w"], ["sum"])
 
 
@@ -265,16 +267,23 @@ def _planned(kind, left, right):
      "plan.groupby"),
     # few groups: the probe's fetch is the only one
     ("groupby-dense", ["sync.groupby.keyrange"], "plan.groupby"),
+    # a sort that could carry an integer column in the key's spare bits
+    # (a table of SORT_PACK_MIN_ROWS rows or more) fetches its range too
+    ("groupby-packed", ["sync.groupby.keyrange", "sync.groupby.valuerange",
+                        "sync.groupby.groups"], "plan.groupby"),
 ])
 def test_planned_local_operator_is_one_counted_spanned_sync(
-        local_ctx, kind, sites, operator):
+        local_ctx, monkeypatch, kind, sites, operator):
     """A planned local join / groupby on fresh inputs: the counter grows
     by exactly 1 a fetch and exactly that many ``sync.*`` spans open, as
     children of the operator's span (what the benchmark's
     ``host_syncs_per_query`` and ``sync_idle_ms_per_query`` read in a
     trace): one for the join and for a groupby over few groups, two for
-    a groupby that sorts."""
+    a groupby that sorts, three for one whose sort packs."""
     from cylon_tpu import telemetry
+    from cylon_tpu.ops import groupby
+
+    monkeypatch.setattr(groupby, "SORT_PACK_MIN_ROWS", 0)
 
     pipe = _planned(kind, *_fresh_pair(local_ctx, 31))
     s0 = _syncs()

@@ -170,6 +170,10 @@ def test_dense_groupby_names_its_probe_its_program_and_its_kernel():
     probe = table_mod._groupby_key_range_fn()
     head = probe.lower(_sds(jnp.int32, n), None, None).as_text()
     assert head.startswith("module @jit_groupby_key_range "), head[:80]
+    # the sort's packing probe (PR 35) matches the same pattern
+    probe = table_mod._groupby_value_range_fn()
+    head = probe.lower((_sds(jnp.int32, n), _sds(jnp.int8, n))).as_text()
+    assert head.startswith("module @jit_groupby_value_range "), head[:80]
     MEAN = _groupby.AggregationOp.MEAN
     with jax.enable_x64(False):
         dense = table_mod._groupby_dense_fn(128, (MEAN, MEAN), (1, 2), True)

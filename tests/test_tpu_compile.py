@@ -149,6 +149,33 @@ def test_groupby_key_range_probe_compiles(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
+def test_groupby_packed_sort_compiles_with_two_operands(chip):
+    """groupby-q5's fused sort as PR 35 packs it (at a sixth of its rows:
+    a sort compiles by the operand, seconds each at 1e8): the key lane,
+    v1 and v2 go in as ONE uint32 word and v3 beside it, so the sort as
+    COMPILED has two operands; and the value columns' range probe keeps
+    no temporary of a column's size."""
+    n = N
+    u32, i32, f32 = (_sds(chip, n, d) for d in (jnp.uint32, jnp.int32,
+                                                jnp.float32))
+    plan = _groupby.sort_pack_plan(20, [3, 4, None])
+    assert plan == ((-1, 0, 1), (2,))
+    params = jax.ShapeDtypeStruct((3, 3), jnp.uint32, sharding=chip)
+    text = _compiled_text(_groupby.presort_groups_jit, (u32,), None,
+                          (i32, i32, f32), (None,) * 3, index=False,
+                          plan=plan, params=params)
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    assert len(sorts) == 1, sorts
+    operands = re.search(r"\bsort\((.*?)\), dimensions=", sorts[0]).group(1)
+    assert operands.count("%") == 2, sorts[0]
+    assert _groupby.sort_operand_count((u32,), None, (i32, i32, f32),
+                                       (None,) * 3, False, plan) == 2
+    with jax.enable_x64(False):
+        probe = jax.jit(_groupby.value_range_probe).lower(
+            (i32, i32)).compile()
+    assert probe.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 def test_setop_stream_compiles(chip):
     """Distinct union over two 8M-row tables of one payload lane."""
     def setop(bits, bits2, tag, lane):
