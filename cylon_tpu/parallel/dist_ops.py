@@ -334,49 +334,54 @@ def _build_exchange_payload(t: Table, ctx: CylonContext,
     None on as it is)."""
     from ..data.strings import LANE_WORDS_MAX
 
-    payload = dict(extra or {})
-    lane_cols = {}
-    for i, c in enumerate(t._columns):
-        payload[f"d{i}"] = c.data  # byte lengths for varbytes columns
-        if c.validity is not None:
-            payload[f"v{i}"] = c.valid_mask()
-        if c.is_varbytes and c.varbytes.max_words <= LANE_WORDS_MAX:
-            vb = c.varbytes
-            lanes = _word_lanes_fn(ctx.mesh, vb.max_words)(
-                shard.pin(vb.words, ctx), shard.pin(vb.starts, ctx),
-                shard.pin(vb.lengths, ctx))
-            lane_cols[i] = vb.max_words
-            for k, l in enumerate(lanes):
-                payload[f"d{i}w{k}"] = l
-    payload = {k: shard.pin(v, ctx) for k, v in payload.items()}
+    with _span("shuffle.payload") as sp:
+        payload = dict(extra or {})
+        lane_cols = {}
+        for i, c in enumerate(t._columns):
+            payload[f"d{i}"] = c.data  # byte lengths for varbytes columns
+            if c.validity is not None:
+                payload[f"v{i}"] = c.valid_mask()
+            if c.is_varbytes and c.varbytes.max_words <= LANE_WORDS_MAX:
+                vb = c.varbytes
+                lanes = _word_lanes_fn(ctx.mesh, vb.max_words)(
+                    shard.pin(vb.words, ctx), shard.pin(vb.starts, ctx),
+                    shard.pin(vb.lengths, ctx))
+                lane_cols[i] = vb.max_words
+                for k, l in enumerate(lanes):
+                    payload[f"d{i}w{k}"] = l
+        payload = {k: shard.pin(v, ctx) for k, v in payload.items()}
+        sp.set(lanes=len(payload))
     return payload, lane_cols
 
 
 def _finish_exchange_table(t: Table, ctx: CylonContext, targets, emit,
                            out, new_emit, meta, lane_cols,
                            extra: Optional[dict]):
-    cols = []
-    for i, c in enumerate(t._columns):
-        d, v = out[f"d{i}"], out.get(f"v{i}")
-        if c.is_varbytes:
-            # the padded-mode exchange over-reads neighbor rows into dead
-            # slots, so dead rows can carry live rows' byte lengths; the
-            # lane masking and every later _word_row_map pass need dead
-            # rows at nw=0 to keep the monotone-starts invariant
-            # (strings.py _word_row_map), so zero them first
-            d = jnp.where(new_emit, d, jnp.zeros((), d.dtype))
-            if i in lane_cols:
-                vb = _from_lanes_sharded(
-                    ctx, [out[f"d{i}w{k}"] for k in range(lane_cols[i])],
-                    d)
+    """Columns back out of the exchanged payload's leaves (a long
+    varbytes column's words ride their own exchange from here)."""
+    with _span("shuffle.unpack"):
+        cols = []
+        for i, c in enumerate(t._columns):
+            d, v = out[f"d{i}"], out.get(f"v{i}")
+            if c.is_varbytes:
+                # the padded-mode exchange over-reads neighbor rows into
+                # dead slots, so dead rows can carry live rows' byte
+                # lengths; the lane masking and every later _word_row_map
+                # pass need dead rows at nw=0 to keep the monotone-starts
+                # invariant (strings.py _word_row_map), so zero them first
+                d = jnp.where(new_emit, d, jnp.zeros((), d.dtype))
+                if i in lane_cols:
+                    vb = _from_lanes_sharded(
+                        ctx,
+                        [out[f"d{i}w{k}"] for k in range(lane_cols[i])], d)
+                else:
+                    vb = _exchange_varbytes_words(ctx, c.varbytes, targets,
+                                                  emit, d, meta)
+                cols.append(Column(vb.lengths, c.dtype, v, None, c.name,
+                                   varbytes=vb))
             else:
-                vb = _exchange_varbytes_words(ctx, c.varbytes, targets,
-                                              emit, d, meta)
-            cols.append(Column(vb.lengths, c.dtype, v, None, c.name,
-                               varbytes=vb))
-        else:
-            cols.append(Column(d, c.dtype, v, c.dictionary, c.name))
-    extra_out = {k: out[k] for k in (extra or {})}
+                cols.append(Column(d, c.dtype, v, c.dictionary, c.name))
+        extra_out = {k: out[k] for k in (extra or {})}
     return cols, new_emit, extra_out
 
 
@@ -954,32 +959,37 @@ def distributed_join(left: Table, right: Table,
     # the runtime-honest algorithm census (adaptive execution: which
     # joins actually went broadcast — see broadcast_hash_join)
     _counter("cylon_join_algorithm_total", {"algo": "shuffle"}).inc()
-    exact_pairs = []
-    if getattr(config, "exact", False):
-        from ..data.strings import EXACT_KEY_WORDS
-
-        for li, rj in zip(config.left_column_idx, config.right_column_idx):
-            a, b = left._columns[li], right._columns[rj]
-            kw = _pair_k(a, b)
-            if kw is not None and kw > EXACT_KEY_WORDS:
-                # long keys join on the 96-bit content hash; exact=True
-                # byte-verifies AFTER the exchange (both key columns are
-                # row-aligned in the output) — INNER filters false
-                # matches, outer joins redo on dictionary codes
-                exact_pairs.append((li, rj))
-
-    left_d = shard.distribute(left, ctx)
-    right_d = shard.distribute(right, ctx)
-    lidx, ridx = config.left_column_idx, config.right_column_idx
-    lcols, rcols = _align_key_columns_dist(ctx, left_d, right_d, lidx, ridx)
-
     seq = ctx.get_next_sequence()
+    lidx, ridx = config.left_column_idx, config.right_column_idx
+    with _span("distributed_join.distribute", seq, world=world) as _sp:
+        exact_pairs = []
+        if getattr(config, "exact", False):
+            from ..data.strings import EXACT_KEY_WORDS
+
+            for li, rj in zip(lidx, ridx):
+                a, b = left._columns[li], right._columns[rj]
+                kw = _pair_k(a, b)
+                if kw is not None and kw > EXACT_KEY_WORDS:
+                    # long keys join on the 96-bit content hash; exact=True
+                    # byte-verifies AFTER the exchange (both key columns
+                    # are row-aligned in the output) — INNER filters false
+                    # matches, outer joins redo on dictionary codes
+                    exact_pairs.append((li, rj))
+
+        left_d = shard.distribute(left, ctx)
+        right_d = shard.distribute(right, ctx)
+        _sp.set(already_distributed=int(left_d is left)
+                + int(right_d is right))
+        lcols, rcols = _align_key_columns_dist(ctx, left_d, right_d, lidx,
+                                               ridx)
+
     shuffled = []
     with _span("distributed_join.shuffle", seq, world=world,
                rows_in=left_d.capacity + right_d.capacity) as _sp:
         plan = []
-        for t, kcols, kidx, other in ((left_d, lcols, lidx, rcols),
-                                      (right_d, rcols, ridx, lcols)):
+        for side, t, kcols, kidx, other in (
+                ("left", left_d, lcols, lidx, rcols),
+                ("right", right_d, rcols, ridx, lcols)):
             sig = shard.partition_signature(kcols, kidx, world)
             if sig is not None and t._hash_partitioned == sig:
                 # co-partitioned (prior shuffle or distribute_by_key host
@@ -990,9 +1000,11 @@ def distributed_join(left: Table, right: Table,
             # recomputed from the shuffled columns below (elementwise /
             # per-shard work), so the exchange moves ~2/3 fewer lanes —
             # measured 813 ms -> the bare-columns exchange cost at 16M
-            targets = shard.pin(
-                _partition_targets_dist(ctx, kcols, other), ctx)
-            emit = shard.pin(t.emit_mask(), ctx)
+            with _span("distributed_join.targets", seq, side=side,
+                       key_columns=len(kcols)):
+                targets = shard.pin(
+                    _partition_targets_dist(ctx, kcols, other), ctx)
+                emit = shard.pin(t.emit_mask(), ctx)
             plan.append(("exchange", t, targets, emit))
         # both sides exchanging: ONE fused count program + ONE host sync
         # covers both shuffles (the reference pays a header phase per
@@ -1022,48 +1034,55 @@ def distributed_join(left: Table, right: Table,
                 cols, emit_s, _x = _exchange_table(t, targets, emit, ctx)
             shuffled.append((cols, emit_s, emit_s))
 
-    # rebuild key bits from the SHUFFLED columns (word lanes reshape out
-    # of the strided layout; plain columns are elementwise ordered-bits)
-    (lcols_all, lmask, lemit), (rcols_all, rmask, remit) = shuffled
-    left_s = Table(list(lcols_all), ctx, lmask)
-    right_s = Table(list(rcols_all), ctx, rmask)
-    lcols2, rcols2 = _align_key_columns_dist(ctx, left_s, right_s,
-                                             lidx, ridx)
-    lkb, lkv, _h1s_l = _dist_key_bits(ctx, lcols2, rcols2)
-    rkb, rkv, _h1s_r = _dist_key_bits(ctx, rcols2, lcols2)
-    lkb = tuple(shard.pin(b, ctx) for b in lkb)
-    rkb = tuple(shard.pin(b, ctx) for b in rkb)
-    lkv = shard.pin(lkv, ctx)
-    rkv = shard.pin(rkv, ctx)
-    lcols_s, rcols_s = lcols_all, rcols_all
-    lvb = [i for i, c in enumerate(lcols_s) if c.is_varbytes]
-    rvb = [i for i, c in enumerate(rcols_s) if c.is_varbytes]
-    # a validity that is None stays None, as _build_exchange_payload
-    # round-trips it: an all-ones mask would ride the plan sort as a "v"
-    # lane that says nothing, and the XLA materialize's gather reads None
-    # as all-valid too
-    ldat = tuple(shard.pin(c.data, ctx) for c in lcols_s)
-    lval = tuple(None if c.validity is None else shard.pin(c.validity, ctx)
-                 for c in lcols_s)
-    rdat = tuple(shard.pin(c.data, ctx) for c in rcols_s)
-    rval = tuple(None if c.validity is None else shard.pin(c.validity, ctx)
-                 for c in rcols_s)
-
     jt = config.type
+    with _span("distributed_join.keybits", seq) as _sp:
+        # rebuild key bits from the SHUFFLED columns (word lanes reshape
+        # out of the strided layout; plain columns are elementwise
+        # ordered-bits)
+        (lcols_all, lmask, lemit), (rcols_all, rmask, remit) = shuffled
+        left_s = Table(list(lcols_all), ctx, lmask)
+        right_s = Table(list(rcols_all), ctx, rmask)
+        lcols2, rcols2 = _align_key_columns_dist(ctx, left_s, right_s,
+                                                 lidx, ridx)
+        lkb, lkv, _h1s_l = _dist_key_bits(ctx, lcols2, rcols2)
+        rkb, rkv, _h1s_r = _dist_key_bits(ctx, rcols2, lcols2)
+        lkb = tuple(shard.pin(b, ctx) for b in lkb)
+        rkb = tuple(shard.pin(b, ctx) for b in rkb)
+        lkv = shard.pin(lkv, ctx)
+        rkv = shard.pin(rkv, ctx)
+        lcols_s, rcols_s = lcols_all, rcols_all
+        lvb = [i for i, c in enumerate(lcols_s) if c.is_varbytes]
+        rvb = [i for i, c in enumerate(rcols_s) if c.is_varbytes]
+        # a validity that is None stays None, as _build_exchange_payload
+        # round-trips it: an all-ones mask would ride the plan sort as a
+        # "v" lane that says nothing, and the XLA materialize's gather
+        # reads None as all-valid too
+        ldat = tuple(shard.pin(c.data, ctx) for c in lcols_s)
+        lval = tuple(None if c.validity is None
+                     else shard.pin(c.validity, ctx) for c in lcols_s)
+        rdat = tuple(shard.pin(c.data, ctx) for c in rcols_s)
+        rval = tuple(None if c.validity is None
+                     else shard.pin(c.validity, ctx) for c in rcols_s)
+
+        mode = _dist_stream_mode(lkb, rkb, jt, world)
+        if mode is not None:
+            hash_mode, br = mode
+            # the sort path's key bits are the shuffled key column's own
+            # ordered bits: it rides once
+            lkey, rkey = (None, None) if hash_mode else (
+                table_mod.sole_key_index(lcols2, lcols_s, lidx),
+                table_mod.sole_key_index(rcols2, rcols_s, ridx))
+            a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval,
+                                                   jt, lkey, rkey)
+            table_mod.count_plan_sort(lkb, (False,) * len(lkb),
+                                      len(ldat) + len(rdat), a_desc,
+                                      b_desc, hash_mode, br)
+        _sp.set(key_lanes=len(lkb),
+                hash_mode=bool(mode is not None and mode[0]))
+
     res = None
-    mode = _dist_stream_mode(lkb, rkb, jt, world)
+    rows_out = None
     if mode is not None:
-        hash_mode, br = mode
-        # the sort path's key bits are the shuffled key column's own
-        # ordered bits: it rides once
-        lkey, rkey = (None, None) if hash_mode else (
-            table_mod.sole_key_index(lcols2, lcols_s, lidx),
-            table_mod.sole_key_index(rcols2, rcols_s, ridx))
-        a_desc, b_desc = _join.plan_lane_descs(ldat, lval, rdat, rval, jt,
-                                               lkey, rkey)
-        table_mod.count_plan_sort(lkb, (False,) * len(lkb),
-                                  len(ldat) + len(rdat), a_desc, b_desc,
-                                  hash_mode, br)
         with _phase("distributed_join.plan", seq):
             rep_counts, counts_dev, a_streams, b_streams = \
                 _join_plan_stream_fn(ctx.mesh, jt, len(lkb), a_desc,
@@ -1076,21 +1095,24 @@ def distributed_join(left: Table, right: Table,
             _counter("cylon_collective_launches_total").inc()
             cm = np.asarray(
                 _host_fetch("join.plan", rep_counts)).reshape(world, -1)
-        if not (hash_mode and int(cm[:, 3].sum()) > 0):
-            cap_e = _join.stream_expand_capacity(int(cm[:, 0].max()), br)
+            collided = hash_mode and int(cm[:, 3].sum()) > 0
+        if not collided:
             with _phase("distributed_join.materialize", seq):
+                cap_e = _join.stream_expand_capacity(int(cm[:, 0].max()),
+                                                     br)
                 res = _join_mat_stream_fn(
                     ctx.mesh, jt, cap_e, a_desc, b_desc, br)(
                     counts_dev, a_streams, b_streams,
                     ldat, lval, rdat, rval)
+                rows_out = int(cm[:, 0].sum())
         # else: 64-bit hash collision — recompute via the exact XLA plan
 
     if res is not None:
         lod, lov, rod, rov, emit, lidx_o, ridx_o = res
     else:
-        table_mod.count_plan_sort(lkb, (False,) * len(lkb),
-                                  len(ldat) + len(rdat))
         with _phase("distributed_join.plan", seq):
+            table_mod.count_plan_sort(lkb, (False,) * len(lkb),
+                                      len(ldat) + len(rdat))
             counts2, lo, m, bperm, un_mask = _join_plan_fn(ctx.mesh, jt)(
                 lkb, lkv, lemit, rkb, rkv, remit)
             # replicated counts-gather: a counted collective dispatch
@@ -1102,14 +1124,16 @@ def distributed_join(left: Table, right: Table,
             # shards share one program)
             counts = np.asarray(
                 _host_fetch("join.plan", counts2)).reshape(world, 2)
-            _annotate(rows_out=int(counts[:, 0].sum()))
-        # bucket_cap, not util.capacity: these caps are cache-key
-        # parameters of _join_mat_fn — 1 bucket per octave bounds the
-        # recompile count under varied cardinalities (specialization
-        # analysis); padding rows are masked by emit, results identical
-        cap_p = _bucket_cap(int(counts[:, 0].max()))
-        cap_u = _bucket_cap(int(counts[:, 1].max())) \
-            if jt == _join.JoinType.FULL_OUTER else 0
+            rows_out = int(counts[:, 0].sum())
+            _annotate(rows_out=rows_out)
+            # bucket_cap, not util.capacity: these caps are cache-key
+            # parameters of _join_mat_fn — 1 bucket per octave bounds the
+            # recompile count under varied cardinalities (specialization
+            # analysis); padding rows are masked by emit, results
+            # identical
+            cap_p = _bucket_cap(int(counts[:, 0].max()))
+            cap_u = _bucket_cap(int(counts[:, 1].max())) \
+                if jt == _join.JoinType.FULL_OUTER else 0
 
         with _span("distributed_join.materialize", seq, world=world,
                    capacity=cap_p + cap_u):
@@ -1117,48 +1141,53 @@ def distributed_join(left: Table, right: Table,
                 ctx.mesh, jt, cap_p, cap_u)(
                 lo, m, bperm, un_mask, aemit, ldat, lval, rdat, rval)
 
-    nl = left_d.column_count
-    cols = _rebuild_columns(lod, lov, lcols_s,
-                            [f"lt-{i}" for i in range(nl)])
-    cols += _rebuild_columns(rod, rov, rcols_s,
-                             [f"rt-{nl + j}" for j in range(right_d.column_count)])
-    # varbytes payload columns: per-shard varlen gather by the
-    # materialized indices (fixed-width lanes carried only the lengths)
-    for i in lvb:
-        vb = _varlen_take_sharded(ctx, lcols_s[i].varbytes, lidx_o)
-        cols[i] = Column(vb.lengths, lcols_s[i].dtype, cols[i].validity,
-                         None, cols[i].name, varbytes=vb)
-    for j in rvb:
-        vb = _varlen_take_sharded(ctx, rcols_s[j].varbytes, ridx_o)
-        cols[nl + j] = Column(vb.lengths, rcols_s[j].dtype,
-                              cols[nl + j].validity, None,
-                              cols[nl + j].name, varbytes=vb)
-    result = Table(cols, ctx, emit)
-    if exact_pairs:
-        result, collided = _exact_post_verify(result, nl, exact_pairs,
-                                              config)
-        if collided:
-            # rare path (an actual 96-bit collision): skip the frees —
-            # the encoded tables share payload columns with the inputs
-            return _exact_dict_redo(left, right, config, exact_pairs)
-    # co-partitioning witness on the OUTPUT: every emitted row sits on
-    # the shard its join-key hash routed it to, so a later shuffle /
-    # pre-partitioned groupby on the same keys can skip its exchange
-    # (the plan optimizer's shuffle-elision hook). Key positions map
-    # straight through (left columns first); dtypes come from the
-    # ALIGNED columns — if alignment promoted, the signature's dtype
-    # string won't match the output column's and the witness correctly
-    # never fires. Outer sides with unmatched null keys invalidate the
-    # witness for that side.
-    if jt in (_join.JoinType.INNER, _join.JoinType.LEFT):
-        result._hash_partitioned = shard.partition_signature(
-            lcols2, tuple(lidx), world)
-    elif jt == _join.JoinType.RIGHT:
-        result._hash_partitioned = shard.partition_signature(
-            rcols2, tuple(nl + j for j in ridx), world)
-    left._free_if_unretained()
-    right._free_if_unretained()
-    return _ledger.track(result, "distributed_join")
+    with _span("distributed_join.finish", seq, rows_out=rows_out):
+        nl = left_d.column_count
+        cols = _rebuild_columns(lod, lov, lcols_s,
+                                [f"lt-{i}" for i in range(nl)])
+        cols += _rebuild_columns(
+            rod, rov, rcols_s,
+            [f"rt-{nl + j}" for j in range(right_d.column_count)])
+        # varbytes payload columns: per-shard varlen gather by the
+        # materialized indices (fixed-width lanes carried only the
+        # lengths)
+        for i in lvb:
+            vb = _varlen_take_sharded(ctx, lcols_s[i].varbytes, lidx_o)
+            cols[i] = Column(vb.lengths, lcols_s[i].dtype,
+                             cols[i].validity, None, cols[i].name,
+                             varbytes=vb)
+        for j in rvb:
+            vb = _varlen_take_sharded(ctx, rcols_s[j].varbytes, ridx_o)
+            cols[nl + j] = Column(vb.lengths, rcols_s[j].dtype,
+                                  cols[nl + j].validity, None,
+                                  cols[nl + j].name, varbytes=vb)
+        result = Table(cols, ctx, emit)
+        if exact_pairs:
+            result, collided = _exact_post_verify(result, nl, exact_pairs,
+                                                  config)
+            if collided:
+                # rare path (an actual 96-bit collision): skip the frees
+                # — the encoded tables share payload columns with the
+                # inputs
+                return _exact_dict_redo(left, right, config, exact_pairs)
+        # co-partitioning witness on the OUTPUT: every emitted row sits
+        # on the shard its join-key hash routed it to, so a later shuffle
+        # / pre-partitioned groupby on the same keys can skip its
+        # exchange (the plan optimizer's shuffle-elision hook). Key
+        # positions map straight through (left columns first); dtypes
+        # come from the ALIGNED columns — if alignment promoted, the
+        # signature's dtype string won't match the output column's and
+        # the witness correctly never fires. Outer sides with unmatched
+        # null keys invalidate the witness for that side.
+        if jt in (_join.JoinType.INNER, _join.JoinType.LEFT):
+            result._hash_partitioned = shard.partition_signature(
+                lcols2, tuple(lidx), world)
+        elif jt == _join.JoinType.RIGHT:
+            result._hash_partitioned = shard.partition_signature(
+                rcols2, tuple(nl + j for j in ridx), world)
+        left._free_if_unretained()
+        right._free_if_unretained()
+        return _ledger.track(result, "distributed_join")
 
 
 def _exact_post_verify(res: Table, nl: int, pairs, config):

@@ -48,7 +48,6 @@ from ..ops import hash as _hash
 from ..ops import tpu_kernels as _tpuk
 from ..resilience import inject as _inject
 from ..resilience import retry as _retry
-from ..telemetry import REGISTRY as _REGISTRY
 from ..telemetry import counted_cache, counter as _counter, \
     host_fetch as _host_fetch, phase as _phase, span as _span
 from ..telemetry import knobs as _knobs
@@ -72,11 +71,6 @@ MAX_BLOCK = 1 << 22
 # dwarfs any remaining overlap win (the 1<<16 MAX_BLOCK measurement
 # above is the same lesson: round count, not block memory, binds).
 MAX_CHUNKS = 64
-
-# cylon_exchange_overlap_ratio buckets: fraction of an exchange's
-# programs issued while earlier chunk work was still in flight
-# ((programs-1)/programs) — 0.0 is single-shot, ->1.0 is a deep pipeline
-OVERLAP_BUCKETS = (0.0, 0.25, 0.5, 0.75, 0.875, 0.9375, 1.0)
 
 
 def _shard_map_for(part, kernel, mesh, in_specs, out_specs):
@@ -636,15 +630,12 @@ def _dispatch_chunked(ctx: CylonContext, block: int, cb: int,
 
 def _record_chunked(sp, chunks: int, cb: int) -> None:
     """Chunk-pipeline observability: per-exchange span attrs plus the
-    cylon_exchange_chunks_total counter and the overlap-ratio histogram
-    ((chunks-1)/chunks — the fraction of the pipeline's programs, one a
-    chunk, issued while earlier chunk work was still in flight)."""
-    ratio = (chunks - 1) / chunks
+    cylon_exchange_chunks_total counter. ``overlap_ratio`` is
+    (chunks-1)/chunks — the fraction of the pipeline's programs, one a
+    chunk, issued while earlier chunk work was still in flight."""
     sp.set(chunks=chunks, chunk_block=cb,
-           overlap_ratio=round(ratio, 4))
+           overlap_ratio=round((chunks - 1) / chunks, 4))
     _counter("cylon_exchange_chunks_total").inc(chunks)
-    _REGISTRY.histogram("cylon_exchange_overlap_ratio",
-                        buckets=OVERLAP_BUCKETS).observe(ratio)
 
 
 @counted_cache
@@ -676,59 +667,64 @@ def exchange_pair(payload1, targets1, emit1, counts1,
     exchanges. Returns (result1, result2) where each result is the
     exchange() 4-tuple."""
     world = ctx.get_world_size()
-    budget = ctx.memory_pool.comm_budget_bytes()
-    # buffer_factor=8: the pair program holds BOTH tables' comm buffers
-    ok1, b1, _mb1 = _padded_route(counts1, payload1, world, budget,
-                                  buffer_factor=8)
-    ok2, b2, _mb2 = _padded_route(counts2, payload2, world, budget,
-                                  buffer_factor=8)
-    if ok1 and ok2 and (
-            _chunk_plan(b1, world, _payload_row_bytes(payload1))[1] > 1
-            or _chunk_plan(b2, world,
-                           _payload_row_bytes(payload2))[1] > 1):
-        # either side is big enough to chunk: the overlapped pipeline
-        # (each side chunked through exchange(), counts already fetched)
-        # beats the monolithic pair program whose send+recv stacks for
-        # BOTH tables would be live at once
+    with _span("shuffle.route", world=world, tables=2) as rsp:
+        budget = ctx.memory_pool.comm_budget_bytes()
+        # buffer_factor=8: the pair program holds BOTH tables' comm
+        # buffers
+        ok1, b1, _mb1 = _padded_route(counts1, payload1, world, budget,
+                                      buffer_factor=8)
+        ok2, b2, _mb2 = _padded_route(counts2, payload2, world, budget,
+                                      buffer_factor=8)
+        chunks = max(
+            _chunk_plan(b1, world, _payload_row_bytes(payload1))[1],
+            _chunk_plan(b2, world, _payload_row_bytes(payload2))[1]) \
+            if ok1 and ok2 else 1
+        # either side big enough to chunk: the overlapped pipeline (each
+        # side chunked through exchange(), counts already fetched) beats
+        # the monolithic pair program whose send+recv stacks for BOTH
+        # tables would be live at once; a side that cannot go padded
+        # sends both through exchange() too
+        fused = ok1 and ok2 and chunks == 1
+        rsp.set(mode="pair" if fused else "each", block=max(b1, b2),
+                chunks=chunks)
+        if fused:
+            seq = ctx.get_next_sequence()
+            rows = (int(counts1.sum()) if counts1 is not None else 0) \
+                + (int(counts2.sum()) if counts2 is not None else 0)
+            nbytes = _payload_nbytes(payload1) + _payload_nbytes(payload2)
+            # per-side histograms carry each table's own row width; the
+            # span attributes carry the COMBINED per-destination totals
+            # (what each shard actually absorbs from the fused program)
+            _skew.observe_exchange(counts1, _payload_row_bytes(payload1),
+                                   world * world * b1)
+            _skew.observe_exchange(counts2, _payload_row_bytes(payload2),
+                                   world * world * b2)
+            pair_stats = _skew.SkewStats.from_counts(
+                np.asarray(counts1) + np.asarray(counts2)) \
+                if counts1 is not None and counts2 is not None else None
+            part1 = _partition_path(ctx.mesh, world, payload1)
+            part2 = _partition_path(ctx.mesh, world, payload2)
+    if not fused:
         return (exchange(payload1, targets1, emit1, ctx, counts=counts1),
                 exchange(payload2, targets2, emit2, ctx, counts=counts2))
-    if ok1 and ok2:
-        seq = ctx.get_next_sequence()
-        rows = (int(counts1.sum()) if counts1 is not None else 0) \
-            + (int(counts2.sum()) if counts2 is not None else 0)
-        nbytes = _payload_nbytes(payload1) + _payload_nbytes(payload2)
-        # per-side histograms carry each table's own row width; the
-        # span attributes carry the COMBINED per-destination totals
-        # (what each shard actually absorbs from the fused program)
-        _skew.observe_exchange(counts1, _payload_row_bytes(payload1),
-                               world * world * b1)
-        _skew.observe_exchange(counts2, _payload_row_bytes(payload2),
-                               world * world * b2)
-        pair_stats = _skew.SkewStats.from_counts(
-            np.asarray(counts1) + np.asarray(counts2)) \
-            if counts1 is not None and counts2 is not None else None
-        part1 = _partition_path(ctx.mesh, world, payload1)
-        part2 = _partition_path(ctx.mesh, world, payload2)
-        with _span("shuffle.exchange_pair", seq, world=world,
-                   mode="padded", rows=rows, bytes_moved=nbytes,
-                   block=max(b1, b2)) as sp:
-            if pair_stats is not None:
-                sp.set(**pair_stats.span_attrs())
-            # one decision per side; the fused program partitions both
-            _record_partition(sp, part1, part2)
-            res = _launch_exchange(
-                lambda: _exchange_padded_pair_fn(ctx.mesh, b1, b2,
-                                                 part1, part2)(
-                    payload1, targets1, emit1, payload2, targets2,
-                    emit2))
-        _record_exchange(rows, nbytes)
-        out1, emit1_o, ci1, out2, emit2_o, ci2 = res
-        return ((out1, emit1_o, world * b1,
-                 {"mode": "padded", "block": b1, "counts_in": ci1}),
-                (out2, emit2_o, world * b2,
-                 {"mode": "padded", "block": b2, "counts_in": ci2}))
-    return (exchange(payload1, targets1, emit1, ctx, counts=counts1),
-            exchange(payload2, targets2, emit2, ctx, counts=counts2))
+    with _span("shuffle.exchange_pair", seq, world=world,
+               mode="padded", rows=rows, bytes_moved=nbytes,
+               block=max(b1, b2)) as sp:
+        if pair_stats is not None:
+            sp.set(**pair_stats.span_attrs())
+        # one decision per side; the fused program partitions both
+        _record_partition(sp, part1, part2)
+        res = _launch_exchange(
+            lambda: _exchange_padded_pair_fn(ctx.mesh, b1, b2,
+                                             part1, part2)(
+                payload1, targets1, emit1, payload2, targets2,
+                emit2))
+    _record_exchange(rows, nbytes)
+    out1, emit1_o, ci1, out2, emit2_o, ci2 = res
+    return ((out1, emit1_o, world * b1,
+             {"mode": "padded", "block": b1, "counts_in": ci1}),
+            (out2, emit2_o, world * b2,
+             {"mode": "padded", "block": b2, "counts_in": ci2}))
 
 
 @counted_cache
@@ -1017,22 +1013,32 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
             ("one", id(ctx.mesh), id(targets), id(emit)),
             (targets, emit),
             lambda: _retry.run_retryable("exchange.count", compute))
-    max_pair = int(counts.max()) if counts.size else 0
-    recv_max = int(counts.sum(axis=0).max()) if counts.size else 0
-    budget = ctx.memory_pool.comm_budget_bytes()
-    padded_ok, block_p, mb = _padded_route(counts, payload, world, budget,
-                                           buffer_factor=4,
-                                           max_block=max_block)
-    cap_padded = world * block_p
-    cap_compact = _pow2(recv_max)
-    rows_live = int(counts.sum()) if counts.size else 0
-    nbytes = _payload_nbytes(payload)
-    row_bytes = _payload_row_bytes(payload)
-    # skew observability rides the ALREADY-FETCHED count matrix: zero
-    # extra device→host transfers (None on a 1-wide mesh)
-    skew_stats = _skew.observe_exchange(
-        counts, row_bytes,
-        world * (cap_padded if padded_ok else cap_compact))
+    with _span("shuffle.route", seq, world=world, tables=1) as rsp:
+        max_pair = int(counts.max()) if counts.size else 0
+        recv_max = int(counts.sum(axis=0).max()) if counts.size else 0
+        budget = ctx.memory_pool.comm_budget_bytes()
+        padded_ok, block_p, mb = _padded_route(counts, payload, world,
+                                               budget, buffer_factor=4,
+                                               max_block=max_block)
+        cap_padded = world * block_p
+        cap_compact = _pow2(recv_max)
+        rows_live = int(counts.sum()) if counts.size else 0
+        nbytes = _payload_nbytes(payload)
+        row_bytes = _payload_row_bytes(payload)
+        # skew observability rides the ALREADY-FETCHED count matrix: zero
+        # extra device→host transfers (None on a 1-wide mesh)
+        skew_stats = _skew.observe_exchange(
+            counts, row_bytes,
+            world * (cap_padded if padded_ok else cap_compact))
+        if padded_ok:
+            block = block_p
+            part = _partition_path(ctx.mesh, world, payload)
+            cb, chunks = _chunk_plan(block_p, world, row_bytes)
+        else:
+            block = min(block_p, mb)
+            chunks = 1
+        rsp.set(mode="padded" if padded_ok else "compact", block=block,
+                chunks=chunks)
     with _span("shuffle.exchange", seq, world=world,
                mode="padded" if padded_ok else "compact",
                rows=rows_live, bytes_moved=nbytes) as sp:
@@ -1040,9 +1046,7 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
             sp.set(**skew_stats.span_attrs())
         if padded_ok:
             sp.set(block=block_p)
-            part = _partition_path(ctx.mesh, world, payload)
             _record_partition(sp, part)
-            cb, chunks = _chunk_plan(block_p, world, row_bytes)
             if chunks > 1:
                 out, new_emit, counts_in = _dispatch_chunked(
                     ctx, block_p, cb, chunks, payload, targets, emit,
@@ -1058,7 +1062,6 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
             _record_exchange(rows_live, nbytes)
             return out, new_emit, cap_padded, {
                 "mode": "padded", "block": block_p, "counts_in": counts_in}
-        block = min(block_p, mb)
         # pow2 round count bounds the compile cache to O(log^3) programs
         rounds = _pow2(-(-max(max_pair, 1) // block))
         sp.set(block=block, rounds=rounds)

@@ -7,7 +7,8 @@ metric file reads, learns it from tier-1 and not as a ``null`` in the
 ledger, each cell runs here through every phase of ``benchmarks/run.py``
 (``--scale``: off a TPU the run ends with exit 1 and no result line),
 sound and as the control, and every metric file that names a counter of
-the program finds that counter's family after the run.
+the program finds that counter's family after the run, every metric file
+that names a span of the program the span, closed.
 
 Each run is a child process, as on the chip: x64 off and four virtual CPU
 devices instead of this suite's x64 on and eight, its counters its own,
@@ -55,6 +56,8 @@ print("REHEARSED " + json.dumps({
     "attempted": result["attempted"], "failed": result["failed"],
     "series": sorted(k for k, v in snap.items()
                      if isinstance(v, (int, float))),
+    "phases": sorted(k.split('"')[1] for k in snap
+                     if k.startswith("cylon_phase_latency_ms{phase=")),
     "packed": snap.get("cylon_groupby_sort_packed_columns_total", 0),
     "sorted": snap.get("cylon_groupby_sort_operands_total", 0)}))
 """
@@ -111,18 +114,23 @@ def test_groupby_q5_rehearses_packed():
     assert said["packed"] == 0 and said["sorted"] == 0
 
 
+def _metric_files():
+    """(metric, its file's content) for every metric of the benchmark."""
+    for path in sorted(glob.glob(os.path.join(
+            ROOT, BENCH["paths"][0], "metrics", "*.json"))):
+        with open(path) as f:
+            yield os.path.splitext(os.path.basename(path))[0], json.load(f)
+
+
 def _counter_metrics():
     """(metric, the counter families its file names) for every metric file
     whose reducer reads the program's counters."""
     found = []
-    for path in sorted(glob.glob(os.path.join(
-            ROOT, BENCH["paths"][0], "metrics", "*.json"))):
-        with open(path) as f:
-            spec = json.load(f)
+    for metric, spec in _metric_files():
         names = [spec[k] for k in ("prefix", "numerator", "denominator")
                  if k in spec]
         if names:
-            found.append((os.path.splitext(os.path.basename(path))[0],
+            found.append((metric,
                           sorted({n.split("{")[0] for n in names})))
     return found
 
@@ -142,3 +150,40 @@ def test_metric_finds_its_counter(metric, families):
     for family in families:
         assert any(s.split("{")[0] == family for s in said["series"]), \
             f"{metric}: no series of {family} after {cell}"
+
+
+def _span_metrics():
+    """(metric, the span names its file gives) for every metric file whose
+    reducer reads the program's host spans: ``span`` (one name or a list),
+    ``spans``, ``less``."""
+    found = []
+    for metric, spec in _metric_files():
+        names = []
+        for key in ("span", "spans", "less"):
+            value = spec.get(key, [])
+            names += [value] if isinstance(value, str) else value
+        if names:
+            found.append((metric, sorted(set(names))))
+    return found
+
+
+SPAN_METRICS = _span_metrics()
+
+
+@pytest.mark.parametrize("metric,spans", SPAN_METRICS,
+                         ids=[m for m, _ in SPAN_METRICS])
+def test_metric_finds_its_span(metric, spans):
+    """After the rehearsal of the first cell that lists the metric, the
+    program has closed every span the metric's file names: each fed
+    ``cylon_phase_latency_ms{phase=}`` under its name without the trace's
+    ``cylon:``. A name that ends in a dot is the start of a family
+    (``cylon:sync.``: any host fetch)."""
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    cell = entry.get("workloads", CELLS)[0]
+    said, _out = rehearse(cell, 0)
+    for name in spans:
+        assert name.startswith("cylon:"), f"{metric}: {name}"
+        name = name[len("cylon:"):]
+        assert any(p.startswith(name) if name.endswith(".") else p == name
+                   for p in said["phases"]), \
+            f"{metric}: no span {name} closed in {cell}: {said['phases']}"
