@@ -182,6 +182,20 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+_KEY_FORMS = (("plain", "int32", False, True), ("lanes", 2, False),
+              ("quad", False))
+
+
+def _key_program_inputs(rows):
+    """Abstract operands of `_KEY_FORMS` (parallel/dist_ops._key_operands):
+    data + validity; two word lanes + byte lengths; h1, h2, h3, length."""
+    import jax.numpy as jnp
+
+    i32, u32 = jnp.int32, jnp.uint32
+    return (rows(i32, jnp.bool_), rows(u32, u32, i32),
+            rows(u32, u32, u32, u32))
+
+
 def default_entry_points() -> List[EntryPoint]:
     """Abstract-input catalog for every traceable kernel factory in
     `parallel/`. Geometry: world=4 shards, 16 rows/shard (n=64 global),
@@ -286,6 +300,21 @@ def default_entry_points() -> List[EntryPoint]:
         EntryPoint(
             "word_lanes", do, lambda m: D(m)._word_lanes_fn(m, 4),
             lambda m: vb(), factory="_word_lanes_fn"),
+        EntryPoint(
+            # one key program a stage (dist_ops "key programs"): a masked
+            # int32 column, short varbytes as 2 word lanes + lengths, the
+            # content-hash quad of long varbytes
+            "partition_targets_program", do,
+            lambda m: D(m)._partition_targets_program_fn(
+                m, _KEY_FORMS, True),
+            lambda m: (_key_program_inputs(rows),),
+            factory="_partition_targets_program_fn"),
+        EntryPoint(
+            "key_bits_program", do,
+            lambda m: D(m)._key_bits_program_fn(
+                m, _KEY_FORMS, (True, False, False)),
+            lambda m: (_key_program_inputs(rows),),
+            factory="_key_bits_program_fn"),
         EntryPoint(
             "word_targets", do, lambda m: D(m)._word_targets_fn(m),
             lambda m: vb() + rows(i32, b), factory="_word_targets_fn"),

@@ -34,8 +34,8 @@ from jax import shard_map
 from .. import dtypes
 from ..context import CylonContext
 from ..data import table as table_mod
-from ..data.column import Column, unify_dictionaries
-from ..data.strings import pair_k_words as _pair_k
+from ..data.column import Column, refuse_planes, unify_dictionaries
+from ..data.strings import EXACT_KEY_WORDS, pair_k_words as _pair_k
 from ..data.table import Table
 from ..ops import groupby as _groupby
 from ..ops import hash as _hash
@@ -139,75 +139,191 @@ def _lanes_hash(lanes: Sequence[jnp.ndarray], ln_u32) -> jnp.ndarray:
     return _hash.fmix32(h)
 
 
-def _dist_col_keys(ctx: CylonContext, c: Column, k_words: int = None):
-    """One column's (key bit arrays, partition hash). Short varbytes
-    (≤ EXACT_KEY_WORDS words, the pair max when ``k_words`` is passed)
-    use raw word lanes + length — byte-exact; longer rows use the
-    content-hash quad. Plain columns use ordered bits."""
-    from ..data.strings import EXACT_KEY_WORDS
+# ---------------------------------------------------------------------------
+# key programs. What a distributed operator computes elementwise from its
+# key columns on the host's side of the exchange is ONE named program a
+# call: the partition targets (and the emit mask of a table without a row
+# mask) before it, the key bits and the combined key validity after it.
+# An eager jnp operation on a sharded column is a program of its own that
+# every chip waits for, ~0.84 ms of host each on four chips (PERF.md
+# section 6, PR 39). A program is compiled for what the host reads off
+# the key columns (`_key_form`), never for a cell or a knob.
+# ---------------------------------------------------------------------------
 
-    if c.is_varbytes:
-        vb = c.varbytes
-        k = vb.max_words if k_words is None else max(int(k_words),
-                                                     vb.max_words)
-        if k <= EXACT_KEY_WORDS:
-            lanes = _dist_word_lanes(ctx, c, k)
-            ln = vb.lengths.astype(jnp.uint32)
-            h1 = _lanes_hash(lanes, ln)
-            if c.validity is not None:
-                h1 = jnp.where(c.validity, h1, jnp.uint32(0x9E3779B9))
-            return lanes + [ln], h1
-        q = _dist_string_keys(ctx, c)
-        h1 = q[0]
-        if c.validity is not None:
-            h1 = jnp.where(c.validity, h1, jnp.uint32(0x9E3779B9))
-        return list(q), h1
-    return [_order.sort_keys([c])[0]], _hash.hash_column(c)
+_DICTIONARY = np.empty(0, object)  # marks a traced column as dictionary codes
+
+
+def _key_form(c: Column, k_words: int = None) -> tuple:
+    """What a key program is compiled for, read off one key column:
+    ``("plain", storage dtype, dictionary codes?, masked?)``, ``("lanes",
+    k, masked?)`` for short varbytes (≤ EXACT_KEY_WORDS words, the pair
+    max when ``k_words`` is passed: raw word lanes + length, byte-exact)
+    or ``("quad", masked?)`` for longer rows (the content-hash quad)."""
+    masked = c.validity is not None
+    if not c.is_varbytes:
+        return ("plain", str(c.data.dtype), c.is_string, masked)
+    k = c.varbytes.max_words if k_words is None \
+        else max(int(k_words), c.varbytes.max_words)
+    return ("lanes", k, masked) if k <= EXACT_KEY_WORDS \
+        else ("quad", masked)
+
+
+def _key_operands(ctx: CylonContext, c: Column, form: tuple) -> tuple:
+    """One key column's arrays as its key program takes them, each on
+    the row sharding: the data, or the word lanes and the byte lengths,
+    or the content-hash quad (both from named per-shard programs), and
+    the validity mask where there is one."""
+    if form[0] == "plain":
+        ops = [shard.pin(c.data, ctx)]
+    elif form[0] == "lanes":
+        ops = _dist_word_lanes(ctx, c, form[1]) \
+            + [shard.pin(c.varbytes.lengths, ctx)]
+    else:
+        ops = list(_dist_string_keys(ctx, c))
+    if form[-1]:
+        ops.append(shard.pin(c.validity, ctx))
+    return tuple(ops)
+
+
+def _key_inputs(ctx: CylonContext, cols: Sequence[Column],
+                paired: Sequence[Column] = None):
+    """(forms, operands) of the key columns. ``paired``: the other
+    side's aligned key columns, so both sides take matching lane
+    counts."""
+    refuse_planes(cols, "a key across chips")
+    forms = tuple(
+        _key_form(c, _pair_k(c, paired[j]) if paired is not None else None)
+        for j, c in enumerate(cols))
+    return forms, tuple(_key_operands(ctx, c, f)
+                        for c, f in zip(cols, forms))
+
+
+def _bits_as_given(form: tuple) -> int:
+    """How many of a form's leading operands ARE key bit arrays as they
+    stand (the word lanes, the quad): a key-bits program does not copy
+    them through."""
+    if form[0] == "lanes":
+        return form[1]
+    return 4 if form[0] == "quad" else 0
+
+
+def _traced_col_keys(form: tuple, ops: Sequence):
+    """One column's (key bit arrays it has to compute, partition hash,
+    validity or None), traced inside a key program. Plain columns use
+    ordered bits and ops/hash.hash_column as they are, so the host-side
+    twins (shard.host_partition_arrays, partition_signature) keep
+    agreeing with the device on every row's shard."""
+    valid = ops[-1] if form[-1] else None
+    if form[0] == "plain":
+        c = Column(ops[0], None, valid, _DICTIONARY if form[2] else None)
+        return [_order.sort_keys([c])[0]], _hash.hash_column(c), valid
+    if form[0] == "lanes":
+        k = form[1]
+        ln = ops[k].astype(jnp.uint32)
+        bits, h1 = [ln], _lanes_hash(ops[:k], ln)
+    else:
+        bits, h1 = [], ops[0]
+    if valid is not None:
+        h1 = jnp.where(valid, h1, jnp.uint32(0x9E3779B9))
+    return bits, h1, valid
+
+
+@counted_cache
+def _partition_targets_program_fn(mesh, forms: tuple, with_emit: bool):
+    """Per-row int32 shard targets of key columns of the given forms, on
+    the row sharding: every column's partition hash, the ops/hash.
+    hash_columns combine scheme, the modulo. ``with_emit``: also the
+    all-ones emit mask of a table that has no row mask."""
+    spec = P(mesh.axis_names[0])
+    world = mesh.devices.size
+
+    def kernel(cols):
+        h = None
+        for form, ops in zip(forms, cols):
+            hc = _traced_col_keys(form, ops)[1]
+            h = hc if h is None else h * np.uint32(31) + hc
+        targets = (_hash.fmix32(h) % np.uint32(world)).astype(jnp.int32)
+        if with_emit:
+            return targets, jnp.ones(targets.shape, bool)
+        return (targets,)
+
+    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,),
+                             out_specs=spec))
+
+
+@counted_cache
+def _key_bits_program_fn(mesh, forms: tuple, null_lanes: tuple):
+    """The key bit arrays a per-shard join / group / set kernel compares
+    and the combined key validity, on the row sharding. Only what has to
+    be computed comes out (`_bits_as_given`); no partition hash does:
+    no caller past the exchange uses one. ``null_lanes[j]``: column j's
+    validity rides as a uint8 key lane after its bits (set operations:
+    nulls compare equal)."""
+    spec = P(mesh.axis_names[0])
+
+    def kernel(cols):
+        out, kv = [], None
+        for form, ops, lane in zip(forms, cols, null_lanes):
+            bits, _h1, valid = _traced_col_keys(form, ops)
+            if valid is None:
+                valid = jnp.ones(ops[0].shape, bool)
+            out.append(tuple(bits)
+                       + ((valid.astype(jnp.uint8),) if lane else ()))
+            kv = valid if kv is None else (kv & valid)
+        return tuple(out), kv
+
+    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,),
+                             out_specs=spec))
 
 
 def _dist_key_bits(ctx: CylonContext, cols: Sequence[Column],
-                   paired: Sequence[Column] = None):
-    """Key bit arrays, combined key-validity, and per-column partition
-    hashes for per-shard join/group kernels. ``paired``: the other
-    side's aligned key columns (joins) so both sides emit matching lane
-    counts and partition hashes."""
+                   paired: Sequence[Column] = None,
+                   null_lanes: Sequence[bool] = None):
+    """Key bit arrays and combined key-validity for per-shard join /
+    group / set kernels, from ONE program. ``paired``: the other side's
+    aligned key columns (joins, set operations) so both sides emit
+    matching lane counts. ``null_lanes``: see `_key_bits_program_fn`."""
+    forms, operands = _key_inputs(ctx, cols, paired)
+    lanes = tuple(bool(x) for x in null_lanes) if null_lanes is not None \
+        else (False,) * len(forms)
+    _counter("cylon_key_programs_total", {"stage": "keybits"}).inc()
+    computed, kv = _key_bits_program_fn(ctx.mesh, forms, lanes)(operands)
     bits: list = []
-    h1s: list = []
-    kv = None
-    for j, c in enumerate(cols):
-        kw = _pair_k(c, paired[j]) if paired is not None else None
-        b, h1 = _dist_col_keys(ctx, c, kw)
-        bits.extend(b)
-        h1s.append(h1)
-        v = c.valid_mask()
-        kv = v if kv is None else (kv & v)
-    return tuple(bits), kv, h1s
+    for form, ops, made in zip(forms, operands, computed):
+        bits.extend(ops[:_bits_as_given(form)])
+        bits.extend(made)
+    return tuple(bits), kv
 
 
-def _targets_from_hashes(ctx: CylonContext, h1s: Sequence[jnp.ndarray]
-                         ) -> jnp.ndarray:
-    """Combine per-column row hashes into a shard target (the
-    ops/hash.hash_columns combine scheme)."""
-    world = ctx.get_world_size()
-    h = None
-    for hc in h1s:
-        h = hc if h is None else h * np.uint32(31) + hc
-    h = _hash.fmix32(h)
-    return (h % np.uint32(world)).astype(jnp.int32)
+def _dispatch_targets(ctx: CylonContext, cols: Sequence[Column],
+                      paired: Sequence[Column], with_emit: bool) -> tuple:
+    """ONE dispatch of the targets program: ``(targets,)`` or ``(targets,
+    all-ones emit mask)``, on the row sharding."""
+    forms, operands = _key_inputs(ctx, cols, paired)
+    _counter("cylon_key_programs_total", {"stage": "targets"}).inc()
+    return _partition_targets_program_fn(ctx.mesh, forms, with_emit)(
+        operands)
 
 
 def _partition_targets_dist(ctx: CylonContext, cols: Sequence[Column],
                             paired: Sequence[Column] = None
                             ) -> jnp.ndarray:
-    """Per-row target shard for mixed plain/varbytes key columns. Plain
-    columns use the elementwise hash (sharding-transparent); varbytes
-    hash per shard. ``paired``: the other side's aligned key columns so
-    both sides hash with matching lane counts."""
-    h1s = []
-    for j, c in enumerate(cols):
-        kw = _pair_k(c, paired[j]) if paired is not None else None
-        h1s.append(_dist_col_keys(ctx, c, kw)[1])
-    return _targets_from_hashes(ctx, h1s)
+    """Per-row target shard for mixed plain/varbytes key columns (plain
+    columns hash elementwise, varbytes per shard). ``paired``: the other
+    side's aligned key columns so both sides hash with matching lane
+    counts."""
+    return _dispatch_targets(ctx, cols, paired, False)[0]
+
+
+def _targets_and_emit(ctx: CylonContext, t: Table, cols: Sequence[Column],
+                      paired: Sequence[Column] = None):
+    """(targets, emit) of an exchange of ``t`` by the key columns
+    ``cols``, both on the row sharding: a table without a row mask gets
+    its all-ones emit mask out of the targets' own program."""
+    if t.row_mask is not None:
+        return (_partition_targets_dist(ctx, cols, paired),
+                shard.pin(t.row_mask, ctx))
+    return _dispatch_targets(ctx, cols, paired, True)
 
 
 @counted_cache
@@ -805,9 +921,8 @@ def shuffle(table: Table, hash_columns: Sequence,
     salted = salted and salt >= 2
     if sig is not None and t._hash_partitioned == sig and not salted:
         return t
-    targets = shard.pin(_partition_targets_dist(
-        ctx, [t._columns[i] for i in idxs]), ctx)
-    emit = shard.pin(t.emit_mask(), ctx)
+    targets, emit = _targets_and_emit(
+        ctx, t, [t._columns[i] for i in idxs])
     if salted:
         warn = float(_knobs.get("CYLON_SKEW_WARN_FACTOR"))
         targets, counts, raw = salted_exchange_targets(
@@ -1002,9 +1117,7 @@ def distributed_join(left: Table, right: Table,
             # measured 813 ms -> the bare-columns exchange cost at 16M
             with _span("distributed_join.targets", seq, side=side,
                        key_columns=len(kcols)):
-                targets = shard.pin(
-                    _partition_targets_dist(ctx, kcols, other), ctx)
-                emit = shard.pin(t.emit_mask(), ctx)
+                targets, emit = _targets_and_emit(ctx, t, kcols, other)
             plan.append(("exchange", t, targets, emit))
         # both sides exchanging: ONE fused count program + ONE host sync
         # covers both shuffles (the reference pays a header phase per
@@ -1044,12 +1157,8 @@ def distributed_join(left: Table, right: Table,
         right_s = Table(list(rcols_all), ctx, rmask)
         lcols2, rcols2 = _align_key_columns_dist(ctx, left_s, right_s,
                                                  lidx, ridx)
-        lkb, lkv, _h1s_l = _dist_key_bits(ctx, lcols2, rcols2)
-        rkb, rkv, _h1s_r = _dist_key_bits(ctx, rcols2, lcols2)
-        lkb = tuple(shard.pin(b, ctx) for b in lkb)
-        rkb = tuple(shard.pin(b, ctx) for b in rkb)
-        lkv = shard.pin(lkv, ctx)
-        rkv = shard.pin(rkv, ctx)
+        lkb, lkv = _dist_key_bits(ctx, lcols2, rcols2)
+        rkb, rkv = _dist_key_bits(ctx, rcols2, lcols2)
         lcols_s, rcols_s = lcols_all, rcols_all
         lvb = [i for i, c in enumerate(lcols_s) if c.is_varbytes]
         rvb = [i for i, c in enumerate(rcols_s) if c.is_varbytes]
@@ -1283,9 +1392,7 @@ def _prep_join_side(ctx: CylonContext, t: Table, cols, other_cols):
     index, lane count) for the rebuild). Shared by the ring join
     (lanes rotate with the visiting block) and the broadcast join
     (lanes gather with the replicated build side)."""
-    bits, kv, _h = _dist_key_bits(ctx, cols, other_cols)
-    bits = tuple(shard.pin(b, ctx) for b in bits)
-    kv = shard.pin(kv, ctx)
+    bits, kv = _dist_key_bits(ctx, cols, other_cols)
     emit = shard.pin(t.emit_mask(), ctx)
     dat = [shard.pin(c.data, ctx) for c in t._columns]
     val = [shard.pin(c.valid_mask(), ctx) for c in t._columns]
@@ -1802,9 +1909,7 @@ def distributed_set_op(left: Table, right: Table,
         for cols, t, other in ((lcols, left_d, rcols),
                                (rcols, right_d, lcols)):
             view = Table(list(cols), ctx, t.row_mask)
-            targets = shard.pin(
-                _partition_targets_dist(ctx, cols, other), ctx)
-            emit = shard.pin(t.emit_mask(), ctx)
+            targets, emit = _targets_and_emit(ctx, t, cols, other)
             sides.append((view, targets, emit))
         cl, cr = count_pair(sides[0][1], sides[0][2],
                             sides[1][1], sides[1][2], ctx)
@@ -1818,19 +1923,10 @@ def distributed_set_op(left: Table, right: Table,
         ctx, Table(list(lcols_s), ctx, lemit),
         Table(list(rcols_s), ctx, remit), all_idx, all_idx)
 
-    def rebits(cols, other, emit):
-        bits = []
-        for ci, c in enumerate(cols):
-            b, _h1 = _dist_col_keys(ctx, c, _pair_k(c, other[ci]))
-            bits.extend(b)
-            if has_validity[ci]:
-                # validity participates in the row key (nulls compare
-                # equal, matching the reference's set-distinct semantics)
-                bits.append(c.valid_mask().astype(jnp.uint8))
-        return tuple(shard.pin(b, ctx) for b in bits)
-
-    lkb = rebits(lcols_s2, rcols_s2, lemit)
-    rkb = rebits(rcols_s2, lcols_s2, remit)
+    # validity participates in the row key (nulls compare equal,
+    # matching the reference's set-distinct semantics)
+    lkb, _lkv = _dist_key_bits(ctx, lcols_s2, rcols_s2, has_validity)
+    rkb, _rkv = _dist_key_bits(ctx, rcols_s2, lcols_s2, has_validity)
     ldat = tuple(shard.pin(c.data, ctx) for c in lcols_s)
     lval = tuple(shard.pin(c.valid_mask(), ctx) for c in lcols_s)
     rdat = tuple(shard.pin(c.data, ctx) for c in rcols_s)
@@ -1906,8 +2002,7 @@ def _groupby_shuffle_agg(ctx: CylonContext, key_columns, value_columns,
                    rows_in=int(emit.shape[0])):
             view = Table(list(key_columns) + list(value_columns), ctx,
                          None)
-            targets = shard.pin(
-                _partition_targets_dist(ctx, key_columns), ctx)
+            targets = _partition_targets_dist(ctx, key_columns)
             out_cols, emit_s, _x = _exchange_table(view, targets, emit,
                                                    ctx)
 
@@ -1916,11 +2011,7 @@ def _groupby_shuffle_agg(ctx: CylonContext, key_columns, value_columns,
     vcols_s = out_cols[nk:]
     # key bits recompute per shard from the shuffled key columns —
     # recomputable lanes never cross the exchange (round-4 review)
-    kbits = []
-    for c in kcols_s:
-        b, _h1 = _dist_col_keys(ctx, c)
-        kbits.extend(b)
-    kbits = tuple(shard.pin(b, ctx) for b in kbits)
+    kbits, _kv = _dist_key_bits(ctx, kcols_s)
     kdat = tuple(shard.pin(c.data, ctx) for c in kcols_s)
     kval = tuple(shard.pin(c.valid_mask(), ctx) for c in kcols_s)
     vdat = tuple(shard.pin(c.data, ctx) for c in vcols_s)
@@ -2022,11 +2113,7 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
             b_ops.append(_groupby.second_phase_op(op))
 
     with _phase("distributed_groupby.pre_aggregate", seq):
-        kbitsA = []
-        for c in key_columns:
-            b, _h1 = _dist_col_keys(ctx, c)
-            kbitsA.extend(b)
-        kbitsA = tuple(shard.pin(b, ctx) for b in kbitsA)
+        kbitsA, _kvA = _dist_key_bits(ctx, key_columns)
         kdatA = tuple(shard.pin(c.data, ctx) for c in key_columns)
         kvalA = tuple(shard.pin(c.valid_mask(), ctx) for c in key_columns)
         vdatA, vvalA = [], []

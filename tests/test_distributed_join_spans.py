@@ -195,3 +195,87 @@ def test_overlap_ratio_is_an_attribute_and_no_histogram(dist_ctx,
         == ex.attrs["chunks"]
     assert not any(k.startswith("cylon_exchange_overlap_ratio")
                    for k in snap)
+
+
+# ---------------------------------------------------------------------------
+# what a join LAUNCHES, read off a profiler trace of the host (PR 39): the
+# dispatches (`PjitFunction(...)` events of the dispatching thread, the
+# outermost of each nest) by the innermost `cylon:` span open at their
+# start, and the programs that ran by name (`hlo_module` of the CPU
+# backend's operation events, the name `XLA Modules` carries on a chip)
+# ---------------------------------------------------------------------------
+
+def _host_trace(fn, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+        jax.block_until_ready([c.data for c in out.columns()])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans, calls, modules = [], [], set()
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                end = e.start_ns + e.duration_ns
+                if e.name.startswith("cylon:"):
+                    spans.append((e.name[len("cylon:"):].split("#")[0],
+                                  e.start_ns, end))
+                elif e.name.startswith("PjitFunction("):
+                    calls.append((e.start_ns, end))
+                else:
+                    modules.update(str(v) for k, v in e.stats
+                                   if k == "hlo_module")
+    calls.sort(key=lambda c: (c[0], -c[1]))
+    by_span, open_until = {}, -1
+    for start, end in calls:
+        if start < open_until:
+            continue    # the inner event of one dispatch
+        open_until = end
+        inner = min((s for s in spans if s[1] <= start < s[2]),
+                    key=lambda s: s[2] - s[1], default=(None,))
+        by_span[inner[0]] = by_span.get(inner[0], 0) + 1
+    return by_span, modules
+
+
+def test_join_launches_one_key_program_a_side_a_stage(dist_ctx, tmp_path):
+    """A four-way join of two int32-keyed tables, already distributed as
+    a cell's are: ONE program a side under `distributed_join.targets`,
+    one a side for the key bits, and in the whole join no program but
+    those the engine names (`anonymous_programs_per_query`'s list): 27 +
+    17 eager one-operation programs a side ran here before PR 39."""
+    import json
+    import os
+    import re
+
+    from cylon_tpu.parallel import shard
+
+    def tables(seed):
+        left, right = _tables(dist_ctx, n=4096, seed=seed)
+        return (shard.distribute(left, dist_ctx),
+                shard.distribute(right, dist_ctx))
+
+    left, right = tables(7)
+    left.distributed_join(right, "inner", on="k")    # compile everything
+    left, right = tables(8)                          # fresh buffers
+    by_span, modules = _host_trace(
+        lambda: left.distributed_join(right, "inner", on="k"), tmp_path)
+    assert by_span["distributed_join.targets"] == 2, by_span
+    assert by_span["distributed_join.keybits"] == 2, by_span
+    assert None not in by_span, by_span     # nothing outside a span
+    assert {"jit_partition_targets_program",
+            "jit_key_bits_program"} <= modules, modules
+    spec = json.load(open(os.path.join(
+        os.path.dirname(__file__), "..", "benchmarks", "metrics",
+        "anonymous_programs_per_query.json")))
+    anonymous = sorted(m for m in modules if not any(
+        re.search(p, m) for p in spec["not_matching"]))
+    assert anonymous == [], anonymous

@@ -224,7 +224,8 @@ def test_a_count_over_three_queries_is_a_whole_number():
     "jit__plan_program_stream_impl(5)", "jit__join_prefix_program(2)",
     "jit__materialize_program_stream_impl(1)", "jit_groupby_dense(4)",
     "jit_presort_groups(8)", "jit_sorted_segment_aggregate(8)",
-    "jit__multi_slice(77)"])
+    "jit__multi_slice(77)", "jit_partition_targets_program(3)",
+    "jit_key_bits_program(3)"])
 def test_named_programs_match_the_metric_s_list(name):
     import re
 

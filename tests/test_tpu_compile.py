@@ -279,3 +279,33 @@ def test_join64_plan_and_expand_compile(chip):
                           a_streams, b_streams, *cols, cap_e=cap_e,
                           wide_key="int64", **kw)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("stage", ["targets", "keybits"])
+def test_key_programs_compile_across_four_chips(topo, chip, stage):
+    """The two key programs of a distributed operator (PR 39) at
+    join-w4's shape, 2^24 int32 keys a chip over the described 2x2 mesh:
+    each is ONE elementwise pass a shard (a loop fusion and the all-ones
+    mask's broadcast), no collective, every output on the row sharding
+    (so `shard.pin` after it launches nothing)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cylon_tpu.parallel import dist_ops
+
+    mesh = Mesh(np.array(topo.devices), ("shards",))
+    row = NamedSharding(mesh, P("shards"))
+    forms = (("plain", "int32", False, False),)
+    fn = dist_ops._partition_targets_program_fn(mesh, forms, True) \
+        if stage == "targets" \
+        else dist_ops._key_bits_program_fn(mesh, forms, (False,))
+    keys = jax.ShapeDtypeStruct((4 * N,), jnp.int32, sharding=row)
+    with jax.enable_x64(False):
+        compiled = fn.lower(((keys,),)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"all-to-all|all-reduce|all-gather|"
+                         r"collective-permute", text)
+    fusions = [ln for ln in text.splitlines()
+               if re.search(r"= \S+ fusion\(", ln)]
+    assert len(fusions) == 1, fusions
+    for sharding in jax.tree.leaves(compiled.output_shardings):
+        assert sharding.spec == P("shards")
