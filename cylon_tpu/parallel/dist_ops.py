@@ -855,19 +855,26 @@ def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
         agg = tuple((arr, av & gvalid) for arr, av in results)
         return kout, kvout, gvalid, agg, safe
 
+    # check_vma off: on a TPU the reduce step is a pallas_call
+    # (groupby_run_reduce), whose outputs carry no varying-mesh-axes
+    # annotation for the checker; the kernel is purely per-shard
     return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 6,
-                             out_specs=spec))
+                             out_specs=spec, check_vma=False))
 
 
-def _aggregate_shards(ctx: CylonContext, ops, col_ids, all_valid, kbits,
-                      kdat, kval, emit, vdat, vval):
+def _aggregate_shards(ctx: CylonContext, phase: str, ops, col_ids,
+                      all_valid, kbits, kdat, kval, emit, vdat, vval):
     """One per-shard sort + reduce step (``_groupby_fn``), its sort's
     operands and its reduce path counted here, where the host can see
     them: the same pure functions of masks, lanes and accumulator widths
     that presort_groups and sorted_segment_aggregate evaluate inside the
     program. After an exchange there is always a row mask, and the
     varlen key gather needs each group's first row: the dead flag and
-    the index both ride."""
+    the index both ride. ``phase`` names the step in
+    ``cylon_groupby_phase_total``: "partial" (a shard's own rows, before
+    the exchange), "merge" (the partials, after it) or "single" (the
+    rows themselves after the exchange, or in place: no pre-aggregation)."""
+    _counter("cylon_groupby_phase_total", {"phase": phase}).inc()
     _counter("cylon_groupby_sort_operands_total").inc(
         _groupby.sort_operand_count(kbits + kval, emit, vdat, vval, True))
     _counter("cylon_groupby_reduce_path_total", {
@@ -1980,18 +1987,39 @@ def distributed_set_op(left: Table, right: Table,
 # ---------------------------------------------------------------------------
 
 
-def _groupby_shuffle_agg(ctx: CylonContext, key_columns, value_columns,
-                         ops: Tuple, emit, seq, col_ids: Tuple = None,
+def _group_key_columns(ctx: CylonContext, kout, kvout, safe, kcols):
+    """The key columns of one per-shard aggregation step's groups, from
+    the gathered key data and validity (a varbytes key's words by a
+    per-shard varlen gather at each group's first row, ``safe``)."""
+    out = []
+    for d, v, kc in zip(kout, kvout, kcols):
+        if kc.is_varbytes:
+            vb = _varlen_take_sharded(ctx, kc.varbytes, safe)
+            out.append(Column(vb.lengths, kc.dtype, v, None, kc.name,
+                              varbytes=vb))
+        else:
+            out.append(Column(d, kc.dtype, v, kc.dictionary, kc.name))
+    return out
+
+
+def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
+                         value_columns, ops: Tuple, emit, seq,
+                         col_ids: Tuple = None,
                          skip_exchange: bool = False):
-    """Shuffle rows by key hash, then aggregate per shard. Returns
-    (key_out_cols, agg list of (arr, valid), gvalid). ``col_ids``: static
+    """Shuffle rows by key hash, then aggregate per shard (the step
+    ``phase`` of `_aggregate_shards`). Returns (the arguments
+    `_group_key_columns` makes the key columns from, agg list of (arr,
+    valid), gvalid). ``col_ids``: static
     source-column names for the aggregate's sub-reduction dedup (repeated
     (column, op) pairs compute once — see sorted_segment_aggregate).
     ``skip_exchange``: caller asserts every key's rows are already
     co-located on one shard (a co-partitioning witness from a prior
     shuffle/join on the same keys) — the per-shard aggregation is then
     globally exact with NO exchange at all (the plan optimizer's elided
-    groupby-after-join path)."""
+    groupby-after-join path). Every host statement runs under a leaf
+    span (`distributed_groupby.targets`, the exchange's own leaves,
+    `.keybits`, `.aggregate`), so that a chip's idle time books to a
+    name; `distributed_groupby.shuffle` keeps what they leave."""
     if skip_exchange:
         out_cols = list(key_columns) + list(value_columns)
         emit_s = emit
@@ -2002,39 +2030,34 @@ def _groupby_shuffle_agg(ctx: CylonContext, key_columns, value_columns,
                    rows_in=int(emit.shape[0])):
             view = Table(list(key_columns) + list(value_columns), ctx,
                          None)
-            targets = _partition_targets_dist(ctx, key_columns)
+            with _span("distributed_groupby.targets", seq,
+                       key_columns=len(key_columns)):
+                targets = _partition_targets_dist(ctx, key_columns)
             out_cols, emit_s, _x = _exchange_table(view, targets, emit,
                                                    ctx)
 
-    nk = len(key_columns)
-    kcols_s = out_cols[:nk]
-    vcols_s = out_cols[nk:]
-    # key bits recompute per shard from the shuffled key columns —
-    # recomputable lanes never cross the exchange (round-4 review)
-    kbits, _kv = _dist_key_bits(ctx, kcols_s)
-    kdat = tuple(shard.pin(c.data, ctx) for c in kcols_s)
-    kval = tuple(shard.pin(c.valid_mask(), ctx) for c in kcols_s)
-    vdat = tuple(shard.pin(c.data, ctx) for c in vcols_s)
-    vval = tuple(None if c.validity is None
-                 else shard.pin(c.valid_mask(), ctx) for c in vcols_s)
+    with _span("distributed_groupby.keybits", seq) as _sp:
+        nk = len(key_columns)
+        kcols_s = out_cols[:nk]
+        vcols_s = out_cols[nk:]
+        # key bits recompute per shard from the shuffled key columns —
+        # recomputable lanes never cross the exchange (round-4 review)
+        kbits, _kv = _dist_key_bits(ctx, kcols_s)
+        kdat = tuple(shard.pin(c.data, ctx) for c in kcols_s)
+        kval = tuple(shard.pin(c.valid_mask(), ctx) for c in kcols_s)
+        vdat = tuple(shard.pin(c.data, ctx) for c in vcols_s)
+        vval = tuple(None if c.validity is None
+                     else shard.pin(c.valid_mask(), ctx) for c in vcols_s)
+        _sp.set(key_lanes=len(kbits), rows=int(emit_s.shape[0]))
 
     with _phase("distributed_groupby.aggregate", seq):
         if col_ids is None:
             col_ids = tuple(range(len(vcols_s)))
         all_valid = tuple(c.validity is None for c in vcols_s)
         kout, kvout, gvalid, agg, safe = _aggregate_shards(
-            ctx, ops, col_ids, all_valid, kbits, kdat, kval, emit_s, vdat,
-            vval)
-
-    key_out = []
-    for d, v, kc in zip(kout, kvout, kcols_s):
-        if kc.is_varbytes:
-            vb = _varlen_take_sharded(ctx, kc.varbytes, safe)
-            key_out.append(Column(vb.lengths, kc.dtype, v, None, kc.name,
-                                  varbytes=vb))
-        else:
-            key_out.append(Column(d, kc.dtype, v, kc.dictionary, kc.name))
-    return key_out, list(agg), gvalid
+            ctx, phase, ops, col_ids, all_valid, kbits, kdat, kval,
+            emit_s, vdat, vval)
+    return (kout, kvout, safe, kcols_s), list(agg), gvalid
 
 
 def distributed_groupby(table: Table, index_col, aggregate_cols: List,
@@ -2055,45 +2078,55 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
                                     aggregate_ops),
             "distributed_groupby")
 
-    t = shard.distribute(table, ctx)
-    idx_cols = index_col if isinstance(index_col, (list, tuple)) else [index_col]
-    idx_cols = [t._col_index(c) for c in idx_cols]
-    val_cols = [t._col_index(c) for c in aggregate_cols]
-    key_columns = [t._columns[i] for i in idx_cols]
-    for vi, op in zip(val_cols, aggregate_ops):
-        if t._columns[vi].is_varbytes and \
-                op != _groupby.AggregationOp.COUNT:
-            raise CylonPlanError(
-                "varbytes value columns support COUNT only",
-                code=Code.NotImplemented)
-
     seq = ctx.get_next_sequence()
-    ops = list(aggregate_ops)
-    emit = shard.pin(t.emit_mask(), ctx)
+    with _span("distributed_groupby.distribute", seq, world=world) as _sp:
+        t = shard.distribute(table, ctx)
+        _sp.set(already_distributed=int(t is table))
+        idx_cols = index_col if isinstance(index_col, (list, tuple)) \
+            else [index_col]
+        idx_cols = [t._col_index(c) for c in idx_cols]
+        val_cols = [t._col_index(c) for c in aggregate_cols]
+        key_columns = [t._columns[i] for i in idx_cols]
+        for vi, op in zip(val_cols, aggregate_ops):
+            if t._columns[vi].is_varbytes and \
+                    op != _groupby.AggregationOp.COUNT:
+                raise CylonPlanError(
+                    "varbytes value columns support COUNT only",
+                    code=Code.NotImplemented)
+        ops = list(aggregate_ops)
+        emit = shard.pin(t.emit_mask(), ctx)
+        # the input's capacity: what dist_groupby_partial_share holds
+        # the exchange's live rows against
+        _counter("cylon_groupby_rows_in_total").inc(int(t.capacity))
     MEAN = _groupby.AggregationOp.MEAN
     SUM = _groupby.AggregationOp.SUM
     COUNT = _groupby.AggregationOp.COUNT
 
-    if pre_partitioned or not pre_aggregate:
-        value_columns = [t._columns[vi] for vi in val_cols]
-        key_out, agg, gvalid = _groupby_shuffle_agg(
-            ctx, key_columns, value_columns, tuple(ops), emit, seq,
-            col_ids=tuple(val_cols), skip_exchange=pre_partitioned)
-        cols = list(key_out)
-        for (arr, av), vi, op in zip(agg, val_cols, ops):
-            src = t._columns[vi]
-            keep_dict = (op in (_groupby.AggregationOp.MIN,
-                                _groupby.AggregationOp.MAX)
-                         and src.is_string)
-            cols.append(Column(arr, table_mod._agg_dtype(src, op), av,
-                               src.dictionary if keep_dict else None,
-                               src.name))
-        out = Table(cols, ctx, gvalid)
+    def agg_column(arr, av, src, op, dtype=None):
+        keep_dict = (op in (_groupby.AggregationOp.MIN,
+                            _groupby.AggregationOp.MAX)
+                     and src.is_string)
+        return Column(arr, dtype or table_mod._agg_dtype(src, op), av,
+                      src.dictionary if keep_dict else None, src.name)
+
+    def finish(key_out, cols, gvalid):
+        out = Table(list(key_out) + cols, ctx, gvalid)
         # output keys stay hash-placed (rows never moved / moved by key
-        # hash): witness lets a further same-key stage skip its shuffle
+        # hash; phase B placed every group on its key-hash shard):
+        # witness lets a further same-key stage skip its shuffle
         out._hash_partitioned = shard.partition_signature(
             key_out, tuple(range(len(key_out))), world)
         return _ledger.track(out, "distributed_groupby")
+
+    if pre_partitioned or not pre_aggregate:
+        value_columns = [t._columns[vi] for vi in val_cols]
+        keys, agg, gvalid = _groupby_shuffle_agg(
+            ctx, "single", key_columns, value_columns, tuple(ops), emit,
+            seq, col_ids=tuple(val_cols), skip_exchange=pre_partitioned)
+        with _span("distributed_groupby.finish", seq):
+            return finish(_group_key_columns(ctx, *keys), [
+                agg_column(arr, av, t._columns[vi], op)
+                for (arr, av), vi, op in zip(agg, val_cols, ops)], gvalid)
 
     # ---- phase A: per-shard partial aggregation (shuffle bytes then
     # scale with per-shard GROUPS, not rows). MEAN expands to
@@ -2128,56 +2161,34 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
         avA = tuple(t._columns[val_cols[j]].validity is None
                     for j, _opA, _c in a_entries)
         koutA, kvoutA, gvalidA, aggA, safeA = _aggregate_shards(
-            ctx, opsA, cidsA, avA, kbitsA, kdatA, kvalA, emit,
+            ctx, "partial", opsA, cidsA, avA, kbitsA, kdatA, kvalA, emit,
             tuple(vdatA), tuple(vvalA))
-
-    pkey_cols = []
-    for d, v, kc in zip(koutA, kvoutA, key_columns):
-        if kc.is_varbytes:
-            vb = _varlen_take_sharded(ctx, kc.varbytes, safeA)
-            pkey_cols.append(Column(vb.lengths, kc.dtype, v, None, kc.name,
-                                    varbytes=vb))
-        else:
-            pkey_cols.append(Column(d, kc.dtype, v, kc.dictionary, kc.name))
-    pval_cols = []
-    for (arr, av), (j, opA, cast) in zip(aggA, a_entries):
-        src = t._columns[val_cols[j]]
-        dt = dtypes.Double() if cast else table_mod._agg_dtype(src, opA)
-        keep_dict = (opA in (_groupby.AggregationOp.MIN,
-                             _groupby.AggregationOp.MAX)
-                     and src.is_string)
-        pval_cols.append(Column(arr, dt, av,
-                                src.dictionary if keep_dict else None,
-                                src.name))
+        # the partial table: one row a (shard, group)
+        pkey_cols = _group_key_columns(ctx, koutA, kvoutA, safeA,
+                                       key_columns)
+        pval_cols = [
+            agg_column(arr, av, t._columns[val_cols[j]], opA,
+                       dtypes.Double() if cast else None)
+            for (arr, av), (j, opA, cast) in zip(aggA, a_entries)]
 
     # ---- phase B: shuffle the partials, merge with second-phase ops
-    key_out, aggB, gvalid = _groupby_shuffle_agg(
-        ctx, pkey_cols, pval_cols, tuple(b_ops), gvalidA, seq)
+    keys, aggB, gvalid = _groupby_shuffle_agg(
+        ctx, "merge", pkey_cols, pval_cols, tuple(b_ops), gvalidA, seq)
 
-    cols = list(key_out)
-    for op, vi, m in zip(ops, val_cols, out_map):
-        src = t._columns[vi]
-        if m[0] == "mean":
-            s_arr, s_av = aggB[m[1]]
-            c_arr, c_av = aggB[m[2]]
-            data = s_arr / jnp.maximum(c_arr.astype(jnp.float64), 1)
-            av = s_av & c_av & (c_arr > 0)
-            cols.append(Column(data, table_mod._agg_dtype(src, op), av,
-                               None, src.name))
-        else:
-            arr, av = aggB[m[1]]
-            keep_dict = (op in (_groupby.AggregationOp.MIN,
-                                _groupby.AggregationOp.MAX)
-                         and src.is_string)
-            cols.append(Column(arr, table_mod._agg_dtype(src, op), av,
-                               src.dictionary if keep_dict else None,
-                               src.name))
-    out = Table(cols, ctx, gvalid)
-    # phase B placed every group on its key-hash shard: witness the
-    # partitioning so later same-key stages can elide their shuffles
-    out._hash_partitioned = shard.partition_signature(
-        key_out, tuple(range(len(key_out))), world)
-    return _ledger.track(out, "distributed_groupby")
+    with _span("distributed_groupby.finish", seq):
+        cols = []
+        for op, vi, m in zip(ops, val_cols, out_map):
+            src = t._columns[vi]
+            if m[0] == "mean":
+                s_arr, s_av = aggB[m[1]]
+                c_arr, c_av = aggB[m[2]]
+                data = s_arr / jnp.maximum(c_arr.astype(jnp.float64), 1)
+                av = s_av & c_av & (c_arr > 0)
+                cols.append(Column(data, table_mod._agg_dtype(src, op), av,
+                                   None, src.name))
+            else:
+                cols.append(agg_column(*aggB[m[1]], src, op))
+        return finish(_group_key_columns(ctx, *keys), cols, gvalid)
 
 
 # ---------------------------------------------------------------------------
