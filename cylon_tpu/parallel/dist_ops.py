@@ -838,21 +838,35 @@ def _varlen_take_concat_fn(mesh, cap_w: int):
 
 @counted_cache
 def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
-                col_ids: Tuple[int, ...], all_valid: Tuple[bool, ...]):
+                col_ids: Tuple[int, ...], all_valid: Tuple[bool, ...],
+                key_spec: tuple = None):
+    """The per-shard sort + reduce step. ``key_spec`` (static;
+    `_group_keys` decides) None: the sort carries the row index and
+    each group's key is gathered from its first original row (``kdat``,
+    ``kval``; ``safe`` goes out for a varbytes key's words). Else
+    ``kbits`` are the key lanes as `ops/groupby._key_columns` reads them
+    (`_group_keys`), no index rides, the sort is not stable, and
+    the key columns come out of the reduce pass: no gather over the rows,
+    ``kdat`` and ``kval`` empty, a key without nulls comes back with
+    validity None and ``safe`` is None."""
     spec = P(mesh.axis_names[0])
 
     def kernel(kbits, kdat, kval, emit, vdat, vval):
         n = emit.shape[0]
         keys = tuple(kbits) + tuple(v.astype(jnp.uint8) for v in kval)
-        vdat_s, vval_s, emit_s, iota_s, new_grp, _ng = \
-            _groupby.presort_groups(keys, emit, vdat, vval)
-        rep, gvalid, results = _groupby.sorted_segment_aggregate(
-            new_grp, emit_s, iota_s, vdat_s, vval_s, n, ops, col_ids,
-            all_valid)
-        safe = jnp.minimum(rep, n - 1)
+        vdat_s, vval_s, emit_s, first_s, new_grp, _ng = \
+            _groupby.presort_groups(keys, emit, vdat, vval,
+                                    index=key_spec is None)
+        firsts, gvalid, results = _groupby.sorted_segment_aggregate(
+            new_grp, emit_s, first_s, vdat_s, vval_s, n, ops, col_ids,
+            all_valid, key_spec=key_spec)
+        agg = tuple((arr, av & gvalid) for arr, av in results)
+        if key_spec is not None:
+            kout, kvout = zip(*firsts)
+            return kout, kvout, gvalid, agg, None
+        safe = jnp.minimum(firsts, n - 1)
         kout = tuple(jnp.take(d, safe, axis=0) for d in kdat)
         kvout = tuple(jnp.take(v, safe) & gvalid for v in kval)
-        agg = tuple((arr, av & gvalid) for arr, av in results)
         return kout, kvout, gvalid, agg, safe
 
     # check_vma off: on a TPU the reduce step is a pallas_call
@@ -862,26 +876,66 @@ def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
                              out_specs=spec, check_vma=False))
 
 
+def _group_keys(ctx: CylonContext, key_columns, value_dtypes, ops,
+                rows: int):
+    """(key_spec, kbits, kdat, kval) of `_groupby_fn`: HOW the per-shard
+    step reads its groups' keys, decided from what the host sees before
+    it dispatches (no knob; `ops/groupby.sort_carries_index`, the
+    function `table.groupby_local` decides by; ``rows``: a shard's), and
+    the key operands that way takes.
+
+    ``key_spec`` None, the sort must carry the row index: a varbytes key
+    (hash lanes have no way back), a lane wider than 32 bits, a reduce
+    step that is not the streaming pass (the CPU, 8-byte accumulators).
+    Then the key bits, the data and every column's mask, to gather from.
+    Else `sorted_segment_aggregate`'s ``key_spec``, one (numpy dtype,
+    is_string, nullable) a key column, and ``kbits`` holds a column's
+    ordered-bits lane and then its validity lane only if the column is
+    nullable, out of the ONE key-bits program: nothing to gather from,
+    no all-ones mask of a key without nulls."""
+    key_spec = None if any(c.is_varbytes for c in key_columns) else tuple(
+        (np.dtype(c.data.dtype), c.is_string, c.validity is not None)
+        for c in key_columns)
+    # a plain column's ordered-bits lane is as wide as its data
+    if _groupby.sort_carries_index([c.data for c in key_columns], key_spec,
+                                   value_dtypes, ops, rows):
+        key_spec = None
+    if key_spec is not None:
+        kbits, _kv = _dist_key_bits(
+            ctx, key_columns,
+            null_lanes=[nullable for _d, _s, nullable in key_spec])
+        return key_spec, kbits, (), ()
+    kbits, _kv = _dist_key_bits(ctx, key_columns)
+    return (None, kbits,
+            tuple(shard.pin(c.data, ctx) for c in key_columns),
+            tuple(shard.pin(c.valid_mask(), ctx) for c in key_columns))
+
+
 def _aggregate_shards(ctx: CylonContext, phase: str, ops, col_ids,
-                      all_valid, kbits, kdat, kval, emit, vdat, vval):
+                      all_valid, key_spec, kbits, kdat, kval, emit, vdat,
+                      vval):
     """One per-shard sort + reduce step (``_groupby_fn``), its sort's
-    operands and its reduce path counted here, where the host can see
-    them: the same pure functions of masks, lanes and accumulator widths
-    that presort_groups and sorted_segment_aggregate evaluate inside the
-    program. After an exchange there is always a row mask, and the
-    varlen key gather needs each group's first row: the dead flag and
-    the index both ride. ``phase`` names the step in
+    operands, its reduce path and the way its groups' keys are read
+    counted here, where the host can see them: the same pure functions
+    of masks, lanes and accumulator widths that presort_groups and
+    sorted_segment_aggregate evaluate inside the program. After an
+    exchange there is always a row mask: the dead flag rides. The row
+    index rides, on a stable sort, only without a ``key_spec``
+    (`_group_keys`). ``phase`` names the step in
     ``cylon_groupby_phase_total``: "partial" (a shard's own rows, before
     the exchange), "merge" (the partials, after it) or "single" (the
     rows themselves after the exchange, or in place: no pre-aggregation)."""
     _counter("cylon_groupby_phase_total", {"phase": phase}).inc()
+    _counter("cylon_groupby_key_readback_total", {
+        "path": "gather" if key_spec is None else "lanes"}).inc()
     _counter("cylon_groupby_sort_operands_total").inc(
-        _groupby.sort_operand_count(kbits + kval, emit, vdat, vval, True))
+        _groupby.sort_operand_count(kbits + kval, emit, vdat, vval,
+                                    key_spec is None))
     _counter("cylon_groupby_reduce_path_total", {
         "path": _groupby.reduce_path(
             [v.dtype for v in vdat], ops,
             emit.shape[0] // ctx.get_world_size())}).inc()
-    return _groupby_fn(ctx.mesh, ops, col_ids, all_valid)(
+    return _groupby_fn(ctx.mesh, ops, col_ids, all_valid, key_spec)(
         kbits, kdat, kval, emit, vdat, vval)
 
 
@@ -1989,8 +2043,10 @@ def distributed_set_op(left: Table, right: Table,
 
 def _group_key_columns(ctx: CylonContext, kout, kvout, safe, kcols):
     """The key columns of one per-shard aggregation step's groups, from
-    the gathered key data and validity (a varbytes key's words by a
-    per-shard varlen gather at each group's first row, ``safe``)."""
+    the key data and validity `_groupby_fn` gives (read off the sorted
+    lanes, validity None for a key without nulls; or gathered, and then
+    a varbytes key's words by a per-shard varlen gather at each group's
+    first row, ``safe``)."""
     out = []
     for d, v, kc in zip(kout, kvout, kcols):
         if kc.is_varbytes:
@@ -2042,12 +2098,12 @@ def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
         vcols_s = out_cols[nk:]
         # key bits recompute per shard from the shuffled key columns —
         # recomputable lanes never cross the exchange (round-4 review)
-        kbits, _kv = _dist_key_bits(ctx, kcols_s)
-        kdat = tuple(shard.pin(c.data, ctx) for c in kcols_s)
-        kval = tuple(shard.pin(c.valid_mask(), ctx) for c in kcols_s)
         vdat = tuple(shard.pin(c.data, ctx) for c in vcols_s)
         vval = tuple(None if c.validity is None
                      else shard.pin(c.valid_mask(), ctx) for c in vcols_s)
+        key_spec, kbits, kdat, kval = _group_keys(
+            ctx, kcols_s, [v.dtype for v in vdat], ops,
+            int(emit_s.shape[0]) // ctx.get_world_size())
         _sp.set(key_lanes=len(kbits), rows=int(emit_s.shape[0]))
 
     with _phase("distributed_groupby.aggregate", seq):
@@ -2055,8 +2111,8 @@ def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
             col_ids = tuple(range(len(vcols_s)))
         all_valid = tuple(c.validity is None for c in vcols_s)
         kout, kvout, gvalid, agg, safe = _aggregate_shards(
-            ctx, phase, ops, col_ids, all_valid, kbits, kdat, kval,
-            emit_s, vdat, vval)
+            ctx, phase, ops, col_ids, all_valid, key_spec, kbits, kdat,
+            kval, emit_s, vdat, vval)
     return (kout, kvout, safe, kcols_s), list(agg), gvalid
 
 
@@ -2146,9 +2202,6 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
             b_ops.append(_groupby.second_phase_op(op))
 
     with _phase("distributed_groupby.pre_aggregate", seq):
-        kbitsA, _kvA = _dist_key_bits(ctx, key_columns)
-        kdatA = tuple(shard.pin(c.data, ctx) for c in key_columns)
-        kvalA = tuple(shard.pin(c.valid_mask(), ctx) for c in key_columns)
         vdatA, vvalA = [], []
         for j, _opA, cast in a_entries:
             src = t._columns[val_cols[j]]
@@ -2157,12 +2210,15 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
             vvalA.append(None if src.validity is None
                          else shard.pin(src.valid_mask(), ctx))
         opsA = tuple(opA for _j, opA, _c in a_entries)
+        specA, kbitsA, kdatA, kvalA = _group_keys(
+            ctx, key_columns, [v.dtype for v in vdatA], opsA,
+            int(emit.shape[0]) // world)
         cidsA = tuple((val_cols[j], cast) for j, _opA, cast in a_entries)
         avA = tuple(t._columns[val_cols[j]].validity is None
                     for j, _opA, _c in a_entries)
         koutA, kvoutA, gvalidA, aggA, safeA = _aggregate_shards(
-            ctx, "partial", opsA, cidsA, avA, kbitsA, kdatA, kvalA, emit,
-            tuple(vdatA), tuple(vvalA))
+            ctx, "partial", opsA, cidsA, avA, specA, kbitsA, kdatA, kvalA,
+            emit, tuple(vdatA), tuple(vvalA))
         # the partial table: one row a (shard, group)
         pkey_cols = _group_key_columns(ctx, koutA, kvoutA, safeA,
                                        key_columns)

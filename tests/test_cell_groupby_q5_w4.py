@@ -282,3 +282,147 @@ def test_stream_reduce_under_shard_map_equals_the_segment_path(
         got, REFERENCE.reference(tables, config, TRAFFIC))}
     assert [numbers[k] for k in EXACT] == [0] * len(EXACT), numbers
     assert numbers[FLOAT] <= 1.0, numbers
+
+
+READBACK = ('cylon_groupby_key_readback_total{path="lanes"}',
+            'cylon_groupby_key_readback_total{path="gather"}',
+            "cylon_groupby_sort_operands_total")
+
+
+def _readback():
+    snap = telemetry.metrics_snapshot()
+    return [snap.get(k, 0) for k in READBACK]
+
+
+def _as_on_a_tpu(monkeypatch):
+    """The host's decision (`G.sort_carries_index`, which says "index" on
+    a CPU backend) and `_groupby_fn`'s reduce step as a TPU backend takes
+    them, under the interpreter."""
+    real_agg, real_index = G.sorted_segment_aggregate, G.sort_carries_index
+    monkeypatch.setattr(
+        G, "sorted_segment_aggregate",
+        lambda *a, **k: real_agg(*a, **k, interpret=True))
+    monkeypatch.setattr(
+        G, "sort_carries_index",
+        lambda *a, **k: real_index(*a, **k, interpret=True))
+
+
+def _with_columns(ctx, t, validity=None, mask=None):
+    """``t`` spread over the chips, column i under ``validity[i]`` (host
+    bool arrays over the capacity), rows under ``mask``."""
+    t = shard.distribute(t, ctx)
+
+    def pinned(a):
+        return shard.pin(jnp.asarray(a), ctx)
+
+    cols = [c if i not in (validity or {}) else ct.Column(
+        c.data, c.dtype, pinned(validity[i]), c.dictionary, c.name)
+        for i, c in enumerate(t.columns())]
+    return ct.Table(cols, ctx, None if mask is None else pinned(mask))
+
+
+def _case_no_nulls(ctx, cols, rng):
+    return _with_columns(ctx, ct.Table.from_pydict(ctx, cols)), [0], {}
+
+
+def _case_nullable_key(ctx, cols, rng):
+    t = ct.Table.from_pydict(ctx, cols)
+    return _with_columns(ctx, t, {0: rng.random(t.capacity) > 0.05}), \
+        [0], {}
+
+
+def _case_two_keys(ctx, cols, rng):
+    second = {"id4": (cols["id6"] % 3).astype(np.int16)}
+    t = ct.Table.from_pydict(ctx, {**second, **cols})   # id4, id6, v1..v3
+    return _with_columns(ctx, t), [0, 1], {}
+
+
+def _case_masked(ctx, cols, rng):
+    t = ct.Table.from_pydict(ctx, cols)
+    return _with_columns(ctx, t, mask=rng.random(t.capacity) > 1 / 3), \
+        [0], {}
+
+
+def _case_single(ctx, cols, rng):
+    t, keys, _kw = _case_no_nulls(ctx, cols, rng)
+    return t, keys, {"pre_aggregate": False}
+
+
+def _case_varbytes(ctx, cols, rng):
+    names = np.array([f"customer-{k:08d}" for k in cols["id6"]], object)
+    t = ct.Table.from_pydict(ctx, {**cols, "id6": names})
+    assert t.get_column(0).is_varbytes
+    return _with_columns(ctx, t), [0], {}
+
+
+# case -> (its table, sort operands a query on the lanes path, on the
+# gather path, dispatches of jit_groupby): the dead flag always (after
+# `distribute` the emit mask exists), then the key lanes, the values,
+# their masks (the partial sums have one each), and the index. The
+# cell's shape is 5 + 8 against 7 + 10
+KEY_READBACK_CASES = {
+    "no_nulls": (_case_no_nulls, 13, 17, 2),
+    "nullable_key": (_case_nullable_key, 6 + 9, 17, 2),
+    "two_keys": (_case_two_keys, 6 + 9, 9 + 12, 2),
+    "masked": (_case_masked, 13, 17, 2),
+    "single": (_case_single, 5, 7, 1),
+    "varbytes": (_case_varbytes, None, None, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(KEY_READBACK_CASES))
+def test_keys_off_the_sorted_lanes_equal_the_gathered_keys(
+        dist_ctx, monkeypatch, case):
+    """The per-shard step reads its groups' keys off the sorted key lanes
+    where `G.sort_carries_index` lets it (PR 41: no row index, no stable
+    sort, no gather over the rows; a key without nulls carries no
+    validity lane and comes back without a mask), and gathers them from
+    each group's first row where it does not: the same groups, keys bit
+    for bit, integer sums equal, a float sum's adds in another order."""
+    make, lanes_ops, gather_ops, steps = KEY_READBACK_CASES[case]
+    _config, tables = _data(2048, 1500, seed=41)
+    cols = dict(tables[TRAFFIC["table"]])
+    t, keys, kw = make(dist_ctx, cols, np.random.default_rng(41))
+    values = list(range(len(keys), len(keys) + 3))
+    frames, moved = {}, {}
+    for path in ("gather", "lanes"):
+        with monkeypatch.context() as m:
+            if path == "lanes":   # "gather": the CPU's own program
+                _as_on_a_tpu(m)
+            dist_ops._groupby_fn.cache_clear()
+            before = _readback()
+            try:
+                out = dist_ops.distributed_groupby(
+                    t, keys, values, [G.AggregationOp.SUM] * 3, **kw)
+                frames[path] = out.to_pandas()
+            finally:
+                dist_ops._groupby_fn.cache_clear()
+            moved[path] = [a - b for a, b in zip(_readback(), before)]
+        if path == "lanes" and case != "varbytes":
+            # a mask only where the key has nulls
+            assert [out.get_column(i).validity is not None for i in keys] \
+                == [case == "nullable_key"] * len(keys)
+    if case == "varbytes":   # hash lanes have no way back: both gather
+        assert moved["lanes"] == moved["gather"]
+        assert moved["gather"][:2] == [0, steps]
+    else:
+        assert moved["lanes"] == [steps, 0, lanes_ops]
+        assert moved["gather"] == [0, steps, gather_ops]
+    by = list(frames["gather"].columns[:len(keys)])
+    want, got = (f.sort_values(by, na_position="last").reset_index(drop=True)
+                 for f in (frames["gather"], frames["lanes"]))
+    assert len(want) == len(got)
+    if case == "nullable_key":   # the null group once, on one shard
+        assert got[by[0]].isna().sum() == want[by[0]].isna().sum() == 1
+    for name in want.columns:
+        w, g = want[name].to_numpy(), got[name].to_numpy()
+        if w.dtype.kind == "f" and name not in by:
+            np.testing.assert_allclose(g, w, rtol=1e-6)
+        else:
+            assert g.dtype == w.dtype and (
+                (g == w) | ((g != g) & (w != w))).all(), name
+    # and the plain oracle of the same rows
+    live = t.to_pandas()
+    oracle = live.groupby(list(live.columns[:len(keys)]), dropna=False)
+    assert len(got) == oracle.ngroups
+    assert int(got.iloc[:, len(keys)].sum()) == int(live.iloc[:, values[0]].sum())

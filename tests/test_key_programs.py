@@ -446,3 +446,65 @@ def test_ring_join_matches_the_shuffle_join(dist_ctx):
     assert _sorted_rows(ring.to_pandas()) == _sorted_rows(want.to_pandas())
     assert ring.row_count == len(
         left.to_pandas().merge(right.to_pandas(), on="k"))
+
+
+def _spy_key_bits(monkeypatch):
+    """Every (forms, null_lanes) `_key_bits_program_fn` is asked for."""
+    asked, real = [], D._key_bits_program_fn
+    monkeypatch.setattr(
+        D, "_key_bits_program_fn",
+        lambda mesh, forms, lanes: asked.append((forms, lanes))
+        or real(mesh, forms, lanes))
+    return asked
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "nullable"])
+def test_join_asks_for_key_bits_without_null_lanes(dist_ctx, monkeypatch,
+                                                   masked):
+    """The groupby hands `_dist_key_bits` ``null_lanes`` since PR 41; a
+    join hands none, so its key-bits programs keep their cache keys: the
+    forms and all-False lanes, a nullable key too."""
+    left, right = _join_tables(dist_ctx, 71)
+    if masked:
+        rng = np.random.default_rng(72)
+        left, right = (Table(
+            [Column(t._columns[0].data, t._columns[0].dtype,
+                    jnp.asarray(rng.random(t.capacity) < 0.9), None, "k"),
+             t._columns[1]], dist_ctx) for t in (left, right))
+    asked = _spy_key_bits(monkeypatch)
+    out = left.distributed_join(right, "inner", on="k")
+    form = ("plain", "int32", False, masked)
+    assert asked == [((form,), (False,))] * 2
+    ref = left.to_pandas().dropna().merge(right.to_pandas().dropna(), on="k")
+    assert out.row_count == len(ref)
+
+
+def test_groupby_lanes_ask_for_a_null_lane_a_nullable_key(dist_ctx,
+                                                          monkeypatch):
+    """`_group_keys` where the keys can be read off the lanes (steered:
+    on the CPU `sort_carries_index` says "index"): a column's bits, then
+    its validity as a uint8 lane only where the column is nullable, out
+    of the one key-bits program; no data or mask to gather from. Where
+    the index rides: the bits alone, the data and every column's mask."""
+    from cylon_tpu.ops import groupby as G
+
+    t = shard.distribute(_frame(dist_ctx, 81), dist_ctx)
+    k, v = t._columns[0], t._columns[1]
+    valid = jnp.asarray(np.random.default_rng(82).random(t.capacity) < 0.8)
+    cols = [k, Column(v.data, v.dtype, shard.pin(valid, dist_ctx), None, "v")]
+    asked = _spy_key_bits(monkeypatch)
+    args = (dist_ctx, cols, [jnp.float32], (G.AggregationOp.SUM,), 24)
+    spec, kbits, kdat, kval = D._group_keys(*args)
+    assert spec is None and asked[-1][1] == (False, False)
+    assert (len(kbits), len(kdat), len(kval)) == (2, 2, 2)
+    real = G.sort_carries_index
+    monkeypatch.setattr(G, "sort_carries_index",
+                        lambda *a, **kw: real(*a, **kw, interpret=True))
+    spec, kbits, kdat, kval = D._group_keys(*args)
+    assert spec == ((np.dtype(np.int32), False, False),
+                    (np.dtype(np.int32), False, True))
+    assert asked[-1][1] == (False, True) and (kdat, kval) == ((), ())
+    assert [str(b.dtype) for b in kbits] == ["uint32", "uint32", "uint8"]
+    assert np.array_equal(np.asarray(kbits[2]), np.asarray(valid))
+    # the second column's lane holds the nulls' extreme, as `sort_keys` does
+    assert (np.asarray(kbits[1])[~np.asarray(valid)] == 0xFFFFFFFF).all()

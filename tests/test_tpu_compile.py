@@ -309,3 +309,80 @@ def test_key_programs_compile_across_four_chips(topo, chip, stage):
     assert len(fusions) == 1, fusions
     for sharding in jax.tree.leaves(compiled.output_shardings):
         assert sharding.spec == P("shards")
+
+
+def _sort_lines(text):
+    return [ln for ln in text.splitlines() if re.search(r"= \S.* sort\(", ln)]
+
+
+@pytest.fixture
+def shards4(topo, chip, monkeypatch):
+    """(the described 2x2 mesh, n -> a maker of row-sharded shapes) for a
+    per-shard program, traced as a TPU backend traces it (this process's
+    backend is the CPU: `ops/groupby.reduce_path` is steered here)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices), ("shards",))
+    row = NamedSharding(mesh, P("shards"))
+    return mesh, lambda n: lambda dtype: jax.ShapeDtypeStruct(
+        (n,), dtype, sharding=row)
+
+
+@pytest.mark.parametrize("phase,operands", [("partial", 5), ("merge", 8)])
+def test_dist_groupby_lanes_program_compiles(shards4, phase, operands):
+    """`groupby-q5-w4`'s per-shard program (`jit_groupby`) as a TPU
+    backend gets it since PR 41, for the described 2x2 mesh, with the
+    cell's two operand lists at 2^13 rows a chip: the program's SHAPE is
+    what is held here, and a sort's compile time goes with its rows up
+    to 2^15 and then stays (3 + 5 s here; 70 + 128 s at 2^20, minutes at
+    the cell's 6.25e7 rows and 2^24 slots: PERF.md section 6, where the
+    real shapes' compile is recorded; the reduce kernel at num_segments
+    = n = 2^24 is `test_groupby_stream_reduce_compiles`'s). An int32 key
+    without nulls read back off its sorted lane, so ONE sort
+    that is not stable (dead flag, key lane, three values; in the merge
+    the three partial sums' masks too), ONE Pallas pass, and no gather or
+    scatter at all."""
+    from cylon_tpu.parallel import dist_ops
+
+    mesh, shapes = shards4
+    rows = shapes(4 << 13)
+    SUM = _groupby.AggregationOp.SUM
+    merge = phase == "merge"
+    vdat = (rows(jnp.int32), rows(jnp.int32), rows(jnp.float32))
+    vval = (rows(jnp.bool_),) * 3 if merge else (None,) * 3
+    fn = dist_ops._groupby_fn(
+        mesh, (SUM,) * 3, (0, 1, 2), (not merge,) * 3,
+        ((np.dtype(np.int32), False, False),))
+    assert _groupby.sort_operand_count(
+        (rows(jnp.uint32),), rows(jnp.bool_), vdat, vval, False) == operands
+    with jax.enable_x64(False):
+        text = fn.lower((rows(jnp.uint32),), (), (), rows(jnp.bool_), vdat,
+                        vval).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    sorts = _sort_lines(text)
+    assert len(sorts) == 1, sorts
+    args = re.search(r" sort\((.*?)\), dimensions", sorts[0]).group(1)
+    assert len(args.split(", ")) == operands, sorts[0]
+    assert "is_stable=true" not in sorts[0]
+    assert not re.search(r"\b(gather|scatter)\(", text)
+
+
+def test_dist_groupby_gather_program_still_compiles(shards4):
+    """The fallback the same function selects (a varbytes key: four
+    content-hash lanes with no way back): the stable sort with the row
+    index, the key's lengths and mask gathered at each group's first row."""
+    from cylon_tpu.parallel import dist_ops
+
+    mesh, shapes = shards4
+    rows = shapes(4 << 12)
+    SUM = _groupby.AggregationOp.SUM
+    fn = dist_ops._groupby_fn(mesh, (SUM,), (0,), (True,), None)
+    with jax.enable_x64(False):
+        text = fn.lower((rows(jnp.uint32),) * 4, (rows(jnp.int32),),
+                        (rows(jnp.bool_),), rows(jnp.bool_),
+                        (rows(jnp.float32),), (None,)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    sorts = _sort_lines(text)
+    assert len(sorts) == 1 and "is_stable=true" in sorts[0], sorts
+    assert re.search(r"\bgather\(", text)
