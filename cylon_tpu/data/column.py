@@ -67,6 +67,13 @@ class Column:
             return Column._encode_strings(arr, name, validity)
         if arr.dtype.kind == "M":  # datetime64
             unit = np.datetime_data(arr.dtype)[0]
+            if unit == "D":
+                # a date: days since the epoch in 32 bits (Arrow's
+                # date32), not a 64-bit timestamp
+                return Column(_to_device(arr.astype("int64")
+                                         .astype(np.int32)),
+                              dtypes.Date32(), _dev_mask(validity), None,
+                              name)
             dt = dtypes.Timestamp(_np_unit(unit))
             data = _to_device(arr.astype("int64"))
             return Column(data, dt, _dev_mask(validity), None, name)
@@ -86,6 +93,10 @@ class Column:
                         validity: Optional[np.ndarray]) -> "Column":
         from .strings import DICT_MAX_RATIO, DICT_MAX_VOCAB, VarBytes
 
+        if arr.dtype.kind == "U" and validity is None and arr.ndim == 1:
+            col = Column._encode_fixed_width(arr, name)
+            if col is not None:
+                return col
         obj = arr.astype(object)
         if validity is None:
             validity = np.array([v is not None and v == v for v in obj], dtype=bool)
@@ -123,6 +134,31 @@ class Column:
                      _dev_mask(validity if not validity.all() else None),
                      vocab, name)
         return col
+
+    @staticmethod
+    def _encode_fixed_width(arr: np.ndarray, name: str
+                            ) -> Optional["Column"]:
+        """`_encode_strings` for a fixed-width ``U`` array with no
+        validity, without a Python-level pass or an object copy (minutes
+        and ~10 GB at 7.5e7 rows): the same sorted vocabulary and the
+        same codes. One code point a string is compared as the integer it
+        is (its uint32 view orders as the string does). None where the
+        distinct values pass the dictionary's threshold: the general
+        path's varbytes branch takes those."""
+        from .strings import DICT_MAX_RATIO, DICT_MAX_VOCAB
+
+        n = len(arr)
+        thresh = min(DICT_MAX_VOCAB, max(16, int(n * DICT_MAX_RATIO)))
+        keys = arr.view(np.uint32) if arr.dtype.itemsize == 4 else arr
+        vocab = keys[:0]
+        for lo in range(0, n, 1 << 22):
+            vocab = np.union1d(vocab, np.unique(keys[lo: lo + (1 << 22)]))
+            if len(vocab) > thresh:
+                return None
+        codes = np.searchsorted(vocab, keys).astype(np.int32)
+        return Column(jnp.asarray(codes), dtypes.String(), None,
+                      vocab.view(arr.dtype) if keys is not arr else vocab,
+                      name)
 
     @staticmethod
     def from_varbytes(vb, validity=None, name: str = "",
@@ -332,6 +368,8 @@ class Column:
                 return data.astype(f"datetime64[{unit}]")
             if self.dtype.type == Type.DURATION:
                 return data.astype(f"timedelta64[{unit}]")
+            if self.dtype.type == Type.DATE32:
+                return data.astype("datetime64[D]")
         return data
 
     def to_pyarrow(self):

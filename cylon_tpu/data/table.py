@@ -22,6 +22,8 @@ distributed API (table.hpp:262-336).
 """
 from __future__ import annotations
 
+import functools
+import math
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -38,6 +40,7 @@ from .column import (Column, align_string_columns, as_varbytes,
 from .strings import concat_varbytes, pair_k_words
 from .. import telemetry as _telemetry
 from ..ops import aggregates as _aggregates
+from ..ops import expr as _expr
 from ..ops import groupby as _groupby
 from ..ops import join as _join
 from ..ops import order as _order
@@ -55,6 +58,11 @@ class Table:
         # by shuffle/distribute_by_key; lets a later shuffle on the same
         # keys skip the exchange (parallel/dist_ops.shuffle)
         self._hash_partitioned = None
+        # order witness: the live rows are in ascending order (nulls
+        # last) of the first `_key_ordered` columns, as a sort by them
+        # would leave them; set by the dense groupby, whose slot order
+        # is key order (`ordered_by`)
+        self._key_ordered = 0
         if columns:
             n = len(columns[0])
             for c in columns:
@@ -141,7 +149,7 @@ class Table:
         """Device bytes this table's buffers span — shape × itemsize,
         computed on the host with NO device sync. The telemetry layer's
         ``bytes`` measurement for EXPLAIN ANALYZE reports."""
-        return sum(int(np.dtype(a.dtype).itemsize) * int(np.prod(a.shape))
+        return sum(a.dtype.itemsize * math.prod(a.shape)
                    for a in self.buffers())
 
     def emit_mask(self) -> jnp.ndarray:
@@ -370,10 +378,37 @@ class Table:
         no count round-trip, no gather. Memory for the dead rows is
         reclaimed at the next shuffle/compact (both drop masked rows)."""
         mask = jnp.asarray(mask)
-        keep = mask & self.emit_mask()
+        # no AND with an all-ones mask: each is a program and a pass
+        keep = mask if self.row_mask is None else mask & self.row_mask
         t = Table(list(self._columns), self._ctx, keep)
         t._hash_partitioned = self._hash_partitioned
         return t
+
+    def with_columns(self, names: Sequence[str], exprs: Sequence[tuple]
+                     ) -> "Table":
+        """This table and, after its columns, one computed column an
+        expression: integer token trees over column POSITIONS
+        (ops/expr.py; expression i may read computed column j < i at
+        position ``column_count + j``). Exact or not at all: ONE program
+        probes the range of every column read (`jit_expr_ranges_program`;
+        the fetch is ``sync.expr.range``), the host shows from the ranges
+        that no step can leave its width - or raises, naming the column
+        and the step - and ONE program evaluates every expression
+        (`jit_expr_compute_program`), each step in the narrowest form its
+        range allows. A computed value is null where a column it reads
+        is. Elementwise: a sharded table stays as it is sharded."""
+        return _with_columns(self, list(names), list(exprs))
+
+    def ordered_by(self, by: Sequence, ascending=True) -> bool:
+        """Whether the live rows are known to be in the order that
+        ``sort(by, ascending)`` would leave them in (a witness set by the
+        operator that produced the table; False says nothing)."""
+        by = [self._col_index(c) for c in
+              (by if isinstance(by, (list, tuple)) else [by])]
+        asc = ascending if isinstance(ascending, (list, tuple)) \
+            else [ascending] * len(by)
+        return 0 < len(by) <= self._key_ordered and all(asc) \
+            and by == list(range(len(by)))
 
     def slice(self, start: int, stop: int) -> "Table":
         t = self.compact()
@@ -604,7 +639,8 @@ class Table:
             else:
                 o = other
                 res = _CMP[op](c.data, o)
-            res = res & c.valid_mask()
+            if c.validity is not None:
+                res = res & c.validity
             out_cols.append(Column(res, dtypes.Bool(), None, None, c.name))
         return Table(out_cols, self._ctx, t.row_mask)
 
@@ -1438,8 +1474,11 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
     idx_cols = index_col if isinstance(index_col, (list, tuple)) else [index_col]
     idx_cols = [table._col_index(c) for c in idx_cols]
     val_cols = [table._col_index(c) for c in aggregate_cols]
-    refuse_planes([table._columns[i] for i in idx_cols + val_cols],
-                  "groupby")
+    # a plane-held int64 VALUE column is summed by the dense table
+    # alone (`_groupby_dense`); what is left of the path refuses it
+    refuse_planes([table._columns[i] for i in idx_cols], "groupby")
+    refuse_planes([c for c in (table._columns[i] for i in val_cols)
+                   if c.dtype.type != dtypes.Type.INT64], "groupby")
     ops = [(_groupby.second_phase_op(o) if second_phase else o)
            for o in aggregate_ops]
 
@@ -1462,6 +1501,16 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
                                       valids, ops, aggregate_ops)
     if dense is not None:
         return dense
+    for c in (table._columns[i] for i in val_cols):
+        if c.is_planes:
+            raise CylonError(
+                Code.NotImplemented,
+                f"groupby: column {c.name!r} is INT64 held as two 32-bit "
+                f"word planes (jax_enable_x64 is off); only the dense "
+                f"table sums such a column (SUM / COUNT / MEAN by integer "
+                f"keys whose observed ranges span at most "
+                f"{_groupby.DENSE_MAX_SLOTS} slots, on one device), and "
+                f"this groupby has to sort")
     keys = []
     for c in key_columns:
         if c.is_varbytes:
@@ -1529,7 +1578,7 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
     for (arr, avalid), vi, op in zip(results, val_cols, aggregate_ops):
         src = table._columns[vi]
         out_cols.append(Column(
-            arr, _agg_dtype(src, op), avalid & group_valid,
+            arr, _agg_dtype(src, op, arr), avalid & group_valid,
             src.dictionary if op in (_groupby.AggregationOp.MIN,
                                      _groupby.AggregationOp.MAX)
             and src.is_string else None,
@@ -1612,23 +1661,33 @@ def _groupby_sort_packing(table: Table, key_column: Column, key_range,
 def _groupby_dense(table: Table, key_columns, val_cols, values, valids, ops,
                    aggregate_ops):
     """(the groupby over few groups, with no sort, or None where
-    `ops/groupby.group_path` says "sort"; the key's observed (lo, hi), or
-    None where nothing was probed). The static conditions cost
-    nothing; only a table that meets them (and lives on ONE device: the
-    kernel is no sharded program) pays the probe, a fused min / max over
-    the key and the fetch of the pair (`sync.groupby.keyrange`). With
-    the observed range inside DENSE_MAX_SLOTS the dense program runs and
-    nothing more is fetched: `_pow2(range)` slots, slot s the key
-    ``lo + s``, ``row_mask`` the slots a live row reached."""
+    `ops/groupby.group_path` says "sort"; ONE key's observed (lo, hi), or
+    None where nothing was probed or there are several). The static
+    conditions cost nothing; only a table that meets them (and lives on
+    ONE device: the kernel is no sharded program) pays the probe, a
+    fused min / max over the keys and the fetch of the array
+    (`sync.groupby.keyrange`). With the observed slots inside
+    DENSE_MAX_SLOTS the dense program runs: `_pow2(slots)` slots in key
+    order, ``row_mask`` the slots a live row reached.
+
+    ONE key column and 32-bit values is the path as it always was
+    (`jit_groupby_key_range`, `jit_groupby_dense`). Several key columns,
+    or a plane-held int64 value column, take `_groupby_dense_keys`."""
     kdtypes = [None if c.is_varbytes else np.dtype(c.data.dtype)
                for c in key_columns]
     knull = [c.validity is not None for c in key_columns]
-    vdtypes = [None if table._columns[i].is_varbytes else np.dtype(v.dtype)
-               for i, v in zip(val_cols, values)]
+    vcols = [table._columns[i] for i in val_cols]
+    vdtypes = [_groupby.PLANES_INT64 if c.is_planes
+               else None if c.is_varbytes else np.dtype(c.data.dtype)
+               for c in vcols]
     n = table.capacity
     if _groupby.group_path(kdtypes, knull, vdtypes, ops, n) != "dense" \
             or len(key_columns[0].data.sharding.device_set) != 1:
         return None, None
+    if len(key_columns) > 1 or _groupby.PLANES_INT64 in vdtypes:
+        return _groupby_dense_keys(table, key_columns, vcols, val_cols,
+                                   values, valids, ops, aggregate_ops,
+                                   (kdtypes, knull, vdtypes)), None
     kc = key_columns[0]
     emit = table.row_mask
     lohi = _groupby_key_range_fn()(kc.data, emit, kc.validity)
@@ -1641,27 +1700,287 @@ def _groupby_dense(table: Table, key_columns, val_cols, values, valids, ops,
         # a key that holds no null keeps its range for the sort's packing
         return None, None if knull[0] else (lo, hi)
     slots = _pow2(key_range + knull[0])
-    # counted where the host evaluates the decision (the sort path
-    # counts its reduce step, `stream` or `segment`, below)
-    _telemetry.counter("cylon_groupby_reduce_path_total",
-                       {"path": "dense"}).inc()
-    _telemetry.counter("cylon_groupby_dense_slots_total").inc(slots)
+    _count_dense(slots, 1)
     key_data, key_validity, group_valid, results = _groupby_dense_fn(
         slots, tuple(ops), tuple(val_cols),
         jax.default_backend() != "tpu")(
             kc.data, kc.validity, emit, lohi, values, valids)
     out_cols = [Column(key_data, kc.dtype, key_validity, kc.dictionary,
                        kc.name)]
-    for (arr, avalid), vi, op in zip(results, val_cols, aggregate_ops):
-        src = table._columns[vi]
-        out_cols.append(Column(arr, _agg_dtype(src, op), avalid, None,
+    for (arr, avalid), src, op in zip(results, vcols, aggregate_ops):
+        out_cols.append(Column(arr, _agg_dtype(src, op, arr), avalid, None,
                                src.name))
-    return Table(out_cols, table._ctx, group_valid), None
+    out = Table(out_cols, table._ctx, group_valid)
+    out._key_ordered = 1
+    return out, None
 
 
-def _agg_dtype(src: Column, op) -> dtypes.DataType:
-    if op == _groupby.AggregationOp.COUNT:
-        return dtypes.Int64()
-    if op == _groupby.AggregationOp.MEAN:
-        return dtypes.Double()
+def _count_dense(slots: int, keys: int) -> None:
+    """Counted where the host evaluates the decision (the sort path
+    counts its reduce step, `stream` or `segment`, where it is taken)."""
+    _telemetry.counter("cylon_groupby_reduce_path_total",
+                       {"path": "dense"}).inc()
+    _telemetry.counter("cylon_groupby_dense_slots_total").inc(slots)
+    _telemetry.counter("cylon_groupby_dense_keys_total").inc(keys)
+
+
+@_telemetry.counted_cache
+def _groupby_ranges_program_fn():
+    """The probe of a dense groupby over several keys: every key's (lo,
+    hi) over its live rows, ONE array (`jit_groupby_ranges_program`)."""
+    def kernel(keys, emit, key_valids):
+        return _groupby.ranges_probe(keys, emit, key_valids)
+
+    return jax.jit(kernel)
+
+
+@_telemetry.counted_cache
+def _groupby_dense_keys_fn(slots: int, ops: tuple, col_ids: tuple,
+                           wide: tuple, interpret: bool):
+    """The no-sort groupby over several keys and 64-bit sums
+    (`jit_groupby_dense_keys`: ops/groupby.dense_aggregate_keys, the
+    Pallas pass inside); the live groups' count and the overflow bits
+    ride out with it as one array of two."""
+    def kernel(keys, key_valids, emit, ranges, values, valids):
+        key_cols, group_valid, results, overflow = \
+            _groupby.dense_aggregate_keys(
+                keys, key_valids, emit, ranges, values, valids, slots, ops,
+                col_ids, wide, interpret=interpret)
+        return key_cols, group_valid, results, jnp.stack(
+            [group_valid.sum(dtype=jnp.int32), overflow])
+
+    return jax.jit(kernel)
+
+
+@functools.lru_cache(maxsize=None)
+def _known_key_ranges(sizes: tuple):
+    """`ops/groupby.ranges_probe`'s array for keys whose values are known
+    to lie in [0, size): made once a tuple of sizes, kept on the device."""
+    return jnp.asarray(np.array([(0, size - 1, 0) for size in sizes],
+                                np.uint32))
+
+
+# the count of live groups the last `_groupby_dense_keys` of a shape (slots,
+# ops, columns, which are wide) found: the next one's cut is dispatched for
+# it before its own count is down (and then held to it)
+_last_live: dict = {}
+
+
+@partial(jax.jit, static_argnames=("n",))
+def _dense_cut_program(arrays, group_valid, n: int):
+    """The ``n`` live slots of every array, in slot order (rows are the
+    last axis; None stays None)."""
+    idx = jnp.nonzero(group_valid, size=n, fill_value=0)[0]
+    return jax.tree.map(lambda a: jnp.take(a, idx, axis=-1), arrays)
+
+
+def _groupby_dense_keys(table: Table, key_columns, vcols, val_cols, values,
+                        valids, ops, aggregate_ops, static):
+    """`_groupby_dense` for several key columns and for plane-held int64
+    value columns, or None where the observed slots say "sort". ONE probe
+    program and ONE fetch for all the keys (`sync.groupby.keyrange`), and
+    none where the keys' values are known without looking (dictionary
+    codes, bools) and span few enough slots.
+
+    A 64-bit column is summed exactly WHATEVER it holds: the kernel cuts
+    it into limbs and the program adds them up wider than an int64, so
+    nothing about the values is observed first; a total that is no int64
+    is seen in the second fetch (`sync.groupby.densegroups`: the live
+    groups' count and the overflow bits) and raises, naming the column.
+    Nothing wrapped is ever handed on. A result that holds a plane-held
+    column is cut to its live groups by that count (one small gather,
+    dispatched before the fetch for the count the last groupby of this
+    shape had, and again where this one's is another): its ``row_mask``
+    is None, as a join result's that holds planes."""
+    kdtypes, knull, vdtypes = static
+    n = table.capacity
+    emit = table.row_mask
+    keys = tuple(c.data for c in key_columns)
+    key_valids = tuple(c.validity for c in key_columns)
+    # a dictionary column's codes lie in [0, len(dictionary)) and a bool is
+    # 0 or 1: where every key is one of these and their slots fit, nothing
+    # is observed at all (no probe program, no fetch)
+    key_ranges = [max(len(c.dictionary), 1) if c.dictionary is not None
+                  else 2 if kd == np.bool_ else None
+                  for c, kd in zip(key_columns, kdtypes)]
+    if None not in key_ranges and _groupby.group_path(
+            kdtypes, knull, vdtypes, ops, n, key_ranges) == "dense":
+        ranges = _known_key_ranges(tuple(key_ranges))
+    else:
+        ranges = _groupby_ranges_program_fn()(keys, emit, key_valids)
+        fetched = np.asarray(_telemetry.host_fetch("groupby.keyrange",
+                                                   ranges))
+        key_ranges = []
+        for row, kd in zip(fetched, kdtypes):
+            lo, hi = _groupby.key_range_of(row, kd)
+            key_ranges.append(hi - lo + 1 if lo <= hi else 1)
+        if _groupby.group_path(kdtypes, knull, vdtypes, ops, n,
+                               key_ranges) != "dense":
+            return None
+    wide = tuple(d is _groupby.PLANES_INT64
+                 and op != _groupby.AggregationOp.COUNT
+                 for d, op in zip(vdtypes, ops))
+    live_slots = 1
+    for r, nullable in zip(key_ranges, knull):
+        live_slots *= r + nullable
+    slots = _pow2(live_slots)
+    _count_dense(slots, len(keys))
+    key_out, group_valid, results, tail = _groupby_dense_keys_fn(
+        slots, tuple(ops), tuple(val_cols), wide,
+        jax.default_backend() != "tpu")(
+            keys, key_valids, emit, ranges, values, valids)
+    arrays = [list(key_out), [tuple(r) for r in results]]
+
+    def result(arrays, mask):
+        out_cols = [Column(data, kc.dtype, validity, kc.dictionary, kc.name)
+                    for kc, (data, validity) in zip(key_columns, arrays[0])]
+        for (arr, avalid), src, op in zip(arrays[1], vcols, aggregate_ops):
+            out_cols.append(Column(arr, _agg_dtype(src, op, arr), avalid,
+                                   None, src.name))
+        out = Table(out_cols, table._ctx, mask)
+        out._key_ordered = len(keys)
+        return out
+
+    if not any(wide):
+        return result(arrays, group_valid)
+    # the cut is dispatched, and its table built, for the count of live
+    # groups the last groupby of this shape had, BEFORE this one's count is
+    # down: where it is the same nothing is left to do when the fetch returns
+    planes = any(arr.ndim == 2 for arr, _ in results)
+    shape = (slots, tuple(ops), tuple(val_cols), wide)
+    guess = _last_live.get(shape) if planes else None
+    out = None if guess is None else result(
+        _dense_cut_program(arrays, group_valid, n=guess), None)
+    live, overflow = (int(x) for x in _telemetry.host_fetch(
+        "groupby.densegroups", tail))
+    if overflow:
+        names = sorted({c.name for j, c in enumerate(vcols)
+                        if wide[j] and overflow >> (j % 31) & 1})
+        raise CylonError(
+            Code.Invalid,
+            f"groupby: the sum of column {', '.join(map(repr, names))} "
+            f"does not fit an int64 in some group; no result (nothing "
+            f"wrapped) is returned")
+    if not planes:
+        return result(arrays, group_valid)
+    if live != guess:
+        if len(_last_live) >= 64:
+            _last_live.clear()
+        _last_live[shape] = live
+        out = result(_dense_cut_program(arrays, group_valid, n=live), None)
+    return out
+
+
+@_telemetry.counted_cache
+def _expr_ranges_program_fn():
+    """The range of every column an expression reads, ONE array
+    (`jit_expr_ranges_program`)."""
+    def kernel(arrays):
+        return _expr.range_probe(arrays)
+
+    return jax.jit(kernel)
+
+
+@_telemetry.counted_cache
+def _expr_compute_program_fn(exprs: tuple, forms: tuple, out_dtypes: tuple,
+                             width: int):
+    """Every computed column of one `Table.with_columns`, and its
+    validity, in ONE program (`jit_expr_compute_program`); ``forms``
+    None: native int64 steps (x64 on). Expression i reads computed column
+    j < i at position ``width + j``; a value is null where a column it
+    reads is (``valids``: position -> mask, the nullable columns only)."""
+    def kernel(leaves, valids):
+        leaves, valids = dict(leaves), dict(valids)
+        out = []
+        for i, (tokens, dtype) in enumerate(zip(exprs, out_dtypes)):
+            col = _expr.evaluate_native(tokens, leaves, dtype) \
+                if forms is None else _expr.evaluate_words(
+                    tokens, forms[i], leaves, dtype)
+            masks = [valids[p] for p in sorted(_expr.columns_of(tokens))
+                     if p in valids]
+            validity = functools.reduce(jnp.logical_and, masks) \
+                if masks else None
+            leaves[width + i] = col
+            if validity is not None:
+                valids[width + i] = validity
+            out.append((col, validity))
+        return out
+
+    return jax.jit(kernel)
+
+
+def value_dtype(tokens, types) -> str:
+    """The dtype string ("int32" / "int64") of a value expression's
+    column over columns of ``types`` (the plan's type strings)."""
+    return _expr.result_dtype(tokens, types)
+
+
+# the forms the last `_with_columns` of a shape (expressions, dtypes, width)
+# proved: what the next one of that shape is dispatched with BEFORE its own
+# ranges are down (and then held to them)
+_last_forms: dict = {}
+
+
+def _with_columns(table: Table, names, exprs) -> Table:
+    """`Table.with_columns`. The probe is dispatched, then the compute
+    program with the forms the last call of this shape proved (with x64
+    on there are no forms to choose), and only then are the ranges
+    fetched (`sync.expr.range`): the chip computes while the host waits
+    and proves. `plan_forms` raises where a step cannot be shown to fit,
+    and forms other than the guessed ones compute again; no column leaves
+    this function that its own table's ranges have not proven."""
+    w = table.column_count
+    read = sorted({p for t in exprs for p in _expr.columns_of(t) if p < w})
+    cols = [table._columns[p] for p in read]
+    types = ["str" if c.is_string else str(c.host_dtype)
+             for c in table._columns]
+    out_dtypes = []
+    for tokens in exprs:    # raises on a column that is no integer
+        out_dtypes.append(_expr.result_dtype(tokens, types + out_dtypes))
+    probe = _expr_ranges_program_fn()(tuple(c.data for c in cols))
+    native = bool(jax.config.jax_enable_x64)
+    shape = (tuple(exprs), tuple(out_dtypes), w)
+
+    def compute(forms):
+        return _expr_compute_program_fn(shape[0], forms, *shape[1:])(
+            {p: c.data for p, c in zip(read, cols)},
+            {p: c.validity for p, c in zip(read, cols)
+             if c.validity is not None})
+
+    guess = None if native else _last_forms.get(shape)
+    computed = compute(guess) if native or guess is not None else None
+    fetched = _telemetry.host_fetch("expr.range", probe)
+    ranges = dict(zip(read, _expr.ranges_of(fetched)))
+    all_names = list(table.column_names) + list(names)
+    forms = []
+    for i, (tokens, dtype) in enumerate(zip(exprs, out_dtypes)):
+        f, ranges[w + i] = _expr.plan_forms(tokens, ranges, dtype, names[i],
+                                            all_names)
+        forms.append(f)
+    forms = None if native else tuple(forms)
+    if computed is None or forms != guess:
+        computed = None   # a wrong guess's columns go before the right ones
+        computed = compute(forms)
+        if len(_last_forms) >= 64:
+            _last_forms.clear()
+        _last_forms[shape] = forms
+    new_cols = [Column(arr, dtypes.from_np_dtype(np.dtype(dtype)), validity,
+                       None, name)
+                for (arr, validity), dtype, name in zip(computed, out_dtypes,
+                                                        names)]
+    _telemetry.counter("cylon_expr_columns_total").inc(len(new_cols))
+    _telemetry.counter("cylon_expr_materialized_bytes_total").inc(
+        sum(c.data.dtype.itemsize * math.prod(c.data.shape)
+            for c in new_cols))
+    out = Table(list(table._columns) + new_cols, table._ctx, table.row_mask)
+    out._hash_partitioned = table._hash_partitioned
+    return out
+
+
+def _agg_dtype(src: Column, op, arr) -> dtypes.DataType:
+    """The label of an aggregate's column: what ``arr`` IS. A COUNT is an
+    int64 and a MEAN a float64 only with x64 on; without it they are the
+    int32 and the float32 the chip computed, and say so."""
+    if op in (_groupby.AggregationOp.COUNT, _groupby.AggregationOp.MEAN):
+        return dtypes.from_np_dtype(np.dtype(arr.dtype))
     return src.dtype

@@ -345,13 +345,22 @@ def presort_groups(keys: Tuple[jnp.ndarray, ...], emit,
 
 _STREAM_DTYPES = (jnp.int32, jnp.float32)
 
+# what a caller puts in ``value_dtypes`` for an int64 column held as two
+# word planes (x64 off): the dense table sums it exactly, wider than 64
+# bits, from limbs the kernel cuts (`dense_aggregate_keys`); nothing else
+# takes it
+PLANES_INT64 = "int64-planes"
+
 
 def _accumulator_dtypes(dtype, op: AggregationOp):
     """What sorted_segment_aggregate accumulates ``op`` over a ``dtype``
-    column in (validity tallies are int32 whatever the column)."""
+    column in (validity tallies are int32 whatever the column). A
+    plane-held int64's SUM and MEAN accumulate int32 limbs."""
     canon = jax.dtypes.canonicalize_dtype
     if op == AggregationOp.COUNT:
         return (canon(jnp.int64),)
+    if dtype is PLANES_INT64:
+        return (np.dtype(np.int32),)
     if op == AggregationOp.MEAN:
         return (canon(jnp.float64), canon(jnp.int64))
     return (np.dtype(dtype),)
@@ -410,35 +419,42 @@ def group_path(key_dtypes, key_nullable, value_dtypes, ops, n: int,
     (`dense_aggregate`) — or ``"sort"``, the fused sort and the reduce
     step over its runs.
 
-    Static conditions first: ONE key column of an integer kind at most
+    Static conditions first: every key column of an integer kind at most
     32 bits wide (bool and dictionary codes are; ``key_dtypes`` holds
     None for a varbytes key), every op a SUM, COUNT or MEAN, every
-    summed column and every accumulator an int32 or a float32 (the
+    summed column an int32, a float32 or a plane-held int64
+    (``PLANES_INT64``) and every accumulator an int32 or a float32 (the
     kernel's lanes: under x64 a COUNT or a MEAN accumulates 8 bytes and
-    sorts), 1 <= n < 2^30. Only when they hold is the key's range worth
+    sorts), 1 <= n < 2^30. Only when they hold are the keys' ranges worth
     observing: called with ``key_range`` None the answer is ``"dense"``
     for "probe it". With the OBSERVED ``key_range`` (hi - lo + 1 over
-    the live rows; a nullable key takes one slot more) it is ``"dense"``
-    while the slots fit DENSE_MAX_SLOTS."""
-    if len(key_dtypes) != 1 or key_dtypes[0] is None:
+    the live rows, one number a key column, or the number itself for one
+    key; a nullable key takes one slot more) it is ``"dense"`` while the
+    PRODUCT of the keys' slots fits DENSE_MAX_SLOTS."""
+    if not key_dtypes or any(k is None for k in key_dtypes):
         return "sort"
-    kd = np.dtype(key_dtypes[0])
-    if kd.kind not in "iub" or kd.itemsize > 4:
-        return "sort"
+    for kd in map(np.dtype, key_dtypes):
+        if kd.kind not in "iub" or kd.itemsize > 4:
+            return "sort"
     if not 1 <= n < (1 << 30):
         return "sort"
     for dtype, op in zip(value_dtypes, ops):
         if op not in _DENSE_OPS:
             return "sort"
-        if op != AggregationOp.COUNT and (dtype is None
-                                          or dtype not in _STREAM_DTYPES):
+        if op != AggregationOp.COUNT and dtype is not PLANES_INT64 and (
+                dtype is None or dtype not in _STREAM_DTYPES):
             return "sort"
         if any(a not in _STREAM_DTYPES
                for a in _accumulator_dtypes(dtype, op)):
             return "sort"
-    if key_range is not None and \
-            key_range + bool(key_nullable[0]) > DENSE_MAX_SLOTS:
-        return "sort"
+    if key_range is not None:
+        ranges = key_range if isinstance(key_range, (list, tuple)) \
+            else [key_range]
+        slots = 1
+        for r, nullable in zip(ranges, key_nullable):
+            slots *= r + bool(nullable)
+        if slots > DENSE_MAX_SLOTS:
+            return "sort"
     return "dense"
 
 
@@ -695,6 +711,38 @@ def dense_aggregate(key, key_valid, emit, lohi,
     if emit is not None:
         k32 = jnp.where(emit, k32, lo - jnp.int32(1))
 
+    streams, kinds, plan = _dense_streams(values, valids, ops, col_ids)
+    rows, sums = tk.groupby_dense_reduce(k32, lo, live_slots, streams, kinds,
+                                         slots, interpret=interpret)
+    group_valid = rows > 0
+    results, _ = _dense_results(rows, sums, plan, group_valid)
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    # the null group's slot holds a key of the column's own (a
+    # dictionary code has to stay inside its dictionary)
+    kd = lo + jnp.where(slot == key_range, jnp.int32(0), slot)
+    key_data = _key_from_int32(kd, key.dtype)
+    key_validity = None if key_valid is None else \
+        group_valid & (slot != key_range)
+    return key_data, key_validity, group_valid, results
+
+
+def _key_from_int32(kd, dtype):
+    """`_key_as_int32`'s inverse."""
+    if dtype == jnp.bool_:
+        return kd != 0
+    if dtype == jnp.uint32:
+        return jax.lax.bitcast_convert_type(kd, jnp.uint32)
+    return kd.astype(dtype)
+
+
+def _dense_streams(values, valids, ops, col_ids, wide=()):
+    """The distinct streams the dense kernel sums for these aggregates
+    (``col_ids`` name the source columns: a stream two ops share runs
+    once) and, an op, where its numbers are: (streams, kinds, plan), plan
+    entries (op, total, count) with ``count`` None for the kernel's own
+    row count. ``wide[j]`` says value j is a plane-held int64 column
+    (``uint32[2, n]``): its stream is the array itself, a null row's
+    words as zero."""
     streams, kinds, where_ = [], [], {}
 
     def stream(name, kind, make):
@@ -704,13 +752,21 @@ def dense_aggregate(key, key_valid, emit, lohi,
             kinds.append(kind)
         return where_[name]
 
+    def planes(arr, vmask):
+        return arr if vmask is None else jnp.where(vmask[None, :], arr,
+                                                   jnp.uint32(0))
+
     plan = []
-    for arr, vmask, op, cid in zip(values, valids, ops, col_ids):
+    for j, (arr, vmask, op, cid) in enumerate(zip(values, valids, ops,
+                                                  col_ids)):
         # rows a slot for an all-valid column: the kernel's own count
         count = None if vmask is None else stream(
             ("count", cid), "int", lambda: vmask.astype(jnp.int32))
         total = None
-        if op != AggregationOp.COUNT:
+        if op != AggregationOp.COUNT and j < len(wide) and wide[j]:
+            total = ("planes", stream(("planes", cid), "planes",
+                                      lambda: planes(arr, vmask)))
+        elif op != AggregationOp.COUNT:
             is_sum_int = op == AggregationOp.SUM and arr.dtype == jnp.int32
             total = stream(
                 ("int" if is_sum_int else "float", cid),
@@ -718,30 +774,135 @@ def dense_aggregate(key, key_valid, emit, lohi,
                 lambda: arr if vmask is None
                 else jnp.where(vmask, arr, jnp.zeros((), arr.dtype)))
         plan.append((op, total, count))
-    rows, sums = tk.groupby_dense_reduce(k32, lo, live_slots, streams, kinds,
-                                         slots, interpret=interpret)
-    group_valid = rows > 0
+    return streams, kinds, plan
+
+
+def _dense_results(rows, sums, plan, group_valid):
+    """(results, overflow): one (array, valid) an op of `_dense_streams`'
+    plan, off the kernel's count and sums. A plane-held int64's SUM is
+    its exact total as ``uint32[2, slots]`` planes; its MEAN that total
+    over the exact count in ONE float32 division carried past 24 bits
+    (`wideint.divide_float32`): within about 2^-24 of the true mean.
+    The kernel's total is wider than 64 bits: ``overflow`` (an int32) has
+    bit j set when op j's total, in some slot, is no int64 - the caller
+    raises, nothing wrapped is handed on."""
+    from . import wideint as W
+
     canon = jax.dtypes.canonicalize_dtype
-    results = []
-    for op, total, count in plan:
+    results, overflow = [], jnp.int32(0)
+    for j, (op, total, count) in enumerate(plan):
         c = rows if count is None else sums[count]
         if op == AggregationOp.COUNT:
             results.append((c.astype(canon(jnp.int64)), group_valid))
+        elif isinstance(total, tuple):
+            w = sums[total[1]]
+            overflow = overflow | (jnp.any(~W.fits_int64(w)).astype(
+                jnp.int32) << (j % 31))
+            t64 = (w[1], w[2])
+            if op == AggregationOp.SUM:
+                results.append((jnp.stack(t64), group_valid & (c > 0)))
+            else:
+                den = W.to_float32_pair(W.from_int32(jnp.maximum(c, 1)))
+                results.append((W.divide_float32(W.to_float32_pair(t64),
+                                                 den),
+                                group_valid & (c > 0)))
         elif op == AggregationOp.MEAN:
             results.append(_mean(sums[total].astype(canon(jnp.float64)),
                                  c, group_valid))
         else:
             results.append((sums[total], group_valid & (c > 0)))
+    return results, overflow
+
+
+def ranges_probe(keys, emit, key_valids):
+    """``uint32[len(keys), 3]``, ONE array to fetch: a row a key column,
+    (lo, hi, empty) with lo and hi the bits of `key_range_probe`'s pair
+    as an int32 (`_key_as_int32`) and ``empty`` 1 when no live row holds
+    a key."""
+    rows = []
+    for key, kv in zip(keys, key_valids):
+        lohi = key_range_probe(key, emit, kv)
+        words = jax.lax.bitcast_convert_type(_key_as_int32(lohi),
+                                             jnp.uint32)
+        rows.append(jnp.stack([words[0], words[1],
+                               (lohi[0] > lohi[1]).astype(jnp.uint32)]))
+    return jnp.stack(rows)
+
+
+def key_range_of(words, dtype) -> tuple:
+    """(lo, hi) of a key column of ``dtype`` as Python integers from its
+    row of `ranges_probe` on the host; lo > hi when ``empty`` is set."""
+    lo, hi, empty = (int(w) for w in words[:3])
+    if empty:
+        return 1, 0
+    if np.dtype(dtype) != np.uint32:     # the words are an int32's bits
+        lo, hi = (w - (1 << 32) if w >> 31 else w for w in (lo, hi))
+    return lo, hi
+
+
+def dense_aggregate_keys(keys, key_valids, emit, ranges,
+                         values: Tuple[jnp.ndarray, ...],
+                         valids: Tuple[jnp.ndarray, ...], slots: int,
+                         ops: Tuple[AggregationOp, ...],
+                         col_ids: Tuple[int, ...],
+                         wide: Tuple[bool, ...] = (),
+                         interpret: bool = False):
+    """`dense_aggregate` over SEVERAL key columns and over plane-held
+    int64 value columns. Key column i spans ``range_i`` slots (one more
+    when it is nullable: its null group, last), and a row's slot is its
+    keys' digits in that mixed radix, the first key the most significant:
+    slot order is key order, nulls last. ``ranges`` is `ranges_probe`'s
+    array as it left the device, so one program serves every set of
+    ranges whose slots round to ``slots`` (the kernel is handed the
+    composite slot with lo = 0).
+
+    ``wide[j]`` says value column j is a plane-held int64
+    (``uint32[2, n]``): the kernel cuts it into limbs and sums it exactly,
+    whatever its values (nothing about them is observed first).
+
+    Returns (key_columns, group_valid, results, overflow): key_columns
+    one (data, validity or None) a key, (slots,) each; ``overflow`` as
+    `_dense_results` gives it."""
+    from . import tpu_kernels as tk
+
+    nk = len(keys)
+    i32 = lambda w: jax.lax.bitcast_convert_type(w, jnp.int32)
+    rel = jnp.int32(0)
+    total = jnp.int32(1)
+    spans = []
+    for i, (key, kv) in enumerate(zip(keys, key_valids)):
+        empty = ranges[i, 2] != 0
+        lo = jnp.where(empty, jnp.int32(0), i32(ranges[i, 0]))
+        key_range = jnp.where(empty, jnp.int32(1),
+                              i32(ranges[i, 1]) - i32(ranges[i, 0])
+                              + jnp.int32(1))
+        d = _key_as_int32(key) - lo
+        span = key_range
+        if kv is not None:
+            d = jnp.where(kv, d, key_range)
+            span = key_range + jnp.int32(1)
+        rel = rel * span + d
+        total = total * span
+        spans.append((lo, key_range, span))
+    if emit is not None:
+        rel = jnp.where(emit, rel, jnp.int32(-1))
+    streams, kinds, plan = _dense_streams(values, valids, ops, col_ids, wide)
+    rows, sums = tk.groupby_dense_reduce(rel, jnp.int32(0), total, streams,
+                                         kinds, slots, interpret=interpret)
+    group_valid = rows > 0
+    results, overflow = _dense_results(rows, sums, plan, group_valid)
+    # a slot's digits, the last key's first
     slot = jnp.arange(slots, dtype=jnp.int32)
-    # the null group's slot holds a key of the column's own (a
-    # dictionary code has to stay inside its dictionary)
-    kd = lo + jnp.where(slot == key_range, jnp.int32(0), slot)
-    key_data = kd != 0 if key.dtype == jnp.bool_ else (
-        jax.lax.bitcast_convert_type(kd, jnp.uint32)
-        if key.dtype == jnp.uint32 else kd.astype(key.dtype))
-    key_validity = None if key_valid is None else \
-        group_valid & (slot != key_range)
-    return key_data, key_validity, group_valid, results
+    key_columns = [None] * nk
+    for i in reversed(range(nk)):
+        lo, key_range, span = spans[i]
+        d = slot % span
+        slot = slot // span
+        data = _key_from_int32(
+            lo + jnp.where(d == key_range, jnp.int32(0), d), keys[i].dtype)
+        key_columns[i] = (data, None if key_valids[i] is None
+                          else group_valid & (d != key_range))
+    return tuple(key_columns), group_valid, tuple(results), overflow
 
 
 presort_groups_jit = partial(jax.jit, static_argnames=("index", "plan"))(

@@ -51,6 +51,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import wideint as W
+from .wideint import two_sum as _two_sum
+
 LANES = 128
 _I32MAX = jnp.iinfo(jnp.int32).max
 # window rows ONE (8, 128) vreg of join_expand_stream's outputs can reach:
@@ -1487,7 +1490,15 @@ def groupby_run_reduce(new_grp: jnp.ndarray, emit: jnp.ndarray,
 # relative, and the wrapper folds the 128 pairs pairwise with two-sums
 # and rounds ONCE. (Adding the blocks' partials one after another into a
 # plain float32 slot is a random walk of half-ulps: ~120 units of 2^-24
-# at 1e5 blocks.)
+# at 1e5 blocks.) A "planes" stream is an int64 column as its two word
+# planes: each block is cut, in the kernel, into three limbs of 22, 22
+# and 20 bits (the last signed, biased to be non-negative), so that a
+# block's partial of a limb (at most 512 of them a lane: such a pass runs
+# blocks of 512 rows) is an int32 that cannot wrap; a limb's partial joins
+# an int32 (hi, lo) pair, lo kept under 2^31 and its carry counted in hi,
+# so the table holds each lane's sums exactly whatever the rows, and the
+# wrapper adds lanes and limbs in 96 bits (`wideint`): the EXACT sum of
+# the int64s, wider than an int64, for the caller to check and cut.
 # ---------------------------------------------------------------------------
 
 DENSE_CHUNK = 8   # slots swept together: the rows of one table tile
@@ -1496,13 +1507,11 @@ DENSE_CHUNK = 8   # slots swept together: the rows of one table tile
 # groupby-q4's shape the pass takes 35.9 ms at 1, 20.5 at 8, 19.5 at 16,
 # 18.8 at 32, 18.2 with the block's 128 (PERF.md section 6, PR 34)
 DENSE_UNROLL = 16
-
-
-def _two_sum(a, b):
-    """(s, e): s = fl(a + b) and a + b = s + e exactly (Knuth)."""
-    s = a + b
-    bb = s - a
-    return s, (a - (s - bb)) + (b - bb)
+# a "planes" stream's limbs: bits of the two low ones (the third takes the
+# 20 that are left, sign included), and the rows of a block of such a
+# pass: 512 limbs of 22 bits a lane stay under 2^31
+DENSE_LIMB_BITS = 22
+DENSE_PLANES_BLOCK_ROWS = 512
 
 
 def _fold_pairs(hi, lo):
@@ -1519,10 +1528,9 @@ def _fold_pairs(hi, lo):
 
 
 def groupby_dense_reduce(keys: jnp.ndarray, lo, live_slots,
-                         streams: Sequence[jnp.ndarray],
-                         kinds: Sequence[str], slots: int,
+                         streams: Sequence, kinds: Sequence[str], slots: int,
                          block_rows: int = 1024, interpret: bool = False
-                         ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
+                         ) -> Tuple[jnp.ndarray, Tuple]:
     """Per-slot count and per-slot sums of unsorted rows: row r belongs
     to slot ``keys[r] - lo`` (int32, wrapping) and to none when that lies
     outside [0, live_slots) — how a caller keeps a dead row out is to
@@ -1534,22 +1542,45 @@ def groupby_dense_reduce(keys: jnp.ndarray, lo, live_slots,
     float32; kinds[k] is ``"int"`` (an int32 stream, its exact wrapping
     int32 sum) or ``"float"`` (a float32 sum, compensated as described
     above; an int32 stream is converted to float32 element by element
-    first, once a block). What a row outside every slot holds is never
-    read into a result.
+    first, once a block) or ``"planes"`` (the stream is an int64
+    column's ``uint32[2, n]`` word planes: its exact sum, in 96 bits; the
+    kernel reads the array as (rows, 2, 128) blocks, which is how its
+    (2, 128) tiles lie in HBM already, so all it costs before the kernel
+    is the padding to whole rows). What a row outside every slot holds is
+    never read into a result.
 
     Returns (count, sums): count (slots,) int32 rows a slot, sums[k]
-    (slots,) int32 or float32; slots at and past live_slots read 0.
+    (slots,) int32 or float32, for a "planes" stream the triple (w2, w1,
+    w0) of (slots,) uint32 words of the sum as a 96-bit two's-complement
+    integer, most significant first; slots at and past live_slots read 0.
     """
     n = keys.shape[0]
     K = len(streams)
     U = DENSE_CHUNK
     assert keys.dtype == jnp.int32 and len(kinds) == K
     assert 1 <= n < (1 << 30) and slots >= 1
+    # the kernel's inputs ((n,) arrays and (2, n) planes), and the LANES
+    # it accumulates: (how it joins the table, where a block's values
+    # are: an input or a scratch)
+    ins, lanes, lanes_of = [], [], []
     for s, kind in zip(streams, kinds):
-        assert s.shape == (n,) and kind in ("int", "float")
-        assert s.dtype == jnp.int32 or (kind == "float"
-                                        and s.dtype == jnp.float32), \
+        assert kind in ("int", "float", "planes")
+        lanes_of.append(len(lanes))
+        if kind == "planes":
+            assert s.shape == (2, n) and s.dtype == jnp.uint32
+            lanes += [("wide", ("limb", len(ins), j)) for j in range(3)]
+            ins.append(s)
+            continue
+        assert s.shape == (n,) and (
+            s.dtype == jnp.int32 or (kind == "float"
+                                     and s.dtype == jnp.float32)), \
             f"groupby_dense_reduce: a {kind} stream of {s.dtype}"
+        # an int32 column summed as float is converted in the kernel
+        conv = kind == "float" and s.dtype == jnp.int32
+        lanes.append((kind, ("conv" if conv else "in", len(ins))))
+        ins.append(s)
+    if "planes" in kinds:
+        block_rows = min(block_rows, DENSE_PLANES_BLOCK_ROWS)
     step_rows = 8 * DENSE_UNROLL
     BR = min(block_rows, -(-rows_for(n) // step_rows) * step_rows)
     assert BR % step_rows == 0
@@ -1558,26 +1589,55 @@ def groupby_dense_reduce(keys: jnp.ndarray, lo, live_slots,
     blocks = -(-rows // BR)          # the last block may be ragged
     SP = -(-slots // U) * U          # table rows: whole chunks
     k2 = pad_rows(keys, rows)
-    s2 = [pad_rows(s, rows) for s in streams]
-    # streams converted in the kernel (an int32 column summed as float)
-    conv = [k for k in range(K)
-            if kinds[k] == "float" and streams[k].dtype == jnp.int32]
-    # table arrays: the count, then one an "int" stream and (hi, lo) a
-    # "float" one
+    stream_spec = pl.BlockSpec((BR, LANES), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM)
+    s2, in_specs = [], []
+    for s in ins:
+        if s.ndim == 1:
+            s2.append(pad_rows(s, rows))
+            in_specs.append(stream_spec)
+            continue
+        # (2, n) -> (rows, 2, 128): the array's own tiles, padded
+        s2.append(jnp.swapaxes(jax.lax.bitcast_convert_type(jnp.pad(
+            s, ((0, 0), (0, rows * LANES - n))), jnp.int32).reshape(
+                2, rows, LANES), 0, 1))
+        in_specs.append(pl.BlockSpec((BR, 2, LANES), lambda i: (i, 0, 0),
+                                     memory_space=pltpu.VMEM))
+    # scratch: one array a lane whose values the kernel makes
+    made = [src for _, src in lanes if src[0] != "in"]
+    # table arrays: the count, then one an "int" lane and (hi, lo) a
+    # "float" or a "wide" one
     tab_dtypes = [jnp.int32]
     tab_of = []
-    for kind in kinds:
+    for how, _ in lanes:
         tab_of.append(len(tab_dtypes))
-        tab_dtypes += [jnp.int32] if kind == "int" else [jnp.float32] * 2
-    T = len(tab_dtypes)
-    acc_dtypes = [jnp.int32] + [jnp.int32 if kind == "int" else jnp.float32
-                                for kind in kinds]
+        tab_dtypes += {"int": [jnp.int32], "float": [jnp.float32] * 2,
+                       "wide": [jnp.int32] * 2}[how]
+    T, L, I = len(tab_dtypes), len(lanes), len(ins)
+    acc_dtypes = [jnp.int32] + [jnp.float32 if how == "float" else jnp.int32
+                                for how, _ in lanes]
+    low = np.int32((1 << DENSE_LIMB_BITS) - 1)
+    top_shift = 2 * DENSE_LIMB_BITS - 32         # the hi word's low bits
+    bias = np.int32(1 << (63 - 2 * DENSE_LIMB_BITS))
+
+    def limb(hi, lo_, j):
+        """Limb j of the int64 (hi, lo_): value = l0 + l1 * 2^22 + t *
+        2^44, t the signed top 20 bits (biased by 2^19 here: every limb
+        is a non-negative int32)."""
+        if j == 0:
+            return lo_ & low
+        if j == 1:
+            return jax.lax.shift_right_logical(
+                lo_, np.int32(DENSE_LIMB_BITS)) | (
+                    (hi & np.int32((1 << top_shift) - 1))
+                    << np.int32(32 - DENSE_LIMB_BITS))
+        return (hi >> np.int32(top_shift)) + bias
 
     def kernel(par_ref, k_ref, *rest):
-        srefs = list(rest[:K])
-        tabs = rest[K:K + T]
-        rel_ref = rest[K + T]
-        conv_refs = dict(zip(conv, rest[K + T + 1:]))
+        irefs = list(rest[:I])
+        tabs = rest[I:I + T]
+        rel_ref = rest[I + T]
+        made_refs = dict(zip(made, rest[I + T + 1:]))
         i = pl.program_id(0)
 
         @pl.when(i == 0)
@@ -1594,9 +1654,16 @@ def groupby_dense_reduce(keys: jnp.ndarray, lo, live_slots,
                       < np.int32(n))
             rel = jnp.where(inside, rel, np.int32(-1))
         rel_ref[:] = rel
-        for k in conv:
-            conv_refs[k][:] = srefs[k][:].astype(jnp.float32)
-        vrefs = [conv_refs.get(k, srefs[k]) for k in range(K)]
+        for src, ref in made_refs.items():
+            if src[0] == "conv":
+                ref[:] = irefs[src[1]][:].astype(jnp.float32)
+        for at in sorted({src[1] for src in made if src[0] == "limb"}):
+            # each plane is read once (a strided read: one sublane a tile)
+            hi, lo_ = irefs[at][:, 0, :], irefs[at][:, 1, :]
+            for j in range(3):
+                made_refs[("limb", at, j)][:] = limb(hi, lo_, j)
+        vrefs = [irefs[src[1]] if src[0] == "in" else made_refs[src]
+                 for _, src in lanes]
         sub = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0)
 
         def sweep(s0):
@@ -1641,10 +1708,17 @@ def groupby_dense_reduce(keys: jnp.ndarray, lo, live_slots,
                                      sweep(s0), zero)
             at = pl.ds(pl.multiple_of(s0, 8), 8)
             tabs[0][at, :] = tabs[0][at, :] + tile(accs, 0)
-            for k in range(K):
+            for k, (how, _) in enumerate(lanes):
                 t, p = tab_of[k], tile(accs, 1 + k)
-                if kinds[k] == "int":
+                if how == "int":
                     tabs[t][at, :] = tabs[t][at, :] + p
+                elif how == "wide":
+                    # lo and p are both under 2^31: a sum past it shows
+                    # as the sign bit, which moves to hi
+                    lo_ = tabs[t + 1][at, :] + p
+                    tabs[t + 1][at, :] = lo_ & np.int32(0x7FFFFFFF)
+                    tabs[t][at, :] = tabs[t][at, :] + jnp.where(
+                        lo_ < 0, np.int32(1), np.int32(0))
                 else:
                     hi, e = _two_sum(tabs[t][at, :], p)
                     tabs[t][at, :] = hi
@@ -1654,24 +1728,24 @@ def groupby_dense_reduce(keys: jnp.ndarray, lo, live_slots,
         chunks = (par_ref[1] + np.int32(U - 1)) // np.int32(U)
         jax.lax.fori_loop(np.int32(0), chunks, chunk, np.int32(0))
 
-    stream_spec = pl.BlockSpec((BR, LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM)
     tab_spec = pl.BlockSpec((SP, LANES), lambda i: (0, 0),
                             memory_space=pltpu.VMEM)
     # the table and every input block are held twice (the pipeline's
     # buffers), the scratch once
     vmem = (2 * T * SP * LANES * 4
-            + (2 * (1 + K) + 1 + len(conv)) * span * 4 + (16 << 20))
+            + (2 * (1 + sum(s.ndim for s in ins)) + 1 + len(made)) * span * 4
+            + (16 << 20))
     res = pl.pallas_call(
         kernel,
         name="groupby_dense_reduce",
         out_shape=[jax.ShapeDtypeStruct((SP, LANES), d) for d in tab_dtypes],
         grid=(blocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [stream_spec] * (1 + K),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), stream_spec]
+        + in_specs,
         out_specs=[tab_spec] * T,
         scratch_shapes=[pltpu.VMEM((BR, LANES), jnp.int32)]
-        + [pltpu.VMEM((BR, LANES), jnp.float32) for _ in conv],
+        + [pltpu.VMEM((BR, LANES), jnp.float32 if src[0] == "conv"
+                      else jnp.int32) for src in made],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
     )
@@ -1680,8 +1754,33 @@ def groupby_dense_reduce(keys: jnp.ndarray, lo, live_slots,
                          jnp.asarray(live_slots, jnp.int32)])
         tabs = [t[:slots] for t in res(par, k2, *s2)]
         count = tabs[0].sum(axis=1, dtype=jnp.int32)
+
+        def lane_sum(t):
+            """A slot's 128 lanes of hi * 2^31 + lo as ONE integer of
+            three words: every partial sum below fits an int32."""
+            hi, lo_ = tabs[t], tabs[t + 1]
+            parts = ((hi, 31), (lo_ >> 16, 16), (lo_ & np.int32(0xFFFF), 0))
+            total = W.wide_const(0, 3)
+            for x, k in parts:
+                total = W.wide_add(total, W.wide_shl(W.wide_from_int32(
+                    x.sum(axis=1, dtype=jnp.int32), 3), k))
+            return total
+
+        def planes_sum(k):
+            """l0 + l1 * 2^22 + (t' - rows * 2^19) * 2^44, exactly."""
+            t = tab_of[lanes_of[k]]
+            s0, s1, s2 = (lane_sum(t + 2 * j) for j in range(3))
+            unbias = W.wide_neg(W.wide_shl(W.wide_from_int32(count, 3),
+                                           63 - 2 * DENSE_LIMB_BITS))
+            top = W.wide_shl(W.wide_add(s2, unbias), 2 * DENSE_LIMB_BITS)
+            return W.wide_add(W.wide_add(s0, W.wide_shl(
+                s1, DENSE_LIMB_BITS)), top)
+
         sums = tuple(
-            tabs[t].sum(axis=1, dtype=jnp.int32) if kind == "int"
-            else _fold_pairs(tabs[t], tabs[t + 1])
-            for t, kind in zip(tab_of, kinds))
+            planes_sum(k) if kind == "planes"
+            else tabs[tab_of[lanes_of[k]]].sum(axis=1, dtype=jnp.int32)
+            if kind == "int"
+            else _fold_pairs(tabs[tab_of[lanes_of[k]]],
+                             tabs[tab_of[lanes_of[k]] + 1])
+            for k, kind in enumerate(kinds))
     return count, sums

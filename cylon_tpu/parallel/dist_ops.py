@@ -2162,7 +2162,7 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
         keep_dict = (op in (_groupby.AggregationOp.MIN,
                             _groupby.AggregationOp.MAX)
                      and src.is_string)
-        return Column(arr, dtype or table_mod._agg_dtype(src, op), av,
+        return Column(arr, dtype or table_mod._agg_dtype(src, op, arr), av,
                       src.dictionary if keep_dict else None, src.name)
 
     def finish(key_out, cols, gvalid):
@@ -2240,7 +2240,7 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
                 c_arr, c_av = aggB[m[2]]
                 data = s_arr / jnp.maximum(c_arr.astype(jnp.float64), 1)
                 av = s_av & c_av & (c_arr > 0)
-                cols.append(Column(data, table_mod._agg_dtype(src, op), av,
+                cols.append(Column(data, table_mod._agg_dtype(src, op, data), av,
                                    None, src.name))
             else:
                 cols.append(agg_column(*aggB[m[1]], src, op))

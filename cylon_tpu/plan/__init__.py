@@ -6,7 +6,7 @@ here the layer is completed the way the paper's own cost model demands:
 every distributed op is *local kernel + all-to-all + local kernel*
 (PAPER.md §1, docs/arch.md), so the dominant optimization is running
 FEWER all-to-alls. A `LazyTable` builds a logical plan (`Scan`,
-`Project`, `Filter`, `Join`, `GroupBy`, `SetOp`, `Sort`, `Shuffle`
+`Project`, `Filter`, `Compute`, `Join`, `GroupBy`, `SetOp`, `Sort`, `Shuffle`
 nodes) over the `table_api` registry; the optimizer propagates
 partitioning metadata and (1) deletes `Shuffle` nodes whose input is
 already hash-placed on the same keys, (2) prunes unreferenced columns
@@ -24,8 +24,8 @@ The retired `parallel/task_plan.py` task-routing overlay lives on as
 `plan.tasks` (same `LogicalTaskPlan`/`task_exchange` API).
 """
 from . import ir, optimizer, executor, report, tasks
-from .ir import (Filter, GroupBy, Join, PlanNode, Project, Scan, SetOp,
-                 Shuffle, Sort, col)
+from .ir import (Compute, Filter, GroupBy, Join, PlanNode, Project, Scan,
+                 SetOp, Shuffle, Sort, col)
 from .lazy import LazyTable, scan
 from .optimizer import PlanStats, optimize
 from .executor import execute, execute_analyzed
@@ -33,7 +33,7 @@ from .report import NodeMeasure, PlanReport
 from .tasks import LogicalTaskPlan, task_exchange
 
 __all__ = [
-    "Filter", "GroupBy", "Join", "LazyTable", "LogicalTaskPlan",
+    "Compute", "Filter", "GroupBy", "Join", "LazyTable", "LogicalTaskPlan",
     "NodeMeasure", "PlanNode", "PlanReport", "PlanStats", "Project",
     "Scan", "SetOp", "Shuffle", "Sort", "col", "execute",
     "execute_analyzed", "executor", "ir", "optimize", "optimizer",

@@ -366,6 +366,13 @@ class _Exec:
             return _ledger.track(t.filter_mask(node.expr.mask(t)),
                                  "plan.filter")
 
+    def _do_compute(self, node: ir.Compute) -> Table:
+        t = self.run(node.children[0])
+        with _span("plan.compute", self._seq(), cols=len(node.names),
+                   rows_in=t.capacity):
+            return _ledger.track(t.with_columns(node.names, node.exprs),
+                                 "plan.compute")
+
     # -- exchanges ------------------------------------------------------
 
     def _side_exchanges(self, t: Table, keys, other: Table,
@@ -541,8 +548,14 @@ class _Exec:
 
         t = self.run(node.children[0])
         if _world(self.ctx) == 1:
+            # a result that says it is in this order already (the dense
+            # groupby's: slot order is key order) is not sorted again;
+            # the table's own witness decides, at run time
+            done = t.ordered_by(node.by, node.ascending)
             with _span("plan.sort", self._seq(), world=1,
-                       rows_in=t.capacity):
+                       rows_in=t.capacity, elided=done):
+                if done:
+                    return t
                 return _ledger.track(t.sort(node.by, node.ascending),
                                      "plan.sort")
         with _span("plan.shuffle.sort", self._seq(),

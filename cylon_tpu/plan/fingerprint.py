@@ -74,6 +74,9 @@ def _own_tokens(n: ir.PlanNode, with_algorithm: bool = True) -> tuple:
         extra = ("cols", tuple(n.cols))
     elif isinstance(n, ir.Filter):
         extra = ("expr", _expr_tokens(n.expr))
+    elif isinstance(n, ir.Compute):
+        # the bound token trees: operators, positions, literals
+        extra = ("compute", tuple(n.names), tuple(n.exprs))
     elif isinstance(n, ir.Shuffle):
         # NB: the `salted` flag is deliberately NOT a token — a salted
         # and an unsalted exchange of the same shape share one measured

@@ -1,4 +1,5 @@
-"""Logical-plan IR nodes and the filter expression mini-language.
+"""Logical-plan IR nodes, the filter expression mini-language and the
+integer value expressions of computed columns (`Value`, `Compute`).
 
 Every node knows its output ``schema`` (column names) and ``types``
 (numpy dtype strings, with the sentinel ``"str"`` for string/varbytes
@@ -59,13 +60,95 @@ class Expr:
         return Not(self)
 
 
-class Col:
+class Value:
+    """Unbound integer VALUE expression: ``col("a") * (100 - col("b"))``.
+    Operands are columns, other value expressions and integer literals;
+    the operations ``+``, ``-``, ``*``. `LazyTable.with_columns` binds it
+    to a token tree over column positions (`bind_value`), which is what a
+    `Compute` node holds, the fingerprint reads and the lowering
+    evaluates: ``("col", i)``, ``("lit", v)``, ``(op, a, b)``."""
+
+    def __add__(self, other):
+        return Arith("add", self, other)
+
+    def __radd__(self, other):
+        return Arith("add", other, self)
+
+    def __sub__(self, other):
+        return Arith("sub", self, other)
+
+    def __rsub__(self, other):
+        return Arith("sub", other, self)
+
+    def __mul__(self, other):
+        return Arith("mul", self, other)
+
+    def __rmul__(self, other):
+        return Arith("mul", other, self)
+
+
+class Arith(Value):
+    def __init__(self, op: str, a, b):
+        self.op, self.a, self.b = op, a, b
+
+    def __repr__(self):
+        return f"({self.a!r} {_VALUE_SYMBOL[self.op]} {self.b!r})"
+
+
+_VALUE_SYMBOL = {"add": "+", "sub": "-", "mul": "*"}
+
+
+def bind_value(v, resolver) -> tuple:
+    """The token tree of an unbound value expression, column references
+    resolved to positions by ``resolver``."""
+    if isinstance(v, Col):
+        return ("col", int(resolver(v.ref)))
+    if isinstance(v, Arith):
+        return (v.op, bind_value(v.a, resolver), bind_value(v.b, resolver))
+    if isinstance(v, int) and not isinstance(v, bool):
+        return ("lit", int(v))
+    raise CylonPlanError(
+        f"a value expression takes columns, + - * and integer literals "
+        f"(decimals are scaled integers), not {v!r}",
+        code=Code.TypeError)
+
+
+def value_columns(tokens) -> set:
+    if tokens[0] == "col":
+        return {tokens[1]}
+    if tokens[0] == "lit":
+        return set()
+    return value_columns(tokens[1]) | value_columns(tokens[2])
+
+
+def value_remap(tokens, mapping) -> tuple:
+    if tokens[0] == "col":
+        return ("col", mapping[tokens[1]])
+    if tokens[0] == "lit":
+        return tokens
+    return (tokens[0], value_remap(tokens[1], mapping),
+            value_remap(tokens[2], mapping))
+
+
+def value_repr(tokens) -> str:
+    if tokens[0] == "col":
+        return f"c{tokens[1]}"
+    if tokens[0] == "lit":
+        return str(tokens[1])
+    return (f"({value_repr(tokens[1])} {_VALUE_SYMBOL[tokens[0]]} "
+            f"{value_repr(tokens[2])})")
+
+
+class Col(Value):
     """Unbound column reference — the user-facing builder. ``col("x") >
     3`` constructs a comparison; `LazyTable.filter` binds names to
-    positions against its schema."""
+    positions against its schema. In arithmetic it is a `Value`."""
 
     def __init__(self, ref: Union[str, int]):
         self.ref = ref
+
+    def __repr__(self):
+        return f"col({self.ref!r})"
 
     def _cmp(self, op, value):
         if isinstance(value, Col) or isinstance(value, Expr):
@@ -255,6 +338,30 @@ class Filter(PlanNode):
 
     def args_repr(self):
         return repr(self.expr)
+
+
+class Compute(PlanNode):
+    """Appends named computed columns: output = the child's columns, then
+    one column an expression (`Value` bound to a token tree). Expression
+    i may read the child's columns and the computed columns before it
+    (positions ``child.width + j``, j < i), so a later column reuses an
+    earlier one. ``out_types`` are the computed columns' dtypes
+    ("int32" / "int64"), given by the builder, which asks the lowering's
+    own rule (`data.table.value_dtype`): plan/ imports no ops/."""
+
+    kind = "compute"
+
+    def __init__(self, child: PlanNode, names: Sequence[str],
+                 exprs: Sequence[tuple], out_types: Sequence[str]):
+        super().__init__([child], list(child.schema) + list(names),
+                         list(child.types) + list(out_types))
+        self.names = [str(n) for n in names]
+        self.exprs = list(exprs)
+        self.out_types = [str(t) for t in out_types]
+
+    def args_repr(self):
+        return ", ".join(f"{n}={value_repr(e)}"
+                         for n, e in zip(self.names, self.exprs))
 
 
 class Shuffle(PlanNode):

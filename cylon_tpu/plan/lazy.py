@@ -20,7 +20,7 @@ from __future__ import annotations
 from typing import List, Optional, Sequence, Union
 
 from .. import table_api
-from ..data.table import Table
+from ..data.table import Table, value_dtype as _value_dtype
 from ..status import Code, CylonPlanError
 from . import ir
 from .executor import execute as _execute, \
@@ -45,11 +45,17 @@ def set_plan_memo(memo) -> None:
     _plan_memo = memo
 
 
-def _optimize_root(root, world):
+def _optimize_root(node, world, fp=None):
+    """(optimized plan, PlanStats) of the logical plan ``node``, which is
+    left as it is: the optimizer rewrites a copy, and a memo hit only
+    reads ``node``'s scans (``fp``: its fingerprint, where the caller has
+    it already)."""
     memo = _plan_memo
     if memo is not None:
-        return memo(root, world)
-    return _optimize(root, world)
+        return memo(node, world, fp)
+    import copy
+
+    return _optimize(copy.deepcopy(node), world)
 
 
 def _snapshot(table: Table, table_id=None, inline=None) -> ir.Scan:
@@ -134,6 +140,33 @@ class LazyTable:
         bound = expr.bind(self._pos)
         return self._wrap(ir.Filter(self._node, bound))
 
+    def with_columns(self, columns) -> "LazyTable":
+        """Append computed columns: ``{"name": value expression}`` (a
+        dict, in its order), each an integer expression over columns and
+        literals (``col("price") * (100 - col("discount"))``: decimals are
+        scaled integers and the query carries the scales). A later
+        expression may read an earlier one by its name. The column is an
+        int64 as soon as an operand is, else an int32; it is computed
+        exactly or the query raises (ops/expr.py)."""
+        names, exprs, types = [], [], []
+        schema, all_types = self.schema, list(self._node.types)
+
+        def pos(c):
+            if isinstance(c, str) and c in names:
+                return self._node.width + names.index(c)
+            return self._pos(c)
+
+        for name, value in dict(columns).items():
+            if name in schema or name in names:
+                raise CylonPlanError(f"with_columns: {name!r} is a column "
+                                     f"already", code=Code.Invalid)
+            tokens = ir.bind_value(value, pos)
+            types.append(_value_dtype(tokens, all_types))
+            all_types.append(types[-1])
+            names.append(str(name))
+            exprs.append(tokens)
+        return self._wrap(ir.Compute(self._node, names, exprs, types))
+
     def shuffle(self, keys) -> "LazyTable":
         return self._wrap(ir.Shuffle(self._node, self._positions(keys)))
 
@@ -196,7 +229,7 @@ class LazyTable:
         Memoized through the plan/fingerprint cache when the service
         package is loaded (equal-shape plans skip the optimizer; see
         service/plancache.py)."""
-        return _optimize_root(self._plan_copy(), self._world())
+        return _optimize_root(self._node, self._world())
 
     def plan_fingerprint(self) -> str:
         """The structural fingerprint of this query's LOGICAL plan
@@ -228,16 +261,17 @@ class LazyTable:
         ``analyze=True`` additionally records a per-node EXPLAIN
         ANALYZE report on ``self.last_report`` (one row-count sync per
         node — the default path pays nothing)."""
-        root = self._plan_copy()
-        stats: Optional[PlanStats] = None
-        if optimize:
-            root, stats = _optimize_root(root, self._world())
         # the LOGICAL-plan fingerprint rides to the executor's root
         # span: the query-log digest's join key, the statistics
         # warehouse's per-query key, and — critically — the plan-cache
         # key space drift eviction must match (fingerprinting the
         # OPTIMIZED root here would fork the key space)
         fp = self.plan_fingerprint()
+        stats: Optional[PlanStats] = None
+        if optimize:
+            root, stats = _optimize_root(self._node, self._world(), fp)
+        else:
+            root = self._plan_copy()
         if analyze:
             result, report = _execute_analyzed(root, self._ctx,
                                                stats=stats, plan_fp=fp)

@@ -11,6 +11,9 @@ Pass order is load-bearing:
 2. ``pushdown_filters`` — `Filter(Shuffle(x))` → `Shuffle(Filter(x))`:
    the shuffle's emit mask drops filtered rows IN TRANSIT, so the
    filter costs one elementwise AND and the exchange moves fewer rows.
+   `Filter(Compute(x))` → `Compute(Filter(x))` when the predicate reads
+   no computed column: the filter is a row mask either way, but below
+   the node it is where the next pass (and a reader of EXPLAIN) finds it.
 3. ``prune_projections`` — required-column analysis: columns no
    downstream node references are dropped at the scans (a `Project`
    over the `Scan`), so fewer payload leaves cross the mesh. All
@@ -54,6 +57,7 @@ class PlanStats:
     groupbys_localized: int = 0
     filters_pushed: int = 0
     columns_pruned: int = 0
+    filters_below_compute: int = 0
     joins_broadcast: int = 0
     shuffles_salted: int = 0
     notes: list = field(default_factory=list)
@@ -63,6 +67,9 @@ class PlanStats:
         if self.joins_broadcast or self.shuffles_salted:
             adaptive = (f"; joins broadcast: {self.joins_broadcast}; "
                         f"exchanges salted: {self.shuffles_salted}")
+        if self.filters_below_compute:
+            adaptive += (f"; filters pushed below computed columns: "
+                         f"{self.filters_below_compute}")
         return (f"shuffles: {self.shuffles_inserted} planned, "
                 f"{self.shuffles_elided} elided; "
                 f"groupbys localized: {self.groupbys_localized}; "
@@ -104,6 +111,16 @@ def pushdown_filters(node: ir.PlanNode, stats: PlanStats) -> ir.PlanNode:
         pushed = ir.Filter(sh.children[0], node.expr)
         stats.filters_pushed += 1
         return pushdown_filters(ir.Shuffle(pushed, sh.keys), stats)
+    if isinstance(node, ir.Filter) and \
+            isinstance(node.children[0], ir.Compute):
+        comp = node.children[0]
+        below = comp.children[0]
+        if all(p < below.width for p in node.expr.columns()):
+            # the child's columns keep their positions under the node
+            pushed = pushdown_filters(ir.Filter(below, node.expr), stats)
+            stats.filters_below_compute += 1
+            return ir.Compute(pushed, comp.names, comp.exprs,
+                              comp.out_types)
     return node
 
 
@@ -149,6 +166,33 @@ def _prune(node: ir.PlanNode, required: Set[int], stats: PlanStats
         need = required | node.expr.columns()
         c, m = _prune(node.children[0], need, stats)
         return ir.Filter(c, node.expr.remap(m)), dict(m)
+
+    if isinstance(node, ir.Compute):
+        w = node.children[0].width
+        # the computed columns someone reads, and those they read in turn
+        kept = set()
+        for j in reversed(range(len(node.exprs))):
+            if w + j in required or w + j in kept:
+                kept.add(w + j)
+                kept |= {p for p in ir.value_columns(node.exprs[j])
+                         if p >= w}
+        below = {p for p in required if p < w}
+        for p in kept:
+            below |= {q for q in ir.value_columns(node.exprs[p - w])
+                      if q < w}
+        c, m = _prune(node.children[0], below, stats)
+        if not kept:
+            return c, {p: m[p] for p in required}
+        m = dict(m)
+        order = sorted(kept)
+        for i, p in enumerate(order):
+            m[p] = c.width + i
+        stats.columns_pruned += len(node.exprs) - len(order)
+        out = ir.Compute(c, [node.names[p - w] for p in order],
+                         [ir.value_remap(node.exprs[p - w], m)
+                          for p in order],
+                         [node.out_types[p - w] for p in order])
+        return out, {p: m[p] for p in required}
 
     if isinstance(node, ir.Shuffle):
         need = required | set(node.keys)
@@ -230,8 +274,8 @@ def _propagate(node: ir.PlanNode, world: int) -> Optional[Tuple[int, ...]]:
         cpb = pbs[0]
         if cpb is not None and all(k in node.cols for k in cpb):
             pb = tuple(node.cols.index(k) for k in cpb)
-    elif isinstance(node, ir.Filter):
-        pb = pbs[0]
+    elif isinstance(node, (ir.Filter, ir.Compute)):
+        pb = pbs[0]   # computed columns come after the child's
     elif isinstance(node, ir.Shuffle):
         # a salted exchange spreads hot keys positionally — its output
         # is load-balanced, never hash-placed (mirror of the runtime:

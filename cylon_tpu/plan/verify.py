@@ -76,8 +76,10 @@ def derive_witness(node: ir.PlanNode, world: int) -> Optional[Witness]:
             return None  # a witness column was projected away
         return tuple(node.cols.index(k) for k in pos), dts
 
-    if isinstance(node, ir.Filter):
-        return child[0]  # dropping rows never moves the survivors
+    if isinstance(node, (ir.Filter, ir.Compute)):
+        # dropping rows never moves the survivors; computed columns are
+        # appended after the child's, which keep their positions
+        return child[0]
 
     if isinstance(node, ir.Shuffle):
         if node.salted:

@@ -109,6 +109,16 @@ def _strip_template(root: ir.PlanNode) -> ir.PlanNode:
     return tmpl
 
 
+def _instance(tmpl: ir.PlanNode) -> ir.PlanNode:
+    """A plan of fresh nodes over the template's own attribute objects:
+    execution reads a plan and never rewrites one (estimates and degrade
+    maps go by ``id(node)``), so only the nodes and their ``children``
+    lists have to be this query's own for its scans to be rebound."""
+    node = copy.copy(tmpl)
+    node.children = [_instance(c) for c in tmpl.children]
+    return node
+
+
 class PlanCache:
     """Fingerprint → (optimized-plan template, PlanStats), bounded LRU.
 
@@ -134,13 +144,18 @@ class PlanCache:
         return _metrics.REGISTRY.counter(
             f"cylon_plan_cache_{event}_total")
 
-    def optimize(self, root: ir.PlanNode, world: int
+    def optimize(self, root: ir.PlanNode, world: int,
+                 fp: Optional[str] = None
                  ) -> Tuple[ir.PlanNode, PlanStats]:
+        """``root`` is the caller's own logical plan and stays as it
+        is: a hit reads its scans, a miss optimizes a copy of it.
+        ``fp``: its fingerprint, where the caller has it."""
         cap = cache_max()
         if cap <= 0 or _bypassed():
             _set_last_event(None, "bypass")
-            return _optimize(root, world)
-        fp = fingerprint(root, world)
+            return _optimize(copy.deepcopy(root), world)
+        if fp is None:
+            fp = fingerprint(root, world)
         with self._lock:
             hit = self._entries.get(fp)
             if hit is not None:
@@ -157,7 +172,7 @@ class PlanCache:
             self.invalidate(fp)
         self._counter("misses").inc()
         _set_last_event(fp, "miss")
-        opt_root, stats = _optimize(root, world)
+        opt_root, stats = _optimize(copy.deepcopy(root), world)
         # the template records the statistics EPOCH and the adaptive
         # decision vector it was optimized under — the staleness
         # signal (_fresh) that keeps a cached algorithm choice from
@@ -218,12 +233,12 @@ class PlanCache:
 
     def _rebind(self, fp: str, entry: tuple, root: ir.PlanNode,
                 world: int) -> Optional[Tuple[ir.PlanNode, PlanStats]]:
-        """Instantiate a cached template for ``root``: deep-copy,
+        """Instantiate a cached template for ``root``: fresh nodes,
         rebind scan tables in walk order, and (in debug mode) re-run
         the witness verifier so a poisoned entry is rejected — evicted
         and raised as :class:`CylonPlanError` — never executed."""
         tmpl, stats = entry[0], entry[1]
-        plan = copy.deepcopy(tmpl)
+        plan = _instance(tmpl)
         dst, src = _scans(plan), _scans(root)
         if len(dst) != len(src):
             return None
@@ -296,11 +311,12 @@ def disabled():
             _bypass -= 1
 
 
-def memo_optimize(root: ir.PlanNode, world: int
+def memo_optimize(root: ir.PlanNode, world: int, fp: Optional[str] = None
                   ) -> Tuple[ir.PlanNode, PlanStats]:
     """The ``plan.lazy`` optimize hook: route every LazyTable
-    optimization through the global fingerprint cache."""
-    return _global.optimize(root, world)
+    optimization through the global fingerprint cache. ``root`` is the
+    LazyTable's own logical plan and stays as it is."""
+    return _global.optimize(root, world, fp)
 
 
 def _evict_on_drift(fp: str) -> None:

@@ -50,6 +50,7 @@ memory, not table-object multiplicity.
 from __future__ import annotations
 
 import itertools
+import math
 import threading
 import time
 import weakref
@@ -98,8 +99,7 @@ def _gauge(owner: str):
 
 def _buffer_bytes(arr) -> int:
     try:
-        return int(np.dtype(arr.dtype).itemsize) * \
-            int(np.prod(arr.shape))
+        return int(np.dtype(arr.dtype).itemsize) * math.prod(arr.shape)
     except Exception:  # pragma: no cover - exotic leaf  # cylint: disable=errors/broad-swallow — exotic leaf contributes 0 bytes
         return 0
 

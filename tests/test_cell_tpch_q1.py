@@ -1,0 +1,22 @@
+"""Tier-1's run of the TPC-H Q1 cell's own tests: the generator's
+population, the plain reference `tpch_q1_exact` on the exact report (word
+planes and int64), on the float32 control, on a dropped, doubled and
+misplaced group, a sum off by one unit, a count off by one, an average off
+by 2^-20, a narrowed schema, a null, and the cell's entries of
+``BENCHMARK.json`` found by name. The tests live with the benchmark, in
+``benchmarks/tests/test_tpch_q1_cell.py`` (run by hand with the rest of
+that suite); this file takes them as they are, fixtures included, so that
+there is one copy. The cell itself is rehearsed through
+``benchmarks/run.py`` by ``test_cells_rehearsal.py``.
+"""
+import importlib.util
+import os
+
+_PATH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "benchmarks", "tests", "test_tpch_q1_cell.py")
+_spec = importlib.util.spec_from_file_location("bench_test_tpch_q1_cell",
+                                               _PATH)
+_mod = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_mod)
+globals().update({name: obj for name, obj in vars(_mod).items()
+                  if name.startswith("test_") or name == "q1_case"})

@@ -123,6 +123,29 @@ def test_groupby_q5_rehearses_packed():
     assert said["packed"] == 0 and said["sorted"] == 0
 
 
+def test_tpch_q1_rehearses_on_the_dense_table_with_its_spans():
+    """The cell runs as at 7.5e7 rows: the dense table over both keys and
+    nothing sorted, the computed columns through `plan.compute`, its two
+    host fetches each under a span of its own, four groups, the control
+    refused by its sums."""
+    said, out = rehearse("tpch-q1", 0)
+    assert said["sorted"] == 0
+    for family in ("cylon_groupby_dense_keys_total", "cylon_expr_columns_total",
+                   "cylon_expr_materialized_bytes_total"):
+        assert any(s.split("{")[0] == family for s in said["series"]), family
+    for span in ("plan.compute", "plan.filter", "plan.groupby", "plan.sort",
+                 "sync.expr.range", "sync.groupby.densegroups"):
+        assert span in said["phases"], (span, said["phases"])
+    # nothing sorted; dictionary keys' ranges are known without a probe
+    assert "sync.groupby.groups" not in said["phases"] \
+        and "sync.groupby.keyrange" not in said["phases"]
+    assert "4 groups (A/F N/F N/O R/F)" in out
+    assert "Compute(disc_price=" in out and "GroupBy(keys=[4, 5]" in out
+    _said, control = rehearse("tpch-q1", 1)
+    bad = re.findall(r"compare first query: (\S+) = .* MISMATCH", control)
+    assert bad and all(name.startswith("sum_diff.") for name in bad)
+
+
 def _metric_files():
     """(metric, its file's content) for every metric of the benchmark."""
     for path in sorted(glob.glob(os.path.join(

@@ -246,7 +246,10 @@ DECISIONS = [
     ([I32], [True], [I32], [Op.SUM], 1000, G.DENSE_MAX_SLOTS, "sort"),
     ([I32], [False], [I32], [Op.SUM], 1000, G.DENSE_MAX_SLOTS + 1, "sort"),
     ([F32], [False], [I32], [Op.SUM], 1000, None, "sort"),
-    ([I32, I32], [False, False], [I32], [Op.SUM], 1000, None, "sort"),
+    ([I32, I32], [False, False], [I32], [Op.SUM], 1000, None, "dense"),
+    ([I32, I32], [False, True], [I32], [Op.SUM], 1000, [32, 31], "dense"),
+    ([I32, I32], [False, True], [I32], [Op.SUM], 1000, [32, 32], "sort"),
+    ([I32, F32], [False, False], [I32], [Op.SUM], 1000, None, "sort"),
     ([PLANES], [False], [I32], [Op.SUM], 1000, None, "sort"),
     ([np.dtype(np.int64)], [False], [I32], [Op.SUM], 1000, None, "sort"),
     ([I32], [False], [I32], [Op.MIN], 1000, None, "sort"),
@@ -277,10 +280,10 @@ EXCLUDED = {
     "float_key": lambda r: ([_col(r.normal(size=64).astype(np.float32), "k"),
                              _col(np.arange(64, dtype=np.int32), "a")],
                             [0], ["sum"]),
-    "two_keys": lambda r: ([_col(np.arange(64, dtype=np.int32) % 3, "k"),
-                            _col(np.arange(64, dtype=np.int32) % 2, "k2"),
-                            _col(np.arange(64, dtype=np.int32), "a")],
-                           [0, 1], ["sum"]),
+    "two_keys_one_float": lambda r: (
+        [_col(np.arange(64, dtype=np.int32) % 3, "k"),
+         _col((np.arange(64) % 2).astype(np.float32), "k2"),
+         _col(np.arange(64, dtype=np.int32), "a")], [0, 1], ["sum"]),
     "varbytes_key": lambda r: ([_col(np.array(
         [f"row{i % 300:03d}" for i in range(400)], object), "k"),
         _col(np.arange(400, dtype=np.int32), "a")], [0], ["sum"]),

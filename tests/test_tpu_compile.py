@@ -386,3 +386,87 @@ def test_dist_groupby_gather_program_still_compiles(shards4):
     sorts = _sort_lines(text)
     assert len(sorts) == 1 and "is_stable=true" in sorts[0], sorts
     assert re.search(r"\bgather\(", text)
+
+
+# -- TPC-H Q1 (PR 42): the expression programs and the dense table over two
+# keys and 64-bit limb streams, at the cell's 75,004,738 rows ---------------
+
+Q1_ROWS = 75_004_738
+# the ranges Q1's columns reach at that size (hundredths): they choose the
+# forms of the steps and the limbs of the sums
+Q1_RANGES = {1: (90_000, 10_495_000), 2: (0, 10), 3: (0, 8)}
+Q1_EXPRS = (("mul", ("col", 1), ("sub", ("lit", 100), ("col", 2))),
+            ("mul", ("col", 7), ("add", ("lit", 100), ("col", 3))))
+
+
+def _planes(chip, n):
+    return jax.ShapeDtypeStruct((2, n), jnp.uint32, sharding=chip)
+
+
+def test_q1_expression_programs_compile(chip):
+    """`Table.with_columns` as tpch-q1 runs it: the range probe over three
+    plane-held columns keeps no temporary of a column's size, and the two
+    computed columns come out of ONE program with no 64-bit lane in it
+    (disc_price fits an int32 by its observed range, charge does not)."""
+    from cylon_tpu.ops import expr as E
+
+    leaves = {p: _planes(chip, Q1_ROWS) for p in (1, 2, 3)}
+    with jax.enable_x64(False):
+        probe = jax.jit(E.range_probe).lower(
+            tuple(leaves.values())).compile()
+    assert probe.memory_analysis().temp_size_in_bytes < (1 << 20)
+    ranges = dict(Q1_RANGES)
+    forms = []
+    for i, tokens in enumerate(Q1_EXPRS):
+        f, ranges[7 + i] = E.plan_forms(tokens, ranges, "int64", f"e{i}")
+        forms.append(f)
+    assert forms[0][0] == "i32" and forms[1][0] == "i64"
+
+    def compute(leaves):
+        leaves, out = dict(leaves), []
+        for i, tokens in enumerate(Q1_EXPRS):
+            out.append(E.evaluate_words(tokens, forms[i], leaves, "int64"))
+            leaves[7 + i] = out[-1]
+        return out
+
+    text = _compiled_text(compute, leaves)
+    assert "s64[" not in text and "u64[" not in text
+
+
+def test_q1_dense_groupby_compiles(chip):
+    """Q1's groupby at the cell's size: two int32 code keys under a row
+    mask into 8 slots, eight aggregates over five plane-held int64
+    columns that the kernel cuts into limbs itself, ONE kernel, no sort,
+    no scatter; and its probe (two keys) in one small program."""
+    G = _groupby
+    S, M, C = G.AggregationOp.SUM, G.AggregationOp.MEAN, G.AggregationOp.COUNT
+    n = Q1_ROWS
+    keys = (_sds(chip, n, jnp.int32),) * 2
+    emit = _sds(chip, n, jnp.bool_)
+    with jax.enable_x64(False):
+        probe = jax.jit(G.ranges_probe).lower(
+            keys, emit, (None, None)).compile()
+    assert probe.memory_analysis().temp_size_in_bytes < (1 << 20)
+    # value columns: qty, price, disc_price, charge, qty, price, disc, qty
+    cols = (0, 1, 7, 8, 0, 1, 2, 0)
+    ops = (S, S, S, S, M, M, M, C)
+    wide = tuple(op != C for op in ops)
+    by_col = {c: _planes(chip, n) for c in set(cols)}
+
+    def dense(keys, emit, ranges, values):
+        return G.dense_aggregate_keys(keys, (None, None), emit, ranges,
+                                      values, (None,) * 8, 8, ops, cols,
+                                      wide)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(dense).lower(
+            keys, emit,
+            jax.ShapeDtypeStruct((2, 3), jnp.uint32, sharding=chip),
+            tuple(by_col[c] for c in cols)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "scatter" not in text and " sort(" not in text
+    # the slot ids and the ten planes as the kernel's (rows, 128) inputs
+    # and one more array: 48 B a row, 3.6 GB beside the 3.3 GB table, its
+    # copy and 1.2 GB of computed columns
+    assert compiled.memory_analysis().temp_size_in_bytes < 50 * n
