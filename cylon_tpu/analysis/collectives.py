@@ -404,7 +404,7 @@ def default_entry_points() -> List[EntryPoint]:
             "groupby", do, lambda m: _groupby_factory(D(m), m),
             lambda m: ((_sds(N, u32),), (_sds(N, i32),), (_sds(N, b),),
                        _sds(N, b), (_sds(N, jnp.float32),),
-                       (_sds(N, b),)),
+                       (_sds(N, b),), None),
             factory="_groupby_fn"),
         EntryPoint(
             "ring_count", do,

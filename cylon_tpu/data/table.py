@@ -25,7 +25,8 @@ from __future__ import annotations
 import functools
 import math
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Dict, List, NamedTuple, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -1536,8 +1537,9 @@ def groupby_local(table: Table, index_col, aggregate_cols: List,
     # scatter cost ~15-30 ns/element). Its operands are counted here,
     # where the host can see them (the same pure function of mask, lanes,
     # columns, index and packing that presort_groups builds its list by)
-    plan, params = _groupby_sort_packing(table, key_columns[0], key_range,
-                                         values)
+    plan, params = _sort_pack_plan(_sort_pack_probe(
+        table.capacity // len(key_columns[0].data.sharding.device_set),
+        None if key_range is None else (key_columns[0], key_range), values))
     _telemetry.counter("cylon_groupby_sort_operands_total").inc(
         _groupby.sort_operand_count(keys, emit, values, valids, index, plan))
     _telemetry.counter("cylon_groupby_sort_packed_columns_total").inc(
@@ -1619,43 +1621,102 @@ def _groupby_value_range_fn():
     return jax.jit(kernel)
 
 
-def _groupby_sort_packing(table: Table, key_column: Column, key_range,
-                          values):
-    """(plan, params) for `ops/groupby.presort_groups`: which integer
-    value columns ride inside another operand's word, by what the host
-    observed, or (None, None) where every column rides alone.
-    ``key_range`` is `_groupby_dense`'s observed (lo, hi) of the ONE key
-    column, None where it probed nothing. The value columns' ranges cost
-    a probe and a fetch (`sync.groupby.valuerange`), paid only where the
-    static side says a word could be saved (an integer value column at
-    most 32 bits wide, and the observed key's spare bits or a second such
-    column to share a word with), for a table of SORT_PACK_MIN_ROWS rows
-    or more that lives on ONE device."""
-    key_bits = key_lo = None
-    if key_range is not None:
-        lo, hi = key_range
-        # no live row: a key of one bit, every row is dead
-        key_bits = max(_groupby.range_bits(lo, hi), 1) if lo <= hi else 1
-        key_lo = _groupby.key_lane_lo(lo, key_column.data.dtype,
-                                      key_column.is_string)
+@_telemetry.counted_cache
+def _groupby_pack_ranges_program_fn():
+    """The probe of the sort's packing where nothing has observed the key
+    yet (across chips): the ONE key's (lo, hi) over the live rows and each
+    integer value column's over every row, ONE array
+    (`jit_groupby_pack_ranges_program`; on a sharded table a min / max
+    over all the shards)."""
+    def kernel(key, emit, values):
+        return _groupby.pack_ranges_probe(key, emit, values)
+
+    return jax.jit(kernel)
+
+
+class _SortPackProbe(NamedTuple):
+    """A dispatched probe of the sort's packing (`_sort_pack_probe`)."""
+    ranges: object      # on the device, not fetched yet
+    probed: tuple       # the value columns it looked at, by position
+    n_values: int
+    key: object         # the key column that may share its word, or None
+    key_range: object   # its (lo, hi) observed before; None: in ``ranges``
+
+
+def _key_word(key: Column, lo: int, hi: int):
+    """(bits, lowest ordered lane) of a key observed in [lo, hi]; no live
+    row (lo > hi): a key of one bit, every row is dead."""
+    bits = max(_groupby.range_bits(lo, hi), 1) if lo <= hi else 1
+    return bits, _groupby.key_lane_lo(lo, key.data.dtype, key.is_string)
+
+
+def _sort_pack_probe(rows: int, key, values, emit=None):
+    """Dispatch the probe by which `_sort_pack_plan` packs the groupby's
+    fused sort (`ops/groupby.presort_groups`), or None where none is paid:
+    ONE decision for the one-chip and the distributed groupby, from what
+    the host can see before it dispatches (no knob).
+
+    ``rows``: a shard's rows. ``key``: None, or (column, range) of the ONE
+    key column whose lane is the sort's only key and may share its word
+    (no nulls, not varbytes, at most 32 bits wide): ``range`` its (lo, hi)
+    where an earlier probe observed it (`_groupby_dense`'s, on one chip),
+    None where nothing has (across chips no dense check runs): then this
+    probe looks at the key too, over the live rows (``emit``; None: all),
+    in the same program and the same array as the value columns
+    (`jit_groupby_pack_ranges_program`, else `jit_groupby_value_range`).
+    The probe and its fetch are paid only where the static side says a
+    word could be saved (an integer value column at most 32 bits wide,
+    and the key's spare bits or a second such column to share a word
+    with), for shards of SORT_PACK_MIN_ROWS rows or more. Both programs
+    are correct on sharded arrays (a min / max over all the shards: the
+    plan is one for the whole table)."""
+    column, key_range = (None, None) if key is None else key
+    key_hope = None if column is None else 1 if key_range is None \
+        else _key_word(column, *key_range)[0]
     # the static side: a bit a column that could pack, the least it takes
     hoped = [1 if _groupby.packs(v.dtype) else None for v in values]
-    if table.capacity < _groupby.SORT_PACK_MIN_ROWS \
-            or _groupby.sort_pack_plan(key_bits, hoped) is None \
-            or len(key_column.data.sharding.device_set) != 1:
+    if rows < _groupby.SORT_PACK_MIN_ROWS \
+            or _groupby.sort_pack_plan(key_hope, hoped) is None:
+        return None
+    probed = tuple(j for j, bit in enumerate(hoped) if bit is not None)
+    looked = tuple(values[j] for j in probed)
+    if column is not None and key_range is None:
+        ranges = _groupby_pack_ranges_program_fn()(column.data, emit, looked)
+    else:
+        ranges = _groupby_value_range_fn()(looked)
+    return _SortPackProbe(ranges, probed, len(values), column, key_range)
+
+
+def _sort_pack_plan(probe):
+    """(plan, params) for `ops/groupby.presort_groups` off a dispatched
+    `_sort_pack_probe`: which integer value columns ride inside another
+    operand's word, by what the host observed, or (None, None) where
+    every column rides alone (no probe, or ranges that do not fit:
+    nothing is truncated). The ONE fetch of the probe is here
+    (`sync.groupby.valuerange`; `sync.groupby.packranges` where the array
+    holds the key's range too), so a caller may dispatch what does not
+    depend on the ranges between the two calls."""
+    if probe is None:
         return None, None
-    probed = [j for j, bit in enumerate(hoped) if bit is not None]
-    ranges = _telemetry.host_fetch(
-        "groupby.valuerange",
-        _groupby_value_range_fn()(tuple(values[j] for j in probed)))
-    value_lo, value_bits = [None] * len(values), [None] * len(values)
-    for j, (lo, hi) in zip(probed, ranges.tolist()):
+    key_bits = key_lo = None
+    if probe.key is not None and probe.key_range is None:
+        ranges = _telemetry.host_fetch("groupby.packranges", probe.ranges)
+        key_bits, key_lo = _key_word(probe.key, *_groupby.key_range_of(
+            ranges[0], probe.key.data.dtype))
+        ranges = ranges[1:, :2]
+    else:
+        ranges = _telemetry.host_fetch("groupby.valuerange", probe.ranges)
+        if probe.key is not None:
+            key_bits, key_lo = _key_word(probe.key, *probe.key_range)
+    value_lo = [None] * probe.n_values
+    value_bits = [None] * probe.n_values
+    for j, (lo, hi) in zip(probe.probed, ranges.tolist()):
         value_lo[j], value_bits[j] = lo, _groupby.range_bits(lo, hi)
     plan = _groupby.sort_pack_plan(key_bits, value_bits)
     if plan is None:
         return None, None
-    return plan, jnp.asarray(_groupby.sort_pack_params(
-        plan, key_lo, key_bits, value_lo, value_bits))
+    return plan, _groupby.sort_pack_params(plan, key_lo, key_bits, value_lo,
+                                           value_bits)
 
 
 def _groupby_dense(table: Table, key_columns, val_cols, values, valids, ops,

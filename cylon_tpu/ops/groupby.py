@@ -22,14 +22,16 @@ validity masks that exist. A dead flag, as the primary key, only when the
 table has a row mask (`emit` is read back off it, never carried). The
 row index only when a caller needs the first ORIGINAL row of a group
 (`sort_carries_index`): the `segment_*` path, an 8-byte or a varbytes
-key, the distributed kernel. Otherwise the sorted key lanes themselves
+key. Otherwise the sorted key lanes themselves
 are compacted to slot g by the reduce pass and the output key columns
 are read off them (`order.from_ordered_bits_raw`), with no index, no
 gather of the key column and no need for a stable sort. And what rides
 takes as few operands as its OBSERVED ranges allow (`sort_pack_plan`,
 PR 35): integer value columns narrow enough ride in the spare low bits
 of the key's word, or several to a word, and are unpacked exactly after
-the sort, inside the same program.
+the sort, inside the same program. The distributed groupby's per-shard
+step (`parallel/dist_ops._groupby_fn`) is handed the same lists by the
+same rules (PR 43).
 
 Distributed semantics (fixing the reference's re-aggregation subtlety noted
 in SURVEY §3.2): partial aggregates are combined with the correct SECOND-
@@ -127,12 +129,12 @@ def sort_pack_plan(key_bits, value_bits):
     shares a word, which is the sort as it always was.
 
     ``key_bits``: bits of the key's observed range, None when it was not
-    observed (several key columns, a nullable, varbytes or 8-byte key, a
-    sharded table). ``value_bits[i]``: bits of value column i's observed
-    physical range, None for a column that cannot pack (`packs`) or was
-    not probed. First fit in column order: the key's word first (the key
-    keeps the HIGH bits, so the word sorts as the key does and the rows
-    of one group fall in an order nobody reads), then the words opened by
+    observed (several key columns, a nullable, varbytes or 8-byte key).
+    ``value_bits[i]``: bits of value column i's observed physical range,
+    None for a column that cannot pack (`packs`) or was not probed. First
+    fit in column order: the key's word first (the key keeps the HIGH
+    bits, so the word sorts as the key does and the rows of one group
+    fall in an order nobody reads), then the words opened by
     earlier columns; a column that fits nowhere opens a word of its own,
     and a word of one member rides as the column itself, untouched. A
     range that does not fit is never truncated: its column rides alone.
@@ -220,6 +222,18 @@ def value_range_probe(values):
                       for lane in map(_lane32, values)])
 
 
+def pack_ranges_probe(key, emit, values):
+    """``uint32[1 + len(values), 3]``, ONE array to fetch: `ranges_probe`'s
+    row of the ONE key (lo, hi, empty: over the live rows, ``emit`` None
+    for all of them), then `value_range_probe`'s (lo, hi, 0) a value
+    column. For a sort whose key nothing has observed yet (across chips no
+    dense check runs before it); correct on sharded arrays, where the
+    min / max is the whole table's."""
+    return jnp.concatenate([
+        ranges_probe((key,), emit, (None,)),
+        jnp.pad(value_range_probe(values), ((0, 0), (0, 1)))])
+
+
 def sort_operand_count(keys, emit, values, valids, index: bool,
                        plan=None) -> int:
     """How many operands presort_groups hands the sort for these
@@ -289,12 +303,14 @@ def presort_groups(keys: Tuple[jnp.ndarray, ...], emit,
     run's first sorted row is its first original row; without it the
     sorted key lanes go out in its place and the sort need not be
     stable (a group's rows arrive in any order). ``plan`` (static;
-    `sort_pack_plan`, None: every column rides alone, as under
-    `shard_map`, where no host observes a range) packs integer columns
-    into shared words by the offsets, shifts and masks of ``params``
-    (`sort_pack_params`) and unpacks them after the sort: what is handed
-    on is what the unpacked sort hands on, only a group's rows in
-    another order.
+    `sort_pack_plan`, None: every column rides alone) packs integer
+    columns into shared words by the offsets, shifts and masks of
+    ``params`` (`sort_pack_params`) and unpacks them after the sort: what
+    is handed on is what the unpacked sort hands on, only a group's rows
+    in another order. Under `shard_map` too (the distributed groupby's
+    per-shard step): the host observes the ranges of the whole sharded
+    table BEFORE it dispatches, the plan is one for all shards and
+    ``params`` a replicated operand.
 
     Returns (values_s, valids_s, emit_s, first_s, new_grp, n_groups):
     emit_s is None when emit was; first_s is the sorted row index, or

@@ -839,24 +839,30 @@ def _varlen_take_concat_fn(mesh, cap_w: int):
 @counted_cache
 def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
                 col_ids: Tuple[int, ...], all_valid: Tuple[bool, ...],
-                key_spec: tuple = None):
+                key_spec: tuple = None, plan: tuple = None):
     """The per-shard sort + reduce step. ``key_spec`` (static;
-    `_group_keys` decides) None: the sort carries the row index and
+    `_group_key_spec` decides) None: the sort carries the row index and
     each group's key is gathered from its first original row (``kdat``,
     ``kval``; ``safe`` goes out for a varbytes key's words). Else
     ``kbits`` are the key lanes as `ops/groupby._key_columns` reads them
-    (`_group_keys`), no index rides, the sort is not stable, and
+    (`_group_key_operands`), no index rides, the sort is not stable, and
     the key columns come out of the reduce pass: no gather over the rows,
     ``kdat`` and ``kval`` empty, a key without nulls comes back with
-    validity None and ``safe`` is None."""
+    validity None and ``safe`` is None. ``emit`` None (a table with no
+    row mask): every row is live, no dead flag rides. ``plan`` (static;
+    `_sort_pack_probe` and `data/table._sort_pack_plan` decide on the
+    host, from the whole table's observed ranges) packs integer columns
+    into shared words by ``params``, a replicated operand:
+    `ops/groupby.presort_groups`."""
     spec = P(mesh.axis_names[0])
 
-    def kernel(kbits, kdat, kval, emit, vdat, vval):
-        n = emit.shape[0]
+    def kernel(kbits, kdat, kval, emit, vdat, vval, params):
+        n = kbits[0].shape[0]
         keys = tuple(kbits) + tuple(v.astype(jnp.uint8) for v in kval)
         vdat_s, vval_s, emit_s, first_s, new_grp, _ng = \
             _groupby.presort_groups(keys, emit, vdat, vval,
-                                    index=key_spec is None)
+                                    index=key_spec is None, plan=plan,
+                                    params=params)
         firsts, gvalid, results = _groupby.sorted_segment_aggregate(
             new_grp, emit_s, first_s, vdat_s, vval_s, n, ops, col_ids,
             all_valid, key_spec=key_spec)
@@ -872,71 +878,117 @@ def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
     # check_vma off: on a TPU the reduce step is a pallas_call
     # (groupby_run_reduce), whose outputs carry no varying-mesh-axes
     # annotation for the checker; the kernel is purely per-shard
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 6,
+    return jax.jit(shard_map(kernel, mesh=mesh,
+                             in_specs=(spec,) * 6 + (P(),),
                              out_specs=spec, check_vma=False))
 
 
-def _group_keys(ctx: CylonContext, key_columns, value_dtypes, ops,
-                rows: int):
-    """(key_spec, kbits, kdat, kval) of `_groupby_fn`: HOW the per-shard
-    step reads its groups' keys, decided from what the host sees before
-    it dispatches (no knob; `ops/groupby.sort_carries_index`, the
-    function `table.groupby_local` decides by; ``rows``: a shard's), and
-    the key operands that way takes.
+def _group_key_spec(key_columns, value_dtypes, ops, rows: int):
+    """`_groupby_fn`'s ``key_spec``: HOW the per-shard step reads its
+    groups' keys, decided from what the host sees before it dispatches
+    anything (no knob; `ops/groupby.sort_carries_index`, the function
+    `table.groupby_local` decides by; ``rows``: a shard's).
 
-    ``key_spec`` None, the sort must carry the row index: a varbytes key
-    (hash lanes have no way back), a lane wider than 32 bits, a reduce
-    step that is not the streaming pass (the CPU, 8-byte accumulators).
-    Then the key bits, the data and every column's mask, to gather from.
-    Else `sorted_segment_aggregate`'s ``key_spec``, one (numpy dtype,
-    is_string, nullable) a key column, and ``kbits`` holds a column's
-    ordered-bits lane and then its validity lane only if the column is
-    nullable, out of the ONE key-bits program: nothing to gather from,
-    no all-ones mask of a key without nulls."""
+    None, the sort must carry the row index: a varbytes key (hash lanes
+    have no way back), a lane wider than 32 bits, a reduce step that is
+    not the streaming pass (the CPU, 8-byte accumulators). Else
+    `sorted_segment_aggregate`'s ``key_spec``, one (numpy dtype,
+    is_string, nullable) a key column."""
     key_spec = None if any(c.is_varbytes for c in key_columns) else tuple(
         (np.dtype(c.data.dtype), c.is_string, c.validity is not None)
         for c in key_columns)
     # a plain column's ordered-bits lane is as wide as its data
     if _groupby.sort_carries_index([c.data for c in key_columns], key_spec,
                                    value_dtypes, ops, rows):
-        key_spec = None
+        return None
+    return key_spec
+
+
+def _group_key_operands(ctx: CylonContext, key_columns, key_spec):
+    """(kbits, kdat, kval) of `_groupby_fn`, the key operands the way
+    ``key_spec`` (`_group_key_spec`) reads the keys takes. None: the key
+    bits, the data and every column's mask, to gather from. Else
+    ``kbits`` holds a column's ordered-bits lane and then its validity
+    lane only if the column is nullable, out of the ONE key-bits program:
+    nothing to gather from, no all-ones mask of a key without nulls."""
     if key_spec is not None:
         kbits, _kv = _dist_key_bits(
             ctx, key_columns,
             null_lanes=[nullable for _d, _s, nullable in key_spec])
-        return key_spec, kbits, (), ()
+        return kbits, (), ()
     kbits, _kv = _dist_key_bits(ctx, key_columns)
-    return (None, kbits,
+    return (kbits,
             tuple(shard.pin(c.data, ctx) for c in key_columns),
             tuple(shard.pin(c.valid_mask(), ctx) for c in key_columns))
 
 
+def _sort_pack_probe(rows: int, key_columns, key_spec, emit, vdat):
+    """Dispatch the probe of the per-shard sort's packing over the WHOLE
+    sharded table (`data/table._sort_pack_probe`, the function the
+    one-chip groupby decides by: the same static test, the same row gate
+    on a SHARD's ``rows``; None where no probe is paid). Across chips
+    nothing has looked at the key before the sort, so the probe does,
+    where the key's lane is the sort's only key (``key_spec`` names ONE
+    column without nulls: no validity lane, no gather path's mask lane)
+    and may share its word (`ops/groupby.packs`)."""
+    key = None
+    if key_spec is not None and len(key_spec) == 1 \
+            and not key_spec[0][2] and _groupby.packs(key_spec[0][0]):
+        key = (key_columns[0], None)
+    return table_mod._sort_pack_probe(rows, key, vdat, emit)
+
+
+def _group_keys(ctx: CylonContext, key_columns, emit, vdat, ops,
+                observe: bool):
+    """(key_spec, kbits, kdat, kval, packing) of one per-shard step:
+    `_group_key_spec`'s decision, the key operands it takes
+    (`_group_key_operands`) and `_aggregate_shards`' ``packing``.
+    ``observe``: nobody has looked at the columns yet and their ranges
+    may pack the sort (`_sort_pack_probe`); what does not depend on the
+    ranges, the key-bits program, is dispatched BEFORE the host waits for
+    them, so the chips work while the ranges come down."""
+    rows = int(key_columns[0].data.shape[0]) // ctx.get_world_size()
+    key_spec = _group_key_spec(key_columns, [v.dtype for v in vdat], ops,
+                               rows)
+    probe = _sort_pack_probe(rows, key_columns, key_spec, emit, vdat) \
+        if observe else None
+    operands = _group_key_operands(ctx, key_columns, key_spec)
+    return (key_spec,) + operands + (table_mod._sort_pack_plan(probe),)
+
+
 def _aggregate_shards(ctx: CylonContext, phase: str, ops, col_ids,
                       all_valid, key_spec, kbits, kdat, kval, emit, vdat,
-                      vval):
+                      vval, packing=(None, None)):
     """One per-shard sort + reduce step (``_groupby_fn``), its sort's
-    operands, its reduce path and the way its groups' keys are read
-    counted here, where the host can see them: the same pure functions
-    of masks, lanes and accumulator widths that presort_groups and
-    sorted_segment_aggregate evaluate inside the program. After an
-    exchange there is always a row mask: the dead flag rides. The row
-    index rides, on a stable sort, only without a ``key_spec``
-    (`_group_keys`). ``phase`` names the step in
-    ``cylon_groupby_phase_total``: "partial" (a shard's own rows, before
-    the exchange), "merge" (the partials, after it) or "single" (the
-    rows themselves after the exchange, or in place: no pre-aggregation)."""
+    operands, the columns that ride inside another operand's word, its
+    reduce path and the way its groups' keys are read counted here, where
+    the host can see them: the same pure functions of masks, lanes,
+    packing and accumulator widths that presort_groups and
+    sorted_segment_aggregate evaluate inside the program. ``emit`` None
+    (a table with no row mask, before any exchange): no dead flag rides;
+    after an exchange there is always a row mask. The row index rides, on
+    a stable sort, only without a ``key_spec`` (`_group_key_spec`).
+    ``packing``: `data/table._sort_pack_plan`'s (plan, params), decided
+    on the host from the observed ranges (`_sort_pack_probe`). ``phase``
+    names the step in ``cylon_groupby_phase_total``: "partial" (a shard's
+    own rows, before the exchange), "merge" (the partials, after it) or
+    "single" (the rows themselves after the exchange, or in place: no
+    pre-aggregation)."""
+    plan, params = packing
     _counter("cylon_groupby_phase_total", {"phase": phase}).inc()
     _counter("cylon_groupby_key_readback_total", {
         "path": "gather" if key_spec is None else "lanes"}).inc()
     _counter("cylon_groupby_sort_operands_total").inc(
         _groupby.sort_operand_count(kbits + kval, emit, vdat, vval,
-                                    key_spec is None))
+                                    key_spec is None, plan))
+    _counter("cylon_groupby_sort_packed_columns_total").inc(
+        _groupby.packed_members(plan))
     _counter("cylon_groupby_reduce_path_total", {
         "path": _groupby.reduce_path(
             [v.dtype for v in vdat], ops,
-            emit.shape[0] // ctx.get_world_size())}).inc()
-    return _groupby_fn(ctx.mesh, ops, col_ids, all_valid, key_spec)(
-        kbits, kdat, kval, emit, vdat, vval)
+            int(kbits[0].shape[0]) // ctx.get_world_size())}).inc()
+    return _groupby_fn(ctx.mesh, ops, col_ids, all_valid, key_spec, plan)(
+        kbits, kdat, kval, emit, vdat, vval, params)
 
 
 # ---------------------------------------------------------------------------
@@ -2061,20 +2113,26 @@ def _group_key_columns(ctx: CylonContext, kout, kvout, safe, kcols):
 def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
                          value_columns, ops: Tuple, emit, seq,
                          col_ids: Tuple = None,
-                         skip_exchange: bool = False):
+                         skip_exchange: bool = False,
+                         observe: bool = False):
     """Shuffle rows by key hash, then aggregate per shard (the step
     ``phase`` of `_aggregate_shards`). Returns (the arguments
     `_group_key_columns` makes the key columns from, agg list of (arr,
-    valid), gvalid). ``col_ids``: static
+    valid), gvalid). ``emit``: the row mask, None for a table without one
+    (then no dead flag rides a step in place, and the exchange's targets
+    program gives the mask it moves the rows under). ``col_ids``: static
     source-column names for the aggregate's sub-reduction dedup (repeated
     (column, op) pairs compute once — see sorted_segment_aggregate).
     ``skip_exchange``: caller asserts every key's rows are already
     co-located on one shard (a co-partitioning witness from a prior
     shuffle/join on the same keys) — the per-shard aggregation is then
     globally exact with NO exchange at all (the plan optimizer's elided
-    groupby-after-join path). Every host statement runs under a leaf
-    span (`distributed_groupby.targets`, the exchange's own leaves,
-    `.keybits`, `.aggregate`), so that a chip's idle time books to a
+    groupby-after-join path). ``observe``: the columns are the operator's
+    input (moved, or in place), whose ranges may pack the sort
+    (`_group_keys`); the merge's partial sums are not looked at. Every
+    host statement runs under a leaf span (`distributed_groupby.targets`,
+    the exchange's own leaves, `.keybits`, `.aggregate`), so that a
+    chip's idle time books to a
     name; `distributed_groupby.shuffle` keeps what they leave."""
     if skip_exchange:
         out_cols = list(key_columns) + list(value_columns)
@@ -2083,12 +2141,16 @@ def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
     else:
         with _span("distributed_groupby.shuffle", seq,
                    world=ctx.get_world_size(),
-                   rows_in=int(emit.shape[0])):
+                   rows_in=int(key_columns[0].data.shape[0])):
             view = Table(list(key_columns) + list(value_columns), ctx,
                          None)
             with _span("distributed_groupby.targets", seq,
                        key_columns=len(key_columns)):
-                targets = _partition_targets_dist(ctx, key_columns)
+                if emit is None:   # the all-ones mask, out of the program
+                    targets, emit = _dispatch_targets(ctx, key_columns,
+                                                      None, True)
+                else:
+                    targets = _partition_targets_dist(ctx, key_columns)
             out_cols, emit_s, _x = _exchange_table(view, targets, emit,
                                                    ctx)
 
@@ -2101,10 +2163,9 @@ def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
         vdat = tuple(shard.pin(c.data, ctx) for c in vcols_s)
         vval = tuple(None if c.validity is None
                      else shard.pin(c.valid_mask(), ctx) for c in vcols_s)
-        key_spec, kbits, kdat, kval = _group_keys(
-            ctx, kcols_s, [v.dtype for v in vdat], ops,
-            int(emit_s.shape[0]) // ctx.get_world_size())
-        _sp.set(key_lanes=len(kbits), rows=int(emit_s.shape[0]))
+        key_spec, kbits, kdat, kval, packing = _group_keys(
+            ctx, kcols_s, emit_s, vdat, ops, observe)
+        _sp.set(key_lanes=len(kbits), rows=int(kbits[0].shape[0]))
 
     with _phase("distributed_groupby.aggregate", seq):
         if col_ids is None:
@@ -2112,7 +2173,7 @@ def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
         all_valid = tuple(c.validity is None for c in vcols_s)
         kout, kvout, gvalid, agg, safe = _aggregate_shards(
             ctx, phase, ops, col_ids, all_valid, key_spec, kbits, kdat,
-            kval, emit_s, vdat, vval)
+            kval, emit_s, vdat, vval, packing)
     return (kout, kvout, safe, kcols_s), list(agg), gvalid
 
 
@@ -2150,7 +2211,10 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
                     "varbytes value columns support COUNT only",
                     code=Code.NotImplemented)
         ops = list(aggregate_ops)
-        emit = shard.pin(t.emit_mask(), ctx)
+        # None (no row mask): every row is live, no dead flag rides the
+        # first sort and no all-ones mask is made (a table `distribute`
+        # had to pad keeps its mask)
+        emit = None if t.row_mask is None else shard.pin(t.row_mask, ctx)
         # the input's capacity: what dist_groupby_partial_share holds
         # the exchange's live rows against
         _counter("cylon_groupby_rows_in_total").inc(int(t.capacity))
@@ -2178,7 +2242,8 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
         value_columns = [t._columns[vi] for vi in val_cols]
         keys, agg, gvalid = _groupby_shuffle_agg(
             ctx, "single", key_columns, value_columns, tuple(ops), emit,
-            seq, col_ids=tuple(val_cols), skip_exchange=pre_partitioned)
+            seq, col_ids=tuple(val_cols), skip_exchange=pre_partitioned,
+            observe=True)
         with _span("distributed_groupby.finish", seq):
             return finish(_group_key_columns(ctx, *keys), [
                 agg_column(arr, av, t._columns[vi], op)
@@ -2210,15 +2275,14 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
             vvalA.append(None if src.validity is None
                          else shard.pin(src.valid_mask(), ctx))
         opsA = tuple(opA for _j, opA, _c in a_entries)
-        specA, kbitsA, kdatA, kvalA = _group_keys(
-            ctx, key_columns, [v.dtype for v in vdatA], opsA,
-            int(emit.shape[0]) // world)
+        specA, kbitsA, kdatA, kvalA, packingA = _group_keys(
+            ctx, key_columns, emit, tuple(vdatA), opsA, True)
         cidsA = tuple((val_cols[j], cast) for j, _opA, cast in a_entries)
         avA = tuple(t._columns[val_cols[j]].validity is None
                     for j, _opA, _c in a_entries)
         koutA, kvoutA, gvalidA, aggA, safeA = _aggregate_shards(
             ctx, "partial", opsA, cidsA, avA, specA, kbitsA, kdatA, kvalA,
-            emit, tuple(vdatA), tuple(vvalA))
+            emit, tuple(vdatA), tuple(vvalA), packingA)
         # the partial table: one row a (shard, group)
         pkey_cols = _group_key_columns(ctx, koutA, kvoutA, safeA,
                                        key_columns)

@@ -39,7 +39,7 @@ SCALES = {"groupby-q5": 0.0025,
           # default scale a chip's 31,250 rows are no multiple of the row
           # quantum (8), so `shard.distribute` pads and the table arrives
           # WITH a row mask, which the cell's 6.25e7 rows a chip never
-          # have: its distribute step makes the all-ones emit mask. (id6's
+          # have: no dead flag rides its first sort (PR 43). (id6's
           # range is the literal 1e7 at every scale, so here nearly every
           # row is a group of its own and the exchange is one program, not
           # the cell's eight chunks: the merge phase's path is the same.)
@@ -121,6 +121,21 @@ def test_groupby_q5_rehearses_packed():
     assert said["sorted"] == 3 * queries
     said, _out = rehearse("groupby-q4", 0)
     assert said["packed"] == 0 and said["sorted"] == 0
+
+
+def test_groupby_q5_w4_rehearses_probed_and_packed():
+    """The cell across chips probes its table before the first per-shard
+    sort, as at 6.25e7 rows a chip (PR 43): ONE fetch of the ranges a
+    query under a sync span of its own. Off a TPU the sort carries the
+    row index and the key as two lanes (bits and mask), so the key is
+    not observed and v1 and v2 share a word of their own: 5 + 10 operands
+    a query where the chip's lanes path has 2 + 8; no row mask, no dead
+    flag."""
+    said, _out = rehearse("groupby-q5-w4", 0)
+    queries = said["packed"]
+    assert queries >= 3 and said["sorted"] == (5 + 10) * queries
+    assert "sync.groupby.valuerange" in said["phases"]
+    assert "sync.groupby.packranges" not in said["phases"]
 
 
 def test_tpch_q1_rehearses_on_the_dense_table_with_its_spans():

@@ -513,18 +513,28 @@ def test_no_probe_where_no_word_could_be_saved(local_ctx, monkeypatch, name):
     assert moved["operands"] == 1 + len(vals) + 1
 
 
-def test_a_sharded_table_packs_nothing(dist_ctx, monkeypatch):
-    """The distributed kernel calls presort_groups under shard_map, where
-    no host observes a range: its sorts carry what they always did."""
+def test_a_sharded_table_packs_by_a_probe_of_the_whole_table(
+        dist_ctx, monkeypatch):
+    """Across chips the first per-shard sort is packed by the same
+    functions, from ONE probe of the whole sharded table before the
+    `shard_map` program is dispatched (PR 43; before, its sorts carried
+    what they always did). Here the CPU's gather path: the key rides as
+    two lanes and is not observed, the two value columns share a word,
+    the merge is not looked at. The cell's shape and the other cases are
+    in tests/test_cell_groupby_q5_w4.py."""
     monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 0)
     rng = np.random.default_rng(3)
-    t = ct.Table.from_pydict(dist_ctx, {
-        "k": _wide_key(rng), "a": _ints(rng, 1, 5), "b": _ints(rng, 1, 15)})
+    cols = {"k": _wide_key(rng), "a": _ints(rng, 1, 5),
+            "b": _ints(rng, 1, 15)}
+    t = ct.Table.from_pydict(dist_ctx, cols)
     before = _counted()
     out = t.groupby(0, [1, 2], ["sum", "sum"])
     moved = _delta(before)
-    assert out.row_count >= 2
-    assert "groupby.valuerange" not in moved and "packed" not in moved
+    assert moved["groupby.valuerange"] == 1 and moved["packed"] == 1
+    got = out.to_pandas()
+    assert len(got) == len(np.unique(cols["k"]))
+    assert int(got["a"].sum()) == int(cols["a"].sum())
+    assert int(got["b"].sum()) == int(cols["b"].sum())
 
 
 def test_one_plan_structure_is_one_program_whatever_the_ranges(

@@ -481,11 +481,12 @@ def test_join_asks_for_key_bits_without_null_lanes(dist_ctx, monkeypatch,
 
 def test_groupby_lanes_ask_for_a_null_lane_a_nullable_key(dist_ctx,
                                                           monkeypatch):
-    """`_group_keys` where the keys can be read off the lanes (steered:
-    on the CPU `sort_carries_index` says "index"): a column's bits, then
-    its validity as a uint8 lane only where the column is nullable, out
-    of the one key-bits program; no data or mask to gather from. Where
-    the index rides: the bits alone, the data and every column's mask."""
+    """`_group_key_spec` / `_group_key_operands` where the keys can be
+    read off the lanes (steered: on the CPU `sort_carries_index` says
+    "index"): a column's bits, then its validity as a uint8 lane only
+    where the column is nullable, out of the one key-bits program; no
+    data or mask to gather from. Where the index rides: the bits alone,
+    the data and every column's mask."""
     from cylon_tpu.ops import groupby as G
 
     t = shard.distribute(_frame(dist_ctx, 81), dist_ctx)
@@ -493,14 +494,16 @@ def test_groupby_lanes_ask_for_a_null_lane_a_nullable_key(dist_ctx,
     valid = jnp.asarray(np.random.default_rng(82).random(t.capacity) < 0.8)
     cols = [k, Column(v.data, v.dtype, shard.pin(valid, dist_ctx), None, "v")]
     asked = _spy_key_bits(monkeypatch)
-    args = (dist_ctx, cols, [jnp.float32], (G.AggregationOp.SUM,), 24)
-    spec, kbits, kdat, kval = D._group_keys(*args)
+    args = (cols, [jnp.float32], (G.AggregationOp.SUM,), 24)
+    spec = D._group_key_spec(*args)
+    kbits, kdat, kval = D._group_key_operands(dist_ctx, cols, spec)
     assert spec is None and asked[-1][1] == (False, False)
     assert (len(kbits), len(kdat), len(kval)) == (2, 2, 2)
     real = G.sort_carries_index
     monkeypatch.setattr(G, "sort_carries_index",
                         lambda *a, **kw: real(*a, **kw, interpret=True))
-    spec, kbits, kdat, kval = D._group_keys(*args)
+    spec = D._group_key_spec(*args)
+    kbits, kdat, kval = D._group_key_operands(dist_ctx, cols, spec)
     assert spec == ((np.dtype(np.int32), False, False),
                     (np.dtype(np.int32), False, True))
     assert asked[-1][1] == (False, True) and (kdat, kval) == ((), ())

@@ -185,6 +185,35 @@ def test_dense_groupby_names_its_probe_its_program_and_its_kernel():
     assert _pallas_names(closed.jaxpr, []) == ["groupby_dense_reduce"]
 
 
+def test_distributed_groupby_names_its_packing_probe():
+    """What a traced ``groupby-q5-w4`` reads since PR 43: the probe of
+    the per-shard sort's packing is a NAMED program, one that
+    `anonymous_programs_per_query`'s list takes (a name that ends in
+    ``_program``) and that `dist_groupby_device_ms_per_query`'s pattern
+    (``^jit_groupby\\b``: the per-shard sort + reduce program alone) does
+    not count."""
+    import json
+    import os
+    import re
+    from cylon_tpu.data import table as table_mod
+
+    n = 4096
+    probe = table_mod._groupby_pack_ranges_program_fn()
+    head = probe.lower(_sds(jnp.int32, n), None,
+                       (_sds(jnp.int32, n), _sds(jnp.int32, n))).as_text()
+    name = "jit_groupby_pack_ranges_program"
+    assert head.startswith(f"module @{name} "), head[:80]
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "metrics")
+    with open(os.path.join(metrics, "anonymous_programs_per_query.json")) as f:
+        named = json.load(f)["not_matching"]
+    assert any(re.search(p, name + "(123)") for p in named)
+    with open(os.path.join(metrics,
+                           "dist_groupby_device_ms_per_query.json")) as f:
+        counted = json.load(f)["patterns"]
+    assert not any(re.search(p, name + "(123)") for p in counted)
+
+
 def test_join_kernels_carry_their_names():
     """The two join kernels, through the programs that the local join
     runs (``_plan_program_stream_impl`` and its materialize): the names

@@ -329,20 +329,26 @@ def shards4(topo, chip, monkeypatch):
         (n,), dtype, sharding=row)
 
 
-@pytest.mark.parametrize("phase,operands", [("partial", 5), ("merge", 8)])
+@pytest.mark.parametrize("phase,operands", [
+    ("partial", 2), ("partial-masked", 5), ("merge", 8)])
 def test_dist_groupby_lanes_program_compiles(shards4, phase, operands):
     """`groupby-q5-w4`'s per-shard program (`jit_groupby`) as a TPU
-    backend gets it since PR 41, for the described 2x2 mesh, with the
-    cell's two operand lists at 2^13 rows a chip: the program's SHAPE is
-    what is held here, and a sort's compile time goes with its rows up
-    to 2^15 and then stays (3 + 5 s here; 70 + 128 s at 2^20, minutes at
-    the cell's 6.25e7 rows and 2^24 slots: PERF.md section 6, where the
-    real shapes' compile is recorded; the reduce kernel at num_segments
-    = n = 2^24 is `test_groupby_stream_reduce_compiles`'s). An int32 key
-    without nulls read back off its sorted lane, so ONE sort
-    that is not stable (dead flag, key lane, three values; in the merge
-    the three partial sums' masks too), ONE Pallas pass, and no gather or
-    scatter at all."""
+    backend gets it, for the described 2x2 mesh, with the cell's operand
+    lists at 2^13 rows a chip: the program's SHAPE is what is held here,
+    and a sort's compile time goes with its rows up to 2^15 and then
+    stays (3 + 5 s here; 70 + 128 s at 2^20, minutes at the cell's
+    6.25e7 rows and 2^24 slots: PERF.md section 6, where the real
+    shapes' compile is recorded; the reduce kernel at num_segments = n =
+    2^24 is `test_groupby_stream_reduce_compiles`'s). An int32 key
+    without nulls read back off its sorted lane (PR 41), so ONE sort that
+    is not stable, ONE Pallas pass, and no gather or scatter at all.
+    "partial" is the cell's first step since PR 43: no row mask, so no
+    dead flag, and the plan the host makes from the observed ranges (id6
+    + v1 + v2 in ONE word, v3) with its replicated ``params``: 2
+    operands. "partial-masked": a table with a row mask whose ranges do
+    not pack, the unpacked list (dead flag, key lane, three values). In
+    the merge the dead flag and the three partial sums' masks ride: 8."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from cylon_tpu.parallel import dist_ops
 
     mesh, shapes = shards4
@@ -351,14 +357,21 @@ def test_dist_groupby_lanes_program_compiles(shards4, phase, operands):
     merge = phase == "merge"
     vdat = (rows(jnp.int32), rows(jnp.int32), rows(jnp.float32))
     vval = (rows(jnp.bool_),) * 3 if merge else (None,) * 3
+    emit = None if phase == "partial" else rows(jnp.bool_)
+    plan = params = None
+    if phase == "partial":
+        plan = _groupby.sort_pack_plan(24, [3, 4, None])
+        assert plan == ((-1, 0, 1), (2,))
+        params = jax.ShapeDtypeStruct((3, 3), jnp.uint32,
+                                      sharding=NamedSharding(mesh, P()))
     fn = dist_ops._groupby_fn(
         mesh, (SUM,) * 3, (0, 1, 2), (not merge,) * 3,
-        ((np.dtype(np.int32), False, False),))
+        ((np.dtype(np.int32), False, False),), plan)
     assert _groupby.sort_operand_count(
-        (rows(jnp.uint32),), rows(jnp.bool_), vdat, vval, False) == operands
+        (rows(jnp.uint32),), emit, vdat, vval, False, plan) == operands
     with jax.enable_x64(False):
-        text = fn.lower((rows(jnp.uint32),), (), (), rows(jnp.bool_), vdat,
-                        vval).compile().as_text()
+        text = fn.lower((rows(jnp.uint32),), (), (), emit, vdat, vval,
+                        params).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     sorts = _sort_lines(text)
     assert len(sorts) == 1, sorts
@@ -366,6 +379,28 @@ def test_dist_groupby_lanes_program_compiles(shards4, phase, operands):
     assert len(args.split(", ")) == operands, sorts[0]
     assert "is_stable=true" not in sorts[0]
     assert not re.search(r"\b(gather|scatter)\(", text)
+
+
+def test_dist_groupby_pack_probe_compiles(shards4):
+    """The probe that packs `groupby-q5-w4`'s first sort
+    (`jit_groupby_pack_ranges_program`) over the described 2x2 mesh at
+    the cell's 6.25e7 rows a chip: a min / max of id6, v1 and v2 a shard
+    and ONE small all-reduce for the whole table's ranges, one
+    ``uint32[3, 3]`` array on every chip; no row mask, so no temporary of
+    a column's size."""
+    from cylon_tpu.data import table as T
+
+    _mesh, shapes = shards4
+    rows = shapes(250_000_000)
+    with jax.enable_x64(False):
+        compiled = T._groupby_pack_ranges_program_fn().lower(
+            rows(jnp.int32), None, (rows(jnp.int32),) * 2).compile()
+    text = compiled.as_text()
+    assert re.search(r"all-reduce", text)
+    assert not re.search(r"all-to-all|all-gather|collective-permute", text)
+    out, = jax.tree.leaves(compiled.output_shardings)
+    assert out.is_fully_replicated
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_dist_groupby_gather_program_still_compiles(shards4):
@@ -381,7 +416,8 @@ def test_dist_groupby_gather_program_still_compiles(shards4):
     with jax.enable_x64(False):
         text = fn.lower((rows(jnp.uint32),) * 4, (rows(jnp.int32),),
                         (rows(jnp.bool_),), rows(jnp.bool_),
-                        (rows(jnp.float32),), (None,)).compile().as_text()
+                        (rows(jnp.float32),), (None,),
+                        None).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     sorts = _sort_lines(text)
     assert len(sorts) == 1 and "is_stable=true" in sorts[0], sorts
