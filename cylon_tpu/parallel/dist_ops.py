@@ -839,8 +839,15 @@ def _varlen_take_concat_fn(mesh, cap_w: int):
 @counted_cache
 def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
                 col_ids: Tuple[int, ...], all_valid: Tuple[bool, ...],
-                key_spec: tuple = None, plan: tuple = None):
-    """The per-shard sort + reduce step. ``key_spec`` (static;
+                key_spec: tuple = None, plan: tuple = None,
+                elided: Tuple[bool, ...] = None):
+    """The per-shard sort + reduce step. Returns (key data, key
+    validities, the groups' row mask ``gvalid``, one (array, validity) an
+    aggregate, ``safe``). An aggregate's validity is its own ANDed with
+    ``gvalid``, or None where ``elided`` (static; `_partial_masks_elided`
+    decides, for the step that makes the partial table, of which
+    ``gvalid`` is the row mask) says it would only repeat ``gvalid``: the
+    AND over the slots is then not computed. ``key_spec`` (static;
     `_group_key_spec` decides) None: the sort carries the row index and
     each group's key is gathered from its first original row (``kdat``,
     ``kval``; ``safe`` goes out for a varbytes key's words). Else
@@ -866,7 +873,8 @@ def _groupby_fn(mesh, ops: Tuple[_groupby.AggregationOp, ...],
         firsts, gvalid, results = _groupby.sorted_segment_aggregate(
             new_grp, emit_s, first_s, vdat_s, vval_s, n, ops, col_ids,
             all_valid, key_spec=key_spec)
-        agg = tuple((arr, av & gvalid) for arr, av in results)
+        agg = tuple((arr, None if e else av & gvalid) for (arr, av), e
+                    in zip(results, elided or (False,) * len(ops)))
         if key_spec is not None:
             kout, kvout = zip(*firsts)
             return kout, kvout, gvalid, agg, None
@@ -902,6 +910,22 @@ def _group_key_spec(key_columns, value_dtypes, ops, rows: int):
                                    value_dtypes, ops, rows):
         return None
     return key_spec
+
+
+def _partial_masks_elided(ops, all_valid) -> Tuple[bool, ...]:
+    """Per aggregate of the PARTIAL step (first-phase ``ops``: MEAN is
+    already a SUM and a COUNT; ``all_valid``: the source column has
+    validity None), whether its validity only repeats the partial table's
+    row mask and so goes as None: no leaf in the exchange, no mask
+    operand and no any-valid pass in the merge. Static and observed on
+    the host before anything is dispatched (no knob): a COUNT is valid
+    wherever its group is, whatever the column; a SUM, MIN or MAX over a
+    column without nulls too (`ops/groupby.sorted_segment_aggregate`
+    hands such an aggregate ``group_valid`` itself). Over a nullable
+    column they keep their mask: a group whose values are all null on a
+    shard is a live partial row with a null aggregate."""
+    return tuple(op == _groupby.AggregationOp.COUNT or av
+                 for op, av in zip(ops, all_valid))
 
 
 def _group_key_operands(ctx: CylonContext, key_columns, key_spec):
@@ -958,7 +982,7 @@ def _group_keys(ctx: CylonContext, key_columns, emit, vdat, ops,
 
 def _aggregate_shards(ctx: CylonContext, phase: str, ops, col_ids,
                       all_valid, key_spec, kbits, kdat, kval, emit, vdat,
-                      vval, packing=(None, None)):
+                      vval, packing=(None, None), elided=None):
     """One per-shard sort + reduce step (``_groupby_fn``), its sort's
     operands, the columns that ride inside another operand's word, its
     reduce path and the way its groups' keys are read counted here, where
@@ -987,7 +1011,8 @@ def _aggregate_shards(ctx: CylonContext, phase: str, ops, col_ids,
         "path": _groupby.reduce_path(
             [v.dtype for v in vdat], ops,
             int(kbits[0].shape[0]) // ctx.get_world_size())}).inc()
-    return _groupby_fn(ctx.mesh, ops, col_ids, all_valid, key_spec, plan)(
+    return _groupby_fn(ctx.mesh, ops, col_ids, all_valid, key_spec, plan,
+                       elided)(
         kbits, kdat, kval, emit, vdat, vval, params)
 
 
@@ -2129,7 +2154,10 @@ def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
     globally exact with NO exchange at all (the plan optimizer's elided
     groupby-after-join path). ``observe``: the columns are the operator's
     input (moved, or in place), whose ranges may pack the sort
-    (`_group_keys`); the merge's partial sums are not looked at. Every
+    (`_group_keys`); the merge's partial sums are not looked at, and a
+    value column with validity None (a partial aggregate over a source
+    without nulls: `_partial_masks_elided`) adds no leaf to the exchange
+    and no mask operand to the step's sort. Every
     host statement runs under a leaf span (`distributed_groupby.targets`,
     the exchange's own leaves, `.keybits`, `.aggregate`), so that a
     chip's idle time books to a
@@ -2252,7 +2280,10 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
     # ---- phase A: per-shard partial aggregation (shuffle bytes then
     # scale with per-shard GROUPS, not rows). MEAN expands to
     # (f64 SUM, COUNT) partial pairs; phase B merges with the correct
-    # second-phase op (COUNT partials are SUMmed).
+    # second-phase op (COUNT partials are SUMmed). The partial table's
+    # columns carry a validity only where it says more than the table's
+    # row mask (a nullable source: `_partial_masks_elided`); the merge's
+    # results, the user's, keep theirs as `groupby_local` gives them.
     a_entries = []   # (orig_pos, opA, cast_f64)
     b_ops = []
     out_map = []     # per original op: ("d", a_idx) | ("mean", si, ci)
@@ -2280,10 +2311,17 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
         cidsA = tuple((val_cols[j], cast) for j, _opA, cast in a_entries)
         avA = tuple(t._columns[val_cols[j]].validity is None
                     for j, _opA, _c in a_entries)
+        # which partials go without a validity (it would be gvalidA)
+        elidedA = _partial_masks_elided(opsA, avA)
+        _counter("cylon_groupby_partial_masks_elided_total").inc(
+            sum(elidedA))
+        _counter("cylon_groupby_partial_masks_carried_total").inc(
+            len(elidedA) - sum(elidedA))
         koutA, kvoutA, gvalidA, aggA, safeA = _aggregate_shards(
             ctx, "partial", opsA, cidsA, avA, specA, kbitsA, kdatA, kvalA,
-            emit, tuple(vdatA), tuple(vvalA), packingA)
-        # the partial table: one row a (shard, group)
+            emit, tuple(vdatA), tuple(vvalA), packingA, elidedA)
+        # the partial table: one row a (shard, group); an elided
+        # aggregate's validity comes back None
         pkey_cols = _group_key_columns(ctx, koutA, kvoutA, safeA,
                                        key_columns)
         pval_cols = [

@@ -362,14 +362,16 @@ def _case_varbytes(ctx, cols, rng):
 # gather path, dispatches of jit_groupby): the dead flag only with a row
 # mask (PR 43: a table `distribute` did not have to pad has none, and
 # after an exchange there always is one), then the key lanes, the values,
-# their masks (the partial sums have one each), and the index. The
-# cell's shape was 4 + 8 against 6 + 10 (the chip packs the 4 into 2:
+# their masks where they have one (PR 44: the partial sums of columns
+# without nulls have none, their validity is the partial table's row
+# mask; they had one each: + 8, + 9, + 10, + 12), and the index. The
+# cell's shape is 4 + 5 against 6 + 7 (the chip packs the 4 into 2:
 # tests/test_groupby_sort_pack.py)
 KEY_READBACK_CASES = {
-    "no_nulls": (_case_no_nulls, 4 + 8, 6 + 10, 2),
-    "nullable_key": (_case_nullable_key, 5 + 9, 6 + 10, 2),
-    "two_keys": (_case_two_keys, 5 + 9, 8 + 12, 2),
-    "masked": (_case_masked, 5 + 8, 7 + 10, 2),
+    "no_nulls": (_case_no_nulls, 4 + 5, 6 + 7, 2),
+    "nullable_key": (_case_nullable_key, 5 + 6, 6 + 7, 2),
+    "two_keys": (_case_two_keys, 5 + 6, 8 + 9, 2),
+    "masked": (_case_masked, 5 + 5, 7 + 7, 2),
     "single": (_case_single, 5, 7, 1),
     "varbytes": (_case_varbytes, None, None, 2),
 }
@@ -487,9 +489,10 @@ def _spread(ctx, cols, validity=None, mask=None):
 
 def _d_no_row_mask(ctx, rng):
     # (a) the cell's shape: no mask, so no dead flag; key + v1 + v2 in one
-    # word and v3: 2 operands, then the merge's 8
+    # word and v3: 2 operands, then the merge's 5 (dead flag, key lane,
+    # three partial sums and none of their masks: 8 before PR 44)
     return _spread(ctx, _q5_cols(rng)), {}, dict(
-        operands=2 + 8, packed=2, site="groupby.packranges")
+        operands=2 + 5, packed=2, site="groupby.packranges")
 
 
 def _d_filtered(ctx, rng):
@@ -501,7 +504,7 @@ def _d_filtered(ctx, rng):
     cols["id6"] = np.where(mask, cols["id6"], rng.choice(
         [I32_MIN, I32_MAX, -1, 70_000], ROWS4)).astype(np.int32)
     return _spread(ctx, cols, mask=mask), {}, dict(
-        operands=3 + 8, packed=2, site="groupby.packranges")
+        operands=3 + 5, packed=2, site="groupby.packranges")
 
 
 def _d_padded(ctx, rng):
@@ -509,14 +512,14 @@ def _d_padded(ctx, rng):
     t = shard.distribute(
         ct.Table.from_pydict(ctx, _q5_cols(rng, ROWS4 - 3)), ctx)
     assert t.row_mask is not None
-    return t, {}, dict(operands=3 + 8, packed=2, site="groupby.packranges")
+    return t, {}, dict(operands=3 + 5, packed=2, site="groupby.packranges")
 
 
 def _d_nullable_key(ctx, rng):
     # (c) a nullable key rides as two lanes and is not observed: the
     # value columns share a word among themselves, probed alone
     t = _spread(ctx, _q5_cols(rng), validity={0: rng.random(ROWS4) > 0.05})
-    return t, {}, dict(operands=4 + 9, packed=1, site="groupby.valuerange")
+    return t, {}, dict(operands=4 + 6, packed=1, site="groupby.valuerange")
 
 
 def _d_wide_range(ctx, rng):
@@ -525,7 +528,7 @@ def _d_wide_range(ctx, rng):
     v1 = rng.integers(I32_MIN, I32_MAX, ROWS4).astype(np.int32)
     v1[:2] = (I32_MIN, I32_MAX)
     return _spread(ctx, _q5_cols(rng, v1=v1)), {}, dict(
-        operands=3 + 8, packed=1, site="groupby.packranges", exact_v1=False)
+        operands=3 + 5, packed=1, site="groupby.packranges", exact_v1=False)
 
 
 def _d_negative(ctx, rng):
@@ -533,7 +536,7 @@ def _d_negative(ctx, rng):
     cols = _q5_cols(rng, key=_ints_of(rng, -9000, -4000),
                     v1=_ints_of(rng, -1000, -990))
     return _spread(ctx, cols), {}, dict(
-        operands=2 + 8, packed=2, site="groupby.packranges")
+        operands=2 + 5, packed=2, site="groupby.packranges")
 
 
 def _d_dictionary_key(ctx, rng):
@@ -542,7 +545,7 @@ def _d_dictionary_key(ctx, rng):
     cols = _q5_cols(rng, key=words[rng.integers(0, 900, ROWS4)])
     t = _spread(ctx, cols)
     assert t.get_column(0).is_string and not t.get_column(0).is_varbytes
-    return t, {}, dict(operands=2 + 8, packed=2, site="groupby.packranges")
+    return t, {}, dict(operands=2 + 5, packed=2, site="groupby.packranges")
 
 
 def _d_empty_shard(ctx, rng):
@@ -550,7 +553,7 @@ def _d_empty_shard(ctx, rng):
     mask = np.ones(ROWS4, bool)
     mask[ROWS4 // 2:3 * ROWS4 // 4] = False
     return _spread(ctx, _q5_cols(rng), mask=mask), {}, dict(
-        operands=3 + 8, packed=2, site="groupby.packranges")
+        operands=3 + 5, packed=2, site="groupby.packranges")
 
 
 def _d_single_after_exchange(ctx, rng):
@@ -647,8 +650,9 @@ def test_the_planned_groupby_across_chips_probes_once_and_packs(
         dist_ctx, monkeypatch):
     """Through the plan, as the cell `groupby-q5-w4` runs it: the probe's
     span opens once, inside `distributed_groupby.pre_aggregate`, the
-    operands are 2 + 8 with two packed columns, and the fetches a query
-    are two (the ranges, the exchange's counts)."""
+    operands are 2 + 5 with two packed columns (the partial sums carry no
+    mask: PR 44), and the fetches a query are two (the ranges, the
+    exchange's counts)."""
     monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 0)
     _as_on_a_tpu(monkeypatch)
     rng = np.random.default_rng(43)
@@ -662,7 +666,7 @@ def test_the_planned_groupby_across_chips_probes_once_and_packs(
     finally:
         dist_ops._groupby_fn.cache_clear()
     moved = _dist_delta(before)
-    assert moved == {"operands": 10, "packed": 2, "partial": 1, "merge": 1,
+    assert moved == {"operands": 7, "packed": 2, "partial": 1, "merge": 1,
                      "groupby.packranges": 1, "shuffle.count": 1}
     syncs = [s for s in cp.spans if s.name.startswith("sync.")]
     assert sorted(s.name for s in syncs) == ["sync.groupby.packranges",
@@ -677,13 +681,15 @@ def test_the_planned_groupby_across_chips_probes_once_and_packs(
 def test_a_shard_under_the_row_gate_pays_no_probe(dist_ctx):
     """Four shards of 512 rows are under SORT_PACK_MIN_ROWS a SHARD: the
     sorts carry what they always did, less the dead flag that a table
-    without a row mask never needed (the CPU's gather path: 6 + 10)."""
+    without a row mask never needed and the masks that the partial sums
+    of columns without nulls never needed (the CPU's gather path: 6 + 7;
+    6 + 10 before PR 44)."""
     assert ROWS4 // 4 < G.SORT_PACK_MIN_ROWS
     t = _spread(dist_ctx, _q5_cols(np.random.default_rng(3)))
     before = _dist_counted()
     out = t.groupby(0, [1, 2, 3], ["sum"] * 3)
     assert out.row_count >= 2
-    assert _dist_delta(before) == {"operands": 6 + 10, "partial": 1,
+    assert _dist_delta(before) == {"operands": 6 + 7, "partial": 1,
                                    "merge": 1, "shuffle.count": 1}
 
 
@@ -691,16 +697,192 @@ def test_the_gather_path_packs_the_values_among_themselves(
         dist_ctx, monkeypatch):
     """On the CPU the per-shard sort carries the row index and the key's
     mask lane beside its bits (two key lanes: the key is not observed),
-    so v1 and v2 share a word of their own: 5 + 10 for 6 + 10."""
+    so v1 and v2 share a word of their own: 5 + 7 for 6 + 7 (the merge
+    carries the dead flag, the key's two lanes, the three partial sums
+    and the index: the sums' three masks rode too before PR 44)."""
     monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 0)
     t = _spread(dist_ctx, _q5_cols(np.random.default_rng(4)))
     before = _dist_counted()
     out = t.groupby(0, [1, 2, 3], ["sum"] * 3)
     moved = _dist_delta(before)
-    assert moved == {"operands": 5 + 10, "packed": 1, "partial": 1,
+    assert moved == {"operands": 5 + 7, "packed": 1, "partial": 1,
                      "merge": 1, "groupby.valuerange": 1, "shuffle.count": 1}
     live = t.to_pandas()
     got = out.to_pandas()
     assert len(got) == live["id6"].nunique()
     for name in ("v1", "v2", "v3"):
         assert float(got[name].sum()) == float(live[name].sum())
+
+
+# --------------------------------------------------------------------------
+# the partial table's masks (PR 44): a partial aggregate whose validity
+# would only repeat the partial table's row mask is built with validity
+# None (`dist_ops._partial_masks_elided`: a COUNT, or a SUM / MIN / MAX over
+# a source column without nulls), so it adds no leaf to the exchange and no
+# mask operand to the merge's sort; a nullable source keeps its mask. The
+# parent's path, every partial with a mask of its own, is the same code
+# under a rule that elides nothing
+# --------------------------------------------------------------------------
+
+OPS = G.AggregationOp
+MASKS = ("cylon_groupby_partial_masks_elided_total",
+         "cylon_groupby_partial_masks_carried_total",
+         "cylon_groupby_sort_operands_total")
+# four shards of 512 rows: keys no random row has, placed by shard
+HALF_NULL, ALL_NULL = 5000, 5001
+
+
+def _null_groups(cols, valid):
+    """HALF_NULL: null on shard 0 and valid (7, 9) on shard 1. ALL_NULL:
+    rows on shards 0 and 2, every value null."""
+    for row, key, ok, value in ((3, HALF_NULL, False, 100),
+                                (9, HALF_NULL, False, 100),
+                                (512 + 5, HALF_NULL, True, 7),
+                                (512 + 6, HALF_NULL, True, 9),
+                                (11, ALL_NULL, False, 100),
+                                (1024 + 2, ALL_NULL, False, 100)):
+        cols["id6"][row], valid[row] = key, ok
+        for name in ("v1", "v2", "v3"):
+            cols[name][row] = value
+    return cols, valid
+
+
+def _m_q5(ctx, rng):
+    # the cell's query: three sums over columns without nulls. 4 columns
+    # cross and no mask (7 leaves before); 2 + 5 operands
+    return _spread(ctx, _q5_cols(rng)), [1, 2, 3], [OPS.SUM] * 3, dict(
+        elided=3, carried=0, lanes=4, operands=2 + 5, parent_lanes=7,
+        parent_operands=2 + 8)
+
+
+def _m_nullable(ctx, rng):
+    # a nullable value column keeps its mask leg and its any-valid pass
+    # (id6 + v1 in one word and v1's mask; then dead flag, key lane, the
+    # partial sum and its mask)
+    cols, valid = _null_groups(_q5_cols(rng), rng.random(ROWS4) > 0.2)
+    return _spread(ctx, cols, validity={1: valid}), [1], [OPS.SUM], dict(
+        elided=0, carried=1, lanes=3, operands=2 + 4, parent_lanes=3,
+        parent_operands=2 + 4, nullable=("v1",))
+
+
+def _m_mixed(ctx, rng):
+    # one nullable and two plain columns: 1 leg carried, 2 elided
+    cols, valid = _null_groups(_q5_cols(rng), rng.random(ROWS4) > 0.2)
+    return _spread(ctx, cols, validity={2: valid}), [1, 2, 3], \
+        [OPS.SUM] * 3, dict(
+            elided=2, carried=1, lanes=5, operands=3 + 6, parent_lanes=7,
+            parent_operands=3 + 8, nullable=("v2",))
+
+
+def _m_all_ops(ctx, rng):
+    # MEAN + COUNT + MIN + MAX in one query, v2 nullable: MEAN(v2) is a
+    # SUM that carries and a COUNT that does not; COUNT(v2) elides
+    # whatever the column; MIN(v2) carries; MAX(v1), MEAN(v3) elide (the
+    # 8-byte accumulators take the gather path on any backend)
+    cols, valid = _null_groups(_q5_cols(rng), rng.random(ROWS4) > 0.2)
+    return _spread(ctx, cols, validity={2: valid}), [2, 2, 2, 1, 3], [
+        OPS.MEAN, OPS.COUNT, OPS.MIN, OPS.MAX, OPS.MEAN], dict(
+            elided=5, carried=2, nullable=("v2",))
+
+
+MASK_CASES = {f.__name__[3:]: f for f in (
+    _m_q5, _m_nullable, _m_mixed, _m_all_ops)}
+
+
+def _masks_counted():
+    snap = telemetry.metrics_snapshot()
+    return [snap.get(k, 0) for k in MASKS]
+
+
+def _grouped(ctx, monkeypatch, t, values, ops, tpu):
+    """(result table, [elided, carried, sort operands] counted, the
+    payload's leaves) of one distributed groupby, as a TPU backend runs it
+    or as the CPU does."""
+    with monkeypatch.context() as m:
+        m.setattr(G, "SORT_PACK_MIN_ROWS", 0)
+        if tpu:
+            _as_on_a_tpu(m)
+        dist_ops._groupby_fn.cache_clear()
+        before = _masks_counted()
+        try:
+            with telemetry.collect_phases() as cp:
+                out = dist_ops.distributed_groupby(t, 0, values, ops)
+        finally:
+            dist_ops._groupby_fn.cache_clear()
+        moved = [a - b for a, b in zip(_masks_counted(), before)]
+    lanes, = [s.attrs["lanes"] for s in cp.spans
+              if s.name == "shuffle.payload"]
+    return out, moved, lanes
+
+
+@pytest.mark.parametrize("backend", ["as_tpu", "cpu"])
+@pytest.mark.parametrize("name", list(MASK_CASES))
+def test_a_partial_mask_rides_only_where_it_says_more_than_the_row_mask(
+        dist_ctx, monkeypatch, name, backend):
+    """Which partial aggregates leave without a mask and which keep
+    theirs (the two counters), what then crosses the exchange (the
+    `shuffle.payload` span's leaves) and rides the merge's sort, and the
+    result: equal to the parent's path (no mask elided) bit for bit, every
+    result column's validity array and the row mask included, and to the
+    plain oracle of the same rows, a group whose values are all null on
+    every shard a null and one that is null on one shard only a value."""
+    t, values, ops, want = MASK_CASES[name](
+        dist_ctx, np.random.default_rng(len(name)))
+    tpu = backend == "as_tpu"
+    out, moved, lanes = _grouped(dist_ctx, monkeypatch, t, values, ops, tpu)
+    with monkeypatch.context() as m:   # the parent: every partial a mask
+        m.setattr(dist_ops, "_partial_masks_elided",
+                  lambda ops, all_valid: (False,) * len(ops))
+        parent, parent_moved, parent_lanes = _grouped(
+            dist_ctx, m, t, values, ops, tpu)
+
+    assert moved[:2] == [want["elided"], want["carried"]]
+    assert parent_moved[:2] == [0, want["elided"] + want["carried"]]
+    if tpu and "lanes" in want:   # the key lane has no mask leg there
+        assert (lanes, parent_lanes) == (want["lanes"], want["parent_lanes"])
+        assert (moved[2], parent_moved[2]) == (want["operands"],
+                                               want["parent_operands"])
+    # a leaf and a sort operand fewer an elided mask, on either path
+    assert parent_lanes - lanes == want["elided"]
+    assert parent_moved[2] - moved[2] == want["elided"]
+
+    assert np.array_equal(np.asarray(out.row_mask),
+                          np.asarray(parent.row_mask))
+    for c, p in zip(out.columns(), parent.columns()):
+        assert (c.name, c.dtype, c.validity is None) == (
+            p.name, p.dtype, p.validity is None)
+        assert np.asarray(c.data).tobytes() == np.asarray(p.data).tobytes()
+        assert np.array_equal(np.asarray(c.valid_mask()),
+                              np.asarray(p.valid_mask())), c.name
+        # the user's result keeps its validity as `groupby_local` gives it
+        assert c.validity is not None or c.name == "id6"
+
+    live = t.to_pandas()
+    got = _by_key(out.to_pandas())
+    fn = {OPS.SUM: "sum", OPS.MEAN: "mean", OPS.COUNT: "count",
+          OPS.MIN: "min", OPS.MAX: "max"}
+    grouped = live.groupby("id6")
+    assert list(got["id6"]) == sorted(grouped.groups)
+    for j, (vi, op) in enumerate(zip(values, ops)):
+        col = live.columns[vi]
+        kw = {"min_count": 1} if op == OPS.SUM else {}
+        ref = getattr(grouped[col], fn[op])(**kw).to_numpy(np.float64)
+        mine = got.iloc[:, 1 + j].to_numpy(np.float64)
+        np.testing.assert_allclose(mine, ref, rtol=1e-6, equal_nan=True)
+        if col in want.get("nullable", ()) and op != OPS.COUNT:
+            by = dict(zip(got["id6"], mine))
+            assert np.isnan(by[ALL_NULL])
+            assert by[HALF_NULL] == {OPS.SUM: 16, OPS.MEAN: 8, OPS.MIN: 7,
+                                     OPS.MAX: 9}[op]
+
+
+@pytest.mark.parametrize("row_bytes,plan", [(19, (2 ** 19, 8)),
+                                            (16, (2 ** 20, 4))])
+def test_the_cells_chunk_geometry_without_the_masks(row_bytes, plan):
+    """`groupby-q5-w4`'s partial table crosses in blocks of 2^22 slots a
+    (source, target) pair: at 4 x 4 bytes of data and 3 bool masks a row
+    the exchange cut it into 8 chunk programs of 2^19 rows, at 16 bytes
+    into 4 of 2^20."""
+    from cylon_tpu.parallel import shuffle
+
+    assert shuffle._chunk_plan(2 ** 22, 4, row_bytes) == plan

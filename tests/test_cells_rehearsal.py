@@ -128,12 +128,13 @@ def test_groupby_q5_w4_rehearses_probed_and_packed():
     sort, as at 6.25e7 rows a chip (PR 43): ONE fetch of the ranges a
     query under a sync span of its own. Off a TPU the sort carries the
     row index and the key as two lanes (bits and mask), so the key is
-    not observed and v1 and v2 share a word of their own: 5 + 10 operands
-    a query where the chip's lanes path has 2 + 8; no row mask, no dead
+    not observed and v1 and v2 share a word of their own: 5 + 7 operands
+    a query where the chip's lanes path has 2 + 5 (PR 44: the partial
+    sums carry no mask, 5 + 10 and 2 + 8 before); no row mask, no dead
     flag."""
     said, _out = rehearse("groupby-q5-w4", 0)
     queries = said["packed"]
-    assert queries >= 3 and said["sorted"] == (5 + 10) * queries
+    assert queries >= 3 and said["sorted"] == (5 + 7) * queries
     assert "sync.groupby.valuerange" in said["phases"]
     assert "sync.groupby.packranges" not in said["phases"]
 

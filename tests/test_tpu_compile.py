@@ -330,7 +330,8 @@ def shards4(topo, chip, monkeypatch):
 
 
 @pytest.mark.parametrize("phase,operands", [
-    ("partial", 2), ("partial-masked", 5), ("merge", 8)])
+    ("partial", 2), ("partial-masked", 5), ("merge", 5),
+    ("merge-nullable", 8)])
 def test_dist_groupby_lanes_program_compiles(shards4, phase, operands):
     """`groupby-q5-w4`'s per-shard program (`jit_groupby`) as a TPU
     backend gets it, for the described 2x2 mesh, with the cell's operand
@@ -346,17 +347,22 @@ def test_dist_groupby_lanes_program_compiles(shards4, phase, operands):
     dead flag, and the plan the host makes from the observed ranges (id6
     + v1 + v2 in ONE word, v3) with its replicated ``params``: 2
     operands. "partial-masked": a table with a row mask whose ranges do
-    not pack, the unpacked list (dead flag, key lane, three values). In
-    the merge the dead flag and the three partial sums' masks ride: 8."""
+    not pack, the unpacked list (dead flag, key lane, three values).
+    "merge" is the cell's second step since PR 44: the dead flag (after
+    an exchange there is always a row mask), the key lane and the three
+    partial sums, whose validity is the partial table's row mask and so
+    None (`dist_ops._partial_masks_elided`): 5. "merge-nullable": the
+    partial sums of three nullable columns keep their masks and the merge
+    its any-valid passes, the cell's program before PR 44: 8."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from cylon_tpu.parallel import dist_ops
 
     mesh, shapes = shards4
     rows = shapes(4 << 13)
     SUM = _groupby.AggregationOp.SUM
-    merge = phase == "merge"
+    nullable = phase == "merge-nullable"
     vdat = (rows(jnp.int32), rows(jnp.int32), rows(jnp.float32))
-    vval = (rows(jnp.bool_),) * 3 if merge else (None,) * 3
+    vval = (rows(jnp.bool_),) * 3 if nullable else (None,) * 3
     emit = None if phase == "partial" else rows(jnp.bool_)
     plan = params = None
     if phase == "partial":
@@ -364,9 +370,14 @@ def test_dist_groupby_lanes_program_compiles(shards4, phase, operands):
         assert plan == ((-1, 0, 1), (2,))
         params = jax.ShapeDtypeStruct((3, 3), jnp.uint32,
                                       sharding=NamedSharding(mesh, P()))
+    all_valid = (not nullable,) * 3
+    # a step that makes the partial table drops the masks that repeat its
+    # row mask, here all three
+    elided = dist_ops._partial_masks_elided((SUM,) * 3, all_valid) \
+        if phase.startswith("partial") else None
     fn = dist_ops._groupby_fn(
-        mesh, (SUM,) * 3, (0, 1, 2), (not merge,) * 3,
-        ((np.dtype(np.int32), False, False),), plan)
+        mesh, (SUM,) * 3, (0, 1, 2), all_valid,
+        ((np.dtype(np.int32), False, False),), plan, elided)
     assert _groupby.sort_operand_count(
         (rows(jnp.uint32),), emit, vdat, vval, False, plan) == operands
     with jax.enable_x64(False):
