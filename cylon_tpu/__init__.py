@@ -23,7 +23,7 @@ from .ops.groupby import AggregationOp
 from .ops.join import JoinAlgorithm, JoinConfig, JoinType
 from . import native
 from .parallel.dist_ops import (distributed_groupby, distributed_join,
-                                distributed_join_ring, distributed_set_op,
+                                distributed_set_op,
                                 distributed_sort, hash_partition,
                                 repartition, shuffle)
 from .parallel.shard import distribute_by_key
@@ -50,7 +50,7 @@ __all__ = [
     "plan", "resilience", "service",
     "Status", "TPUConfig", "Table", "Type", "concat_tables",
     "distribute_by_key", "distributed_groupby", "distributed_join",
-    "distributed_join_ring", "distributed_set_op",
+    "distributed_set_op",
     "distributed_sort", "hash_partition", "join", "native", "read_csv",
     "read_csv_per_rank",
     "read_parquet", "read_parquet_per_rank", "repartition", "set_op",

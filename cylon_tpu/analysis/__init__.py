@@ -4,8 +4,7 @@ Ten checker families guard the invariants the paper's *local kernel +
 shuffle + local kernel* decomposition rests on (SURVEY §1), each
 registered in `core.CHECKERS` and runnable from one entry point:
 
-* ``layering``      — declarative per-subsystem import contracts
-                      (generalizes scripts/check_plan_imports.py);
+* ``layering``      — declarative per-subsystem import contracts;
 * ``hostsync``      — AST detector for host transfers inside traced
                       (`jit`/`shard_map`/Pallas) code;
 * ``collectives``   — jaxpr-level checks over the `parallel/` kernel

@@ -407,20 +407,6 @@ def default_entry_points() -> List[EntryPoint]:
                        (_sds(N, b),), None),
             factory="_groupby_fn"),
         EntryPoint(
-            "ring_count", do,
-            lambda m: D(m)._ring_count_fn(m, True, 1),
-            lambda m: ((_sds(N, u32),), _sds(N, b), _sds(N, b),
-                       (_sds(N, u32),), _sds(N, b), _sds(N, b)),
-            factory="_ring_count_fn"),
-        EntryPoint(
-            "ring_materialize", do,
-            lambda m: D(m)._ring_mat_fn(m, True, 8, 8, 1),
-            lambda m: ((_sds(N, u32),), _sds(N, b), _sds(N, b),
-                       (_sds(N, u32),), _sds(N, b), _sds(N, b),
-                       rows(i32, jnp.float32), rows(b, b),
-                       rows(i32,), rows(b,)),
-            factory="_ring_mat_fn"),
-        EntryPoint(
             "shard_sort", do,
             lambda m: D(m)._shard_sort_fn(m, 2, 2, 1),
             lambda m: ((_sds(N, u32),), _sds(N, b),

@@ -8,8 +8,8 @@ own key preparation, shuffle routing, witness semantics and capacity
 policy. Each `LayerContract` below states one such seam as data; the
 checker is a single AST pass that resolves every import (absolute and
 relative) to a package-relative module path and matches it against the
-contract table. `scripts/check_plan_imports.py` — the original ad-hoc
-gate this generalizes — now delegates to the ``plan-no-ops`` rule.
+contract table (the ``plan-no-ops`` rule is the original ad-hoc gate,
+generalized).
 
 Contracts are matched against the *package root* of the analysis
 context, so the same checker runs against fixture trees with seeded
@@ -109,8 +109,6 @@ DEFAULT_CONTRACTS: Tuple[LayerContract, ...] = (
         name="parallel-no-plan",
         scope=("parallel",),
         forbid=("plan",),
-        exempt=("task_plan.py",),  # legacy shim: absorbed as plan.tasks
-        #        in PR 1, kept only to re-export the moved names
         reason="the plan subsystem lowers ONTO parallel/; an upward "
                "import would cycle the lowering contract",
     ),

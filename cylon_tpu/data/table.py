@@ -469,8 +469,6 @@ class Table:
     def distributed_join(self, table: "Table", join_type: str = "inner",
                          algorithm: str = "auto", **kwargs) -> "Table":
         """comm="shuffle" (default) repartitions both sides via all-to-all;
-        comm="ring" streams the build side around the mesh ring
-        (ArrowJoin-style overlap, best for a small build side);
         comm="broadcast" replicates ``build_side`` (0=left, 1=right;
         default right) to every shard and probes locally — zero
         all-to-all, the adaptive optimizer's rewrite target for a
@@ -480,16 +478,13 @@ class Table:
         comm = kwargs.pop("comm", "shuffle")
         build_side = kwargs.pop("build_side", 1)
         cfg = self._make_join_config(table, join_type, algorithm, kwargs)
-        if comm == "ring":
-            return dist_ops.distributed_join_ring(self, table, cfg)
         if comm == "broadcast":
             return dist_ops.broadcast_hash_join(self, table, cfg,
                                                 build_side=int(build_side))
         if comm != "shuffle":
             raise CylonError(Code.Invalid,
                              f"unknown comm mode {comm!r} "
-                             "(expected 'shuffle', 'ring' or "
-                             "'broadcast')")
+                             "(expected 'shuffle' or 'broadcast')")
         return dist_ops.distributed_join(self, table, cfg)
 
     def _make_join_config(self, table: "Table", join_type, algorithm, kwargs

@@ -45,10 +45,9 @@ from ..ops import setops as _setops
 from ..status import Code, CylonPlanError
 from ..telemetry import annotate as _annotate, counted_cache, \
     counter as _counter, host_fetch as _host_fetch, ledger as _ledger, \
-    phase as _phase, span as _span
+    span as _span
 from . import shard
-from ..util import bucket_cap as _bucket_cap, capacity as _capacity, \
-    pow2_floor as _pow2_floor
+from ..util import bucket_cap as _bucket_cap, pow2_floor as _pow2_floor
 from .shuffle import count_pair, exchange, exchange_pair, \
     replicated_gather
 
@@ -1330,7 +1329,7 @@ def distributed_join(left: Table, right: Table,
     res = None
     rows_out = None
     if mode is not None:
-        with _phase("distributed_join.plan", seq):
+        with _span("distributed_join.plan", seq):
             rep_counts, counts_dev, a_streams, b_streams = \
                 _join_plan_stream_fn(ctx.mesh, jt, len(lkb), a_desc,
                                      b_desc, br, hash_mode)(
@@ -1344,7 +1343,7 @@ def distributed_join(left: Table, right: Table,
                 _host_fetch("join.plan", rep_counts)).reshape(world, -1)
             collided = hash_mode and int(cm[:, 3].sum()) > 0
         if not collided:
-            with _phase("distributed_join.materialize", seq):
+            with _span("distributed_join.materialize", seq):
                 cap_e = _join.stream_expand_capacity(int(cm[:, 0].max()),
                                                      br)
                 res = _join_mat_stream_fn(
@@ -1357,7 +1356,7 @@ def distributed_join(left: Table, right: Table,
     if res is not None:
         lod, lov, rod, rov, emit, lidx_o, ridx_o = res
     else:
-        with _phase("distributed_join.plan", seq):
+        with _span("distributed_join.plan", seq):
             table_mod.count_plan_sort(lkb, (False,) * len(lkb),
                                       len(ldat) + len(rdat))
             counts2, lo, m, bperm, un_mask = _join_plan_fn(ctx.mesh, jt)(
@@ -1507,17 +1506,8 @@ def _exact_dict_redo(left: Table, right: Table, config: _join.JoinConfig,
 
 
 # ---------------------------------------------------------------------------
-# streaming / overlapped ring join (reference: ArrowJoin, arrow_join.hpp:
-# 50-198 — the streaming alternative to the barrier shuffle: two
-# ArrowAllToAlls drained incrementally while local joins run).
-#
-# TPU-native form: the BUILD side rotates around the mesh ring via
-# `lax.ppermute` while every shard joins its RESIDENT probe shard against
-# the visiting block — XLA's async collective-permute overlaps the next
-# block's transfer with the current block's join. The probe side is never
-# repartitioned at all, so total bytes on the ring ≈ size(build), vs
-# size(probe+build) through the all-to-all — the win when the build side
-# is small or the probe side is large and already resident.
+# one join side as per-shard kernel operands and back: what the broadcast
+# join hands its programs and rebuilds its result from
 # ---------------------------------------------------------------------------
 
 
@@ -1527,9 +1517,8 @@ def _prep_join_side(ctx: CylonContext, t: Table, cols, other_cols):
     with every (short) varbytes column's word lanes APPENDED as extra
     fixed-width lanes (the ArrowJoin trick — strings ride the
     fixed-width machinery; ``lane_slots`` maps column -> (first lane
-    index, lane count) for the rebuild). Shared by the ring join
-    (lanes rotate with the visiting block) and the broadcast join
-    (lanes gather with the replicated build side)."""
+    index, lane count) for the rebuild). The broadcast join's lanes
+    gather with the replicated build side."""
     bits, kv = _dist_key_bits(ctx, cols, other_cols)
     emit = shard.pin(t.emit_mask(), ctx)
     dat = [shard.pin(c.data, ctx) for c in t._columns]
@@ -1567,232 +1556,6 @@ def _rebuild_join_side(ctx: CylonContext, slabs_d, slabs_v, t: Table,
             cols.append(Column(d, c.dtype, v, c.dictionary,
                                f"{prefix}-{i}"))
     return cols
-
-
-def _varying(axis, tree):
-    """Mark a pytree as mesh-varying so fori_loop carries type-match the
-    ppermute/per-shard values produced inside the loop body."""
-    return jax.tree.map(lambda x: jax.lax.pcast(x, axis, to="varying"),
-                        tree)
-
-
-@counted_cache
-def _ring_count_fn(mesh, emit_unmatched_a: bool, nkeys: int):
-    axis = mesh.axis_names[0]
-    world = mesh.devices.size
-    spec = P(axis)
-    perm = [(i, (i + 1) % world) for i in range(world)]
-
-    def kernel(lbits, lkv, lemit, rbits, rkv, remit):
-        def rot(t):
-            return jax.tree.map(lambda x: jax.lax.ppermute(x, axis, perm), t)
-
-        def step(k, carry):
-            (rb, rkvc, remc), pairs, amatched = carry
-            _, _, m, _, _ = _join.join_plan_keys(
-                lbits, lkv, lemit, rb, rkvc, remc, _join.JoinType.INNER)
-            pairs = pairs.at[k].set(m.sum(dtype=jnp.int32))
-            amatched = amatched | (m > 0)
-            return rot((rb, rkvc, remc)), pairs, amatched
-
-        pairs0, amatched0 = _varying(axis, (
-            jnp.zeros(world, jnp.int32), jnp.zeros(lemit.shape[0], bool)))
-        _, pairs, amatched = jax.lax.fori_loop(
-            0, world, step, ((rbits, rkv, remit), pairs0, amatched0))
-        n_extra = (lemit & ~amatched).sum(dtype=jnp.int32) \
-            if emit_unmatched_a else jnp.zeros((), jnp.int32)
-        counts = jnp.concatenate([pairs, n_extra[None]])
-        return replicated_gather(counts, axis, world)
-
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 6,
-                             out_specs=P()))
-
-
-@counted_cache
-def _ring_mat_fn(mesh, emit_unmatched_a: bool, cap_step: int, cap_extra: int,
-                 nkeys: int):
-    axis = mesh.axis_names[0]
-    world = mesh.devices.size
-    spec = P(axis)
-    perm = [(i, (i + 1) % world) for i in range(world)]
-    cap_total = world * cap_step + cap_extra
-
-    def kernel(lbits, lkv, lemit, rbits, rkv, remit, adat, aval, bdat, bval):
-        def rot(t):
-            return jax.tree.map(lambda x: jax.lax.ppermute(x, axis, perm), t)
-
-        def slab_like(x):
-            return jnp.zeros((cap_total,) + x.shape[1:], x.dtype)
-
-        slabs_a = tuple(slab_like(d) for d in adat)
-        slabs_av = tuple(jnp.zeros(cap_total, bool) for _ in adat)
-        slabs_b = tuple(slab_like(d) for d in bdat)
-        slabs_bv = tuple(jnp.zeros(cap_total, bool) for _ in bdat)
-        emit0 = jnp.zeros(cap_total, bool)
-        slabs_a, slabs_av, slabs_b, slabs_bv, emit0 = _varying(
-            axis, (slabs_a, slabs_av, slabs_b, slabs_bv, emit0))
-
-        def step(k, carry):
-            visit, slabs, amatched = carry
-            rb, rkvc, remc, bdat_v, bval_v = visit
-            sa, sav, sb, sbv, emit = slabs
-            _, lo, m, bperm, _ = _join.join_plan_keys(
-                lbits, lkv, lemit, rb, rkvc, remc, _join.JoinType.INNER)
-            lidx, ridx, e = _join.join_materialize_gids(
-                lo, m, bperm, jnp.zeros(remc.shape[0], bool), lemit,
-                _join.JoinType.INNER, cap_step, 0)
-            ad, av = _gather_side(adat, aval, lidx)
-            bd, bv = _gather_side(bdat_v, bval_v, ridx)
-            off = k * cap_step
-
-            def put(slab, block):
-                return jax.lax.dynamic_update_slice_in_dim(slab, block,
-                                                           off, 0)
-
-            slabs = (tuple(put(s, d) for s, d in zip(sa, ad)),
-                     tuple(put(s, v) for s, v in zip(sav, av)),
-                     tuple(put(s, d) for s, d in zip(sb, bd)),
-                     tuple(put(s, v) for s, v in zip(sbv, bv)),
-                     put(emit, e))
-            amatched = amatched | (m > 0)
-            return rot((rb, rkvc, remc, bdat_v, bval_v)), slabs, amatched
-
-        visit0 = (rbits, rkv, remit, bdat, bval)
-        amatched0 = _varying(axis, jnp.zeros(lemit.shape[0], bool))
-        _, slabs, amatched = jax.lax.fori_loop(
-            0, world, step,
-            (visit0, (slabs_a, slabs_av, slabs_b, slabs_bv, emit0),
-             amatched0))
-        sa, sav, sb, sbv, emit = slabs
-
-        if emit_unmatched_a:
-            un = _join._masked_indices(lemit & ~amatched, cap_extra)
-            ad, av = _gather_side(adat, aval, un)
-            hole = jnp.full(cap_extra, -1, jnp.int32)
-            bd, bv = _gather_side(bdat, bval, hole)
-            off = world * cap_step
-
-            def put(slab, block):
-                return jax.lax.dynamic_update_slice_in_dim(slab, block,
-                                                           off, 0)
-
-            sa = tuple(put(s, d) for s, d in zip(sa, ad))
-            sav = tuple(put(s, v) for s, v in zip(sav, av))
-            sb = tuple(put(s, d) for s, d in zip(sb, bd))
-            sbv = tuple(put(s, v) for s, v in zip(sbv, bv))
-            emit = put(emit, un >= 0)
-        return sa, sav, sb, sbv, emit
-
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 10,
-                             out_specs=spec))
-
-
-def distributed_join_ring(left: Table, right: Table,
-                          config: _join.JoinConfig) -> Table:
-    """Streaming ring join (ArrowJoin analog). INNER/LEFT/RIGHT; the
-    resident (probe) side is the left table (right for RIGHT joins) and
-    the other side rotates. FULL_OUTER falls back to the shuffle path.
-
-    Memory note: the per-shard output slab is world*cap_step + cap_extra
-    rows where cap_step covers the worst (shard, step) block — heavy key
-    skew inflates it; the shuffle path degrades more gracefully there.
-    """
-    from ..data.strings import LANE_WORDS_MAX
-
-    ctx = left._ctx
-    world = ctx.get_world_size()
-    jt = config.type
-    if world == 1 or jt == _join.JoinType.FULL_OUTER or \
-            any(c.is_varbytes and c.varbytes.max_words > LANE_WORDS_MAX
-                for c in left._columns + right._columns):
-        # long varbytes payload can't ride the ring's fixed-width
-        # rotation (short rows ride as word lanes below)
-        return distributed_join(left, right, config)
-    if getattr(config, "exact", False):
-        from ..data.strings import EXACT_KEY_WORDS
-
-        for li, rj in zip(config.left_column_idx,
-                          config.right_column_idx):
-            kw = _pair_k(left._columns[li], right._columns[rj])
-            if kw is not None and kw > EXACT_KEY_WORDS:
-                # the ring can't byte-verify mid-rotation; the shuffle
-                # path post-verifies (round-5) — route there rather
-                # than reject (keys <= EXACT_KEY_WORDS*4 bytes are
-                # byte-exact on the ring by construction)
-                return distributed_join(left, right, config)
-
-    left_d = shard.distribute(left, ctx)
-    right_d = shard.distribute(right, ctx)
-    lidx, ridx = config.left_column_idx, config.right_column_idx
-    lcols, rcols = table_mod.align_key_columns(left_d, right_d, lidx, ridx)
-
-    if jt == _join.JoinType.RIGHT:
-        a_t, a_cols, b_t, b_cols = right_d, rcols, left_d, lcols
-    else:
-        a_t, a_cols, b_t, b_cols = left_d, lcols, right_d, rcols
-    emit_un_a = jt != _join.JoinType.INNER
-
-    # varbytes keys become per-shard word lanes (byte-exact) or the
-    # content-hash quad; either way the bit arrays rotate like any
-    # fixed lane. Short varbytes PAYLOADS ride as appended word lanes
-    # (the ArrowJoin analog now streams whole tables incl. strings,
-    # reference arrow_join.hpp:50-198).
-    abits, akv, aemit, adat, aval, a_lane_slots = _prep_join_side(
-        ctx, a_t, a_cols, b_cols)
-    bbits, bkv, bemit, bdat, bval, b_lane_slots = _prep_join_side(
-        ctx, b_t, b_cols, a_cols)
-
-    seq = ctx.get_next_sequence()
-    with _phase("ring_join.count", seq):
-        counts = np.asarray(_host_fetch("ring.count", _ring_count_fn(
-            ctx.mesh, emit_un_a, len(abits))(
-            abits, akv, aemit, bbits, bkv, bemit)))
-    pairs, extra = counts[:, :world], counts[:, world]
-    cap_step = _bucket_cap(int(pairs.max())) if pairs.size else 1
-    cap_extra = _bucket_cap(int(extra.max())) if emit_un_a else 0
-    # skew guard: the output slab is world*cap_step rows per shard, with
-    # cap_step set by the WORST (shard, step) block — a hot key inflates
-    # every shard's slab. When the slab overshoots the actual worst
-    # per-shard output by more than RING_SKEW_FACTOR (or blows the HBM
-    # budget), the shuffle join's blockwise machinery degrades more
-    # gracefully — route there.
-    worst_total = int(pairs.sum(axis=1).max()) if pairs.size else 0
-    slab = world * cap_step
-    budget = ctx.memory_pool.comm_budget_bytes()
-    row_bytes = sum(
-        int(np.dtype(c.data.dtype).itemsize) + 1
-        + (5 * c.varbytes.max_words if c.is_varbytes else 0)
-        for c in a_t._columns + b_t._columns)
-    over_budget = bool(budget) and slab * row_bytes > budget
-    # absolute floor: tiny slabs are free regardless of ratio — without
-    # it, sparse-output joins (cap_step ~ a few rows) would always
-    # misroute off the ring
-    skewed = slab > (1 << 16) and \
-        slab > RING_SKEW_FACTOR * _capacity(max(worst_total, 1))
-    if skewed or over_budget:
-        return distributed_join(left, right, config)
-
-    _counter("cylon_join_algorithm_total", {"algo": "ring"}).inc()
-    with _phase("ring_join.materialize", seq):
-        sa, sav, sb, sbv, emit = _ring_mat_fn(
-            ctx.mesh, emit_un_a, cap_step, cap_extra, len(abits))(
-            abits, akv, aemit, bbits, bkv, bemit, adat, aval, bdat, bval)
-
-    na = a_t.column_count
-    a_cols_out = _rebuild_join_side(ctx, sa, sav, a_t, a_lane_slots, "a")
-    b_cols_out = _rebuild_join_side(ctx, sb, sbv, b_t, b_lane_slots, "b")
-    if jt == _join.JoinType.RIGHT:
-        cols = b_cols_out + a_cols_out
-        nl = b_t.column_count
-    else:
-        cols = a_cols_out + b_cols_out
-        nl = na
-    cols = [c.rename(f"lt-{i}" if i < nl else f"rt-{i}")
-            for i, c in enumerate(cols)]
-    result = Table(cols, ctx, emit)
-    left._free_if_unretained()
-    right._free_if_unretained()
-    return _ledger.track(result, "distributed_join_ring")
 
 
 # ---------------------------------------------------------------------------
@@ -2070,14 +1833,14 @@ def distributed_set_op(left: Table, right: Table,
     rdat = tuple(shard.pin(c.data, ctx) for c in rcols_s)
     rval = tuple(shard.pin(c.valid_mask(), ctx) for c in rcols_s)
 
-    with _phase("distributed_set_op.count", seq):
+    with _span("distributed_set_op.count", seq):
         counts = np.asarray(_host_fetch(
             "setop.count", _setop_count_fn(ctx.mesh)(
                 lkb, lemit, rkb, remit))).reshape(world, 3)
     total = counts[:, int(op)]
     cap = _bucket_cap(int(total.max()))
 
-    with _phase("distributed_set_op.materialize", seq):
+    with _span("distributed_set_op.materialize", seq):
         od, ov, emit, idx = _setop_mat_fn(ctx.mesh, op, cap)(
             lkb, lemit, rkb, remit, ldat, lval, rdat, rval)
 
@@ -2195,7 +1958,7 @@ def _groupby_shuffle_agg(ctx: CylonContext, phase: str, key_columns,
             ctx, kcols_s, emit_s, vdat, ops, observe)
         _sp.set(key_lanes=len(kbits), rows=int(kbits[0].shape[0]))
 
-    with _phase("distributed_groupby.aggregate", seq):
+    with _span("distributed_groupby.aggregate", seq):
         if col_ids is None:
             col_ids = tuple(range(len(vcols_s)))
         all_valid = tuple(c.validity is None for c in vcols_s)
@@ -2297,7 +2060,7 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
             a_entries.append((j, op, False))
             b_ops.append(_groupby.second_phase_op(op))
 
-    with _phase("distributed_groupby.pre_aggregate", seq):
+    with _span("distributed_groupby.pre_aggregate", seq):
         vdatA, vvalA = [], []
         for j, _opA, cast in a_entries:
             src = t._columns[val_cols[j]]
@@ -2361,10 +2124,6 @@ def distributed_groupby(table: Table, index_col, aggregate_cols: List,
 
 # per-shard sample count for splitter estimation (total = world * this)
 SORT_SAMPLES_PER_SHARD = 4096
-
-# ring join routes to the shuffle join when its output slab overshoots
-# the worst per-shard output by this factor (hot-key skew)
-RING_SKEW_FACTOR = 4
 
 
 @counted_cache
@@ -2514,7 +2273,7 @@ def distributed_sort(table: Table, order_by, ascending=True) -> Table:
         cols_s, emit_s, _x = _exchange_table(
             t, shard.pin(targets, ctx), emit, ctx)
 
-    with _phase("distributed_sort.local", seq):
+    with _span("distributed_sort.local", seq):
         # key lanes recompute per shard from the shuffled columns —
         # recomputable lanes never cross the exchange (same pattern as
         # the join/set-op/groupby shuffles)

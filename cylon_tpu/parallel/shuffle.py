@@ -49,7 +49,7 @@ from ..ops import tpu_kernels as _tpuk
 from ..resilience import inject as _inject
 from ..resilience import retry as _retry
 from ..telemetry import counted_cache, counter as _counter, \
-    host_fetch as _host_fetch, phase as _phase, span as _span
+    host_fetch as _host_fetch, span as _span
 from ..telemetry import knobs as _knobs
 from ..telemetry import skew as _skew
 from ..util import pow2 as _pow2, pow2_floor as _pow2_floor
@@ -235,16 +235,14 @@ def _send_block(xs, start, o, block, world):
 # ---------------------------------------------------------------------------
 # the fused partition kernel (ROADMAP item 2 close-out, SURVEY §7): the
 # padded-mode partition — a stable bucket sort by target — is the one
-# spot the survey reserves Pallas for. CYLON_PARTITION_KERNEL routes it:
-# "auto" picks the two-pass histogram+scatter kernel on TPU (up to
-# _PARTITION_MAX_WORLD targets — past that the scatter's per-bucket
-# passes cost more than the sort), "sort" forces the XLA stable sort
-# everywhere (the exact pre-kernel program — the path string is part of
-# every factory cache key), "pallas" forces the kernel (interpreter
-# off-TPU; tests pin bit-identity through it). Both paths return the
-# identical (sorted_leaves, counts_out, start) triple, so everything
-# downstream — chunk pipeline, skew attrs, ledger, admission — is
-# partition-path-oblivious.
+# spot the survey reserves Pallas for. `_partition_path` routes it by
+# what it observes, no knob: the two-pass histogram+scatter kernel on a
+# TPU (up to _PARTITION_MAX_WORLD targets — past that the scatter's
+# per-bucket passes cost more than the sort), the XLA stable sort
+# everywhere else (the path string is part of every factory cache key).
+# Both paths return the identical (sorted_leaves, counts_out, start)
+# triple, so everything downstream — chunk pipeline, skew attrs,
+# ledger, admission — is partition-path-oblivious.
 # ---------------------------------------------------------------------------
 
 # beyond this world size the scatter pass's per-bucket input streaming
@@ -260,21 +258,18 @@ def _partition_eligible(payload) -> bool:
 
 
 def _partition_path(mesh, world: int, payload) -> str:
-    """Resolve the partition path for one exchange dispatch — "sort",
-    "pallas" (compiled kernel) or "interp" (interpreter, tests). The
-    result keys the exchange factory caches, so flipping the knob can
-    never reuse a program built for the other path."""
-    mode = _knobs.get("CYLON_PARTITION_KERNEL")
-    if mode not in ("auto", "pallas", "sort"):
-        mode = "auto"
+    """Resolve the partition path for one exchange dispatch, from
+    platform, world and payload alone: "sort" or "pallas" (the compiled
+    kernel). The result keys the exchange factory caches, so a program
+    built for one path is never reused for the other. A third spelling,
+    "interp" (the kernel under the interpreter), is only ever passed by
+    a test that patches this function."""
     # world+1 buckets (dead rows included) must fit one histogram lane
-    # row — past that even a forced knob falls back to the sort
-    if mode == "sort" or world < 2 or world + 1 > _tpuk.LANES \
+    # row
+    if world < 2 or world + 1 > _tpuk.LANES \
             or not _partition_eligible(payload):
         return "sort"
     on_tpu = mesh.devices.flat[0].platform == "tpu"
-    if mode == "pallas":
-        return "pallas" if on_tpu else "interp"
     return "pallas" if on_tpu and world <= _PARTITION_MAX_WORLD \
         else "sort"
 
@@ -418,7 +413,7 @@ def _exchange_padded_fn(mesh, block: int, part: str = "sort"):
     marks each source's live prefix), capacity world*block; the host
     routes here when that padding is acceptable (see exchange()).
     ``part`` (the partition path — see _partition_path) is part of the
-    cache key: a knob flip can never reuse the other path's program."""
+    cache key: one path's program is never reused for the other."""
     axis = mesh.axis_names[0]
     world = mesh.devices.size
     spec = P(axis)
@@ -452,8 +447,6 @@ def _chunk_plan(block: int, world: int, bytes_per_row: int):
     keys compiled chunk programs — 1 per octave, specialization-clean)
     and floored again so the pipeline never exceeds MAX_CHUNKS
     programs."""
-    if not _knobs.get("CYLON_EXCHANGE_OVERLAP"):
-        return block, 1
     target = int(_knobs.get("CYLON_EXCHANGE_CHUNK_BYTES"))
     per_slot = max(int(bytes_per_row), 1) * max(world, 1)
     cb = _pow2_floor(max(target // per_slot, 1))
@@ -994,9 +987,9 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
     sharded per-source receive-count matrix (each shard's own [W] slice),
     consumed by the varbytes word/row layout reconciliation. Padded-mode
     exchanges whose payload exceeds CYLON_EXCHANGE_CHUNK_BYTES run as
-    the chunked, double-buffered pipeline (meta gains ``chunks``;
-    ``CYLON_EXCHANGE_OVERLAP=0`` restores the single-shot program, and
-    the two paths are bit-identical on every live row).
+    the chunked, double-buffered pipeline (meta gains ``chunks``; the
+    single-shot program is its one-chunk case, and the two are
+    bit-identical on every live row).
     ``max_block`` caps the per-round blockwise block size.
     """
     world = ctx.get_world_size()

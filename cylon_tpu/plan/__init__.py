@@ -12,16 +12,16 @@ partitioning metadata and (1) deletes `Shuffle` nodes whose input is
 already hash-placed on the same keys, (2) prunes unreferenced columns
 below the exchanges, and (3) pushes filters below shuffles so dead rows
 drop in transit; the executor lowers the optimized plan onto the
-existing `dist_ops`/`table_api` primitives (never `ops/` kernels — see
-scripts/check_plan_imports.py) and stamps per-node `telemetry.span`
-spans, so a plan's shuffle count is directly observable in logs and
+existing `dist_ops`/`table_api` primitives (never `ops/` kernels — the
+analysis suite's `layering/plan-no-ops` rule) and stamps per-node
+`telemetry.span` spans, so a plan's shuffle count is directly observable in logs and
 Perfetto traces as ``plan.shuffle.*`` labels. `LazyTable.explain(
 analyze=True)` executes the query under a recorder and renders the
 plan annotated with measured rows/bytes/ms per node (EXPLAIN ANALYZE
 — see `plan.report.PlanReport` and docs/telemetry.md).
 
-The retired `parallel/task_plan.py` task-routing overlay lives on as
-`plan.tasks` (same `LogicalTaskPlan`/`task_exchange` API).
+The task-routing overlay is `plan.tasks` (`LogicalTaskPlan`,
+`task_exchange`).
 """
 from . import ir, optimizer, executor, report, tasks
 from .ir import (Compute, Filter, GroupBy, Join, PlanNode, Project, Scan,

@@ -379,10 +379,10 @@ def elide_shuffles(root: ir.PlanNode, world: int,
 #   price of the placement witness, which _propagate then withholds).
 #
 # First execution of a shape finds no qualified statistics and stays
-# shuffle (exploratory); CYLON_JOIN_ALGORITHM=shuffle disables every
-# adaptive rewrite (the exact pre-adaptive program — broadcast kernel
-# factories are never built), =broadcast forces the rewrite on every
-# eligible shape. Soundness is not stats-dependent: replication is
+# shuffle (exploratory); a join the caller wrote as
+# algorithm="broadcast" is rewritten whatever the statistics say (that
+# is the plan, not a process-wide switch: there is none). Soundness is
+# not stats-dependent: replication is
 # always correct, the witness verifier (plan/verify.py) checks every
 # broadcast CLAIM structurally, and a mis-learned choice self-corrects
 # — the first broadcast run measures the true input sizes under the
@@ -412,11 +412,6 @@ def _stats_store():
     return _stats
 
 
-def join_algorithm_mode() -> str:
-    mode = _knobs.get("CYLON_JOIN_ALGORITHM")
-    return mode if mode in ("auto", "shuffle", "broadcast") else "auto"
-
-
 def broadcast_choice(node: ir.PlanNode, world: int) -> Optional[int]:
     """The build side (0|1) a broadcast rewrite would pick for one
     Join, or None — a pure function of (join shape, knobs, warehouse),
@@ -425,9 +420,6 @@ def broadcast_choice(node: ir.PlanNode, world: int) -> Optional[int]:
     side) re-decides from the live statistics, so a post-drift check
     sees the choice revert."""
     if world <= 1 or not isinstance(node, ir.Join):
-        return None
-    mode = join_algorithm_mode()
-    if mode == "shuffle":
         return None
     sides = _BROADCAST_SIDES.get(node.how)
     if not sides:
@@ -445,9 +437,9 @@ def broadcast_choice(node: ir.PlanNode, world: int) -> Optional[int]:
 
         fp = join_decision_fingerprint(node, world)
         lb, rb = st.join_input_bytes(fp)
-    if mode == "broadcast" or user_forced:
-        # forced: measured sizes only break the tie between two
-        # eligible sides; no statistics are required
+    if user_forced:
+        # the plan says broadcast: measured sizes only break the tie
+        # between two eligible sides; no statistics are required
         if len(sides) == 2 and lb is not None and rb is not None:
             return 0 if lb <= rb else 1
         return sides[0]
@@ -476,8 +468,6 @@ def salt_choice(node: ir.PlanNode, world: int) -> bool:
         return False
     if int(_knobs.get("CYLON_SALT_FACTOR")) < 2:
         return False
-    if join_algorithm_mode() == "shuffle":
-        return False  # the "exact pre-adaptive program" escape hatch
     from .fingerprint import shuffle_decision_fingerprint
 
     skew = _stats_store().node_skew(
@@ -493,8 +483,7 @@ def adaptive_knobs() -> tuple:
     CYLON_STATS_MIN_OBS gate broadcast_choice through the warehouse
     reads, so they belong here just as much as the headline knobs)."""
     st = _stats_store()
-    return (join_algorithm_mode(),
-            int(_knobs.get("CYLON_BROADCAST_MAX_BYTES")),
+    return (int(_knobs.get("CYLON_BROADCAST_MAX_BYTES")),
             int(_knobs.get("CYLON_SALT_FACTOR")),
             float(_knobs.get("CYLON_SKEW_WARN_FACTOR")),
             float(st.safety()), int(st.min_obs()))
@@ -554,8 +543,7 @@ def adapt_from_stats(root: ir.PlanNode, world: int,
             rewrite(c, node)
         if isinstance(node, ir.Join) and world > 1:
             side = broadcast_choice(node, world)
-            forced = join_algorithm_mode() == "broadcast" or \
-                node.algorithm == "broadcast"
+            forced = node.algorithm == "broadcast"
             # auto rewrites only fire when the join still PAYS an
             # exchange on EITHER side: broadcast elides both, so a
             # free build side with a paying probe is exactly the case

@@ -1,6 +1,6 @@
-"""Task-routed all-to-all — ArrowTaskAllToAll parity (absorbed from
-parallel/task_plan.py into the plan subsystem; the reference shipped
-this overlay next to its LogicalTaskPlan, arrow_task_all_to_all.h:9-57).
+"""Task-routed all-to-all — ArrowTaskAllToAll parity (the reference
+shipped this overlay next to its LogicalTaskPlan,
+arrow_task_all_to_all.h:9-57).
 
 Reference: cpp/src/cylon/arrow/arrow_task_all_to_all.h:9-57 (.cpp) — a
 task-graph overlay the reference never finished: `LogicalTaskPlan` holds

@@ -191,23 +191,11 @@ declare("CYLON_FLIGHT_MAX_DUMPS", 32, "int",
         "rotation", lo=1)
 
 # parallel/shuffle.py (the chunked, double-buffered exchange)
-declare("CYLON_EXCHANGE_OVERLAP", True, "bool",
-        "chunk the padded-mode exchange and pipeline chunk N+1's "
-        "all_to_all against chunk N's compaction (async dispatch + "
-        "donated double buffers); 0 falls back to the single-shot "
-        "monolithic exchange program")
 declare("CYLON_EXCHANGE_CHUNK_BYTES", 1 << 26, "int",
         "target payload bytes per exchange chunk and per shard "
         "(across all destinations); the chunk block is pow2-floored "
         "from it and the chunk count is capped at MAX_CHUNKS per "
         "exchange", lo=1 << 12)
-declare("CYLON_PARTITION_KERNEL", "auto", "str",
-        "partition path of the padded exchange: auto routes to the "
-        "fused Pallas histogram+scatter kernel on TPU (small worlds) "
-        "and the XLA stable sort elsewhere; sort forces the sort "
-        "everywhere (the exact pre-kernel program); pallas forces the "
-        "kernel (Pallas interpreter off-TPU — tests). Bit-identical "
-        "on every live row either way")
 
 # plan/
 declare("CYLON_TPU_VERIFY_PLANS", False, "bool",
@@ -279,12 +267,6 @@ declare("CYLON_STATS_PATH", None, "str",
         "quarantined (renamed aside), never fatal")
 
 # plan/optimizer.py (adaptive join execution — stats-driven rewrites)
-declare("CYLON_JOIN_ALGORITHM", "auto", "str",
-        "distributed-join algorithm policy: auto lets the optimizer "
-        "rewrite shuffle joins to broadcast-hash joins from measured "
-        "build-side statistics; shuffle disables every adaptive "
-        "rewrite (the exact pre-adaptive program); broadcast forces "
-        "the broadcast path on every eligible join shape")
 declare("CYLON_BROADCAST_MAX_BYTES", 1 << 22, "int",
         "broadcast-hash-join budget: a join side whose MEASURED size "
         "(EWMA x CYLON_STATS_SAFETY) fits under this many bytes may "

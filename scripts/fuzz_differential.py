@@ -6,9 +6,10 @@ pandas. Seeded per case; a failure prints the reproducing seed.
 Each case additionally generates a random **LazyTable plan** (scan →
 optional filter → join → optional groupby / standalone shuffle) and
 differentially tests the OPTIMIZED execution against the unoptimized
-plan and pandas, with the adaptive-join knobs toggled per case
-(CYLON_JOIN_ALGORITHM ∈ auto/shuffle/broadcast, CYLON_SALT_FACTOR ∈
-0/4) and the warehouse pre-learned for the auto cases — randomized
+plan and pandas, with the join's algorithm varied per case (auto /
+no adaptive rewrite / a join written as algorithm="broadcast";
+CYLON_SALT_FACTOR ∈ 0/4) and the warehouse pre-learned for the auto
+cases — randomized
 evidence per optimizer rule, broadcast/salt rewrites included
 (ROADMAP item 5).
 
@@ -31,6 +32,13 @@ import pandas as pd  # noqa: E402
 
 import cylon_tpu as ct  # noqa: E402
 from cylon_tpu.data import strings as _strings  # noqa: E402
+
+# No knob chooses a distributed path (PR 45): the fuzzer reaches the
+# paths the code would not choose on the CPU as the tests do, by
+# standing in for the functions that choose (tests/forced_paths.py).
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tests"))
+import forced_paths  # noqa: E402
+from _pytest.monkeypatch import MonkeyPatch  # noqa: E402
 
 
 def rand_keys(rng, n, kind):
@@ -79,17 +87,19 @@ def one_case(seed):
     # chunk target every padded exchange runs the chunked pipeline,
     # which must stay bit-identical to the single-shot program on all
     # of the distributed-vs-local comparisons below
+    patch = MonkeyPatch()
     overlap = bool(rng.integers(0, 2))
-    os.environ["CYLON_EXCHANGE_OVERLAP"] = "1" if overlap else "0"
     if overlap:
-        os.environ["CYLON_EXCHANGE_CHUNK_BYTES"] = "4096"
+        patch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+    else:
+        forced_paths.single_shot(patch)
     # …and, orthogonally, the partition path: "pallas" runs the fused
     # hash+bucket+scatter kernel under the Pallas interpreter on CPU,
     # "sort" the XLA stable sort — differential evidence across the
-    # full knob matrix (overlap × partition), every combination must
+    # full matrix (overlap × partition), every combination must
     # agree with local AND pandas
     partition = "pallas" if bool(rng.integers(0, 2)) else "sort"
-    os.environ["CYLON_PARTITION_KERNEL"] = partition
+    forced_paths.partition(patch, partition)
 
     old = _strings.DICT_MAX_VOCAB
     if force_vb:
@@ -155,15 +165,15 @@ def one_case(seed):
         assert kd == kl, f"sort seed={seed}"
     finally:
         _strings.DICT_MAX_VOCAB = old
-        os.environ.pop("CYLON_EXCHANGE_OVERLAP", None)
-        os.environ.pop("CYLON_EXCHANGE_CHUNK_BYTES", None)
-        os.environ.pop("CYLON_PARTITION_KERNEL", None)
+        patch.undo()
     return kind, jt, force_vb, overlap, partition
 
 
 def lazy_plan_case(seed):
     """One random LazyTable plan, differentially tested optimized vs
-    unoptimized vs pandas under randomized adaptive-join knobs."""
+    unoptimized vs pandas under a randomized join algorithm: what the
+    statistics decide ("auto"), no adaptive rewrite at all ("shuffle")
+    or a join the plan itself writes as algorithm="broadcast"."""
     import pandas as pd
 
     from cylon_tpu import plan as ct_plan
@@ -179,9 +189,11 @@ def lazy_plan_case(seed):
     zipf = bool(rng.integers(0, 2))
     with_gb = bool(rng.integers(0, 2)) and kind != "short_str"
     with_shuffle = bool(rng.integers(0, 2))
-    os.environ["CYLON_JOIN_ALGORITHM"] = mode
-    os.environ["CYLON_SALT_FACTOR"] = str(salt)
-    os.environ["CYLON_STATS_MIN_OBS"] = "2"
+    patch = MonkeyPatch()
+    if mode == "shuffle":
+        forced_paths.shuffle_joins_only(patch)
+    patch.setenv("CYLON_SALT_FACTOR", str(salt))
+    patch.setenv("CYLON_STATS_MIN_OBS", "2")
     stats_mod.reset()
     try:
         ld = rand_table(rng, n1, kind, "v")
@@ -198,7 +210,9 @@ def lazy_plan_case(seed):
             lt = ct_plan.scan(lt_d)
             if with_shuffle:
                 lt = lt.shuffle(["k"])
-            p = lt.join(ct_plan.scan(rt_d), jt, on="k")
+            p = lt.join(ct_plan.scan(rt_d), jt, on="k",
+                        algorithm="broadcast" if mode == "broadcast"
+                        else "auto")
             if with_gb:
                 # aggregate_cols pairs 1:1 with ops (the eager groupby
                 # call shape above)
@@ -224,9 +238,7 @@ def lazy_plan_case(seed):
                 f"lazy plan rowcount vs pandas seed={seed}: " \
                 f"{len(ref)} != {len(jp)}"
     finally:
-        os.environ.pop("CYLON_JOIN_ALGORITHM", None)
-        os.environ.pop("CYLON_SALT_FACTOR", None)
-        os.environ.pop("CYLON_STATS_MIN_OBS", None)
+        patch.undo()
         stats_mod.reset()
     return jt, mode, salt, with_gb, with_shuffle
 

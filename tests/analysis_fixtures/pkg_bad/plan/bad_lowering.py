@@ -1,5 +1,5 @@
 """Fixture plan/ module lowering straight onto kernels (the original
-check_plan_imports.py violation, both import forms)."""
+plan-import violation, both import forms)."""
 from ..ops import bad_kernel  # SEEDED: layering/plan-no-ops
 import pkg_bad.ops.bad_kernel as bk  # SEEDED: layering/plan-no-ops
 

@@ -4,18 +4,20 @@ shuffle join across join types / dtypes (incl. varbytes keys) / world
 sizes / empty build side / exact byte threshold; salted exchange
 bit-identity (post-unsalt) with measured max-shard reduction under
 Zipfian keys; verifier rejection of hand-mutated broadcast claims; the
-CYLON_JOIN_ALGORITHM=shuffle escape hatch restoring the exact
-pre-adaptive program (factory-reuse pinned); the stats-driven learn →
+pre-adaptive program (no rewrite: `broadcast_choice` patched to
+None; factory-reuse pinned); the stats-driven learn →
 broadcast → drift → revert closed loop; and the observability surface
 (counters, span attrs, EXPLAIN `algo=`, digest v3)."""
 import numpy as np
 import pytest
 
 import cylon_tpu as ct
+import forced_paths
 from cylon_tpu import plan, telemetry
 from cylon_tpu.data import strings as _strings
 from cylon_tpu.parallel import dist_ops
 from cylon_tpu.plan import ir
+from cylon_tpu.plan import optimizer as optimizer_mod
 from cylon_tpu.plan.fingerprint import join_decision_fingerprint
 from cylon_tpu.plan.optimizer import (BROADCAST_MIN_RATIO,
                                       broadcast_choice, optimize)
@@ -279,7 +281,8 @@ def test_learned_loop_end_to_end_bit_identity(dist_ctx, monkeypatch):
 
 def test_join_algorithm_shuffle_restores_pre_adaptive_program(
         dist_ctx, monkeypatch):
-    """CYLON_JOIN_ALGORITHM=shuffle is the exact pre-adaptive program:
+    """With no adaptive choice (`forced_paths.shuffle_joins_only`: no
+    knob does it since PR 45) the plan is the exact pre-adaptive program:
     learned statistics are ignored, the plan renders identically to a
     fresh-stats optimize, and NO broadcast kernel factory is ever
     built (the broadcast path lives in factories of its own, keyed
@@ -294,7 +297,7 @@ def test_join_algorithm_shuffle_restores_pre_adaptive_program(
     _feed_join_inputs(pipe()._node, 4, left_bytes=1 << 20,
                       right_bytes=1 << 8)
     assert "algo=broadcast" in pipe().explain()
-    monkeypatch.setenv("CYLON_JOIN_ALGORITHM", "shuffle")
+    forced_paths.shuffle_joins_only(monkeypatch)
     assert pipe().explain() == fresh_txt
     builds0 = {k: v for k, v in telemetry.metrics_snapshot().items()
                if "_bcast_join" in k}
@@ -302,13 +305,15 @@ def test_join_algorithm_shuffle_restores_pre_adaptive_program(
     builds1 = {k: v for k, v in telemetry.metrics_snapshot().items()
                if "_bcast_join" in k}
     assert builds0 == builds1
-    monkeypatch.delenv("CYLON_JOIN_ALGORITHM")
+    forced_paths.shuffle_joins_only(monkeypatch, False)
     rb = pipe().execute()
     assert _canon(r) == _canon(rb)
 
 
-def test_forced_broadcast_knob(dist_ctx, monkeypatch):
-    monkeypatch.setenv("CYLON_JOIN_ALGORITHM", "broadcast")
+def test_forced_broadcast_choice(dist_ctx, monkeypatch):
+    monkeypatch.setattr(
+        optimizer_mod, "broadcast_choice",
+        lambda node, world: 1 if isinstance(node, ir.Join) else None)
     left, right = _tables(dist_ctx, 512, 64, seed=22)
     lt = plan.scan(left).join(plan.scan(right), on="k")
     root, stats = optimize(lt._plan_copy(), 4)

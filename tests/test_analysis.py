@@ -60,13 +60,16 @@ def test_layering_real_tree_clean():
     assert res.findings == [], res.format_text()
 
 
-def test_plan_imports_shim_delegates():
-    r = subprocess.run(
-        [sys.executable, os.path.join(PKG_REAL, "..", "scripts",
-                                      "check_plan_imports.py")],
-        capture_output=True, text=True, timeout=120)
-    assert r.returncode == 0, r.stderr
-    assert "plan-import lint: OK" in r.stdout
+def test_parallel_no_plan_has_no_exemption():
+    """The `parallel/task_plan.py` shim went (PR 45): nothing under
+    `parallel/` imports `plan/`, and the contract exempts no file."""
+    from cylon_tpu.analysis import layering
+
+    (c,) = [c for c in layering.DEFAULT_CONTRACTS
+            if c.name == "parallel-no-plan"]
+    assert not c.exempt
+    assert not os.path.exists(os.path.join(PKG_REAL, "parallel",
+                                           "task_plan.py"))
 
 
 # ---------------------------------------------------------------------------
@@ -442,6 +445,23 @@ def test_envknobs_real_registry_matches_docs_table():
     # and the registry itself parses + floors like env_number did
     assert knobs.get("CYLON_RETRY_MAX") == 3
     assert knobs.default("CYLON_SERVICE_QUEUE_MAX") == 256
+
+
+@pytest.mark.parametrize("name", [
+    "CYLON_PARTITION_KERNEL", "CYLON_EXCHANGE_OVERLAP",
+    "CYLON_JOIN_ALGORITHM"])
+def test_no_knob_chooses_a_distributed_path(name, monkeypatch):
+    """The three switches by which a user chose a code path under
+    `parallel/` and `plan/` went (PR 45): none is declared, reading one
+    is the registry's KeyError, and setting it in the environment
+    changes nothing the registry can see."""
+    from cylon_tpu.telemetry import knobs
+
+    monkeypatch.setenv(name, "sort")
+    assert name not in knobs.KNOBS
+    with pytest.raises(KeyError, match="not a declared knob"):
+        knobs.get(name)
+    assert len(knobs.KNOBS) == 27
 
 
 def test_envknobs_undocumented_knob(tmp_path):

@@ -10,84 +10,23 @@ up to the whole table's answer (what ties the configuration's share to its
 source), the bfloat16 control fails by the float bound alone, the leaf
 spans and the two counters this PR brought, and the streaming reduce kernel
 under `shard_map` (the step the chip takes, never lowered there before)
-against the `segment_*` path. And (PR 43) what the first per-shard sort is
-handed: no dead flag without a row mask, and integer columns inside the
-key's word by a probe of the whole sharded table, as on one chip.
+against the `segment_*` path. What the first per-shard sort is handed
+(PR 43) is `tests/test_cell_groupby_q5_w4_pack.py`, a file of its own so
+that `--dist loadfile` gives each half a worker (PR 45); what both use
+is `tests/cell_groupby_q5_w4_cases.py`.
 """
-import copy
-import importlib.util
-import json
-import os
-import sys
-
 import numpy as np
 import pytest
-
-import jax.numpy as jnp
 
 import cylon_tpu as ct
 from cylon_tpu import plan, telemetry
 from cylon_tpu.ops import groupby as G
-from cylon_tpu.parallel import dist_ops, shard
+from cylon_tpu.parallel import dist_ops
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))), "benchmarks")
-
-
-def _code(kind, name):
-    spec = importlib.util.spec_from_file_location(
-        f"q5w4_{kind}_{name}", os.path.join(BENCH, kind, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    path = list(sys.path)
-    try:
-        spec.loader.exec_module(mod)
-    finally:
-        sys.path[:] = path   # run.py puts its own directory first
-    return mod
-
-
-def _json(kind, name):
-    with open(os.path.join(BENCH, kind, name + ".json")) as f:
-        return json.load(f)
-
-
-GENERATOR = _code("generators", "h2o_g1")
-REFERENCE = _code("references", "groupby_sum_f64")
-QUERY = _code("queries", "groupby_agg")
-RUN = _code("", "run")          # host_result, as the harness reads a result
-TRAFFIC = _json("traffic", "q5-4chip")
-CONFIG = _json("configs", "h2o-groupby-1e9-f32")
-I32_MIN, I32_MAX = np.iinfo(np.int32).min, np.iinfo(np.int32).max
-EXACT = ("schema_diff", "nulls", "groups_diff", "int_sum_mismatches.v1",
-         "int_sum_mismatches.v2")
-FLOAT = "f32_sum_err_over_bound.v3"
-
-
-def _data(rows, key_range, seed):
-    """The cell's table at a test size: the configuration as committed,
-    fewer rows, id6 over a literal range wider than a shard's rows."""
-    config = copy.deepcopy(CONFIG)
-    config["N"] = rows
-    config["columns"]["id6"]["high"] = key_range
-    return config, GENERATOR.generate(config, TRAFFIC, 4, 1.0,
-                                      seed)["tables"]
-
-
-def _numbers(out, tables, config):
-    ref = REFERENCE.reference(tables, config, TRAFFIC)
-    assert int(out.row_count) == REFERENCE.rows_out(ref)
-    return {n["name"]: n["value"] for n in REFERENCE.compare(
-        RUN.host_result(out), ref)}
-
-
-def _planned(ctx, cols, mask=None):
-    t = shard.distribute(ct.Table.from_pydict(ctx, cols), ctx)
-    if mask is not None:
-        live = np.zeros(t.capacity, bool)
-        live[:len(mask)] = mask
-        t = ct.Table(list(t.columns()), ctx, shard.pin(jnp.asarray(live),
-                                                       ctx))
-    return QUERY.build(plan, {TRAFFIC["table"]: t}, TRAFFIC)
+from cell_groupby_q5_w4_cases import (
+    EXACT, FLOAT, REFERENCE, ROWS4, RUN, TRAFFIC, _as_on_a_tpu, _by_key,
+    _data, _numbers, _planned, _q5_cols, _readback, _spread,
+    _with_columns)
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["dense", "masked"])
@@ -287,43 +226,6 @@ def test_stream_reduce_under_shard_map_equals_the_segment_path(
     assert numbers[FLOAT] <= 1.0, numbers
 
 
-READBACK = ('cylon_groupby_key_readback_total{path="lanes"}',
-            'cylon_groupby_key_readback_total{path="gather"}',
-            "cylon_groupby_sort_operands_total")
-
-
-def _readback():
-    snap = telemetry.metrics_snapshot()
-    return [snap.get(k, 0) for k in READBACK]
-
-
-def _as_on_a_tpu(monkeypatch):
-    """The host's decision (`G.sort_carries_index`, which says "index" on
-    a CPU backend) and `_groupby_fn`'s reduce step as a TPU backend takes
-    them, under the interpreter."""
-    real_agg, real_index = G.sorted_segment_aggregate, G.sort_carries_index
-    monkeypatch.setattr(
-        G, "sorted_segment_aggregate",
-        lambda *a, **k: real_agg(*a, **k, interpret=True))
-    monkeypatch.setattr(
-        G, "sort_carries_index",
-        lambda *a, **k: real_index(*a, **k, interpret=True))
-
-
-def _with_columns(ctx, t, validity=None, mask=None):
-    """``t`` spread over the chips, column i under ``validity[i]`` (host
-    bool arrays over the capacity), rows under ``mask``."""
-    t = shard.distribute(t, ctx)
-
-    def pinned(a):
-        return shard.pin(jnp.asarray(a), ctx)
-
-    cols = [c if i not in (validity or {}) else ct.Column(
-        c.data, c.dtype, pinned(validity[i]), c.dictionary, c.name)
-        for i, c in enumerate(t.columns())]
-    return ct.Table(cols, ctx, None if mask is None else pinned(mask))
-
-
 def _case_no_nulls(ctx, cols, rng):
     return _with_columns(ctx, ct.Table.from_pydict(ctx, cols)), [0], {}
 
@@ -433,285 +335,6 @@ def test_keys_off_the_sorted_lanes_equal_the_gathered_keys(
     oracle = live.groupby(list(live.columns[:len(keys)]), dropna=False)
     assert len(got) == oracle.ngroups
     assert int(got.iloc[:, len(keys)].sum()) == int(live.iloc[:, values[0]].sum())
-
-
-# --------------------------------------------------------------------------
-# across chips (PR 43): the distributed groupby's per-shard sort is handed
-# what the one-chip sort is handed, by the same functions. The host probes
-# the WHOLE sharded table before it dispatches the `shard_map` program
-# (`dist_ops._sort_pack_probe` -> `table._sort_pack_probe`), the plan is a
-# static argument of `_groupby_fn` and `params` a replicated operand
-# --------------------------------------------------------------------------
-
-ROWS4 = 2048        # four shards of 512: a multiple of the row quantum
-DIST_SITES = ("groupby.packranges", "groupby.valuerange", "shuffle.count",
-              "groupby.keyrange", "groupby.groups")
-
-
-def _dist_counted():
-    snap = telemetry.metrics_snapshot()
-    out = {"operands": snap.get("cylon_groupby_sort_operands_total", 0),
-           "packed": snap.get("cylon_groupby_sort_packed_columns_total", 0)}
-    for phase in ("partial", "merge", "single"):
-        out[phase] = snap.get(
-            'cylon_groupby_phase_total{phase="%s"}' % phase, 0)
-    for site in DIST_SITES:
-        out[site] = snap.get('cylon_host_syncs_total{site="%s"}' % site, 0)
-    return out
-
-
-def _dist_delta(before):
-    after = _dist_counted()
-    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
-
-
-def _ints_of(rng, lo, hi, n=None):
-    """int32 values over exactly [lo, hi]."""
-    x = rng.integers(lo, hi + 1, n or ROWS4).astype(np.int32)
-    x[:2] = (lo, hi)
-    return x
-
-
-def _q5_cols(rng, n=ROWS4, key=None, v1=None):
-    """The cell's columns at a test size: id6 over a range wider than a
-    shard's rows, v1 in [1, 5], v2 in [1, 15], v3 whole floats (their
-    sums do not feel the order of a group's rows)."""
-    return {"id6": _ints_of(rng, 1, 3000, n) if key is None else key,
-            "v1": _ints_of(rng, 1, 5, n) if v1 is None else v1,
-            "v2": _ints_of(rng, 1, 15, n),
-            "v3": rng.integers(-64, 64, n).astype(np.float32)}
-
-
-def _spread(ctx, cols, validity=None, mask=None):
-    return _with_columns(ctx, ct.Table.from_pydict(ctx, cols), validity,
-                         mask)
-
-
-def _d_no_row_mask(ctx, rng):
-    # (a) the cell's shape: no mask, so no dead flag; key + v1 + v2 in one
-    # word and v3: 2 operands, then the merge's 5 (dead flag, key lane,
-    # three partial sums and none of their masks: 8 before PR 44)
-    return _spread(ctx, _q5_cols(rng)), {}, dict(
-        operands=2 + 5, packed=2, site="groupby.packranges")
-
-
-def _d_filtered(ctx, rng):
-    # (b) a row mask (a third of the rows dead, as after a filter; dead
-    # rows hold keys far outside the live rows' range): the flag rides
-    cols = _q5_cols(rng)
-    mask = rng.random(ROWS4) > 1 / 3
-    mask[:2] = True
-    cols["id6"] = np.where(mask, cols["id6"], rng.choice(
-        [I32_MIN, I32_MAX, -1, 70_000], ROWS4)).astype(np.int32)
-    return _spread(ctx, cols, mask=mask), {}, dict(
-        operands=3 + 5, packed=2, site="groupby.packranges")
-
-
-def _d_padded(ctx, rng):
-    # (b) rows that `distribute` has to pad: the padding's mask and flag
-    t = shard.distribute(
-        ct.Table.from_pydict(ctx, _q5_cols(rng, ROWS4 - 3)), ctx)
-    assert t.row_mask is not None
-    return t, {}, dict(operands=3 + 5, packed=2, site="groupby.packranges")
-
-
-def _d_nullable_key(ctx, rng):
-    # (c) a nullable key rides as two lanes and is not observed: the
-    # value columns share a word among themselves, probed alone
-    t = _spread(ctx, _q5_cols(rng), validity={0: rng.random(ROWS4) > 0.05})
-    return t, {}, dict(operands=4 + 6, packed=1, site="groupby.valuerange")
-
-
-def _d_wide_range(ctx, rng):
-    # (d) v1 over all of int32 does not fit and rides alone, whole; v2
-    # still rides with the key
-    v1 = rng.integers(I32_MIN, I32_MAX, ROWS4).astype(np.int32)
-    v1[:2] = (I32_MIN, I32_MAX)
-    return _spread(ctx, _q5_cols(rng, v1=v1)), {}, dict(
-        operands=3 + 5, packed=1, site="groupby.packranges", exact_v1=False)
-
-
-def _d_negative(ctx, rng):
-    # (e) negative v1 and a key whose range starts below zero
-    cols = _q5_cols(rng, key=_ints_of(rng, -9000, -4000),
-                    v1=_ints_of(rng, -1000, -990))
-    return _spread(ctx, cols), {}, dict(
-        operands=2 + 5, packed=2, site="groupby.packranges")
-
-
-def _d_dictionary_key(ctx, rng):
-    # (e) dictionary codes are their own lanes
-    words = np.array([f"w{i:04d}" for i in range(900)], object)
-    cols = _q5_cols(rng, key=words[rng.integers(0, 900, ROWS4)])
-    t = _spread(ctx, cols)
-    assert t.get_column(0).is_string and not t.get_column(0).is_varbytes
-    return t, {}, dict(operands=2 + 5, packed=2, site="groupby.packranges")
-
-
-def _d_empty_shard(ctx, rng):
-    # (f) a shard with no live row: the ranges are the whole table's
-    mask = np.ones(ROWS4, bool)
-    mask[ROWS4 // 2:3 * ROWS4 // 4] = False
-    return _spread(ctx, _q5_cols(rng), mask=mask), {}, dict(
-        operands=3 + 5, packed=2, site="groupby.packranges")
-
-
-def _d_single_after_exchange(ctx, rng):
-    # no pre-aggregation: the rows themselves cross, observed behind the
-    # exchange (always a mask there)
-    return _spread(ctx, _q5_cols(rng)), {"pre_aggregate": False}, dict(
-        operands=3, packed=2, site="groupby.packranges", phases=("single",))
-
-
-def _d_single_in_place(ctx, rng):
-    # the elided groupby after a join on the same keys: every key's rows
-    # on ONE shard already (here by construction), one step, no exchange,
-    # no mask
-    key = (np.arange(ROWS4) // (ROWS4 // 4) * 10_000
-           + rng.integers(1, 700, ROWS4)).astype(np.int32)
-    return _spread(ctx, _q5_cols(rng, key=key)), {
-        "pre_partitioned": True}, dict(
-            operands=2, packed=2, site="groupby.packranges",
-            phases=("single",), exchange=False)
-
-
-DIST_CASES = {f.__name__[3:]: f for f in (
-    _d_no_row_mask, _d_filtered, _d_padded, _d_nullable_key, _d_wide_range,
-    _d_negative, _d_dictionary_key, _d_empty_shard,
-    _d_single_after_exchange, _d_single_in_place)}
-
-
-def _by_key(frame):
-    return frame.sort_values(frame.columns[0], na_position="last"
-                             ).reset_index(drop=True)
-
-
-@pytest.mark.parametrize("name", list(DIST_CASES))
-def test_four_shard_groupby_packs_as_the_one_chip_sort_does(
-        dist_ctx, monkeypatch, name):
-    """The four-shard groupby with the packing on (as a TPU backend runs
-    it: the key one lane, under the interpreter) equals the same query
-    with the packing off (the CPU's own program) and the plain oracle of
-    the same rows; and what the host counted: the operands of both
-    phases, the packed columns, ONE fetch of the ranges under a sync span
-    of its own, once a query."""
-    t, kw, want = DIST_CASES[name](dist_ctx, np.random.default_rng(len(name)))
-    SUM = [G.AggregationOp.SUM] * 3
-    monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 1 << 40)
-    before = _dist_counted()
-    plain = _by_key(dist_ops.distributed_groupby(
-        t, 0, [1, 2, 3], SUM, **kw).to_pandas())
-    plain_moved = _dist_delta(before)
-    assert "packed" not in plain_moved and not (
-        {"groupby.packranges", "groupby.valuerange"} & set(plain_moved))
-
-    monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 0)
-    with monkeypatch.context() as m:
-        _as_on_a_tpu(m)
-        dist_ops._groupby_fn.cache_clear()
-        before = _dist_counted()
-        try:
-            with telemetry.collect_phases() as cp:
-                packed = _by_key(dist_ops.distributed_groupby(
-                    t, 0, [1, 2, 3], SUM, **kw).to_pandas())
-        finally:
-            dist_ops._groupby_fn.cache_clear()
-        moved = _dist_delta(before)
-
-    phases = want.get("phases", ("partial", "merge"))
-    assert {p: moved.get(p, 0) for p in ("partial", "merge", "single")} == {
-        p: int(p in phases) for p in ("partial", "merge", "single")}
-    assert moved["operands"] == want["operands"]
-    assert moved["packed"] == want["packed"]
-    # the probe's fetch and the exchange's count: two a query in the
-    # cell's shape, and no other
-    fetched = {k: v for k, v in moved.items() if k in DIST_SITES}
-    assert fetched == {want["site"]: 1, **(
-        {"shuffle.count": 1} if want.get("exchange", True) else {})}
-    assert cp.count("sync." + want["site"]) == 1
-
-    assert list(packed.columns) == list(plain.columns)
-    assert len(packed) == len(plain)
-    for col in plain.columns:
-        g, w = packed[col].to_numpy(), plain[col].to_numpy()
-        assert g.dtype == w.dtype
-        # whole floats: a sum does not feel the order of a group's rows
-        assert ((g == w) | ((g != g) & (w != w))).all(), col
-    live = t.to_pandas()
-    oracle = live.groupby(live.columns[0], dropna=False)
-    assert len(packed) == oracle.ngroups
-    if want.get("exact_v1", True):
-        assert int(packed.iloc[:, 1].sum()) == int(live.iloc[:, 1].sum())
-    assert int(packed.iloc[:, 2].sum()) == int(live.iloc[:, 2].sum())
-    assert float(packed.iloc[:, 3].sum()) == float(live.iloc[:, 3].sum())
-
-
-def test_the_planned_groupby_across_chips_probes_once_and_packs(
-        dist_ctx, monkeypatch):
-    """Through the plan, as the cell `groupby-q5-w4` runs it: the probe's
-    span opens once, inside `distributed_groupby.pre_aggregate`, the
-    operands are 2 + 5 with two packed columns (the partial sums carry no
-    mask: PR 44), and the fetches a query are two (the ranges, the
-    exchange's counts)."""
-    monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 0)
-    _as_on_a_tpu(monkeypatch)
-    rng = np.random.default_rng(43)
-    t = _spread(dist_ctx, _q5_cols(rng))
-    pipe = plan.scan(t).groupby("id6", ["v1", "v2", "v3"], ["sum"] * 3)
-    dist_ops._groupby_fn.cache_clear()
-    before = _dist_counted()
-    try:
-        with telemetry.collect_phases() as cp:
-            out = pipe.execute()
-    finally:
-        dist_ops._groupby_fn.cache_clear()
-    moved = _dist_delta(before)
-    assert moved == {"operands": 7, "packed": 2, "partial": 1, "merge": 1,
-                     "groupby.packranges": 1, "shuffle.count": 1}
-    syncs = [s for s in cp.spans if s.name.startswith("sync.")]
-    assert sorted(s.name for s in syncs) == ["sync.groupby.packranges",
-                                             "sync.shuffle.count"]
-    by_id = {s.span_id: s for s in cp.spans}
-    probe = next(s for s in syncs if s.name == "sync.groupby.packranges")
-    assert by_id[probe.parent_id].name == "distributed_groupby.pre_aggregate"
-    live = t.to_pandas()
-    assert out.row_count == live["id6"].nunique()
-
-
-def test_a_shard_under_the_row_gate_pays_no_probe(dist_ctx):
-    """Four shards of 512 rows are under SORT_PACK_MIN_ROWS a SHARD: the
-    sorts carry what they always did, less the dead flag that a table
-    without a row mask never needed and the masks that the partial sums
-    of columns without nulls never needed (the CPU's gather path: 6 + 7;
-    6 + 10 before PR 44)."""
-    assert ROWS4 // 4 < G.SORT_PACK_MIN_ROWS
-    t = _spread(dist_ctx, _q5_cols(np.random.default_rng(3)))
-    before = _dist_counted()
-    out = t.groupby(0, [1, 2, 3], ["sum"] * 3)
-    assert out.row_count >= 2
-    assert _dist_delta(before) == {"operands": 6 + 7, "partial": 1,
-                                   "merge": 1, "shuffle.count": 1}
-
-
-def test_the_gather_path_packs_the_values_among_themselves(
-        dist_ctx, monkeypatch):
-    """On the CPU the per-shard sort carries the row index and the key's
-    mask lane beside its bits (two key lanes: the key is not observed),
-    so v1 and v2 share a word of their own: 5 + 7 for 6 + 7 (the merge
-    carries the dead flag, the key's two lanes, the three partial sums
-    and the index: the sums' three masks rode too before PR 44)."""
-    monkeypatch.setattr(G, "SORT_PACK_MIN_ROWS", 0)
-    t = _spread(dist_ctx, _q5_cols(np.random.default_rng(4)))
-    before = _dist_counted()
-    out = t.groupby(0, [1, 2, 3], ["sum"] * 3)
-    moved = _dist_delta(before)
-    assert moved == {"operands": 5 + 7, "packed": 1, "partial": 1,
-                     "merge": 1, "groupby.valuerange": 1, "shuffle.count": 1}
-    live = t.to_pandas()
-    got = out.to_pandas()
-    assert len(got) == live["id6"].nunique()
-    for name in ("v1", "v2", "v3"):
-        assert float(got[name].sum()) == float(live[name].sum())
 
 
 # --------------------------------------------------------------------------

@@ -8,12 +8,12 @@ distributed-op compositions. A retry that finds its donated accumulator
 consumed rebuilds the pipeline through the partition program without
 chunk 0 and lands on the same bits.
 """
-import os
 
 import numpy as np
 import pytest
 
 import cylon_tpu as ct
+import forced_paths
 from cylon_tpu import telemetry
 from cylon_tpu.parallel import shard as _shard
 from cylon_tpu.parallel import shuffle as _shuffle
@@ -76,9 +76,9 @@ def test_chunked_bit_identical_across_chunk_counts(dist_ctx, monkeypatch,
     bit: same live rows, emit mask, counts_in and capacity."""
     payload, targets, emit = _mk_exchange_inputs(dist_ctx, n)
     counts = _counts(dist_ctx, targets, emit)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     base = _run(dist_ctx, payload, targets, emit, counts)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
+    forced_paths.single_shot(monkeypatch, False)
     monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", str(cbytes))
     c0 = telemetry.metrics_snapshot().get(
         "cylon_exchange_chunks_total", 0)
@@ -96,9 +96,9 @@ def test_chunked_bit_identical_odd_remainder(dist_ctx, monkeypatch):
     nor clobber earlier rows."""
     payload, targets, emit = _mk_exchange_inputs(dist_ctx, 4096, seed=3)
     counts = _counts(dist_ctx, targets, emit)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     base = _run(dist_ctx, payload, targets, emit, counts)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
+    forced_paths.single_shot(monkeypatch, False)
     monkeypatch.setattr(
         _shuffle, "_chunk_plan",
         lambda block, w, rb: (3, -(-block // 3)) if block > 3
@@ -122,9 +122,9 @@ def test_chunked_skew_attrs_match_single_shot(dist_ctx, monkeypatch):
 
     telemetry.add_sink(sink)
     try:
-        monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+        forced_paths.single_shot(monkeypatch)
         _run(dist_ctx, payload, targets, emit, counts)
-        monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
+        forced_paths.single_shot(monkeypatch, False)
         monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
         _run(dist_ctx, payload, targets, emit, counts)
     finally:
@@ -148,9 +148,9 @@ def test_chunked_per_chunk_retry_bit_identical(dist_ctx, monkeypatch):
     monkeypatch.setenv("CYLON_RETRY_BACKOFF_S", "0.001")
     payload, targets, emit = _mk_exchange_inputs(dist_ctx, 4096, seed=9)
     counts = _counts(dist_ctx, targets, emit)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     base = _run(dist_ctx, payload, targets, emit, counts)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
+    forced_paths.single_shot(monkeypatch, False)
     monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
 
     def retries():
@@ -188,7 +188,7 @@ def test_rebuild_after_consumed_donation_is_bit_identical(dist_ctx,
 
     payload, targets, emit = _mk_exchange_inputs(dist_ctx, 16384, seed=11)
     counts = _counts(dist_ctx, targets, emit)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
+    forced_paths.single_shot(monkeypatch, False)
     monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
 
     def launches():
@@ -297,10 +297,10 @@ def test_exchange_pair_routes_through_chunked(dist_ctx, monkeypatch):
     p1, t1, e1 = side(n1, 13)
     p2, t2, e2 = side(n2, 14)
     c1, c2 = _shuffle.count_pair(t1, e1, t2, e2, dist_ctx)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     b1, b2 = _shuffle.exchange_pair(p1, t1, e1, c1, p2, t2, e2, c2,
                                     dist_ctx)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
+    forced_paths.single_shot(monkeypatch, False)
     monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
     o1, o2 = _shuffle.exchange_pair(p1, t1, e1, c1, p2, t2, e2, c2,
                                     dist_ctx)
@@ -309,12 +309,29 @@ def test_exchange_pair_routes_through_chunked(dist_ctx, monkeypatch):
     assert o1[3].get("chunks", 1) > 1 or o2[3].get("chunks", 1) > 1
 
 
+@pytest.mark.parametrize("block,world,row_bytes,want", [
+    # the payload fits one chunk's bytes: the single-shot program
+    (2 ** 20, 4, 8, (2 ** 20, 1)),
+    # `join-w4`'s side: 2^22 slots a pair, 8 bytes a row, four targets
+    (2 ** 22, 4, 8, (2 ** 21, 2)),
+    # the chunk block is floored so that no pipeline passes MAX_CHUNKS
+    (2 ** 30, 4, 64, (2 ** 24, 64)),
+], ids=["one-chunk", "join-w4", "max-chunks"])
+def test_chunk_plan_is_decided_by_the_bytes(monkeypatch, block, world,
+                                            row_bytes, want):
+    """`_chunk_plan` reads the geometry and `CYLON_EXCHANGE_CHUNK_BYTES`
+    (64 MiB) and no switch: single-shot is its one-chunk case."""
+    monkeypatch.delenv("CYLON_EXCHANGE_CHUNK_BYTES", raising=False)
+    assert _shuffle._chunk_plan(block, world, row_bytes) == want
+    assert want[1] <= _shuffle.MAX_CHUNKS
+
+
 @pytest.mark.parametrize("overlap", ["0", "1"])
 def test_distributed_join_identical_under_overlap(dist_ctx, monkeypatch,
                                                   overlap):
     """End to end through the dist_ops composition: the distributed
-    join's rows are independent of the overlap knob."""
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", overlap)
+    join's rows are the same chunked and single-shot."""
+    forced_paths.single_shot(monkeypatch, overlap == "0")
     monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
     rng = np.random.default_rng(17)
     n = 4096

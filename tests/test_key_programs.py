@@ -24,7 +24,7 @@ from cylon_tpu.ops import hash as H
 from cylon_tpu.ops import order as O
 from cylon_tpu.parallel import dist_ops as D
 from cylon_tpu.parallel import shard
-from cylon_tpu.status import CylonError
+from cylon_tpu.status import Code, CylonError
 
 ROWS = 96  # 48 and 24 a shard: shard.distribute pads nothing
 
@@ -435,17 +435,16 @@ def test_distributed_groupby_sums_by_key(dist_ctx):
     assert _sorted_rows(got) == _sorted_rows(want)
 
 
-def test_ring_join_matches_the_shuffle_join(dist_ctx):
+def test_no_ring_join_is_left_to_ask_for(dist_ctx):
+    """The ring join went after its one run on the chip (PR 45): asking
+    for it is an invalid argument, and the error names what is left."""
     left, right = _join_tables(dist_ctx, 61)
-    before = _counter("cylon_key_programs_total", stage="keybits")
-    ring = left.distributed_join(right, "inner", on="k", comm="ring")
-    # the ring moves no row by hash: key bits a side, no targets
-    assert _counter("cylon_key_programs_total", stage="keybits") \
-        == before + 2
-    want = left.distributed_join(right, "inner", on="k")
-    assert _sorted_rows(ring.to_pandas()) == _sorted_rows(want.to_pandas())
-    assert ring.row_count == len(
-        left.to_pandas().merge(right.to_pandas(), on="k"))
+    with pytest.raises(CylonError) as e:
+        left.distributed_join(right, "inner", on="k", comm="ring")
+    assert e.value.code == Code.Invalid
+    assert "'shuffle'" in str(e.value) and "'broadcast'" in str(e.value)
+    assert "'ring'" in str(e.value) and "or 'ring'" not in str(e.value)
+    assert not hasattr(ct, "distributed_join_ring")
 
 
 def _spy_key_bits(monkeypatch):

@@ -1,14 +1,15 @@
 """The Pallas fused hash+bucket+scatter partition kernel.
 
-Core contract: CYLON_PARTITION_KERNEL routes the padded exchange's
+Core contract: `shuffle._partition_path` routes the padded exchange's
 partition through either the XLA stable sort or the fused Pallas
-histogram+scatter kernel (interpreter off-TPU), and the two paths are
-BIT-IDENTICAL on every live row — leaves, counts, start offsets, emit
+histogram+scatter kernel, from platform, world and payload alone (no
+knob: a test reaches the other path, the kernel under the interpreter
+off a TPU, by patching the function, `forced_paths.partition`), and the
+two paths are BIT-IDENTICAL on every live row — leaves, counts, start offsets, emit
 mask — across dtypes (varbytes word legs included), chunk geometry
 (single-shot / deep / odd remainder), empty buckets, all-dead emit
 masks, and end to end through distributed_join /
-distributed_groupby. `CYLON_PARTITION_KERNEL=sort` restores the exact
-pre-kernel program (the path string keys every factory cache).
+distributed_groupby. The path string keys every factory cache.
 
 Interpreter-cost guard: sizes here stay <= 4096 rows and world <= 4
 (one pallas block, <= 5 grid buckets). The PR-1-era lesson holds: an
@@ -16,12 +17,13 @@ interpreted Pallas graph compiles through XLA:CPU at real cost, and
 each distinct (block, part) geometry is one compile — keep geometries
 few and tiny.
 """
-import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 import cylon_tpu as ct
+import forced_paths
 from cylon_tpu import telemetry
 from cylon_tpu.ops import tpu_kernels as tk
 from cylon_tpu.parallel import shard as _shard
@@ -63,10 +65,10 @@ def _counts(ctx, targets, emit):
 
 def _both_paths(ctx, payload, targets, emit, monkeypatch, **kw):
     counts = _counts(ctx, targets, emit)
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "sort")
+    forced_paths.partition(monkeypatch, "sort")
     base = _shuffle.exchange(payload, targets, emit, ctx, counts=counts,
                              **kw)
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "pallas")
+    forced_paths.partition(monkeypatch, "pallas")
     out = _shuffle.exchange(payload, targets, emit, ctx, counts=counts,
                             **kw)
     return base, out
@@ -175,7 +177,7 @@ def test_leg_split_round_trips_2d_leaf():
 
 @pytest.mark.parametrize("live", [1.0, 0.85])
 def test_exchange_bit_identical_single_shot(dist_ctx, monkeypatch, live):
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     payload, targets, emit = _mk_inputs(dist_ctx, 2048, seed=5,
                                         live=live)
     base, out = _both_paths(dist_ctx, payload, targets, emit,
@@ -186,7 +188,7 @@ def test_exchange_bit_identical_single_shot(dist_ctx, monkeypatch, live):
 def test_exchange_bit_identical_narrow_dtypes(dist_ctx, monkeypatch):
     """2-byte and 1-byte leaves ride as widened u32 legs and come back
     bit-exact."""
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     payload, targets, emit = _mk_inputs(
         dist_ctx, 2048, seed=6, extra_dtypes=(np.int16, np.int8))
     base, out = _both_paths(dist_ctx, payload, targets, emit,
@@ -201,13 +203,13 @@ def test_exchange_bit_identical_chunked_and_odd_geometry(dist_ctx,
     forced non-pow2 chunk block (the dropping-scatter remainder)."""
     payload, targets, emit = _mk_inputs(dist_ctx, 4096, seed=7)
     counts = _counts(dist_ctx, targets, emit)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "sort")
+    forced_paths.single_shot(monkeypatch)
+    forced_paths.partition(monkeypatch, "sort")
     base = _shuffle.exchange(payload, targets, emit, dist_ctx,
                              counts=counts)
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "1")
+    forced_paths.single_shot(monkeypatch, False)
     monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "pallas")
+    forced_paths.partition(monkeypatch, "pallas")
     deep = _shuffle.exchange(payload, targets, emit, dist_ctx,
                              counts=counts)
     assert deep[3].get("chunks", 1) > 1
@@ -227,7 +229,7 @@ def test_exchange_bit_identical_empty_buckets(dist_ctx, monkeypatch):
     scatter must still land counts/offsets exactly."""
     import jax.numpy as jnp
 
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     payload, _targets, emit = _mk_inputs(dist_ctx, 2048, seed=8)
     targets = _shard.pin(jnp.zeros(2048, jnp.int32), dist_ctx)
     base, out = _both_paths(dist_ctx, payload, targets, emit,
@@ -238,7 +240,7 @@ def test_exchange_bit_identical_empty_buckets(dist_ctx, monkeypatch):
 def test_exchange_bit_identical_all_dead(dist_ctx, monkeypatch):
     """An all-False emit mask sends every row to the dead bucket: both
     paths must report zero live rows everywhere."""
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     payload, targets, emit = _mk_inputs(dist_ctx, 2048, seed=9,
                                         live=0.0)
     base, out = _both_paths(dist_ctx, payload, targets, emit,
@@ -248,32 +250,39 @@ def test_exchange_bit_identical_all_dead(dist_ctx, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# routing, observability, and the restored pre-kernel program
+# routing, observability, and a program a path
 # ---------------------------------------------------------------------------
 
 
-def test_partition_path_routing_matrix(dist_ctx, monkeypatch):
-    mesh, world = dist_ctx.mesh, dist_ctx.get_world_size()
-    payload = {"a": np.zeros(8, np.int32)}
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "sort")
-    assert _shuffle._partition_path(mesh, world, payload) == "sort"
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "pallas")
-    # off-TPU a forced kernel runs under the interpreter
-    assert _shuffle._partition_path(mesh, world, payload) == "interp"
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "auto")
-    # auto off-TPU: the XLA sort (the kernel only wins on the chip)
-    assert _shuffle._partition_path(mesh, world, payload) == "sort"
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "bogus")
-    assert _shuffle._partition_path(mesh, world, payload) == "sort"
-    # a >4-byte-itemsize 3-D leaf is ineligible — falls back to sort
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "pallas")
-    assert _shuffle._partition_path(
-        mesh, world, {"a": np.zeros((8, 2, 2), np.int32)}) == "sort"
-    # world+1 buckets must fit one histogram lane row: past 127
-    # targets even the forced knob routes to sort instead of tripping
-    # the kernel's nbuckets assert mid-exchange
-    assert _shuffle._partition_path(mesh, 127, payload) == "interp"
-    assert _shuffle._partition_path(mesh, 128, payload) == "sort"
+def _stub_mesh(platform):
+    devs = np.empty(1, object)
+    devs[0] = SimpleNamespace(platform=platform)
+    return SimpleNamespace(devices=devs)
+
+
+_NARROW = {"a": np.zeros(8, np.int32)}
+
+
+@pytest.mark.parametrize("platform,world,payload,want", [
+    # off a TPU: the XLA sort (the kernel only wins on the chip)
+    ("cpu", 4, _NARROW, "sort"),
+    ("tpu", 4, _NARROW, "pallas"),
+    # past _PARTITION_MAX_WORLD the scatter's passes lose to the sort
+    ("tpu", 17, _NARROW, "sort"),
+    # one shard: nothing to partition
+    ("tpu", 1, _NARROW, "sort"),
+    # a 3-D leaf does not split into u32 legs: ineligible
+    ("tpu", 4, {"a": np.zeros((8, 2, 2), np.int32)}, "sort"),
+    # world+1 buckets must fit one histogram lane row
+    ("tpu", 127, _NARROW, "sort"),
+], ids=["cpu", "tpu-w4", "tpu-w17", "tpu-w1", "tpu-3d-leaf", "tpu-w127"])
+def test_partition_path_is_a_pure_function(platform, world, payload,
+                                           want):
+    """Platform, world and payload decide, and nothing else: no knob
+    and no environment variable is read (PR 45), and the interpreter's
+    spelling is never returned."""
+    assert _shuffle._partition_path(_stub_mesh(platform), world,
+                                    payload) == want
 
 
 def test_exchange_pair_mixed_partition_paths(dist_ctx, monkeypatch):
@@ -284,7 +293,7 @@ def test_exchange_pair_mixed_partition_paths(dist_ctx, monkeypatch):
     import jax.numpy as jnp
 
     world = dist_ctx.get_world_size()
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
 
     def side(n, seed, extra_3d=False):
         r = np.random.default_rng(seed)
@@ -303,10 +312,10 @@ def test_exchange_pair_mixed_partition_paths(dist_ctx, monkeypatch):
     p1, t1, e1 = side(1024, 31, extra_3d=True)
     p2, t2, e2 = side(512, 32)
     c1, c2 = _shuffle.count_pair(t1, e1, t2, e2, dist_ctx)
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "sort")
+    forced_paths.partition(monkeypatch, "sort")
     b1, b2 = _shuffle.exchange_pair(p1, t1, e1, c1, p2, t2, e2, c2,
                                     dist_ctx)
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "pallas")
+    forced_paths.partition(monkeypatch, "pallas")
     assert _shuffle._partition_path(dist_ctx.mesh, world, p1) == "sort"
     assert _shuffle._partition_path(dist_ctx.mesh, world, p2) == "interp"
     spans = []
@@ -327,7 +336,7 @@ def test_exchange_pair_mixed_partition_paths(dist_ctx, monkeypatch):
 
 
 def test_partition_path_counter_and_span_attr(dist_ctx, monkeypatch):
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+    forced_paths.single_shot(monkeypatch)
     payload, targets, emit = _mk_inputs(dist_ctx, 2048, seed=11)
     counts = _counts(dist_ctx, targets, emit)
     spans = []
@@ -343,11 +352,11 @@ def test_partition_path_counter_and_span_attr(dist_ctx, monkeypatch):
                 f'cylon_partition_path_total{{path="{path}"}}', 0)
 
         s0, p0 = total("sort"), total("pallas")
-        monkeypatch.setenv("CYLON_PARTITION_KERNEL", "sort")
+        forced_paths.partition(monkeypatch, "sort")
         _shuffle.exchange(payload, targets, emit, dist_ctx,
                           counts=counts)
         assert total("sort") == s0 + 1
-        monkeypatch.setenv("CYLON_PARTITION_KERNEL", "pallas")
+        forced_paths.partition(monkeypatch, "pallas")
         _shuffle.exchange(payload, targets, emit, dist_ctx,
                           counts=counts)
         assert total("pallas") == p0 + 1
@@ -356,12 +365,11 @@ def test_partition_path_counter_and_span_attr(dist_ctx, monkeypatch):
     assert [s["partition_path"] for s in spans] == ["sort", "pallas"]
 
 
-def test_knob_sort_reuses_the_pre_kernel_program(dist_ctx, monkeypatch):
-    """CYLON_PARTITION_KERNEL=sort keys the exact pre-PR factory cache
-    entry: repeated sort-path exchanges build the padded program once,
-    and a pallas-path exchange in between builds a DIFFERENT program
-    without evicting it."""
-    monkeypatch.setenv("CYLON_EXCHANGE_OVERLAP", "0")
+def test_the_sort_path_keys_a_program_of_its_own(dist_ctx, monkeypatch):
+    """The path string keys the factory cache: repeated sort-path
+    exchanges build the padded program once, and a pallas-path exchange
+    in between builds a DIFFERENT program without evicting it."""
+    forced_paths.single_shot(monkeypatch)
     payload, targets, emit = _mk_inputs(dist_ctx, 2048, seed=12)
     counts = _counts(dist_ctx, targets, emit)
 
@@ -370,12 +378,12 @@ def test_knob_sort_reuses_the_pre_kernel_program(dist_ctx, monkeypatch):
             'cylon_kernel_factory_builds_total'
             '{factory="_exchange_padded_fn"}', 0)
 
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "sort")
+    forced_paths.partition(monkeypatch, "sort")
     _shuffle.exchange(payload, targets, emit, dist_ctx, counts=counts)
     b0 = builds()
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "pallas")
+    forced_paths.partition(monkeypatch, "pallas")
     _shuffle.exchange(payload, targets, emit, dist_ctx, counts=counts)
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "sort")
+    forced_paths.partition(monkeypatch, "sort")
     _shuffle.exchange(payload, targets, emit, dist_ctx, counts=counts)
     # the second sort-path exchange re-used the first program; only
     # the pallas variant could have added a build
@@ -390,7 +398,7 @@ def test_knob_sort_reuses_the_pre_kernel_program(dist_ctx, monkeypatch):
 @pytest.mark.parametrize("knob", ["sort", "pallas"])
 def test_distributed_join_and_groupby_end_to_end(dist_ctx, monkeypatch,
                                                  knob):
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", knob)
+    forced_paths.partition(monkeypatch, knob)
     rng = np.random.default_rng(17)
     n = 2048
     left = ct.Table.from_pydict(dist_ctx, {
@@ -437,7 +445,7 @@ def test_varbytes_word_legs_end_to_end(dist_ctx, monkeypatch, knob):
     same partition — the strings must survive both paths."""
     from cylon_tpu.data import strings as _strings
 
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", knob)
+    forced_paths.partition(monkeypatch, knob)
     monkeypatch.setattr(_strings, "DICT_MAX_VOCAB", 0)
     rng = np.random.default_rng(19)
     n = 512
@@ -463,7 +471,7 @@ def test_varbytes_word_legs_end_to_end(dist_ctx, monkeypatch, knob):
 def test_explain_analyze_renders_partition_path(dist_ctx8, monkeypatch):
     from cylon_tpu import plan
 
-    monkeypatch.setenv("CYLON_PARTITION_KERNEL", "sort")
+    forced_paths.partition(monkeypatch, "sort")
     rng = np.random.default_rng(23)
     n = 2048
     left = ct.Table.from_pydict(dist_ctx8, {

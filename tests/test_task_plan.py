@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 import cylon_tpu as ct
-from cylon_tpu.parallel.task_plan import LogicalTaskPlan, task_exchange
+from cylon_tpu.plan.tasks import LogicalTaskPlan, task_exchange
 
 
 @pytest.fixture(scope="module")
