@@ -51,7 +51,7 @@ DEFAULT_CONTRACTS: Tuple[LayerContract, ...] = (
     LayerContract(
         name="base-leaf",
         scope=("status.py", "dtypes.py", "util.py", "native.py",
-               "memory.py"),
+               "memory.py", "exprtokens.py"),
         forbid=("",),  # any intra-package import...
         allow=("telemetry.knobs",),
         # ...except the declared knob registry, itself a stdlib-only

@@ -46,6 +46,7 @@ from ..ops import groupby as _groupby
 from ..ops import join as _join
 from ..ops import order as _order
 from ..ops import setops as _setops
+from ..ops import tpu_kernels as _kernels
 
 
 class Table:
@@ -687,14 +688,81 @@ class Table:
                f"cols={self.column_names})"
 
 
-_CMP = {
-    "eq": lambda a, b: a == b,
-    "ne": lambda a, b: a != b,
-    "lt": lambda a, b: a < b,
-    "gt": lambda a, b: a > b,
-    "le": lambda a, b: a <= b,
-    "ge": lambda a, b: a >= b,
-}
+_CMP = _expr.COMPARE   # op name -> elementwise compare
+
+def _comparable_pair(a: Column, b: Column) -> None:
+    """Raise unless ``a <op> b`` is a compare of two lanes of one type:
+    no strings (two vocabularies' codes, or varbytes), no word planes."""
+    if a.is_string or b.is_string:
+        raise CylonError(
+            Code.NotImplemented,
+            f"compare of two columns: {a.name!r} and {b.name!r}: a "
+            f"{'varbytes' if a.is_varbytes or b.is_varbytes else 'dictionary'}"
+            f" string column is not compared with another column; compare "
+            f"it with a literal, or join on it")
+    refuse_planes([a, b], "compare of two columns")
+    if a.data.dtype != b.data.dtype:
+        raise CylonError(
+            Code.TypeError,
+            f"compare of two columns: {a.name!r} is {a.data.dtype}, "
+            f"{b.name!r} is {b.data.dtype}; cast one on the host")
+
+
+def compare_columns(table: Table, a: int, op: str, b: int):
+    """The bool lane ``column a <op> column b`` over ``table``'s capacity
+    (the plan's `ir.ColCmp`): two columns of one fixed-width type at most
+    32 bits wide; false where either is null, as `Table._compare` has a
+    compare with a literal."""
+    ca, cb = table._columns[a], table._columns[b]
+    _comparable_pair(ca, cb)
+    res = _CMP[op](ca.data, cb.data)
+    for c in (ca, cb):
+        if c.validity is not None:
+            res = res & c.validity
+    return res
+
+
+def _resolve_predicate(tokens, columns) -> tuple:
+    """A `case_when` predicate's tokens as `ops/expr.predicate` takes
+    them: a string literal against a dictionary column becomes the
+    literal's code in THIS column's vocabulary (``("miss", i, op)`` where
+    it has none), and what no compare is built for raises, naming the
+    column. ``columns``: position -> Column (computed ones too)."""
+    kind = tokens[0]
+    if kind == "cmp":
+        _, pos, op, value = tokens
+        c = columns[pos]
+        refuse_planes([c], "case_when")
+        if c.is_varbytes or isinstance(value, str) != c.is_string:
+            raise CylonError(
+                Code.TypeError,
+                f"case_when: column {c.name!r} against {value!r}: a "
+                f"dictionary string column compares with a string, a "
+                f"number column with a number (no varbytes)")
+        if not c.is_string:
+            return tokens
+        if op not in ("eq", "ne"):
+            raise CylonError(Code.TypeError,
+                             "ordering vs str uses dictionary order")
+        code = int(np.searchsorted(c.dictionary, value))
+        if code < len(c.dictionary) and c.dictionary[code] == value:
+            return ("cmp", pos, op, code)
+        return ("miss", pos, op)
+    if kind == "colcmp":
+        _comparable_pair(columns[tokens[1]], columns[tokens[3]])
+        return tokens
+    return (kind,) + tuple(_resolve_predicate(t, columns)
+                           for t in tokens[1:])
+
+
+def _resolve_value(tokens, columns) -> tuple:
+    if tokens[0] in ("col", "lit"):
+        return tokens
+    if tokens[0] == "case":
+        return ("case", _resolve_predicate(tokens[1], columns))
+    return (tokens[0],) + tuple(_resolve_value(t, columns)
+                                for t in tokens[1:])
+
 
 _JOIN_TYPES = {
     "inner": _join.JoinType.INNER,
@@ -717,8 +785,167 @@ def _as_agg_op(o) -> _groupby.AggregationOp:
     return _groupby.AggregationOp(int(o))
 
 
+from ..util import bucket_cap as _bucket_cap
 from ..util import capacity as _capacity
 from ..util import pow2 as _pow2  # shared capacity-rounding policy
+
+
+# ---------------------------------------------------------------------------
+# Device compaction: a table whose row mask keeps few of its slots, cut to
+# the capacity its live rows need, before an operator that sorts its slots
+# ---------------------------------------------------------------------------
+
+# rows a block of `stream_compact` holds here: block_rows * 128
+COMPACT_BLOCK_ROWS = 256
+
+
+def compact_path() -> str:
+    """"stream": the Pallas kernel `stream_compact` (a TPU); "xla": one
+    `nonzero` and a gather a stream (everything else: the interpreter
+    would take seconds a program). By the backend alone, no knob."""
+    return "stream" if jax.default_backend() == "tpu" else "xla"
+
+
+@_telemetry.counted_cache
+def _compact_count_program_fn():
+    """The live rows of a row mask, one scalar
+    (`jit_compact_count_program`)."""
+    def kernel(mask):
+        return mask.sum(dtype=jnp.int32)
+
+    return jax.jit(kernel)
+
+
+def _to_word(x):
+    """A lane of at most 32 bits as a uint32 stream, bit for bit for a
+    4-byte dtype and by value for a narrower one."""
+    if x.dtype == jnp.uint32:
+        return x
+    if x.dtype.itemsize == 4:
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    if x.dtype == jnp.bool_:
+        return x.astype(jnp.uint32)
+    if jnp.issubdtype(x.dtype, jnp.floating):    # float16 / bfloat16
+        return jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+    return jax.lax.bitcast_convert_type(x.astype(jnp.int32), jnp.uint32)
+
+
+def _from_word(w, dtype):
+    dtype = jnp.dtype(dtype)
+    if dtype == jnp.uint32:
+        return w
+    if dtype.itemsize == 4:
+        return jax.lax.bitcast_convert_type(w, dtype)
+    if dtype == jnp.bool_:
+        return w != 0
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jax.lax.bitcast_convert_type(w.astype(jnp.uint16), dtype)
+    return jax.lax.bitcast_convert_type(w, jnp.int32).astype(dtype)
+
+
+@_telemetry.counted_cache
+def _compact_program_fn(cap: int, path: str, interpret: bool = False):
+    """The live rows of every array (rows on the last axis: a lane
+    ``[n]``, 8 bytes wide only with x64 on, or an int64's word planes
+    ``uint32[2, n]``) and of every validity mask, in row order in the
+    first slots of ``cap``, and the row mask of the cut table: ONE program
+    (`jit_compact_program`). The masks ride 32 to a stream; a plane-held
+    or 8-byte column is two streams."""
+    def kernel(mask, arrays, valids):
+        streams = []
+        for a in arrays:
+            if a.ndim == 2:
+                streams += [a[0], a[1]]
+            elif a.dtype.itemsize == 8:     # a native 64-bit lane (x64 on)
+                words = jax.lax.bitcast_convert_type(a, jnp.uint32)
+                streams += [words[:, 0], words[:, 1]]
+            else:
+                streams.append(_to_word(a))
+        for lo in range(0, len(valids), 32):
+            word = jnp.zeros(mask.shape, jnp.uint32)
+            for bit, v in enumerate(valids[lo:lo + 32]):
+                word = word | (v.astype(jnp.uint32) << np.uint32(bit))
+            streams.append(word)
+        if path == "stream":
+            outs, count = _kernels.stream_compact(
+                mask, streams, block_rows=COMPACT_BLOCK_ROWS,
+                interpret=interpret, out_elems=cap)
+        else:
+            idx = jnp.nonzero(mask, size=cap, fill_value=0)[0]
+            outs = [jnp.take(s, idx) for s in streams]
+            count = mask.sum(dtype=jnp.int32)
+        outs = list(outs)
+        new_arrays = []
+        for a in arrays:
+            if a.ndim == 2:
+                new_arrays.append(jnp.stack([outs.pop(0), outs.pop(0)]))
+            elif a.dtype.itemsize == 8:
+                new_arrays.append(jax.lax.bitcast_convert_type(
+                    jnp.stack([outs.pop(0), outs.pop(0)], axis=1), a.dtype))
+            else:
+                new_arrays.append(_from_word(outs.pop(0), a.dtype))
+        new_valids = [(outs[j // 32] >> np.uint32(j % 32)) & np.uint32(1) != 0
+                      for j in range(len(valids))]
+        live = jnp.arange(cap, dtype=jnp.int32) < count
+        return new_arrays, new_valids, live
+
+    return jax.jit(kernel)
+
+
+def compact_streams(columns: Sequence[Column]) -> int:
+    """The 32-bit streams `compact_live` moves for ``columns``."""
+    masks = sum(c.validity is not None for c in columns)
+    return sum(2 if c.is_planes or c.data.dtype.itemsize == 8 else 1
+               for c in columns) + -(-masks // 32)
+
+
+def compact_live(table: Table):
+    """(``table`` cut to the capacity its live rows need, or ``table``
+    itself; what was done, for the caller's span). A table with a row
+    mask has its live rows counted (ONE fetch, ``sync.compact.count``,
+    memoised on the mask's buffer as the join's count fetch is on its
+    inputs'); where the count's `bucket_cap` is under half the capacity
+    ONE device program moves the live rows of every column, in row
+    order, to the first slots of that capacity (`_compact_program_fn`: no
+    index array leaves or reaches the host), and the result's row mask is
+    the prefix of live rows. Else (no row mask, over half alive, a
+    varbytes column, a table sharded over several devices) the table is
+    handed back as it is: every operator honours a row mask."""
+    from ..parallel.shuffle import _count_cached
+
+    info = {"rows_in": table.capacity, "compacted": False}
+    mask = table.row_mask
+    if mask is None or not table._columns \
+            or any(c.is_varbytes for c in table._columns) \
+            or len(table._columns[0].data.sharding.device_set) != 1:
+        return table, info
+    count = _count_cached(
+        ("compact_count", id(mask)), (mask,),
+        lambda: int(_telemetry.host_fetch("compact.count",
+                                          _compact_count_program_fn()(mask))))
+    table._row_count_cache = count
+    cap = _bucket_cap(count)
+    info.update(rows_out=count, capacity=cap)
+    if 2 * cap >= table.capacity:
+        return table, info
+    cols = table._columns
+    nullable = [c for c in cols if c.validity is not None]
+    arrays, valids, live = _compact_program_fn(cap, compact_path())(
+        mask, [c.data for c in cols], [c.validity for c in nullable])
+    valids = iter(valids)
+    out = Table([Column(a, c.dtype,
+                        None if c.validity is None else next(valids),
+                        c.dictionary, c.name)
+                 for a, c in zip(arrays, cols)], table._ctx, live)
+    out._row_count_cache = count
+    out._hash_partitioned = table._hash_partitioned
+    out._key_ordered = table._key_ordered
+    streams = compact_streams(cols)
+    info.update(compacted=True, streams=streams)
+    _telemetry.counter("cylon_compact_rows_in_total").inc(table.capacity)
+    _telemetry.counter("cylon_compact_rows_out_total").inc(count)
+    _telemetry.counter("cylon_compact_streams_total").inc(streams)
+    return out, info
 
 
 def _sort_keys_mixed(cols: Sequence[Column], asc: Sequence[bool]):
@@ -944,14 +1171,17 @@ def _join_plan_bytes_estimate(left: Table, right: Table) -> int:
 
 def count_plan_sort(keys, str_flags, n_cols: int, a_desc=None, b_desc=None,
                     hash_mode: bool = False,
-                    block_rows: Optional[int] = None) -> None:
+                    block_rows: Optional[int] = None, rows: int = 0) -> None:
     """Count what the host hands a join's plan sort, what the join will
     gather and what its expand kernel sweeps, once a join, where the
     operand list is built (the local join here,
     `parallel/dist_ops.distributed_join`): the four ``cylon_join_*``
-    counters of docs/telemetry.md. ``keys``: one side's key arrays;
+    counters of docs/telemetry.md and the slots of both sides that sort
+    was handed (``rows``, `cylon_join_plan_sort_rows_total`: dead slots
+    are sorted as live ones are). ``keys``: one side's key arrays;
     ``n_cols``: the input columns of both sides; the lane descriptors
     and the kernels' ``block_rows`` only on the stream path."""
+    _telemetry.counter("cylon_join_plan_sort_rows_total").inc(rows)
     _telemetry.counter("cylon_join_sort_operands_total").inc(
         _join.plan_sort_operand_count(keys, str_flags, a_desc, b_desc,
                                       hash_mode))
@@ -1061,7 +1291,8 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
                                                wide_key)
         br = _join.stream_block_rows(lkeys[0].shape[-1], rkeys[0].shape[-1])
         count_plan_sort(lkeys, str_flags, len(ldat) + len(rdat), a_desc,
-                        b_desc, hash_mode, br)
+                        b_desc, hash_mode, br,
+                        rows=left.capacity + right.capacity)
         with _telemetry.phase("join.plan", seq):
             counts, a_streams, b_streams = _join.plan_program_stream(
                 lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
@@ -1108,7 +1339,8 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
     else:
         from ..parallel.shuffle import _count_cached
 
-        count_plan_sort(lkeys, str_flags, len(ldat) + len(rdat))
+        count_plan_sort(lkeys, str_flags, len(ldat) + len(rdat),
+                        rows=left.capacity + right.capacity)
         with _telemetry.phase("join.plan", seq):
             counts2, lo, m, bperm, un_mask = _join.plan_program(
                 lkeys, lkvalid, lemit, rkeys, rkvalid, remit, str_flags,
@@ -1949,10 +2181,12 @@ def _expr_compute_program_fn(exprs: tuple, forms: tuple, out_dtypes: tuple,
         leaves, valids = dict(leaves), dict(valids)
         out = []
         for i, (tokens, dtype) in enumerate(zip(exprs, out_dtypes)):
-            col = _expr.evaluate_native(tokens, leaves, dtype) \
+            col = _expr.evaluate_native(tokens, leaves, dtype, valids) \
                 if forms is None else _expr.evaluate_words(
-                    tokens, forms[i], leaves, dtype)
-            masks = [valids[p] for p in sorted(_expr.columns_of(tokens))
+                    tokens, forms[i], leaves, dtype, valids)
+            # a case_when is 0 where its predicate reads a null: never null
+            masks = [valids[p] for p in
+                     sorted(_expr.columns_of(tokens, values_only=True))
                      if p in valids]
             validity = functools.reduce(jnp.logical_and, masks) \
                 if masks else None
@@ -1993,8 +2227,23 @@ def _with_columns(table: Table, names, exprs) -> Table:
     out_dtypes = []
     for tokens in exprs:    # raises on a column that is no integer
         out_dtypes.append(_expr.result_dtype(tokens, types + out_dtypes))
-    probe = _expr_ranges_program_fn()(tuple(c.data for c in cols))
+    # a case_when's string literals as this table's dictionary codes (a
+    # computed column is a number: Column's defaults stand for it)
+    by_pos = dict(enumerate(table._columns))
     native = bool(jax.config.jax_enable_x64)
+    for i, (name, dtype) in enumerate(zip(names, out_dtypes)):
+        by_pos[w + i] = Column(
+            np.zeros(0, dtype) if native or dtype == "int32"
+            else np.zeros((2, 0), np.uint32),
+            dtypes.from_np_dtype(np.dtype(dtype)), name=name)
+    exprs = [_resolve_value(t, by_pos) for t in exprs]
+    # only what a VALUE reads is probed: a case_when's range is {0, 1}
+    # whatever its predicate reads, and a table whose expressions are all
+    # of that kind is neither probed nor fetched
+    probed = sorted({p for t in exprs
+                     for p in _expr.columns_of(t, values_only=True) if p < w})
+    probe = _expr_ranges_program_fn()(
+        tuple(table._columns[p].data for p in probed)) if probed else None
     shape = (tuple(exprs), tuple(out_dtypes), w)
 
     def compute(forms):
@@ -2005,8 +2254,8 @@ def _with_columns(table: Table, names, exprs) -> Table:
 
     guess = None if native else _last_forms.get(shape)
     computed = compute(guess) if native or guess is not None else None
-    fetched = _telemetry.host_fetch("expr.range", probe)
-    ranges = dict(zip(read, _expr.ranges_of(fetched)))
+    ranges = {} if probe is None else dict(zip(probed, _expr.ranges_of(
+        _telemetry.host_fetch("expr.range", probe))))
     all_names = list(table.column_names) + list(names)
     forms = []
     for i, (tokens, dtype) in enumerate(zip(exprs, out_dtypes)):

@@ -1,11 +1,24 @@
 """Integer value expressions over columns: ``add`` / ``sub`` / ``mul`` of
 columns, other expressions and integer literals, evaluated exactly or not
-at all.
+at all; and ``case``, a predicate as the integer 1 or 0.
 
 An expression arrives as a token tree (``plan/ir.py`` builds it, and the
 plan's fingerprint reads the same tokens): ``("col", i)``, ``("lit", v)``,
-``(op, a, b)`` with op one of ``add``, ``sub``, ``mul``. Its column has a
-declared dtype, int32 or int64, by the operands' (`result_dtype`).
+``(op, a, b)`` with op one of ``add``, ``sub``, ``mul``, and ``("case",
+predicate)``. Its column has a declared dtype, int32 or int64, by the
+operands' (`result_dtype`).
+
+A PREDICATE is a token tree too (`predicate`): ``("cmp", i, op,
+literal)``, ``("colcmp", i, op, j)``, ``("and" | "or", x, y)``, ``("not",
+x)``; a compare is false where a column it reads is null, as the plan's
+filters have it (`data/table.Table._compare`). ``("case", p)`` is 1 where
+``p`` is true and 0 where it is false or null: its range is {0, 1} without
+a look at the table, it is an int32 lane in every form, and it is never
+null, so the columns only a predicate reads are neither probed nor part of
+the computed column's validity (`columns_of(..., values_only=True)`). The
+caller hands a predicate over with its string literals resolved to the
+column's dictionary codes (`data/table._resolve_predicate`: ``("miss", i,
+op)`` for a literal the vocabulary lacks).
 
 EXACT OR NOT AT ALL. Before a computed column is handed on the host
 walks the tree with the OBSERVED range of every column it reads
@@ -30,10 +43,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..exprtokens import pred_columns as predicate_columns
+from ..exprtokens import value_columns as columns_of  # noqa: F401
+from ..exprtokens import value_repr as render
 from ..status import Code, CylonError
 from . import wideint as W
 
-_SYMBOL = {"add": "+", "sub": "-", "mul": "*"}
 # integer column dtypes an expression may read, and those that make its
 # column an int64
 _NARROW = ("int8", "int16", "int32", "uint8", "uint16")
@@ -57,25 +72,40 @@ def result_dtype(tokens, types) -> str:
     if kind == "lit":
         return "int32" if W.fits(tokens[1], tokens[1], W.INT32_RANGE) \
             else "int64"
+    if kind == "case":
+        return "int32"
     a, b = (result_dtype(t, types) for t in tokens[1:])
     return "int64" if "int64" in (a, b) else "int32"
 
 
-def columns_of(tokens) -> set:
-    if tokens[0] == "col":
-        return {tokens[1]}
-    if tokens[0] == "lit":
-        return set()
-    return columns_of(tokens[1]) | columns_of(tokens[2])
+COMPARE = {
+    "eq": lambda a, b: a == b, "ne": lambda a, b: a != b,
+    "lt": lambda a, b: a < b, "gt": lambda a, b: a > b,
+    "le": lambda a, b: a <= b, "ge": lambda a, b: a >= b,
+}
 
 
-def render(tokens, names=None) -> str:
-    if tokens[0] == "col":
-        return names[tokens[1]] if names else f"c{tokens[1]}"
-    if tokens[0] == "lit":
-        return str(tokens[1])
-    return (f"({render(tokens[1], names)} {_SYMBOL[tokens[0]]} "
-            f"{render(tokens[2], names)})")
+def predicate(tokens, leaves, valids):
+    """The bool lane of a predicate over ``leaves`` (column position ->
+    array) and ``valids`` (position -> validity mask, nullable columns
+    only): what the plan's filter masks give for the same expression."""
+    kind = tokens[0]
+    if kind in ("cmp", "miss", "colcmp"):
+        x = leaves[tokens[1]]
+        if kind == "cmp":
+            res = COMPARE[tokens[2]](x, tokens[3])
+        elif kind == "miss":    # the literal is no word of the vocabulary
+            res = jnp.full(x.shape, tokens[2] == "ne")
+        else:
+            res = COMPARE[tokens[2]](x, leaves[tokens[3]])
+        for p in sorted(predicate_columns(tokens)):
+            if p in valids:
+                res = res & valids[p]
+        return res
+    if kind == "not":
+        return ~predicate(tokens[1], leaves, valids)
+    a, b = (predicate(t, leaves, valids) for t in tokens[1:])
+    return a & b if kind == "and" else a | b
 
 
 def _interval(op, a, b):
@@ -106,6 +136,8 @@ def plan_forms(tokens, ranges, dtype: str, name: str, names=None):
             rng, kids = ranges[t[1]], ()
         elif t[0] == "lit":
             rng, kids = (t[1], t[1]), ()
+        elif t[0] == "case":
+            rng, kids = (0, 1), ()
         else:
             (fa, ra), (fb, rb) = walk(t[1]), walk(t[2])
             rng, kids = _interval(t[0], ra, rb), (fa, fb)
@@ -120,7 +152,7 @@ def plan_forms(tokens, ranges, dtype: str, name: str, names=None):
     return forms, rng
 
 
-def evaluate_native(tokens, leaves, dtype: str):
+def evaluate_native(tokens, leaves, dtype: str, valids=None):
     """The expression over native arrays (x64 on): every step an int64,
     the result cast to its declared dtype. `plan_forms` has shown that
     nothing wraps."""
@@ -129,6 +161,8 @@ def evaluate_native(tokens, leaves, dtype: str):
             return leaves[t[1]].astype(jnp.int64)
         if t[0] == "lit":
             return jnp.int64(t[1])
+        if t[0] == "case":
+            return predicate(t[1], leaves, valids or {}).astype(jnp.int64)
         a, b = walk(t[1]), walk(t[2])
         return a + b if t[0] == "add" else a - b if t[0] == "sub" else a * b
 
@@ -153,7 +187,7 @@ def _widen(v, form):
     return v if form == "i64" else W.from_int32(v)
 
 
-def evaluate_words(tokens, forms, leaves, dtype: str):
+def evaluate_words(tokens, forms, leaves, dtype: str, valids=None):
     """The expression where x64 is off: ``leaves`` maps a column position
     to its array (an integer lane at most 32 bits wide, or the
     ``uint32[2, n]`` planes of an int64). The result is an int32 lane or,
@@ -164,6 +198,8 @@ def evaluate_words(tokens, forms, leaves, dtype: str):
             return _leaf(leaves[t[1]], form)
         if t[0] == "lit":
             return np.int32(t[1]) if form == "i32" else W.const(t[1])
+        if t[0] == "case":    # {0, 1}: an int32 lane in every form
+            return predicate(t[1], leaves, valids or {}).astype(jnp.int32)
         fa, fb = f[1], f[2]
         a, b = walk(t[1], fa), walk(t[2], fb)
         if form == "i32":

@@ -250,13 +250,21 @@ def inverse_monotone(P: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
 
 
 def stream_compact(mask: jnp.ndarray, streams: Sequence[jnp.ndarray],
-                   block_rows: int = 32, interpret: bool = False
+                   block_rows: int = 32, interpret: bool = False,
+                   out_elems: Optional[int] = None
                    ) -> Tuple[Tuple[jnp.ndarray, ...], jnp.ndarray]:
     """Compact ``streams[k][mask]`` into dense zero-padded prefixes.
 
     mask: (n,) bool/int; streams: 1-D 32-bit arrays of length n.
     Returns (tuple of compacted (n_pad,) arrays, count int32). n_pad =
     n rounded up to a block multiple (tail beyond `count` is zeros).
+
+    ``out_elems``: the outputs hold that many elements and no more (the
+    caller knows the count is at most that: `data/table.compact_live`
+    fetched it), so a mask that keeps a hundredth of its rows writes a
+    hundredth of the input, not all of it and a tail of zeros. A count
+    beyond it is not written past the end (the window's row is held to
+    the array) and comes back as it is, for the caller to see.
     """
     nstreams = len(streams)
     n = mask.shape[0]
@@ -275,7 +283,10 @@ def stream_compact(mask: jnp.ndarray, streams: Sequence[jnp.ndarray],
     s2 = [pad_rows(s if s.dtype == jnp.uint32 else s.view(jnp.uint32),
                    rows) for s in streams]
 
-    out_rows = rows + BR + 8  # dynamic write window may extend past rows
+    # dynamic write window may extend past the last row written
+    keep_rows = rows if out_elems is None \
+        else -(-rows_for(out_elems) // 8) * 8
+    out_rows = keep_rows + BR + 8
 
     scratch = ([pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((nstreams, LANES), jnp.uint32)]
@@ -296,7 +307,8 @@ def stream_compact(mask: jnp.ndarray, streams: Sequence[jnp.ndarray],
         bufs = list(rest[2 * nstreams + 3:2 * nstreams + 3 + nstreams])
         sems = rest[2 * nstreams + 3 + nstreams]
         _compact_streams(nstreams, BR, mask_ref, srefs, outs, cnt_ref,
-                         wptr, tails, bufs, sems, interpret)
+                         wptr, tails, bufs, sems, interpret,
+                         None if out_elems is None else out_rows)
 
     res = pl.pallas_call(
         kernel,
@@ -314,9 +326,10 @@ def stream_compact(mask: jnp.ndarray, streams: Sequence[jnp.ndarray],
     with _x32_trace():
         res = res(m2, *s2)
     outs, count = res[:nstreams], res[nstreams][0]
+    keep = rows * LANES if out_elems is None else out_elems
     flat = tuple(
-        o.reshape(-1)[:rows * LANES].view(s.dtype)
-        if s.dtype != jnp.uint32 else o.reshape(-1)[:rows * LANES]
+        o.reshape(-1)[:keep].view(s.dtype)
+        if s.dtype != jnp.uint32 else o.reshape(-1)[:keep]
         for o, s in zip(outs, streams))
     return flat, count
 
@@ -947,11 +960,13 @@ def join_expand_stream(counts: jnp.ndarray,
 
 
 def _compact_write(BR, m, vals, out_refs, wptr, wslot, tails, trow0,
-                   bufs, sems, srow0, interpret):
+                   bufs, sems, srow0, interpret, last_row0=None):
     """Compact the masked elements of `vals` (VMEM (BR,128) u32 values,
     mask m int32 0/1) onto `out_refs` at the running write pointer
     ``wptr[wslot]``, carrying the partial-row tail in rows trow0.. of
-    `tails` and using semaphores srow0.. of `sems`.
+    `tails` and using semaphores srow0.. of `sems`. ``last_row0``: the
+    last row a write window may start at (outputs smaller than the
+    input: `stream_compact`'s ``out_elems``).
 
     Staged-shift compaction: selected element at j must move UP by
     d[j] = #unselected before j (monotone non-decreasing). Moving by
@@ -964,6 +979,8 @@ def _compact_write(BR, m, vals, out_refs, wptr, wslot, tails, trow0,
     cnt = P[BR - 1, LANES - 1]
     base = wptr[wslot]
     s = base % _L32
+    row0 = base // _L32 if last_row0 is None \
+        else jnp.minimum(base // _L32, np.int32(last_row0))
 
     one_u = np.uint32(1)
     q = flat_iota((BR, LANES))
@@ -998,13 +1015,13 @@ def _compact_write(BR, m, vals, out_refs, wptr, wslot, tails, trow0,
         blk = jnp.concatenate([first, shifted[1:]])
         bufs[k][:] = blk
         pltpu.make_async_copy(
-            bufs[k], out_refs[k].at[pl.ds(base // _L32, BR + 8)],
+            bufs[k], out_refs[k].at[pl.ds(row0, BR + 8)],
             sems.at[srow0 + k]).start()
     newp = base + cnt
     rel = newp // _L32 - base // _L32
     for k in range(nstreams):
         pltpu.make_async_copy(
-            bufs[k], out_refs[k].at[pl.ds(base // _L32, BR + 8)],
+            bufs[k], out_refs[k].at[pl.ds(row0, BR + 8)],
             sems.at[srow0 + k]).wait()
         tails[trow0 + k:trow0 + k + 1, :] = bufs[k][pl.ds(rel, 1), :]
     wptr[wslot] = newp
@@ -1012,7 +1029,10 @@ def _compact_write(BR, m, vals, out_refs, wptr, wslot, tails, trow0,
 
 
 def _compact_streams(nstreams, BR, mask_ref, streams, out_refs, cnt_ref,
-                     wptr, tails, bufs, sems, interpret=False):
+                     wptr, tails, bufs, sems, interpret=False,
+                     out_rows=None):
+    """``out_rows``: the rows of the output arrays where they are fewer
+    than the input's blocks and one window."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -1025,7 +1045,8 @@ def _compact_streams(nstreams, BR, mask_ref, streams, out_refs, cnt_ref,
     vals = [st[:] for st in streams]
     base = wptr[0]  # write pointer before this block's compaction
     newp = _compact_write(BR, m, vals, out_refs, wptr, 0, tails, 0,
-                          bufs, sems, 0, interpret)
+                          bufs, sems, 0, interpret,
+                          None if out_rows is None else out_rows - (BR + 8))
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -1033,7 +1054,8 @@ def _compact_streams(nstreams, BR, mask_ref, streams, out_refs, cnt_ref,
         # The documented contract zero-pads the tail; real HBM outputs are
         # not zero-initialized, so sweep zero windows over whatever lies
         # beyond the final write window.
-        total_rows = pl.num_programs(0) * BR + BR + 8
+        total_rows = pl.num_programs(0) * BR + BR + 8 \
+            if out_rows is None else out_rows
         start = base // LANES + BR + 8
         nwin = (total_rows - start + (BR + 8) - 1) // (BR + 8)
         for k in range(nstreams):

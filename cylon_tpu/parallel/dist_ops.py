@@ -1322,7 +1322,9 @@ def distributed_join(left: Table, right: Table,
                                                    jt, lkey, rkey)
             table_mod.count_plan_sort(lkb, (False,) * len(lkb),
                                       len(ldat) + len(rdat), a_desc,
-                                      b_desc, hash_mode, br)
+                                      b_desc, hash_mode, br,
+                                      rows=lkb[0].shape[-1]
+                                      + rkb[0].shape[-1])
         _sp.set(key_lanes=len(lkb),
                 hash_mode=bool(mode is not None and mode[0]))
 
@@ -1358,7 +1360,9 @@ def distributed_join(left: Table, right: Table,
     else:
         with _span("distributed_join.plan", seq):
             table_mod.count_plan_sort(lkb, (False,) * len(lkb),
-                                      len(ldat) + len(rdat))
+                                      len(ldat) + len(rdat),
+                                      rows=lkb[0].shape[-1]
+                                      + rkb[0].shape[-1])
             counts2, lo, m, bperm, un_mask = _join_plan_fn(ctx.mesh, jt)(
                 lkb, lkv, lemit, rkb, rkv, remit)
             # replicated counts-gather: a counted collective dispatch

@@ -11,7 +11,8 @@ nodes) over the `table_api` registry; the optimizer propagates
 partitioning metadata and (1) deletes `Shuffle` nodes whose input is
 already hash-placed on the same keys, (2) prunes unreferenced columns
 below the exchanges, and (3) pushes filters below shuffles so dead rows
-drop in transit; the executor lowers the optimized plan onto the
+drop in transit, and a filter's conjuncts through a join to the side
+whose columns they read; the executor lowers the optimized plan onto the
 existing `dist_ops`/`table_api` primitives (never `ops/` kernels — the
 analysis suite's `layering/plan-no-ops` rule) and stamps per-node
 `telemetry.span` spans, so a plan's shuffle count is directly observable in logs and
@@ -25,7 +26,7 @@ The task-routing overlay is `plan.tasks` (`LogicalTaskPlan`,
 """
 from . import ir, optimizer, executor, report, tasks
 from .ir import (Compute, Filter, GroupBy, Join, PlanNode, Project, Scan,
-                 SetOp, Shuffle, Sort, col)
+                 SetOp, Shuffle, Sort, case_when, col)
 from .lazy import LazyTable, scan
 from .optimizer import PlanStats, optimize
 from .executor import execute, execute_analyzed
@@ -35,7 +36,7 @@ from .tasks import LogicalTaskPlan, task_exchange
 __all__ = [
     "Compute", "Filter", "GroupBy", "Join", "LazyTable", "LogicalTaskPlan",
     "NodeMeasure", "PlanNode", "PlanReport", "PlanStats", "Project",
-    "Scan", "SetOp", "Shuffle", "Sort", "col", "execute",
+    "Scan", "SetOp", "Shuffle", "Sort", "case_when", "col", "execute",
     "execute_analyzed", "executor", "ir", "optimize", "optimizer",
     "report", "scan", "task_exchange", "tasks",
 ]

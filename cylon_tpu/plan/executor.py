@@ -362,6 +362,9 @@ class _Exec:
 
     def _do_filter(self, node: ir.Filter) -> Table:
         t = self.run(node.children[0])
+        if node.below_join:
+            telemetry.counter("cylon_plan_filters_below_join_total").inc(
+                node.below_join)
         with _span("plan.filter", self._seq(), rows_in=t.capacity):
             return _ledger.track(t.filter_mask(node.expr.mask(t)),
                                  "plan.filter")
@@ -426,6 +429,21 @@ class _Exec:
             self._stamp_stats(sp, node, out)
             return out
 
+    def _compacted(self, t: Table) -> Table:
+        """``t`` cut on the device to the capacity its live rows need,
+        before an operator whose cost goes with its input's SLOTS (the
+        local join sorts every slot of both sides, dead ones too): a
+        table with a row mask has its live rows counted
+        (``sync.compact.count``) and, where they fill under half its
+        capacity, is compacted by one program (`table.compact_live`).
+        One chip only: across chips the exchange drops dead rows."""
+        if t.row_mask is None:
+            return t
+        with _span("plan.compact", self._seq(), rows_in=t.capacity) as sp:
+            out, info = table_mod.compact_live(t)
+            sp.set(**info)
+            return out if out is t else _ledger.track(out, "plan.compact")
+
     def _do_join(self, node: ir.Join) -> Table:
         l, r = node.children
         # fold Shuffle markers into the join's own (fused, skippable)
@@ -435,6 +453,8 @@ class _Exec:
         lt = self.run(lsrc)
         rt = self.run(rsrc)
         world = _world(self.ctx)
+        if world == 1:
+            lt, rt = self._compacted(lt), self._compacted(rt)
         broadcast = world > 1 and node.algorithm == "broadcast" \
             and getattr(node, "build_side", None) in (0, 1)
         # the label reports what the RUNTIME will do, not what the plan

@@ -51,6 +51,8 @@ def _expr_tokens(e) -> tuple:
     if isinstance(e, ir.Cmp):
         return ("cmp", int(e.pos), str(e.op), type(e.value).__name__,
                 repr(e.value))
+    if isinstance(e, ir.ColCmp):
+        return e.tokens()
     if isinstance(e, ir.BoolOp):
         return (str(e.op), _expr_tokens(e.a), _expr_tokens(e.b))
     if isinstance(e, ir.Not):
@@ -75,7 +77,8 @@ def _own_tokens(n: ir.PlanNode, with_algorithm: bool = True) -> tuple:
     elif isinstance(n, ir.Filter):
         extra = ("expr", _expr_tokens(n.expr))
     elif isinstance(n, ir.Compute):
-        # the bound token trees: operators, positions, literals
+        # the bound token trees: operators, positions, literals, and a
+        # case_when's predicate tokens
         extra = ("compute", tuple(n.names), tuple(n.exprs))
     elif isinstance(n, ir.Shuffle):
         # NB: the `salted` flag is deliberately NOT a token — a salted

@@ -1,5 +1,7 @@
-"""Logical-plan IR nodes, the filter expression mini-language and the
-integer value expressions of computed columns (`Value`, `Compute`).
+"""Logical-plan IR nodes, the filter expression mini-language (column
+against literal, column against column, and / or / not) and the integer
+value expressions of computed columns (`Value`, `Compute`; `case_when`
+makes a predicate a value).
 
 Every node knows its output ``schema`` (column names) and ``types``
 (numpy dtype strings, with the sentinel ``"str"`` for string/varbytes
@@ -21,6 +23,9 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Tuple, Union
 
+from ..exprtokens import VALUE_SYMBOL as _VALUE_SYMBOL
+from ..exprtokens import (pred_columns, pred_remap, pred_repr,  # noqa: F401
+                          value_columns, value_remap, value_repr)
 from ..status import Code, CylonPlanError
 
 # string-typed columns can never carry a hash-placement witness
@@ -50,6 +55,17 @@ class Expr:
         elementwise ops)."""
         raise NotImplementedError
 
+    def tokens(self) -> tuple:
+        """The BOUND expression as a token tree, the form a `Compute`
+        node holds a predicate in (`case_when`) and the fingerprint
+        reads: ``("cmp", pos, op, literal)``, ``("colcmp", a, op, b)``,
+        ``("and" | "or", x, y)``, ``("not", x)``."""
+        raise NotImplementedError
+
+    def check(self, schema, types) -> None:
+        """Raise a `CylonPlanError` where the bound expression cannot be
+        evaluated over columns of ``types`` (the plan's type strings)."""
+
     def __and__(self, other: "Expr") -> "Expr":
         return BoolOp("and", self, other)
 
@@ -63,10 +79,11 @@ class Expr:
 class Value:
     """Unbound integer VALUE expression: ``col("a") * (100 - col("b"))``.
     Operands are columns, other value expressions and integer literals;
-    the operations ``+``, ``-``, ``*``. `LazyTable.with_columns` binds it
-    to a token tree over column positions (`bind_value`), which is what a
-    `Compute` node holds, the fingerprint reads and the lowering
-    evaluates: ``("col", i)``, ``("lit", v)``, ``(op, a, b)``."""
+    the operations ``+``, ``-``, ``*``, and `case_when` (a predicate as
+    1 / 0). `LazyTable.with_columns` binds it to a token tree over column
+    positions (`bind_value`), which is what a `Compute` node holds, the
+    fingerprint reads and the lowering evaluates: ``("col", i)``,
+    ``("lit", v)``, ``(op, a, b)``, ``("case", predicate tokens)``."""
 
     def __add__(self, other):
         return Arith("add", self, other)
@@ -95,48 +112,49 @@ class Arith(Value):
         return f"({self.a!r} {_VALUE_SYMBOL[self.op]} {self.b!r})"
 
 
-_VALUE_SYMBOL = {"add": "+", "sub": "-", "mul": "*"}
+class Case(Value):
+    """``CASE WHEN predicate THEN 1 ELSE 0 END``: an int32 that is 1 where
+    the predicate is true and 0 where it is false or null (so it is never
+    null itself). Built by `case_when`."""
+
+    def __init__(self, pred: "Expr"):
+        if not isinstance(pred, Expr):
+            raise CylonPlanError(
+                f"case_when takes a predicate (col('x') > 3), not {pred!r}",
+                code=Code.TypeError)
+        self.pred = pred
+
+    def __repr__(self):
+        return f"case_when({self.pred!r})"
 
 
-def bind_value(v, resolver) -> tuple:
+def case_when(pred: "Expr") -> Case:
+    """``CASE WHEN pred THEN 1 ELSE 0 END`` as a value expression for
+    `LazyTable.with_columns`: summed, it counts the rows ``pred`` holds
+    on."""
+    return Case(pred)
+
+
+def bind_value(v, resolver, check=None) -> tuple:
     """The token tree of an unbound value expression, column references
-    resolved to positions by ``resolver``."""
+    resolved to positions by ``resolver``; ``check(bound predicate)`` sees
+    every `case_when` predicate once it is bound."""
     if isinstance(v, Col):
         return ("col", int(resolver(v.ref)))
     if isinstance(v, Arith):
-        return (v.op, bind_value(v.a, resolver), bind_value(v.b, resolver))
+        return (v.op, bind_value(v.a, resolver, check),
+                bind_value(v.b, resolver, check))
+    if isinstance(v, Case):
+        bound = v.pred.bind(resolver)
+        if check is not None:
+            check(bound)
+        return ("case", bound.tokens())
     if isinstance(v, int) and not isinstance(v, bool):
         return ("lit", int(v))
     raise CylonPlanError(
-        f"a value expression takes columns, + - * and integer literals "
-        f"(decimals are scaled integers), not {v!r}",
+        f"a value expression takes columns, + - *, case_when and integer "
+        f"literals (decimals are scaled integers), not {v!r}",
         code=Code.TypeError)
-
-
-def value_columns(tokens) -> set:
-    if tokens[0] == "col":
-        return {tokens[1]}
-    if tokens[0] == "lit":
-        return set()
-    return value_columns(tokens[1]) | value_columns(tokens[2])
-
-
-def value_remap(tokens, mapping) -> tuple:
-    if tokens[0] == "col":
-        return ("col", mapping[tokens[1]])
-    if tokens[0] == "lit":
-        return tokens
-    return (tokens[0], value_remap(tokens[1], mapping),
-            value_remap(tokens[2], mapping))
-
-
-def value_repr(tokens) -> str:
-    if tokens[0] == "col":
-        return f"c{tokens[1]}"
-    if tokens[0] == "lit":
-        return str(tokens[1])
-    return (f"({value_repr(tokens[1])} {_VALUE_SYMBOL[tokens[0]]} "
-            f"{value_repr(tokens[2])})")
 
 
 class Col(Value):
@@ -151,10 +169,13 @@ class Col(Value):
         return f"col({self.ref!r})"
 
     def _cmp(self, op, value):
-        if isinstance(value, Col) or isinstance(value, Expr):
+        if isinstance(value, Col):
+            return ColCmp(self.ref, op, value.ref)
+        if isinstance(value, (Expr, Value)):
             raise CylonPlanError(
-                "column-vs-column predicates: compare against "
-                "literals", code=Code.NotImplemented)
+                f"a predicate compares a column with a literal or with "
+                f"another column, not with {value!r}",
+                code=Code.NotImplemented)
         return Cmp(self.ref, op, value)
 
     def __eq__(self, v):  # type: ignore[override]
@@ -212,8 +233,66 @@ class Cmp(Expr):
                     table.row_mask)
         return sub._compare(self.value, self.op)._columns[0].data
 
+    def tokens(self) -> tuple:
+        return ("cmp", int(self.pos), str(self.op), self.value)
+
     def __repr__(self):
         return f"c{self.pos} {self.op} {self.value!r}"
+
+
+# the widest column a column-against-column predicate compares: one 32-bit
+# lane a side (a 64-bit column is two word planes where x64 is off)
+_COLCMP_TYPES = ("bool", "int8", "int16", "int32", "uint8", "uint16",
+                 "uint32", "float16", "float32")
+
+
+class ColCmp(Expr):
+    """column <op> column: two columns of ONE fixed-width type at most 32
+    bits wide (integers, floats, dates as their int32 days). Null where
+    either side is null, as `Cmp` treats a null."""
+
+    def __init__(self, a, op: str, b):
+        self.a, self.op, self.b = a, op, b
+
+    def bind(self, resolver) -> "ColCmp":
+        return ColCmp(resolver(self.a), self.op, resolver(self.b))
+
+    def columns(self) -> set:
+        return {self.a, self.b}
+
+    def remap(self, mapping) -> "ColCmp":
+        return ColCmp(mapping[self.a], self.op, mapping[self.b])
+
+    def check(self, schema, types) -> None:
+        ta, tb = str(types[self.a]), str(types[self.b])
+        names = f"{schema[self.a]!r} ({ta}) and {schema[self.b]!r} ({tb})"
+        if STR_TYPE in (ta, tb):
+            raise CylonPlanError(
+                f"compare of two columns: {names}: string columns "
+                f"(dictionary codes of two vocabularies, or varbytes) are "
+                f"not compared with each other; compare each with a "
+                f"literal, or join on them", code=Code.NotImplemented)
+        if ta != tb:
+            raise CylonPlanError(
+                f"compare of two columns: {names} are of two types; cast "
+                f"one on the host", code=Code.TypeError)
+        if ta not in _COLCMP_TYPES:
+            raise CylonPlanError(
+                f"compare of two columns: {names}: a 64-bit column is held "
+                f"as two 32-bit word planes where x64 is off, and a "
+                f"compare of planes is not built (ROADMAP 2a-b); columns "
+                f"of at most 32 bits compare", code=Code.NotImplemented)
+
+    def mask(self, table):
+        from ..data.table import compare_columns
+
+        return compare_columns(table, self.a, self.op, self.b)
+
+    def tokens(self) -> tuple:
+        return ("colcmp", int(self.a), str(self.op), int(self.b))
+
+    def __repr__(self):
+        return f"c{self.a} {self.op} c{self.b}"
 
 
 class BoolOp(Expr):
@@ -231,9 +310,16 @@ class BoolOp(Expr):
     def remap(self, mapping) -> "BoolOp":
         return BoolOp(self.op, self.a.remap(mapping), self.b.remap(mapping))
 
+    def check(self, schema, types) -> None:
+        self.a.check(schema, types)
+        self.b.check(schema, types)
+
     def mask(self, table):
         a, b = self.a.mask(table), self.b.mask(table)
         return (a & b) if self.op == "and" else (a | b)
+
+    def tokens(self) -> tuple:
+        return (str(self.op), self.a.tokens(), self.b.tokens())
 
     def __repr__(self):
         return f"({self.a!r} {self.op} {self.b!r})"
@@ -252,8 +338,14 @@ class Not(Expr):
     def remap(self, mapping) -> "Not":
         return Not(self.a.remap(mapping))
 
+    def check(self, schema, types) -> None:
+        self.a.check(schema, types)
+
     def mask(self, table):
         return ~self.a.mask(table)
+
+    def tokens(self) -> tuple:
+        return ("not", self.a.tokens())
 
     def __repr__(self):
         return f"~{self.a!r}"
@@ -332,12 +424,17 @@ class Project(PlanNode):
 class Filter(PlanNode):
     kind = "filter"
 
-    def __init__(self, child: PlanNode, expr: Expr):
+    def __init__(self, child: PlanNode, expr: Expr, below_join: int = 0):
         super().__init__([child], child.schema, child.types)
         self.expr = expr
+        # conjuncts of this filter that the optimizer moved here through a
+        # Join (`optimizer.pushdown_filters`); the executor counts them
+        self.below_join = int(below_join)
 
     def args_repr(self):
-        return repr(self.expr)
+        return repr(self.expr) + (
+            f", {self.below_join} conjunct(s) pushed below a join"
+            if self.below_join else "")
 
 
 class Compute(PlanNode):

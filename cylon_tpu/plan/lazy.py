@@ -138,16 +138,19 @@ class LazyTable:
             raise CylonPlanError(
                 "filter needs a predicate, e.g. col('x') > 3")
         bound = expr.bind(self._pos)
+        bound.check(self._node.schema, self._node.types)
         return self._wrap(ir.Filter(self._node, bound))
 
     def with_columns(self, columns) -> "LazyTable":
         """Append computed columns: ``{"name": value expression}`` (a
         dict, in its order), each an integer expression over columns and
         literals (``col("price") * (100 - col("discount"))``: decimals are
-        scaled integers and the query carries the scales). A later
-        expression may read an earlier one by its name. The column is an
-        int64 as soon as an operand is, else an int32; it is computed
-        exactly or the query raises (ops/expr.py)."""
+        scaled integers and the query carries the scales), or
+        ``case_when(predicate)``: 1 where the predicate holds, 0 where it
+        is false or null. A later expression may read an earlier one by
+        its name. The column is an int64 as soon as an operand is, else
+        an int32; it is computed exactly or the query raises
+        (ops/expr.py)."""
         names, exprs, types = [], [], []
         schema, all_types = self.schema, list(self._node.types)
 
@@ -160,7 +163,8 @@ class LazyTable:
             if name in schema or name in names:
                 raise CylonPlanError(f"with_columns: {name!r} is a column "
                                      f"already", code=Code.Invalid)
-            tokens = ir.bind_value(value, pos)
+            tokens = ir.bind_value(
+                value, pos, lambda p: p.check(schema + names, all_types))
             types.append(_value_dtype(tokens, all_types))
             all_types.append(types[-1])
             names.append(str(name))

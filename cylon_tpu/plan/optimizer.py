@@ -14,6 +14,12 @@ Pass order is load-bearing:
    `Filter(Compute(x))` → `Compute(Filter(x))` when the predicate reads
    no computed column: the filter is a row mask either way, but below
    the node it is where the next pass (and a reader of EXPLAIN) finds it.
+   `Filter(Join(l, r))`: the filter is split into its conjuncts and each
+   that reads ONE side's columns goes below the join on that side (both
+   sides of an INNER join, the preserved side alone of a LEFT / RIGHT
+   join, neither of a FULL OUTER join: `_JOIN_PUSH_SIDES`); what reads
+   both sides stays above. The pushed filter then meets the rules above,
+   so across chips it lands below the side's `Shuffle` too.
 3. ``prune_projections`` — required-column analysis: columns no
    downstream node references are dropped at the scans (a `Project`
    over the `Scan`), so fewer payload leaves cross the mesh. All
@@ -43,6 +49,7 @@ Pass order is load-bearing:
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Set, Tuple
 
@@ -58,6 +65,7 @@ class PlanStats:
     filters_pushed: int = 0
     columns_pruned: int = 0
     filters_below_compute: int = 0
+    filters_below_join: int = 0   # conjuncts moved through a Join
     joins_broadcast: int = 0
     shuffles_salted: int = 0
     notes: list = field(default_factory=list)
@@ -70,6 +78,9 @@ class PlanStats:
         if self.filters_below_compute:
             adaptive += (f"; filters pushed below computed columns: "
                          f"{self.filters_below_compute}")
+        if self.filters_below_join:
+            adaptive += (f"; conjuncts pushed below a join: "
+                         f"{self.filters_below_join}")
         return (f"shuffles: {self.shuffles_inserted} planned, "
                 f"{self.shuffles_elided} elided; "
                 f"groupbys localized: {self.groupbys_localized}; "
@@ -102,22 +113,82 @@ def insert_shuffles(node: ir.PlanNode, world: int,
 # ---------------------------------------------------------------------------
 
 
+# the sides of a join that a one-sided conjunct of a filter ABOVE it may
+# move to without changing the answer: a row the predicate drops from a
+# preserved side is gone from the result either way; a row it drops from
+# the other side of an outer join would come back as an unmatched row of
+# nulls, which the filter above had dropped
+_JOIN_PUSH_SIDES = {"inner": (0, 1), "left": (0,), "right": (1,)}
+
+
+def _conjuncts(e: ir.Expr) -> list:
+    if isinstance(e, ir.BoolOp) and e.op == "and":
+        return _conjuncts(e.a) + _conjuncts(e.b)
+    return [e]
+
+
+def _conjunction(parts: list) -> ir.Expr:
+    return functools.reduce(lambda a, b: ir.BoolOp("and", a, b), parts)
+
+
+def _push_through_join(node: ir.Filter, stats: PlanStats) -> ir.PlanNode:
+    join = node.children[0]
+    nl = join.children[0].width
+    sides = _JOIN_PUSH_SIDES.get(join.how, ())
+    pushed, above = ([], []), []
+    for e in _conjuncts(node.expr):
+        cols = e.columns()
+        side = 0 if all(p < nl for p in cols) else \
+            1 if all(p >= nl for p in cols) else None
+        if side in sides and cols:
+            pushed[side].append(e)
+        else:
+            above.append(e)
+    if not (pushed[0] or pushed[1]):
+        return node
+    sides_out = list(join.children)
+    for side, parts in enumerate(pushed):
+        if not parts:
+            continue
+        child = join.children[side]
+        shift = {p: p - side * nl for p in range(side * nl,
+                                                 side * nl + child.width)}
+        stats.filters_below_join += len(parts)
+        # the new filter meets the rules above and below again (a Shuffle
+        # marker, a Compute, another Join)
+        sides_out[side] = pushdown_filters(
+            ir.Filter(child, _conjunction(parts).remap(shift),
+                      below_join=len(parts)), stats)
+    # a NEW join, as every other rule here builds: a plan may hold the
+    # one `join` under two parents (`j.filter(p).union(j)`), and the
+    # other parent's branch is not filtered
+    out = ir.Join(sides_out[0], sides_out[1], join.left_on, join.right_on,
+                  join.how, join.algorithm)
+    if not above:
+        return out
+    return ir.Filter(out, _conjunction(above), node.below_join)
+
+
 def pushdown_filters(node: ir.PlanNode, stats: PlanStats) -> ir.PlanNode:
     node.children = [pushdown_filters(c, stats) for c in node.children]
     if isinstance(node, ir.Filter) and \
             isinstance(node.children[0], ir.Shuffle):
         sh = node.children[0]
         # shuffle is schema-identity, so the expr's positions transfer
-        pushed = ir.Filter(sh.children[0], node.expr)
+        pushed = ir.Filter(sh.children[0], node.expr, node.below_join)
         stats.filters_pushed += 1
         return pushdown_filters(ir.Shuffle(pushed, sh.keys), stats)
+    if isinstance(node, ir.Filter) and \
+            isinstance(node.children[0], ir.Join):
+        return _push_through_join(node, stats)
     if isinstance(node, ir.Filter) and \
             isinstance(node.children[0], ir.Compute):
         comp = node.children[0]
         below = comp.children[0]
         if all(p < below.width for p in node.expr.columns()):
             # the child's columns keep their positions under the node
-            pushed = pushdown_filters(ir.Filter(below, node.expr), stats)
+            pushed = pushdown_filters(
+                ir.Filter(below, node.expr, node.below_join), stats)
             stats.filters_below_compute += 1
             return ir.Compute(pushed, comp.names, comp.exprs,
                               comp.out_types)
@@ -143,6 +214,19 @@ def _identity(n: int) -> Dict[int, int]:
     return {i: i for i in range(n)}
 
 
+def _prune_to(node: ir.PlanNode, need: Set[int], stats: PlanStats
+              ) -> Tuple[ir.PlanNode, Dict[int, int]]:
+    """`_prune`, and a `Project` on top where the pruned node still holds
+    columns only IT needed: exactly ``need`` leaves it."""
+    c, m = _prune(node, need, stats)
+    if c.width > len({m[p] for p in need}):
+        keep = sorted({m[p] for p in need})
+        stats.columns_pruned += c.width - len(keep)
+        c = ir.Project(c, keep)
+        m = {p: keep.index(m[p]) for p in need}
+    return c, m
+
+
 def _prune(node: ir.PlanNode, required: Set[int], stats: PlanStats
            ) -> Tuple[ir.PlanNode, Dict[int, int]]:
     """Rewrite ``node`` so its output contains at least ``required``
@@ -165,7 +249,7 @@ def _prune(node: ir.PlanNode, required: Set[int], stats: PlanStats
     if isinstance(node, ir.Filter):
         need = required | node.expr.columns()
         c, m = _prune(node.children[0], need, stats)
-        return ir.Filter(c, node.expr.remap(m)), dict(m)
+        return ir.Filter(c, node.expr.remap(m), node.below_join), dict(m)
 
     if isinstance(node, ir.Compute):
         w = node.children[0].width
@@ -196,23 +280,19 @@ def _prune(node: ir.PlanNode, required: Set[int], stats: PlanStats
 
     if isinstance(node, ir.Shuffle):
         need = required | set(node.keys)
-        c, m = _prune(node.children[0], need, stats)
-        if c.width > len({m[p] for p in need}):
-            # the child kept columns only IT needed (filter predicate
-            # inputs, say) — project them away BEFORE the exchange so
-            # they never cross the mesh
-            keep = sorted({m[p] for p in need})
-            stats.columns_pruned += c.width - len(keep)
-            c = ir.Project(c, keep)
-            m = {p: keep.index(m[p]) for p in need}
+        # the child's own columns (filter predicate inputs, say) are
+        # projected away BEFORE the exchange: they never cross the mesh
+        c, m = _prune_to(node.children[0], need, stats)
         return ir.Shuffle(c, [m[k] for k in node.keys]), dict(m)
 
     if isinstance(node, ir.Join):
         nl = node.children[0].width
         lneed = {p for p in required if p < nl} | set(node.left_on)
         rneed = {p - nl for p in required if p >= nl} | set(node.right_on)
-        l, lm = _prune(node.children[0], lneed, stats)
-        r, rm = _prune(node.children[1], rneed, stats)
+        # ... and before a join: on one chip they would ride through its
+        # sort and its expand as payload, and into the result
+        l, lm = _prune_to(node.children[0], lneed, stats)
+        r, rm = _prune_to(node.children[1], rneed, stats)
         out = ir.Join(l, r, [lm[k] for k in node.left_on],
                       [rm[k] for k in node.right_on], node.how,
                       node.algorithm)

@@ -136,6 +136,10 @@ def preflight_estimates(root: ir.PlanNode) -> Dict[int, dict]:
     return est
 
 
+# the kinds whose output has its child's rows, one for one
+_ROW_KEEPING = ("filter", "project", "compute", "sort")
+
+
 def calibrate_estimates(root: ir.PlanNode, est: Dict[int, dict],
                         world: int) -> Dict[int, dict]:
     """Overlay the statistics warehouse onto a pre-flight estimate map
@@ -152,6 +156,10 @@ def calibrate_estimates(root: ir.PlanNode, est: Dict[int, dict],
       above the static width x row bound, so calibration only relaxes
       false alarms. Entries without qualified stats keep
       ``est_source="static"``.
+
+    A filter, project, compute or sort over a child with a calibrated
+    estimate takes that estimate at its own row width (it has the
+    child's rows), again never above its static bound.
 
     Idempotent (keyed on ``node_fp`` presence), so the service path —
     which estimates at submit time but calibrates at DISPATCH time for
@@ -187,6 +195,23 @@ def calibrate_estimates(root: ir.PlanNode, est: Dict[int, dict],
         if source == "measured":
             e["calibrated_bytes"] = eff
             e["est_source"] = "measured"
+    # a node that keeps its child's rows takes a measured child's
+    # estimate at its own row width: a Compute over a join of filtered
+    # sides is no wider than the join the warehouse has watched, whatever
+    # the static bound (filters keep their input rows) says of it
+    for node in reversed(list(ir.walk(root))):   # children first
+        e = est.get(id(node))
+        if node.kind not in _ROW_KEEPING or e is None \
+                or e.get("bytes") is None or "calibrated_bytes" in e:
+            continue
+        child = node.children[0]
+        cb = est.get(id(child), {}).get("calibrated_bytes")
+        if cb is None:
+            continue
+        scaled = -(-cb * _row_width_bytes(node.types)
+                   // _row_width_bytes(child.types))
+        e["calibrated_bytes"] = min(e["bytes"], scaled)
+        e["est_source"] = "measured"
     return est
 
 
@@ -234,6 +259,11 @@ class NodeMeasure:
     #                                lands as
     salted: bool = False           # this node's exchange ran the
     #                                hot-key salted (sub-bucketed) path
+    compacted: List[tuple] = field(default_factory=list)  # (slots in,
+    #                                live rows, capacity out) of each
+    #                                input this node's lowering cut to
+    #                                its live rows on the device first
+    #                                (``plan.compact``)
 
     @property
     def shuffles(self) -> int:
@@ -262,10 +292,12 @@ class NodeMeasure:
         algo = f", algo={self.join_algorithm}" \
             if self.join_algorithm is not None else ""
         salt = ", salted" if self.salted else ""
+        cut = "".join(f", compacted={a}->{b} rows in {c} slots"
+                      for a, b, c in self.compacted)
         return (f"{self.desc}{pb}  (actual time={self.ms:.2f} ms, "
                 f"rows={self.rows}, bytes={_human_bytes(self.bytes)}"
                 f"{est}, shuffles={self.shuffles}{algo}{salt}{part}"
-                f"{sk}){mem}{rt}")
+                f"{cut}{sk}){mem}{rt}")
 
     def to_dict(self) -> dict:
         return {
@@ -283,6 +315,7 @@ class NodeMeasure:
             "partition_path": self.partition_path,
             "join_algorithm": self.join_algorithm,
             "salted": self.salted,
+            "compacted": [list(c) for c in self.compacted],
             "shuffles": self.shuffles, "labels": list(self.labels),
             "skew": dict(self.skew) if self.skew is not None else None,
             "children": [c.to_dict() for c in self.children],
@@ -374,6 +407,7 @@ def build_measures(node: ir.PlanNode, recs: Dict[int, object],
     part = None
     algo = None
     salted = False
+    compacted = []
     if spans is not None:
         ex_spans = [spans[i] for i in own_idx
                     if spans[i].name.startswith("shuffle.exchange")]
@@ -389,10 +423,14 @@ def build_measures(node: ir.PlanNode, recs: Dict[int, object],
                 algo = str(a["join_algorithm"])
             if a.get("salted"):
                 salted = True
+            if a.get("compacted"):      # a ``plan.compact`` span that cut
+                compacted.append((int(a["rows_in"]), int(a["rows_out"]),
+                                  int(a["capacity"])))
     return NodeMeasure(executed=True, ms=r.ms, rows=r.rows,
                        bytes=r.nbytes, labels=own, skew=skew,
                        retries=retries, partition_path=part,
-                       join_algorithm=algo, salted=salted, **base)
+                       join_algorithm=algo, salted=salted,
+                       compacted=compacted, **base)
 
 
 @dataclass
@@ -456,6 +494,7 @@ class PlanReport:
                 "groupbys_localized": self.stats.groupbys_localized,
                 "filters_pushed": self.stats.filters_pushed,
                 "columns_pruned": self.stats.columns_pruned,
+                "filters_below_join": self.stats.filters_below_join,
             }
         if self.memory:
             d["memory"] = dict(self.memory)

@@ -249,6 +249,41 @@ def test_calibrate_estimates_is_idempotent(dist_ctx, monkeypatch):
     assert e["node_fp"] == node_fingerprint(join, 4)
 
 
+def test_a_row_keeping_node_takes_its_measured_childs_estimate(
+        local_ctx, monkeypatch):
+    """A Compute / Sort over a join the warehouse has watched is the
+    join's rows at its own width: its estimate follows the join's, never
+    above its static bound, and `plan.preflight` stops warning of it."""
+    from cylon_tpu.plan import col
+
+    monkeypatch.setenv("CYLON_STATS_MIN_OBS", "1")
+    left, right = _lowmatch_tables(local_ctx)
+
+    def pipe():
+        return (plan.scan(left).join(plan.scan(right), on="k")
+                .with_columns({"s": col("lt-0") + col("rt-2")})
+                .sort("s"))
+
+    root, _ = pipe().optimized()
+    cold = calibrate_estimates(root, preflight_estimates(root), 1)
+    assert all("calibrated_bytes" not in cold[id(n)]
+               for n in plan.ir.walk(root))
+    pipe().execute()
+    root, _ = pipe().optimized()
+    est = calibrate_estimates(root, preflight_estimates(root), 1)
+    by_kind = {n.kind: est[id(n)] for n in plan.ir.walk(root)}
+    join, comp, top = by_kind["join"], by_kind["compute"], by_kind["sort"]
+    assert join["calibrated_bytes"] < join["bytes"] / 16
+    for e in (comp, top):
+        assert e["est_source"] == "measured"
+        assert join["calibrated_bytes"] <= e["calibrated_bytes"] \
+            < e["bytes"] / 16
+    assert "calibrated_bytes" not in by_kind["scan"]
+    again = {k: dict(v) for k, v in est.items()}
+    calibrate_estimates(root, est, 1)
+    assert {k: dict(v) for k, v in est.items()} == again
+
+
 # ---------------------------------------------------------------------------
 # the pinned closed loop: shed/degrade on first sight, measured
 # admission on repeat — sound in both directions

@@ -517,3 +517,65 @@ def test_q1_dense_groupby_compiles(chip):
     # and one more array: 48 B a row, 3.6 GB beside the 3.3 GB table, its
     # copy and 1.2 GB of computed columns
     assert compiled.memory_analysis().temp_size_in_bytes < 50 * n
+
+
+# TPC-H Q12 (PR 46): 75,000,000 lines filtered to 0.52%, compacted to
+# 524,288 slots, joined to 18,750,000 orders
+Q12_LINES, Q12_ORDERS, Q12_CAP = 75_000_000, 18_750_000, 1 << 19
+
+
+def test_q12_compaction_compiles(chip):
+    """The cell's compaction as a TPU runs it: the row mask and two
+    streams (l_orderkey, l_shipmode's codes) of 75,000,000 rows through
+    ONE Pallas pass into 524,288 slots; the outputs are of the capacity,
+    not of the input (`stream_compact`'s ``out_elems``)."""
+    from cylon_tpu.data import table as T
+
+    fn = T._compact_program_fn(Q12_CAP, "stream")
+    i32 = _sds(chip, Q12_LINES, jnp.int32)
+    with jax.enable_x64(False):
+        compiled = fn.lower(_sds(chip, Q12_LINES, jnp.bool_), [i32, i32],
+                            []).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "scatter" not in text and " sort(" not in text
+    assert compiled.memory_analysis().output_size_in_bytes < 16 * Q12_CAP
+    assert T.compact_streams([]) == 0
+
+
+def test_q12_join_compiles(chip):
+    """The cell's join: 18,750,000 orders (key, priority codes) against
+    the compacted lines (key, ship-mode codes; a row mask: the prefix of
+    live rows), 19,274,288 slots through the plan sort, block_rows 64;
+    the key rides once, so the sort as COMPILED has key bits, tag and one
+    payload slot."""
+    ok = _sds(chip, Q12_ORDERS, jnp.int32)
+    lk = _sds(chip, Q12_CAP, jnp.int32)
+    cols = ((ok, ok), (None, None), (lk, lk), (None, None))
+    a_desc, b_desc = _join.plan_lane_descs(*cols, _join.JoinType.INNER, 0, 0)
+    keys = ((ok,), (None,), None, (lk,), (None,),
+            _sds(chip, Q12_CAP, jnp.bool_))
+    kw = dict(join_type=_join.JoinType.INNER, a_desc=a_desc, b_desc=b_desc,
+              block_rows=_join.stream_block_rows(Q12_ORDERS, Q12_CAP),
+              interpret=False)
+    assert kw["block_rows"] == 64
+    plan_kw = dict(str_flags=(False,), hash_mode=False, **kw)
+    text = _compiled_text(_join._plan_program_stream_jit, *keys, *cols,
+                          **plan_kw)
+    assert "tpu_custom_call" in text
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    assert len(sorts) == 1, sorts
+    operands = re.search(r"\bsort\((.*?)\), dimensions=", sorts[0]).group(1)
+    assert operands.count("%") == 3, sorts[0]
+    with jax.enable_x64(False):
+        plan_out = jax.eval_shape(
+            lambda *a: _join._plan_program_stream_impl(*a, **plan_kw),
+            *keys, *cols)
+    counts, a_streams, b_streams = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        plan_out)
+    cap_e = _join.stream_expand_capacity(390_564, kw["block_rows"])
+    assert cap_e == Q12_CAP
+    text = _compiled_text(_join._materialize_program_stream_jit, counts,
+                          a_streams, b_streams, *cols, cap_e=cap_e, **kw)
+    assert "tpu_custom_call" in text
