@@ -1091,7 +1091,7 @@ def _compact_streams(nstreams, BR, mask_ref, streams, out_refs, cnt_ref,
 # O(n log n) (96-192 ms for a 33M-row multi-operand sort on v5e) where
 # the problem only needs a counting sort. These two kernels replace it
 # with the SURVEY §7 shape: one histogram pass and one scatter pass,
-# both bandwidth-bound sequential HBM streams.
+# both sequential HBM streams.
 #
 # * ``partition_hist``   — pass 1: streams the target-id blocks once and
 #   emits the per-block × per-bucket histogram. Summed over blocks it is
@@ -1108,11 +1108,47 @@ def _compact_streams(nstreams, BR, mask_ref, streams, out_refs, cnt_ref,
 #   same pass as a u32 leg, so one kernel materializes the whole
 #   partition (varbytes word legs included).
 #
-# Traffic: pass 2 re-streams the input once per bucket (blocked
-# prefetch), so the pair costs ~(W+2) elementwise-priced passes — a win
-# over the sort up to W≈16 (shuffle routes by world size; empty-bucket
-# appends skip their DMA entirely, so clustered/skewed inputs pay less).
+# Cost: pass 2 visits every block once a bucket, and its time goes with
+# those visits. A visit that finds rows of its bucket pays a fixed
+# ~1.6 us of latency (one serial chain: scan, count to a scalar, the
+# pointer through SMEM, the staged passes each waiting for the roll
+# before, a DMA started and waited for) whatever the block's height,
+# so the block is as TALL as VMEM allows (`partition_block_rows`: 256
+# rows up to 10 legs, fewer for wider payloads): the passes of its 32
+# vregs an array overlap and the step runs at what its XLU and VALU
+# slots carry, 0.165 ns a row and bucket at 2 legs (PERF.md section 6,
+# PR 47; at 32 rows a block, before, the fixed part was 53 of the 64 ms
+# the kernel took a join-w4 query). The input is re-streamed once a
+# bucket, W + 1 reads for one write: a win over the sort up to W≈16
+# (shuffle routes by world size; a visit that finds no row of its
+# bucket skips compaction and DMA, so clustered inputs pay less).
 # ---------------------------------------------------------------------------
+
+# VMEM the partition kernel's blocks may take: the id block and a block
+# a leg, each double-buffered by the pipeline, and a write window a leg
+# (the compaction's own temporaries come to as much again, inside
+# Mosaic's 16 MiB)
+_PARTITION_VMEM_BYTES = 4 << 20
+# taller buys nothing: 13.23 ms at 256 and at 512 rows for join-w4's 16M
+# rows, 2 legs (the passes go from 15 to 16, the compile from 2 to 4 s)
+_PARTITION_MAX_BLOCK_ROWS = 256
+
+
+def partition_block_rows(legs: int) -> int:
+    """Rows of a `partition_scatter` block for a payload of ``legs`` u32
+    legs: the tallest power of two from 32 to 256 whose blocks fit the
+    VMEM budget (256 up to 10 legs, 128 to 20, 64 to 42, then 32)."""
+    per_row = (2 * (1 + legs) + legs) * LANES * 4
+    fit = max(_PARTITION_VMEM_BYTES // per_row, 32)
+    return min(1 << (fit.bit_length() - 1), _PARTITION_MAX_BLOCK_ROWS)
+
+
+def partition_scatter_steps(n: int, nbuckets: int, legs: int) -> int:
+    """Grid steps `partition_scatter` takes over n rows: every block
+    once a bucket (what the host adds to
+    ``cylon_partition_steps_total`` a dispatch)."""
+    span = partition_block_rows(legs) * LANES
+    return nbuckets * max(-(-n // span), 1)
 
 
 def partition_hist(t_s: jnp.ndarray, nbuckets: int, block_rows: int = 32,
@@ -1162,7 +1198,7 @@ def partition_hist(t_s: jnp.ndarray, nbuckets: int, block_rows: int = 32,
 
 
 def partition_scatter(t_s: jnp.ndarray, streams: Sequence[jnp.ndarray],
-                      nbuckets: int, block_rows: int = 32,
+                      nbuckets: int, block_rows: Optional[int] = None,
                       interpret: bool = False
                       ) -> Tuple[jnp.ndarray, ...]:
     """Stable counting scatter of u32 streams into bucket-contiguous
@@ -1177,11 +1213,12 @@ def partition_scatter(t_s: jnp.ndarray, streams: Sequence[jnp.ndarray],
     Grid is (nbuckets, blocks), bucket-major; grid order on TPU is
     sequential, so the single carried write pointer makes the appends a
     stable counting sort. A (bucket, block) pair with no matching rows
-    skips its compaction and DMA entirely.
+    skips its compaction and DMA entirely. ``block_rows`` None: as tall
+    as the legs allow (`partition_block_rows`); a test passes a height.
     """
     n = t_s.shape[0]
-    BR = block_rows
     L = len(streams)
+    BR = partition_block_rows(L) if block_rows is None else block_rows
     assert BR % 8 == 0 and BR >= 8
     assert 1 <= nbuckets <= LANES
     assert L >= 1

@@ -280,16 +280,32 @@ def partition_path_label(part: str) -> str:
     return "sort" if part == "sort" else "pallas"
 
 
-def _record_partition(sp, *parts: str) -> None:
+def _payload_legs(payload) -> int:
+    """The u32 legs `_leg_split` cuts a payload's leaves into: one a
+    column of at most 4 bytes, two an 8-byte one."""
+    return sum(int(np.prod(x.shape[1:]))
+               * (2 if np.dtype(x.dtype).itemsize == 8 else 1)
+               for x in jax.tree.leaves(payload))
+
+
+def _record_partition(sp, world: int, *sides) -> None:
     """Observability for the partition-path decisions of one dispatch
-    (one per exchange, two for a fused pair): the
-    cylon_partition_path_total counter per side, and ONE
+    (one ``(part, payload)`` per exchange, two for a fused pair): the
+    cylon_partition_path_total counter per side, the grid steps a
+    shard's `partition_scatter` takes over the side
+    (cylon_partition_steps_total: 0 where the sort partitions), and ONE
     partition_path span attr EXPLAIN ANALYZE folds per node ("mixed"
     when a pair's sides differ)."""
-    paths = [partition_path_label(p) for p in parts]
+    paths = [partition_path_label(p) for p, _ in sides]
     sp.set(partition_path=paths[0] if len(set(paths)) == 1 else "mixed")
-    for p in paths:
-        _counter("cylon_partition_path_total", {"path": p}).inc()
+    for path, (_, payload) in zip(paths, sides):
+        _counter("cylon_partition_path_total", {"path": path}).inc()
+        steps = 0
+        if path == "pallas":
+            rows = jax.tree.leaves(payload)[0].shape[0] // world
+            steps = _tpuk.partition_scatter_steps(
+                rows, world + 1, _payload_legs(payload))
+        _counter("cylon_partition_steps_total").inc(steps)
 
 
 def _leg_split(x):
@@ -706,7 +722,8 @@ def exchange_pair(payload1, targets1, emit1, counts1,
         if pair_stats is not None:
             sp.set(**pair_stats.span_attrs())
         # one decision per side; the fused program partitions both
-        _record_partition(sp, part1, part2)
+        _record_partition(sp, world, (part1, payload1),
+                          (part2, payload2))
         res = _launch_exchange(
             lambda: _exchange_padded_pair_fn(ctx.mesh, b1, b2,
                                              part1, part2)(
@@ -1039,7 +1056,7 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
             sp.set(**skew_stats.span_attrs())
         if padded_ok:
             sp.set(block=block_p)
-            _record_partition(sp, part)
+            _record_partition(sp, world, (part, payload))
             if chunks > 1:
                 out, new_emit, counts_in = _dispatch_chunked(
                     ctx, block_p, cb, chunks, payload, targets, emit,

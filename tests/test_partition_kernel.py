@@ -111,20 +111,92 @@ def test_partition_hist_matches_reference():
         assert np.array_equal(hist, ref), (n, w)
 
 
-def test_partition_scatter_is_the_stable_sort_permutation():
+def _targets(kind, n, w, rng):
+    """Bucket ids for one scatter case; the LAST bucket is the dead
+    rows' (`_kernel_partition` keys them ``world``)."""
+    if kind == "uniform":
+        return rng.integers(0, w, n)
+    if kind == "empty_bucket":        # no row of bucket 2
+        t = rng.integers(0, w - 1, n)
+        return np.where(t >= 2, t + 1, t)
+    if kind == "all_dead":
+        return np.full(n, w - 1)
+    if kind == "one_live":
+        t = np.full(n, w - 1)
+        t[n // 3] = 1
+        return t
+    if kind == "clustered":           # whole blocks hold one bucket
+        return np.sort(rng.integers(0, w, n))[::-1]
+    if kind == "dead_tail":           # groupby-q5-w4: a live prefix
+        return np.where(np.arange(n) < n // 6,
+                        rng.integers(0, w - 1, n), w - 1)
+    raise AssertionError(kind)
+
+
+# a block is `partition_block_rows(legs)` x 128 rows: 32,768 up to 10
+# legs, 8,192 at 21; block_rows 8 is a test's height, for many blocks
+SCATTER_CASES = [
+    # (n, buckets, legs, targets, block_rows)
+    (1000, 5, 3, "uniform", None),          # under one block
+    (17, 1, 1, "uniform", None),
+    (32767, 5, 2, "uniform", None),         # one row short of a block
+    (32768, 4, 2, "uniform", None),         # a block exactly
+    (32769, 5, 2, "uniform", None),         # one row over
+    (4095, 3, 1, "uniform", 32),            # around the old block
+    (4097, 3, 1, "uniform", 32),
+    (70000, 5, 1, "empty_bucket", None),
+    (40000, 5, 2, "all_dead", None),
+    (40000, 5, 2, "one_live", None),
+    (100000, 5, 2, "clustered", None),      # the skip path
+    (100000, 5, 4, "dead_tail", None),
+    (9000, 17, 2, "uniform", None),         # world 16
+    (5000, 5, 8, "uniform", None),
+    (9000, 3, 21, "uniform", None),         # 64 rows a block
+    (9000, 9, 3, "uniform", 8),             # nine blocks
+]
+
+
+@pytest.mark.parametrize(
+    "n,w,nlegs,kind,block_rows", SCATTER_CASES,
+    ids=[f"{n}-b{w}-l{l}-{k}" + (f"-r{r}" if r else "")
+         for n, w, l, k, r in SCATTER_CASES])
+def test_partition_scatter_is_the_stable_sort_permutation(
+        n, w, nlegs, kind, block_rows):
     import jax.numpy as jnp
 
     rng = np.random.default_rng(2)
-    for n, w in [(1000, 5), (4096, 3), (9000, 9), (17, 1)]:
-        t = rng.integers(0, w, n).astype(np.int32)
-        legs = [rng.integers(0, 1 << 32, n, dtype=np.uint64)
-                .astype(np.uint32) for _ in range(3)]
-        outs = tk.partition_scatter(jnp.asarray(t),
-                                    [jnp.asarray(x) for x in legs], w,
-                                    interpret=True)
-        perm = np.argsort(t, kind="stable")
-        for o, x in zip(outs, legs):
-            assert np.array_equal(np.asarray(o), x[perm]), (n, w)
+    t = _targets(kind, n, w, rng).astype(np.int32)
+    legs = [rng.integers(0, 1 << 32, n, dtype=np.uint64)
+            .astype(np.uint32) for _ in range(nlegs)]
+    outs = tk.partition_scatter(jnp.asarray(t),
+                                [jnp.asarray(x) for x in legs], w,
+                                block_rows=block_rows, interpret=True)
+    perm = np.argsort(t, kind="stable")
+    for o, x in zip(outs, legs):
+        assert np.array_equal(np.asarray(o), x[perm])
+
+
+@pytest.mark.parametrize("legs,rows", [
+    (1, 256), (2, 256), (4, 256), (10, 256), (11, 128), (20, 128),
+    (21, 64), (32, 64), (42, 64), (43, 32), (128, 32)])
+def test_partition_block_rows_fit_the_vmem_budget(legs, rows):
+    """The block is as tall as the legs allow, and no taller than pays:
+    id block and leg blocks double-buffered plus a write window a leg
+    stay inside the budget wherever the floor of 32 rows is not what
+    holds the height."""
+    assert tk.partition_block_rows(legs) == rows
+    held = (2 * (1 + legs) + legs) * rows * tk.LANES * 4
+    assert held <= tk._PARTITION_VMEM_BYTES or rows == 32
+
+
+@pytest.mark.parametrize("n,buckets,legs,steps", [
+    (16_000_000, 5, 2, 2445),       # join-w4, a side: 489 blocks
+    (62_500_000, 5, 4, 9540),       # groupby-q5-w4's partial table
+    (1, 2, 1, 2), (0, 5, 2, 5),     # one block at the least
+    (32768, 17, 2, 17), (32769, 17, 2, 34),
+    (8192, 5, 21, 5), (8193, 5, 21, 10)])
+def test_partition_scatter_steps_are_the_grid(n, buckets, legs, steps):
+    assert tk.partition_scatter_steps(n, buckets, legs) == steps
 
 
 @pytest.mark.parametrize("dtypes", [
@@ -363,6 +435,52 @@ def test_partition_path_counter_and_span_attr(dist_ctx, monkeypatch):
     finally:
         telemetry.remove_sink(sink)
     assert [s["partition_path"] for s in spans] == ["sort", "pallas"]
+
+
+def test_partition_steps_counted_by_the_kernels_grid(dist_ctx,
+                                                     monkeypatch):
+    """`cylon_partition_steps_total` grows once a dispatch on the kernel
+    path by the grid ONE shard's `partition_scatter` takes over the
+    payload, a pure function of the shapes, and by nothing on the sort
+    path (where the family still exists: the benchmark's
+    `partition_steps_per_query` reads 0 there, not "no counter")."""
+    forced_paths.single_shot(monkeypatch)
+    world = dist_ctx.get_world_size()
+    n = 2048
+    payload, targets, emit = _mk_inputs(dist_ctx, n, seed=13,
+                                        extra_dtypes=(np.int64,))
+    counts = _counts(dist_ctx, targets, emit)
+    legs = _shuffle._payload_legs(payload)
+    assert legs == 5      # a, b, m one each; the int64 column two
+
+    def total():
+        return telemetry.metrics_snapshot().get(
+            "cylon_partition_steps_total")
+
+    forced_paths.partition(monkeypatch, "sort")
+    _shuffle.exchange(payload, targets, emit, dist_ctx, counts=counts)
+    t0 = total()
+    assert t0 is not None
+    _shuffle.exchange(payload, targets, emit, dist_ctx, counts=counts)
+    assert total() == t0
+    forced_paths.partition(monkeypatch, "pallas")
+    _shuffle.exchange(payload, targets, emit, dist_ctx, counts=counts)
+    assert total() - t0 == tk.partition_scatter_steps(
+        n // world, world + 1, legs) == world + 1
+
+
+def test_payload_legs_are_what_leg_split_cuts():
+    import jax.numpy as jnp
+
+    payload = {"a": jnp.zeros(8, jnp.int8), "b": jnp.zeros(8, jnp.int16),
+               "c": jnp.zeros(8, jnp.float32), "d": jnp.zeros(8, bool),
+               "e": jnp.zeros(8, jnp.int64), "f": jnp.zeros(8, jnp.float64),
+               "g": jnp.zeros((8, 3), jnp.uint32),
+               "h": jnp.zeros((8, 2), jnp.int64)}
+    for name, x in payload.items():
+        assert _shuffle._payload_legs({name: x}) \
+            == len(_shuffle._leg_split(x)[0]), name
+    assert _shuffle._payload_legs(payload) == 15
 
 
 def test_the_sort_path_keys_a_program_of_its_own(dist_ctx, monkeypatch):

@@ -63,16 +63,21 @@ def _compiled_text(fn, *args, **kw):
         return jitted.lower(*args, **kw).compile().as_text()
 
 
-def test_partition_kernels_compile(chip):
+@pytest.mark.parametrize("n,legs", [
+    (16_000_000, 2),     # join-w4: a side's key and payload
+    (62_500_000, 4),     # groupby-q5-w4: the partial table's four leaves
+    (N, 21),             # a wide table: 64 rows a block
+], ids=["join_w4", "groupby_q5_w4", "wide"])
+def test_partition_kernels_compile(chip, n, legs):
     """The exchange's fused partitioner at world 4 -> 5 buckets (4 live
-    targets + the dead-row tail), two payload legs."""
-    def part(t, a, b):
+    targets + the dead-row tail), at the block height the legs give
+    (`partition_block_rows`: 256 rows at 2 and at 4 legs)."""
+    def part(t, *streams):
         return (tk.partition_hist(t, 5),
-                tk.partition_scatter(t, [a, b], 5))
+                tk.partition_scatter(t, streams, 5))
 
-    text = _compiled_text(part, _sds(chip, N, jnp.int32),
-                          _sds(chip, N, jnp.uint32),
-                          _sds(chip, N, jnp.uint32))
+    text = _compiled_text(part, _sds(chip, n, jnp.int32),
+                          *[_sds(chip, n, jnp.uint32)] * legs)
     assert text.count("tpu_custom_call") >= 2
 
 
