@@ -344,8 +344,8 @@ def four_chips(ctx, ct, n, seed, on_tpu, compiles, world):
     shard_report(out, "result", world)
     stages = cp.count("plan.shuffle")
     spans = [lab for lab in cp.labels if lab.startswith("shuffle.exchange")]
-    # one STAGE moves both join sides: as one pair program, or — past
-    # CYLON_EXCHANGE_CHUNK_BYTES — as one chunked exchange a side
+    # one STAGE moves both join sides: as one pair program, or — where
+    # the comm budget refuses a side's stacks — as one exchange a side
     check(stages == 1 and 1 <= len(spans) <= 2,
           f"ONE exchange stage in the planned run (plan.shuffle x{stages}, "
           f"spans {spans})")

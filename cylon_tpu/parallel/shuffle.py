@@ -50,7 +50,6 @@ from ..resilience import inject as _inject
 from ..resilience import retry as _retry
 from ..telemetry import counted_cache, counter as _counter, \
     host_fetch as _host_fetch, span as _span
-from ..telemetry import knobs as _knobs
 from ..telemetry import skew as _skew
 from ..util import pow2 as _pow2, pow2_floor as _pow2_floor
 
@@ -443,33 +442,60 @@ def _exchange_padded_fn(mesh, block: int, part: str = "sort"):
 
 
 # ---------------------------------------------------------------------------
-# the chunked, double-buffered padded exchange (overlapped blockwise
-# pipeline): the padded payload splits into CYLON_EXCHANGE_CHUNK_BYTES-
-# sized blocks, and chunk N+1's all_to_all is dispatched while chunk N's
-# received rows are still being compacted into the output — JAX async
-# dispatch is the overlap engine, so the host never waits between
-# chunks. Peak comm-buffer HBM per leaf drops from 2*W*block (the
-# single-shot send+recv stacks) to 2*W*chunk_block: the live pair is
-# one in-flight chunk's buffers plus the (donated, reused) accumulator.
-# Chunk geometry derives from the count matrix the host already fetched
-# for block geometry — zero new host syncs.
+# the chunked padded exchange: the padded payload splits into blocks
+# whose send and receive stacks fit the comm budget, one program a
+# chunk, each landing its rows in a donated accumulator — peak
+# comm-buffer HBM per leaf is 2*W*chunk_block where the single-shot
+# program holds 2*W*block. It runs ONLY where the budget refuses the
+# single-shot stacks (`_chunk_plan`): on four v5e chips the wire is
+# 3-4.5 ms a query and there is nothing for a pipeline to hide, while
+# every chunk program copies its whole accumulator (0.97 ms a leaf a
+# program whatever the chunk's rows: PERF.md section 6, PR 44): 64 MiB
+# chunks cost 4.6 / 20.0 / 16.4 ms a query in the three four-chip
+# cells, the chunk programs' whole time (PR 48; the pipeline was built
+# on a CPU in PR 13 to overlap the wire with the landing). Chunk geometry
+# derives from the count matrix and the budget the host already holds
+# for the route — zero new host syncs.
 # ---------------------------------------------------------------------------
 
 
-def _chunk_plan(block: int, world: int, bytes_per_row: int):
-    """(chunk_block, chunks) for a padded exchange with per-(src,dst)
-    ``block``; chunks == 1 means single-shot. Pure host arithmetic over
-    already-known geometry. The chunk block is pow2-floored (its value
-    keys compiled chunk programs — 1 per octave, specialization-clean)
-    and floored again so the pipeline never exceeds MAX_CHUNKS
-    programs."""
-    target = int(_knobs.get("CYLON_EXCHANGE_CHUNK_BYTES"))
-    per_slot = max(int(bytes_per_row), 1) * max(world, 1)
-    cb = _pow2_floor(max(target // per_slot, 1))
+def _budget_block_cap(block: int, world: int, bytes_per_row: int, budget,
+                      buffer_factor: int) -> int:
+    """Halve ``block`` until buffer_factor * world * block * row bytes
+    (``buffer_factor`` is 4 a table the program holds) fits the comm
+    budget; never under 1,024 rows, and untouched where no budget is
+    known — the Allocator analog feeding receive buffers from the pool
+    (arrow_all_to_all.cpp:234-247). Pow2-floored: the result feeds
+    block sizes that key compiled exchange programs — keep them
+    1-per-octave (specialization analysis). ONE copy: the route's block
+    cap and the chunk block are the same arithmetic."""
+    if budget:
+        while block > 1024 and \
+                buffer_factor * world * block * bytes_per_row > budget:
+            block //= 2
+    return _pow2_floor(block)
+
+
+def _chunks_of(block: int, cb: int):
+    """(chunk_block, chunks) for a chunk block of about ``cb`` rows,
+    raised so the pipeline never exceeds MAX_CHUNKS programs; a chunk
+    block that covers the block is the single-shot program."""
     cb = max(cb, _pow2_floor(max(block // MAX_CHUNKS, 1)))
     if cb >= block:
         return block, 1
     return cb, -(-block // cb)
+
+
+def _chunk_plan(block: int, world: int, bytes_per_row: int, budget,
+                buffer_factor: int = 4):
+    """(chunk_block, chunks) for a padded exchange with per-(src,dst)
+    ``block``; chunks == 1 means single-shot: whenever the single-shot
+    stacks fit ``budget`` (`_padded_route`'s own check, so every payload
+    it admitted on that budget) or no budget is known. Else the chunk
+    block is the largest power of two whose stacks fit. Pure host
+    arithmetic over already-known geometry; no knob."""
+    return _chunks_of(block, _budget_block_cap(
+        block, world, max(int(bytes_per_row), 1), budget, buffer_factor))
 
 
 def _chunk_write(axis, world, block, cb, xs, start, out, o):
@@ -684,15 +710,18 @@ def exchange_pair(payload1, targets1, emit1, counts1,
                                       buffer_factor=8)
         ok2, b2, _mb2 = _padded_route(counts2, payload2, world, budget,
                                       buffer_factor=8)
+        # the pair program holds BOTH tables' stacks: one program where
+        # each side's fit the half of the budget its route was held to
+        # (on four v5e chips the fused program beat a chunked exchange a
+        # side by 4.6 ms a query, 20 under skew: PERF.md section 6, PR 48); a
+        # side that must chunk, or cannot go padded, sends both through
+        # exchange()
         chunks = max(
-            _chunk_plan(b1, world, _payload_row_bytes(payload1))[1],
-            _chunk_plan(b2, world, _payload_row_bytes(payload2))[1]) \
+            _chunk_plan(b1, world, _payload_row_bytes(payload1), budget,
+                        buffer_factor=8)[1],
+            _chunk_plan(b2, world, _payload_row_bytes(payload2), budget,
+                        buffer_factor=8)[1]) \
             if ok1 and ok2 else 1
-        # either side big enough to chunk: the overlapped pipeline (each
-        # side chunked through exchange(), counts already fetched) beats
-        # the monolithic pair program whose send+recv stacks for BOTH
-        # tables would be live at once; a side that cannot go padded
-        # sends both through exchange() too
         fused = ok1 and ok2 and chunks == 1
         rsp.set(mode="pair" if fused else "each", block=max(b1, b2),
                 chunks=chunks)
@@ -942,23 +971,6 @@ def count_pair(targets1, emit1, targets2, emit2, ctx: CylonContext):
         lambda: _retry.run_retryable("exchange.count", compute))
 
 
-def _budget_block_cap(payload, world: int, budget, mb: int,
-                      buffer_factor: int) -> int:
-    """Shrink the per-round block cap so buffer_factor * world * block *
-    row_bytes fits the comm budget (pow2-floored) — the Allocator analog
-    feeding receive buffers from the pool
-    (arrow_all_to_all.cpp:234-247)."""
-    bytes_per_row = sum(
-        int(np.dtype(x.dtype).itemsize) * int(np.prod(x.shape[1:]))
-        for x in jax.tree.leaves(payload)) or 4
-    if budget:
-        while mb > 1024 and buffer_factor * world * mb * bytes_per_row                 > budget:
-            mb //= 2
-    # pow2_floor: the cap feeds block sizes that key compiled exchange
-    # programs — keep them 1-per-octave (specialization analysis)
-    return _pow2_floor(mb)
-
-
 def _padded_route(counts, payload, world: int, budget,
                   buffer_factor: int = 4, max_block: int = None):
     """(padded_ok, block) — ONE routing rule shared by exchange() and
@@ -971,7 +983,8 @@ def _padded_route(counts, payload, world: int, budget,
         # rows a chip on 4 chips (block 8M) could never go padded and
         # every such exchange fell to the blockwise sort+scatter rounds
         max_block = max(block_p, MAX_BLOCK) if budget else MAX_BLOCK
-    mb = _budget_block_cap(payload, world, budget, max_block,
+    mb = _budget_block_cap(max_block, world,
+                           _payload_row_bytes(payload) or 4, budget,
                            buffer_factor)
     ok = (world * block_p
           <= PADDED_WASTE_FACTOR * max(_pow2(recv_max), 1)
@@ -1002,11 +1015,13 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
 
     meta = {"mode", "block", "counts_in"} — counts_in is the [world*W]
     sharded per-source receive-count matrix (each shard's own [W] slice),
-    consumed by the varbytes word/row layout reconciliation. Padded-mode
-    exchanges whose payload exceeds CYLON_EXCHANGE_CHUNK_BYTES run as
-    the chunked, double-buffered pipeline (meta gains ``chunks``; the
-    single-shot program is its one-chunk case, and the two are
-    bit-identical on every live row).
+    consumed by the varbytes word/row layout reconciliation. A
+    padded-mode exchange is ONE program wherever its send and receive
+    stacks fit the pool's comm budget (every shape the benchmark holds:
+    one program beat 2-4 chunk programs by 4.6-20 ms a query on four
+    chips, PERF.md section 6, PR 48); where they do not it runs as the
+    chunked pipeline (`_chunk_plan`; meta gains ``chunks``), and the two
+    are bit-identical on every live row.
     ``max_block`` caps the per-round blockwise block size.
     """
     world = ctx.get_world_size()
@@ -1043,7 +1058,7 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
         if padded_ok:
             block = block_p
             part = _partition_path(ctx.mesh, world, payload)
-            cb, chunks = _chunk_plan(block_p, world, row_bytes)
+            cb, chunks = _chunk_plan(block_p, world, row_bytes, budget)
         else:
             block = min(block_p, mb)
             chunks = 1

@@ -190,13 +190,6 @@ declare("CYLON_FLIGHT_MAX_DUMPS", 32, "int",
         "crash-dump files kept in CYLON_FLIGHT_DIR before oldest-first "
         "rotation", lo=1)
 
-# parallel/shuffle.py (the chunked, double-buffered exchange)
-declare("CYLON_EXCHANGE_CHUNK_BYTES", 1 << 26, "int",
-        "target payload bytes per exchange chunk and per shard "
-        "(across all destinations); the chunk block is pow2-floored "
-        "from it and the chunk count is capped at MAX_CHUNKS per "
-        "exchange", lo=1 << 12)
-
 # plan/
 declare("CYLON_TPU_VERIFY_PLANS", False, "bool",
         "debug assert: re-derive partitioning witnesses over every "

@@ -78,8 +78,12 @@ if "--xla_force_host_platform_device_count" not in \
 # fast, deterministic backoff for the drill
 os.environ.setdefault("CYLON_RETRY_BACKOFF_S", "0.001")
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
+# the chunk pipeline is reached by no budget at a drill's size: the
+# overlap scenario stands in for the function that chooses
+# (tests/forced_paths.py)
+sys.path.insert(0, os.path.join(_ROOT, "tests"))
 
 SCENARIOS = ("compile", "transient", "overlap", "persistent", "shed",
              "degrade", "deadline", "stats", "mislearn", "service")
@@ -246,7 +250,11 @@ def run_seed(seed: int, only=None) -> dict:
     if wants("overlap"):
         nth = 2 + seed % 3
         fp = f"exchange:{nth}:transient"
-        os.environ["CYLON_EXCHANGE_CHUNK_BYTES"] = "4096"
+        import forced_paths
+        from _pytest.monkeypatch import MonkeyPatch
+
+        patch = MonkeyPatch()
+        forced_paths.chunked(patch, 4096)
         inject.arm(fp)
         r0 = _retries(telemetry)
         c0 = telemetry.metrics_snapshot().get(
@@ -257,7 +265,7 @@ def run_seed(seed: int, only=None) -> dict:
             result = p.execute()
         finally:
             inject.disarm()
-            os.environ.pop("CYLON_EXCHANGE_CHUNK_BYTES", None)
+            patch.undo()
         chunks_moved = telemetry.metrics_snapshot().get(
             "cylon_exchange_chunks_total", 0) - c0
         _check(chunks_moved > 0,

@@ -83,14 +83,15 @@ def one_case(seed):
     force_vb = bool(rng.integers(0, 2)) and "str" in kind
     with_nulls = bool(rng.integers(0, 2)) and "str" in kind
 
-    # randomly toggle the overlapped (chunked) exchange: with a tiny
-    # chunk target every padded exchange runs the chunked pipeline,
+    # randomly toggle the chunked exchange: with a tiny chunk target
+    # (forced: no budget asks for one) every padded exchange runs the
+    # chunked pipeline,
     # which must stay bit-identical to the single-shot program on all
     # of the distributed-vs-local comparisons below
     patch = MonkeyPatch()
     overlap = bool(rng.integers(0, 2))
     if overlap:
-        patch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+        forced_paths.chunked(patch, 4096)
     else:
         forced_paths.single_shot(patch)
     # …and, orthogonally, the partition path: "pallas" runs the fused

@@ -7,6 +7,7 @@ that wants the other one patches that function for its own duration
 from cylon_tpu.ops import tpu_kernels as _tk
 from cylon_tpu.parallel import shuffle as _shuffle
 from cylon_tpu.plan import optimizer as _optimizer
+from cylon_tpu.util import pow2_floor as _pow2_floor
 
 _chunk_plan = _shuffle._chunk_plan
 _broadcast_choice = _optimizer.broadcast_choice
@@ -30,12 +31,24 @@ def partition(monkeypatch, part: str) -> None:
 
 
 def single_shot(monkeypatch, on: bool = True) -> None:
-    """The exchange as ONE program whatever its bytes (``on``), or
-    chunked as the bytes decide."""
+    """The exchange as ONE program whatever the pool's budget (``on``),
+    or chunked as the budget decides."""
     monkeypatch.setattr(
         _shuffle, "_chunk_plan",
-        (lambda block, world, bytes_per_row: (block, 1)) if on
-        else _chunk_plan)
+        (lambda block, *_a, **_k: (block, 1)) if on else _chunk_plan)
+
+
+def chunked(monkeypatch, chunk_bytes: int) -> None:
+    """The exchange chunked at ``chunk_bytes`` of payload a chunk and a
+    shard, whatever the pool's budget: the arithmetic by which a
+    constant of bytes decided before PR 48 (64 MiB on the chip, 4096 in
+    the tests), so that the chunk pipeline runs at a test's size."""
+    def plan(block, world, bytes_per_row, *_a, **_k):
+        per_slot = max(int(bytes_per_row), 1) * max(world, 1)
+        return _shuffle._chunks_of(
+            block, _pow2_floor(max(chunk_bytes // per_slot, 1)))
+
+    monkeypatch.setattr(_shuffle, "_chunk_plan", plan)
 
 
 def shuffle_joins_only(monkeypatch, on: bool = True) -> None:

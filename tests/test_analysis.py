@@ -447,21 +447,29 @@ def test_envknobs_real_registry_matches_docs_table():
     assert knobs.default("CYLON_SERVICE_QUEUE_MAX") == 256
 
 
-@pytest.mark.parametrize("name", [
-    "CYLON_PARTITION_KERNEL", "CYLON_EXCHANGE_OVERLAP",
-    "CYLON_JOIN_ALGORITHM"])
-def test_no_knob_chooses_a_distributed_path(name, monkeypatch):
-    """The three switches by which a user chose a code path under
-    `parallel/` and `plan/` went (PR 45): none is declared, reading one
-    is the registry's KeyError, and setting it in the environment
-    changes nothing the registry can see."""
+@pytest.mark.parametrize("name,value", [
+    ("CYLON_PARTITION_KERNEL", "sort"), ("CYLON_EXCHANGE_OVERLAP", "0"),
+    ("CYLON_JOIN_ALGORITHM", "sort"),
+    ("CYLON_EXCHANGE_CHUNK_BYTES", "4096")])
+def test_no_knob_chooses_a_distributed_path(name, value, monkeypatch):
+    """The switches by which a user chose a code path under `parallel/`
+    and `plan/` went (three in PR 45, the chunk's byte target in PR 48):
+    none is declared, reading one is the registry's KeyError, and
+    setting it in the environment changes nothing the registry can see
+    and no exchange's plan."""
+    from cylon_tpu.parallel import shuffle
     from cylon_tpu.telemetry import knobs
 
-    monkeypatch.setenv(name, "sort")
+    plans = [shuffle._chunk_plan(1 << 22, 4, 8, budget)
+             for budget in (None, 1 << 28)]
+    monkeypatch.setenv(name, value)
     assert name not in knobs.KNOBS
     with pytest.raises(KeyError, match="not a declared knob"):
         knobs.get(name)
-    assert len(knobs.KNOBS) == 27
+    assert len(knobs.KNOBS) == 26
+    assert [shuffle._chunk_plan(1 << 22, 4, 8, budget)
+            for budget in (None, 1 << 28)] == plans \
+        == [(1 << 22, 1), (1 << 21, 2)]
 
 
 def test_envknobs_undocumented_knob(tmp_path):

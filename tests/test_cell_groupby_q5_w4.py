@@ -501,11 +501,19 @@ def test_a_partial_mask_rides_only_where_it_says_more_than_the_row_mask(
 
 @pytest.mark.parametrize("row_bytes,plan", [(19, (2 ** 19, 8)),
                                             (16, (2 ** 20, 4))])
-def test_the_cells_chunk_geometry_without_the_masks(row_bytes, plan):
+def test_the_cells_chunk_geometry_without_the_masks(monkeypatch, row_bytes,
+                                                    plan):
     """`groupby-q5-w4`'s partial table crosses in blocks of 2^22 slots a
-    (source, target) pair: at 4 x 4 bytes of data and 3 bool masks a row
-    the exchange cut it into 8 chunk programs of 2^19 rows, at 16 bytes
-    into 4 of 2^20."""
+    (source, target) pair, as ONE program since PR 48: its stacks (4
+    chips x 2^22 slots x 16 bytes, 4 buffers) are 1.07 GB of the 2.5 GB
+    that a quarter of a v5e's free HBM is. Before, a 64 MiB target cut
+    it: at 4 x 4 bytes of data and 3 bool masks a row into 8 chunk
+    programs of 2^19 rows, at 16 bytes into 4 of 2^20."""
+    import forced_paths
     from cylon_tpu.parallel import shuffle
 
-    assert shuffle._chunk_plan(2 ** 22, 4, row_bytes) == plan
+    budget = 10_000_000_000 // 4
+    assert shuffle._chunk_plan(2 ** 22, 4, row_bytes, budget) \
+        == (2 ** 22, 1)
+    forced_paths.chunked(monkeypatch, 1 << 26)
+    assert shuffle._chunk_plan(2 ** 22, 4, row_bytes, budget) == plan

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 import cylon_tpu as ct
+import forced_paths
 from cylon_tpu import telemetry
 from cylon_tpu.parallel import dist_ops
 
@@ -31,7 +32,8 @@ FUSED = [
     "distributed_join.materialize",
     "distributed_join.finish",
 ]
-# past CYLON_EXCHANGE_CHUNK_BYTES each side is a chunked exchange of its
+# where a side must chunk (no payload the budget admits since PR 48:
+# forced, `forced_paths.chunked`) each side is a chunked exchange of its
 # own: the pair's routing sends both on, and each routes for itself
 CHUNKED = FUSED[:9] + [
     "shuffle.route",
@@ -94,12 +96,12 @@ def _ancestors(spans):
 
 
 @pytest.mark.parametrize("chunk_bytes,want", [(None, FUSED),
-                                              ("4096", CHUNKED)],
+                                              (4096, CHUNKED)],
                          ids=["fused-pair", "chunked-each"])
 def test_join_opens_every_leaf_in_order(dist_ctx, monkeypatch, chunk_bytes,
                                         want):
     if chunk_bytes:
-        monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", chunk_bytes)
+        forced_paths.chunked(monkeypatch, chunk_bytes)
     # 4096 rows a side: ~256 a (source, target) pair, over the 128-row
     # chunk that 4096 bytes buy
     spans, out = _joined(dist_ctx, seed=1, n=4096 if chunk_bytes else 256)
@@ -180,7 +182,7 @@ def test_overlap_ratio_is_an_attribute_and_no_histogram(dist_ctx,
                                                        monkeypatch):
     """`cylon_exchange_overlap_ratio` said nothing its span attribute and
     `cylon_exchange_chunks_total` do not."""
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+    forced_paths.chunked(monkeypatch, 4096)
     before = telemetry.metrics_snapshot().get(
         "cylon_exchange_chunks_total", 0)
     left, _right = _tables(dist_ctx, n=4096, seed=7)

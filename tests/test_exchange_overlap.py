@@ -78,8 +78,7 @@ def test_chunked_bit_identical_across_chunk_counts(dist_ctx, monkeypatch,
     counts = _counts(dist_ctx, targets, emit)
     forced_paths.single_shot(monkeypatch)
     base = _run(dist_ctx, payload, targets, emit, counts)
-    forced_paths.single_shot(monkeypatch, False)
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", str(cbytes))
+    forced_paths.chunked(monkeypatch, cbytes)
     c0 = telemetry.metrics_snapshot().get(
         "cylon_exchange_chunks_total", 0)
     out = _run(dist_ctx, payload, targets, emit, counts)
@@ -101,7 +100,7 @@ def test_chunked_bit_identical_odd_remainder(dist_ctx, monkeypatch):
     forced_paths.single_shot(monkeypatch, False)
     monkeypatch.setattr(
         _shuffle, "_chunk_plan",
-        lambda block, w, rb: (3, -(-block // 3)) if block > 3
+        lambda block, *_: (3, -(-block // 3)) if block > 3
         else (block, 1))
     out = _run(dist_ctx, payload, targets, emit, counts)
     _assert_bit_identical(base, out)
@@ -124,8 +123,7 @@ def test_chunked_skew_attrs_match_single_shot(dist_ctx, monkeypatch):
     try:
         forced_paths.single_shot(monkeypatch)
         _run(dist_ctx, payload, targets, emit, counts)
-        forced_paths.single_shot(monkeypatch, False)
-        monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+        forced_paths.chunked(monkeypatch, 4096)
         _run(dist_ctx, payload, targets, emit, counts)
     finally:
         telemetry.remove_sink(sink)
@@ -150,8 +148,7 @@ def test_chunked_per_chunk_retry_bit_identical(dist_ctx, monkeypatch):
     counts = _counts(dist_ctx, targets, emit)
     forced_paths.single_shot(monkeypatch)
     base = _run(dist_ctx, payload, targets, emit, counts)
-    forced_paths.single_shot(monkeypatch, False)
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+    forced_paths.chunked(monkeypatch, 4096)
 
     def retries():
         return sum(v for k, v in telemetry.metrics_snapshot().items()
@@ -188,8 +185,7 @@ def test_rebuild_after_consumed_donation_is_bit_identical(dist_ctx,
 
     payload, targets, emit = _mk_exchange_inputs(dist_ctx, 16384, seed=11)
     counts = _counts(dist_ctx, targets, emit)
-    forced_paths.single_shot(monkeypatch, False)
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+    forced_paths.chunked(monkeypatch, 4096)
 
     def launches():
         return telemetry.metrics_snapshot().get(
@@ -300,8 +296,7 @@ def test_exchange_pair_routes_through_chunked(dist_ctx, monkeypatch):
     forced_paths.single_shot(monkeypatch)
     b1, b2 = _shuffle.exchange_pair(p1, t1, e1, c1, p2, t2, e2, c2,
                                     dist_ctx)
-    forced_paths.single_shot(monkeypatch, False)
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+    forced_paths.chunked(monkeypatch, 4096)
     o1, o2 = _shuffle.exchange_pair(p1, t1, e1, c1, p2, t2, e2, c2,
                                     dist_ctx)
     _assert_bit_identical(b1, o1)
@@ -309,21 +304,91 @@ def test_exchange_pair_routes_through_chunked(dist_ctx, monkeypatch):
     assert o1[3].get("chunks", 1) > 1 or o2[3].get("chunks", 1) > 1
 
 
-@pytest.mark.parametrize("block,world,row_bytes,want", [
-    # the payload fits one chunk's bytes: the single-shot program
-    (2 ** 20, 4, 8, (2 ** 20, 1)),
-    # `join-w4`'s side: 2^22 slots a pair, 8 bytes a row, four targets
-    (2 ** 22, 4, 8, (2 ** 21, 2)),
+# a quarter (`MemoryPool.comm_fraction`) of 10 GB: what a v5e's 15.75 GB
+# leave free beside the live tables of the benchmark's largest cell
+V5E_BUDGET = 10_000_000_000 // 4
+
+
+@pytest.mark.parametrize("block,row_bytes,budget,factor,want", [
+    # the three four-chip cells (world 4): every exchange is one program.
+    # `join-w4`: 2^22 slots a pair, 8 bytes a row, two tables a program
+    (2 ** 22, 8, V5E_BUDGET, 8, (2 ** 22, 1)),
+    # `join-w4-zipf`: S's block doubles beside R's 2^22
+    (2 ** 23, 8, V5E_BUDGET, 8, (2 ** 23, 1)),
+    # `groupby-q5-w4`: the partial table, 16 bytes a row, one table
+    (2 ** 22, 16, V5E_BUDGET, 4, (2 ** 22, 1)),
+    # a budget of a quarter of the stacks: 4 chunks of a power of two
+    (2 ** 22, 8, 4 * 4 * 2 ** 22 * 8 // 4, 4, (2 ** 20, 4)),
+    # ... and what fits to the byte is not chunked
+    (2 ** 22, 8, 4 * 4 * 2 ** 22 * 8, 4, (2 ** 22, 1)),
+    # no budget is known (the CPU): one program whatever the bytes
+    (2 ** 30, 64, None, 4, (2 ** 30, 1)),
     # the chunk block is floored so that no pipeline passes MAX_CHUNKS
-    (2 ** 30, 4, 64, (2 ** 24, 64)),
-], ids=["one-chunk", "join-w4", "max-chunks"])
-def test_chunk_plan_is_decided_by_the_bytes(monkeypatch, block, world,
-                                            row_bytes, want):
-    """`_chunk_plan` reads the geometry and `CYLON_EXCHANGE_CHUNK_BYTES`
-    (64 MiB) and no switch: single-shot is its one-chunk case."""
-    monkeypatch.delenv("CYLON_EXCHANGE_CHUNK_BYTES", raising=False)
-    assert _shuffle._chunk_plan(block, world, row_bytes) == want
+    (2 ** 30, 64, 1 << 20, 4, (2 ** 24, 64)),
+    # `_padded_route`'s floor: a block of 1,024 rows is never cut
+    (2 ** 10, 8, 1, 4, (2 ** 10, 1)),
+], ids=["join-w4", "join-w4-zipf", "groupby-q5-w4", "quarter-budget",
+        "exact-fit", "no-budget", "max-chunks", "route-floor"])
+def test_chunk_plan_is_decided_by_the_budget(block, row_bytes, budget,
+                                             factor, want):
+    """`_chunk_plan` reads the geometry and the pool's comm budget, no
+    knob and no constant of bytes: single-shot wherever the stacks
+    `_padded_route` checked fit, and by the same arithmetic."""
+    assert _shuffle._chunk_plan(block, 4, row_bytes, budget,
+                                buffer_factor=factor) == want
     assert want[1] <= _shuffle.MAX_CHUNKS
+    # what the route admits on a budget, the plan leaves whole
+    payload = {"x": np.zeros((8, row_bytes), np.uint8)}
+    ok, block_p, _mb = _shuffle._padded_route(
+        np.full((4, 4), block), payload, 4, budget, buffer_factor=factor)
+    if ok and budget:
+        assert block_p == block and want[1] == 1
+
+
+@pytest.mark.parametrize("s_pair_rows", [4_000_000, 8_000_000],
+                         ids=["join-w4", "join-w4-zipf"])
+def test_exchange_pair_is_one_program_at_the_cells_geometry(
+        dist_ctx, monkeypatch, s_pair_rows):
+    """16M rows of 8 bytes a side a chip on four chips, on a v5e's
+    budget: `exchange_pair` takes its fused branch (PR 48; the 64 MiB
+    chunk target sent each side through 2-4 chunk programs), one
+    dispatch and one collective launch for the two tables. Routed over
+    shapes alone: the pair program is stood in for."""
+    import jax
+
+    rows = 4 * 16_000_000
+    leaf = jax.ShapeDtypeStruct((rows,), np.int32)
+    side = {"k": leaf, "v": leaf}
+    counts = [np.full((4, 4), n) for n in (4_000_000, s_pair_rows)]
+    built = []
+
+    def pair_fn(mesh, b1, b2, part1, part2):
+        built.append((b1, b2))
+        return lambda *operands: ("o1", "e1", "c1", "o2", "e2", "c2")
+
+    monkeypatch.setattr(_shuffle, "_exchange_padded_pair_fn", pair_fn)
+    monkeypatch.setattr(dist_ctx.memory_pool, "comm_budget_bytes",
+                        lambda: V5E_BUDGET)
+
+    def launches():
+        return telemetry.metrics_snapshot().get(
+            "cylon_collective_launches_total", 0)
+
+    l0 = launches()
+    with telemetry.collect_phases() as cp:
+        r1, r2 = _shuffle.exchange_pair(side, None, None, counts[0],
+                                        side, None, None, counts[1],
+                                        dist_ctx)
+    assert launches() - l0 == 1
+    assert built == [(2 ** 22, _shuffle._pow2(s_pair_rows))]
+    route, = [s for s in cp.spans if s.name == "shuffle.route"]
+    assert (route.attrs["mode"], route.attrs["chunks"],
+            route.attrs["tables"]) == ("pair", 1, 2)
+    assert [s.name for s in cp.spans if s.name.startswith(
+        "shuffle.exchange")] == ["shuffle.exchange_pair"]
+    assert (r1[0], r1[2], r2[0], r2[2]) \
+        == ("o1", 4 * 2 ** 22, "o2", 4 * _shuffle._pow2(s_pair_rows))
+    assert "chunks" not in r1[3] and "chunks" not in r2[3]
 
 
 @pytest.mark.parametrize("overlap", ["0", "1"])
@@ -331,8 +396,10 @@ def test_distributed_join_identical_under_overlap(dist_ctx, monkeypatch,
                                                   overlap):
     """End to end through the dist_ops composition: the distributed
     join's rows are the same chunked and single-shot."""
-    forced_paths.single_shot(monkeypatch, overlap == "0")
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+    if overlap == "0":
+        forced_paths.single_shot(monkeypatch)
+    else:
+        forced_paths.chunked(monkeypatch, 4096)
     rng = np.random.default_rng(17)
     n = 4096
     left = ct.Table.from_pydict(dist_ctx, {

@@ -279,8 +279,7 @@ def test_exchange_bit_identical_chunked_and_odd_geometry(dist_ctx,
     forced_paths.partition(monkeypatch, "sort")
     base = _shuffle.exchange(payload, targets, emit, dist_ctx,
                              counts=counts)
-    forced_paths.single_shot(monkeypatch, False)
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+    forced_paths.chunked(monkeypatch, 4096)
     forced_paths.partition(monkeypatch, "pallas")
     deep = _shuffle.exchange(payload, targets, emit, dist_ctx,
                              counts=counts)
@@ -288,7 +287,7 @@ def test_exchange_bit_identical_chunked_and_odd_geometry(dist_ctx,
     _assert_bit_identical(base, deep)
     monkeypatch.setattr(
         _shuffle, "_chunk_plan",
-        lambda block, w, rb: (3, -(-block // 3)) if block > 3
+        lambda block, *_: (3, -(-block // 3)) if block > 3
         else (block, 1))
     odd = _shuffle.exchange(payload, targets, emit, dist_ctx,
                             counts=counts)

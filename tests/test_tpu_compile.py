@@ -440,6 +440,53 @@ def test_dist_groupby_gather_program_still_compiles(shards4):
     assert re.search(r"\bgather\(", text)
 
 
+# -- the padded exchange as ONE program (PR 48): what the three four-chip
+# cells run since `_chunk_plan` chunks by the budget and not by 64 MiB ---------
+
+@pytest.mark.parametrize("cell,blocks,rows,leaves,collectives", [
+    ("join-w4", (2 ** 22, 2 ** 22), 16_000_000, 2, 6),
+    ("join-w4-zipf", (2 ** 22, 2 ** 23), 16_000_000, 2, 6),
+    ("groupby-q5-w4", (2 ** 22,), 62_500_000, 4, 5),
+])
+def test_single_shot_exchange_compiles_at_the_cells_shapes(
+        shards4, cell, blocks, rows, leaves, collectives):
+    """A join's two sides in the fused pair program (no cell reached it on
+    a TPU before PR 48) and the groupby's partial table in the single
+    program, partitioned by the Pallas kernels, for the described 2x2
+    mesh at the cells' rows a chip: Mosaic and HBM take them, one
+    all_to_all a leaf and one for the counts a table, and of the chunk
+    programs' loops and landings (2 `while`, 4 `dynamic-update-slice` a
+    two-leaf chunk program) only the partition prefix's own are left,
+    one of each a table."""
+    from cylon_tpu.parallel import shuffle
+
+    mesh, shaped = shards4
+    row = shaped(4 * rows)
+
+    def side():
+        payload = {f"c{i}": row(jnp.int32 if i else jnp.float32)
+                   for i in range(leaves)}
+        return payload, row(jnp.int32), row(jnp.bool_)
+
+    if len(blocks) == 2:
+        fn = shuffle._exchange_padded_pair_fn(mesh, *blocks, "pallas",
+                                              "pallas")
+        operands = side() + side()
+    else:
+        fn = shuffle._exchange_padded_fn(mesh, blocks[0], "pallas")
+        operands = side()
+    with jax.enable_x64(False):
+        compiled = fn.lower(*operands).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * len(blocks)
+    assert len(re.findall(r" all-to-all\(", text)) == collectives
+    for opcode in ("while", "dynamic-update-slice"):
+        assert len(re.findall(rf" {opcode}\(", text)) == len(blocks), opcode
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes \
+        + mem.argument_size_in_bytes < 6 * 10 ** 9, mem
+
+
 # -- TPC-H Q1 (PR 42): the expression programs and the dense table over two
 # keys and 64-bit limb streams, at the cell's 75,004,738 rows ---------------
 

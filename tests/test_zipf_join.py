@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import cylon_tpu as ct
+import forced_paths
 from cylon_tpu import plan, telemetry
 from cylon_tpu.parallel import dist_ops, shard
 from cylon_tpu.util import pow2
@@ -119,8 +120,9 @@ def _host_syncs():
             if k.startswith("cylon_host_syncs_total")}
 
 
-# exponent, chunk bytes (None: the default, one fused pair program at this
-# size; 4096: both sides through the chunked pipeline, the chip's route)
+# exponent, chunk bytes (None: the default, one fused pair program, the
+# chip's route since PR 48; 4096: both sides through the chunked pipeline,
+# forced as `forced_paths.chunked` does, the chip's route before)
 PADDED = [(0.0, 4096), (1.05, 4096), (1.25, 4096), (1.05, None)]
 
 
@@ -128,7 +130,7 @@ PADDED = [(0.0, 4096), (1.05, 4096), (1.25, 4096), (1.05, None)]
 def test_planned_join_equals_reference_on_the_padded_route(
         dist_ctx, monkeypatch, exponent, chunk_bytes):
     if chunk_bytes is not None:
-        monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", str(chunk_bytes))
+        forced_paths.chunked(monkeypatch, chunk_bytes)
     tables = _data(exponent)
     pipe = _query(_place(dist_ctx, tables))
     assert "Shuffle" in pipe.explain()
@@ -211,7 +213,7 @@ def test_exchange_counters_stay_still_on_one_chip(local_ctx):
 def test_a_query_pays_two_host_fetches(dist_ctx, monkeypatch, exponent):
     """The count matrix and the join plan's counts, as at the parent: the
     skew counters ride the matrix that ``count_pair`` already fetched."""
-    monkeypatch.setenv("CYLON_EXCHANGE_CHUNK_BYTES", "4096")
+    forced_paths.chunked(monkeypatch, 4096)
     placed = _place(dist_ctx, _data(exponent, seed=7))
     before = _host_syncs()
     _query(placed).execute()
