@@ -352,6 +352,12 @@ def default_entry_points() -> List[EntryPoint]:
                        (_sds(N, u32),), _sds(N, b), _sds(N, b)),
             factory="_join_plan_fn"),
         EntryPoint(
+            "join_plan_semi", do,
+            lambda m: D(m)._semi_plan_fn(m, _join_type("SEMI")),
+            lambda m: ((_sds(N, u32),), _sds(N, b), _sds(N, b),
+                       (_sds(N, u32),), _sds(N, b), _sds(N, b)),
+            factory="_semi_plan_fn"),
+        EntryPoint(
             "join_materialize", do,
             lambda m: _join_mat_factory(D(m), m),
             lambda m: (_sds(N, i32), _sds(N, i32), _sds(N, i32),
@@ -418,13 +424,20 @@ def default_entry_points() -> List[EntryPoint]:
         EntryPoint(
             "join_mat_stream", do, lambda m: None, lambda m: (),
             factory="_join_mat_stream_fn", tpu_only=True),
+        EntryPoint(
+            "join_plan_semi_stream", do, lambda m: None, lambda m: (),
+            factory="_semi_plan_stream_fn", tpu_only=True),
     ]
     return eps
 
 
-def _join_factory(dist_ops, mesh, jt_name):
+def _join_type(jt_name):
     from ..ops import join as _join
-    return dist_ops._join_plan_fn(mesh, getattr(_join.JoinType, jt_name))
+    return getattr(_join.JoinType, jt_name)
+
+
+def _join_factory(dist_ops, mesh, jt_name):
+    return dist_ops._join_plan_fn(mesh, _join_type(jt_name))
 
 
 def _join_mat_factory(dist_ops, mesh):

@@ -460,7 +460,20 @@ class Table:
              algorithm: str = "auto", **kwargs) -> "Table":
         """Local join; self is the LEFT table (pycylon table.pyx:373-390).
         algorithm: "auto" (default — fastest applicable path), "sort", or
-        "hash" (reference join_config.hpp:25)."""
+        "hash" (reference join_config.hpp:25).
+
+        join_type: "inner", "left", "right", "outer" / "full_outer", and
+        "semi" / "anti" (the LEFT semi and anti join, SQL's ``EXISTS`` /
+        ``NOT EXISTS``; the reference has neither). "semi" keeps a left
+        row when at least one live right row has an equal, non-null key,
+        "anti" when none has: a left row whose key is null is kept by
+        "anti" and dropped by "semi", null keys on the right match
+        nothing. Each kept row comes out once whatever the number of
+        matches, and the result holds only the left's columns, named
+        ``lt-<i>`` as every join names them, at the LEFT side's capacity
+        under a row mask decided on the device: nothing is expanded and
+        the sort path fetches no count (the hash path fetches its
+        collision count)."""
         blk = kwargs.pop("probe_block_rows", None)
         cfg = self._make_join_config(table, join_type, algorithm, kwargs)
         if blk:
@@ -473,7 +486,10 @@ class Table:
         comm="broadcast" replicates ``build_side`` (0=left, 1=right;
         default right) to every shard and probes locally — zero
         all-to-all, the adaptive optimizer's rewrite target for a
-        measured-small build side."""
+        measured-small build side. ``join_type`` as `join`; "semi" and
+        "anti" run on the shuffle path alone: neither side of such a join
+        may be replicated yet, so comm="broadcast" falls back to it, as
+        for a full outer join."""
         from ..parallel import dist_ops
 
         comm = kwargs.pop("comm", "shuffle")
@@ -770,6 +786,8 @@ _JOIN_TYPES = {
     "right": _join.JoinType.RIGHT,
     "outer": _join.JoinType.FULL_OUTER,
     "full_outer": _join.JoinType.FULL_OUTER,
+    "semi": _join.JoinType.SEMI,
+    "anti": _join.JoinType.ANTI,
 }
 
 _JOIN_ALGOS = {"sort": _join.JoinAlgorithm.SORT,
@@ -1193,9 +1211,113 @@ def count_plan_sort(keys, str_flags, n_cols: int, a_desc=None, b_desc=None,
         _join.expand_sweep_rows(block_rows))
 
 
+def _stream_route(alg, lkeys, rkeys, str_flags, join_type) -> Optional[str]:
+    """The path a local join takes for its algorithm hint and key arrays:
+    "sort" (the sort-stream path: one 4-byte or plane-held key), "hash"
+    (the hash-stream path: several key columns, wide keys) or None (the
+    XLA plan)."""
+    if alg != _join.JoinAlgorithm.HASH and _join.stream_plan_applicable(
+            lkeys, rkeys, str_flags, join_type):
+        return "sort"
+    if alg in (_join.JoinAlgorithm.HASH, _join.JoinAlgorithm.AUTO) \
+            and _join.hash_stream_applicable(lkeys, rkeys, str_flags,
+                                             join_type):
+        return "hash"
+    return None
+
+
+def _semi_join_once(left: Table, right: Table,
+                    config: _join.JoinConfig) -> Table:
+    """The local SEMI / ANTI join (`ops/join.JoinType`): the left rows
+    with (without) a live right row of an equal, non-null key, each once,
+    the left's columns only. ONE device program and no second phase:
+
+    * sort path (one 4-byte or plane-held key) and hash path (several
+      key columns, wide keys): the stream plan program in its semi form
+      (`ops/join._plan_program_stream_impl`): the right side rides the
+      sort with its key and tag alone, the plan pass keeps each probe
+      row at most once and compacts the kept rows' lanes in key order;
+      the result has the LEFT side's capacity and a prefix row mask
+      decided on the device. No expand kernel, and on the sort path no
+      fetch at all; the hash path fetches its counts for the collision
+      check, and recomputes exactly on a collision;
+    * XLA path (everything else): `ops/join.semi_plan_program` gives the
+      left table's new row mask in its own row order; the result is the
+      left table under it, with no gather."""
+    from ..data.strings import EXACT_KEY_WORDS
+
+    jt = config.type
+    if config.exact and any(
+            a.is_varbytes and b.is_varbytes
+            and pair_k_words(a, b) > EXACT_KEY_WORDS
+            for a, b in ((left._columns[li], right._columns[rj])
+                         for li, rj in zip(config.left_column_idx,
+                                           config.right_column_idx))):
+        # a long varbytes key joins on its content hash; no matched pair
+        # comes out of a semi join to verify afterwards, so exact=True
+        # joins on shared dictionary codes from the start
+        return _exact_dict_fallback_join(left, right, config)
+    lcols, rcols = align_key_columns(left, right, config.left_column_idx,
+                                     config.right_column_idx)
+    lkeys, lkvalid, str_flags, key_wide = _expanded_keys(lcols, rcols)
+    rkeys, rkvalid, _, _ = _expanded_keys(rcols, lcols)
+    lemit, remit = left.row_mask, right.row_mask
+    ldat = tuple(c.data for c in left._columns)
+    lval = tuple(c.validity for c in left._columns)
+    seq = left._ctx.get_next_sequence()
+    route = _stream_route(config.algorithm, lkeys, rkeys, str_flags, jt)
+    _telemetry.counter("cylon_join_semi_total",
+                       {"kind": jt.name.lower()}).inc()
+    rows = left.capacity + right.capacity
+
+    def _stream(hash_mode: bool):
+        interp = jax.default_backend() != "tpu"
+        wide_key = None if hash_mode else key_wide[0]
+        lkey = None if hash_mode else sole_key_index(
+            lcols, left._columns, config.left_column_idx)
+        a_desc, b_desc = _join.plan_lane_descs(ldat, lval, (), (), jt,
+                                               lkey, None, wide_key)
+        br = _join.stream_block_rows(lkeys[0].shape[-1], rkeys[0].shape[-1])
+        count_plan_sort(lkeys, str_flags, len(ldat), a_desc, b_desc,
+                        hash_mode, rows=rows)
+        with _telemetry.phase("join.semi", seq):
+            counts, lod, lov, emit, lidx = _join.plan_program_stream(
+                lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
+                ldat, lval, (), (), str_flags, jt,
+                a_desc=a_desc, b_desc=b_desc, block_rows=br,
+                hash_mode=hash_mode, interpret=interp, wide_key=wide_key)
+            if hash_mode and int(_telemetry.host_fetch(
+                    "join.count", counts)[3]) > 0:
+                return None  # hash collision: recompute exactly
+        cols = []
+        for i, (d, v, c) in enumerate(zip(lod, lov, left._columns)):
+            v = None if c.validity is None else v
+            if c.is_varbytes:
+                vb = c.varbytes.take(lidx)
+                cols.append(Column(vb.lengths, c.dtype, v, None, f"lt-{i}",
+                                   varbytes=vb))
+            else:
+                cols.append(Column(d, c.dtype, v, c.dictionary, f"lt-{i}"))
+        return Table(cols, left._ctx, emit)
+
+    out = _stream(hash_mode=route == "hash") if route else None
+    if out is None:
+        count_plan_sort(lkeys, str_flags, 0, rows=rows)
+        with _telemetry.phase("join.semi", seq):
+            keep = _join.semi_plan_program(
+                lkeys, lkvalid, lemit, rkeys, rkvalid, remit, str_flags, jt,
+                key_wide=key_wide)
+        out = Table([c.rename(f"lt-{i}")
+                     for i, c in enumerate(left._columns)], left._ctx, keep)
+        out._key_ordered = left._key_ordered
+    return out
+
+
 def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
     from ..data.strings import EXACT_KEY_WORDS, LANE_WORDS_MAX, VarBytes
 
+    if _join.is_semi(config.type):
+        return _semi_join_once(left, right, config)
     lcols, rcols = align_key_columns(left, right, config.left_column_idx,
                                      config.right_column_idx)
     # varbytes alignment may have lifted a dictionary key column: joins
@@ -1266,14 +1388,7 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
         out = _join_once(left, right, sub)
         return _append_unmatched_right(left, right, config, out,
                                        aligned=(lcols, rcols))
-    use_stream = (alg != _join.JoinAlgorithm.HASH
-                  and _join.stream_plan_applicable(lkeys, rkeys, str_flags,
-                                                   config.type))
-    use_hash = (not use_stream
-                and alg in (_join.JoinAlgorithm.HASH,
-                            _join.JoinAlgorithm.AUTO)
-                and _join.hash_stream_applicable(lkeys, rkeys, str_flags,
-                                                 config.type))
+    route = _stream_route(alg, lkeys, rkeys, str_flags, config.type)
 
     def _stream_join(hash_mode: bool):
         from ..parallel.shuffle import _count_cached
@@ -1329,11 +1444,7 @@ def _join_once(left: Table, right: Table, config: _join.JoinConfig) -> Table:
                 a_desc=a_desc, b_desc=b_desc, block_rows=br,
                 interpret=interp, wide_key=wide_key), n_primary
 
-    res = None
-    if use_stream:
-        res = _stream_join(hash_mode=False)
-    elif use_hash:
-        res = _stream_join(hash_mode=True)
+    res = _stream_join(hash_mode=route == "hash") if route else None
     if res is not None:
         (lod, lov, rod, rov, emit, lidx, ridx), n_primary = res
     else:

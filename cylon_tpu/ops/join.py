@@ -46,12 +46,26 @@ from ..util import bucket_cap as _bucket_cap, pow2 as _pow2
 
 
 class JoinType(enum.IntEnum):
-    """Reference: join/join_config.hpp:22 `JoinType`."""
+    """Reference: join/join_config.hpp:22 `JoinType`, which stops at
+    FULL_OUTER. SEMI and ANTI are additions (the reference has neither):
+    the LEFT semi and LEFT anti join, SQL's ``EXISTS`` and ``NOT EXISTS``.
+    SEMI keeps a left row when at least one live right row has an equal,
+    non-null key; ANTI keeps it when none has (a left row whose key is
+    null is kept by ANTI and dropped by SEMI; null keys on the right
+    match nothing). Each kept row comes out once whatever the number of
+    matches, and only the left's columns."""
 
     INNER = 0
     LEFT = 1
     RIGHT = 2
     FULL_OUTER = 3
+    SEMI = 4
+    ANTI = 5
+
+
+def is_semi(join_type: JoinType) -> bool:
+    """The two kinds whose result is a subset of the left rows."""
+    return join_type in (JoinType.SEMI, JoinType.ANTI)
 
 
 class JoinAlgorithm(enum.IntEnum):
@@ -97,6 +111,14 @@ class JoinConfig:
     @staticmethod
     def FullOuterJoin(l, r, algorithm: JoinAlgorithm = JoinAlgorithm.SORT):
         return JoinConfig(JoinType.FULL_OUTER, l, r, algorithm)
+
+    @staticmethod
+    def SemiJoin(l, r, algorithm: JoinAlgorithm = JoinAlgorithm.SORT):
+        return JoinConfig(JoinType.SEMI, l, r, algorithm)
+
+    @staticmethod
+    def AntiJoin(l, r, algorithm: JoinAlgorithm = JoinAlgorithm.SORT):
+        return JoinConfig(JoinType.ANTI, l, r, algorithm)
 
     def GetType(self) -> JoinType:
         return self.type
@@ -288,11 +310,20 @@ def join_plan_keys(lbits, lkv, lemit, rbits, rkv, remit,
     m_at = cum_b - b_before  # valid at a positions: run b's all precede
 
     dest_a = jnp.where(is_a, idx_s, na)
-    lo = jnp.zeros(na, jnp.int32).at[dest_a].set(b_before, mode="drop")
+    semi = is_semi(join_type)
+    if not semi:
+        lo = jnp.zeros(na, jnp.int32).at[dest_a].set(b_before, mode="drop")
     m = jnp.zeros(na, jnp.int32).at[dest_a].set(m_at, mode="drop")
     # dead a rows sharing the all-ones run with live max-key b rows must
     # not match them
     m = jnp.where(live_a, m, 0)
+    if semi:
+        # a semi / anti join reads m alone: nothing is expanded, so no
+        # run start and no build permutation
+        kept = aemit & ((m > 0) if join_type == JoinType.SEMI else (m == 0))
+        counts2 = jnp.stack([kept.sum(dtype=cdt), jnp.zeros((), cdt)])
+        return (counts2, jnp.zeros(na, jnp.int32), m,
+                jnp.zeros(nb, jnp.int32), jnp.zeros(nb, bool))
     bperm = jnp.zeros(nb, jnp.int32).at[
         jnp.where(ib == 1, cum_b - 1, nb)].set(idx_s - na, mode="drop")
 
@@ -439,7 +470,7 @@ def stream_plan_applicable(lkeys, rkeys, str_flags,
     """Host-side check over key arrays (pre-ordered-bits): single 4-byte
     (or dictionary-string) key, or a single 8-byte key held as word
     planes on both sides (data/column.py: two exact key lanes, no hash);
-    INNER/LEFT/RIGHT, both sides non-empty."""
+    INNER/LEFT/RIGHT/SEMI/ANTI, both sides non-empty."""
     if STREAM_PLAN is False or join_type == JoinType.FULL_OUTER:
         return False
     if len(lkeys) != 1:
@@ -540,7 +571,11 @@ def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType,
     the output must not)
     with validity None (then no emitted row had its bits forced to
     all-ones as a dead row). Otherwise the key rides as "d", as any
-    other column."""
+    other column.
+
+    SEMI / ANTI: the build (right) side rides the sort with its key and
+    tag alone, so its descriptor is () and no slot is shared: the key
+    rides once where the LEFT side alone allows it."""
     if join_type == JoinType.RIGHT:
         adat, aval, akey, bdat, bval, bkey = rdat, rval, rkey, ldat, lval, lkey
     else:
@@ -554,7 +589,9 @@ def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType,
             return np.dtype(wide_key).kind in "iu"
         return k.dtype.itemsize == 4 and jnp.issubdtype(k.dtype, jnp.integer)
 
-    once = key_once(adat, aval, akey) and key_once(bdat, bval, bkey)
+    semi = is_semi(join_type)
+    once = key_once(adat, aval, akey) \
+        and (semi or key_once(bdat, bval, bkey))
 
     def side(dat, val, key):
         head = ()
@@ -576,7 +613,7 @@ def plan_lane_descs(ldat, lval, rdat, rval, join_type: JoinType,
                     desc.append((ci, "v"))
         return head + tuple(desc)
 
-    return side(adat, aval, akey), side(bdat, bval, bkey)
+    return side(adat, aval, akey), (() if semi else side(bdat, bval, bkey))
 
 
 def plan_key_lane_count(keys, str_flags) -> int:
@@ -614,7 +651,9 @@ def plan_gathered_column_count(n_cols: int, a_desc=None, b_desc=None) -> int:
     sides) that are materialised through the index-gather fallback and
     not off a sort lane (counted as
     ``cylon_join_gathered_columns_total``): on the stream path those no
-    lane descriptor names, on the XLA plan all of them."""
+    lane descriptor names, on the XLA plan all of them. A SEMI or ANTI
+    join's ``n_cols`` are the left side's alone (``b_desc`` is ()), and
+    its XLA plan gathers none: the caller passes 0."""
     if a_desc is None:
         return n_cols
     return n_cols - sum(len({ci for ci, _ in desc})
@@ -627,7 +666,9 @@ def expand_sweep_rows(block_rows: Optional[int] = None) -> int:
     ``cylon_join_expand_sweep_rows_total``): the slab's sub-window on
     the stream path, whatever the block (before PR 33 the block's whole
     ``block_rows + 8`` window: 72 at 64); 0 on the XLA plan
-    (``block_rows`` None), which materialises by gathers."""
+    (``block_rows`` None), which materialises by gathers, and for a SEMI
+    or ANTI join, which launches no expand kernel (its callers pass
+    None)."""
     if block_rows is None:
         return 0
     from . import tpu_kernels as tk
@@ -691,7 +732,16 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
     row hash instead of raw key bits, so ANY key shape costs two sort
     operands; the true key bits ride as verify lanes and counts[3]
     reports within-run mismatches (64-bit hash collisions) for the
-    caller's exact fallback."""
+    caller's exact fallback.
+
+    SEMI / ANTI: this ONE program is the whole join. The build side
+    rides the sort with its key and tag alone (``b_desc`` is ()), the
+    plan pass keeps each probe row at most once (its multiplicity is
+    ``min(m, 1)`` or ``m == 0``) and compacts no build group, and the
+    kept rows' columns are read off the compacted lanes here: returns
+    ``(counts, lod, lov, emit, lidx)`` at the PROBE side's capacity, the
+    row mask ``emit`` a prefix decided on the device. Nothing is
+    expanded and no count has to reach the host."""
     from . import tpu_kernels as tk
     from .hash import hash2_streams
 
@@ -721,8 +771,11 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
 
     # the join key rides once (plan_lane_descs): no lane of its own here,
     # the sorted key bits stand in slot 0 for both sides after the sort
+    semi = is_semi(join_type)
+    keep = {JoinType.SEMI: "semi", JoinType.ANTI: "anti"}.get(join_type)
     key_once = bool(a_desc) and a_desc[0][1][0] == "k"
-    assert key_once == (bool(b_desc) and b_desc[0][1][0] == "k")
+    assert semi or key_once == (bool(b_desc) and b_desc[0][1][0] == "k")
+    assert not (semi and b_desc), "a semi join reads no build column"
     assert not (key_once and hash_mode), "the hash path sorts by hashes"
     a_lanes = _side_lanes(adat, aval, a_desc)
     b_lanes = _side_lanes(bdat, bval, b_desc)
@@ -756,13 +809,16 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
         res = jax.lax.sort((h1, h2, tag) + tuple(kb_lanes) + tuple(lanes),
                            num_keys=3, is_stable=False)
         nk = len(kb_lanes)
-        return tk.join_plan_stream(
+        planned = tk.join_plan_stream(
             res[0], res[2], na, nb,
             emit_unmatched_a=join_type != JoinType.INNER,
             lanes=res[3 + nk:], n_a_lanes=len(a_lanes),
             n_b_lanes=len(b_lanes), bits2_s=res[1],
             verify_lanes=res[3:3 + nk],
-            block_rows=block_rows, interpret=interpret)
+            block_rows=block_rows, interpret=interpret, keep=keep)
+        if semi:
+            return _semi_result(planned, adat, aval, a_desc, na, wide_key)
+        return planned
 
     # one bit array for a 4-byte key, (hi, lo) for one held as word planes
     assert len(abits) in (1, 2)
@@ -778,12 +834,32 @@ def _plan_program_stream_impl(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
     bits_s, tag_s, lanes_s = res[:nk], res[nk], res[nk + 1:]
     if key_once:
         lanes_s = tuple(bits_s) + tuple(lanes_s)
-    return tk.join_plan_stream(bits_s[0], tag_s, na, nb,
-                               emit_unmatched_a=join_type != JoinType.INNER,
-                               lanes=lanes_s, n_a_lanes=len(a_desc),
-                               n_b_lanes=len(b_desc),
-                               bits2_s=bits_s[1] if nk == 2 else None,
-                               block_rows=block_rows, interpret=interpret)
+    planned = tk.join_plan_stream(
+        bits_s[0], tag_s, na, nb,
+        emit_unmatched_a=join_type != JoinType.INNER,
+        lanes=lanes_s, n_a_lanes=len(a_desc), n_b_lanes=len(b_desc),
+        bits2_s=bits_s[1] if nk == 2 else None,
+        block_rows=block_rows, interpret=interpret, keep=keep)
+    if semi:
+        return _semi_result(planned, adat, aval, a_desc, na, wide_key)
+    return planned
+
+
+def _semi_result(planned, adat, aval, a_desc, na: int,
+                 wide_key: Optional[str]):
+    """The kept probe rows of a SEMI / ANTI plan pass as columns: group A
+    of `tpu_kernels.join_plan_stream(keep=...)` holds, compacted in key
+    order, each kept row's index and payload lanes; cut to the probe
+    side's ``na`` slots they ARE the result, under the prefix row mask
+    ``iota < n_kept``. Columns no lane carries gather by the index."""
+    counts, a_streams, _ = planned
+    emit = jnp.arange(na, dtype=jnp.int32) < counts[1]
+    flat = [x.reshape(-1)[:na] for x in a_streams]
+    lidx = jnp.where(emit, flat[0].astype(jnp.int32), -1)
+    zero = jnp.uint32(0)   # the slots past the kept rows hold no row
+    lanes = [jnp.where(emit, x, zero) for x in flat[1:]]
+    lod, lov = _unpack_side(adat, aval, a_desc, lanes, emit, lidx, wide_key)
+    return counts, lod, lov, emit, lidx
 
 
 _plan_program_stream_jit = partial(
@@ -802,6 +878,55 @@ def plan_program_stream(*args, interpret: bool = False, **kw):
     return _plan_program_stream_jit(*args, interpret=False, **kw)
 
 
+def _unpack_side(dat, val, desc, lane_outs, hit, idx,
+                 wide_key: Optional[str] = None):
+    """One side's output columns from its lanes (``desc`` says which
+    column and kind each lane is; `plan_lane_descs`), ``hit`` marking
+    the rows that hold a row of this side and ``idx`` their source
+    indices (-1 elsewhere), for the columns no lane carries."""
+    from .order import from_ordered_bits_planes, from_ordered_bits_raw
+
+    od: list = [None] * len(dat)
+    ov: list = [None] * len(dat)
+    planes: dict = {}  # column -> its "kh"/"kl" or "dh"/"dl" lanes
+    for (ci, kind), lane in zip(desc, lane_outs):
+        if kind in ("kh", "kl", "dh", "dl"):
+            planes.setdefault(ci, {})[kind] = lane
+        elif kind == "k":
+            # the lane is the key's ordered bits; rows without a hit
+            # read 0 as every lane does (the kernel zeroes them)
+            od[ci] = jnp.where(
+                hit, from_ordered_bits_raw(lane, dat[ci].dtype),
+                jnp.zeros((), dat[ci].dtype))
+            ov[ci] = hit
+        elif kind == "d":
+            od[ci] = lane if dat[ci].dtype == jnp.uint32 \
+                else lane.view(dat[ci].dtype)
+            if val[ci] is None:
+                ov[ci] = hit
+        else:
+            ov[ci] = (lane != 0) & hit
+    for ci, got in planes.items():
+        if "kh" in got:  # the key's ordered lanes; see "k" above
+            zero = jnp.uint32(0)
+            od[ci] = join_planes(*(jnp.where(hit, w, zero) for w in
+                                   from_ordered_bits_planes(
+                                       got["kh"], got["kl"], wide_key)))
+            ov[ci] = hit
+        else:
+            od[ci] = join_planes(got["dh"], got["dl"])
+            if val[ci] is None:
+                ov[ci] = hit
+    fb = [ci for ci in range(len(dat)) if od[ci] is None]
+    if fb:
+        fbd, fbv = gather_columns(
+            tuple(dat[ci] for ci in fb), tuple(val[ci] for ci in fb),
+            idx)
+        for k, ci in enumerate(fb):
+            od[ci], ov[ci] = fbd[k], fbv[k]
+    return tuple(od), tuple(ov)
+
+
 def _materialize_program_stream_impl(counts, a_streams, b_streams,
                                      ldat, lval, rdat, rval,
                                      join_type: JoinType, cap_e: int,
@@ -817,7 +942,6 @@ def _materialize_program_stream_impl(counts, a_streams, b_streams,
     key's through ``wide_key``, its logical dtype); the rest gather
     by the materialized aidx/bidx."""
     from . import tpu_kernels as tk
-    from .order import from_ordered_bits_planes, from_ordered_bits_raw
 
     aidx, bidx, a_lane_outs, b_lane_outs = tk.join_expand_stream(
         counts, a_streams, b_streams, cap_e, block_rows=block_rows,
@@ -831,49 +955,10 @@ def _materialize_program_stream_impl(counts, a_streams, b_streams,
     else:
         adat, aval, bdat, bval = ldat, lval, rdat, rval
 
-    def unpack(dat, val, desc, lane_outs, hit, idx):
-        od: list = [None] * len(dat)
-        ov: list = [None] * len(dat)
-        planes: dict = {}  # column -> its "kh"/"kl" or "dh"/"dl" lanes
-        for (ci, kind), lane in zip(desc, lane_outs):
-            if kind in ("kh", "kl", "dh", "dl"):
-                planes.setdefault(ci, {})[kind] = lane
-            elif kind == "k":
-                # the lane is the key's ordered bits; rows without a hit
-                # read 0 as every lane does (the kernel zeroes them)
-                od[ci] = jnp.where(
-                    hit, from_ordered_bits_raw(lane, dat[ci].dtype),
-                    jnp.zeros((), dat[ci].dtype))
-                ov[ci] = hit
-            elif kind == "d":
-                od[ci] = lane if dat[ci].dtype == jnp.uint32 \
-                    else lane.view(dat[ci].dtype)
-                if val[ci] is None:
-                    ov[ci] = hit
-            else:
-                ov[ci] = (lane != 0) & hit
-        for ci, got in planes.items():
-            if "kh" in got:  # the key's ordered lanes; see "k" above
-                zero = jnp.uint32(0)
-                od[ci] = join_planes(*(jnp.where(hit, w, zero) for w in
-                                       from_ordered_bits_planes(
-                                           got["kh"], got["kl"], wide_key)))
-                ov[ci] = hit
-            else:
-                od[ci] = join_planes(got["dh"], got["dl"])
-                if val[ci] is None:
-                    ov[ci] = hit
-        fb = [ci for ci in range(len(dat)) if od[ci] is None]
-        if fb:
-            fbd, fbv = gather_columns(
-                tuple(dat[ci] for ci in fb), tuple(val[ci] for ci in fb),
-                idx)
-            for k, ci in enumerate(fb):
-                od[ci], ov[ci] = fbd[k], fbv[k]
-        return tuple(od), tuple(ov)
-
-    aod, aov = unpack(adat, aval, a_desc, a_lane_outs, valid, aidx)
-    bod, bov = unpack(bdat, bval, b_desc, b_lane_outs, bhit, bidx)
+    aod, aov = _unpack_side(adat, aval, a_desc, a_lane_outs, valid, aidx,
+                            wide_key)
+    bod, bov = _unpack_side(bdat, bval, b_desc, b_lane_outs, bhit, bidx,
+                            wide_key)
     if join_type == JoinType.RIGHT:
         lod, lov, rod, rov = bod, bov, aod, aov
     else:
@@ -940,6 +1025,29 @@ def plan_program(lkeys, lkvalid, lemit, rkeys, rkvalid, remit, str_flags,
     lbits, lkv, rbits, rkv = _keys_to_bits(lkeys, lkvalid, rkeys, rkvalid,
                                            str_flags, key_wide)
     return join_plan_keys(lbits, lkv, _vm(lemit, lkv.shape[0]),
+                          rbits, rkv, _vm(remit, rkv.shape[0]), join_type)
+
+
+def semi_keep_mask(lbits, lkv, lemit, rbits, rkv, remit,
+                   join_type: JoinType):
+    """Traceable: the left rows a SEMI / ANTI join keeps, as a mask in
+    the left's own row order (`join_plan_keys` holds each probe row's
+    match count ``m`` there: SEMI is ``lemit & (m > 0)``, ANTI ``lemit &
+    (m == 0)``; a dead or null-key row has ``m == 0``). No gather."""
+    _, _, m, _, _ = join_plan_keys(lbits, lkv, lemit, rbits, rkv, remit,
+                                   join_type)
+    return lemit & ((m > 0) if join_type == JoinType.SEMI else (m == 0))
+
+
+@partial(jax.jit, static_argnames=("str_flags", "join_type", "key_wide"))
+def semi_plan_program(lkeys, lkvalid, lemit, rkeys, rkvalid, remit,
+                      str_flags, join_type: JoinType, key_wide=()):
+    """The XLA path's whole SEMI / ANTI join, one compiled program: raw
+    key columns → the left table's new row mask. The result is the left
+    table under that mask; nothing reaches the host."""
+    lbits, lkv, rbits, rkv = _keys_to_bits(lkeys, lkvalid, rkeys, rkvalid,
+                                           str_flags, key_wide)
+    return semi_keep_mask(lbits, lkv, _vm(lemit, lkv.shape[0]),
                           rbits, rkv, _vm(remit, rkv.shape[0]), join_type)
 
 

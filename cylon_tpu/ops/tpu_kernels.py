@@ -346,7 +346,8 @@ def join_plan_stream(bits_s: jnp.ndarray, tag_s: jnp.ndarray, na: int,
                      n_b_lanes: Optional[int] = None,
                      bits2_s: Optional[jnp.ndarray] = None,
                      verify_lanes: Sequence[jnp.ndarray] = (),
-                     block_rows: int = 64, interpret: bool = False):
+                     block_rows: int = 64, interpret: bool = False,
+                     keep: Optional[str] = None):
     """ONE sequential pass over the key-sorted row stream that computes the
     whole join plan — the Pallas replacement for the XLA scatter/gather
     chain in ops/join.join_plan_keys (profiled ~2 s of latency-bound
@@ -384,7 +385,18 @@ def join_plan_stream(bits_s: jnp.ndarray, tag_s: jnp.ndarray, na: int,
     a_lane…) and b_streams = (blist, b_lane…), each a PADDED (rows,
     LANES) u32 block array; entries beyond their count are garbage —
     consumers mask by the counts (join_expand_stream).
+
+    ``keep``: None, or "semi" / "anti", the pass of a semi / anti join:
+    a probe row's multiplicity is ``min(m, 1)`` ("semi": a live row with
+    a match) or ``m == 0`` ("anti": an emitted row with none, a null key
+    included) instead of ``m``, so each probe row is kept at most once
+    and nothing is left to expand. Group A is then (elist, a_lane…): the
+    kept rows' indices and lanes, compacted in key order (no delta, no
+    output start); group B is not compacted at all, because nothing
+    reads the build side past its match count: b_streams is (). counts =
+    [n_kept, n_kept, 0, n_collisions].
     """
+    assert keep in (None, "semi", "anti")
     n = bits_s.shape[0]
     BR = block_rows
     L = len(lanes)
@@ -393,7 +405,7 @@ def join_plan_stream(bits_s: jnp.ndarray, tag_s: jnp.ndarray, na: int,
     # group only compacts ITS lanes (the tail slots are the other side's)
     La = L if n_a_lanes is None else n_a_lanes
     Lb = L if n_b_lanes is None else n_b_lanes
-    nA, nB = 3 + La, 1 + Lb
+    nA, nB = (3 + La, 1 + Lb) if keep is None else (1 + La, 0)
     has_b2 = bits2_s is not None
     nv = len(verify_lanes)
     assert BR % 8 == 0 and BR >= 8
@@ -505,17 +517,23 @@ def join_plan_stream(bits_s: jnp.ndarray, tag_s: jnp.ndarray, na: int,
         bb = jnp.maximum(block_cummax(headv, interpret), carr[2])
         m_at = cumb - bb
         eff_m = jnp.where(live, m_at, 0)
-        if emit_unmatched_a:
+        if keep == "semi":
+            mm = (side & live & (eff_m > 0)).astype(jnp.int32)
+        elif keep == "anti":
+            mm = (side & emit & (eff_m == 0)).astype(jnp.int32)
+        elif emit_unmatched_a:
             mm = jnp.where(side & emit, jnp.maximum(eff_m, 1), 0)
         else:
             mm = jnp.where(side & live, eff_m, 0)
-        offv = block_cumsum(mm, interpret) + carr[1]
-        start = offv - mm
-        delta2 = (bb - start) * 2 + (eff_m > 0).astype(jnp.int32)
+        if keep is None:
+            offv = block_cumsum(mm, interpret) + carr[1]
+            start = offv - mm
+            delta2 = (bb - start) * 2 + (eff_m > 0).astype(jnp.int32)
 
         # carries must update before the compaction writes bump wptrs
         carr[0] = cumb[BR - 1, LANES - 1]
-        carr[1] = offv[BR - 1, LANES - 1]
+        if keep is None:
+            carr[1] = offv[BR - 1, LANES - 1]
         carr[2] = bb[BR - 1, LANES - 1]
         tails[t_prev:t_prev + 1, :] = bits[BR - 1:BR, :]
         tails[t_prev + 1:t_prev + 2, :] = tag[BR - 1:BR, :]
@@ -526,19 +544,25 @@ def join_plan_stream(bits_s: jnp.ndarray, tag_s: jnp.ndarray, na: int,
             tails[vb:vb + 1, :] = vrefs[vi][BR - 1:BR, :]
 
         mA = (mm > 0).astype(jnp.int32)
-        valsA = [idx_u,
-                 jax.lax.bitcast_convert_type(delta2, jnp.uint32),
-                 jax.lax.bitcast_convert_type(start, jnp.uint32)] \
-            + lane_vals[:La]
+        if keep is None:
+            valsA = [idx_u,
+                     jax.lax.bitcast_convert_type(delta2, jnp.uint32),
+                     jax.lax.bitcast_convert_type(start, jnp.uint32)] \
+                + lane_vals[:La]
+        else:
+            valsA = [idx_u] + lane_vals[:La]
         _compact_write(BR, mA, valsA, list(outsA), carr, 4, tails, 0,
                        bufsA, sems, 0, interpret)
-        valsB = [idx_u - jnp.uint32(na)] + lane_vals[:Lb]
-        _compact_write(BR, ib, valsB, list(outsB), carr, 5, tails, nA,
-                       bufsB, sems, nA, interpret)
+        if keep is None:
+            valsB = [idx_u - jnp.uint32(na)] + lane_vals[:Lb]
+            _compact_write(BR, ib, valsB, list(outsB), carr, 5, tails, nA,
+                           bufsB, sems, nA, interpret)
 
         @pl.when(i == pl.num_programs(0) - 1)
         def _():
-            cnt_ref[0] = offv[BR - 1, LANES - 1]  # n_out
+            # n_out: a kept row is one output row
+            cnt_ref[0] = offv[BR - 1, LANES - 1] if keep is None \
+                else carr[4]
             cnt_ref[1] = carr[4]                  # n_emit
             cnt_ref[2] = carr[5]                  # n_blive
             cnt_ref[3] = carr[6]                  # hash collisions

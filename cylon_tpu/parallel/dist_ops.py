@@ -42,7 +42,7 @@ from ..ops import hash as _hash
 from ..ops import join as _join
 from ..ops import order as _order
 from ..ops import setops as _setops
-from ..status import Code, CylonPlanError
+from ..status import Code, CylonError, CylonPlanError
 from ..telemetry import annotate as _annotate, counted_cache, \
     counter as _counter, host_fetch as _host_fetch, ledger as _ledger, \
     span as _span
@@ -713,6 +713,41 @@ def _join_plan_stream_fn(mesh, join_type: _join.JoinType, nk: int,
 
 
 @counted_cache
+def _semi_plan_fn(mesh, join_type: _join.JoinType):
+    """Per-shard SEMI / ANTI join on the XLA plan: the new row mask of
+    the (exchanged) left side in its own row order, nothing else: no
+    counts gather, no materialise program."""
+    spec = P(mesh.axis_names[0])
+
+    def kernel(lbits, lkv, lemit, rbits, rkv, remit):
+        return _join.semi_keep_mask(lbits, lkv, lemit, rbits, rkv, remit,
+                                    join_type)
+
+    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 6,
+                             out_specs=spec))
+
+
+@counted_cache
+def _semi_plan_stream_fn(mesh, join_type: _join.JoinType, a_desc,
+                         block_rows: int):
+    """Per-shard SEMI / ANTI join on the sort-stream path, the plan pass
+    in its semi form under shard_map (`ops/join._plan_program_stream_impl`):
+    each shard's kept left rows compacted in key order at the shard's
+    capacity, under a prefix row mask. ONE program; no count leaves it."""
+    spec = P(mesh.axis_names[0])
+
+    def kernel(lkb, lkv, lemit, rkb, rkv, remit, ldat, lval):
+        _counts, lod, lov, emit, lidx = _join._plan_program_stream_impl(
+            lkb, (lkv,), lemit, rkb, (rkv,), remit, ldat, lval, (), (),
+            (False,), join_type, a_desc=a_desc, b_desc=(),
+            block_rows=block_rows, hash_mode=False, interpret=False)
+        return lod, lov, emit, lidx
+
+    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 8,
+                             out_specs=spec, check_vma=False))
+
+
+@counted_cache
 def _join_mat_stream_fn(mesh, join_type: _join.JoinType, cap_e: int,
                         a_desc, b_desc, block_rows: int):
     spec = P(mesh.axis_names[0])
@@ -1227,6 +1262,14 @@ def distributed_join(left: Table, right: Table,
                     # are row-aligned in the output) — INNER filters false
                     # matches, outer joins redo on dictionary codes
                     exact_pairs.append((li, rj))
+        if exact_pairs and _join.is_semi(config.type):
+            # no matched pair comes out of a semi join to byte-verify
+            raise CylonError(
+                Code.NotImplemented,
+                f"distributed {config.type.name.lower()} join with "
+                f"exact=True on a varbytes key longer than "
+                f"{EXACT_KEY_WORDS} words: join locally, or on a "
+                f"dictionary-encoded key")
 
         left_d = shard.distribute(left, ctx)
         right_d = shard.distribute(right, ctx)
@@ -1311,7 +1354,20 @@ def distributed_join(left: Table, right: Table,
                      else shard.pin(c.validity, ctx) for c in rcols_s)
 
         mode = _dist_stream_mode(lkb, rkb, jt, world)
-        if mode is not None:
+        semi = _join.is_semi(jt)
+        sort_rows = lkb[0].shape[-1] + rkb[0].shape[-1]
+        if semi and mode is not None and mode[0]:
+            # a semi join's hash path would need its collision count on
+            # the host: several key columns take the exact XLA plan
+            mode = None
+        if semi and mode is not None:
+            lkey = table_mod.sole_key_index(lcols2, lcols_s, lidx)
+            a_desc, b_desc = _join.plan_lane_descs(ldat, lval, (), (), jt,
+                                                   lkey, None)
+            br = mode[1]
+            table_mod.count_plan_sort(lkb, (False,), len(ldat), a_desc,
+                                      b_desc, rows=sort_rows)
+        elif mode is not None:
             hash_mode, br = mode
             # the sort path's key bits are the shuffled key column's own
             # ordered bits: it rides once
@@ -1327,6 +1383,43 @@ def distributed_join(left: Table, right: Table,
                                       + rkb[0].shape[-1])
         _sp.set(key_lanes=len(lkb),
                 hash_mode=bool(mode is not None and mode[0]))
+
+    if semi:
+        _counter("cylon_join_semi_total", {"kind": jt.name.lower()}).inc()
+        with _span("distributed_join.plan", seq):
+            if mode is not None:
+                lod, lov, emit, lidx_o = _semi_plan_stream_fn(
+                    ctx.mesh, jt, a_desc, br)(
+                    lkb, lkv, lemit, rkb, rkv, remit, ldat, lval)
+            else:
+                table_mod.count_plan_sort(lkb, (False,) * len(lkb), 0,
+                                          rows=sort_rows)
+                emit = _semi_plan_fn(ctx.mesh, jt)(
+                    lkb, lkv, lemit, rkb, rkv, remit)
+        with _span("distributed_join.finish", seq):
+            names = [f"lt-{i}" for i in range(left_d.column_count)]
+            if mode is not None:
+                # a validity that was None stays None: the row mask says
+                # which slots hold a row
+                cols = _rebuild_columns(
+                    lod, [None if c.validity is None else v
+                          for v, c in zip(lov, lcols_s)], lcols_s, names)
+                for i in lvb:
+                    vb = _varlen_take_sharded(ctx, lcols_s[i].varbytes,
+                                              lidx_o)
+                    cols[i] = Column(vb.lengths, lcols_s[i].dtype,
+                                     cols[i].validity, None, names[i],
+                                     varbytes=vb)
+            else:   # the exchanged left side as it is, under the new mask
+                cols = [c.rename(nm) for c, nm in zip(lcols_s, names)]
+            result = Table(cols, ctx, emit)
+            # the kept rows sit where the exchange put them: the left
+            # side's co-partitioning witness, as INNER and LEFT keep it
+            result._hash_partitioned = shard.partition_signature(
+                lcols2, tuple(lidx), world)
+            left._free_if_unretained()
+            right._free_if_unretained()
+            return _ledger.track(result, "distributed_join")
 
     res = None
     rows_out = None

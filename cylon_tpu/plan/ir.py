@@ -491,17 +491,26 @@ class Join(PlanNode):
     is replicated to every shard inside one gather program and probed
     locally, with NO all-to-all on either side. ``build_side`` is set
     only by the rewrite; a user-forced ``algorithm="broadcast"`` leaves
-    it None until the optimizer picks the side."""
+    it None until the optimizer picks the side.
+
+    ``how`` "semi" / "anti" (SQL's ``EXISTS`` / ``NOT EXISTS``): the
+    schema is the LEFT side's columns only, under the names every join
+    gives its left columns (``lt-0 .. lt-(nl-1)``), and the rows are a
+    subset of the left side's."""
 
     kind = "join"
+    LEFT_ONLY = ("semi", "anti")
 
     def __init__(self, left: PlanNode, right: PlanNode,
                  left_on: Sequence[int], right_on: Sequence[int],
                  how: str = "inner", algorithm: str = "auto"):
         nl = left.width
-        schema = [f"lt-{i}" for i in range(nl)] \
-            + [f"rt-{nl + j}" for j in range(right.width)]
-        super().__init__([left, right], schema, left.types + right.types)
+        schema = [f"lt-{i}" for i in range(nl)]
+        types = list(left.types)
+        if how not in self.LEFT_ONLY:
+            schema += [f"rt-{nl + j}" for j in range(right.width)]
+            types += right.types
+        super().__init__([left, right], schema, types)
         self.left_on = [int(i) for i in left_on]
         self.right_on = [int(j) for j in right_on]
         self.how = how

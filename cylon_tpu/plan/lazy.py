@@ -27,7 +27,8 @@ from .executor import execute as _execute, \
     execute_analyzed as _execute_analyzed
 from .optimizer import PlanStats, optimize as _optimize
 
-_JOIN_TYPES = ("inner", "left", "right", "outer", "full_outer")
+_JOIN_TYPES = ("inner", "left", "right", "outer", "full_outer", "semi",
+               "anti")
 _AGG_OPS = ("sum", "count", "min", "max", "mean")
 
 # Late-bound optimize memo: the service tier's plan/fingerprint cache
@@ -177,6 +178,13 @@ class LazyTable:
     def join(self, other: "LazyTable", join_type: str = "inner",
              algorithm: str = "auto", on=None, left_on=None,
              right_on=None) -> "LazyTable":
+        """``join_type``: "inner", "left", "right", "outer" /
+        "full_outer", or "semi" / "anti": SQL's ``EXISTS`` / ``NOT
+        EXISTS`` (`Table.join` has the null semantics). A semi or anti
+        join's schema is the LEFT side's columns only (``lt-0 ..``): a
+        filter above it cannot name a right column, every conjunct above
+        it goes below it on the left, and the right side is pruned to its
+        key columns."""
         if join_type not in _JOIN_TYPES:
             raise CylonPlanError(
                 f"unsupported join type {join_type!r}")

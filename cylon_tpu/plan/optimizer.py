@@ -117,8 +117,12 @@ def insert_shuffles(node: ir.PlanNode, world: int,
 # move to without changing the answer: a row the predicate drops from a
 # preserved side is gone from the result either way; a row it drops from
 # the other side of an outer join would come back as an unmatched row of
-# nulls, which the filter above had dropped
-_JOIN_PUSH_SIDES = {"inner": (0, 1), "left": (0,), "right": (1,)}
+# nulls, which the filter above had dropped. A semi or anti join's schema
+# is its left side's: every conjunct above it reads left columns alone and
+# goes below it on the left (whether a left row is kept is decided by its
+# key alone, so dropping it before or after the join is the same)
+_JOIN_PUSH_SIDES = {"inner": (0, 1), "left": (0,), "right": (1,),
+                    "semi": (0,), "anti": (0,)}
 
 
 def _conjuncts(e: ir.Expr) -> list:
@@ -290,7 +294,9 @@ def _prune(node: ir.PlanNode, required: Set[int], stats: PlanStats
         lneed = {p for p in required if p < nl} | set(node.left_on)
         rneed = {p - nl for p in required if p >= nl} | set(node.right_on)
         # ... and before a join: on one chip they would ride through its
-        # sort and its expand as payload, and into the result
+        # sort and its expand as payload, and into the result (a semi or
+        # anti join's schema has no right column, so its right side is
+        # cut to its key columns: the subquery's `select *` costs nothing)
         l, lm = _prune_to(node.children[0], lneed, stats)
         r, rm = _prune_to(node.children[1], rneed, stats)
         out = ir.Join(l, r, [lm[k] for k in node.left_on],
@@ -380,7 +386,7 @@ def _propagate(node: ir.PlanNode, world: int) -> Optional[Tuple[int, ...]]:
         dtypes_ok = all(l.types[li] == r.types[rj]
                         for li, rj in zip(node.left_on, node.right_on))
         if dtypes_ok and world > 1:
-            if node.how in ("inner", "left") and \
+            if node.how in ("inner", "left", "semi", "anti") and \
                     _hashable_keys(l, node.left_on):
                 pb = tuple(node.left_on)
             elif node.how == "right" and _hashable_keys(r, node.right_on):

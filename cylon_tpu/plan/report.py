@@ -105,7 +105,8 @@ def preflight_estimates(root: ir.PlanNode) -> Dict[int, dict]:
     plan node — schema widths × propagated row estimates, computed on
     the host BEFORE execution. Deliberately simple upper-bound-ish
     propagation (no key statistics exist): filters keep their input
-    rows, joins sum both sides, groupbys keep child rows. The point is
+    rows, joins sum both sides (a semi or anti join keeps at most its
+    left side's), groupbys keep child rows. The point is
     catching plans whose OUTPUT SCHEMA × input scale already exceeds
     the comm budget — the class of OOM a pre-flight check can see."""
     est: Dict[int, dict] = {}
@@ -117,6 +118,8 @@ def preflight_estimates(root: ir.PlanNode) -> Dict[int, dict]:
         if any(k is None for k in kids):
             return None
         if node.kind == "join":
+            if node.how in node.LEFT_ONLY:
+                return kids[0]
             return kids[0] + kids[1]
         if node.kind == "setop":
             if node.op == "subtract":

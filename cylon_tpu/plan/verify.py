@@ -118,7 +118,7 @@ def derive_witness(node: ir.PlanNode, world: int) -> Optional[Witness]:
         if any(l.types[li] != r.types[rj]
                for li, rj in zip(node.left_on, node.right_on)):
             return None
-        if node.how in ("inner", "left") and \
+        if node.how in ("inner", "left", "semi", "anti") and \
                 _hashable(l.types, node.left_on):
             pos = tuple(node.left_on)
             return pos, tuple(l.types[k] for k in pos)

@@ -27,10 +27,19 @@ def test_benchmark_lists_the_cell_and_its_metrics_by_name(monkeypatch):
     PR 42 wrote it (a file of the benchmark is a `benchmark` PR's to
     edit). Cells are only ever appended, so it is held to the first
     eight: everything it says of `tpch-q1` stands, and what follows the
-    eighth is exactly what later PRs appended, by name."""
+    eighth is what later PRs appended: a list that starts with `tpch-q12`,
+    each cell once and each over a configuration found by name (held to
+    names, not to a count: the next cell appended does not fail it)."""
     whole = _mod.data
-    assert [w["name"] for w in whole("..", "BENCHMARK")["workloads"][8:]] \
-        == ["tpch-q12"]
+    bench = whole("..", "BENCHMARK")
+    appended = bench["workloads"][8:]
+    assert appended and appended[0]["name"] == "tpch-q12"
+    names = [w["name"] for w in bench["workloads"]]
+    assert len(set(names)) == len(names)
+    configs = {c["name"] for c in bench["configs"]}
+    for w in appended:
+        assert w["config"] in configs, w["name"]
+        assert whole("configs", w["config"])["name"] == w["config"]
 
     def first_eight(kind, name):
         found = whole(kind, name)

@@ -48,7 +48,12 @@ SCALES = {"groupby-q5": 0.0025,
           # some 1,560 pass the five predicates; at the default scale the
           # few dozen lines whose order key lies past 2^29 (where the
           # control's float32 keys collide) could miscount to a net of 0
-          "tpch-q12": 0.004}
+          "tpch-q12": 0.004,
+          # tpch-q4 (PR 49): tpch-q12's population: 75,000 orders, of
+          # which some 2,870 fall in the quarter and some 2,630 are
+          # counted; the control overcounts by the few orders without a
+          # late line whose float32 key is a neighbour's with one
+          "tpch-q4": 0.004}
 LIMIT_S = 120         # a run takes 4-8 s
 
 _CHILD = """
@@ -192,6 +197,38 @@ def test_tpch_q12_rehearses_pushed_compacted_and_dense():
     _said, control = rehearse("tpch-q12", 1)
     bad = re.findall(r"compare first query: (\S+) = .* MISMATCH", control)
     assert sorted(bad) == ["high_count_diff", "low_count_diff"]
+
+
+def test_tpch_q4_rehearses_as_a_semi_join():
+    """The cell runs as at 18,750,000 orders: ONE semi join a query and no
+    inner join under it (no count fetch, no materialise phase), the two
+    date conjuncts written above it run below it on ORDERS, the filtered
+    ORDERS counted and compacted before the join sorts them (the SMALL
+    side: the sort is handed the lines and the compacted capacity),
+    LINEITEM counted and left as it is, the dense table, the sort elided;
+    the control refused by its count alone."""
+    said, out = rehearse("tpch-q4", 0)
+    for family in ("cylon_join_semi_total",
+                   "cylon_plan_filters_below_join_total",
+                   "cylon_compact_rows_in_total",
+                   "cylon_compact_rows_out_total",
+                   "cylon_join_plan_sort_rows_total"):
+        assert any(s.split("{")[0] == family for s in said["series"]), family
+    assert 'cylon_join_semi_total{kind="semi"}' in said["series"]
+    for span in ("plan.filter", "plan.project", "plan.compact",
+                 "sync.compact.count", "plan.join", "join.semi",
+                 "plan.groupby", "plan.sort"):
+        assert span in said["phases"], (span, said["phases"])
+    for span in ("sync.join.count", "join.plan", "join.materialize",
+                 "sync.groupby.groups"):
+        assert span not in said["phases"], (span, said["phases"])
+    assert "Join(semi, l[0]=r[0])" in out
+    assert "2 conjunct(s) pushed below a join" in out
+    assert re.search(r"5 groups \(1-URGENT=\d+ 2-HIGH=\d+ 3-MEDIUM=\d+ "
+                     r"4-NOT SPECIFIED=\d+ 5-LOW=\d+\)", out)
+    _said, control = rehearse("tpch-q4", 1)
+    bad = re.findall(r"compare first query: (\S+) = .* MISMATCH", control)
+    assert bad == ["order_count_diff"]
 
 
 def _metric_files():

@@ -30,7 +30,7 @@ def _sds(dtype, n=N):
 
 
 def _stream_join(mesh):
-    """The two Pallas stream factories (TPU-only in the catalog: they do
+    """The three Pallas stream factories (TPU-only in the catalog: they do
     not lower off a TPU, but they trace anywhere): each program with its
     abstract inputs."""
     k, w, e = _sds(jnp.int32), _sds(jnp.float32), _sds(jnp.bool_)
@@ -43,9 +43,16 @@ def _stream_join(mesh):
     _rep, counts, a_streams, b_streams = jax.eval_shape(plan, *plan_in)
     mat = dist_ops._join_mat_stream_fn(
         mesh, _join.JoinType.INNER, 4096, a_desc, b_desc, br)
+    # the semi join's ONE program: the left side's columns alone (PR 49)
+    semi_desc, _ = _join.plan_lane_descs(
+        (k, w), (None, None), (), (), _join.JoinType.SEMI, 0, None)
+    semi = dist_ops._semi_plan_stream_fn(mesh, _join.JoinType.SEMI,
+                                         semi_desc, br)
     return {"_join_plan_stream_fn": (plan, plan_in),
             "_join_mat_stream_fn": (mat, (counts, a_streams, b_streams)
-                                    + cols)}
+                                    + cols),
+            "_semi_plan_stream_fn": (semi, ((k,), e, e, (k,), e, e,
+                                            (k, w), (None, None)))}
 
 
 def _pallas_names(jaxpr, out):

@@ -631,3 +631,78 @@ def test_q12_join_compiles(chip):
     text = _compiled_text(_join._materialize_program_stream_jit, counts,
                           a_streams, b_streams, *cols, cap_e=cap_e, **kw)
     assert "tpu_custom_call" in text
+
+
+# TPC-H Q4 (PR 49): 18,750,000 orders filtered to the quarter's 716,958
+# and compacted to 1,048,576 slots, semi-joined to 75,000,000 lines under
+# a 63% row mask
+Q4_LINES, Q4_CAP = 75_000_000, 1 << 20
+
+
+@pytest.mark.parametrize("how,lines", [("SEMI", Q4_LINES),
+                                       ("ANTI", 1 << 22)])
+def test_q4_semi_join_compiles(chip, how, lines):
+    """(The anti join, one comparison apart, at a size that compiles in
+    seconds.) The cell's semi join, ONE program: the compacted orders (key,
+    priority codes; a row mask: the prefix of live rows) against the lines
+    (key alone; a row mask), 76,048,576 slots through the plan sort,
+    block_rows 64. The build side rides with its key and tag alone and the
+    key rides once, so the sort as COMPILED has key bits, tag and ONE
+    payload slot; one Pallas call (the plan pass; no expand), and the
+    result is of the PROBE side's capacity."""
+    jt = _join.JoinType[how]
+    ok = _sds(chip, Q4_CAP, jnp.int32)
+    lk = _sds(chip, lines, jnp.int32)
+    cols = ((ok, ok), (None, None), (), ())
+    a_desc, b_desc = _join.plan_lane_descs(*cols, jt, 0, None)
+    assert a_desc == ((0, "k"), (1, "d")) and b_desc == ()
+    keys = ((ok,), (None,), _sds(chip, Q4_CAP, jnp.bool_),
+            (lk,), (None,), _sds(chip, lines, jnp.bool_))
+    kw = dict(str_flags=(False,), join_type=jt, a_desc=a_desc, b_desc=b_desc,
+              block_rows=_join.stream_block_rows(Q4_CAP, lines),
+              hash_mode=False, interpret=False)
+    assert kw["block_rows"] == 64
+    with jax.enable_x64(False):
+        compiled = _join._plan_program_stream_jit.lower(
+            *keys, *cols, **kw).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    assert len(sorts) == 1, sorts
+    operands = re.search(r"\bsort\((.*?)\), dimensions=", sorts[0]).group(1)
+    assert operands.count("%") == 3, sorts[0]
+    assert f"u32[{lines + Q4_CAP}]" in sorts[0]
+    # counts, two columns, the row mask, the kept rows' indices: of the
+    # probe side's capacity, not of the sort's
+    assert compiled.memory_analysis().output_size_in_bytes < 24 * Q4_CAP
+    # it fits the chip beside the placed tables and their fresh copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 4_000_000_000
+
+
+def test_semi_join_plan_compiles_across_four_chips(shards4):
+    """The distributed semi join's per-shard program on the sort-stream
+    path at join-w4's shape (2^24 rows a side a chip over the described
+    2x2 mesh): the plan pass in its semi form under shard_map, no
+    collective (no replicated counts gather), every output on the row
+    sharding."""
+    from jax.sharding import PartitionSpec as P
+
+    from cylon_tpu.parallel import dist_ops
+
+    mesh, rows = shards4
+    row = rows(4 * N)
+    u32, i32, b = row(jnp.uint32), row(jnp.int32), row(jnp.bool_)
+    ldat, lval = (i32, i32), (None, None)
+    a_desc, _ = _join.plan_lane_descs(ldat, lval, (), (),
+                                      _join.JoinType.SEMI, 0, None)
+    fn = dist_ops._semi_plan_stream_fn(mesh, _join.JoinType.SEMI, a_desc,
+                                       _join.stream_block_rows(N, N))
+    with jax.enable_x64(False):
+        compiled = fn.lower((u32,), b, b, (u32,), b, b, ldat, lval).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"all-to-all|all-reduce|all-gather|"
+                         r"collective-permute", text)
+    assert len(_sort_lines(text)) == 1
+    for sharding in jax.tree.leaves(compiled.output_shardings):
+        assert sharding.spec == P("shards")
