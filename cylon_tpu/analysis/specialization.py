@@ -16,10 +16,15 @@ recompile storm depends on each key parameter's *cardinality class*:
   bucketing helper (``util.bucket_cap``, ``util.pow2``,
   ``util.pow2_floor``, ``ops.join.stream_expand_capacity``): bounded to
   ~1 bucket per octave of data size. Fine.
+* **fine-bucketed capacity** — a runtime count routed through
+  ``util.capacity``'s mantissa rounding: bounded, but at 16 buckets per
+  octave. Fine ONLY for a factory parameter listed in
+  ``FINE_KEYED_FACTORY_PARAMS`` (the compaction before a join's sort,
+  where every padded slot is sorted: docs/analysis.md); a finding
+  anywhere else.
 * **data-dependent** — a runtime count (``device_get`` fetch,
-  ``.max()``/``.sum()`` reduction) reaching a cache key raw, or through
-  the 16-buckets-per-octave ``util.capacity`` mantissa rounding: one
-  compile per distinct value (or per 4-bit mantissa step). Finding.
+  ``.max()``/``.sum()`` reduction) reaching a cache key raw: one compile
+  per distinct value. Finding.
 * **unbounded** — cardinality not provable from the derivation chain at
   all. Finding.
 
@@ -49,9 +54,9 @@ from .core import (AnalysisContext, Finding, ModuleIndex, attr_chain,
                    build_module_index, register)
 
 # classification lattice (join = max)
-STRUCTURAL, SCHEMA, BUCKETED, DATA, UNBOUNDED = range(5)
+STRUCTURAL, SCHEMA, BUCKETED, FINE, DATA, UNBOUNDED = range(6)
 CLASS_NAMES = ("structural", "schema-bound", "bucketed-capacity",
-               "data-dependent", "unbounded")
+               "fine-bucketed-capacity", "data-dependent", "unbounded")
 
 # recognized bucketing helpers, by package-relative (module, name) and —
 # for single-file fixture trees where imports do not resolve — bare name
@@ -67,6 +72,16 @@ BUCKET_HELPER_NAMES = {"bucket_cap", "_bucket_cap", "pow2", "_pow2",
 # deliberately NOT recognized as bucketing for cache keys (the names are
 # reserved: see docs/analysis.md)
 FINE_ROUNDER_NAMES = {"capacity", "_capacity", "_cap"}
+
+# the (factory, parameter) pairs that may take such a capacity all the
+# same, by the factory's bare name (fixture trees resolve no imports).
+# ONE: the compaction before a join's sort (PR 50). The operator behind
+# it sorts every SLOT it is handed and is itself keyed on its inputs'
+# raw capacities, so an octave here bought one factory fewer programs at
+# up to twice the slots sorted. A policy decision recorded where the
+# policy lives, not a per-line suppression: a new pair belongs here with
+# its measurement.
+FINE_KEYED_FACTORY_PARAMS = {("_compact_program_fn", "cap")}
 
 # package functions known to return schema descriptors (their bodies
 # use nested defs the generic return-trace cannot follow)
@@ -530,7 +545,7 @@ class _Tracer:
                 (target is not None
                  and target[1] in FINE_ROUNDER_NAMES):
             return _Result(
-                DATA, f"{name}(...) — util.capacity's 16-buckets-per-"
+                FINE, f"{name}(...) — util.capacity's 16-buckets-per-"
                       f"octave mantissa rounding is NOT a recognized "
                       f"bucketing helper for cache keys")
         if chain in DATA_CALL_CHAINS:
@@ -777,7 +792,10 @@ def check_specialization(ctx: AnalysisContext) -> List[Finding]:
                     continue
                 res = tracer.classify_arg(arg, cmod, cfn, ccls)
                 census[CLASS_NAMES[res.rank]] += 1
-                if res.rank == DATA:
+                if res.rank == FINE and \
+                        (fname, p.arg) in FINE_KEYED_FACTORY_PARAMS:
+                    continue
+                if res.rank in (FINE, DATA):
                     findings.append(Finding(
                         rule="specialization/unbucketed-capacity",
                         path=cmod.sf.rel, line=call.lineno,

@@ -803,7 +803,6 @@ def _as_agg_op(o) -> _groupby.AggregationOp:
     return _groupby.AggregationOp(int(o))
 
 
-from ..util import bucket_cap as _bucket_cap
 from ..util import capacity as _capacity
 from ..util import pow2 as _pow2  # shared capacity-rounding policy
 
@@ -917,18 +916,35 @@ def compact_streams(columns: Sequence[Column]) -> int:
                for c in columns) + -(-masks // 32)
 
 
+def compaction_pays(cap: int, slots: int) -> bool:
+    """Whether cutting a table of ``slots`` slots to ``cap`` saves the
+    sort behind it more than the pass costs: where at least an EIGHTH of
+    the slots go. Measured on the chip (PERF.md section 6, PR 50: one
+    uint32 stream of 75,000,000 rows compacted and the semi join's three
+    operands sorted at the cut size, against the sort of every slot): a
+    slot costs the join's sort and plan pass 4-5.5 ns, a row costs the
+    compaction 0.18 ns whatever the density, so the pair still won by
+    3.4% of the sort at 95% alive and by 7% at 87.5%; an eighth is where
+    the win stops being small, with room for the 6.25% that ``cap`` may
+    lie over the live rows. A constant derived from that measurement,
+    decided by the fetched count alone."""
+    return 8 * cap < 7 * slots
+
+
 def compact_live(table: Table):
     """(``table`` cut to the capacity its live rows need, or ``table``
     itself; what was done, for the caller's span). A table with a row
     mask has its live rows counted (ONE fetch, ``sync.compact.count``,
     memoised on the mask's buffer as the join's count fetch is on its
-    inputs'); where the count's `bucket_cap` is under half the capacity
-    ONE device program moves the live rows of every column, in row
-    order, to the first slots of that capacity (`_compact_program_fn`: no
-    index array leaves or reaches the host), and the result's row mask is
-    the prefix of live rows. Else (no row mask, over half alive, a
-    varbytes column, a table sharded over several devices) the table is
-    handed back as it is: every operator honours a row mask."""
+    inputs'). The capacity they need is `util.capacity`'s (the
+    16-an-octave grid); where `compaction_pays` (at least an eighth of
+    the slots go) ONE device program moves the live rows of every
+    column, in row order, to the first slots of that capacity
+    (`_compact_program_fn`: no index array leaves or reaches the host),
+    and the result's row mask is the prefix of live rows. Else (no row
+    mask, too few dead slots, a varbytes column, a table sharded over
+    several devices) the table is handed back as it is: every operator
+    honours a row mask."""
     from ..parallel.shuffle import _count_cached
 
     info = {"rows_in": table.capacity, "compacted": False}
@@ -942,9 +958,14 @@ def compact_live(table: Table):
         lambda: int(_telemetry.host_fetch("compact.count",
                                           _compact_count_program_fn()(mask))))
     table._row_count_cache = count
-    cap = _bucket_cap(count)
+    # util.capacity's grid (at most 6.25% over, 16 shapes an octave), not
+    # bucket_cap's octave: the join behind the cut sorts every SLOT it is
+    # handed and its own programs are keyed on raw capacities already.
+    # THE one count that reaches a program's cache key this way
+    # (analysis/specialization.FINE_KEYED_FACTORY_PARAMS, docs/analysis.md)
+    cap = _capacity(count)
     info.update(rows_out=count, capacity=cap)
-    if 2 * cap >= table.capacity:
+    if not compaction_pays(cap, table.capacity):
         return table, info
     cols = table._columns
     nullable = [c for c in cols if c.validity is not None]
@@ -962,6 +983,7 @@ def compact_live(table: Table):
     info.update(compacted=True, streams=streams)
     _telemetry.counter("cylon_compact_rows_in_total").inc(table.capacity)
     _telemetry.counter("cylon_compact_rows_out_total").inc(count)
+    _telemetry.counter("cylon_compact_slots_out_total").inc(cap)
     _telemetry.counter("cylon_compact_streams_total").inc(streams)
     return out, info
 

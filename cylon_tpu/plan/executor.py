@@ -434,9 +434,11 @@ class _Exec:
         before an operator whose cost goes with its input's SLOTS (the
         local join sorts every slot of both sides, dead ones too): a
         table with a row mask has its live rows counted
-        (``sync.compact.count``) and, where they fill under half its
-        capacity, is compacted by one program (`table.compact_live`).
-        One chip only: across chips the exchange drops dead rows."""
+        (``sync.compact.count``) and, where the cut takes at least an
+        eighth of its slots (`table.compaction_pays`), is compacted by
+        one program to the grid capacity its live rows need
+        (`table.compact_live`). One chip only: across chips the exchange
+        drops dead rows."""
         if t.row_mask is None:
             return t
         with _span("plan.compact", self._seq(), rows_in=t.capacity) as sp:

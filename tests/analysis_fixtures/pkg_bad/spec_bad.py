@@ -126,3 +126,32 @@ def run_salted(mesh, counts):
     _salted_exchange_fn(mesh, 4)            # clean: structural literal
     raw = int(np.asarray(jax.device_get(counts)).max())
     _salted_exchange_fn(mesh, raw)   # SEEDED: raw capacity as salt key
+
+
+@counted_cache
+def _compact_program_fn(cap: int, path: str):
+    """Compaction-shaped factory: THE (factory, parameter) pair whose
+    capacity may arrive on util.capacity's 16-an-octave grid (every slot
+    past the live rows is sorted by the join behind it)."""
+    def kernel(x):
+        return x
+
+    return jax.jit(kernel)
+
+
+@counted_cache
+def _setop_program_fn(cap: int, path: str):
+    """The same shape under another name: the grid is accepted for the
+    compaction alone, so here it is a finding as it is at line 68."""
+    def kernel(x):
+        return x
+
+    return jax.jit(kernel)
+
+
+def run_compacted(mask):
+    count = int(np.asarray(jax.device_get(mask)).sum())
+    _compact_program_fn(_capacity(count), "xla")    # clean: the one pair
+    _compact_program_fn(bucket_cap(count), "xla")   # clean: bucketed
+    _compact_program_fn(count, "xla")       # SEEDED: raw count, even here
+    _setop_program_fn(_capacity(count), "xla")  # SEEDED: mantissa elsewhere

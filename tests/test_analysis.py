@@ -11,7 +11,7 @@ import pytest
 
 import cylon_tpu
 from cylon_tpu.analysis import (AnalysisContext, SCHEMA_VERSION,
-                                run_checkers, to_json_text)
+                                run_checkers, specialization, to_json_text)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FIXTURES = os.path.join(HERE, "analysis_fixtures")
@@ -542,13 +542,20 @@ def test_specialization_fixture_reports_exactly_seeded():
         # literal stays clean, a raw runtime count as the salt key is
         # a finding
         ("spec_bad.py", 128, "specialization/unbucketed-capacity"),
+        # the compaction-shaped factory (PR 50): its capacity may come
+        # through the 16-an-octave mantissa rounding (lines 154-155 stay
+        # clean), a raw count may not, and the same rounding into a
+        # factory of another name is the finding it is at line 68
+        ("spec_bad.py", 156, "specialization/unbucketed-capacity"),
+        ("spec_bad.py", 157, "specialization/unbucketed-capacity"),
     }, res.format_text()
     # the reasoned per-line disable on the env-sourced cap counted
     assert res.suppressed == 1
     msgs = {f.line: f.message for f in res.findings}
     # findings carry the derivation chain / classification rationale
     assert "bucket_cap" in msgs[67]
-    assert "mantissa" in msgs[68]
+    assert "mantissa" in msgs[68] and "mantissa" in msgs[157]
+    assert "runtime" in msgs[156] and "mantissa" not in msgs[156]
     assert "derivation:" in msgs[69]
     assert "make_scaled" in msgs[59] and "'scale'" in msgs[59]
 
@@ -569,6 +576,11 @@ def test_specialization_real_tree_clean_zero_suppressions():
     # census means the auditor lost sight of factories
     assert "0 data-dependent" in census[0], census[0]
     assert "0 unbounded" in census[0], census[0]
+    # ONE key on util.capacity's grid, the compaction's (PR 50): the
+    # policy's one recorded exception, not a suppression
+    assert "1 fine-bucketed-capacity" in census[0], census[0]
+    assert specialization.FINE_KEYED_FACTORY_PARAMS \
+        == {("_compact_program_fn", "cap")}
 
 
 def test_specialization_in_fixture_cli_default():

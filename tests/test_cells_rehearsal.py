@@ -77,6 +77,15 @@ print("REHEARSED " + json.dumps({
                      if isinstance(v, (int, float))),
     "phases": sorted(k.split('"')[1] for k in snap
                      if k.startswith("cylon_phase_latency_ms{phase=")),
+    "counts": {name: sum(v for k, v in snap.items()
+                         if k.split("{")[0] == name
+                         and isinstance(v, (int, float)))
+               for name in ("cylon_compact_rows_in_total",
+                            "cylon_compact_rows_out_total",
+                            "cylon_compact_slots_out_total",
+                            "cylon_compact_streams_total",
+                            "cylon_join_plan_sort_rows_total",
+                            "cylon_join_semi_total")},
     "packed": snap.get("cylon_groupby_sort_packed_columns_total", 0),
     "sorted": snap.get("cylon_groupby_sort_operands_total", 0)}))
 """
@@ -194,6 +203,15 @@ def test_tpch_q12_rehearses_pushed_compacted_and_dense():
     assert "sync.groupby.groups" not in said["phases"]
     assert "5 conjunct(s) pushed below a join" in out
     assert "2 groups (high/low MAIL=" in out
+    # the lines are cut to the 16-an-octave grid (PR 50): under 6.25% of
+    # the compacted slots are dead; the orders have no mask and ride the
+    # sort whole beside them
+    c = said["counts"]
+    assert 0 < c["cylon_compact_rows_out_total"] \
+        <= c["cylon_compact_slots_out_total"] \
+        <= 1.0625 * c["cylon_compact_rows_out_total"] + 16
+    assert c["cylon_join_plan_sort_rows_total"] \
+        > c["cylon_compact_slots_out_total"]
     _said, control = rehearse("tpch-q12", 1)
     bad = re.findall(r"compare first query: (\S+) = .* MISMATCH", control)
     assert sorted(bad) == ["high_count_diff", "low_count_diff"]
@@ -204,16 +222,32 @@ def test_tpch_q4_rehearses_as_a_semi_join():
     inner join under it (no count fetch, no materialise phase), the two
     date conjuncts written above it run below it on ORDERS, the filtered
     ORDERS counted and compacted before the join sorts them (the SMALL
-    side: the sort is handed the lines and the compacted capacity),
-    LINEITEM counted and left as it is, the dense table, the sort elided;
-    the control refused by its count alone."""
+    side), LINEITEM under its column-column compare (63% alive) counted
+    and compacted too since PR 50, so `plan.compact` cuts BOTH sides and
+    the sort is handed the two compacted capacities and not one slot
+    more; the dense table, the sort elided; the control refused by its
+    count alone."""
     said, out = rehearse("tpch-q4", 0)
     for family in ("cylon_join_semi_total",
                    "cylon_plan_filters_below_join_total",
                    "cylon_compact_rows_in_total",
                    "cylon_compact_rows_out_total",
+                   "cylon_compact_slots_out_total",
                    "cylon_join_plan_sort_rows_total"):
         assert any(s.split("{")[0] == family for s in said["series"]), family
+    c = said["counts"]
+    queries = c["cylon_join_semi_total"]
+    assert queries >= 3
+    # both sides: every slot the plan sort is handed came out of a
+    # compaction, two streams for the orders and one for the lines
+    assert c["cylon_join_plan_sort_rows_total"] \
+        == c["cylon_compact_slots_out_total"]
+    assert c["cylon_compact_streams_total"] == (2 + 1) * queries
+    assert 0.5 < c["cylon_compact_rows_out_total"] \
+        / c["cylon_compact_rows_in_total"] < 0.55     # (0.038 x 1 + 0.63 x 4) / 5
+    assert c["cylon_compact_rows_out_total"] \
+        <= c["cylon_compact_slots_out_total"] \
+        <= 1.0625 * c["cylon_compact_rows_out_total"] + 16 * queries
     assert 'cylon_join_semi_total{kind="semi"}' in said["series"]
     for span in ("plan.filter", "plan.project", "plan.compact",
                  "sync.compact.count", "plan.join", "join.semi",

@@ -134,3 +134,48 @@ def test_pow2_floor_rounds_down():
     assert util.pow2_floor(1023) == 512
     assert util.pow2_floor(1024) == 1024
     assert util.pow2_floor(0) == 1  # degenerate: never zero
+
+
+def test_capacity_grid_policy():
+    """`util.capacity`: 17..32 x 2^e, at most 6.25% over, 16 shapes an
+    octave; the capacities PR 50's compaction gives `tpch-q4`'s and
+    `tpch-q12`'s filtered sides, beside the octave's."""
+    for live, grid, octave in ((47_400_000, 48_234_496, 67_108_864),
+                               (716_958, 720_896, 1_048_576),
+                               (390_367, 393_216, 524_288)):
+        assert util.capacity(live) == grid and bucket_cap(live) == octave
+    octave = {util.capacity(n) for n in range((1 << 16) + 1, 1 << 17, 97)}
+    assert len(octave) == 16
+    for n in (1, 16, 17, 33, 311, 5000, 123_457, 75_000_000):
+        cap = util.capacity(n)
+        assert n <= cap <= n + max(n // 16, 1)
+        assert util.capacity(cap) == cap           # idempotent
+
+
+def test_compaction_builds_are_bounded_by_the_grid(local_ctx):
+    """The ONE factory whose capacity key comes through `util.capacity`
+    (analysis/specialization.FINE_KEYED_FACTORY_PARAMS): over an octave
+    of live counts `_compact_program_fn` builds at most 16 programs, one
+    a grid capacity and not one a count, the cut tables hold under 6.25%
+    of dead slots where the octave's rounding held up to half, and the
+    rows are `Table.compact()`'s."""
+    from cylon_tpu.data import table as T
+
+    n = 4096
+    counts = list(range(1030, 2040, 37))
+    caps = {util.capacity(c) for c in counts}
+    assert len(counts) == 28 and len(caps) <= 16
+    rng = np.random.default_rng(50)
+    vals = rng.integers(0, 1 << 20, n).astype(np.int32)
+    before = _builds("_compact_program_fn")
+    slots = 0
+    for c in counts:
+        mask = np.zeros(n, bool)
+        mask[rng.permutation(n)[:c]] = True
+        t = ct.Table.from_pydict(local_ctx, {"v": vals}).filter_mask(mask)
+        out, info = T.compact_live(t)
+        assert info["compacted"] and out.capacity == util.capacity(c)
+        assert out.to_pandas()["v"].tolist() == vals[mask].tolist()
+        slots += out.capacity
+    assert _builds("_compact_program_fn") - before <= len(caps)
+    assert slots <= 1.0625 * sum(counts) < 0.8 * len(counts) * bucket_cap(1030)

@@ -22,7 +22,7 @@ import pandas as pd
 import pytest
 
 import cylon_tpu as ct
-from cylon_tpu import plan, telemetry
+from cylon_tpu import plan, telemetry, util
 from cylon_tpu.data import table as T
 from cylon_tpu.data.column import Column
 from cylon_tpu.ops import groupby as G
@@ -154,8 +154,7 @@ def test_q12_through_the_plan_matches_the_reference_exactly(local_ctx, q12,
     assert [ln.split("(")[0] for ln in hand_text.splitlines()[:8]] \
         == [ln.split("(")[0] for ln in text.splitlines()[:8]]
     n_orders, n_lines = (t.capacity for t in tables.values())
-    cap = 512 if ref["rows_kept"] <= 512 else 1024
-    assert ref["rows_kept"] <= cap
+    cap = util.capacity(ref["rows_kept"])
     assert list(counted.values()) == [
         5, n_lines, ref["rows_kept"], 2, n_orders + cap, 2, 1], counted
     for span in ("plan.compact", "sync.compact.count", "sync.join.count",
@@ -537,21 +536,29 @@ def _same_live_rows(a, b):
 @pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
 @pytest.mark.parametrize("n,live", [
     (4096, 37), (4096, 0), (4096, 512), (4096, 513), (4096, 1024),
-    (5000, 1), (3000, 700)])
+    (5000, 1), (3000, 700),
+    # both sides of the line (PR 50): 63% alive is cut, 90% is not; 3456
+    # rows are 27 x 128 slots, the last capacity under 7/8 of 4096, and
+    # one row more takes the next (28 x 128: an eighth exactly, left)
+    (4096, 2580), (4096, 3686), (4096, 3456), (4096, 3457)])
 def test_compact_live_is_table_compact_without_a_host_index(local_ctx, n,
                                                              live, x64):
     """Against `Table.compact()` (the host's `flatnonzero`): the same live
     rows in the same order, validity and all; ONE fetch (the count), no
-    index array from or to the host; a plane-held column as two streams."""
+    index array from or to the host; a plane-held column as two streams.
+    Cut wherever at least an eighth of the slots go, to the 16-an-octave
+    grid's capacity and not the octave's."""
     with jax.enable_x64(x64):
         t, mask = _masked(local_ctx, n, live, seed=n + live, wide=True)
         assert t.get_column(5).is_planes == (not x64)
         before = telemetry.metrics_snapshot()
         with telemetry.collect_phases() as cp:
             out, info = T.compact_live(t)
-        cap = max(512, 1 << max(live - 1, 0).bit_length())
+        cap = util.capacity(live)
+        assert live <= cap <= max(live + live // 16, 1)
         assert info["rows_out"] == live and info["capacity"] == cap
-        assert info["compacted"] == (2 * cap < n)
+        assert info["compacted"] == (8 * cap < 7 * n)
+        assert info["compacted"] == (live not in (3686, 3457))
         assert cp.labels == ["sync.compact.count"]
         assert _delta(before, "cylon_host_syncs_total") == 1
         if not info["compacted"]:
@@ -562,6 +569,7 @@ def test_compact_live_is_table_compact_without_a_host_index(local_ctx, n,
         assert _delta(before, "cylon_compact_streams_total") == info["streams"]
         assert _delta(before, "cylon_compact_rows_in_total") == n
         assert _delta(before, "cylon_compact_rows_out_total") == live
+        assert _delta(before, "cylon_compact_slots_out_total") == cap
         assert np.asarray(out.row_mask).tolist() \
             == (np.arange(cap) < live).tolist()
         assert [c.name for c in out.columns()] == list("ifsbh") + ["w"]
@@ -575,32 +583,37 @@ def test_compact_live_is_table_compact_without_a_host_index(local_ctx, n,
         assert cp.labels == []
 
 
+@pytest.mark.parametrize("cap", [320, 512], ids=["grid", "octave"])
 def test_the_stream_kernel_compacts_as_the_xla_path_does(local_ctx,
-                                                          monkeypatch):
+                                                          monkeypatch, cap):
     """On a TPU the program holds the Pallas pass `stream_compact`; here
-    under the interpreter, against the CPU's own path."""
+    under the interpreter, against the CPU's own path: at the capacity
+    `compact_live` gives 311 rows (20 x 16 slots: no power of two, no
+    whole (8, 128) tile) and at the octave's."""
     with jax.enable_x64(False):
         t, _mask = _masked(local_ctx, 40_000, 311, seed=4, wide=True)
         plain, _info = T.compact_live(t)
         arrays = [c.data for c in t.columns()]
         valids = [c.validity for c in t.columns() if c.validity is not None]
         monkeypatch.setattr(T, "COMPACT_BLOCK_ROWS", 16)
-        got, gv, live = T._compact_program_fn(512, "stream", True)(
+        got, gv, live = T._compact_program_fn(cap, "stream", True)(
             t.row_mask, arrays, valids)
-        want, wv, wlive = T._compact_program_fn(512, "xla")(
+        want, wv, wlive = T._compact_program_fn(cap, "xla")(
             t.row_mask, arrays, valids)
     assert np.asarray(live).tolist() == np.asarray(wlive).tolist()
     for a, b in zip(list(got) + list(gv), list(want) + list(wv)):
         assert a.dtype == b.dtype and a.shape == b.shape
         assert np.asarray(a)[..., :311].tobytes() \
             == np.asarray(b)[..., :311].tobytes()
-    assert plain.capacity == 512
+    assert plain.capacity == 320 == util.capacity(311)
 
 
 def test_what_is_not_compacted_stays_as_it_is(local_ctx, dist_ctx):
-    t, _m = _masked(local_ctx, 4096, 1500, nullable=False)    # cap 2048
+    t, _m = _masked(local_ctx, 4096, 3700, nullable=False)    # 29 x 128
+    before = telemetry.metrics_snapshot()
     out, info = T.compact_live(t)
-    assert out is t and not info["compacted"] and info["capacity"] == 2048
+    assert out is t and not info["compacted"] and info["capacity"] == 3712
+    assert _delta(before, "cylon_compact_") == 0
     bare = ct.Table.from_pydict(local_ctx, {"a": np.arange(64, dtype=I32)})
     with telemetry.collect_phases() as cp:
         assert T.compact_live(bare)[0] is bare
@@ -641,12 +654,16 @@ def test_a_join_compacts_a_filtered_side_and_only_on_one_chip(local_ctx,
     assert cp.count("plan.compact") == 1 \
         and cp.count("sync.compact.count") == 1
     assert _delta(before, "cylon_compact_rows_out_total") == live
-    assert _delta(before, "cylon_join_plan_sort_rows_total") == 500 + 512
+    cap = util.capacity(live)
+    assert live <= cap < 512
+    assert _delta(before, "cylon_compact_slots_out_total") == cap
+    assert _delta(before, "cylon_join_plan_sort_rows_total") == 500 + cap
     assert _rows(out) == _rows(q.execute(optimize=False))
-    assert f"compacted={n}->{live} rows in 512 slots" \
+    assert f"compacted={n}->{live} rows in {cap} slots" \
         in q.explain(analyze=True)
-    # over half alive: counted, not compacted, the join sorts every slot
-    q = query(local_ctx, False, 60)
+    # over the line (95% alive: under an eighth of the slots would go):
+    # counted, not compacted, the join sorts every slot
+    q = query(local_ctx, False, 95)
     before = telemetry.metrics_snapshot()
     with telemetry.collect_phases() as cp:
         out = q.execute()
@@ -661,3 +678,50 @@ def test_a_join_compacts_a_filtered_side_and_only_on_one_chip(local_ctx,
         out = q.execute()
     assert cp.count("plan.compact") == 0
     assert len(_rows(out)) == live
+
+
+@pytest.mark.parametrize("how", ["semi", "inner"])
+def test_a_side_63_percent_alive_is_cut_and_the_join_says_the_same(
+        local_ctx, monkeypatch, how):
+    """`tpch-q4`'s shape (PR 50): the large side keeps 63% of its rows,
+    over half, so the octave rule sorted every slot of it. Cut to the
+    grid's capacity the join gives the same rows, and its plan sort is
+    handed fewer slots by the dead ones less the grid's overshoot."""
+    r = np.random.default_rng(50)
+    n, m = 8000, 600
+    fact = {"k": r.integers(0, 900, n).astype(I32),
+            "v": r.integers(0, 100, n).astype(I32)}
+    dim = {"k": r.permutation(900)[:m].astype(I32),
+           "d": r.integers(0, 9, m).astype(I32)}
+    live = int((fact["v"] < 63).sum())
+    cap = util.capacity(live)
+    assert 2 * util.bucket_cap(live) >= n and 8 * cap < 7 * n   # PR 50's rule
+
+    def run():
+        small = plan.scan(ct.Table.from_pydict(local_ctx, dim))
+        large = plan.scan(ct.Table.from_pydict(local_ctx, fact)).filter(
+            col("v") < 63)
+        q = small.join(large, how, on="k") if how == "semi" \
+            else large.join(small, how, on="k")
+        before = telemetry.metrics_snapshot()
+        with telemetry.collect_phases() as cp:
+            out = q.execute()
+        assert cp.count("sync.compact.count") == 1
+        return _rows(out), {name: _delta(before, name) for name in (
+            "cylon_join_plan_sort_rows_total", "cylon_compact_rows_in_total",
+            "cylon_compact_rows_out_total", "cylon_compact_slots_out_total",
+            "cylon_host_syncs_total")}
+
+    cut_rows, cut = run()
+    monkeypatch.setattr(T, "compaction_pays", lambda cap, slots: False)
+    whole_rows, whole = run()
+    assert cut_rows == whole_rows and len(cut_rows) > 0
+    assert (whole["cylon_compact_rows_in_total"],
+            cut["cylon_compact_rows_in_total"]) == (0, n)
+    assert cut["cylon_compact_rows_out_total"] == live
+    assert cut["cylon_compact_slots_out_total"] == cap
+    assert whole["cylon_join_plan_sort_rows_total"] == m + n
+    assert cut["cylon_join_plan_sort_rows_total"] == m + cap \
+        == m + n - ((n - live) - (cap - live))
+    # the count that decides is the one fetched before: no new fetch
+    assert cut["cylon_host_syncs_total"] == whole["cylon_host_syncs_total"]

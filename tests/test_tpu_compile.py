@@ -571,34 +571,50 @@ def test_q1_dense_groupby_compiles(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 50 * n
 
 
-# TPC-H Q12 (PR 46): 75,000,000 lines filtered to 0.52%, compacted to
-# 524,288 slots, joined to 18,750,000 orders
-Q12_LINES, Q12_ORDERS, Q12_CAP = 75_000_000, 18_750_000, 1 << 19
+# TPC-H Q12 (PR 46): 75,000,000 lines filtered to 0.52% (390,367 rows),
+# compacted to 393,216 slots (3 x 2^17: `util.capacity`'s grid since PR
+# 50, 524,288 before), joined to 18,750,000 orders
+Q12_LINES, Q12_ORDERS, Q12_CAP = 75_000_000, 18_750_000, 3 << 17
+# TPC-H Q4 (PR 49): 18,750,000 orders filtered to the quarter's 716,958
+# and compacted to 720,896 slots (22 x 2^15; 1,048,576 before PR 50),
+# semi-joined to 75,000,000 lines whose 63% row mask PR 50 cuts too:
+# 47.4M rows in 48,234,496 slots (23 x 2^21)
+Q4_LINES, Q4_ORDERS = 75_000_000, 18_750_000
+Q4_CAP, Q4_LINES_CAP = 22 << 15, 23 << 21
 
 
-def test_q12_compaction_compiles(chip):
-    """The cell's compaction as a TPU runs it: the row mask and two
-    streams (l_orderkey, l_shipmode's codes) of 75,000,000 rows through
-    ONE Pallas pass into 524,288 slots; the outputs are of the capacity,
-    not of the input (`stream_compact`'s ``out_elems``)."""
+@pytest.mark.parametrize("rows,streams,cap", [
+    (Q12_LINES, 2, Q12_CAP), (Q4_ORDERS, 2, Q4_CAP),
+    (Q4_LINES, 1, Q4_LINES_CAP)], ids=["q12-lines", "q4-orders", "q4-lines"])
+def test_compaction_compiles(chip, rows, streams, cap):
+    """The cells' compactions as a TPU runs them: the row mask and the
+    side's streams (`tpch-q12`: l_orderkey and l_shipmode's codes of
+    75,000,000 rows; `tpch-q4`: the orders' key and priority codes, and
+    the lines' key alone, 63% alive) through ONE Pallas pass into a
+    capacity on the 16-an-octave grid, none a power of two; the outputs
+    are of the capacity, not of the input (`stream_compact`'s
+    ``out_elems``)."""
+    from cylon_tpu import util
     from cylon_tpu.data import table as T
 
-    fn = T._compact_program_fn(Q12_CAP, "stream")
-    i32 = _sds(chip, Q12_LINES, jnp.int32)
+    assert util.capacity(cap) == cap and cap & (cap - 1)
+    fn = T._compact_program_fn(cap, "stream")
+    i32 = _sds(chip, rows, jnp.int32)
     with jax.enable_x64(False):
-        compiled = fn.lower(_sds(chip, Q12_LINES, jnp.bool_), [i32, i32],
+        compiled = fn.lower(_sds(chip, rows, jnp.bool_), [i32] * streams,
                             []).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert "scatter" not in text and " sort(" not in text
-    assert compiled.memory_analysis().output_size_in_bytes < 16 * Q12_CAP
+    assert compiled.memory_analysis().output_size_in_bytes \
+        < (4 * streams + 8) * cap
     assert T.compact_streams([]) == 0
 
 
 def test_q12_join_compiles(chip):
     """The cell's join: 18,750,000 orders (key, priority codes) against
     the compacted lines (key, ship-mode codes; a row mask: the prefix of
-    live rows), 19,274,288 slots through the plan sort, block_rows 64;
+    live rows), 19,143,216 slots through the plan sort, block_rows 64;
     the key rides once, so the sort as COMPILED has key bits, tag and one
     payload slot."""
     ok = _sds(chip, Q12_ORDERS, jnp.int32)
@@ -627,25 +643,20 @@ def test_q12_join_compiles(chip):
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
         plan_out)
     cap_e = _join.stream_expand_capacity(390_564, kw["block_rows"])
-    assert cap_e == Q12_CAP
+    assert cap_e == 1 << 19      # the RESULT's capacity keeps its octave
     text = _compiled_text(_join._materialize_program_stream_jit, counts,
                           a_streams, b_streams, *cols, cap_e=cap_e, **kw)
     assert "tpu_custom_call" in text
 
 
-# TPC-H Q4 (PR 49): 18,750,000 orders filtered to the quarter's 716,958
-# and compacted to 1,048,576 slots, semi-joined to 75,000,000 lines under
-# a 63% row mask
-Q4_LINES, Q4_CAP = 75_000_000, 1 << 20
-
-
-@pytest.mark.parametrize("how,lines", [("SEMI", Q4_LINES),
+@pytest.mark.parametrize("how,lines", [("SEMI", Q4_LINES_CAP),
                                        ("ANTI", 1 << 22)])
 def test_q4_semi_join_compiles(chip, how, lines):
     """(The anti join, one comparison apart, at a size that compiles in
     seconds.) The cell's semi join, ONE program: the compacted orders (key,
-    priority codes; a row mask: the prefix of live rows) against the lines
-    (key alone; a row mask), 76,048,576 slots through the plan sort,
+    priority codes; a row mask: the prefix of live rows) against the
+    compacted lines (key alone; a row mask: the prefix of live rows),
+    48,955,392 slots through the plan sort (76,048,576 before PR 50),
     block_rows 64. The build side rides with its key and tag alone and the
     key rides once, so the sort as COMPILED has key bits, tag and ONE
     payload slot; one Pallas call (the plan pass; no expand), and the
