@@ -53,8 +53,9 @@ Rules (all package-relative, suppressible per line like every family):
   reached from a thread-entry domain (worker/finalizer/hook) whose
   closure never ``set``s it: a fresh thread's context carries the
   DEFAULT, not the submitter's stamp — exactly the tenant-label /
-  deadline bug class PR 7 hand-dodged with ``root_attrs``/
-  ``query_deadline`` re-stamps.
+  deadline bug class PR 7 hand-dodged with re-stamps at the thread
+  boundary (today ``spans.attach`` of the query's root span and
+  ``query_deadline``).
 * ``concurrency/finalizer-hazard`` — finalizer-domain code acquiring a
   NON-reentrant ``threading.Lock`` (same-thread GC re-entry deadlocks
   it; use RLock) or dispatching through jax (device work inside GC).

@@ -144,12 +144,13 @@ def _stamp_plan_fp(root_span, plan: ir.PlanNode, ctx,
                    plan_fp=None) -> None:
     """Make sure the ``plan.query`` root span carries a plan
     fingerprint — the statistics warehouse's per-query key and the
-    digest's join column. The service path stamps the LOGICAL-plan
-    fingerprint through root_attrs (the plan-cache key space, which
-    drift eviction must match); the library path passes the same
-    logical fingerprint down from ``LazyTable.execute``. Only when
-    neither exists (a raw ``executor.execute`` call on a hand-built
-    plan) is the fingerprint derived from the plan at hand."""
+    digest's join column. The service path passes the LOGICAL-plan
+    fingerprint down from ``submit()`` (the plan-cache key space, which
+    drift eviction must match; it rides the ``service.query`` root
+    too); the library path passes the same logical fingerprint down
+    from ``LazyTable.execute``. Only when neither exists (a raw
+    ``executor.execute`` call on a hand-built plan) is the fingerprint
+    derived from the plan at hand."""
     if root_span.attrs.get("plan_fp"):
         return
     if plan_fp is None:
@@ -217,7 +218,9 @@ def execute_analyzed(plan: ir.PlanNode, ctx=None, stats=None,
                 ex = _Exec(ctx, recorder=_Recorder(cp.labels),
                            degrade=decision.degrade_blocks, est=est)
                 result = ex.run(plan)
-    leaks = _ledger.leak_report(root_span.span_id,
+    # by the TREE's root: in served mode ``plan.query`` nests under the
+    # scheduler's ``service.query`` and the ledger books by root
+    leaks = _ledger.leak_report(root_span.root_id,
                                 exclude={id(result)})
     pool = getattr(ex.ctx, "memory_pool", None) if ex.ctx is not None \
         else None

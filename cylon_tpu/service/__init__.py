@@ -8,9 +8,11 @@ enqueue, and a plan/fingerprint cache so repeated query shapes skip
 optimization and re-hit the compiled-kernel memos.
 
 * ``scheduler`` — :class:`QueryService` / :class:`QueryTicket`: the
-  async submission surface and the single executor worker (device
+  async submission surface, the single executor worker (device
   execution stays serialized; host-side optimize/preflight pipelines
-  on the submitters' threads).
+  on the submitters' threads) and the completion thread (a ticket
+  resolves when its result is ready on the DEVICE, without holding
+  the worker).
 * ``plancache`` — the structural plan fingerprint and the bounded LRU
   of optimized plans, shared between the service and library mode.
 * ``obs_http`` — the live operational surface: a stdlib HTTP endpoint

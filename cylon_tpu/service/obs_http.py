@@ -59,8 +59,6 @@ from ..telemetry import querylog as _querylog
 from ..telemetry import slo as _slo
 from ..telemetry import stats as _stats
 
-DEFAULT_OBS_PORT = _knobs.default("CYLON_OBS_PORT")
-
 ROUTES = ("/metrics", "/healthz", "/queries", "/slo", "/stats")
 
 

@@ -67,9 +67,9 @@ its own submodules, and its underscore names are module-private
 from __future__ import annotations
 
 from .spans import (Span, annotate, collect_phases, current_span,
-                    host_fetch, log_to_stderr, logger, phase, root_attrs,
-                    span, add_sink, remove_sink, add_root_hook,
-                    remove_root_hook)
+                    host_fetch, log_to_stderr, logger, phase,
+                    span, open_span, close_span, attach, add_sink,
+                    remove_sink, add_root_hook, remove_root_hook)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       REGISTRY, counted_cache, counter, gauge, histogram,
                       metrics_snapshot, reset_metrics, sample_memory,
@@ -84,8 +84,9 @@ from .skew import SkewStats
 __all__ = [
     # spans
     "Span", "annotate", "collect_phases", "current_span", "host_fetch",
-    "log_to_stderr", "logger", "phase", "root_attrs", "span", "add_sink",
-    "remove_sink", "add_root_hook", "remove_root_hook",
+    "log_to_stderr", "logger", "phase", "span",
+    "open_span", "close_span", "attach", "add_sink", "remove_sink",
+    "add_root_hook", "remove_root_hook",
     # metrics
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "counted_cache", "counter", "gauge", "histogram", "metrics_snapshot",

@@ -10,10 +10,17 @@ the aggregate signals a single query contributes to the metrics
 (shuffle bytes/rows, retries, peak HBM, worst skew).
 
 Implementation: a root-span close hook (``spans.add_root_hook``) that
-fires for ``plan.query`` roots only — the plan executor wraps BOTH
-execute paths in that root span, so every query produces exactly one
-digest whether it ran through the service, a bare ``collect()``, or
-``explain(analyze=True)``. Eager top-level ops (a direct
+fires for query roots only: ``plan.query`` — the plan executor wraps
+BOTH execute paths in that span, the root of a bare ``collect()`` or
+``explain(analyze=True)`` — and ``service.query``, the root the service
+scheduler opens in ``submit()`` and closes when the query is COMPLETE
+(result ready on the device, or shed / failed / timed out), with
+``plan.query`` as a descendant. So every query produces exactly one
+digest, and a served query's is written at completion and carries its
+whole life: ``submit_ms``, ``queue_wait_ms``, ``dispatch_ms``,
+``drain_ms`` and their sum ``total_ms`` (all None in library mode).
+``exec_ms`` is in both modes the host's ``plan.query`` time. Eager
+top-level ops (a direct
 ``distributed_join`` call) are operator phases, not queries, and stay
 out of the log. The digest is assembled from the completed span tree —
 which head sampling (telemetry/sampling.py) deliberately keeps in
@@ -51,7 +58,12 @@ from . import stats as _stats
 
 # root span names that ARE queries (everything else a root hook sees —
 # eager op roots, marker spans — is not a query digest)
-QUERY_ROOT_NAMES = ("plan.query",)
+QUERY_ROOT_NAMES = ("plan.query", "service.query")
+
+# a served query's stage times, read off its ``service.query`` root
+# (service/scheduler.py stamps them at completion)
+SERVED_FIELDS = ("submit_ms", "queue_wait_ms", "dispatch_ms", "drain_ms",
+                 "total_ms")
 
 # the digest ring holds this multiple of CYLON_FLIGHT_RING entries:
 # digests are ~200 B dicts where flight-ring entries are whole span
@@ -62,7 +74,10 @@ RING_FACTOR = 4
 # v3: + join_algorithms / salted_exchanges (PR 15 — "which queries
 #      went broadcast, and did they win" is joinable offline from the
 #      JSONL alone against exec_ms / shuffle_bytes)
-DIGEST_SCHEMA_VERSION = 3
+# v4: + submit_ms / queue_wait_ms / dispatch_ms / drain_ms / total_ms
+#      (PR 51: a served query's line is written at completion; its
+#      ``outcome`` is the ticket's, ok | shed | error | timeout)
+DIGEST_SCHEMA_VERSION = 4
 
 
 def _ring_size() -> int:
@@ -78,6 +93,10 @@ def digest(root) -> dict:
     """Reduce one completed root query span tree to its flat digest
     record — the query-log line and the ``/queries`` entry."""
     a = root.attrs
+    # the host's time in the executor: the root itself in library mode,
+    # the ``plan.query`` under a served query's root (None for a query
+    # that was shed before it ran)
+    exec_ms = None
     shuffle_bytes = 0
     shuffle_rows = 0
     shuffles = 0
@@ -88,6 +107,8 @@ def digest(root) -> dict:
     salted = 0
     for node in root.walk():
         at = node.attrs
+        if node.name == "plan.query" and exec_ms is None:
+            exec_ms = node.elapsed_ms
         if node.name.startswith("shuffle.exchange"):
             shuffle_bytes += int(at.get("bytes_moved") or 0)
             shuffle_rows += int(at.get("rows") or 0)
@@ -112,9 +133,10 @@ def digest(root) -> dict:
         "tenant": a.get("tenant", "default"),
         "service": a.get("service"),
         "root": root.label,
-        "outcome": "error" if root.error else "ok",
-        "exec_ms": round(root.elapsed_ms, 3)
-        if root.elapsed_ms is not None else None,
+        "outcome": a.get("outcome") or
+        ("error" if root.error else "ok"),
+        "exec_ms": round(exec_ms, 3) if exec_ms is not None else None,
+        **{name: a.get(name) for name in SERVED_FIELDS},
         "wait_s": a.get("wait_s"),
         "admission": a.get("admission"),
         # the admission estimate + its provenance (static width x row
@@ -171,9 +193,10 @@ def _on_root_close(root) -> None:
                 _spans.logger.exception("querylog write failed")
     # the digest is the SLO tracker's feed: per-tenant latency,
     # objective evaluation, burn accounting (outside our lock — slo
-    # has its own)
-    _slo.observe(d["tenant"], d["exec_ms"] or 0.0,
-                 error=root.error)
+    # has its own). A served query's latency is its whole life, submit
+    # called to result ready; a library one's the executor's host time
+    latency_ms = d["exec_ms"] if d["total_ms"] is None else d["total_ms"]
+    _slo.observe(d["tenant"], latency_ms or 0.0, error=root.error)
     # ... and the statistics warehouse's: measured per-fingerprint
     # truth (q-error, drift, stats-informed admission) accumulates at
     # the same choke point where a finished query becomes operator-

@@ -50,8 +50,6 @@ from . import metrics as _metrics
 SLO_BUCKETS_MS = (1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
                   1000.0, 2500.0, 5000.0, 10000.0, 30000.0, 60000.0)
 
-DEFAULT_TARGET = _knobs.default("CYLON_SLO_TARGET")
-
 
 def objective_ms() -> Optional[float]:
     """The declared p95 latency objective, or None (no SLO)."""

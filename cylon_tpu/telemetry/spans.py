@@ -23,6 +23,15 @@ shuffle timing; join/join.cpp:101-253 per-phase logs; arrow_hash_kernels.hpp
   histogram (metrics) on completion. The plan executor's per-query
   EXPLAIN ANALYZE report (plan/report.py) is built on this tree.
 
+Every span is stamped ``start_s`` / ``end_s`` on ``time.perf_counter``
+(one clock for every thread) and closes in ONE place, ``close_span``.
+``span()`` is ``open_span`` + the block + ``close_span``; the two are
+public for a span that is no ``with`` block on one thread, and
+``attach`` makes an open span the current one of another thread, so a
+tree can cross threads (the service's ``service.query`` root opens in
+``submit()`` on the caller's thread and closes on the completion
+thread: service/scheduler.py).
+
 ``phase(name, seq)`` is the original module's API, now a thin wrapper
 over ``span`` — all pre-package call sites keep their exact semantics
 (label format ``name#seq``, one INFO line per span, collect_phases
@@ -89,14 +98,6 @@ def _hbm_attrs_on() -> bool:
 _current: ContextVar[Optional["Span"]] = ContextVar(
     "cylon_tpu_current_span", default=None)
 
-# attributes stamped onto every ROOT span opened in this context (the
-# service tier sets tenant/query_id here, so EXPLAIN ANALYZE trees,
-# flight-ring entries and crash dumps all say whose query they were) —
-# root-only keeps attr volume flat however deep the query tree is
-_root_attrs: ContextVar[Optional[dict]] = ContextVar(
-    "cylon_tpu_root_attrs", default=None)
-
-
 @dataclass
 class Span:
     """One timed operation with typed attributes and child spans.
@@ -120,7 +121,14 @@ class Span:
     # this span skips trace sinks + device-trace annotation; the tree
     # itself is still built (crash dumps / error promotion need it)
     sampled: bool = True
-    _t0: float = 0.0
+    # stamps on ``time.perf_counter``'s clock: ``start_s`` when the span
+    # opened, ``end_s`` None while it is open. A body that knows the
+    # true edge better than its ``with`` block does may stamp either
+    # (the service's ``service.submit`` ends where the job is enqueued,
+    # inside the lock); ``close_span`` keeps a stamped end and computes
+    # ``elapsed_ms`` from the two
+    start_s: float = 0.0
+    end_s: Optional[float] = None
     _hbm0: Optional[int] = None    # pool bytes_in_use at span enter
 
     @property
@@ -151,6 +159,7 @@ class Span:
         ``nested=True`` to embed children instead."""
         d = {"span_id": self.span_id, "parent_id": self.parent_id,
              "root_id": self.root_id, "name": self.name, "seq": self.seq,
+             "start_s": self.start_s, "end_s": self.end_s,
              "elapsed_ms": self.elapsed_ms, "error": self.error,
              "attrs": dict(self.attrs)}
         if nested:
@@ -170,23 +179,6 @@ def annotate(**attrs) -> None:
     s = _current.get()
     if s is not None:
         s.attrs.update(attrs)
-
-
-@contextmanager
-def root_attrs(**attrs) -> Iterator[None]:
-    """Stamp ``attrs`` onto every ROOT span opened inside the context
-    (contextvar-scoped, so concurrent submitters/threads never leak
-    labels into each other's queries). Explicit span attrs win on key
-    collision. The service scheduler threads ``tenant``/``query_id``
-    through here — one context manager instead of touching every
-    execute path."""
-    outer = _root_attrs.get()
-    merged = {**outer, **attrs} if outer else dict(attrs)
-    token = _root_attrs.set(merged)
-    try:
-        yield
-    finally:
-        _root_attrs.reset(token)
 
 
 def add_sink(sink: Callable) -> None:
@@ -266,19 +258,18 @@ def log_to_stderr(level: int = logging.INFO) -> None:
     logger.setLevel(level)
 
 
-@contextmanager
-def span(name: str, seq: Optional[int] = None, **attrs) -> Iterator[Span]:
-    """Open one span: time it, nest it under the current span, annotate
-    device traces with the same label, feed sinks and the per-phase
-    latency histogram on close. Yields the Span so the body can
-    ``s.set(rows_out=...)``. Exceptions re-raise after the span records
-    ``error=True`` and its elapsed time (the fixed phase() bug)."""
-    parent = _current.get()
+def open_span(name: str, seq: Optional[int] = None, *,
+              parent: Optional[Span] = None, **attrs) -> Span:
+    """Open one span under ``parent`` (None: a ROOT, which takes the
+    head-sampling decision) and stamp its start. The head of
+    ``span()``; called alone for a span that does not live in one
+    ``with`` block on one thread (the service's
+    ``service.query`` root opens in ``submit()`` and closes on the
+    completion thread): such a span is NOT the current one anywhere
+    until ``attach`` makes it so, carries no device-trace annotation,
+    and stays open until ``close_span``."""
     sid = next(_span_ids)
     if parent is None:
-        ra = _root_attrs.get()
-        if ra:
-            attrs = {**ra, **attrs}
         # head sampling decided HERE, once per tree: deterministic on
         # the stamped query_id (the service scheduler's monotonic id;
         # this root's span_id outside the service — replayable either
@@ -310,13 +301,88 @@ def span(name: str, seq: Optional[int] = None, **attrs) -> Iterator[Span]:
             s._hbm0 = int(pool.snapshot()[0])
         except Exception:  # pragma: no cover - defensive  # cylint: disable=errors/broad-swallow — pool snapshot failure disables hbm attrs
             s._hbm0 = None
+    s.start_s = time.perf_counter()
+    return s
+
+
+def close_span(s: Span) -> None:
+    """Close ``s``: THE one place a span ends, on whichever thread.
+    Stamps ``end_s`` (unless the body already has), computes
+    ``elapsed_ms``, takes the HBM attrs, feeds the per-phase histogram
+    and the sinks, and for a root promotes an errored sampled-out tree
+    and runs the root hooks. ``span()`` calls it from its ``finally``;
+    a span opened with ``open_span`` is closed by calling it directly,
+    from any thread (its children have to be closed by then)."""
+    if s.end_s is None:
+        s.end_s = time.perf_counter()
+    s.elapsed_ms = (s.end_s - s.start_s) * 1e3
+    pool = _metrics.get_memory_pool() if s._hbm0 is not None else None
+    if pool is not None:
+        try:
+            used, peak, _limit = pool.snapshot()
+            s.attrs["hbm_delta"] = int(used) - s._hbm0
+            s.attrs["hbm_peak"] = int(peak)
+        except Exception:  # pragma: no cover - defensive  # cylint: disable=errors/broad-swallow — pool snapshot failure drops hbm attrs
+            pass
+    _metrics.observe_phase(s.name, s.elapsed_ms, error=s.error)
+    if s.sampled:
+        _emit_to_sinks(s)
+    if not s.parent_id:
+        if s.error and not s.sampled:
+            # error promotion: the whole tree is complete (children
+            # closed first) and still in memory — record it to the
+            # sinks post-hoc, children before parents, so the JSONL
+            # trace AND the crash dump read like a fully sampled
+            # query. Forensics never degrade under sampling.
+            s.sampled = True
+            # the sampled attr means "a full trace was exported":
+            # after promotion that is TRUE — the query log's
+            # digest must not tell an operator that the one class
+            # of query GUARANTEED to have a trace has none
+            s.attrs["sampled"] = True
+            s.attrs["sampled_promoted"] = True
+            _sampling.record_promotion()
+            for node in s.walk_postorder():
+                node.sampled = True
+                _emit_to_sinks(node)
+        for hook in list(_root_hooks):
+            try:
+                hook(s)
+            except Exception:  # pragma: no cover - defensive
+                logger.exception("root-span hook failed")
+    if logger.isEnabledFor(logging.INFO):
+        logger.info("%s %.3f ms%s", s.label, s.elapsed_ms,
+                    " error=True" if s.error else "")
+
+
+@contextmanager
+def attach(s: Span) -> Iterator[Span]:
+    """Make the open span ``s`` the current one of THIS thread's context
+    for the block: spans opened inside nest under it, ``annotate`` and
+    ``current_span`` find it. How a tree crosses threads (a contextvar's
+    value does not): the service's worker and completion thread attach
+    the root that ``submit()`` opened on the caller's."""
     token = _current.set(s)
-    s._t0 = time.perf_counter()
+    try:
+        yield s
+    finally:
+        _current.reset(token)
+
+
+@contextmanager
+def span(name: str, seq: Optional[int] = None, **attrs) -> Iterator[Span]:
+    """Open one span: time it, nest it under the current span, annotate
+    device traces with the same label, feed sinks and the per-phase
+    latency histogram on close. Yields the Span so the body can
+    ``s.set(rows_out=...)``. Exceptions re-raise after the span records
+    ``error=True`` and its elapsed time (the fixed phase() bug)."""
+    s = open_span(name, seq, parent=_current.get(), **attrs)
+    token = _current.set(s)
     try:
         # sampled-out trees skip the device-trace annotation too — the
         # Perfetto label volume is part of the per-span cost the head
         # decision bounds
-        with jax.profiler.TraceAnnotation(f"cylon:{label}") \
+        with jax.profiler.TraceAnnotation(f"cylon:{s.label}") \
                 if s.sampled else nullcontext():
             yield s
     except BaseException:
@@ -324,44 +390,8 @@ def span(name: str, seq: Optional[int] = None, **attrs) -> Iterator[Span]:
         s.attrs["error"] = True
         raise
     finally:
-        s.elapsed_ms = (time.perf_counter() - s._t0) * 1e3
         _current.reset(token)
-        if s._hbm0 is not None:
-            try:
-                used, peak, _limit = pool.snapshot()
-                s.attrs["hbm_delta"] = int(used) - s._hbm0
-                s.attrs["hbm_peak"] = int(peak)
-            except Exception:  # pragma: no cover - defensive  # cylint: disable=errors/broad-swallow — pool snapshot failure drops hbm attrs
-                pass
-        _metrics.observe_phase(s.name, s.elapsed_ms, error=s.error)
-        if s.sampled:
-            _emit_to_sinks(s)
-        if parent is None:
-            if s.error and not s.sampled:
-                # error promotion: the whole tree is complete (children
-                # closed first) and still in memory — record it to the
-                # sinks post-hoc, children before parents, so the JSONL
-                # trace AND the crash dump read like a fully sampled
-                # query. Forensics never degrade under sampling.
-                s.sampled = True
-                # the sampled attr means "a full trace was exported":
-                # after promotion that is TRUE — the query log's
-                # digest must not tell an operator that the one class
-                # of query GUARANTEED to have a trace has none
-                s.attrs["sampled"] = True
-                s.attrs["sampled_promoted"] = True
-                _sampling.record_promotion()
-                for node in s.walk_postorder():
-                    node.sampled = True
-                    _emit_to_sinks(node)
-            for hook in list(_root_hooks):
-                try:
-                    hook(s)
-                except Exception:  # pragma: no cover - defensive
-                    logger.exception("root-span hook failed")
-        if logger.isEnabledFor(logging.INFO):
-            logger.info("%s %.3f ms%s", label, s.elapsed_ms,
-                        " error=True" if s.error else "")
+        close_span(s)
 
 
 def host_fetch(site: str, x):

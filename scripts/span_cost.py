@@ -13,7 +13,11 @@ median and the 90th percentile of the batches, in microseconds a span
 (open + close). The traced round runs under ``jax.profiler`` with the
 options ``benchmarks/run.py`` traces with; ``off_no_hbm_attrs`` is the
 untraced round again with ``CYLON_HBM_SPAN_ATTRS=0``, to say what of the
-cost the snapshots are. The last line is JSON.
+cost the snapshots are. ``crossed`` is a span that is no ``with`` block
+(PR 51: the service's ``service.query`` and ``service.queue_wait``):
+``open_span`` on this thread, ``close_span`` on another one under
+``attach``, profiler off; its two halves are timed apart and add up to
+one span. The last line is JSON.
 """
 import argparse
 import json
@@ -22,6 +26,7 @@ import shutil
 import statistics
 import sys
 import tempfile
+import threading
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -37,6 +42,32 @@ def batches_us(telemetry, roots, children):
                     pass
             out.append((time.perf_counter() - t0) * 1e6 / children)
     return out
+
+
+def crossed_us(telemetry, roots, children):
+    """(open, close) microseconds a span: the children of a root opened
+    here with ``open_span`` and closed by another thread that attached
+    the root."""
+    opens, closes = [], []
+    for _ in range(roots):
+        root = telemetry.open_span("plan.query")
+        t0 = time.perf_counter()
+        kids = [telemetry.open_span("cost.crossed", parent=root)
+                for _ in range(children)]
+        opens.append((time.perf_counter() - t0) * 1e6 / children)
+
+        def close_all():
+            with telemetry.attach(root):
+                t0 = time.perf_counter()
+                for kid in kids:
+                    telemetry.close_span(kid)
+                closes.append((time.perf_counter() - t0) * 1e6 / children)
+
+        closer = threading.Thread(target=close_all)
+        closer.start()
+        closer.join()
+        telemetry.close_span(root)
+    return opens, closes
 
 
 def summary(us):
@@ -62,6 +93,8 @@ def main(argv=None):
               "kind": jax.devices()[0].device_kind,
               "devices": len(ctx.devices), "spans": roots * children,
               "off": summary(batches_us(telemetry, roots, children))}
+    opens, closes = crossed_us(telemetry, roots, children)
+    result["crossed"] = {"open": summary(opens), "close": summary(closes)}
     # where the cost is: the same round without the two pool snapshots a
     # span takes (the knob is read live)
     os.environ["CYLON_HBM_SPAN_ATTRS"] = "0"

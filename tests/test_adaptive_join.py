@@ -272,7 +272,8 @@ def test_learned_loop_end_to_end_bit_identity(dist_ctx, monkeypatch):
     assert "algo=broadcast" in atxt
     assert _counter("cylon_shuffle_bytes_total") == b0
     d = querylog.recent()[-1]
-    assert d["v"] == 3
+    # v3 brought join_algorithms; v4 (PR 51) a served query's stage times
+    assert d["v"] == 4
     assert d["join_algorithms"] == ["broadcast"]
     assert d["shuffles"] == 0
     rep = p.last_report.to_dict()

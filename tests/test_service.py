@@ -355,9 +355,13 @@ def test_service_shed_typed_others_unaffected(dist_ctx):
     direct = _rows(_pipe(left, right).execute())
     marker_spans = []
 
+    roots = {}
+
     def sink(s):
         if s.name == "plan.admission":
             marker_spans.append(s)
+        if s.name == "service.query":
+            roots[s.span_id] = s
 
     svc = QueryService(start=False)
     inject.arm("pool:262144:oom")
@@ -381,11 +385,13 @@ def test_service_shed_typed_others_unaffected(dist_ctx):
              if d.get("action") == "shed"]
     assert sheds and sheds[-1]["tenant"] == "greedy"
     # the service-dispatch shed emits the documented plan.admission
-    # marker span, tenant-stamped via root_attrs
+    # marker span, under the tenant-stamped service.query root (PR 51:
+    # a served query is ONE tree; the marker was a root of its own)
     assert marker_spans
     m = marker_spans[-1]
     assert m.attrs["decision"] == "shed"
-    assert m.attrs["tenant"] == "greedy"
+    assert roots[m.root_id].attrs["tenant"] == "greedy"
+    assert roots[m.root_id].attrs["outcome"] == "shed"
     svc.close()
 
 
@@ -434,9 +440,15 @@ def test_service_tenant_rides_root_spans_and_report(dist_ctx):
     assert rep.span.attrs["tenant"] == "acme"
     assert rep.span.attrs["query_id"] == tk.query_id
     assert rep.span.attrs["service"] == "svc-test"
-    # the flight ring's completed-query entry carries the same labels
-    ring = [s for s in flight.recent() if s.name == "plan.query"]
-    assert ring and ring[-1].attrs.get("tenant") == "acme"
+    # the flight ring's completed-query entry is the served query's
+    # root (PR 51: service.query, with the report's plan.query span
+    # inside its tree) and carries the labels
+    ring = [s for s in flight.recent() if s.name == "service.query"]
+    assert ring and rep.span.root_id == ring[-1].span_id
+    assert rep.span in list(ring[-1].walk())
+    assert ring[-1].attrs["tenant"] == "acme"
+    assert ring[-1].attrs["query_id"] == tk.query_id
+    assert ring[-1].attrs["service"] == "svc-test"
     svc.close()
 
 
