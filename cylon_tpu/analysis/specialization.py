@@ -19,9 +19,9 @@ recompile storm depends on each key parameter's *cardinality class*:
 * **fine-bucketed capacity** — a runtime count routed through
   ``util.capacity``'s mantissa rounding: bounded, but at 16 buckets per
   octave. Fine ONLY for a factory parameter listed in
-  ``FINE_KEYED_FACTORY_PARAMS`` (the compaction before a join's sort,
-  where every padded slot is sorted: docs/analysis.md); a finding
-  anywhere else.
+  ``FINE_KEYED_FACTORY_PARAMS`` (the compaction before a join's sort
+  and the padded exchange's block, where every padded slot is sorted:
+  docs/analysis.md); a finding anywhere else.
 * **data-dependent** — a runtime count (``device_get`` fetch,
   ``.max()``/``.sum()`` reduction) reaching a cache key raw: one compile
   per distinct value. Finding.
@@ -75,13 +75,37 @@ FINE_ROUNDER_NAMES = {"capacity", "_capacity", "_cap"}
 
 # the (factory, parameter) pairs that may take such a capacity all the
 # same, by the factory's bare name (fixture trees resolve no imports).
-# ONE: the compaction before a join's sort (PR 50). The operator behind
-# it sorts every SLOT it is handed and is itself keyed on its inputs'
-# raw capacities, so an octave here bought one factory fewer programs at
-# up to twice the slots sorted. A policy decision recorded where the
-# policy lives, not a per-line suppression: a new pair belongs here with
-# its measurement.
-FINE_KEYED_FACTORY_PARAMS = {("_compact_program_fn", "cap")}
+# Each has an operator behind it that pays for every SLOT it is handed
+# and is itself keyed on its inputs' raw capacities, so an octave here
+# bought one factory fewer programs at up to twice the slots. A policy
+# decision recorded where the policy lives, not a per-line suppression:
+# a new pair belongs here with its measurement.
+FINE_KEYED_FACTORY_PARAMS = {
+    # the compaction before a join's sort (PR 50: `tpch-q4` 0.4378 ->
+    # 0.2463 s a query)
+    ("_compact_program_fn", "cap"),
+    # the padded exchange's block, `_padded_route`'s capacity of the
+    # worst (source, target) pair (PR 52: each chip receives world *
+    # block slots and the join's or the merge's sort behind it sorts
+    # them all; `join-w4-zipf` 50.3M -> 36.2M slots a chip: PERF.md
+    # section 6, PR 52). The chunk programs take that block when the
+    # comm budget refuses the single-shot stacks; their `chunk_block` is
+    # a power of two wherever one of them is built, which the trace
+    # cannot tell from `_chunk_plan`'s single-shot answer (block, 1)
+    ("_exchange_padded_fn", "block"),
+    ("_exchange_padded_pair_fn", "block1"),
+    ("_exchange_padded_pair_fn", "block2"),
+    ("_exchange_chunk_first_fn", "block"),
+    ("_exchange_chunk_first_fn", "chunk_block"),
+    ("_exchange_chunk_fn", "block"),
+    ("_exchange_chunk_fn", "chunk_block"),
+    ("_exchange_partition_fn", "block"),
+    ("_exchange_partition_fn", "chunk_block"),
+    # a varbytes column's two padded layouts (rows, words) reconciled:
+    # both blocks are read off those exchanges' meta
+    ("_starts_reconcile_fn", "row_block"),
+    ("_starts_reconcile_fn", "word_block"),
+}
 
 # package functions known to return schema descriptors (their bodies
 # use nested defs the generic return-trace cannot follow)

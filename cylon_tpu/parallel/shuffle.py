@@ -20,6 +20,9 @@ every body message) maps to the static-shape world as a TWO-PHASE exchange:
      rows by target once, then loops K rounds, each round moving one [W,B]
      block per payload leaf through `all_to_all` and compacting received
      rows into a [cap_out] output at running per-source offsets.
+     (Where the pairs are level enough the body is ONE round with no
+     receive scatter, the "padded" layout, in a block on
+     `util.capacity`'s grid: see exchange() and `_padded_route`.)
 
 The blockwise loop is the TPU analog of the reference's incremental
 buffer-at-a-time streaming (arrow_all_to_all.cpp:83-135): peak comm-buffer
@@ -51,7 +54,8 @@ from ..resilience import retry as _retry
 from ..telemetry import counted_cache, counter as _counter, \
     host_fetch as _host_fetch, span as _span
 from ..telemetry import skew as _skew
-from ..util import pow2 as _pow2, pow2_floor as _pow2_floor
+from ..util import capacity as _capacity, pow2 as _pow2, \
+    pow2_floor as _pow2_floor
 
 # Upper bound on the per-round block (rows per (src,dst) pair per round)
 # where the memory-pool budget is UNKNOWN (stats unavailable): comm/
@@ -376,6 +380,16 @@ def _kernel_partition(payload, targets, emit, world, interpret: bool):
     return jax.tree.unflatten(treedef, out_leaves), counts_out, start
 
 
+def _padded_emit(counts_in, block: int):
+    """The padded layout's emit mask: source s's live rows are the first
+    ``counts_in[s]`` of its ``block`` slots. A compare against a
+    broadcast, not ``pos % block`` / ``pos // block`` over every slot: a
+    block on `util.capacity`'s grid is no power of two, and a division
+    by one over a chip's tens of millions of positions is no shift."""
+    biota = jnp.arange(block, dtype=jnp.int32)
+    return (biota[None, :] < counts_in[:, None]).reshape(-1)
+
+
 def _padded_partition(axis, world, block, payload, targets, emit,
                       part: str = "sort"):
     """The shared partition prefix of BOTH padded-mode bodies (the
@@ -385,7 +399,6 @@ def _padded_partition(axis, world, block, payload, targets, emit,
     start offsets and the final emit mask. ONE copy on purpose — the
     chunked path's bit-identity with the single-shot program is
     structural, not two texts kept in sync."""
-    cap_out = world * block
     if part == "sort":
         sorted_leaves, counts_out, start = _bucket_sort(
             payload, targets, emit, world)
@@ -394,9 +407,7 @@ def _padded_partition(axis, world, block, payload, targets, emit,
             payload, targets, emit, world, interpret=part == "interp")
     counts_in = jax.lax.all_to_all(counts_out, axis, split_axis=0,
                                    concat_axis=0, tiled=True)
-    pos = jnp.arange(cap_out, dtype=jnp.int32)
-    new_emit = (pos % block) < jnp.take(counts_in, pos // block)
-    return sorted_leaves, counts_in, start, new_emit
+    return sorted_leaves, counts_in, start, _padded_emit(counts_in, block)
 
 
 def _padded_body(axis, world, block, payload, targets, emit,
@@ -461,25 +472,35 @@ def _exchange_padded_fn(mesh, block: int, part: str = "sort"):
 
 def _budget_block_cap(block: int, world: int, bytes_per_row: int, budget,
                       buffer_factor: int) -> int:
-    """Halve ``block`` until buffer_factor * world * block * row bytes
+    """``block`` as it is where buffer_factor * world * block * row bytes
     (``buffer_factor`` is 4 a table the program holds) fits the comm
-    budget; never under 1,024 rows, and untouched where no budget is
-    known — the Allocator analog feeding receive buffers from the pool
-    (arrow_all_to_all.cpp:234-247). Pow2-floored: the result feeds
-    block sizes that key compiled exchange programs — keep them
-    1-per-octave (specialization analysis). ONE copy: the route's block
-    cap and the chunk block are the same arithmetic."""
-    if budget:
-        while block > 1024 and \
-                buffer_factor * world * block * bytes_per_row > budget:
-            block //= 2
-    return _pow2_floor(block)
+    budget, or where no budget is known; else halved until it fits,
+    never under 1,024 rows — the Allocator analog feeding receive
+    buffers from the pool (arrow_all_to_all.cpp:234-247). Only a block
+    that has to be cut is pow2-floored first (one chunk or round block
+    an octave): a padded block on `util.capacity`'s grid that fits must
+    come back whole, or the route would refuse the very block it sized
+    (`_padded_route`) and the plan would chunk it (`_chunk_plan`). ONE
+    copy: the route's block cap and the chunk block are the same
+    arithmetic."""
+    def fits(b):
+        return buffer_factor * world * b * bytes_per_row <= budget
+
+    if not budget or fits(block):
+        return block
+    block = _pow2_floor(block)
+    while block > 1024 and not fits(block):
+        block //= 2
+    return block
 
 
 def _chunks_of(block: int, cb: int):
     """(chunk_block, chunks) for a chunk block of about ``cb`` rows,
     raised so the pipeline never exceeds MAX_CHUNKS programs; a chunk
-    block that covers the block is the single-shot program."""
+    block that covers the block is the single-shot program. The chunk
+    block need not divide the block (one on `util.capacity`'s grid is no
+    power of two): the last chunk is moved back to end with the block
+    (`_chunk_offset`)."""
     cb = max(cb, _pow2_floor(max(block // MAX_CHUNKS, 1)))
     if cb >= block:
         return block, 1
@@ -498,29 +519,28 @@ def _chunk_plan(block: int, world: int, bytes_per_row: int, budget,
         block, world, max(int(bytes_per_row), 1), budget, buffer_factor))
 
 
+def _chunk_offset(k, block: int, cb: int):
+    """Where chunk ``k`` starts inside the block: k * cb, but a last
+    chunk that would pass the block's end starts at block - cb and
+    moves some of the rows before it a second time, the same rows to the
+    same slots. So every chunk is a whole [cb] slice whatever the block
+    (a grid block is s * 2^e, s in [17, 32]: no power of two above 2^e
+    divides it) and none needs a scatter to drop what would wrap."""
+    return jnp.minimum(k * cb, block - cb)
+
+
 def _chunk_write(axis, world, block, cb, xs, start, out, o):
     """Move ONE chunk of one leaf: slice rows [start[t]+o, +cb) per
     target (contiguous — the payload is bucket-sorted), all_to_all,
-    land source s's rows at the STATIC padded slot s*block + o. When
-    the chunk block divides the block the landing is a memcpy-class
-    dynamic_update_slice; a remainder chunk (non-pow2 geometry, only
-    reachable through forced test plans) falls back to a dropping
-    scatter so out-of-block rows vanish instead of wrapping."""
+    land source s's rows at the STATIC padded slot s*block + o by a
+    memcpy-class dynamic_update_slice (``o`` is a `_chunk_offset`, so
+    o + cb <= block)."""
     send = _send_block(xs, start, o, cb, world)
     recv = jax.lax.all_to_all(send, axis, split_axis=0,
                               concat_axis=0, tiled=False)
-    if block % cb == 0:
-        out2d = out.reshape((world, block) + xs.shape[1:])
-        out2d = jax.lax.dynamic_update_slice_in_dim(out2d, recv, o,
-                                                    axis=1)
-        return out2d.reshape((world * block,) + xs.shape[1:])
-    biota = jnp.arange(cb, dtype=jnp.int32)
-    pos = (jnp.arange(world, dtype=jnp.int32) * block)[:, None] \
-        + o + biota[None, :]
-    valid = (o + biota) < block
-    psafe = jnp.where(valid[None, :], pos, world * block).reshape(-1)
-    flat = recv.reshape((world * cb,) + xs.shape[1:])
-    return out.at[psafe].set(flat, mode="drop")
+    out2d = out.reshape((world, block) + xs.shape[1:])
+    out2d = jax.lax.dynamic_update_slice_in_dim(out2d, recv, o, axis=1)
+    return out2d.reshape((world * block,) + xs.shape[1:])
 
 
 def _partition_body(axis, world, block, cb, payload, targets, emit,
@@ -607,7 +627,7 @@ def _exchange_chunk_fn(mesh, block: int, chunk_block: int):
     spec = P(axis)
 
     def kernel(padded, start, out, k):
-        o = k.astype(jnp.int32) * chunk_block
+        o = _chunk_offset(k.astype(jnp.int32), block, chunk_block)
         return jax.tree.map(
             lambda xs, ob: _chunk_write(axis, world, block, chunk_block,
                                         xs, start, ob, o),
@@ -823,9 +843,11 @@ def _exchange_fn(mesh, block: int, rounds: int, cap_out: int):
 
 
 # padded-mode acceptance: worst-case capacity blowup over the compact
-# layout before the blockwise (skew) path takes over. Uniform hash
-# placement gives W*pow2(max_pair) <= 2*pow2(recv_max); a hot (src,dst)
-# pair blows past 2 and routes to the blockwise path.
+# layout before the blockwise (skew) path takes over. The padded block
+# is the worst pair's rows on `util.capacity`'s grid (at most 6.25%
+# over), so uniform hash placement gives W*block <= ~1.07*recv_max,
+# well inside 2*pow2(recv_max); one hot (src,dst) pair blows past 2 and
+# routes to the blockwise path.
 PADDED_WASTE_FACTOR = 2
 
 
@@ -973,15 +995,26 @@ def count_pair(targets1, emit1, targets2, emit2, ctx: CylonContext):
 
 def _padded_route(counts, payload, world: int, budget,
                   buffer_factor: int = 4, max_block: int = None):
-    """(padded_ok, block) — ONE routing rule shared by exchange() and
-    exchange_pair() so the two paths can never silently diverge."""
+    """(padded_ok, block, block cap) — ONE routing rule shared by
+    exchange() and exchange_pair() so the two paths can never silently
+    diverge. The block every (src,dst) pair crosses in is the worst
+    pair's rows on `util.capacity`'s 16-an-octave grid, not its octave:
+    each chip receives world * block SLOTS and the operator behind the
+    exchange (a join's or a merge's sort) pays for every one of them
+    (four v5e chips, PR 52: 15% over a power of two used to double the
+    block, and `join-w4-zipf` sorted 50.3M slots a chip for 35.3M rows).
+    Where the pairs INTO the worst chip are level that is the capacity a
+    compaction behind the exchange would have cut to, with no pass. The
+    price is up to 16 exchange programs (and per-shard consumers) an
+    octave of the worst pair where there was one."""
     max_pair = int(counts.max()) if counts.size else 0
     recv_max = int(counts.sum(axis=0).max()) if counts.size else 0
-    block_p = _pow2(max_pair)
+    block_p = _capacity(max_pair)
     if max_block is None:
         # a known budget decides alone: capped at MAX_BLOCK too, 16M
-        # rows a chip on 4 chips (block 8M) could never go padded and
-        # every such exchange fell to the blockwise sort+scatter rounds
+        # rows a chip on 4 chips (blocks over 4M) could never go padded
+        # and every such exchange fell to the blockwise sort+scatter
+        # rounds
         max_block = max(block_p, MAX_BLOCK) if budget else MAX_BLOCK
     mb = _budget_block_cap(max_block, world,
                            _payload_row_bytes(payload) or 4, budget,
@@ -1006,7 +1039,9 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
 
     * "padded" (the fast path): every (src,dst) pair moves one slice and
       lands at a static slot — no receive scatter. Source s's rows start
-      at s*block; capacity world*block. Picked when that padding stays
+      at s*block; capacity world*block, the block being the worst
+      pair's rows on `util.capacity`'s grid (at most 6.25% over; NOT a
+      power of two: `_padded_route`). Picked when that padding stays
       within PADDED_WASTE_FACTOR of the compact capacity (uniform-ish
       distributions, which hash placement makes the common case).
     * "compact" (skew fallback): blockwise rounds with bounded comm
@@ -1056,13 +1091,16 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
             counts, row_bytes,
             world * (cap_padded if padded_ok else cap_compact))
         if padded_ok:
-            block = block_p
             part = _partition_path(ctx.mesh, world, payload)
             cb, chunks = _chunk_plan(block_p, world, row_bytes, budget)
         else:
-            block = min(block_p, mb)
+            # the blockwise rounds scatter what they receive, whatever
+            # the slots: their block keeps its power of two, and their
+            # programs stay one an octave
+            round_block = min(_pow2(max_pair), _pow2_floor(mb))
             chunks = 1
-        rsp.set(mode="padded" if padded_ok else "compact", block=block,
+        rsp.set(mode="padded" if padded_ok else "compact",
+                block=block_p if padded_ok else round_block,
                 chunks=chunks)
     with _span("shuffle.exchange", seq, world=world,
                mode="padded" if padded_ok else "compact",
@@ -1088,12 +1126,12 @@ def exchange(payload: Dict[str, jnp.ndarray], targets: jnp.ndarray,
             return out, new_emit, cap_padded, {
                 "mode": "padded", "block": block_p, "counts_in": counts_in}
         # pow2 round count bounds the compile cache to O(log^3) programs
-        rounds = _pow2(-(-max(max_pair, 1) // block))
-        sp.set(block=block, rounds=rounds)
+        rounds = _pow2(-(-max(max_pair, 1) // round_block))
+        sp.set(block=round_block, rounds=rounds)
         out, new_emit, counts_in = _launch_exchange(
             lambda: _exchange_fn(
-                ctx.mesh, block, rounds, cap_compact)(payload, targets,
-                                                      emit))
+                ctx.mesh, round_block, rounds, cap_compact)(
+                payload, targets, emit))
     _record_exchange(rows_live, nbytes)
     return out, new_emit, cap_compact, {
         "mode": "compact", "block": 0, "counts_in": counts_in}

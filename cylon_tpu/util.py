@@ -11,9 +11,10 @@ def pow2(n: int) -> int:
 
 def pow2_floor(n: int) -> int:
     """Round DOWN to a power of two (≥1) — the budget-shrink direction:
-    a comm-buffer cap halved to fit stays a pow2, so the block sizes it
-    feeds into kernel-factory cache keys keep 1-per-octave cardinality
-    (the specialization analysis recognizes this helper)."""
+    a comm-buffer cap halved to fit stays a pow2, so the chunk and round
+    block sizes it feeds into kernel-factory cache keys keep
+    1-per-octave cardinality (the specialization analysis recognizes
+    this helper)."""
     return 1 << (max(int(n), 1).bit_length() - 1)
 
 
@@ -39,7 +40,9 @@ def bucket_cap(n: int, floor: int = BUCKET_FLOOR) -> int:
     capacity — only compile cardinality changes. The ``specialization``
     analysis family (docs/analysis.md) statically enforces that
     capacity-keyed call sites use this helper (or ``pow2`` /
-    ``pow2_floor`` for exchange blocks)."""
+    ``pow2_floor`` for a block the comm budget cut), but for the pairs
+    it lists that take ``capacity``'s grid: an operator behind them pays
+    for every padded slot."""
     return max(pow2(max(int(n), 1)), int(floor))
 
 
@@ -48,7 +51,9 @@ def capacity(n: int) -> int:
     s * 2^e ≥ n with s ∈ [17, 32]. Overshoot ≤ 6.25% (vs up to 100% for
     pow2) while still bounding distinct compiled programs to 16 per
     octave. Used for OUTPUT capacities on the hot path, where every
-    padded row costs real gather/scan work."""
+    padded row costs real gather/scan work, for the compaction before a
+    join's sort (PR 50) and for the padded exchange's block (PR 52),
+    where every padded slot is sorted."""
     n = max(int(n), 1)
     if n <= 16:
         return pow2(n)

@@ -576,11 +576,27 @@ def test_specialization_real_tree_clean_zero_suppressions():
     # census means the auditor lost sight of factories
     assert "0 data-dependent" in census[0], census[0]
     assert "0 unbounded" in census[0], census[0]
-    # ONE key on util.capacity's grid, the compaction's (PR 50): the
-    # policy's one recorded exception, not a suppression
-    assert "1 fine-bucketed-capacity" in census[0], census[0]
-    assert specialization.FINE_KEYED_FACTORY_PARAMS \
-        == {("_compact_program_fn", "cap")}
+    # the keys on util.capacity's grid: the compaction's (PR 50) and the
+    # padded exchange's block (PR 52), 11 call-site arguments in all; the
+    # policy's recorded exceptions, not suppressions
+    assert "11 fine-bucketed-capacity" in census[0], census[0]
+    assert specialization.FINE_KEYED_FACTORY_PARAMS == {
+        ("_compact_program_fn", "cap"),
+        ("_exchange_padded_fn", "block"),
+        ("_exchange_padded_pair_fn", "block1"),
+        ("_exchange_padded_pair_fn", "block2"),
+        ("_exchange_chunk_first_fn", "block"),
+        ("_exchange_chunk_first_fn", "chunk_block"),
+        ("_exchange_chunk_fn", "block"),
+        ("_exchange_chunk_fn", "chunk_block"),
+        ("_exchange_partition_fn", "block"),
+        ("_exchange_partition_fn", "chunk_block"),
+        ("_starts_reconcile_fn", "row_block"),
+        ("_starts_reconcile_fn", "word_block")}
+    # ... and the compact rounds' block is not among them: it keeps its
+    # power of two
+    assert ("_exchange_fn", "block") \
+        not in specialization.FINE_KEYED_FACTORY_PARAMS
 
 
 def test_specialization_in_fixture_cli_default():

@@ -499,21 +499,28 @@ def test_a_partial_mask_rides_only_where_it_says_more_than_the_row_mask(
                                      OPS.MAX: 9}[op]
 
 
-@pytest.mark.parametrize("row_bytes,plan", [(19, (2 ** 19, 8)),
-                                            (16, (2 ** 20, 4))])
-def test_the_cells_chunk_geometry_without_the_masks(monkeypatch, row_bytes,
-                                                    plan):
-    """`groupby-q5-w4`'s partial table crosses in blocks of 2^22 slots a
-    (source, target) pair, as ONE program since PR 48: its stacks (4
-    chips x 2^22 slots x 16 bytes, 4 buffers) are 1.07 GB of the 2.5 GB
-    that a quarter of a v5e's free HBM is. Before, a 64 MiB target cut
-    it: at 4 x 4 bytes of data and 3 bool masks a row into 8 chunk
-    programs of 2^19 rows, at 16 bytes into 4 of 2^20."""
+@pytest.mark.parametrize("block,row_bytes,plan", [
+    (2 ** 22, 19, (2 ** 19, 8)), (2 ** 22, 16, (2 ** 20, 4)),
+    (2_621_440, 19, (2 ** 19, 5)), (2_621_440, 16, (2 ** 20, 3))])
+def test_the_cells_chunk_geometry_without_the_masks(monkeypatch, block,
+                                                    row_bytes, plan):
+    """`groupby-q5-w4`'s partial table crosses in blocks of 2,621,440
+    slots a (source, target) pair (its 2.4955M rows on `util.capacity`'s
+    grid, PR 52; 2^22 on the octave before), as ONE program since PR 48:
+    its stacks (4 chips x 2^22 slots x 16 bytes, 4 buffers) were 1.07 GB
+    of the 2.5 GB that a quarter of a v5e's free HBM is. Before, a
+    64 MiB target cut it: at 4 x 4 bytes of data and 3 bool masks a row
+    into 8 chunk programs of 2^19 rows, at 16 bytes into 4 of 2^20 (the
+    grid block: 5 and 3, the last one ending with the block)."""
     import forced_paths
+    from cylon_tpu import util
     from cylon_tpu.parallel import shuffle
 
     budget = 10_000_000_000 // 4
-    assert shuffle._chunk_plan(2 ** 22, 4, row_bytes, budget) \
-        == (2 ** 22, 1)
+    assert block == util.capacity(block)
+    assert shuffle._chunk_plan(block, 4, row_bytes, budget) == (block, 1)
+    # the join cells' S side beside it: 4.81M rows a pair, two tables
+    assert shuffle._chunk_plan(4_980_736, 4, 8, budget,
+                               buffer_factor=8) == (4_980_736, 1)
     forced_paths.chunked(monkeypatch, 1 << 26)
-    assert shuffle._chunk_plan(2 ** 22, 4, row_bytes, budget) == plan
+    assert shuffle._chunk_plan(block, 4, row_bytes, budget) == plan

@@ -14,7 +14,7 @@ import pytest
 
 import cylon_tpu as ct
 import forced_paths
-from cylon_tpu import telemetry
+from cylon_tpu import telemetry, util
 from cylon_tpu.parallel import shard as _shard
 from cylon_tpu.parallel import shuffle as _shuffle
 from cylon_tpu.resilience import inject as _inject
@@ -66,8 +66,9 @@ def _assert_bit_identical(base, out):
 
 @pytest.mark.parametrize("n,cbytes,want_chunks", [
     (4096, 1 << 26, 1),    # chunk >= payload: single-shot
-    (4096, 4096, 2),       # two-chunk pipeline
-    (16384, 4096, 8),      # deep pipeline
+    (4096, 4096, 2),       # two-chunk pipeline: 240 slots in 128s
+    (16384, 4096, 7),      # deep pipeline: a block of 896 = 7 * 128
+    (16384, 8192, 4),      # 896 in 256s: the last chunk is moved back
 ])
 def test_chunked_bit_identical_across_chunk_counts(dist_ctx, monkeypatch,
                                                    n, cbytes,
@@ -76,6 +77,9 @@ def test_chunked_bit_identical_across_chunk_counts(dist_ctx, monkeypatch,
     bit: same live rows, emit mask, counts_in and capacity."""
     payload, targets, emit = _mk_exchange_inputs(dist_ctx, n)
     counts = _counts(dist_ctx, targets, emit)
+    # the forced chunk block: cbytes over 8 bytes a row on 4 chips
+    assert want_chunks == 1 or -(-util.capacity(int(counts.max()))
+                                 // (cbytes // 32)) == want_chunks
     forced_paths.single_shot(monkeypatch)
     base = _run(dist_ctx, payload, targets, emit, counts)
     forced_paths.chunked(monkeypatch, cbytes)
@@ -89,10 +93,103 @@ def test_chunked_bit_identical_across_chunk_counts(dist_ctx, monkeypatch,
     assert moved == (want_chunks if want_chunks > 1 else 0)
 
 
+def _exact_pairs(ctx, pair, seed=0):
+    """Exchange inputs whose EVERY (source, target) pair moves ``pair``
+    live rows, in a drawn order among dead ones: the block is
+    `util.capacity(pair)` by construction."""
+    import jax.numpy as jnp
+
+    world = ctx.get_world_size()
+    per = util.pow2(2 * world * pair)
+    rng = np.random.default_rng(seed)
+    t = np.zeros((world, per), np.int32)
+    e = np.zeros((world, per), bool)
+    for s in range(world):
+        where = rng.permutation(per)[:world * pair]
+        t[s, where] = np.repeat(np.arange(world), pair)
+        e[s, where] = True
+    n = world * per
+    payload = {
+        "a": _shard.pin(jnp.asarray(
+            rng.integers(0, 1 << 30, n).astype(np.int32)), ctx),
+        "b": _shard.pin(jnp.asarray(
+            rng.normal(size=n).astype(np.float32)), ctx)}
+    return (payload, _shard.pin(jnp.asarray(t.reshape(-1)), ctx),
+            _shard.pin(jnp.asarray(e.reshape(-1)), ctx))
+
+
+@pytest.mark.parametrize("pair,cbytes,want", [
+    (600, 2048, (64, 10)),      # 19 * 32 in 64s: 9 whole chunks and a half
+    (600, 8192, (256, 3)),
+    (600, 16384, (512, 2)),     # the second chunk re-lands 416 slots
+    (1200, 8192, (256, 5)),     # 19 * 64
+    (330, 4096, (128, 3)),      # 21 * 16
+])
+def test_chunked_bit_identical_at_a_grid_block(dist_ctx, monkeypatch,
+                                               pair, cbytes, want):
+    """A block on `util.capacity`'s grid (19 * 2^k, 21 * 2^k: what the
+    route sizes since PR 52) is no multiple of a power-of-two chunk
+    block above 2^k: the last chunk starts early and lands some slots a
+    second time, the same rows, and the result is the single-shot
+    program's bit for bit. No chunk program scatters."""
+    import jax
+
+    payload, targets, emit = _exact_pairs(dist_ctx, pair, seed=pair)
+    counts = _counts(dist_ctx, targets, emit)
+    assert counts.min() == counts.max() == pair
+    block = util.capacity(pair)
+    assert block != util.pow2(block) and block % want[0]
+    forced_paths.single_shot(monkeypatch)
+    base = _run(dist_ctx, payload, targets, emit, counts)
+    assert base[3]["block"] == block and base[2] == 4 * block
+    forced_paths.chunked(monkeypatch, cbytes)
+    with telemetry.collect_phases() as cp:
+        out = _run(dist_ctx, payload, targets, emit, counts)
+    _assert_bit_identical(base, out)
+    # every slot, not only the live ones: a slot landed twice holds what
+    # it held
+    for k in base[0]:
+        assert np.array_equal(np.asarray(base[0][k]),
+                              np.asarray(out[0][k])), k
+    sp, = [s for s in cp.spans if s.name == "shuffle.exchange"]
+    assert (sp.attrs["chunk_block"], sp.attrs["chunks"]) == want
+    assert out[3]["chunks"] == want[1]
+    # the chunk program at this geometry: a dynamic-update-slice a leaf
+    # and no scatter
+    world = dist_ctx.get_world_size()
+    rows = jax.tree.leaves(payload)[0].shape[0] + world * want[0]
+    leaf = lambda n, dt: jax.ShapeDtypeStruct((n,), dt)  # noqa: E731
+    hlo = _shuffle._exchange_chunk_fn(dist_ctx.mesh, block, want[0]).lower(
+        {"a": leaf(rows, np.int32), "b": leaf(rows, np.float32)},
+        leaf(world * world, np.int32),
+        {"a": leaf(world * world * block, np.int32),
+         "b": leaf(world * world * block, np.float32)},
+        jax.ShapeDtypeStruct((), np.int32)).as_text()
+    assert "scatter" not in hlo
+    assert hlo.count("dynamic_update_slice") == 2
+
+
+@pytest.mark.parametrize("block", [608, 1216, 336, 2 ** 9, 5, 2_621_440])
+def test_padded_emit_is_the_modulo_form(block):
+    """`_padded_emit` (a compare against a broadcast) against the form
+    it replaced, `pos % block < counts_in[pos // block]`, at blocks that
+    are no power of two."""
+    import jax.numpy as jnp
+
+    world = 4
+    rng = np.random.default_rng(block)
+    counts_in = rng.integers(0, block + 1, world).astype(np.int32)
+    counts_in[1], counts_in[2] = 0, block
+    pos = np.arange(world * block)
+    want = (pos % block) < counts_in[pos // block]
+    got = np.asarray(_shuffle._padded_emit(jnp.asarray(counts_in), block))
+    assert got.shape == want.shape and np.array_equal(got, want)
+
+
 def test_chunked_bit_identical_odd_remainder(dist_ctx, monkeypatch):
-    """A non-pow2 chunk block (forced plan) exercises the dropping-
-    scatter remainder path; the last partial chunk must neither wrap
-    nor clobber earlier rows."""
+    """A chunk block that is no power of two and divides nothing (forced
+    plan): the last chunk, moved back to end with the block, must
+    neither wrap nor clobber earlier rows."""
     payload, targets, emit = _mk_exchange_inputs(dist_ctx, 4096, seed=3)
     counts = _counts(dist_ctx, targets, emit)
     forced_paths.single_shot(monkeypatch)
@@ -327,8 +424,21 @@ V5E_BUDGET = 10_000_000_000 // 4
     (2 ** 30, 64, 1 << 20, 4, (2 ** 24, 64)),
     # `_padded_route`'s floor: a block of 1,024 rows is never cut
     (2 ** 10, 8, 1, 4, (2 ** 10, 1)),
-], ids=["join-w4", "join-w4-zipf", "groupby-q5-w4", "quarter-budget",
-        "exact-fit", "no-budget", "max-chunks", "route-floor"])
+    # the blocks the cells cross in since PR 52, `util.capacity` of the
+    # worst pair (4.00M, 4.81M and 2.4955M rows): a block that fits comes
+    # back whole, never floored to the octave under it
+    (4_063_232, 8, V5E_BUDGET, 8, (4_063_232, 1)),
+    (4_980_736, 8, V5E_BUDGET, 8, (4_980_736, 1)),
+    (2_621_440, 16, V5E_BUDGET, 4, (2_621_440, 1)),
+    # a grid block (19 * 2^18) over a budget of 5/8 of its stacks: the
+    # chunk block is the power of two that fits, the second chunk ends
+    # with the block
+    (4_980_736, 8, 4 * 4 * 4_980_736 * 8 * 5 // 8, 4, (2 ** 21, 3)),
+    (4_980_736, 8, 4 * 4 * 4_980_736 * 8 - 1, 4, (2 ** 22, 2)),
+], ids=["join-w4-octave", "join-w4-zipf-octave", "groupby-q5-w4-octave",
+        "quarter-budget", "exact-fit", "no-budget", "max-chunks",
+        "route-floor", "join-w4", "join-w4-zipf", "groupby-q5-w4",
+        "grid-block-cut", "grid-block-a-byte-over"])
 def test_chunk_plan_is_decided_by_the_budget(block, row_bytes, budget,
                                              factor, want):
     """`_chunk_plan` reads the geometry and the pool's comm budget, no
@@ -339,27 +449,32 @@ def test_chunk_plan_is_decided_by_the_budget(block, row_bytes, budget,
     assert want[1] <= _shuffle.MAX_CHUNKS
     # what the route admits on a budget, the plan leaves whole
     payload = {"x": np.zeros((8, row_bytes), np.uint8)}
-    ok, block_p, _mb = _shuffle._padded_route(
+    ok, block_p, mb = _shuffle._padded_route(
         np.full((4, 4), block), payload, 4, budget, buffer_factor=factor)
     if ok and budget:
-        assert block_p == block and want[1] == 1
+        assert block_p == block <= mb and want[1] == 1
 
 
-@pytest.mark.parametrize("s_pair_rows", [4_000_000, 8_000_000],
-                         ids=["join-w4", "join-w4-zipf"])
+@pytest.mark.parametrize("s_pair_rows,s_block", [
+    (4_003_000, 4_063_232), (4_810_000, 4_980_736),
+    (8_000_000, 8_126_464)],
+    ids=["join-w4", "join-w4-zipf", "twice-the-pair"])
 def test_exchange_pair_is_one_program_at_the_cells_geometry(
-        dist_ctx, monkeypatch, s_pair_rows):
+        dist_ctx, monkeypatch, s_pair_rows, s_block):
     """16M rows of 8 bytes a side a chip on four chips, on a v5e's
     budget: `exchange_pair` takes its fused branch (PR 48; the 64 MiB
     chunk target sent each side through 2-4 chunk programs), one
-    dispatch and one collective launch for the two tables. Routed over
-    shapes alone: the pair program is stood in for."""
+    dispatch and one collective launch for the two tables, in blocks on
+    `util.capacity`'s grid (PR 52: S's 4.81M rows a pair crossed in
+    2^23 slots). Routed over shapes alone: the pair program is stood in
+    for."""
     import jax
 
     rows = 4 * 16_000_000
     leaf = jax.ShapeDtypeStruct((rows,), np.int32)
     side = {"k": leaf, "v": leaf}
-    counts = [np.full((4, 4), n) for n in (4_000_000, s_pair_rows)]
+    counts = [np.full((4, 4), n) for n in (4_003_000, s_pair_rows)]
+    assert util.capacity(s_pair_rows) == s_block
     built = []
 
     def pair_fn(mesh, b1, b2, part1, part2):
@@ -380,14 +495,15 @@ def test_exchange_pair_is_one_program_at_the_cells_geometry(
                                         side, None, None, counts[1],
                                         dist_ctx)
     assert launches() - l0 == 1
-    assert built == [(2 ** 22, _shuffle._pow2(s_pair_rows))]
+    assert built == [(4_063_232, s_block)]
     route, = [s for s in cp.spans if s.name == "shuffle.route"]
     assert (route.attrs["mode"], route.attrs["chunks"],
             route.attrs["tables"]) == ("pair", 1, 2)
     assert [s.name for s in cp.spans if s.name.startswith(
         "shuffle.exchange")] == ["shuffle.exchange_pair"]
     assert (r1[0], r1[2], r2[0], r2[2]) \
-        == ("o1", 4 * 2 ** 22, "o2", 4 * _shuffle._pow2(s_pair_rows))
+        == ("o1", 4 * 4_063_232, "o2", 4 * s_block)
+    assert (r1[3]["block"], r2[3]["block"]) == (4_063_232, s_block)
     assert "chunks" not in r1[3] and "chunks" not in r2[3]
 
 
@@ -431,17 +547,21 @@ def test_distributed_join_identical_under_overlap(dist_ctx, monkeypatch,
 
 def test_padded_route_block_cap_comes_from_a_known_budget():
     """16M rows a chip on 4 chips: every (src, dst) pair moves ~4.2M
-    rows, so the padded block is 8M — past MAX_BLOCK, which caps the
-    block only where the budget is unknown. With live HBM numbers the
-    budget alone decides (chip run, PR 22: capped at MAX_BLOCK the main
-    path fell to the blockwise sort rounds and never met the partition
-    kernel)."""
+    rows, so the padded block is 4.46M (`util.capacity`; 8M on the
+    octave before PR 52) — past MAX_BLOCK, which caps the block only
+    where the budget is unknown. With live HBM numbers the budget alone
+    decides (chip run, PR 22: capped at MAX_BLOCK the main path fell to
+    the blockwise sort rounds and never met the partition kernel)."""
     counts = np.full((4, 4), 4_200_000)
     payload = {"k": np.zeros(8, np.int32), "w": np.zeros(8, np.float32)}
+    grid = util.capacity(4_200_000)
+    assert grid == 4_456_448 == 17 * 2 ** 18
     assert _shuffle._padded_route(counts, payload, 4, None) \
-        == (False, 1 << 23, _shuffle.MAX_BLOCK)
+        == (False, grid, _shuffle.MAX_BLOCK)
     ok, block, mb = _shuffle._padded_route(counts, payload, 4, 4 << 30)
-    assert ok and block == 1 << 23 and mb >= block
+    # the cap is the block itself, whole: floored to 2^22 it would
+    # refuse the block it was sized for
+    assert ok and block == grid == mb
     # a budget that cannot hold 4 * world * block * 8 B still refuses
     assert not _shuffle._padded_route(counts, payload, 4, 512 << 20)[0]
     # an explicit max_block binds whatever the budget

@@ -179,3 +179,44 @@ def test_compaction_builds_are_bounded_by_the_grid(local_ctx):
         slots += out.capacity
     assert _builds("_compact_program_fn") - before <= len(caps)
     assert slots <= 1.0625 * sum(counts) < 0.8 * len(counts) * bucket_cap(1030)
+
+
+def test_exchange_builds_are_bounded_by_the_grid(dist_ctx):
+    """The padded exchange's block comes through `util.capacity` (PR 52;
+    analysis/specialization.FINE_KEYED_FACTORY_PARAMS): over an octave
+    of worst pairs `_exchange_padded_fn` builds at most 16 programs, one
+    a grid block and not one a count, each chip's slots stay within
+    6.25% of world x the worst pair where the octave's rounding held up
+    to twice that, and every live row arrives."""
+    import jax.numpy as jnp
+
+    from cylon_tpu.parallel import shard as _shard
+    from cylon_tpu.parallel import shuffle
+
+    world = dist_ctx.get_world_size()
+    per = 2048                      # rows a source
+    pairs = list(range(260, 512, 9))
+    blocks = {util.capacity(p) for p in pairs}
+    assert len(pairs) == 28 and len(blocks) <= 16
+    rng = np.random.default_rng(52)
+    vals = rng.integers(0, 1 << 30, world * per).astype(np.int32)
+    payload = {"v": _shard.pin(jnp.asarray(vals), dist_ctx)}
+    before = _builds("_exchange_padded_fn")
+    slots = 0
+    for p in pairs:
+        # every source sends every target exactly p live rows
+        t = np.tile(np.repeat(np.arange(world), per // world), world)
+        e = np.tile(np.tile(np.arange(per // world) < p, world), world)
+        counts = np.full((world, world), p)
+        out, emit, cap, meta = shuffle.exchange(
+            payload, _shard.pin(jnp.asarray(t.astype(np.int32)), dist_ctx),
+            _shard.pin(jnp.asarray(e), dist_ctx), dist_ctx, counts=counts)
+        assert meta["mode"] == "padded"
+        assert meta["block"] == util.capacity(p)
+        assert cap == world * meta["block"]
+        got = np.asarray(out["v"])[np.asarray(emit)]
+        assert sorted(got.tolist()) == sorted(vals[e].tolist())
+        slots += cap
+    assert _builds("_exchange_padded_fn") - before <= len(blocks)
+    assert slots <= 1.0625 * world * sum(pairs) \
+        < 0.8 * world * sum(util.pow2(p) for p in pairs)

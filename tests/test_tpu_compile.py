@@ -444,16 +444,19 @@ def test_dist_groupby_gather_program_still_compiles(shards4):
 # cells run since `_chunk_plan` chunks by the budget and not by 64 MiB ---------
 
 @pytest.mark.parametrize("cell,blocks,rows,leaves,collectives", [
-    ("join-w4", (2 ** 22, 2 ** 22), 16_000_000, 2, 6),
-    ("join-w4-zipf", (2 ** 22, 2 ** 23), 16_000_000, 2, 6),
-    ("groupby-q5-w4", (2 ** 22,), 62_500_000, 4, 5),
+    ("join-w4", (4_063_232, 4_063_232), 16_000_000, 2, 6),
+    ("join-w4-zipf", (4_063_232, 4_980_736), 16_000_000, 2, 6),
+    ("groupby-q5-w4", (2_621_440,), 62_500_000, 4, 5),
 ])
 def test_single_shot_exchange_compiles_at_the_cells_shapes(
         shards4, cell, blocks, rows, leaves, collectives):
     """A join's two sides in the fused pair program (no cell reached it on
     a TPU before PR 48) and the groupby's partial table in the single
     program, partitioned by the Pallas kernels, for the described 2x2
-    mesh at the cells' rows a chip: Mosaic and HBM take them, one
+    mesh at the cells' rows a chip and in the blocks they cross in since
+    PR 52 (`util.capacity` of the worst pair's 4.00M, 4.81M and 2.4955M
+    rows: 31 * 2^17, 19 * 2^18, 20 * 2^17; 2^22, 2^23 and 2^22 before):
+    Mosaic and HBM take them, no power of two among them, one
     all_to_all a leaf and one for the counts a table, and of the chunk
     programs' loops and landings (2 `while`, 4 `dynamic-update-slice` a
     two-leaf chunk program) only the partition prefix's own are left,
@@ -485,6 +488,34 @@ def test_single_shot_exchange_compiles_at_the_cells_shapes(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes \
         + mem.argument_size_in_bytes < 6 * 10 ** 9, mem
+
+
+def test_join_plan_compiles_at_the_zipf_cells_slots(shards4):
+    """What the exchange hands `join-w4-zipf`'s per-shard join since PR 52:
+    16,252,928 slots of R and 19,922,944 of S a chip (four blocks of
+    4,063,232 and of 4,980,736; 2^24 and 2^25 before), neither a power of
+    two. The stream plan under `shard_map` over the described 2x2 mesh:
+    ONE sort of 36,175,872 slots and three operands, one kernel."""
+    from cylon_tpu.parallel import dist_ops
+
+    mesh, rows = shards4
+    na, nb = 4 * 4_063_232, 4 * 4_980_736
+    r, s = rows(4 * na), rows(4 * nb)
+    ldat, rdat = (r(jnp.int32), r(jnp.float32)), (s(jnp.int32),
+                                                  s(jnp.float32))
+    none2 = (None, None)
+    a_desc, b_desc = _join.plan_lane_descs(ldat, none2, rdat, none2,
+                                           _join.JoinType.INNER, 0, 0)
+    fn = dist_ops._join_plan_stream_fn(
+        mesh, _join.JoinType.INNER, 1, a_desc, b_desc,
+        _join.stream_block_rows(na, nb), False)
+    with jax.enable_x64(False):
+        text = fn.lower((r(jnp.uint32),), None, r(jnp.bool_),
+                        (s(jnp.uint32),), None, s(jnp.bool_),
+                        ldat, none2, rdat, none2).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    sorts = _sort_lines(text)
+    assert len(sorts) == 1 and sorts[0].count(f"u32[{na + nb}]") == 3, sorts
 
 
 # -- TPC-H Q1 (PR 42): the expression programs and the dense table over two
